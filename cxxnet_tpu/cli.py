@@ -347,8 +347,8 @@ class LearnTask:
         from . import obs
 
         obs.configure(self.cfg)
-        # persistent XLA compile cache (compile_cache_dir): enabled
-        # before ANY jit of this run so every task's programs hit it
+        # persistent XLA compile cache, on for every task and enabled
+        # before ANY jit of this run (utils/compile_cache.py says where)
         compile_cache.configure(self.cfg, silent=bool(self.silent))
         if self.task not in ("train", "finetune", "pred", "pred_raw",
                              "extract", "generate", "summary", "serve",

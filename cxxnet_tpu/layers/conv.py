@@ -506,24 +506,6 @@ def _unpool_strided(xp, y, g, kh, kw, s, oh, ow):
 _maxpool_eq.defvjp(_maxpool_eq_fwd, _maxpool_eq_bwd)
 
 
-_PALLAS_PBWD_OK: dict = {}
-
-
-def _pallas_pool_bwd_works(k: int, pad: int, nchannel: int, dtype) -> bool:
-    """Compile probe for the stride-1 one-pass backward kernel."""
-    key = (k, pad, int(nchannel), jnp.dtype(dtype).name)
-    if key not in _PALLAS_PBWD_OK:
-        from ..ops.maxpool import maxpool_bwd_s1
-
-        def probe():
-            v0 = jnp.ones((2, k + 2, k + 2, key[2]), dtype)
-            y0 = _maxpool_eq(v0, k, k, 1, pad, pad)
-            maxpool_bwd_s1(v0, y0, y0, k, pad).block_until_ready()
-
-        _PALLAS_PBWD_OK[key] = _run_probe_untraced(probe)
-    return _PALLAS_PBWD_OK[key]
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
 def _maxpool_eq_pb(x, k: int, pad: int, interpret: bool):
     """Stride-1 max pooling: XLA forward tree (cheap, fuses well) with
@@ -546,48 +528,6 @@ def _maxpool_eq_pb_bwd(k, pad, interpret, res, g):
 
 
 _maxpool_eq_pb.defvjp(_maxpool_eq_pb_fwd, _maxpool_eq_pb_bwd)
-
-
-_PALLAS_POOL_OK: dict = {}
-
-
-def _run_probe_untraced(fn) -> bool:
-    """Run a compile probe on a worker thread.
-
-    Probes fire while the net is being jit-traced (layer ``apply`` is
-    where the impl choice lives); JAX trace contexts are thread-local,
-    so a worker thread executes the probe eagerly — really compiling
-    and running the kernel — instead of tracing junk into the outer
-    program and failing spuriously (``block_until_ready`` on a tracer),
-    which would silently disable every Pallas kernel inside real nets.
-    """
-    import concurrent.futures
-
-    with concurrent.futures.ThreadPoolExecutor(1) as ex:
-        try:
-            ex.submit(fn).result(timeout=300)
-            return True
-        except Exception:  # pragma: no cover - backend-specific
-            return False
-
-
-def _pallas_pool_works(kh, kw, s, py, px, nchannel, dtype) -> bool:
-    """Compile probe so ``pool_impl=auto`` can never take down a run
-    (same discipline as the LRN kernel's probe): keyed on the full
-    static config + channel count + dtype, probing fwd AND bwd."""
-    key = (kh, kw, s, py, px, int(nchannel), jnp.dtype(dtype).name)
-    if key not in _PALLAS_POOL_OK:
-        from ..ops.maxpool import maxpool_fused
-
-        def probe():
-            v0 = jnp.ones((2, kh + s, kw + s, key[5]), dtype)
-            jax.grad(
-                lambda v: maxpool_fused(v, kh, kw, s, py, px)
-                .astype(jnp.float32).sum()
-            )(v0).block_until_ready()
-
-        _PALLAS_POOL_OK[key] = _run_probe_untraced(probe)
-    return _PALLAS_POOL_OK[key]
 
 
 class _PoolBase(Layer):
@@ -649,71 +589,36 @@ class _PoolBase(Layer):
             acc = sl if acc is None else reducer(acc, sl)
         return acc
 
-    def _use_pallas(self, nchannel: int, dtype) -> bool:
-        """``pool_impl = pallas`` is explicit opt-in; ``auto`` never
-        chooses the kernel: it wins isolated microbenchmarks (2.39 vs
-        3.26 ms for the b128 inception pool, fwd+bwd) but embedding 9
-        pool kernels in the scanned train step regressed XLA compile
-        time pathologically on the v5e AOT runtime, and stride>1 needs
-        a strided slice Mosaic lowers as an unsupported gather
-        (doc/performance.md).  Opt-in still goes through the compile
-        probe on TPU so a bad geometry degrades to the XLA path with a
-        warning instead of taking down the run."""
-        if self.pool_impl != "pallas":
-            return False
-        if jax.default_backend() != "tpu":
-            return True  # interpret mode, works on any backend
-        p = self.param
-        if _pallas_pool_works(p.kernel_height, p.kernel_width, p.stride,
-                              p.pad_y, p.pad_x, nchannel, dtype):
-            return True
-        import warnings
-
-        warnings.warn(
-            f"{self.type_name}: pool_impl=pallas requested but the kernel "
-            f"probe failed for k=({p.kernel_height},{p.kernel_width}) "
-            f"s={p.stride} C={nchannel}; using the XLA path"
-        )
-        return False
-
     def _max_pool(self, x: jnp.ndarray) -> jnp.ndarray:
         """Max pooling with the unpool-equality backward: the XLA
         expression (``_maxpool_eq``) by default, the fused Pallas
         kernel (``ops/maxpool.py``) under ``pool_impl = pallas``, or
-        XLA forward + the one-pass Pallas backward for stride-1 pools
-        under ``pool_impl = pallas_bwd`` — identical semantics,
-        pair-tested."""
-        p = self.param
-        if self.pool_impl == "pallas_bwd":
-            eligible = (
-                p.stride == 1
-                and p.kernel_height == p.kernel_width
-                and p.pad_y == p.pad_x
-                and p.pad_y * 2 == p.kernel_height - 1  # same-size only
-            )
-            if eligible:
-                interp = jax.default_backend() != "tpu"
-                if interp or _pallas_pool_bwd_works(
-                    p.kernel_height, p.pad_y, x.shape[-1], x.dtype
-                ):
-                    return _maxpool_eq_pb(
-                        x, p.kernel_height, p.pad_y, interp
-                    )
-            import warnings
+        XLA forward + the one-pass Pallas backward under ``pool_impl =
+        pallas_bwd`` for the pools that kernel is defined for
+        (same-size stride-1, odd k — every other pool in the net stays
+        on the XLA path) — identical semantics, pair-tested.
 
-            warnings.warn(
-                f"{self.type_name}: pool_impl=pallas_bwd "
-                + ("probe failed"
-                   if eligible else
-                   "needs a same-size stride-1 pool (odd k, pad=(k-1)/2)")
-                + f" for k=({p.kernel_height},{p.kernel_width}) "
-                f"s={p.stride} pad=({p.pad_y},{p.pad_x}) "
-                f"C={x.shape[-1]}; using the XLA path"
-            )
-        if self._use_pallas(x.shape[-1], x.dtype):
+        Both kernels are explicit opt-ins and ``auto`` never chooses
+        them: ``pallas`` won isolated microbenchmarks but regressed the
+        scanned train step's compile time pathologically on the v5e,
+        and ``pallas_bwd`` lost in context (doc/performance.md).  An
+        opt-in is honoured or fails: off the chip the kernel runs
+        under the Pallas interpreter; on it a geometry Mosaic or libtpu
+        refuses (stride > 1 needs a strided slice Mosaic lowers as an
+        unsupported gather) fails the program's compile with the
+        compiler's message — it is never swapped for the XLA path."""
+        p = self.param
+        interp = jax.default_backend() != "tpu"
+        if self.pool_impl == "pallas_bwd" and (
+            p.stride == 1
+            and p.kernel_height == p.kernel_width
+            and p.pad_y == p.pad_x
+            and p.pad_y * 2 == p.kernel_height - 1
+        ):
+            return _maxpool_eq_pb(x, p.kernel_height, p.pad_y, interp)
+        if self.pool_impl == "pallas":
             from ..ops.maxpool import maxpool_fused
 
-            interp = jax.default_backend() != "tpu"  # forced-on off-TPU
             return maxpool_fused(
                 x, p.kernel_height, p.kernel_width, p.stride, p.pad_y,
                 p.pad_x, interp,
@@ -799,29 +704,6 @@ class InsanityPoolingLayer(_PoolBase):
         return [self._max_pool(x)]
 
 
-_PALLAS_LRN_OK: dict = {}
-
-
-def _pallas_lrn_works(nchannel: int, dtype) -> bool:
-    """Compile probe so ``lrn_impl=auto`` can never take down a run on a
-    backend whose Pallas lowering is broken/unavailable.
-
-    Keyed on ``(channel count, dtype)`` and probed at the layer's real
-    channel width: a backend that compiles the aligned 128-lane case can
-    still reject the 64- or 192-lane blocks GoogLeNet actually runs.
-    """
-    key = (int(nchannel), jnp.dtype(dtype).name)
-    if key not in _PALLAS_LRN_OK:
-        from ..ops.lrn import lrn
-
-        def probe():
-            lrn(jnp.ones((8, key[0]), dtype), 5, 1e-4, 0.75, 1.0
-                ).block_until_ready()
-
-        _PALLAS_LRN_OK[key] = _run_probe_untraced(probe)
-    return _PALLAS_LRN_OK[key]
-
-
 @register
 class LRNLayer(Layer):
     type_name = "lrn"
@@ -852,29 +734,6 @@ class LRNLayer(Layer):
         else:
             super().set_param(name, val)
 
-    def _use_pallas(self, nchannel: int, dtype) -> bool:
-        """``lrn_impl = pallas`` is explicit opt-in.  ``auto`` stays on
-        the XLA path: embedding the kernel in the scanned GoogLeNet
-        train step regressed XLA compile from ~47s to >25min on the
-        v5e AOT runtime (same pathology as the pool kernel,
-        doc/performance.md), and the measured step-time difference
-        vs lrn_xla was ~0 — LRN is ~3.5ms of a 60ms step.  Opt-in
-        still goes through the compile probe on TPU so an unsupported
-        shape degrades to lrn_xla with a warning, not a crash."""
-        if self.impl != "pallas":
-            return False
-        if jax.default_backend() != "tpu":
-            return True  # interpret mode, works on any backend
-        if _pallas_lrn_works(nchannel, dtype):
-            return True
-        import warnings
-
-        warnings.warn(
-            f"lrn: lrn_impl=pallas requested but the kernel probe failed "
-            f"for C={nchannel} {jnp.dtype(dtype).name}; using lrn_xla"
-        )
-        return False
-
     def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
         self._check_arity(in_shapes, 1)
         return [tuple(in_shapes[0])]
@@ -883,8 +742,13 @@ class LRNLayer(Layer):
         from ..ops.lrn import lrn, lrn_matmul, lrn_xla
 
         x = inputs[0]
-        if self._use_pallas(x.shape[-1], x.dtype):
-            interp = jax.default_backend() != "tpu"  # forced-on off-TPU
+        if self.impl == "pallas":
+            # explicit opt-in, honoured or failed (never swapped for
+            # lrn_xla): interpreter off the chip, Mosaic on it.  auto
+            # stays on XLA — in the scanned GoogLeNet step the kernel
+            # took compile from ~47s to >25min on the v5e for a ~0
+            # step-time difference (doc/performance.md)
+            interp = jax.default_backend() != "tpu"
             y = lrn(x, self.nsize, self.alpha, self.beta, self.knorm, interp)
         elif self.impl == "matmul":
             y = lrn_matmul(x, self.nsize, self.alpha, self.beta, self.knorm)

@@ -45,19 +45,45 @@ def _layer_norm(x, w, b, eps: float):
     ).astype(x.dtype)
 
 
-_FLASH_OK: dict = {}
+_FLASH_PROBE: dict = {}  # attention geometry -> None (works) | the error
 
 
-def _flash_works(t: int, tk: int, dh: int, dtype, causal: bool,
-                 ring: bool = False) -> bool:
-    """Compile probe so ``attn_impl`` can never take down a run (the
-    pool/LRN probe discipline, layers/conv.py): keyed on the static
-    attention geometry, probing fwd AND bwd of the real (T, Dh).
+_SAID: set = set()
+
+
+def _say_once(msg: str) -> None:
+    """One stderr line plus a structured event, once per distinct
+    message, for an attention-path decision an operator must be able to
+    see: a failed kernel probe, an ``auto`` that lands on the XLA path
+    on a chip."""
+    if msg in _SAID:
+        return
+    _SAID.add(msg)
+    import sys
+
+    from ..obs import events as obs_events
+
+    obs_events.emit("attention.impl", message=msg)
+    print(f"attention: {msg}", file=sys.stderr, flush=True)
+
+
+def _flash_probe(t: int, tk: int, dh: int, dtype, causal: bool,
+                 ring: bool = False):
+    """Compile and run the flash kernel, fwd AND bwd, at the real static
+    attention geometry; returns None when it works, else the exception
+    (cached per geometry, reported once — never swallowed).
     ``ring=True`` probes the dynamic-offset lse kernel the flash ring
-    uses (per-shard shapes)."""
+    uses (per-shard shapes).
+
+    Probes fire while the net is being jit-traced (layer ``apply`` is
+    where the impl choice lives); JAX trace contexts are thread-local,
+    so a worker thread executes the probe eagerly — really compiling
+    and running the kernel — instead of tracing it into the outer
+    program."""
     key = (t, tk, dh, jnp.dtype(dtype).name, causal, ring)
-    if key not in _FLASH_OK:
-        from .conv import _run_probe_untraced
+    if key not in _FLASH_PROBE:
+        import concurrent.futures
+
         from ..ops.flash import flash_mha, flash_mha_lse
 
         def probe():
@@ -77,8 +103,18 @@ def _flash_works(t: int, tk: int, dh: int, dtype, causal: bool,
                     ).astype(jnp.float32).sum()
             jax.grad(f)(q).block_until_ready()
 
-        _FLASH_OK[key] = _run_probe_untraced(probe)
-    return _FLASH_OK[key]
+        err = None
+        with concurrent.futures.ThreadPoolExecutor(1) as ex:
+            try:
+                ex.submit(probe).result()
+            except Exception as e:  # noqa: BLE001 - reported, then returned
+                err = e
+                _say_once(
+                    f"flash kernel probe failed for T={t}, Tk={tk}, "
+                    f"Dh={dh}, {key[3]}, causal={causal}, ring={ring}: "
+                    f"{type(e).__name__}: {e}")
+        _FLASH_PROBE[key] = err
+    return _FLASH_PROBE[key]
 
 
 @register
@@ -161,28 +197,42 @@ class AttentionLayer(Layer):
             t, tk, dh = q.shape[1], k.shape[1], q.shape[3]
             on_tpu = jax.default_backend() == "tpu"
             if self.attn_impl == "auto":
-                # auto never takes the interpret-mode emulation (a silent
-                # orders-of-magnitude slowdown off-TPU), and falls back
-                # to mha when an odd T would shrink blocks into scalar
-                # territory (block 1 kernels compile forever / run slow)
-                if (
-                    not on_tpu
-                    or t < self._AUTO_FLASH_MIN_T
-                    or _pick_block(t, 512) < 128
-                    or _pick_block(tk, 512) < 128
-                ):
+                # auto never takes the interpret-mode emulation (an
+                # orders-of-magnitude slowdown off-TPU), and short
+                # sequences are the XLA path's home ground
+                if not on_tpu or t < self._AUTO_FLASH_MIN_T:
                     return xla_attn(q, k, v, causal)
-            if on_tpu and not _flash_works(t, tk, dh, q.dtype, causal):
-                if self.attn_impl == "pallas":
-                    raise RuntimeError(
-                        "attention: attn_impl=pallas requested but the "
-                        f"flash kernel probe failed for T={t}, Dh={dh}, "
-                        f"{q.dtype} on this backend"
-                    )
-                return xla_attn(q, k, v, causal)
+                # past here auto MEANS flash; landing on mha is reported
+                if (_pick_block(t, 512) < 128
+                        or _pick_block(tk, 512) < 128):
+                    # an odd T shrinks blocks into scalar territory
+                    # (block 1 kernels compile forever / run slow)
+                    self._say_mha(
+                        t, tk, "no block >= 128 divides the sequence")
+                    return xla_attn(q, k, v, causal)
+            if on_tpu:
+                err = _flash_probe(t, tk, dh, q.dtype, causal)
+                if err is not None:
+                    if self.attn_impl == "pallas":
+                        raise RuntimeError(
+                            "attention: attn_impl=pallas requested but "
+                            f"the flash kernel failed for T={t}, Dh={dh}, "
+                            f"{q.dtype} on this backend: "
+                            f"{type(err).__name__}: {err}"
+                        ) from err
+                    self._say_mha(t, tk, "the flash kernel probe failed")
+                    return xla_attn(q, k, v, causal)
             return flash_attn(q, k, v, causal)
 
         return dispatch
+
+    @staticmethod
+    def _say_mha(t: int, tk: int, why: str) -> None:
+        """``attn_impl = auto`` on a chip at flash-sized T is taking
+        the XLA path — O(T^2) score memory where the operator expects
+        O(T).  Said once per geometry, never silently."""
+        _say_once(f"attn_impl=auto at T={t}, Tk={tk} runs the XLA mha "
+                  f"path, not the flash kernel: {why}")
 
     def bind_mesh(self, plan) -> None:
         self.mesh_plan = plan
@@ -334,14 +384,16 @@ class AttentionLayer(Layer):
                             f"with a block >= 128; use attn_impl=xla "
                             f"for short shards"
                         )
-                    if not _flash_works(
+                    err = _flash_probe(
                         ts, ts, dh, q.dtype, bool(self.causal), ring=True
-                    ):
+                    )
+                    if err is not None:
                         raise RuntimeError(
                             "attention: attn_impl=pallas requested but "
-                            f"the flash ring kernel probe failed for "
-                            f"T={ts}, Dh={dh}, {q.dtype} on this backend"
-                        )
+                            f"the flash ring kernel failed for T={ts}, "
+                            f"Dh={dh}, {q.dtype} on this backend: "
+                            f"{type(err).__name__}: {err}"
+                        ) from err
                 o = ring_self_attention_flash(
                     q, k, v, plan.mesh, "model", causal=bool(self.causal),
                     interpret=jax.default_backend() != "tpu",

@@ -189,40 +189,73 @@ def alexnet_conf(
 
 
 # ---------------------------------------------------------------------------
-def _inception(x: str, m: str, c1: int, c3r: int, c3: int, c5r: int, c5: int,
-               cp: int) -> str:
-    """One GoogLeNet inception module: 4 branches ch_concat'd to node m."""
+def _he_init(cin: int, k: int) -> str:
+    """Gaussian init at ``sqrt(2 / fan_in)``, spelled out per conv.
 
-    def conv(src: str, dst: str, tag: str, k: int, ch: int, pad: int) -> str:
-        # kaiming, not xavier: every branch conv feeds a relu, and xavier
-        # halves activation variance per relu layer — measured signal
-        # collapse of ~2x per inception block by i5b (the vanishing the
-        # paper's auxiliary heads existed to patch); He-init keeps the
-        # forward signal unit-scale through all 9 modules
+    Every GoogLeNet conv feeds a relu, so He scaling keeps the forward
+    signal's second moment through the stack.  The conf grammar's own
+    ``random_type = kaiming`` is the reference's rule (param.h) and
+    scales by ``nchannel * k * k`` — fan-OUT.  An inception module is
+    many-in / few-out 1x1 reduces (192 -> 16 in i3a), where that
+    over-scales by up to sqrt(cin / cout): measured on unit-variance
+    input, activations doubled per module to ~10^3 at the classifier,
+    the first sgd step at the conf's own eta blew the weights up and
+    the second gave ``logloss:nan`` (CHANGES.md, PR 21).  With fan-in
+    scaling the same probe stays at rms ~3 through all nine modules.
+    """
+    return ("  random_type = gaussian\n"
+            f"  init_sigma = {(2.0 / (cin * k * k)) ** 0.5:.6g}\n")
+
+
+def _inception(x: str, m: str, cin: int, c1: int, c3r: int, c3: int,
+               c5r: int, c5: int, cp: int) -> str:
+    """One GoogLeNet inception module over the ``cin``-channel node
+    ``x``: 4 branches ch_concat'd to node ``m`` (``c1+c3+c5+cp``
+    channels)."""
+
+    def conv(src: str, dst: str, tag: str, k: int, ci: int, ch: int,
+             pad: int) -> str:
         return (
             f"layer[{src}->{dst}] = conv:{tag}\n"
             f"  kernel_size = {k}\n  nchannel = {ch}\n  pad = {pad}\n"
-            "  random_type = kaiming\n"
+            + _he_init(ci, k)
         )
 
-    s = conv(x, f"{m}_c1", f"{m}_1x1", 1, c1, 0)
+    s = conv(x, f"{m}_c1", f"{m}_1x1", 1, cin, c1, 0)
     s += f"layer[+1:{m}_b1] = relu\n"
-    s += conv(x, f"{m}_c3r", f"{m}_3x3r", 1, c3r, 0)
+    s += conv(x, f"{m}_c3r", f"{m}_3x3r", 1, cin, c3r, 0)
     s += f"layer[+1:{m}_b2r] = relu\n"
-    s += conv(f"{m}_b2r", f"{m}_c3", f"{m}_3x3", 3, c3, 1)
+    s += conv(f"{m}_b2r", f"{m}_c3", f"{m}_3x3", 3, c3r, c3, 1)
     s += f"layer[+1:{m}_b2] = relu\n"
-    s += conv(x, f"{m}_c5r", f"{m}_5x5r", 1, c5r, 0)
+    s += conv(x, f"{m}_c5r", f"{m}_5x5r", 1, cin, c5r, 0)
     s += f"layer[+1:{m}_b3r] = relu\n"
-    s += conv(f"{m}_b3r", f"{m}_c5", f"{m}_5x5", 5, c5, 2)
+    s += conv(f"{m}_b3r", f"{m}_c5", f"{m}_5x5", 5, c5r, c5, 2)
     s += f"layer[+1:{m}_b3] = relu\n"
     s += (
         f"layer[{x}->{m}_p] = max_pooling\n"
         "  kernel_size = 3\n  stride = 1\n  pad = 1\n"
     )
-    s += conv(f"{m}_p", f"{m}_pp", f"{m}_pool_proj", 1, cp, 0)
+    s += conv(f"{m}_p", f"{m}_pp", f"{m}_pool_proj", 1, cin, cp, 0)
     s += f"layer[+1:{m}_b4] = relu\n"
     s += f"layer[{m}_b1,{m}_b2,{m}_b3,{m}_b4->{m}] = ch_concat\n"
     return s
+
+
+# (module, c1, c3r, c3, c5r, c5, pool_proj) — Szegedy et al. 2014,
+# table 1; a None entry is the stride-2 max pool between stages
+_GOOGLENET_MODULES = (
+    ("i3a", 64, 96, 128, 16, 32, 32),
+    ("i3b", 128, 128, 192, 32, 96, 64),
+    None,
+    ("i4a", 192, 96, 208, 16, 48, 64),
+    ("i4b", 160, 112, 224, 24, 64, 64),
+    ("i4c", 128, 128, 256, 24, 64, 64),
+    ("i4d", 112, 144, 288, 32, 64, 64),
+    ("i4e", 256, 160, 320, 32, 128, 128),
+    None,
+    ("i5a", 256, 160, 320, 32, 128, 128),
+    ("i5b", 384, 192, 384, 48, 128, 128),
+)
 
 
 def googlenet_conf(
@@ -255,31 +288,33 @@ def googlenet_conf(
         "netconfig = start\n"
         "layer[0->c1] = conv:conv1\n"
         "  kernel_size = 7\n  stride = 2\n  pad = 3\n  nchannel = 64\n"
-        "  random_type = kaiming\n"
+        + _he_init(3, 7) +
         "layer[+1:c1r] = relu\n"
         "layer[c1r->p1] = max_pooling\n  kernel_size = 3\n  stride = 2\n"
         "layer[p1->n1] = lrn\n" + lrn +
         "layer[n1->c2r] = conv:conv2_reduce\n"
-        "  kernel_size = 1\n  nchannel = 64\n  random_type = kaiming\n"
+        "  kernel_size = 1\n  nchannel = 64\n" + _he_init(64, 1) +
         "layer[+1:c2rr] = relu\n"
         "layer[c2rr->c2] = conv:conv2\n"
         "  kernel_size = 3\n  pad = 1\n  nchannel = 192\n"
-        "  random_type = kaiming\n"
+        + _he_init(64, 3) +
         "layer[+1:c2a] = relu\n"
         "layer[c2a->n2] = lrn\n" + lrn +
         "layer[n2->p2] = max_pooling\n  kernel_size = 3\n  stride = 2\n"
-        + _inception("p2", "i3a", 64, 96, 128, 16, 32, 32)
-        + _inception("i3a", "i3b", 128, 128, 192, 32, 96, 64)
-        + "layer[i3b->p3] = max_pooling\n  kernel_size = 3\n  stride = 2\n"
-        + _inception("p3", "i4a", 192, 96, 208, 16, 48, 64)
-        + _inception("i4a", "i4b", 160, 112, 224, 24, 64, 64)
-        + _inception("i4b", "i4c", 128, 128, 256, 24, 64, 64)
-        + _inception("i4c", "i4d", 112, 144, 288, 32, 64, 64)
-        + _inception("i4d", "i4e", 256, 160, 320, 32, 128, 128)
-        + "layer[i4e->p4] = max_pooling\n  kernel_size = 3\n  stride = 2\n"
-        + _inception("p4", "i5a", 256, 160, 320, 32, 128, 128)
-        + _inception("i5a", "i5b", 384, 192, 384, 48, 128, 128)
-        + f"layer[i5b->pool5] = avg_pooling\n"
+    )
+    prev, cin, npool = "p2", 192, 2
+    for mod in _GOOGLENET_MODULES:
+        if mod is None:
+            npool += 1
+            net += (f"layer[{prev}->p{npool}] = max_pooling\n"
+                    "  kernel_size = 3\n  stride = 2\n")
+            prev = f"p{npool}"
+            continue
+        name, c1, c3r, c3, c5r, c5, cp = mod
+        net += _inception(prev, name, cin, c1, c3r, c3, c5r, c5, cp)
+        prev, cin = name, c1 + c3 + c5 + cp
+    net += (
+        f"layer[{prev}->pool5] = avg_pooling\n"
         f"  kernel_size = {max(1, input_size // 32)}\n  stride = 1\n"
         "layer[pool5->pool5] = dropout\n  threshold = 0.4\n"
         "layer[pool5->flat] = flatten\n"

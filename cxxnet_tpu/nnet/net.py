@@ -794,33 +794,25 @@ class FunctionalNet:
             return bool(self.conv_branch_embed)
         if train:
             return False
-        if backend is None:
-            backend = self.exec_backend
-        if backend is None:
-            try:
-                backend = jax.default_backend()
-            except Exception:  # noqa: BLE001 - no backend: stay plain
-                return False
-        return backend != "cpu"
+        return (backend or self._backend()) != "cpu"
+
+    def _backend(self) -> str:
+        """The platform this net's programs run on: what the trainer
+        bound from its mesh, else (a net driven without a trainer) the
+        process default.  Never guessed — a wrong answer here would
+        interpret a Pallas kernel on a chip or compile one on a CPU."""
+        return self.exec_backend or jax.default_backend()
 
     def bound_kernels(self, backend: Optional[str] = None):
         """The kernel library's selector bound to this net's execution
         backend (``ops/kernels/``): what the forward dispatch sites
-        consume.  Resolution mirrors ``use_branch_embed`` — the bound
-        ``exec_backend`` wins, then the process default; ``backend``
-        overrides both (tests)."""
+        consume.  Resolution mirrors ``use_branch_embed``; ``backend``
+        overrides it (tests)."""
         from ..ops import kernels as _klib
 
         if self._kernel_sel is None:
             self._kernel_sel = _klib.KernelSelector(self.kernel_lib)
-        if backend is None:
-            backend = self.exec_backend
-        if backend is None:
-            try:
-                backend = jax.default_backend()
-            except Exception:  # noqa: BLE001 - no backend: treat as cpu
-                backend = "cpu"
-        return self._kernel_sel.bind(backend)
+        return self._kernel_sel.bind(backend or self._backend())
 
     def _apply_quant_layer(self, lay, lparams, inputs, kernels=None):
         """Dispatch one int8-quantized layer (doc/performance.md

@@ -347,15 +347,14 @@ class NetTrainer:
         self.mesh_plan = make_mesh(self.dev, self.model_parallel)
         if self.batch_size:
             self.mesh_plan.check_batch(self.batch_size)
+        if not self.silent:
+            print(f"devices: {self.mesh_plan.describe_devices()}", flush=True)
         if self.net is not None:
             # bind the platform the programs will actually run on (NOT
             # the process default backend — dev=cpu on a TPU host must
-            # read as cpu): auto branch-embed keys on it
-            try:
-                devs = self.mesh_plan.mesh.devices.reshape(-1)
-                self.net.exec_backend = str(devs[0].platform)
-            except Exception:  # noqa: BLE001 - fall back to the probe
-                pass
+            # read as cpu): auto branch-embed and the kernel library's
+            # interpret switch key on it
+            self.net.exec_backend = self.mesh_plan.platform
 
     def _sh(self):
         """(replicated, data-sharded, per-extra) shardings for the mesh."""
@@ -411,12 +410,19 @@ class NetTrainer:
             self.params = jax.device_put(self.params, psh)
             if self.ustates:
                 self.ustates = jax.device_put(self.ustates, ush)
+            rep = self.mesh_plan.replicated()
             if self.aux:
-                rep = self.mesh_plan.replicated()
                 self.aux = jax.device_put(
                     self.aux,
                     jax.tree_util.tree_map(lambda _: rep, self.aux),
                 )
+            # the rng key too: the scanned step hands its carried key
+            # back mesh-replicated, and jax's tracing cache keys on the
+            # mesh an argument lives on — a key that starts on one
+            # device makes the SECOND scan call retrace and recompile
+            # the whole program (30.7 s for GoogLeNet on four v5e
+            # chips, CHANGES.md PR 21)
+            self._rng_key = jax.device_put(self._rng_key, rep)
         self._export_state_bytes()
 
     def state_shard_bytes(self):
@@ -674,7 +680,6 @@ class NetTrainer:
         plan = self.mesh_plan
         n = plan.n_data
         per_shard_grad = self._shard_grad_fn()
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def per_shard(params, data, labels, mask, rng, epoch):
@@ -691,11 +696,11 @@ class NetTrainer:
             grads = jax.tree_util.tree_map(fold, g)
             return grads, fold(loss), out
 
-        return shard_map(
+        return jax.shard_map(
             per_shard, mesh=plan.mesh,
             in_specs=(P(), P("data"), P("data"), P("data"), P(), P()),
             out_specs=(P(), P(), P("data")),
-            check_rep=False,
+            check_vma=False,
         )
 
     def _loss_and_out(self, params, aux, data, labels, mask, rng, epoch,
@@ -787,14 +792,12 @@ class NetTrainer:
                       with_out: bool):
         """K fused train steps as ONE device program (``lax.scan``).
 
-        TPU-first: host dispatch cost is per-*program*, not per-step —
-        on a tunneled/remote runtime each execute RPC costs ~100ms+, so
-        per-batch dispatch (the reference's ``Update(batch)`` loop,
-        ``cxxnet_main.cpp:170-185``) caps throughput regardless of how
-        fast the chip is.  Scanning the fused step K times on device
-        amortizes dispatch to nothing while keeping identical per-step
-        semantics: same updater math, same epoch advance per step, a
-        fresh folded RNG per step.
+        TPU-first: host dispatch cost is per-*program*, not per-step,
+        so per-batch dispatch (the reference's ``Update(batch)`` loop,
+        ``cxxnet_main.cpp:170-185``) pays it K times where one scanned
+        program pays it once.  Scanning the fused step K times on
+        device keeps identical per-step semantics: same updater math,
+        same epoch advance per step, a fresh folded RNG per step.
 
         ``per_step_data=False`` closes over ONE staged batch reused every
         step (synthetic/benchmark mode); otherwise ``xs`` is the
@@ -1896,11 +1899,6 @@ class NetTrainer:
                 self.params, self.aux, data, labels, mask,
                 self._next_rng(), step, extras,
             )
-            self.train_metric.add_eval(
-                self._train_metric_preds(out, n_real, node_cache),
-                np.asarray(batch.label)[:n_real],
-                self._label_ranges(),
-            )
         else:
             (loss, self.aux), grads = self._grad_fn()(
                 self.params, self.aux, data, labels, mask,
@@ -1909,7 +1907,15 @@ class NetTrainer:
         if self.divergence_policy:
             # accumulation path: catch the blow-up per micro-batch,
             # BEFORE the bad gradient is folded into the accumulator
+            # (and, as on the fused path, before the train metric sees
+            # the NaN prediction — logloss refuses one on its own)
             self._guard_loss(loss, self.epoch_counter)
+        if self.eval_train:
+            self.train_metric.add_eval(
+                self._train_metric_preds(out, n_real, node_cache),
+                np.asarray(batch.label)[:n_real],
+                self._label_ranges(),
+            )
         if self._grad_accum is None:
             self._grad_accum = grads
         else:

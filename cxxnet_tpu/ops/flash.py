@@ -38,7 +38,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ._compat import pallas_tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -284,7 +283,7 @@ def _flash_fwd_raw(q, k, v, causal, bq, bk, interpret,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             **_dims(("parallel", "parallel", "arbitrary"))
         ),
         interpret=interpret,
@@ -318,7 +317,7 @@ def _flash_bwd_raw(q, k, v, do, lse, delta, causal, bq, bk, interpret,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             **_dims(("parallel", "parallel", "arbitrary"))
         ),
         interpret=interpret,
@@ -346,7 +345,7 @@ def _flash_bwd_raw(q, k, v, do, lse, delta, causal, bq, bk, interpret,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             **_dims(("parallel", "parallel", "arbitrary"))
         ),
         interpret=interpret,

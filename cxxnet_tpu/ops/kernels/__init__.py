@@ -29,9 +29,9 @@ a capable kernel actually RUNS is the selector's call:
   follows its measured CPU reject: a kernel runs only where a committed
   ``promote`` verdict from the bisect A/B (``tools/kernel_ab.py``)
   says it pays.  CPU rejects are recorded (Pallas on CPU is emulation);
-  TPU verdicts stay queued in ``tools/tpu_queue.sh`` — until a window
-  drains the queue and commits a promote, ``auto`` means stock, so
-  adopting a kernel is always a measured decision, never faith.
+  no TPU verdict is recorded yet (ROADMAP S9) — until a chip A/B
+  commits a promote, ``auto`` means stock, so adopting a kernel is
+  always a measured decision, never faith.
 """
 
 from __future__ import annotations
@@ -170,7 +170,6 @@ class KernelSelector:
     def active(self, name: str, backend: str) -> bool:
         if name not in KERNELS:
             raise ValueError(f"unknown kernel {name!r}")
-        backend = backend or "cpu"
         if self.mode == "off":
             return False
         if self.mode == "auto":
@@ -186,8 +185,13 @@ class KernelSelector:
         names = [n for n in sorted(KERNELS) if self.active(n, backend)]
         return "+".join(names)
 
-    def bind(self, backend: Optional[str]) -> "BoundKernels":
-        return BoundKernels(self, backend or "cpu")
+    def bind(self, backend: str) -> "BoundKernels":
+        if not backend:
+            # no backend must never read as "cpu": that would run a
+            # kernel under the Pallas interpreter on a chip
+            raise ValueError("KernelSelector.bind needs the backend the "
+                             "programs run on")
+        return BoundKernels(self, backend)
 
 
 class BoundKernels:

@@ -25,8 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .._compat import pallas_tpu_compiler_params
-from .conv_block import _pick_block
+from .conv_block import col_tile, row_tiles
 
 
 def _int8_kernel(x_ref, q_ref, s_ref, b_ref, o_ref, *, relu, has_bias):
@@ -54,9 +53,9 @@ def int8_gemm_rescale(x2d, q, scale, bias=None, *, relu: bool = False,
     ``x2d`` is ``(M, K)`` f32/bf16, ``q`` ``(O, K)`` int8 (the fullc
     layout — the int8 array itself is the program operand; weights at
     rest stay 1 byte/element), ``scale`` ``(O,)`` f32, ``bias`` ``(O,)``
-    or None.  ``bm``/``bn`` tile M/O (0 = whole axis, the bit-parity
-    default); K stays whole so each output element is one full-K
-    contraction in f32.
+    or None.  ``bm``/``bn`` pin the M/O tiles (tests); 0 tiles from
+    the shapes (``conv_block.row_tiles`` / ``col_tile``).  K stays
+    whole so each output element is one full-K contraction in f32.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -69,17 +68,19 @@ def int8_gemm_rescale(x2d, q, scale, bias=None, *, relu: bool = False,
     s2 = scale.reshape(1, o)
     b2 = (bias.reshape(1, o) if has_bias
           else jnp.zeros((1, 1), jnp.float32))
-    bm = _pick_block(m, bm) if bm else m
-    bn = _pick_block(o, bn) if bn else o
+    bm, mp = row_tiles(m, bm)
+    bn = col_tile(o, bn)
+    if mp > m:
+        x2d = jnp.pad(x2d, ((0, mp - m), (0, 0)))
     kern = functools.partial(_int8_kernel, relu=relu, has_bias=has_bias)
     row = lambda i, j: (0, j)  # noqa: E731 - (1, bn) per-channel rows
     bspec = (pl.BlockSpec((1, bn), row, memory_space=pltpu.VMEM)
              if has_bias
              else pl.BlockSpec((1, 1), lambda i, j: (0, 0),
                                memory_space=pltpu.VMEM))
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kern,
-        grid=(m // bm, o // bn),
+        grid=(mp // bm, o // bn),
         in_specs=[
             pl.BlockSpec((bm, k), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),
@@ -90,11 +91,12 @@ def int8_gemm_rescale(x2d, q, scale, bias=None, *, relu: bool = False,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m, o), x2d.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((mp, o), x2d.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x2d, q, s2, b2)
+    return y[:m] if mp > m else y
 
 
 def probe(backend: str, x=None, q=None, **_kw):

@@ -29,8 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .._compat import pallas_tpu_compiler_params
-from .conv_block import _pick_block
+from .conv_block import row_tiles
 
 _LANES = 128
 
@@ -57,15 +56,17 @@ def sgd_update(w, g, m, lr, mom, *, wd: float = 0.0, clip: float = 0.0,
     Returns ``(new_w, new_m)`` with ``w``'s shape/dtype.  ``lr``/``mom``
     are (traced) scalars already cast to ``w.dtype`` (the stock rule's
     spelling); ``wd``/``clip`` are static floats.  The tensor is
-    flattened and padded to a ``(rows, 128)`` lane layout; ``br`` tiles
-    the rows (0 = whole tensor in one block).
+    flattened and padded to a ``(rows, 128)`` lane layout; ``br`` pins
+    the row tile (tests), 0 tiles from the size
+    (``conv_block.row_tiles``) — a whole 1000x1024 classifier weight is
+    five 4 MB operands, past the scoped VMEM limit in one block.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     shape = w.shape
     n = int(w.size)
-    rows = max(1, -(-n // _LANES))
+    br, rows = row_tiles(max(1, -(-n // _LANES)), br)
     total = rows * _LANES
 
     def lanes(a):
@@ -74,7 +75,6 @@ def sgd_update(w, g, m, lr, mom, *, wd: float = 0.0, clip: float = 0.0,
             f = jnp.pad(f, (0, total - n))
         return f.reshape(rows, _LANES)
 
-    br = _pick_block(rows, br) if br else rows
     sc = lambda v: jnp.asarray(v, w.dtype).reshape(1, 1)  # noqa: E731
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0),
@@ -86,7 +86,7 @@ def sgd_update(w, g, m, lr, mom, *, wd: float = 0.0, clip: float = 0.0,
         in_specs=[smem, smem, vspec, vspec, vspec],
         out_specs=[vspec, vspec],
         out_shape=[out, out],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(sc(lr), sc(mom), lanes(w), lanes(g), lanes(m))

@@ -94,8 +94,6 @@ def pipeline_apply(
     """
     from jax.sharding import PartitionSpec as P
 
-    from ._compat import shard_map_nocheck
-
     b = x.shape[0]
     if b % n_microbatch != 0:
         raise ValueError(
@@ -120,8 +118,9 @@ def pipeline_apply(
     pspec = jax.tree_util.tree_map(
         lambda v: P(stage_axis, *([None] * (v.ndim - 1))), params_stacked
     )
-    out = shard_map_nocheck(
+    out = jax.shard_map(
         functools.partial(gpipe, block_fn, axis_name=stage_axis),
-        mesh, (pspec, row_spec), row_spec,
+        mesh=mesh, in_specs=(pspec, row_spec), out_specs=row_spec,
+        check_vma=False,  # ppermute under scan confuses the checker
     )(params_stacked, x_mb)
     return out.reshape((b,) + out.shape[2:])

@@ -105,7 +105,6 @@ class AsyncStepper:
         # closure — the det_reduce step traces the identical one, which
         # is what keeps the staleness=0 bitwise-parity contract honest
         per_shard_grad = tr._shard_grad_fn()
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def per_shard(params, data, labels, mask, rng, epoch):
@@ -114,11 +113,11 @@ class AsyncStepper:
             gstack = jax.tree_util.tree_map(lambda x: x[None], g)
             return gstack, loss[None], out
 
-        sm = shard_map(
+        sm = jax.shard_map(
             per_shard, mesh=plan.mesh,
             in_specs=(P(), P("data"), P("data"), P("data"), P(), P()),
             out_specs=(P("data"), P("data"), P("data")),
-            check_rep=False,
+            check_vma=False,
         )
         rep, dsh, _ = tr._sh()
         psh, _ = tr._param_sh()
@@ -139,7 +138,6 @@ class AsyncStepper:
         tr = self.trainer
         plan = tr.mesh_plan
         n = plan.n_data
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def per_shard(gsub):
@@ -152,10 +150,10 @@ class AsyncStepper:
 
             return jax.tree_util.tree_map(fold, gsub)
 
-        sm = shard_map(
+        sm = jax.shard_map(
             per_shard, mesh=plan.mesh,
             in_specs=(P("data"),), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         rep, dsh, _ = tr._sh()
         # no donation: the sharded partial stack cannot alias the

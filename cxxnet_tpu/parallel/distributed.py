@@ -244,10 +244,7 @@ def shutdown_distributed(timeout_s: float = 10.0,
                          error=(None if t.is_alive()
                                 else str(box.get(name))),
                          timed_out=t.is_alive())
-    try:
-        jax.clear_caches()
-    except Exception:  # noqa: BLE001 - older jax spellings
-        pass
+    jax.clear_caches()
     from jax._src import api as _api
 
     _api.clear_backends()
@@ -270,12 +267,9 @@ def _enable_cpu_collectives() -> None:
     2-device 2x2 data x model mesh).  Selecting the gloo TCP
     implementation here makes the CPU backend a faithful miniature of
     the TPU pod: one jit program, partitions on every process, XLA
-    collectives across them.  No-op when the jax build lacks the flag or
-    another platform is primary (TPU/GPU ignore it)."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 - older jax: flag absent; keep going
-        pass
+    collectives across them.  No-op when another platform is primary
+    (TPU/GPU ignore it)."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def process_info() -> Tuple[int, int]:

@@ -11,7 +11,10 @@ The platform word is advisory: confs written for the reference say
 ``gpu``; on a TPU host the same conf runs on TPU chips, and under the
 CPU test harness on virtual CPU devices.  What is honored exactly is the
 device *count and ordinals* — ``batch_size`` must divide by the data-axis
-size, as in the reference (``nnet_impl-inl.hpp:146-151``).
+size, as in the reference (``nnet_impl-inl.hpp:146-151``).  Advisory is
+not silent: ``MeshPlan.describe_devices`` names the platform, device
+kind and ordinals actually bound (and the platform that was asked for,
+when it differs), and the trainer prints it every time it builds a mesh.
 
 The mesh is always 2-D ``('data', 'model')``; ``model=1`` gives pure data
 parallelism (the reference's only strategy).  ``model_parallel=k`` in the
@@ -56,10 +59,30 @@ class MeshPlan:
     mesh: Mesh
     n_data: int
     n_model: int
+    # the platform word of the dev= string ("" when the caller handed
+    # make_mesh its devices directly)
+    requested: str = ""
 
     @property
     def n_devices(self) -> int:
         return self.n_data * self.n_model
+
+    @property
+    def platform(self) -> str:
+        """The platform the mesh's programs actually run on."""
+        return str(self.mesh.devices.flat[0].platform)
+
+    def describe_devices(self) -> str:
+        """One line of device truth: platform, device kind and ordinals
+        bound — plus the platform ``dev=`` asked for when this process
+        does not have it (the advisory fall-through, never silent)."""
+        devs = list(self.mesh.devices.flat)
+        line = (f"{self.platform} ({devs[0].device_kind}) "
+                f"ordinals {[int(d.id) for d in devs]}")
+        if self.requested and self.requested != self.platform:
+            line += (f" — dev asked for {self.requested!r}, which this "
+                     "process does not have")
+        return line
 
     def replicated(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())
@@ -168,6 +191,7 @@ def make_mesh(
     ``gpu`` run unchanged on TPU).
     """
     plat, ids = parse_device(dev)
+    requested = "" if devices is not None else plat
     if devices is None:
         try:
             pool = jax.devices(plat)
@@ -196,4 +220,5 @@ def make_mesh(
     n_model = model_parallel
     n_data = n // n_model
     arr = np.asarray(devices, dtype=object).reshape(n_data, n_model)
-    return MeshPlan(mesh=Mesh(arr, ("data", "model")), n_data=n_data, n_model=n_model)
+    return MeshPlan(mesh=Mesh(arr, ("data", "model")), n_data=n_data,
+                    n_model=n_model, requested=requested)

@@ -1,28 +1,43 @@
-"""Persistent XLA compilation cache (``compile_cache_dir``).
+"""Persistent XLA compilation cache: the one place that decides where.
 
 Every jitted program in this framework — the fused train step, the
 ``update_scan`` body, eval/predict programs, the serving engine's
-shape-bucket cache entries — is re-compiled from scratch on process
-start.  On the v5e AOT runtime a GoogLeNet scan step alone costs ~47 s
-of XLA time (doc/performance.md), so a restart, a preemption resume, or
-a serve reload stalls exactly that long before the first step runs.
+shape-bucket cache entries — is compiled from scratch on process start;
+the GoogLeNet scan step alone is minutes of XLA time on a chip.  JAX's
+persistent cache keys executables by (HLO, compile options, backend)
+and reloads them on later runs, so warm restarts skip XLA entirely.
 
-Setting ``compile_cache_dir = <dir>`` (global config key, any task)
-points JAX's persistent compilation cache at an on-disk directory:
-compiled executables are keyed by (HLO, compile options, backend) and
-reloaded on later runs, so warm restarts skip XLA entirely.  The
-thresholds are dropped to zero — this framework's programs are few and
-large, so caching everything is strictly better than re-jitting.
+Every entry point (the CLI, ``serve.Engine``, ``bench.py``,
+``chip_smoke.py``, the ``tools/`` drivers) calls :func:`enable` before
+its first jit.  The directory is resolved in this order:
 
-The cache directory is shared safely between concurrent processes
-(JAX writes entries atomically), and a stale entry is just a miss:
-an XLA/jaxlib upgrade changes the cache key, never loads wrong code.
+1. ``JAX_COMPILATION_CACHE_DIR`` in the environment — JAX reads it
+   itself, so nothing here touches the directory setting: whoever
+   launches the process (a scheduler that mounts a shared cache) owns
+   the placement, and a conf key cannot override it;
+2. the ``compile_cache_dir`` conf key;
+3. ``<checkout>/.jax_cache`` — a fixed path, because the path is part
+   of what makes a later run find the entries again.
+
+The thresholds are dropped to zero: this framework's programs are few
+and large, so caching everything is strictly better than re-jitting.
+The directory is shared safely between concurrent processes (JAX
+writes entries atomically), and a stale entry is just a miss.
+``JAX_ENABLE_COMPILATION_CACHE=false`` (JAX's own switch) turns the
+cache off whatever the directory — the test suite runs that way.
 """
 
 from __future__ import annotations
 
 import os
 from typing import Optional, Sequence, Tuple
+
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 _enabled_dir: Optional[str] = None
 
@@ -32,79 +47,46 @@ def enabled_dir() -> Optional[str]:
     return _enabled_dir
 
 
-def enable(path: str, silent: bool = True) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` (created if
-    missing).  Idempotent; returns True when newly enabled.  Must run
-    before the programs it should serve are compiled — config parsing
-    order guarantees that for the CLI and the serving engine."""
+def resolve(conf_dir: str = "") -> str:
+    """The cache directory for this process (see the module docstring
+    for the precedence)."""
+    path = os.environ.get(ENV_DIR) or conf_dir or DEFAULT_DIR
+    return os.path.abspath(os.path.expanduser(path))
+
+
+def enable(conf_dir: str = "", silent: bool = True) -> str:
+    """Turn the persistent cache on at :func:`resolve`'s directory
+    (created if missing) and return it.  Idempotent.  Call before the
+    programs it should serve are compiled."""
     global _enabled_dir
-    if not path:
-        return False
-    path = os.path.abspath(os.path.expanduser(path))
+    path = resolve(conf_dir)
     if _enabled_dir == path:
-        return False
+        return path
     os.makedirs(path, exist_ok=True)
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-    # KNOWN SHARP EDGE (jaxlib 0.4.3x, root-caused in PR 8): enabling
-    # the cache MID-PROCESS — after donated-buffer programs (the fused
-    # train step) have already compiled — intermittently corrupts
-    # subsequent re-jitted programs: silent numeric garbage or a glibc
-    # SIGSEGV/Abort inside batched_device_put.  This was tier-1's
-    # multi-file flake (a test enabled the cache mid-suite; every
-    # later trainer rebuild re-jitted through it).  The CLI and the
-    # serving engine enable the cache BEFORE any jit (config order
-    # guarantees it), which is verified safe; anything else gets a
-    # loud warning instead of a latent heisenbug.
-    try:
-        from jax._src import xla_bridge as _xb
-
-        mid_process = bool(getattr(_xb, "_backends", None))
-    except Exception:  # pragma: no cover - jax internals moved
-        mid_process = False
-    if mid_process:
-        from ..obs import events as obs_events
-
-        obs_events.emit("compile_cache.mid_process_enable", dir=path)
-        print(
-            "WARNING: compile_cache enabled after a JAX backend was "
-            "already initialized; on jaxlib 0.4.3x re-jitting donated "
-            "programs through a mid-process-enabled cache can corrupt "
-            "results or crash — enable compile_cache_dir before the "
-            "first jit (the CLI/serve engine order)", flush=True,
-        )
-    jax.config.update("jax_compilation_cache_dir", path)
-    for knob, val in (
-        # cache every program no matter how small/fast to compile —
-        # the program count here is tiny and restart latency is the
-        # thing being bought
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-    ):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # pragma: no cover - older jax: defaults apply
-            pass
-    try:
-        # jax initializes the cache backend lazily ONCE; if anything
-        # compiled before this point (cache disabled then), the dir
-        # update alone would never take effect in this process
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - internal API moved
-        pass
+    if not os.environ.get(ENV_DIR):
+        jax.config.update("jax_compilation_cache_dir", path)
+    # cache every program no matter how small or fast to compile — the
+    # program count here is tiny and restart latency is what is bought
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # jax decides ONCE, at its first compile, whether a cache is in
+    # use; a library caller that already compiled something would
+    # otherwise never see the directory take effect
+    cc.reset_cache()
     _enabled_dir = path
     if not silent:
         print(f"compile cache: persistent XLA cache at {path}", flush=True)
-    return True
+    return path
 
 
-def configure(cfg: Sequence[Tuple[str, str]], silent: bool = True) -> bool:
-    """Scan an ordered config stream for ``compile_cache_dir`` (last
-    one wins) and enable it.  No-op without the key."""
-    path = ""
+def configure(cfg: Sequence[Tuple[str, str]], silent: bool = True) -> str:
+    """:func:`enable` with the ``compile_cache_dir`` key of an ordered
+    config stream (last one wins) as the conf-level directory."""
+    conf_dir = ""
     for name, val in cfg or ():
         if name == "compile_cache_dir":
-            path = val
-    return enable(path, silent=silent) if path else False
+            conf_dir = val
+    return enable(conf_dir, silent=silent)

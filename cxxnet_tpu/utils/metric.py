@@ -84,10 +84,13 @@ class MetricLogloss(Metric):
         if pred.shape[1] != 1:
             tgt = label[:, 0].astype(np.int64)
             p = np.clip(pred[np.arange(len(tgt)), tgt], eps, 1 - eps)
-            return -np.sum(np.log(p))
-        p = np.clip(pred[:, 0], eps, 1 - eps)
-        y = label[:, 0]
-        res = -(y * np.log(p) + (1 - y) * np.log(1 - p))
+            res = -np.log(p)
+        else:
+            p = np.clip(pred[:, 0], eps, 1 - eps)
+            y = label[:, 0]
+            res = -(y * np.log(p) + (1 - y) * np.log(1 - p))
+        # np.clip passes NaN through: a diverged net must stop the run,
+        # not print "logloss:nan" round after round
         if np.isnan(res).any():
             raise FloatingPointError("logloss: NaN detected!")
         return np.sum(res)

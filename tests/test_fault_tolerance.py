@@ -289,9 +289,11 @@ def test_divergence_guard_in_process():
         tr.update(DataBatch(data=x, label=y))
     assert ei.value.epoch == 0
 
-    # guard disabled (default): no raise — reference behavior preserved
+    # guard disabled (default): the TRAINER does not raise — reference
+    # behavior preserved (eval_train off: the logloss metric, like the
+    # reference's, refuses a NaN prediction on its own)
     tr2 = NetTrainer()
-    tr2.set_params(C.parse_pairs(MLP_CFG))
+    tr2.set_params(C.parse_pairs(MLP_CFG + "eval_train = 0\n"))
     tr2.init_model()
     tr2.update(DataBatch(data=x, label=y))
 

@@ -366,14 +366,10 @@ def test_pool_watchdog_and_close_are_clean(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# persistent compile cache.  BOTH tests run in a SUBPROCESS: enabling
+# persistent compile cache.  Both tests run in a SUBPROCESS: enabling
 # jax's persistent compilation cache is process-global and permanent,
-# and enabling it MID-PROCESS — after donated-buffer programs already
-# compiled — intermittently corrupts later re-jitted programs on
-# jaxlib 0.4.3x (silent numeric garbage or a SIGSEGV in
-# batched_device_put).  Running these in-process was the root cause of
-# tier-1's multi-file loop-gate flake (PR 8 bisect; see
-# utils/compile_cache.py for the production-order guarantee).
+# and the suite itself runs with it switched off (tests/conftest.py).
+# The resolver's precedence is pinned in tests/test_chip_contracts.py.
 def _run_py(script, cwd):
     import subprocess
     import sys
@@ -382,6 +378,8 @@ def _run_py(script, cwd):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = repo
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # the conf key must place it
     return subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         cwd=str(cwd), env=env, timeout=240,
@@ -426,12 +424,12 @@ def test_compile_cache_configure_scans_cfg(tmp_path):
 from cxxnet_tpu.utils import compile_cache
 
 d = {str(tmp_path / "cc")!r}
-assert compile_cache.configure([("foo", "1"), ("compile_cache_dir", d)])
+assert compile_cache.configure([("foo", "1"), ("compile_cache_dir", d)]) == d
 assert compile_cache.enabled_dir() == d
 import os
 assert os.path.isdir(d)
 # idempotent
-assert not compile_cache.configure([("compile_cache_dir", d)])
+assert compile_cache.configure([("compile_cache_dir", d)]) == d
 print("CONFIGURE_OK")
 """, tmp_path)
     assert r.returncode == 0, r.stderr[-2000:]

@@ -1,13 +1,21 @@
 """On-chip kernel library (``cxxnet_tpu/ops/kernels/``): interpret-mode
 parity, selector/verdict discipline, and end-to-end dispatch.
 
-The parity contract everything here pins: each Pallas kernel, run under
-``interpret=True`` on CPU, is BIT-EQUAL (``np.array_equal``) to the
-JITTED stock lowering it replaces.  The jitted reference is the honest
-one — the net's real programs are always compiled, and on CPU the eager
-op-by-op spelling differs from its own compiled form (FMA fusion), so
-"parity with the stock lowering" means the lowering, not the eager
-replay.
+The parity contract pinned here: each Pallas kernel, run under
+``interpret=True`` on CPU, matches the JITTED stock lowering it
+replaces.  The jitted reference is the honest one — the net's real
+programs are always compiled, and on CPU the eager op-by-op spelling
+differs from its own compiled form (FMA fusion), so "parity with the
+stock lowering" means the lowering, not the eager replay.
+
+``int8_gemm`` and ``zero_update`` replay the stock op chain and are
+held BIT-EQUAL (``np.array_equal``).  ``conv_block`` replaces a
+convolution by a GEMM, and two facts set its tolerance
+(``_assert_conv_close``): XLA:CPU's conv and dot emitters order their
+FMAs differently from host to host, so f32 agrees to a few ulps, not
+bit for bit; and the kernel keeps an f32 accumulator through the bias
+add (Mosaic refuses a bf16 one), rounding a bf16 tile once where the
+stock conv-then-add rounds twice — one bf16 ulp.
 """
 
 import json
@@ -47,36 +55,58 @@ def _conv_case(dtype=np.float32, b=4, hw=6, cin=8, cout=16, seed=0):
     return x, wk, bias
 
 
+def _assert_conv_close(ref, got):
+    """conv_block vs the stock conv, at the tolerance the module
+    docstring derives from the dtype: a few f32 ulps of the O(1)
+    operands, or one bf16 ulp (2^-7 relative)."""
+    assert got.dtype == ref.dtype
+    tol = 2.0 ** -7 if ref.dtype == jnp.bfloat16 else 1e-6
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref, np.float32),
+        rtol=tol, atol=tol)
+
+
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
-def test_conv_block_bit_parity(dtype):
+def test_conv_block_parity(dtype):
     x, wk, bias = _conv_case(dtype)
     ref = jax.jit(_conv_ref)(x, wk, bias)
-    got = conv_block.conv1x1_block(x, wk, bias, interpret=True)
-    assert got.dtype == ref.dtype
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+    _assert_conv_close(
+        ref, conv_block.conv1x1_block(x, wk, bias, interpret=True))
 
 
 def test_conv_block_blocked_and_stride_and_relu():
     x, wk, bias = _conv_case(b=4, hw=8, cin=8, cout=16)
     # explicit bm/bn tiling (the MXU shape) keeps the full-K contraction
     got = conv_block.conv1x1_block(x, wk, bias, interpret=True, bm=8, bn=8)
-    ref = jax.jit(_conv_ref)(x, wk, bias)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+    _assert_conv_close(jax.jit(_conv_ref)(x, wk, bias), got)
     # stride via host-side subsampling (exact for 1x1/pad-0)
     ref2 = jax.jit(lambda *a: _conv_ref(*a, stride=2))(x, wk, bias)
-    got2 = conv_block.conv1x1_block(x, wk, bias, stride=2, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref2), np.asarray(got2))
+    _assert_conv_close(
+        ref2, conv_block.conv1x1_block(x, wk, bias, stride=2,
+                                       interpret=True))
     # relu folded into the epilogue
     ref3 = jax.jit(lambda *a: _conv_ref(*a, relu=True))(x, wk, bias)
-    got3 = conv_block.conv1x1_block(x, wk, bias, relu=True, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref3), np.asarray(got3))
+    _assert_conv_close(
+        ref3, conv_block.conv1x1_block(x, wk, bias, relu=True,
+                                       interpret=True))
+
+
+def test_conv_block_tiles_rows_past_one_block():
+    """M past ``_ROWS`` that is no multiple of it: the launcher pads to
+    whole row blocks and slices the pad off — the shape of every
+    serving bucket that is not a power of two."""
+    x, wk, bias = _conv_case(b=3, hw=14, cin=8, cout=16)  # M = 588
+    assert conv_block.row_tiles(588) == (512, 1024)
+    assert conv_block.col_tile(2560) == 512 and conv_block.col_tile(176) == 176
+    got = conv_block.conv1x1_block(x, wk, bias, interpret=True)
+    _assert_conv_close(jax.jit(_conv_ref)(x, wk, bias), got)
 
 
 def test_conv_block_no_bias_and_probe():
     x, wk, _ = _conv_case()
     ref = jax.jit(lambda x, w: _conv_ref(x, w, None))(x, wk)
-    got = conv_block.conv1x1_block(x, wk, None, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+    _assert_conv_close(
+        ref, conv_block.conv1x1_block(x, wk, None, interpret=True))
     assert conv_block.probe("cpu", x=x, wk=wk) is None
     assert "1x1" in conv_block.probe(
         "cpu", x=x, wk=jnp.zeros((3, 3, 8, 16), jnp.float32))
@@ -258,7 +288,7 @@ def test_committed_cpu_verdicts_exist_and_auto_honors_them():
         assert ent["parity"] is True  # never committed on wrong math
         assert sel.active(name, "cpu") == (ent["verdict"] == "promote")
         # nothing recorded for tpu yet: auto stays stock on-chip until
-        # tpu_queue.sh drains
+        # a chip A/B commits a promote
         assert not sel.active(name, "tpu")
 
 
@@ -310,8 +340,9 @@ def _sibling_trainer(kernel_lib, cfg=None, seed="7"):
 
 
 def test_net_forward_parity_conv_block():
-    """Scores of the kernel-forced net are bit-equal to the stock net
-    (same seed) — including the strided ResNet boundary pair."""
+    """Scores of the kernel-forced net match the stock net (same seed)
+    to f32 conv-vs-GEMM rounding — including the strided ResNet
+    boundary pair."""
     from tests.test_trainer import RESNET_BOUNDARY_CFG
 
     rng = np.random.RandomState(5)
@@ -321,7 +352,7 @@ def test_net_forward_parity_conv_block():
         t1 = _sibling_trainer("conv_block", cfg)
         s0 = np.asarray(t0.predict_fn(None)(t0.params, t0.aux, x, ()))
         s1 = np.asarray(t1.predict_fn(None)(t1.params, t1.aux, x, ()))
-        np.testing.assert_array_equal(s0, s1)
+        np.testing.assert_allclose(s1, s0, rtol=1e-5, atol=1e-7)
 
 
 def test_net_quant_predict_parity_int8_gemm():

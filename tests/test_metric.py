@@ -49,3 +49,23 @@ def test_rec_at_n_tiebreak_keeps_clear_winners():
     pred = rng.rand(64, 12).astype(np.float32)
     label = np.argmax(pred, axis=1).astype(np.float32)[:, None]
     assert _score("rec@1", pred, label) == 1.0
+
+
+def test_logloss_raises_on_nan_in_both_branches():
+    """A diverged net must stop the run: np.clip passes NaN through, so
+    the multiclass branch used to print ``logloss:nan`` round after
+    round where the binary branch raised."""
+    import pytest
+
+    from cxxnet_tpu.utils.metric import MetricLogloss
+
+    m = MetricLogloss()
+    label = np.array([[1.0], [0.0]], np.float32)
+    ok = np.array([[0.2, 0.8], [0.6, 0.4]], np.float32)
+    assert np.isclose(m._batch_sum(ok, label), -np.log(0.8) - np.log(0.6))
+    bad = ok.copy()
+    bad[1, 0] = np.nan  # the target-class probability of row 1
+    with pytest.raises(FloatingPointError, match="NaN"):
+        m._batch_sum(bad, label)
+    with pytest.raises(FloatingPointError, match="NaN"):
+        m._batch_sum(np.array([[np.nan], [0.5]], np.float32), label)

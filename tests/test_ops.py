@@ -108,16 +108,28 @@ def test_lrn_pallas_bf16(rng):
     )
 
 
-def test_lrn_layer_uses_xla_on_cpu(rng):
-    """lrn_impl=auto falls back to stock XLA off-TPU; pallas forced works."""
+def test_lrn_layer_impl_selection(rng, monkeypatch):
+    """lrn_impl=auto is the stock XLA path; the pallas opt-in really
+    routes through the kernel (interpreted off-TPU) and agrees."""
+    import importlib
+
     from cxxnet_tpu.layers import create_layer
 
+    lrn_mod = importlib.import_module("cxxnet_tpu.ops.lrn")
+    calls = []
+    real = lrn_mod.lrn
+    monkeypatch.setattr(
+        lrn_mod, "lrn", lambda *a: calls.append(a[-1]) or real(*a))
     lay = create_layer("lrn")
     lay.set_param("local_size", "5")
-    assert not lay._use_pallas(64, "float32")
     x = jnp.asarray(rng.randn(2, 4, 4, 16).astype(np.float32))
     (y_xla,) = lay.apply({}, [x])
+    assert calls == []  # auto never picks the kernel
     lay.set_param("lrn_impl", "pallas")
+    (y_pl,) = lay.apply({}, [x])
+    assert calls == [True]  # the kernel, under the interpreter
+    np.testing.assert_allclose(np.asarray(y_pl), np.asarray(y_xla),
+                               rtol=1e-5, atol=1e-6)
     with pytest.raises(Exception):
         lay.set_param("lrn_impl", "bogus")
 
@@ -159,15 +171,26 @@ def test_maxpool_pallas_bf16(rng):
     )
 
 
-def test_pool_layer_uses_xla_on_cpu(rng):
+def test_pool_layer_impl_selection(rng, monkeypatch):
     from cxxnet_tpu.layers import create_layer
 
+    import importlib
+
+    mp_mod = importlib.import_module("cxxnet_tpu.ops.maxpool")
+    calls = []
+    real = mp_mod.maxpool_fused
+    monkeypatch.setattr(
+        mp_mod, "maxpool_fused", lambda *a: calls.append(a[-1]) or real(*a))
     lay = create_layer("max_pooling")
     lay.set_param("kernel_size", "2")
     lay.set_param("stride", "2")
-    assert lay._use_pallas(8, jnp.float32) is False  # auto never picks pallas
+    x = jnp.asarray(rng.randn(2, 6, 6, 8).astype(np.float32))
+    (y_xla,) = lay.apply({}, [x])
+    assert calls == []  # auto never picks pallas
     lay.set_param("pool_impl", "pallas")
-    assert lay._use_pallas(8, jnp.float32) is True
+    (y_pl,) = lay.apply({}, [x])
+    assert calls == [True]  # the kernel, under the interpreter
+    np.testing.assert_array_equal(np.asarray(y_pl), np.asarray(y_xla))
     with pytest.raises(ValueError):
         lay.set_param("pool_impl", "bogus")
 

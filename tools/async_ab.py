@@ -23,9 +23,10 @@ different proof obligations, and this tool runs both:
   (lr halved, rounds doubled) against the same-lr sync baseline.  The
   committed CPU verdict lives in example/MNIST/async_ab.json.
 * ``--overlap-bench`` — in-process step-wall micro-bench (sync fence
-  per step vs one round fence), the TPU-window measurement queued in
-  ``tpu_queue.sh`` (CPU numbers are dispatch-overhead weather; the
-  chip is where overlap pays).
+  per step vs one round fence).  One process, so ``--dev tpu`` goes to
+  a multi-chip host as it is; the measurement is still owed (ROADMAP
+  S10): CPU numbers are dispatch-overhead weather, the chip is where
+  overlap pays.
 
 Usage::
 
@@ -380,8 +381,8 @@ def run_ab(out_dir: str, rounds: int, tol: float, timeout: float,
 def run_overlap_bench(dev: str, steps: int, hidden: int) -> dict:
     """In-process step-wall micro-bench on ``dev``: per-step fence
     (sync) vs one round-boundary fence (async) over the same stream.
-    Queued for the TPU window in tpu_queue.sh — CPU numbers only show
-    dispatch overhead, the chip shows exchange/compute overlap."""
+    CPU numbers only show dispatch overhead; the chip shows
+    exchange/compute overlap."""
     import numpy as np
 
     from cxxnet_tpu.io.data import DataBatch
@@ -515,8 +516,7 @@ def main() -> int:
         doc["overlap"] = run_overlap_bench(args.dev, args.steps,
                                            args.hidden)
         o = doc["overlap"]
-        # relay-greppable one-liner (the tpu_queue.sh filter keeps
-        # only bench[/stage[ lines from a TPU-window run)
+        # one self-contained verdict line, greppable from a long log
         print(f"bench[async_overlap:{o['dev']}] "
               f"sync_step={o['sync_step_wall_sec']}s "
               f"async_step={o['async_step_wall_sec']}s "

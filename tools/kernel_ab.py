@@ -5,8 +5,10 @@ The promotion discipline for the Pallas block kernels — the
 of ``conv_block`` / ``int8_gemm`` / ``zero_update``, three stages:
 
 1. **interpret-parity gate** — the kernel (interpret mode off-TPU, the
-   compiled Mosaic program on TPU) vs the JITTED stock lowering,
-   ``np.array_equal`` over the workload shapes.  The reference is the
+   compiled Mosaic program on TPU) vs the JITTED stock lowering over
+   the workload shapes: ``np.array_equal`` for ``int8_gemm`` and
+   ``zero_update``, which replay the stock op chain; f32 rounding for
+   ``conv_block``, which swaps a conv for a GEMM.  The reference is the
    jitted stock function, not an eager replay: the net's real programs
    are always compiled, and on CPU the eager op-by-op spelling differs
    from its own compiled form (FMA fusion) — "parity with the stock
@@ -23,8 +25,9 @@ of ``conv_block`` / ``int8_gemm`` / ``zero_update``, three stages:
    the verdict for the measured backend into
    ``ops/kernels/verdicts.json`` — the committed state ``kernel_lib =
    auto`` follows.  On CPU the Pallas paths run under the interpreter
-   (emulation), so CPU verdicts are honest rejects; the TPU
-   invocations live in ``tools/tpu_queue.sh``.
+   (emulation), so CPU verdicts are honest rejects; the TPU verdicts
+   are still owed (ROADMAP S9) — one process, so the tool goes to the
+   chip as it is.
 
 Each kernel's numbers also flow through ``perf_guard`` (bench
 ``kernel_bench``): the appended history makes later runs comparable
@@ -107,7 +110,11 @@ def ab_conv_block(smoke, interpret, reps):
     f_stock = _instrumented(stock, "conv_block")
     f_kern = _instrumented(lambda x: kern(x), "conv_block")
     a, k = f_stock(x), f_kern(x)
-    parity = bool(np.array_equal(np.asarray(a), np.asarray(k)))
+    # a conv replaced by a GEMM: the two emitters order their FMAs
+    # differently, so f32 agrees to rounding, not bit for bit (the
+    # tolerance tests/test_kernels.py states for this kernel)
+    parity = bool(np.allclose(np.asarray(k), np.asarray(a),
+                              rtol=1e-6, atol=1e-6))
     walls = _time_legs([("stock", lambda: f_stock(x)),
                         ("kernel", lambda: f_kern(x))], reps)
     return parity, walls, f"b{b} {hw}x{hw} {cin}->{cout} f32"

@@ -155,8 +155,8 @@
 #                                     # kernel_bench perf_guard history);
 #                                     # the full-size CPU measurement +
 #                                     # --record writes ops/kernels/
-#                                     # verdicts.json, and the TPU legs
-#                                     # stay queued in tpu_queue.sh
+#                                     # verdicts.json; the TPU legs
+#                                     # are ROADMAP S9
 #        SDC=1 tools/run_tier1.sh     # also run the silent-data-
 #                                     # corruption lane: a 4-process
 #                                     # CPU-mesh CLI train has one real
@@ -335,7 +335,7 @@ if [ "${FLEET:-0}" = "1" ]; then
       --input "$fleet_out/fleet_smoke.json" \
       --history "$fleet_out/bench_history.jsonl" > /dev/null || rc=1
   # scaled-down burst profile over the in-process engine (the full
-  # >=10^6-request invocation is queued in tpu_queue.sh)
+  # >=10^6-request invocation is ROADMAP S10)
   timeout -k 10 300 env JAX_PLATFORMS=cpu \
     python tools/serve_bench.py --open-loop --burst --duration 6 \
       --base-rate 50 --burst-rate 200 --phase 1 \

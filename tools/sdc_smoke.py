@@ -379,10 +379,12 @@ def main() -> int:
     ap.add_argument("--overhead-only", action="store_true",
                     help="skip the flip/parity/canary walk and measure "
                          "only the fingerprint-sweep overhead (the "
-                         "tpu_queue full-size bench entry)")
+                         "full-size bench entry)")
     ap.add_argument("--dev", default="cpu",
-                    help="conf dev= value for the overhead run "
-                         "(e.g. tpu)")
+                    help="conf dev= value for the overhead run (e.g. "
+                         "tpu: the run is ONE child process, which "
+                         "owns the chip — this parent never "
+                         "initializes a JAX backend)")
     ap.add_argument("--hidden", type=int, default=N_HIDDEN,
                     help="fc1 width for the overhead run (scale the "
                          "model up for the on-chip measurement)")

@@ -16,8 +16,8 @@ micro-batcher + compiled-predict-cache data path:
   arrival schedule alternating ``--base-rate`` and ``--burst-rate``
   every ``--phase`` seconds, sustained for ``--duration`` seconds (or
   until ``--total-requests`` arrivals — the ROADMAP's >= 10^6-request
-  story; the full-scale invocation is queued in ``tpu_queue.sh``, a
-  scaled-down one runs in the FLEET=1 tier-1 lane).  Reports sustained
+  story; a scaled-down one runs in the FLEET=1 tier-1 lane, the
+  full-scale on-chip one is ROADMAP S10).  Reports sustained
   p50/p99 plus explicit shed (429) / expired (504) / error counts, so
   admission-control behavior under burst pressure is a first-class
   series.  ``--url`` points the same harness at a running HTTP front
@@ -800,8 +800,7 @@ def main(argv=None):
         if args.json_path:
             with open(args.json_path, "w", encoding="utf-8") as f:
                 json.dump(result, f, indent=1)
-        # the bench[...] spelling is what the TPU queue's relay-log grep
-        # keeps (tools/tpu_queue.sh) — one self-contained verdict line
+        # one self-contained verdict line, greppable from a long log
         print(f"bench[quant_ab:{args.model}] f32 "
               f"{ab['f32']['req_per_sec']:.1f} req/s vs {ab['scheme']} "
               f"{ab['quant']['req_per_sec']:.1f} req/s speedup "

@@ -8,7 +8,8 @@ decode pool + threadbuffer + chunked async scan), runs ``task=train``
 for a few rounds via the CLI, and leaves the log for committing to
 ``example/ImageNet/``.
 
-Run through the serialized queue (tools/tpu_queue.sh) only:
+One process, so it goes to the chip as it is (the CLI names the device
+it bound and turns the compile cache on):
 
     python tools/tpu_train_e2e.py [n_images] [rounds] [batch]
 """
@@ -20,19 +21,8 @@ import tempfile
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
-)
-
 
 def main() -> None:
-    import jax
-
-    os.makedirs(CACHE_DIR, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
     from io_bench import generate_imgbin
 
     from cxxnet_tpu.cli import LearnTask

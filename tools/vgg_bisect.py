@@ -6,7 +6,7 @@ The F(4x4,3x3) rewrite inflates each conv's input 2.25x in HBM
 (224/112px) may trade worse than the late ones; these variants bound
 the sweet spot before promoting a conf default.
 
-Run on the TPU host (through tools/tpu_queue.sh):
+Run on the chip (one process; exits 2 without an accelerator):
 
     python tools/vgg_bisect.py [variant ...]
 
