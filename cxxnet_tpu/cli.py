@@ -19,7 +19,7 @@ from __future__ import annotations
 import os
 import sys
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -99,6 +99,9 @@ class LearnTask:
         self.data_service_ready_file = ""
         self.telemetry = 0  # per-round JSONL records (doc/observability.md)
         self.telemetry_path = "telemetry.jsonl"
+        # seconds of set-up by phase, stamped where each happens and
+        # carried in every telemetry record (doc/observability.md)
+        self._setup: Dict[str, float] = {}
         # self-tuning knob controller (cxxnet_tpu/tune/,
         # doc/performance.md): tune_* keys are parsed by
         # tune.options_from_cfg from the raw cfg stream
@@ -321,6 +324,7 @@ class LearnTask:
         # the fleet supervisor re-launches this exact invocation per
         # replica (conf + overrides, fleet keys pinned) — keep the raw
         # argv around for serve.fleet.cli_spawn_fn
+        t_run = time.perf_counter()
         self.conf_path = argv[0]
         self.cli_overrides = list(argv[1:])
         for name, val in cfgmod.parse_file(argv[0]):
@@ -363,7 +367,12 @@ class LearnTask:
             if self.task != "train":
                 raise ValueError("elastic_join=1 only supports task=train")
         else:
+            t_init = time.perf_counter()
+            self._setup["conf_s"] = t_init - t_run
             self.init()
+            self._setup["model_s"] = (
+                time.perf_counter() - t_init
+                - self._setup.get("iterators_s", 0.0))
         if not self.silent:
             print("initializing end, start working")
         if self.task == "export_quant":
@@ -666,6 +675,7 @@ class LearnTask:
         return True
 
     def _create_iterators(self) -> None:
+        t0 = time.perf_counter()
         split = cfgmod.split_sections(self.cfg)
         for sec in split.sections:
             if sec.kind == "data" and self.task not in ("pred", "pred_raw",
@@ -724,6 +734,8 @@ class LearnTask:
                         it.set_param("dist_num_worker", str(nproc))
                         it.set_param("dist_worker_rank", str(pid))
                 it.init()
+        self._setup["iterators_s"] = (self._setup.get("iterators_s", 0.0)
+                                      + time.perf_counter() - t0)
 
     # ------------------------------------------------------------------
     # self-tuning controller (cxxnet_tpu/tune/): ``controller = 1``
@@ -1183,6 +1195,7 @@ class LearnTask:
         from .utils.checkpoint import DivergenceError, PreemptionHandler
 
         self._train_start = time.time()
+        self._train_t0 = time.perf_counter()  # to the first fence: set-up
         if self.elastic_opts is None:  # task_train without run()
             from .parallel.elastic import ElasticOptions
 
@@ -1508,10 +1521,16 @@ class LearnTask:
         from .parallel.distributed import process_info
 
         from .obs import trace as obs_trace
-        from .utils.profiler import pipeline_stats
+        from .utils.profiler import pipeline_stats, stage
 
         nproc = process_info()[1]
         check_preempt = nproc == 1
+        trainer = self.net_trainer
+        # fence to fence (benchmarks/lib/window.py's edges): a chunk's
+        # period runs from the previous fence — for a round's first
+        # chunk from here — to its own; next / copy / stack (below) and
+        # h2d / dispatch / device_wait / metric (trainer) tile it
+        chunk = stage("chunk", step=trainer.epoch_counter).begin()
         # async data-parallel (doc/parallel.md "Async data-parallel"):
         # per-step fences move to the round boundary — the loop must
         # not sync after every update or the overlap is gone
@@ -1520,14 +1539,17 @@ class LearnTask:
         preempted = False
         sample_counter = 0
         self.net_trainer.start_round(self.start_counter)
-        self.itr_train.before_first()
-        # anchor the augmentation epoch to the ROUND counter (after the
-        # rewind, overriding the process-local epoch count): a resumed
-        # run's round r then draws the identical stream an uninterrupted
-        # run drew at round r (io/augment.py `augment_epoch`)
-        self.itr_train.set_param("augment_epoch", str(self.start_counter))
-        timer.clear()
         pipeline_stats().reset()  # per-round stage breakdown
+        with stage("next", step=trainer.epoch_counter):
+            self.itr_train.before_first()
+            # anchor the augmentation epoch to the ROUND counter (after
+            # the rewind, overriding the process-local epoch count): a
+            # resumed run's round r then draws the identical stream an
+            # uninterrupted run drew at round r (io/augment.py
+            # `augment_epoch`)
+            self.itr_train.set_param("augment_epoch",
+                                     str(self.start_counter))
+        timer.clear()
         pipe_mark = time.perf_counter()  # last fence (lap start)
         pending: List = []  # scan_steps>1: batches staged for ONE dispatch
         in_flight: List = []  # async (handle, n_steps) chunks in flight
@@ -1539,9 +1561,20 @@ class LearnTask:
             exactly, so samples/sec is the true PIPELINE rate (max of
             host and device time per chunk), not just device time."""
             nonlocal pipe_mark
+            _chunk_fence(n_steps)
             now = time.perf_counter()
             timer.add(now - pipe_mark, n_steps)
             pipe_mark = now
+
+        def _chunk_fence(n_steps: int) -> None:
+            """Bill the chunk that ends at this fence and open the
+            next; called just before the timer records the fence."""
+            nonlocal chunk
+            chunk.end(rows=n_steps * trainer.batch_size)
+            if "first_fence_s" not in self._setup:
+                self._setup["first_fence_s"] = (
+                    time.perf_counter() - self._train_t0)
+            chunk = stage("chunk", step=trainer.epoch_counter).begin()
 
         def _fence(drain_all: bool) -> None:
             """Block on finished chunks, recording a lap per chunk.
@@ -1551,13 +1584,9 @@ class LearnTask:
 
             while len(in_flight) > (0 if drain_all else 1):
                 handle, ns = in_flight.pop(0)
-                t0 = time.perf_counter()
-                with obs_trace.span("train.device_wait", steps=ns):
+                with stage("device_wait", rows=ns * trainer.batch_size,
+                           step=trainer.epoch_counter):
                     _jx.block_until_ready(handle)
-                pipeline_stats().add(
-                    "device_wait", time.perf_counter() - t0,
-                    rows=ns * self.net_trainer.batch_size,
-                )
                 _lap(ns)
 
         def _flush_pending() -> None:
@@ -1592,31 +1621,31 @@ class LearnTask:
                     _DB(data=pending[0][0], label=pending[0][1])
                 )
                 if not sync_mode:
-                    t0 = time.perf_counter()
-                    self.net_trainer.sync()
-                    pipeline_stats().add(
-                        "device_wait", time.perf_counter() - t0,
-                        rows=self.net_trainer.batch_size,
-                    )
+                    with stage("device_wait", rows=trainer.batch_size,
+                               step=trainer.epoch_counter):
+                        self.net_trainer.sync()
                     _lap(1)
             else:
                 import numpy as _np
 
-                with obs_trace.span("train.dispatch",
-                                    steps=len(pending)):
-                    handle = self.net_trainer.update_scan(
-                        _np.stack([d for d, _ in pending]),
-                        _np.stack([l for _, l in pending]),
-                        sync=sync_mode,
-                        # sharded iterators guarantee equal K per process
-                        # (equal-steps contract) — skip the collective
-                        # K-check so the async overlap stays unbroken
-                        check_steps=False,
-                    )
+                with stage("stack", rows=len(pending) * trainer.batch_size,
+                           step=trainer.epoch_counter):
+                    data = _np.stack([d for d, _ in pending])
+                    labels = _np.stack([l for _, l in pending])
+                handle = self.net_trainer.update_scan(
+                    data, labels,
+                    sync=sync_mode,
+                    # sharded iterators guarantee equal K per process
+                    # (equal-steps contract) — skip the collective
+                    # K-check so the async overlap stays unbroken
+                    check_steps=False,
+                )
+                del data, labels
                 if not sync_mode:
                     in_flight.append((handle, len(pending)))
                     _fence(drain_all=False)
             if sync_mode:
+                _chunk_fence(len(pending))
                 timer.stop(n_steps=len(pending))
             self._global_step += len(pending)
             pending.clear()
@@ -1662,19 +1691,24 @@ class LearnTask:
                 batch, staged_next = staged_next, None
             elif exhausted:
                 break
-            elif self.itr_train.next():
-                batch = (self.itr_train.value() if self.test_io == 0
-                         else None)
             else:
-                break
+                with stage("next", step=trainer.epoch_counter) as st:
+                    more = self.itr_train.next()
+                    batch = (self.itr_train.value()
+                             if more and self.test_io == 0 else None)
+                    st.rows = trainer.batch_size if more else 0
+                if not more:
+                    break
             if self.test_io == 0:
                 if scan_ok and not batch.num_batch_padd:
                     import numpy as _np
 
                     # copy: iterator buffers are reused by next()
-                    pending.append(
-                        (_np.array(batch.data), _np.array(batch.label))
-                    )
+                    with stage("copy", rows=trainer.batch_size,
+                               step=trainer.epoch_counter):
+                        pending.append(
+                            (_np.array(batch.data), _np.array(batch.label))
+                        )
                     if len(pending) >= self.scan_steps:
                         _flush_pending()
                 else:
@@ -1687,19 +1721,25 @@ class LearnTask:
                     self.net_trainer.update(batch)
                     if not self.net_trainer.eval_train:
                         if db_ok and not exhausted:
-                            if self.itr_train.next():
+                            with stage("next",
+                                       step=trainer.epoch_counter) as st:
+                                more = self.itr_train.next()
+                                st.rows = trainer.batch_size if more else 0
+                            if more:
                                 import numpy as _np
 
                                 from .io.data import DataBatch as _DB
 
                                 v = self.itr_train.value()
-                                staged_next = _DB(
-                                    data=_np.array(v.data),
-                                    label=_np.array(v.label),
-                                    num_batch_padd=v.num_batch_padd,
-                                    extra_data=[_np.array(e)
-                                                for e in v.extra_data],
-                                )
+                                with stage("copy", rows=trainer.batch_size,
+                                           step=trainer.epoch_counter):
+                                    staged_next = _DB(
+                                        data=_np.array(v.data),
+                                        label=_np.array(v.label),
+                                        num_batch_padd=v.num_batch_padd,
+                                        extra_data=[_np.array(e)
+                                                    for e in v.extra_data],
+                                    )
                                 self.net_trainer.stage_batch(staged_next)
                             else:
                                 exhausted = True
@@ -1707,12 +1747,11 @@ class LearnTask:
                             # async mode: NO per-step fence — the
                             # dispatch pipeline runs free until the
                             # round-boundary async_round_end below
-                            t0 = time.perf_counter()
-                            self.net_trainer.sync()
-                            pipeline_stats().add(
-                                "device_wait", time.perf_counter() - t0,
-                                rows=self.net_trainer.batch_size,
-                            )
+                            with stage("device_wait",
+                                       rows=trainer.batch_size,
+                                       step=trainer.epoch_counter):
+                                self.net_trainer.sync()
+                    _chunk_fence(1)
                     timer.stop()
                     self._global_step += 1
                     pipe_mark = time.perf_counter()  # span was timed
@@ -1730,18 +1769,16 @@ class LearnTask:
                 break
         _flush_pending()  # tail chunk shorter than scan_steps
         _drain_in_flight()  # round/preemption boundary: queue empty
+        chunk.drop()  # what follows the round's last fence is in no chunk
         if async_on:
             # round-boundary fence (and, on resync rounds, the hard
             # barrier draining the staleness buffers); billed as one
             # device_wait lap so the round timing stays honest
-            t0 = time.perf_counter()
+            wait = stage("device_wait",
+                         rows=sample_counter * trainer.batch_size,
+                         step=trainer.epoch_counter).begin()
             self.net_trainer.async_round_end(self.start_counter)
-            dt = time.perf_counter() - t0
-            pipeline_stats().add(
-                "device_wait", dt,
-                rows=sample_counter * self.net_trainer.batch_size,
-            )
-            timer.add(dt, 0)
+            timer.add(wait.end(), 0)
         if preempted:
             return False
         stage_line = pipeline_stats().report()
@@ -1827,6 +1864,10 @@ class LearnTask:
             # seconds, sampled step fences — lifetime totals, so per-
             # round deltas are computable between records
             "device": obs_device.summary(),
+            # set-up by phase, lifetime too: conf parse and arming, the
+            # iterators' init, trainer + model init or load, and
+            # task_train's entry to the first fence
+            "setup": {k: round(v, 6) for k, v in self._setup.items()},
         }
         async_snap = self.net_trainer.async_snapshot()
         if async_snap is not None:

@@ -669,117 +669,130 @@ class FunctionalNet:
         items = (embed_items if embed_items is not None
                  else [("L", i) for i in range(len(g.layers))])
         for kind, i in items:
-            spec = g.layers[i]
-            if kind == "E":
-                idxs = embed_groups[i]
-                xs = [nodes[g.layers[j].nindex_in[0]] for j in idxs]
-                if any(v is None for v in xs):
-                    raise ValueError(
-                        f"branch-embed group at layer {i}: unset input node")
-                gparams = [params.get(self.param_key[j], {}) for j in idxs]
-                run_f = (
-                    jax.checkpoint(self._apply_branch_embed)
-                    if (self.remat and train) else self._apply_branch_embed
-                )
-                for j, out in zip(idxs, run_f(gparams, xs)):
-                    nodes[g.layers[j].nindex_out[0]] = out
-                continue
-            if i in fuse_member:
-                if fuse_member[i] != i:
-                    continue  # output produced by its group leader below
-                idxs = fuse_groups[i]
-                x = nodes[spec.nindex_in[0]]
-                if x is None:
-                    raise ValueError(f"layer {i}: unset input node")
-                gparams = [params.get(self.param_key[j], {}) for j in idxs]
-                # stride bound statically (shared by the whole group via
-                # the fusion key); jax.checkpoint must not trace it
-                fused = functools.partial(
-                    self._apply_fused_1x1,
-                    self.layer_objs[i].param.stride,
-                    kernels=kern_lib,
-                )
-                run_f = (
-                    jax.checkpoint(fused)
-                    if (self.remat and train) else fused
-                )
-                for j, out in zip(idxs, run_f(gparams, x)):
-                    nodes[g.layers[j].nindex_out[0]] = out
-                continue
-            lay = self.layer_objs[i]
-            inputs = [nodes[n] for n in spec.nindex_in]
-            if any(v is None for v in inputs):
-                raise ValueError(f"layer {i}: unset input node")
-            lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            if isinstance(lay, LossLayer):
-                logits = inputs[0].astype(jnp.float32)
-                if labels is not None:
-                    field = self._label_field(labels, lay.target)
-                    scale = lay.grad_scale / (batch * self.update_period)
-                    total_loss = total_loss + scale * lay.loss_masked(
-                        logits, field, sample_mask
+            # every device operation's metadata carries the layer's
+            # scope, so a profiler trace names operations by conf layer
+            with jax.named_scope(self.layer_scope(i)):
+                spec = g.layers[i]
+                if kind == "E":
+                    idxs = embed_groups[i]
+                    xs = [nodes[g.layers[j].nindex_in[0]] for j in idxs]
+                    if any(v is None for v in xs):
+                        raise ValueError(
+                            f"branch-embed group at layer {i}: "
+                            "unset input node")
+                    gparams = [params.get(self.param_key[j], {}) for j in idxs]
+                    run_f = (
+                        jax.checkpoint(self._apply_branch_embed)
+                        if (self.remat and train) else self._apply_branch_embed
                     )
-                # transform is f32 math; only downcast if a downstream layer
-                # consumes it — the terminal node goes to host metrics in f32
-                out = lay.transform(logits)
-                if spec.nindex_out[0] != out_idx:
-                    out = out.astype(cdt)
-                nodes[spec.nindex_out[0]] = out
-            else:
-                key = self.param_key[i]
-                lparams = params.get(key, {})
-                if _opsq().is_quantized(lparams):
-                    # int8 entry: dequant-free apply (ops/quant.py) —
-                    # conv/fullc only, by the exporter's construction
-                    nodes[spec.nindex_out[0]] = self._apply_quant_layer(
-                        lay, lparams, inputs, kernels=kern_lib
-                    )
+                    for j, out in zip(idxs, run_f(gparams, xs)):
+                        nodes[g.layers[j].nindex_out[0]] = out
                     continue
-                # shared stateful layers chain their state: a later
-                # occurrence reads the state the earlier one produced
-                if new_aux is not None:
-                    lstate = new_aux.get(key)
-                elif aux is not None:
-                    lstate = aux.get(key)
+                if i in fuse_member:
+                    if fuse_member[i] != i:
+                        continue  # output produced by its group leader below
+                    idxs = fuse_groups[i]
+                    x = nodes[spec.nindex_in[0]]
+                    if x is None:
+                        raise ValueError(f"layer {i}: unset input node")
+                    gparams = [params.get(self.param_key[j], {}) for j in idxs]
+                    # stride bound statically (shared by the whole group via
+                    # the fusion key); jax.checkpoint must not trace it
+                    fused = functools.partial(
+                        self._apply_fused_1x1,
+                        self.layer_objs[i].param.stride,
+                        kernels=kern_lib,
+                    )
+                    run_f = (
+                        jax.checkpoint(fused)
+                        if (self.remat and train) else fused
+                    )
+                    for j, out in zip(idxs, run_f(gparams, x)):
+                        nodes[g.layers[j].nindex_out[0]] = out
+                    continue
+                lay = self.layer_objs[i]
+                inputs = [nodes[n] for n in spec.nindex_in]
+                if any(v is None for v in inputs):
+                    raise ValueError(f"layer {i}: unset input node")
+                lrng = jax.random.fold_in(rng, i) if rng is not None else None
+                if isinstance(lay, LossLayer):
+                    logits = inputs[0].astype(jnp.float32)
+                    if labels is not None:
+                        field = self._label_field(labels, lay.target)
+                        scale = lay.grad_scale / (batch * self.update_period)
+                        total_loss = total_loss + scale * lay.loss_masked(
+                            logits, field, sample_mask
+                        )
+                    # transform is f32 math; only downcast if a downstream
+                    # layer consumes it — the terminal node goes to host
+                    # metrics in f32
+                    out = lay.transform(logits)
+                    if spec.nindex_out[0] != out_idx:
+                        out = out.astype(cdt)
+                    nodes[spec.nindex_out[0]] = out
                 else:
-                    lstate = None
-                if lstate is not None and hasattr(lay, "apply_stateful"):
-                    if self.remat and train:
-                        # state outputs are non-differentiable, so
-                        # checkpointing the stateful call is safe — a
-                        # bn_eval=running net keeps activation recompute
-                        def run_st(p, st, xs, lay=lay, lrng=lrng):
-                            return lay.apply_stateful(
-                                p, st, xs, train=True, rng=lrng, step=step
+                    key = self.param_key[i]
+                    lparams = params.get(key, {})
+                    if _opsq().is_quantized(lparams):
+                        # int8 entry: dequant-free apply (ops/quant.py) —
+                        # conv/fullc only, by the exporter's construction
+                        nodes[spec.nindex_out[0]] = self._apply_quant_layer(
+                            lay, lparams, inputs, kernels=kern_lib
+                        )
+                        continue
+                    # shared stateful layers chain their state: a later
+                    # occurrence reads the state the earlier one produced
+                    if new_aux is not None:
+                        lstate = new_aux.get(key)
+                    elif aux is not None:
+                        lstate = aux.get(key)
+                    else:
+                        lstate = None
+                    if lstate is not None and hasattr(lay, "apply_stateful"):
+                        if self.remat and train:
+                            # state outputs are non-differentiable, so
+                            # checkpointing the stateful call is safe — a
+                            # bn_eval=running net keeps activation recompute
+                            def run_st(p, st, xs, lay=lay, lrng=lrng):
+                                return lay.apply_stateful(
+                                    p, st, xs, train=True, rng=lrng, step=step
+                                )
+
+                            outs, new_state = jax.checkpoint(run_st)(
+                                lparams, lstate, inputs
+                            )
+                        else:
+                            outs, new_state = lay.apply_stateful(
+                                lparams, lstate, inputs,
+                                train=train, rng=lrng, step=step,
+                            )
+                        if new_aux is not None:
+                            new_aux[key] = new_state
+                    elif self.remat and train:
+
+                        def run(p, xs, lay=lay, lrng=lrng):
+                            return lay.apply(
+                                p, xs, train=True, rng=lrng, step=step
                             )
 
-                        outs, new_state = jax.checkpoint(run_st)(
-                            lparams, lstate, inputs
-                        )
+                        outs = jax.checkpoint(run)(lparams, inputs)
                     else:
-                        outs, new_state = lay.apply_stateful(
-                            lparams, lstate, inputs,
-                            train=train, rng=lrng, step=step,
+                        outs = lay.apply(
+                            lparams, inputs, train=train, rng=lrng, step=step
                         )
-                    if new_aux is not None:
-                        new_aux[key] = new_state
-                elif self.remat and train:
-
-                    def run(p, xs, lay=lay, lrng=lrng):
-                        return lay.apply(
-                            p, xs, train=True, rng=lrng, step=step
-                        )
-
-                    outs = jax.checkpoint(run)(lparams, inputs)
-                else:
-                    outs = lay.apply(
-                        lparams, inputs, train=train, rng=lrng, step=step
-                    )
-                for n, v in zip(spec.nindex_out, outs):
-                    nodes[n] = v
+                    for n, v in zip(spec.nindex_out, outs):
+                        nodes[n] = v
         if return_aux:
             return nodes, total_loss, (new_aux if new_aux is not None else {})
         return nodes, total_loss
+
+    def layer_scope(self, i: int) -> str:
+        """``l<index>_<conf name, or the type where the conf gives no
+        name>``: the ``jax.named_scope`` of layer ``i``'s operations (a
+        fused sibling group runs under its leader's).  Spelled like the
+        params' keys, but a shared layer keeps its own index."""
+        spec = self.graph.layers[i]
+        return f"l{i}_{spec.name or spec.type_name}"
 
     def use_branch_embed(self, train: bool,
                          backend: Optional[str] = None) -> bool:

@@ -47,6 +47,7 @@ from ..updater import Updater, create_updater
 from ..utils import checkpoint as ckpt
 from ..utils.checkpoint import MODEL_MAGIC, DivergenceError  # noqa: F401
 from ..utils.metric import MetricSet
+from ..utils.profiler import stage
 from .graph import NetGraph
 from .net import FunctionalNet
 
@@ -962,45 +963,58 @@ class NetTrainer:
                         "process must scan the same K"
                     )
         with_out = bool(self.eval_train)
-        fn = self._scan_step_fn(k, per_step, with_out)
         first_epoch = self.epoch_counter
-        step0 = jnp.asarray(first_epoch, jnp.int32)
-        (self.params, self.ustates, self.aux, self._rng_key, _end, ys) = fn(
-            self.params, self.ustates, self.aux,
-            self._stage_scan(data, per_step, count_rows=True),
-            self._stage_scan(labels, per_step),
-            self._next_rng(), step0,
-        )
+        rows = k * self.batch_size
+        # host stages of the chunk, on the caller's thread; with the
+        # round loop's next / copy / stack they tile its period
+        # (doc/observability.md).  h2d is the ENQUEUE: the transfer's
+        # tail ends inside device_wait and only a trace can split it
+        fed = data_arr.shape[0] * (data_arr.shape[1] if per_step else 1)
+        with stage("h2d", rows=fed, step=first_epoch):
+            data_dev = self._stage_scan(data, per_step)
+            labels_dev = self._stage_scan(labels, per_step)
+        with stage("dispatch", rows=rows, step=first_epoch):
+            fn = self._scan_step_fn(k, per_step, with_out)
+            step0 = jnp.asarray(first_epoch, jnp.int32)
+            (self.params, self.ustates, self.aux, self._rng_key, _end,
+             ys) = fn(
+                self.params, self.ustates, self.aux, data_dev, labels_dev,
+                self._next_rng(), step0,
+            )
+            del data_dev, labels_dev
         self.epoch_counter += k
+        losses, outs = ys if with_out else (ys, None)
         if self.divergence_policy:
             # guard fetches the per-step losses — with sync=False this
             # serializes the async overlap (the cost of the check)
-            self._guard_loss(ys[0] if with_out else ys, first_epoch, k)
+            with stage("device_wait", step=first_epoch):
+                self._guard_loss(losses, first_epoch, k)
+        if not with_out and not sync:
+            return losses  # async: device array, queue not drained
+        with stage("device_wait", rows=rows, step=first_epoch):
+            if with_out:
+                outs_np = self._local_scan_rows(outs)
+            losses_np = np.asarray(jax.device_get(losses))
         if with_out:
-            losses, outs = ys
-            outs_np = self._local_scan_rows(outs)
-            labels_np = np.asarray(labels)
-            if not per_step:
-                labels_np = np.broadcast_to(
-                    labels_np, (k,) + labels_np.shape
-                )
-            for i in range(k):
-                self.train_metric.add_eval(
-                    outs_np[i], labels_np[i], self._label_ranges()
-                )
-        else:
-            losses = ys
-            if not sync:
-                return losses  # async: device array, queue not drained
-        return np.asarray(jax.device_get(losses))
+            with stage("metric", rows=rows, step=first_epoch):
+                labels_np = np.asarray(labels)
+                if not per_step:
+                    labels_np = np.broadcast_to(
+                        labels_np, (k,) + labels_np.shape
+                    )
+                for i in range(k):
+                    self.train_metric.add_eval(
+                        outs_np[i], labels_np[i], self._label_ranges()
+                    )
+        return losses_np
 
-    def _stage_scan(self, x, per_step: bool, count_rows: bool = False):
+    def _stage_scan(self, x, per_step: bool):
         """Host stack → device array for update_scan; multi-process runs
         assemble the global array from per-process shards ([K, B, ...]
         step-stacks shard on batch axis 1; one staged batch is exactly
-        the _to_device case)."""
+        the _to_device case).  The caller bills the ``h2d`` stage."""
         if not per_step:
-            return self._to_device(x, count_rows=count_rows)
+            return self._place(x)
         if jax.process_count() == 1:
             return jnp.asarray(x)
         return jax.make_array_from_process_local_data(
@@ -1634,24 +1648,21 @@ class NetTrainer:
         time but no rows, so the stage's rows/sec stays the true batch
         rate instead of 3-4x it.
         """
-        from ..utils.profiler import pipeline_stats
-        import time as _time
+        rows = (x.shape[0] if count_rows and getattr(x, "ndim", 0) else 0)
+        with stage("h2d", rows=rows, step=self.epoch_counter):
+            return self._place(x, own=own)
 
-        t0 = _time.perf_counter()
+    def _place(self, x: np.ndarray, own: bool = False) -> jax.Array:
+        """:meth:`_to_device` without the bill."""
         if jax.process_count() == 1:
             sh = self._h2d_sharding()
             if sh is None:
-                out = jnp.asarray(x)
-            else:
-                src = x if own else np.array(x, copy=True)
-                out = jax.device_put(src, sh)
-        else:
-            out = jax.make_array_from_process_local_data(
-                self.mesh_plan.data_sharding(), np.asarray(x)
-            )
-        rows = (x.shape[0] if count_rows and getattr(x, "ndim", 0) else 0)
-        pipeline_stats().add("h2d", _time.perf_counter() - t0, rows=rows)
-        return out
+                return jnp.asarray(x)
+            src = x if own else np.array(x, copy=True)
+            return jax.device_put(src, sh)
+        return jax.make_array_from_process_local_data(
+            self.mesh_plan.data_sharding(), np.asarray(x)
+        )
 
     def _transfer_batch(self, data_np, label_np, mask_np, extras_np,
                         own: bool = False):
@@ -1664,9 +1675,6 @@ class NetTrainer:
         configurations fall back to per-array :meth:`_to_device`.
         Returns ``(data, labels, mask, extras)`` device arrays; billed
         to the ``h2d`` stage with the batch's row count."""
-        from ..utils.profiler import pipeline_stats
-        import time as _time
-
         sh = self._h2d_sharding()
         if jax.process_count() != 1 or sh is None:
             data = self._to_device(data_np, count_rows=True, own=own)
@@ -1674,18 +1682,14 @@ class NetTrainer:
             mask = self._to_device(mask_np, own=own)
             extras = tuple(self._to_device(e, own=own) for e in extras_np)
             return data, labels, mask, extras
-        t0 = _time.perf_counter()
-        leaves = (data_np, label_np, mask_np) + tuple(extras_np)
-        if not own:
-            # device_put may alias host memory (CPU zero-copy); copy
-            # anything we do not own — same cost jnp.asarray paid
-            leaves = tuple(np.array(a, copy=True) for a in leaves)
-        placed = jax.device_put(leaves, sh)
-        data, labels, mask = placed[0], placed[1], placed[2]
-        extras = tuple(placed[3:])
-        pipeline_stats().add("h2d", _time.perf_counter() - t0,
-                             rows=data_np.shape[0])
-        return data, labels, mask, extras
+        with stage("h2d", rows=data_np.shape[0], step=self.epoch_counter):
+            leaves = (data_np, label_np, mask_np) + tuple(extras_np)
+            if not own:
+                # device_put may alias host memory (CPU zero-copy); copy
+                # anything we do not own — same cost jnp.asarray paid
+                leaves = tuple(np.array(a, copy=True) for a in leaves)
+            placed = jax.device_put(leaves, sh)
+        return placed[0], placed[1], placed[2], tuple(placed[3:])
 
     def stage_batch(self, batch: DataBatch) -> bool:
         """Double-buffered device feed: begin the (async) H2D of the

@@ -91,6 +91,13 @@ class _State:
         self.compile_seconds = 0.0
         self.cold_call_seconds = 0.0
         self.sampled_steps = 0
+        # what a program costs before and beside its backend compile:
+        # jax's own trace / lowering / cache-load durations, and this
+        # module's extra lowering for the cost analysis
+        self.trace_seconds = 0.0
+        self.lower_seconds = 0.0
+        self.cache_retrieval_seconds = 0.0
+        self.cost_analysis_seconds = 0.0
 
 
 _STATE = _State()
@@ -188,6 +195,10 @@ def reset() -> None:
         _STATE.compile_seconds = 0.0
         _STATE.cold_call_seconds = 0.0
         _STATE.sampled_steps = 0
+        _STATE.trace_seconds = 0.0
+        _STATE.lower_seconds = 0.0
+        _STATE.cache_retrieval_seconds = 0.0
+        _STATE.cost_analysis_seconds = 0.0
     with _METRICS_LOCK:
         _METRICS = None
 
@@ -198,7 +209,22 @@ _LISTENER_INSTALLED = False
 _LISTENER_LOCK = threading.Lock()
 
 
+#: jax.monitoring duration events totalled into ``summary()`` as they
+#: are (the backend compile also feeds the registry, below)
+_DURATION_TOTALS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "cache_retrieval_seconds",
+}
+
+
 def _on_event_duration(name: str, duration: float, **_kw) -> None:
+    total = _DURATION_TOTALS.get(name)
+    if total is not None:
+        with _STATE.lock:
+            setattr(_STATE, total, getattr(_STATE, total) + duration)
+        return
     if not name.endswith("backend_compile_duration"):
         return
     try:
@@ -404,12 +430,15 @@ class InstrumentedJit:
         # nothing else; everything after the call is pure-Python
         # metric/event writes.
         cost = None
+        t0 = time.perf_counter()
         try:
             cost = _cost_of(self.fn.lower(*args))
         except Exception as e:  # noqa: BLE001 - accounting is best-effort
             obs_events.log_exception_once(
                 f"obs.device.lower:{self.kind}", e,
                 kind="obs.device_error", program=self.kind)
+        with _STATE.lock:
+            _STATE.cost_analysis_seconds += time.perf_counter() - t0
         bucket = self._bucket(args)
         t0 = time.perf_counter()
         out = self.fn(*args)
@@ -535,7 +564,10 @@ def maybe_sample_step(step: int, sync_fn: Callable[[], None]) -> bool:
 def summary() -> Dict[str, float]:
     """Lifetime totals for the per-round telemetry record (cli.py):
     programs instrumented, estimated FLOPs/bytes across them, backend
-    compiles and their cumulative seconds, sampled fences."""
+    compiles and their cumulative seconds, sampled fences, and the
+    seconds jax spent tracing, lowering and loading programs from the
+    persistent cache, beside this module's own extra lowering for the
+    cost analysis (``cost_analysis_seconds``)."""
     with _STATE.lock:
         return {
             "programs": _STATE.programs,
@@ -545,4 +577,10 @@ def summary() -> Dict[str, float]:
             "compile_seconds": round(_STATE.compile_seconds, 6),
             "cold_call_seconds": round(_STATE.cold_call_seconds, 6),
             "sampled_steps": _STATE.sampled_steps,
+            "trace_seconds": round(_STATE.trace_seconds, 6),
+            "lower_seconds": round(_STATE.lower_seconds, 6),
+            "cache_retrieval_seconds": round(
+                _STATE.cache_retrieval_seconds, 6),
+            "cost_analysis_seconds": round(
+                _STATE.cost_analysis_seconds, 6),
         }

@@ -116,6 +116,12 @@ class _Metric:
         self.labelnames = tuple(labelnames)
         self._lock = threading.Lock()
         self._children: Dict[LabelValues, object] = {}
+        if not self.labelnames:
+            # a label-less family exports its zeroed sample from birth
+            # (the Prometheus client convention): a histogram nobody has
+            # observed yet still renders its buckets, so a scrape never
+            # shows a TYPE line with no samples under it
+            self._children[()] = self._make_child()
 
     # child management ---------------------------------------------------
     def labels(self, *values, **kv):

@@ -130,8 +130,11 @@ class _LiveSpan:
         t1 = time.perf_counter()
         tr = self._tracer
         stack = tr._stack()
-        if stack and stack[-1] == self.span_id:
-            stack.pop()
+        if self.span_id in stack:
+            # also drops what was left open above it: a span abandoned
+            # when an exception unwound past its owner (the round loop's
+            # ``train.chunk``) must not become every later span's parent
+            del stack[stack.index(self.span_id):]
         th = threading.current_thread()
         tr._record(Span(
             self.name, self.cat,

@@ -16,10 +16,12 @@ Config keys (all global):
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from ..obs import trace as _obs_trace
 from ..obs.registry import PercentileWindow, registry as _obs_registry
 
 ConfigEntry = Tuple[str, str]
@@ -42,9 +44,14 @@ class PercentileTracker(PercentileWindow):
 
 
 class PipelineStats:
-    """Per-stage input-pipeline timing: decode / augment / batch / h2d /
-    device_wait (plus any custom stage name), each on a
-    :class:`PercentileTracker` with total-time and row accounting.
+    """Per-stage host timing of a training round, each stage on a
+    :class:`PercentileTracker` with total-time and row accounting (plus
+    any custom stage name).  ``decode`` / ``augment`` / ``batch`` are
+    billed by the iterator's threads and overlap the rest; ``next`` /
+    ``copy`` / ``stack`` / ``h2d`` / ``dispatch`` / ``device_wait`` /
+    ``metric`` are billed on the round loop's thread through
+    :func:`stage` and tile ``chunk``, the fence-to-fence period of one
+    dispatch (doc/observability.md has the table).
 
     One process-wide instance (:func:`pipeline_stats`) so the io/ chain,
     the trainer's transfer path, and the CLI's round loop all record
@@ -56,7 +63,8 @@ class PipelineStats:
     (``tools/io_bench.py`` emits the same snapshot as JSON).
     """
 
-    STAGES = ("decode", "augment", "batch", "h2d", "device_wait")
+    STAGES = ("decode", "augment", "batch", "next", "copy", "stack",
+              "h2d", "dispatch", "device_wait", "metric", "chunk")
 
     def __init__(self, window: int = 2048) -> None:
         self._window = window
@@ -163,6 +171,78 @@ _obs_registry().register_collector(_PIPELINE_STATS.collect)
 def pipeline_stats() -> PipelineStats:
     """The process-wide per-stage pipeline timing registry."""
     return _PIPELINE_STATS
+
+
+def _annotate(label: str, args: dict):
+    """An entered ``jax.profiler.TraceAnnotation``, or None in a process
+    that never imported jax (no profiler session can be open there, and
+    the io/ tools that only time iterators stay free of it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(label, **args)
+    ann.__enter__()
+    return ann
+
+
+class Stage:
+    """One timed host stage of training, with three sinks: on exit it
+    bills ``pipeline_stats().add(name, dt, rows)``; it is the
+    ``obs.trace`` span ``train.<name>`` (parent tracked per thread) when
+    ``trace_dir`` tracing is on; and it is a
+    ``jax.profiler.TraceAnnotation("train.<name>", **args)``, so in any
+    profiler session (``profile = 1``, a benchmark's) the span lies in
+    the xplane's host plane on the device trace's clock.  With neither
+    on it costs two ``perf_counter`` calls, one locked ``add`` and a
+    no-op annotation.
+
+    Use :func:`stage` as a context manager.  A stage that cannot be a
+    ``with`` block (the round loop's fence-to-fence ``chunk``) calls
+    :meth:`begin` and then :meth:`end`, or :meth:`drop` to close the
+    spans without billing."""
+
+    __slots__ = ("name", "rows", "args", "_t0", "_span", "_ann")
+
+    def __init__(self, name: str, rows: int, args: dict) -> None:
+        self.name = name
+        self.rows = rows
+        self.args = args
+
+    def begin(self) -> "Stage":
+        label = "train." + self.name
+        self._span = _obs_trace.span(label, **self.args)
+        self._span.__enter__()
+        self._ann = _annotate(label, self.args)
+        self._t0 = time.perf_counter()
+        return self
+
+    def drop(self) -> float:
+        """Close the spans; returns the seconds since :meth:`begin`."""
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._span.__exit__(None, None, None)
+        return dt
+
+    def end(self, rows: Optional[int] = None) -> float:
+        """Close the spans and bill the stage; returns its seconds."""
+        if rows is not None:
+            self.rows = rows
+        dt = self.drop()
+        _PIPELINE_STATS.add(self.name, dt, self.rows)
+        return dt
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+def stage(name: str, rows: int = 0, **args) -> Stage:
+    """``with stage("h2d", rows=n, step=first_step): ...`` — see
+    :class:`Stage`.  ``args`` go to both span systems; the stages of one
+    chunk carry its first global step as ``step``."""
+    return Stage(name, rows, args)
 
 
 class StepTimer:

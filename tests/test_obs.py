@@ -34,6 +34,28 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 import obs_dump  # noqa: E402 - the CI lane's validator, under test too
 
 
+@pytest.fixture(autouse=True)
+def _fresh_obs_state():
+    """What ran before this file in the worker must not decide its
+    outcome, nor what it leaves behind the next file's: the process-wide
+    tracer, alert evaluator and device-plane state start and end each
+    test at their defaults.  The shared registry keeps its families —
+    live code holds references to them — and renders every label-less
+    one from its birth, observed or not (obs/registry.py)."""
+    from cxxnet_tpu.obs import alerts as obs_alerts
+    from cxxnet_tpu.obs import device as obs_device
+    from cxxnet_tpu.obs import tracer
+
+    def reset():
+        tracer().reset()
+        obs_alerts.reset()
+        obs_device.reset()
+
+    reset()
+    yield
+    reset()
+
+
 # ----------------------------------------------------------------------
 # PercentileTracker (the facade over obs.PercentileWindow)
 def test_tracker_empty_window():
@@ -162,6 +184,22 @@ def test_full_registry_exposition_is_valid():
     assert 'd_rows_total{stage="decode"} 7' in text
     problems = obs_dump.validate_prometheus_text(text)
     assert problems == [], problems
+
+
+def test_label_less_families_render_from_birth():
+    """A histogram nobody has observed yet still renders its buckets:
+    the live registry holds ``train_step_device_seconds`` from the first
+    ``device_metrics()`` call, sampled or not, and a scrape of it must
+    validate whatever ran before (the order dependence ISSUE 25 found)."""
+    reg = MetricsRegistry()
+    reg.histogram("t_wait_seconds", "never observed", buckets=(0.1, 1.0))
+    reg.counter("t_events_total", "never incremented")
+    reg.histogram("t_labeled_seconds", "no child yet", labelnames=("k",))
+    text = reg.render_prometheus()
+    assert 't_wait_seconds_bucket{le="+Inf"} 0' in text
+    assert "t_wait_seconds_count 0" in text and "t_events_total 0" in text
+    probs = obs_dump.validate_prometheus_text(text)
+    assert probs == ["t_labeled_seconds: histogram with no _bucket samples"]
 
 
 def test_gauge_function_failure_yields_absent_sample():

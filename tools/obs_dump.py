@@ -65,7 +65,8 @@ _METRIC_KINDS = ("counter", "gauge", "histogram", "summary", "untyped")
 #: keys every per-round telemetry record must carry
 TELEMETRY_REQUIRED = ("ts", "round", "steps", "eval", "stages")
 #: canonical pipeline stages every record's ``stages`` must include
-TELEMETRY_STAGES = ("decode", "augment", "batch", "h2d", "device_wait")
+TELEMETRY_STAGES = ("decode", "augment", "batch", "next", "copy", "stack",
+                    "h2d", "dispatch", "device_wait", "metric", "chunk")
 
 
 def _parse_labels(text: str) -> Optional[Dict[str, str]]:
