@@ -63,6 +63,7 @@ class LearnTask:
         self.extract_node_name = ""
         self.output_format = 1
         self.scan_steps = 1
+        self._chunks = None  # io.chunk.ChunkAssembler of the scanned path
         self.gen_prompt = ""
         self.gen_prompt_file = ""
         self.gen_len = 256
@@ -1520,6 +1521,7 @@ class LearnTask:
             print(f"update round {self.start_counter - 1}", flush=True)
         from .parallel.distributed import process_info
 
+        from .io.chunk import ChunkAssembler
         from .obs import trace as obs_trace
         from .utils.profiler import pipeline_stats, stage
 
@@ -1551,7 +1553,14 @@ class LearnTask:
                                      str(self.start_counter))
         timer.clear()
         pipe_mark = time.perf_counter()  # last fence (lap start)
-        pending: List = []  # scan_steps>1: batches staged for ONE dispatch
+        # scan_steps>1: batches staged for ONE dispatch, each copied once
+        # into a [K, B, ...] host block that outlives the round and is
+        # written again only when nothing references the chunk it was
+        # handed out as (io/chunk.py)
+        if self._chunks is None:
+            self._chunks = ChunkAssembler(self.scan_steps)
+        chunks = self._chunks
+        chunks.reset()
         in_flight: List = []  # async (handle, n_steps) chunks in flight
 
         def _lap(n_steps: int) -> None:
@@ -1604,34 +1613,35 @@ class LearnTask:
             device wait, so the round statistics report the honest
             pipeline rate.  With ``eval_train = 1`` every chunk is
             synchronous (metrics fetch outputs) and the timer spans
-            just the dispatch+wait, the plain step-time metric."""
-            if not pending:
+            just the dispatch+wait, the plain step-time metric.
+
+            The chunk is the assembler's block as it stands: ``copy``
+            put each batch in its slot, ``stack`` only closes the chunk
+            (a short tail is a leading slice), and ``update_scan`` gets
+            the very bytes ``np.stack`` of the batches would hold."""
+            n = len(chunks)
+            if not n:
                 return
             tracer.step(self._global_step)
             obs_trace.step(self._global_step)
             sync_mode = bool(self.net_trainer.eval_train)
             if sync_mode:
                 timer.start()
-            if len(pending) == 1:
+            with stage("stack", rows=n * trainer.batch_size,
+                       step=trainer.epoch_counter):
+                data, labels = chunks.take()
+            if n == 1:
                 from .io.data import DataBatch as _DB
 
                 if not sync_mode:
                     _fence(drain_all=True)  # update() syncs anyway
-                self.net_trainer.update(
-                    _DB(data=pending[0][0], label=pending[0][1])
-                )
+                self.net_trainer.update(_DB(data=data[0], label=labels[0]))
                 if not sync_mode:
                     with stage("device_wait", rows=trainer.batch_size,
                                step=trainer.epoch_counter):
                         self.net_trainer.sync()
                     _lap(1)
             else:
-                import numpy as _np
-
-                with stage("stack", rows=len(pending) * trainer.batch_size,
-                           step=trainer.epoch_counter):
-                    data = _np.stack([d for d, _ in pending])
-                    labels = _np.stack([l for _, l in pending])
                 handle = self.net_trainer.update_scan(
                     data, labels,
                     sync=sync_mode,
@@ -1640,15 +1650,13 @@ class LearnTask:
                     # K-check so the async overlap stays unbroken
                     check_steps=False,
                 )
-                del data, labels
                 if not sync_mode:
-                    in_flight.append((handle, len(pending)))
+                    in_flight.append((handle, n))
                     _fence(drain_all=False)
             if sync_mode:
-                _chunk_fence(len(pending))
-                timer.stop(n_steps=len(pending))
-            self._global_step += len(pending)
-            pending.clear()
+                _chunk_fence(n)
+                timer.stop(n_steps=n)
+            self._global_step += n
 
         def _drain_in_flight() -> None:
             _fence(drain_all=True)
@@ -1701,15 +1709,11 @@ class LearnTask:
                     break
             if self.test_io == 0:
                 if scan_ok and not batch.num_batch_padd:
-                    import numpy as _np
-
-                    # copy: iterator buffers are reused by next()
+                    # the one copy: iterator buffers are reused by next()
                     with stage("copy", rows=trainer.batch_size,
                                step=trainer.epoch_counter):
-                        pending.append(
-                            (_np.array(batch.data), _np.array(batch.label))
-                        )
-                    if len(pending) >= self.scan_steps:
+                        chunks.add(batch.data, batch.label)
+                    if len(chunks) >= self.scan_steps:
                         _flush_pending()
                 else:
                     _flush_pending()  # keep update order
@@ -1786,6 +1790,9 @@ class LearnTask:
             # per-stage host-pipeline breakdown (decode/augment/batch/
             # h2d/device_wait) — prints in test_io dry-runs too, where
             # it IS the measurement
+            if chunks.allocated + chunks.recycled:
+                stage_line += (f" | chunk blocks {chunks.allocated} new "
+                               f"{chunks.recycled} recycled")
             print(
                 f"round {self.start_counter - 1:8d} pipeline: "
                 + stage_line,
@@ -1859,6 +1866,10 @@ class LearnTask:
             "eval": metrics,
             "step": timer.summary(self.net_trainer.batch_size),
             "stages": pipeline_stats().snapshot(),
+            # host blocks the scanned path's chunk assembler mapped anew
+            # and took back from its free list this round (io/chunk.py)
+            "chunks": {"allocated": self._chunks.allocated,
+                       "recycled": self._chunks.recycled},
             # device plane (doc/observability.md): programs compiled so
             # far, their estimated FLOPs/bytes, cumulative XLA compile
             # seconds, sampled step fences — lifetime totals, so per-

@@ -253,6 +253,13 @@ def validate_telemetry(path: str) -> List[str]:
                     problems.append(f"line {ln}: missing stage {st!r}")
         if not isinstance(rec.get("eval"), dict):
             problems.append(f"line {ln}: eval is not an object")
+        if "chunks" in rec:  # records older than the assembler lack it
+            ch = rec["chunks"]
+            for key in ("allocated", "recycled"):
+                v = ch.get(key) if isinstance(ch, dict) else None
+                if not isinstance(v, int) or v < 0:
+                    problems.append(
+                        f"line {ln}: chunks.{key} is not a count")
         r = rec.get("round")
         if isinstance(r, int):
             if last_round is not None and r < last_round:
