@@ -7,8 +7,9 @@ traffic file, drives ``cxxnet_tpu.cli.LearnTask.run([conf])`` (what
 ``python -m cxxnet_tpu <conf>`` runs), warms up through one whole round,
 measures fence to fence over whole chunks (``lib/window.py``), stops the
 run through the program's own SIGTERM path, compares the first chunk the
-program trained with the plain reference (``lib/reference.py``) and
-prints, last, one JSON object.  Without a TPU it exits 2 and prints no
+program trained with the configuration's plain reference (the module
+its file names, or ``references/conv_sgd.py``) and prints, last, one
+JSON object.  Without a TPU it exits 2 and prints no
 result; ``--cpu-rehearsal`` walks the same control flow at toy sizes on
 the CPU, prints no device metric and always exits non-zero.
 README.md in this directory has the layout and how to add to it.
@@ -24,6 +25,7 @@ import argparse  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
 import importlib.util  # noqa: E402
+import inspect  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -50,13 +52,60 @@ def find_cell(bench: dict, name: str) -> dict:
     raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
 
 
-def load_metric(name: str):
-    """A per-layer metric is a file of its own, found by its name."""
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def load_file(path: str, kind: str):
+    """A module of the benchmark's own, by its path."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_metric(name: str):
+    """A per-layer metric is a file of its own, found by its name."""
+    return load_file(os.path.join(HERE, "metrics", f"{name}.py"), "metric")
+
+
+def load_named(owner: dict, key: str, default: str):
+    """The seam: a configuration may name its ``reference`` and a mix its
+    ``generator``, each a file under ``benchmarks/`` given as a path from
+    the root of the checkout (``benchmarks/references/<name>.py``).  One
+    that names none gets ``default``, the code every cell ran before."""
+    rel = owner.get(key, default)
+    path = os.path.normpath(os.path.join(ROOT, rel))
+    if not path.startswith(HERE + os.sep):
+        raise SystemExit(f"{key} {rel!r} of {owner.get('name')!r} is not a "
+                         "file under benchmarks/")
+    return load_file(path, key)
+
+
+def load_reference(config: dict):
+    """``describe``, ``make_weights``, ``train_chunk``,
+    ``program_update_state``, ``step_flops``, ``step_min_bytes``."""
+    return load_named(config, "reference", "benchmarks/references/conv_sgd.py")
+
+
+def load_generator(mix: dict):
+    """``make(mix, fill, out)`` and ``check_feed(mix, fill, data, labels)``."""
+    return load_named(mix, "generator", "benchmarks/lib/traffic.py")
+
+
+def conf_globals(text: str) -> dict:
+    """The global keys of a conf text, the last value winning: the
+    ``name = value`` lines outside the ``netconfig`` block.  All the
+    harness reads of a net itself (``scan_steps``, ``input_shape``); the
+    layers are the reference module's to read."""
+    glob, inside = {}, False
+    for raw in text.splitlines():
+        line = raw.split("#", 1)[0].strip()
+        if "=" not in line:
+            continue
+        k, v = (t.strip() for t in line.split("=", 1))
+        if k == "netconfig":
+            inside = v == "start"
+        elif not inside:
+            glob[k] = v
+    return glob
 
 
 def param_index(key: str) -> int:
@@ -76,8 +125,12 @@ def net_text(config: dict, args: dict, dev: str) -> str:
     a conf text kept beside the configuration file."""
     if "builder" in config:
         mod, _, fn = config["builder"].rpartition(".")
-        return getattr(importlib.import_module(mod), fn)(
-            synthetic=False, dev=dev, **args)
+        build = getattr(importlib.import_module(mod), fn)
+        # a builder that can write an iterator block of its own is told
+        # not to: the mix brings the feed
+        own_feed = "synthetic" in inspect.signature(build).parameters
+        return build(dev=dev, **args, **({"synthetic": False} if own_feed
+                                         else {}))
     with open(os.path.join(ROOT, config["net_conf"]), "r",
               encoding="utf-8") as f:
         return f.read().format(dev=dev, **args)
@@ -93,18 +146,20 @@ def build_conf(config: dict, traffic: dict, seed: int, out: str,
     batch = int(args["batch_size"]) * int(tr.get("batch_scale", 1))
     args["batch_size"] = batch
     net = net_text(config, args, tr["dev"])
-    from benchmarks.lib import netconf
-
-    _, glob = netconf.parse_net(net)
+    glob = conf_globals(net)
     scan = int(glob.get("scan_steps", 1))
-    fill = {
-        "nsample": batch * scan * int(tr["chunks_per_round"]),
-        "input_shape": glob["input_shape"], "batch_size": batch,
-        "num_class": args["num_class"], "seed": seed, "out": out,
-    }
-    from benchmarks.lib import traffic as traffic_gen
-
-    fill.update(traffic_gen.make(tr, fill, out))
+    # a mix's template may use every key of the configuration's args
+    # (num_class, seq_len, ...) beside the harness's own
+    fill = dict(args)
+    fill.update({"nsample": batch * scan * int(tr["chunks_per_round"]),
+                 "batch_size": batch, "seed": seed, "out": out})
+    if "input_shape" in glob:
+        fill["input_shape"] = glob["input_shape"]
+    if int(tr["chunks_per_round"]) < 3:
+        raise SystemExit("a round holds at least three chunks, whatever "
+                         "makes the mix's files (lib/traffic.py has why)")
+    gen = load_generator(tr)
+    fill.update(gen.make(tr, fill, out))
     data = "\n".join(tr["conf"]).format(**fill) + "\n"
     tail = (
         "num_round = 1000000\nmax_round = 1000000\n"
@@ -118,6 +173,7 @@ def build_conf(config: dict, traffic: dict, seed: int, out: str,
         f.write(data + net + tail)
     return {"path": path, "net": net, "batch": batch, "scan": scan,
             "nsample": fill["nsample"], "fill": fill, "mix": tr,
+            "generator": gen,
             "chunks_per_round": int(tr["chunks_per_round"])}
 
 
@@ -125,8 +181,9 @@ def build_conf(config: dict, traffic: dict, seed: int, out: str,
 class Run:
     """What one run holds between the hooks and the report."""
 
-    def __init__(self, a, conf, out) -> None:
+    def __init__(self, a, conf, out, ref) -> None:
         self.a, self.conf, self.out = a, conf, out
+        self.ref = ref           # the configuration's reference module
         self.seed = fold_seed(a.seed)
         self.split = {}          # set-up split, seconds since _T0
         self.chunk_losses = []   # one array per update_scan call
@@ -145,13 +202,11 @@ class Run:
     def inject(self, task) -> None:
         import jax
 
-        from benchmarks.lib import netconf, reference
-
         self.split["net_built"] = time.perf_counter() - _T0
         tr = task.net_trainer
-        layers, glob, shapes, pshapes = netconf.describe_net(
-            self.conf["net"], self.conf["batch"])
-        made = reference.make_weights(layers, shapes, pshapes, self.seed)
+        self.net = self.ref.describe(self.conf["net"], self.conf["batch"])
+        pshapes = self.net.pshapes
+        made = self.ref.make_weights(self.net, self.seed)
         new = {}
         for key, tags in tr.params.items():
             i = param_index(key)
@@ -164,7 +219,6 @@ class Run:
         tr.params = new
         tr._rng_key = jax.random.PRNGKey(self.seed)
         tr._place_state()
-        self.net = (layers, glob, shapes, pshapes)
         self.span_wrap(tr, "_local_scan_rows")
         self.span_wrap(tr, "update_scan")
         inner = tr.update_scan
@@ -278,33 +332,9 @@ class Run:
 
 
 # ----------------------------------------------------------------------
-def check_reference(run: Run, limits: dict) -> dict:
-    """Follow the first chunk with the plain reference, from weights made
-    again from the seed, and compare (``reference.compare_chunk``)."""
-    import jax
-    import numpy as np
-
-    from benchmarks.lib import reference
-
-    layers, glob, shapes, pshapes = run.net
-    first = run.first
-    t0 = time.perf_counter()
-    weights = reference.make_weights(layers, shapes, pshapes, run.seed)
-    start = jax.device_get(weights)
-    ref_l, ref_p, ref_m = reference.train_chunk(
-        layers, glob, weights, first["data"], first["labels"],
-        jax.random.PRNGKey(run.seed))
-    prog = {
-        "losses": np.asarray(first["losses"], np.float64),
-        "params": {param_index(k): v for k, v in first["params"].items()},
-        "momentum": {param_index(k): {t: s["m"] for t, s in v.items()}
-                     for k, v in first["ustates"].items()},
-    }
-    nums = reference.compare_chunk(
-        prog, {"losses": ref_l, "params": ref_p, "momentum": ref_m}, start)
-    nums["reference_s"] = time.perf_counter() - t0
-    nums["losses_program"] = [float(x) for x in prog["losses"]]
-    nums["losses_reference"] = [float(x) for x in ref_l]
+def held_to_limits(nums: dict, limits: dict) -> bool:
+    """Each number of ``compare_chunk`` beside its limit, and whether all
+    are inside: what a run's ``correct`` and a control's test both read."""
     ok = True
     for name in ("loss_gap", "update_norm_gap", "dparam_norm_gap"):
         lim = float(limits[name])
@@ -313,10 +343,40 @@ def check_reference(run: Run, limits: dict) -> dict:
         say(f"compare {name}: {nums[name]:.6g} (limit {lim:g}) "
             f"{'ok' if good else 'OVER'}"
             + (f" at {nums[name + '_at']}" if name + "_at" in nums else ""))
-    from benchmarks.lib import traffic as traffic_gen
+    return ok
 
-    feed = traffic_gen.check_feed(run.conf["mix"], run.conf["fill"],
-                                  first["data"], first["labels"])
+
+def check_reference(run: Run, limits: dict) -> dict:
+    """Follow the first chunk with the configuration's plain reference,
+    from weights made again from the seed, and compare
+    (``lib/reference.compare_chunk``: generic on trees, one for all)."""
+    import jax
+    import numpy as np
+
+    from benchmarks.lib import reference
+
+    ref = run.ref
+    first = run.first
+    t0 = time.perf_counter()
+    weights = ref.make_weights(run.net, run.seed)
+    start = jax.device_get(weights)
+    ref_l, ref_p, ref_m = ref.train_chunk(
+        run.net, weights, first["data"], first["labels"],
+        jax.random.PRNGKey(run.seed))
+    prog = {
+        "losses": np.asarray(first["losses"], np.float64),
+        "params": {param_index(k): v for k, v in first["params"].items()},
+        "momentum": ref.program_update_state(
+            {param_index(k): v for k, v in first["ustates"].items()}),
+    }
+    nums = reference.compare_chunk(
+        prog, {"losses": ref_l, "params": ref_p, "momentum": ref_m}, start)
+    nums["reference_s"] = time.perf_counter() - t0
+    nums["losses_program"] = [float(x) for x in prog["losses"]]
+    nums["losses_reference"] = [float(x) for x in ref_l]
+    ok = held_to_limits(nums, limits)
+    feed = run.conf["generator"].check_feed(
+        run.conf["mix"], run.conf["fill"], first["data"], first["labels"])
     if feed is not None:
         lim = float(limits["feed_gap_levels"])
         good = feed["feed_gap_levels"] <= lim
@@ -399,7 +459,7 @@ def run_cell(a):
     if os.path.exists(tele_path):  # the program appends
         os.remove(tele_path)
     conf = build_conf(config, traffic, fold_seed(a.seed), out, a.cpu_rehearsal)
-    run = Run(a, conf, out)
+    run = Run(a, conf, out, load_reference(config))
     run.split["imports"] = run_split_imports
     run.split["data_made"] = time.perf_counter() - _T0
 
@@ -491,14 +551,12 @@ def run_cell(a):
             json.dump(tracered.describe(rows), f, indent=1)
         trace = tracered.reduce(rows, traced_steps)
 
-    from benchmarks.lib import netconf
-
-    layers, glob, shapes, _ = run.net
     record = {
         "window": win, "telemetry": whole, "trace": trace, "spans": spans,
         "device_at_setup": run.dev_at_setup, "device_at_stop": run.dev_at_stop,
         "memory_peak_bytes": peak_bytes, "peaks": peak,
-        "flops_per_step": netconf.step_flops(layers, shapes),
+        "flops_per_step": run.ref.step_flops(run.net),
+        "min_bytes_per_step": run.ref.step_min_bytes(run.net),
         "batch": conf["batch"], "scan": conf["scan"], "chips": cell["chips"],
         "setup_s": setup_s,
     }

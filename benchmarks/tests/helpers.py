@@ -1,5 +1,8 @@
 """Shared by the benchmark's tests: a throw-away copy of the benchmark
-with one more configuration, mix, metric and cell dropped in as files."""
+with two more configurations (a small conv net on the shipped reference;
+a small language model with a reference module of its own), their mixes
+(one with a generator of its own), a metric and their cells dropped in
+as files."""
 
 from __future__ import annotations
 
@@ -33,6 +36,33 @@ TINY_MIX = {
              "  label_width = 1", "  seed_data = {seed}", "iter = end"],
 }
 
+# another family: the program's byte-level transformer under adam at toy
+# size, on layer types lib/netconf.py does not know, with its reference
+# (data/tiny_lm_reference.py) and its feed (data/tiny_text_generator.py)
+TINY_LM_CONFIG = {
+    "name": "tiny_lm",
+    "source": "none: a throw-away two-block token model",
+    "builder": "cxxnet_tpu.models.transformer_lm_conf",
+    "reference": "benchmarks/references/tiny_lm_reference.py",
+    "args": {"batch_size": 8, "vocab": 256, "seq_len": 16, "dim": 32,
+             "nhead": 4, "nlayer": 2, "compute_dtype": "float32"},
+    "reduced": [],
+    # the copy's tools/limits.py --cpu-toy on 3 seeds: sound largest
+    # 1.6e-7 / 4.3e-6 / 4.8e-6, bfloat16 control smallest 2.0e-4 / 2.5e-3
+    # / 0.19 (CPU, PR 27); a fed token is the file's or it is not
+    "limits": {"loss_gap": 1e-5, "update_norm_gap": 1e-4,
+               "dparam_norm_gap": 1e-3, "feed_gap_levels": 0.0},
+}
+
+TINY_TEXT_MIX = {
+    "name": "tiny_text", "dev": "cpu", "batch_scale": 1,
+    "chunks_per_round": 3,
+    "generator": "benchmarks/traffic/tiny_text_generator.py",
+    "conf": ["data = train", "iter = text", "  filename = {text_file}",
+             "  seq_len = {seq_len}", "  shuffle = 1",
+             "  seed_data = {seed}", "iter = end"],
+}
+
 TINY_METRIC = '''"""Chunks in the window: a throw-away metric."""
 LAYER = "round loop"
 UNIT = "count"
@@ -59,6 +89,14 @@ def copy_with_dropins(tmp: str) -> str:
         json.dump(TINY_MIX, f)
     with open(os.path.join(dst, "metrics", "chunks_in_window.py"), "w") as f:
         f.write(TINY_METRIC)
+    shutil.copy(os.path.join(HERE, "data", "tiny_lm_reference.py"),
+                os.path.join(dst, "references", "tiny_lm_reference.py"))
+    shutil.copy(os.path.join(HERE, "data", "tiny_text_generator.py"),
+                os.path.join(dst, "traffic", "tiny_text_generator.py"))
+    with open(os.path.join(dst, "configs", "tiny_lm.json"), "w") as f:
+        json.dump(TINY_LM_CONFIG, f)
+    with open(os.path.join(dst, "traffic", "tiny_text.json"), "w") as f:
+        json.dump(TINY_TEXT_MIX, f)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     bench["configs"].append({"name": "tiny", "source": "none",
@@ -72,10 +110,17 @@ def copy_with_dropins(tmp: str) -> str:
     bench["workloads"].append({"name": "tiny_jpeg_cell", "config": "tiny",
                                "traffic": "train_jpeg", "chips": 1,
                                "why": "test"})
+    bench["configs"].append({"name": "tiny_lm", "source": "none",
+                             "file": "benchmarks/configs/tiny_lm.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "tiny_lm_cell", "config": "tiny_lm",
+                               "traffic": "tiny_text", "chips": 1,
+                               "why": "test"})
     bench["per_layer"].append({
         "name": "chunks_in_window", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "round loop",
-        "moves": "train_samples_s_chip", "workloads": ["tiny_cell"]})
+        "moves": "train_samples_s_chip",
+        "workloads": ["tiny_cell", "tiny_lm_cell"]})
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return dst

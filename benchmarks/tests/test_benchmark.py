@@ -254,8 +254,11 @@ def test_every_cell_finds_its_files_and_every_metric_its_reader(bench):
 
 
 # ----------------------------------------------------------------------
-# a whole run at toy size on the CPU, on a copy with a configuration, a
-# mix and a metric dropped in as files
+# a whole run at toy size on the CPU, on a copy with two configurations
+# (a conv net on the shipped reference; a token model with a reference
+# and a feed of its own), their mixes and a metric dropped in as files
+FAMILIES = ["tiny_cell", "tiny_lm_cell"]
+
 @pytest.fixture(scope="module")
 def copy(tmp_path_factory):
     return helpers.copy_with_dropins(str(tmp_path_factory.mktemp("bench")))
@@ -267,18 +270,24 @@ def _run(copy, seed, trace=0, cell="tiny_cell", frozen=False):
                "--trace", str(trace), "--cpu-rehearsal"], frozen=frozen)
 
 
-def test_dropped_in_files_are_picked_up_and_the_reference_agrees(copy):
-    res = _run(copy, 2147483999, trace=1)
+@pytest.mark.parametrize("cell", FAMILIES)
+def test_dropped_in_files_are_picked_up_and_the_reference_agrees(copy, cell):
+    res = _run(copy, 2147483999, trace=1, cell=cell)
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] >= 1
     # the dropped-in metric reads; the device-trace ones find no device
     # plane in a CPU trace, return nothing and are left out of the line
     assert res["metrics"]["chunks_in_window"]["value"] == res["attempted"]
     assert "device_step_ms" not in res["metrics"]
-    out = os.path.join(os.path.dirname(copy), "bench_out", "tiny_cell")
+    out = os.path.join(os.path.dirname(copy), "bench_out", cell)
     nums = json.load(open(os.path.join(
         out, "seed2147483999_trace1", "compare.json")))
     assert nums["update_norm_gap"] < 1e-3 and nums["loss_gap"] < 1e-4
+    if cell == "tiny_lm_cell":
+        # float32 on both sides, and every fed row found in the file the
+        # mix's own generator made
+        assert nums["update_norm_gap"] < 1e-4 and nums["loss_gap"] < 1e-5
+        assert nums["rows"] == 64 and nums["feed_gap_levels"] == 0
 
 
 def test_the_image_mix_packs_feeds_and_checks_its_rows(copy):
@@ -289,17 +298,54 @@ def test_the_image_mix_packs_feeds_and_checks_its_rows(copy):
     assert nums["rows"] == 16 and nums["feed_gap_levels"] <= 2
 
 
-def test_end_to_end_line_has_the_two_metrics(copy):
-    res = _run(copy, 5)
+@pytest.mark.parametrize("cell", FAMILIES)
+def test_end_to_end_line_has_the_two_metrics(copy, cell):
+    res = _run(copy, 5, cell=cell)
     assert set(res["metrics"]) == {"train_samples_s_chip", "setup_s"}
     assert res["metrics"]["train_samples_s_chip"]["value"] > 0
 
 
-def test_a_step_that_returns_its_state_unchanged_is_not_correct(copy):
+@pytest.mark.parametrize("cell", FAMILIES)
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(copy, cell):
     """The harness's look for a chip skipped (rehearsal) and the rest of
     a run driven, with the timed path broken underneath
     (``helpers.CHILD``)."""
-    assert _run(copy, 7, frozen=True)["correct"] is False
+    assert _run(copy, 7, cell=cell, frozen=True)["correct"] is False
+
+
+def test_the_copy_adds_files_and_edits_none(copy):
+    for top, _, files in os.walk(BENCH):
+        if "__pycache__" in top or top.startswith(HERE):
+            continue
+        for name in files:
+            with open(os.path.join(top, name), "rb") as f, \
+                    open(os.path.join(copy, os.path.relpath(top, BENCH),
+                                      name), "rb") as g:
+                assert f.read() == g.read(), name
+
+
+@pytest.mark.parametrize("config,lim", [
+    ("tiny", helpers.TINY_CONFIG["limits"]),
+    ("tiny_lm", helpers.TINY_LM_CONFIG["limits"])], ids=["tiny", "tiny_lm"])
+def test_the_limits_tool_walks_the_same_seam(copy, config, lim):
+    """``tools/limits.py`` of the copy, on the CPU at toy size: the
+    program's chunk, the configuration's reference and its control."""
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT,
+               JAX_ENABLE_COMPILATION_CACHE="false")
+    out = subprocess.run(
+        [sys.executable, os.path.join(copy, "tools", "limits.py"),
+         "--config", config, "--cpu-toy", "--seeds", "2", "--control", "1"],
+        cwd=os.path.dirname(copy), env=env, capture_output=True, text=True,
+        timeout=600)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    rows = json.load(open(os.path.join(
+        os.path.dirname(copy), "chiprun_out", "limits", config + ".json")))
+    for row in rows["rows"]:
+        assert all(row["sound"][k] <= lim[k] for k in
+                   ("loss_gap", "update_norm_gap", "dparam_norm_gap"))
+    assert "control" in rows["rows"][0] and "control" not in rows["rows"][1]
 
 
 # ----------------------------------------------------------------------
@@ -335,3 +381,131 @@ def test_the_fp8_control_fails_where_bf16_passes(seed):
     sound = reference.compare_chunk(chunk(jnp.bfloat16), ref, start)
     control = reference.compare_chunk(chunk(jnp.float8_e4m3fn), ref, start)
     assert control["update_norm_gap"] > 3 * sound["update_norm_gap"]
+
+
+# ----------------------------------------------------------------------
+# the seam: a file that names nothing gets the code it got before
+def test_no_accepted_file_names_a_reference_or_a_generator(bench):
+    from benchmarks import run
+
+    for c in bench["configs"]:
+        assert "reference" not in run.load_json(os.path.join(ROOT, c["file"]))
+    for w in bench["workloads"]:
+        assert "generator" not in run.load_json(os.path.join(
+            BENCH, "traffic", w["traffic"] + ".json"))
+
+
+def test_the_default_generator_is_the_shipped_one():
+    from benchmarks import run
+
+    gen = run.load_generator({})
+    assert gen.__file__ == os.path.join(BENCH, "lib", "traffic.py")
+    assert gen.check_feed({}, {}, None, None) is None
+
+
+def test_the_default_reference_hands_the_shipped_code_through(monkeypatch):
+    import jax.numpy as jnp
+
+    from benchmarks import run
+    from benchmarks.lib import reference
+
+    ref = run.load_reference({"name": "names_none"})
+    assert ref.__file__ == os.path.join(BENCH, "references", "conv_sgd.py")
+    assert ref.netconf is netconf and ref.reference is reference
+    with open(os.path.join(HERE, "data", "tiny.conf")) as f:
+        conf = f.read().format(num_class=10, input_size=16, batch_size=4,
+                               dev="cpu", compute_dtype="float32")
+    net = ref.describe(conf, 4)
+    assert tuple(net) == netconf.describe_net(conf, 4)
+    assert ref.step_flops(net) == netconf.step_flops(net.layers, net.shapes)
+    assert ref.step_min_bytes(net) == netconf.step_min_bytes(net.layers,
+                                                             net.shapes)
+    seen = []
+    monkeypatch.setattr(reference, "make_weights",
+                        lambda *a: seen.append(a) or "made")
+    monkeypatch.setattr(reference, "train_chunk",
+                        lambda *a, **kw: seen.append((a, kw)) or "trained")
+    assert ref.make_weights(net, 3) == "made"
+    assert seen.pop() == (net.layers, net.shapes, net.pshapes, 3)
+    for control, quant in ((None, None), (True, jnp.float8_e4m3fn),
+                           ("bfloat16", jnp.bfloat16)):
+        assert ref.train_chunk(net, "w", "x", "y", "k",
+                               control=control) == "trained"
+        assert seen.pop() == ((net.layers, net.glob, "w", "x", "y", "k"),
+                              {"quant": quant})
+    assert ref.program_update_state({3: {"wmat": {"m": 1, "x": 2}}}) == {
+        3: {"wmat": 1}}
+
+
+def test_a_reference_outside_the_benchmark_is_refused():
+    from benchmarks import run
+
+    with pytest.raises(SystemExit):
+        run.load_reference({"name": "x", "reference": "cxxnet_tpu/models.py"})
+
+
+@pytest.mark.parametrize("builder", ["googlenet_conf", "resnet50_conf",
+                                     "transformer_lm_conf"])
+def test_the_harness_reads_the_global_keys_the_shipped_parse_reads(builder):
+    from benchmarks import run
+    from cxxnet_tpu import models
+
+    conf = getattr(models, builder)(batch_size=2)
+    assert run.conf_globals(conf) == netconf.parse_net(conf)[1]
+
+
+def test_a_template_takes_every_key_of_the_configurations_args(tmp_path):
+    from benchmarks import run
+
+    config = dict(helpers.TINY_LM_CONFIG, args=dict(
+        helpers.TINY_LM_CONFIG["args"], seq_len=8))
+    mix = dict(helpers.TINY_TEXT_MIX, generator=os.path.relpath(
+        os.path.join(HERE, "data", "tiny_text_generator.py"), ROOT))
+    conf = run.build_conf(config, mix, 5, str(tmp_path), False)
+    text = open(conf["path"]).read()
+    assert "  seq_len = 8\n" in text and "input_shape" not in mix["conf"]
+    assert f"  filename = {tmp_path}/tokens.bin" in text
+    # 8 rows x 8 steps x 3 chunks of 8 bytes, and the last label
+    assert os.path.getsize(tmp_path / "tokens.bin") == 8 * 8 * 3 * 8 + 1
+    assert "num_class" not in conf["fill"]
+    with pytest.raises(SystemExit):
+        run.build_conf(config, dict(mix, chunks_per_round=2), 5,
+                       str(tmp_path), False)
+
+
+# ----------------------------------------------------------------------
+# the dropped-in token model's two controls: its reference, put in the
+# program's place with the causal mask left out, or one precision down,
+# must come out not correct on the configuration's own limits
+@pytest.mark.parametrize("seed", [11, 12, 13])
+@pytest.mark.parametrize("control", ["no_causal_mask", "precision"])
+def test_the_token_models_controls_are_not_correct(control, seed, monkeypatch):
+    import jax
+
+    from benchmarks import run
+    from benchmarks.lib import reference
+
+    cfg = helpers.TINY_LM_CONFIG
+    ref = run.load_reference(dict(cfg, reference=os.path.relpath(
+        os.path.join(HERE, "data", "tiny_lm_reference.py"), ROOT)))
+    net = ref.describe(run.net_text(cfg, dict(cfg["args"]), "cpu"),
+                       cfg["args"]["batch_size"])
+    data, labels = ref.seeded_chunk(net, seed, 4)
+    key = jax.random.PRNGKey(seed)
+
+    def chunk(**kw):
+        l, p, m = ref.train_chunk(net, ref.make_weights(net, seed), data,
+                                  labels, key, **kw)
+        return {"losses": l, "params": p, "momentum": m}
+
+    start = jax.device_get(ref.make_weights(net, seed))
+    plain = chunk()
+    assert run.held_to_limits(
+        reference.compare_chunk(chunk(), plain, start), cfg["limits"])
+    if control == "precision":
+        broken = chunk(control=True)
+    else:
+        monkeypatch.setattr(ref, "CAUSAL_MASK", False)
+        broken = chunk()
+    assert not run.held_to_limits(
+        reference.compare_chunk(broken, plain, start), cfg["limits"])
