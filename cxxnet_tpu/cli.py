@@ -1866,6 +1866,9 @@ class LearnTask:
             "eval": metrics,
             "step": timer.summary(self.net_trainer.batch_size),
             "stages": pipeline_stats().snapshot(),
+            # what the round's iterators counted that is no stage's
+            # time (io/tokens.py: tokens, docs, docs_cut)
+            "counters": pipeline_stats().counters(),
             # host blocks the scanned path's chunk assembler mapped anew
             # and took back from its free list this round (io/chunk.py)
             "chunks": {"allocated": self._chunks.allocated,

@@ -155,6 +155,7 @@ def create_iterator(cfg: Sequence[ConfigEntry]) -> DataIter:
     from .attach_txt import AttachTxtIterator
     from .libsvm import LibSVMIterator
     from .text import TextIterator
+    from .tokens import TokenIterator
 
     it: Optional[DataIter] = None
     for name, val in cfg:
@@ -188,6 +189,10 @@ def create_iterator(cfg: Sequence[ConfigEntry]) -> DataIter:
                 if it is not None:
                     raise ValueError("text cannot chain over another iterator")
                 it = TextIterator()
+            elif val == "tokens":
+                if it is not None:
+                    raise ValueError("tokens cannot chain over another iterator")
+                it = TokenIterator()
             elif val == "libsvm":
                 if it is not None:
                     raise ValueError("libsvm cannot chain over another iterator")

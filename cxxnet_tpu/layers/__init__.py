@@ -20,6 +20,7 @@ from . import (  # noqa: F401
     linear,
     loss,
     sequence,
+    ssm,
     structure,
 )
 from .pairtest import PairTestLayer  # noqa: F401

@@ -11,6 +11,14 @@ inputs (SURVEY §5); this follows the framework's own conventions
 * ``pos = none|learned|sin`` — positional encoding added to the token
   embedding: a trained ``(T, D)`` table (tag ``pos``, so ``pos:lr``
   scoping works) or fixed sinusoidal (Vaswani et al. 2017)
+* ``multiplier`` — the looked-up rows are scaled by it (default 1)
+
+``lm_head`` is the head that uses an embedding's matrix transposed:
+``logits = x E^T / divisor`` with ``tied = <the embedding's name>`` and
+``divisor`` (default 1).  It owns no parameter: the net hands it the
+named layer's, so the matrix is one leaf with one gradient, the sum of
+both uses (``shared[...]`` aliases a layer of the same type, which a
+head is not).
 
 Input is a flat ``(N, T)`` node of token ids (the text iterator emits
 ids as float32 — exact for any realistic vocab); output is the
@@ -54,12 +62,15 @@ class EmbeddingLayer(Layer):
         super().__init__()
         self.nvocab = 0
         self.pos = "none"
+        self.multiplier = 1.0
         self.decode = 0
         self.decode_window = 0
 
     def set_param(self, name, val):
         if name == "nvocab":
             self.nvocab = int(val)
+        elif name == "multiplier":
+            self.multiplier = float(val)
         elif name == "pos":
             if val not in ("none", "learned", "sin"):
                 raise ValueError(
@@ -121,6 +132,8 @@ class EmbeddingLayer(Layer):
         )
         table = params["wmat"]
         out = jnp.take(table, ids, axis=0)
+        if self.multiplier != 1.0:
+            out = out * jnp.asarray(self.multiplier, out.dtype)
         t, d = out.shape[1], out.shape[2]
         if self.decode:
             # absolute positions step..step+t-1 (the decode loop's clock)
@@ -140,3 +153,39 @@ class EmbeddingLayer(Layer):
         elif self.pos == "sin":
             out = out + sin_pos_table(t, d).astype(out.dtype)
         return [out]
+
+
+@register
+class LMHeadLayer(Layer):
+    type_name = "lm_head"
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.tied = ""  # FunctionalNet reads it: the layer whose params
+        self.divisor = 1.0
+
+    def set_param(self, name, val):
+        if name == "tied":
+            self.tied = val
+        elif name == "divisor":
+            self.divisor = float(val)
+        else:
+            super().set_param(name, val)
+
+    def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
+        self._check_arity(in_shapes, 1)
+        if not self.tied or self.param.num_hidden <= 0:
+            raise ValueError(
+                "lm_head: set tied (the embedding's name) and nhidden "
+                "(its nvocab)")
+        return [tuple(in_shapes[0][:-1]) + (self.param.num_hidden,)]
+
+    def apply(self, params, inputs, *, train=False, rng=None, step=None):
+        x = inputs[0]
+        table = params["wmat"]
+        if table.shape != (self.param.num_hidden, x.shape[-1]):
+            raise ValueError(
+                f"lm_head: tied matrix is {tuple(table.shape)}, the head "
+                f"needs ({self.param.num_hidden}, {x.shape[-1]})")
+        y = x @ table.astype(x.dtype).T
+        return [y / jnp.asarray(self.divisor, y.dtype)]

@@ -1,9 +1,16 @@
-"""Dense layers: fullc, fixconn, flatten.
+"""Dense layers: fullc, gated_mlp, fixconn, flatten.
 
 Parity sources:
 * fullc — ``/root/reference/src/layer/fullc_layer-inl.hpp`` (``out =
   dot(in, W^T) + bias``; W stored ``(nhidden, nin)``; init fan_in =
   W.shape[1], fan_out = W.shape[0])
+* gated_mlp — no parity source (new scope): the gated feed-forward of
+  Shazeer 2020 ("GLU Variants Improve Transformer") with silu,
+  ``(silu(x W_g) * (x W_v)) W_2``.  ``wmat`` is ``[W_g; W_v]`` fused,
+  ``(2 * nhidden, nin)``, ``wproj`` is ``(nin, nhidden)``; no biases.
+  ``prenorm`` / ``residual_scale`` / ``eps`` keep the residual branch
+  in the one layer (``sequence.Branch``), so that under ``remat`` the
+  ``2 * nhidden``-wide activation is recomputed and never kept
 * fixconn — ``/root/reference/src/layer/fixconn_layer-inl.hpp`` (frozen
   sparse weight loaded from a ``nrow ncol nnz`` + ``row col val`` text
   file; never updated)
@@ -15,10 +22,12 @@ from __future__ import annotations
 
 from typing import List, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .base import Layer, Params, Shape, register
+from .sequence import Branch
 
 
 @register
@@ -56,6 +65,43 @@ class FullConnectLayer(Layer):
         if "bias" in params:
             y = y + params["bias"].astype(x.dtype)
         return [y]
+
+
+@register
+class GatedMLPLayer(Layer, Branch):
+    type_name = "gated_mlp"
+    # cast where they are used, inside the layer's checkpoint
+    f32_tags = frozenset({"wmat", "wproj", "norm"})
+
+    def set_param(self, name, val):
+        if not self.set_branch_param(name, val):
+            super().set_param(name, val)
+
+    def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
+        self._check_arity(in_shapes, 1)
+        if len(in_shapes[0]) not in (2, 3):
+            raise ValueError(
+                "gated_mlp: input needs to be a matrix or sequence node")
+        if self.param.num_hidden <= 0:
+            raise ValueError("gated_mlp: must set nhidden correctly")
+        return [tuple(in_shapes[0])]
+
+    def init_params(self, key, in_shapes) -> Params:
+        p = self.param
+        d, nh = in_shapes[0][-1], p.num_hidden
+        k1, k2 = jax.random.split(key)
+        out = {"wmat": p.rand_init_weight(k1, (2 * nh, d), d, 2 * nh),
+               "wproj": p.rand_init_weight(k2, (d, nh), nh, d)}
+        out.update(self.branch_params(d))
+        return out
+
+    def apply(self, params, inputs, *, train=False, rng=None, step=None):
+        x = inputs[0]
+        nh = self.param.num_hidden
+        gv = self.branch_in(params, x) @ params["wmat"].astype(x.dtype).T
+        y = (jax.nn.silu(gv[..., :nh]) * gv[..., nh:]) @ params[
+            "wproj"].astype(x.dtype).T
+        return [self.branch_out(x, y)]
 
 
 @register

@@ -9,7 +9,20 @@ layout, NHWC-style batch-major nodes).  Sequence nodes are ``(N, T, D)``
 ``attention`` config keys:
 
 * ``nhead`` — number of attention heads (D % nhead == 0)
+* ``nkvhead`` — key/value heads (grouped-query attention: each serves
+  ``nhead / nkvhead`` query heads; default ``nhead``).  The fused
+  projection ``wmat`` is then ``((nhead + 2 nkvhead) * Dh, D)``
+* ``score_scale`` — the score multiplier (default ``1 / sqrt(Dh)``)
+* ``no_bias`` — 1 drops ``bias`` and ``bproj``
 * ``causal`` — 1 for autoregressive masking
+* a second input, the net's token ids ``layer[x,0->y] = attention``:
+  a token then sees only its own document (one begins after every
+  separator id 0, ``ops/ssd.doc_index``).  No positional encoding is
+  added anywhere: a net without ``pos`` on its embedding is
+  position-free
+* ``prenorm`` / ``residual_scale`` / ``eps`` — the residual branch in
+  one layer, ``y = x + residual_scale * f(rms_norm(x))``, as ``mamba2``
+  and ``gated_mlp`` have it (``Branch`` below)
 * ``seq_parallel`` — sequence/context parallelism over the mesh's
   ``model`` axis (``ops/attention.py``; off the mesh, or with a model
   axis of 1, both fall back to plain attention):
@@ -43,6 +56,52 @@ def _layer_norm(x, w, b, eps: float):
     return (
         y * w.astype(jnp.float32) + b.astype(jnp.float32)
     ).astype(x.dtype)
+
+
+def rms_norm(x, w, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * w`` over the last axis (Zhang &
+    Sennrich 2019); statistics in f32 under mixed precision."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt((xf * xf).mean(axis=-1, keepdims=True)
+                           + jnp.float32(eps))
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+class Branch:
+    """The keys of a residual branch kept in ONE layer, for the layer
+    types a pre-norm residual net is made of (``mamba2``, ``attention``,
+    ``gated_mlp``): ``prenorm = 1`` norms the input with an ``rms_norm``
+    of the layer's own (tag ``norm``, ``eps``), ``residual_scale = r``
+    returns ``x + r * f(...)`` and not ``f(...)`` alone.  Under
+    ``remat = 1`` a conf layer is one ``jax.checkpoint``: a branch in
+    one layer keeps one ``(N, T, D)`` input alive for the backward, not
+    the norm's, the mixer's and the sum's."""
+
+    prenorm = 0
+    residual_scale = 0.0
+    eps = 1e-5
+
+    def set_branch_param(self, name: str, val: str) -> bool:
+        if name == "prenorm":
+            self.prenorm = int(val)
+        elif name == "residual_scale":
+            self.residual_scale = float(val)
+        elif name == "eps":
+            self.eps = float(val)
+        else:
+            return False
+        return True
+
+    def branch_params(self, d: int) -> Params:
+        return {"norm": jnp.ones((d,), jnp.float32)} if self.prenorm else {}
+
+    def branch_in(self, params, x):
+        return rms_norm(x, params["norm"], self.eps) if self.prenorm else x
+
+    def branch_out(self, x, y):
+        if not self.residual_scale:
+            return y
+        return x + jnp.asarray(self.residual_scale, y.dtype) * y
 
 
 _FLASH_PROBE: dict = {}  # attention geometry -> None (works) | the error
@@ -117,13 +176,30 @@ def _flash_probe(t: int, tk: int, dh: int, dtype, causal: bool,
     return _FLASH_PROBE[key]
 
 
+def _check_ids_input(who: str, in_shapes: Sequence[Shape]) -> None:
+    """One input, or two: the sequence node and the net's token ids
+    ``(N, T)``, from which the layer reads where documents begin."""
+    if len(in_shapes) not in (1, 2):
+        raise ValueError(
+            f"{who}: expected 1 or 2 input(s), got {len(in_shapes)}")
+    if len(in_shapes) == 2 and tuple(in_shapes[1]) != tuple(
+            in_shapes[0][:2]):
+        raise ValueError(
+            f"{who}: the second input is the (N, T) token ids of the "
+            f"sequence node {tuple(in_shapes[0])}, got "
+            f"{tuple(in_shapes[1])}")
+
+
 @register
-class AttentionLayer(Layer):
+class AttentionLayer(Layer, Branch):
     type_name = "attention"
+    f32_tags = frozenset({"norm"})
 
     def __init__(self) -> None:
         super().__init__()
         self.nhead = 1
+        self.nkvhead = 0  # 0: as many as nhead
+        self.scale = 0.0  # 0: 1 / sqrt(Dh)
         self.causal = 0
         self.seq_parallel = 0
         self.attn_impl = "auto"
@@ -137,6 +213,12 @@ class AttentionLayer(Layer):
     def set_param(self, name, val):
         if name == "nhead":
             self.nhead = int(val)
+        elif name == "nkvhead":
+            self.nkvhead = int(val)
+        elif name == "score_scale":
+            self.scale = float(val)
+        elif self.set_branch_param(name, val):
+            pass
         elif name == "causal":
             self.causal = int(val)
         elif name == "attn_impl":
@@ -304,9 +386,15 @@ class AttentionLayer(Layer):
         )
         return [out], {"kcache": kc, "vcache": vc}
 
+    def _plain(self, n_in: int = 1) -> bool:
+        """The layer as it was before grouped heads, a stated scale and
+        documents: every path but the masked ``mha`` knows only this."""
+        return (n_in == 1 and not self.scale and not self.param.no_bias
+                and self.nkvhead in (0, self.nhead))
+
     def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
-        self._check_arity(in_shapes, 1)
-        (shape,) = in_shapes
+        _check_ids_input("attention", in_shapes)
+        shape = in_shapes[0]
         if len(shape) != 3:
             raise ValueError(
                 "attention: input must be a sequence node (N, T, D); set "
@@ -316,6 +404,19 @@ class AttentionLayer(Layer):
         if self.nhead <= 0 or d % self.nhead != 0:
             raise ValueError(
                 f"attention: nhead={self.nhead} must divide model dim {d}"
+            )
+        if self.nkvhead and self.nhead % self.nkvhead:
+            raise ValueError(
+                f"attention: nkvhead={self.nkvhead} must divide "
+                f"nhead={self.nhead}"
+            )
+        if not self._plain(len(in_shapes)) and (
+                self.seq_parallel or self.decode
+                or self.attn_impl == "pallas"):
+            raise ValueError(
+                "attention: nkvhead, score_scale, no_bias and a document input run the "
+                "masked XLA path (ops/attention.mha); seq_parallel, "
+                "decode and attn_impl = pallas know none of them"
             )
         if self.seq_parallel and self.mesh_plan is not None:
             nm = self.mesh_plan.n_model
@@ -336,18 +437,55 @@ class AttentionLayer(Layer):
         p = self.param
         k1, k2 = jax.random.split(key)
         sigma = p.init_sigma  # framework default 0.01; set via init_sigma
-        return {
+        nqkv = d + 2 * (self.nkvhead or self.nhead) * (d // self.nhead)
+        out = {
             # framework (nout, nin) layout: fused qkv then output proj
-            "wmat": jax.random.normal(k1, (3 * d, d), jnp.float32) * sigma,
-            "bias": jnp.zeros((3 * d,), jnp.float32),
+            "wmat": jax.random.normal(k1, (nqkv, d), jnp.float32) * sigma,
             "wproj": jax.random.normal(k2, (d, d), jnp.float32) * sigma,
-            "bproj": jnp.zeros((d,), jnp.float32),
         }
+        if not p.no_bias:
+            out["bias"] = jnp.zeros((nqkv,), jnp.float32)
+            out["bproj"] = jnp.zeros((d,), jnp.float32)
+        out.update(self.branch_params(d))
+        return out
+
+    def _apply_masked(self, params, x, ids):
+        """Grouped-query heads, a stated scale, documents: q, k and v
+        from the one fused projection, then ``mha`` with its mask, in
+        row blocks once the sequence is long."""
+        from ..ops.attention import mha
+        from ..ops.ssd import doc_index
+
+        n, t, d = x.shape
+        h, hk = self.nhead, self.nkvhead or self.nhead
+        dh = d // h
+        qkv = x @ params["wmat"].astype(x.dtype).T
+        if "bias" in params:
+            qkv = qkv + params["bias"].astype(x.dtype)
+        q = qkv[..., :d].reshape(n, t, h, dh)
+        k = qkv[..., d:d + hk * dh].reshape(n, t, hk, dh)
+        v = qkv[..., d + hk * dh:].reshape(n, t, hk, dh)
+        o = mha(q, k, v, causal=bool(self.causal),
+                scale=self.scale or None,
+                doc=None if ids is None else doc_index(ids),
+                block_q=512 if t >= self._AUTO_FLASH_MIN_T else 0)
+        out = o.reshape(n, t, d) @ params["wproj"].astype(x.dtype).T
+        if "bproj" in params:
+            out = out + params["bproj"].astype(x.dtype)
+        return out
 
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
-        from ..ops.attention import mha, ring_self_attention
+        x = self.branch_in(params, inputs[0])
+        if self._plain(len(inputs)):
+            y = self._apply_plain(params, x)
+        else:
+            y = self._apply_masked(
+                params, x, inputs[1] if len(inputs) > 1 else None)
+        return [self.branch_out(inputs[0], y)]
 
-        x = inputs[0]
+    def _apply_plain(self, params, x):
+        from ..ops.attention import ring_self_attention
+
         n, t, d = x.shape
         h = self.nhead
         dh = d // h
@@ -405,10 +543,10 @@ class AttentionLayer(Layer):
         else:
             o = self._local_attn()(q, k, v)
         o = o.reshape(n, t, d)
-        return [
+        return (
             o @ params["wproj"].astype(x.dtype).T
             + params["bproj"].astype(x.dtype)
-        ]
+        )
 
 
 @register
@@ -439,6 +577,34 @@ class LayerNormLayer(Layer):
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
         x = inputs[0]
         return [_layer_norm(x, params["wmat"], params["bias"], self.eps)]
+
+
+@register
+class RMSNormLayer(Layer):
+    """``rms_norm``: ``x / sqrt(mean(x^2) + eps) * wmat`` over the last
+    axis; key ``eps`` (default 1e-5)."""
+
+    type_name = "rms_norm"
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.eps = 1e-5
+
+    def set_param(self, name, val):
+        if name == "eps":
+            self.eps = float(val)
+        else:
+            super().set_param(name, val)
+
+    def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
+        self._check_arity(in_shapes, 1)
+        return [tuple(in_shapes[0])]
+
+    def init_params(self, key, in_shapes) -> Params:
+        return {"wmat": jnp.ones((in_shapes[0][-1],), jnp.float32)}
+
+    def apply(self, params, inputs, *, train=False, rng=None, step=None):
+        return [rms_norm(inputs[0], params["wmat"], self.eps)]
 
 
 @register

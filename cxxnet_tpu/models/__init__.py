@@ -19,6 +19,7 @@ Parity sources (structure, hyper-parameters, schedules):
 from .builders import (  # noqa: F401
     alexnet_conf,
     googlenet_conf,
+    granite_h_conf,
     kaggle_bowl_conf,
     mnist_conv_conf,
     mnist_mlp_conf,
@@ -44,4 +45,5 @@ MODEL_BUILDERS = {
     "kaggle_bowl": kaggle_bowl_conf,
     "transformer": transformer_conf,
     "transformer_lm": transformer_lm_conf,
+    "granite_h": granite_h_conf,
 }

@@ -442,6 +442,129 @@ def transformer_lm_conf(
     )
 
 
+# the published order of one period of granite-4.0-h-micro's stack
+# (config.json `layer_types`, layers 0-9): nine Mamba-2 layers around
+# one attention layer
+GRANITE_H_PERIOD = "mmmmmammmm"
+
+
+def granite_h_conf(
+    vocab: int = 12544,
+    seq_len: int = 8192,
+    hidden: int = 2048,
+    layer_types: str = GRANITE_H_PERIOD,
+    mamba_heads: int = 64,
+    mamba_head_dim: int = 64,
+    mamba_state: int = 128,
+    mamba_conv: int = 4,
+    mamba_chunk: int = 256,
+    attn_heads: int = 32,
+    attn_kv_heads: int = 8,
+    mlp_hidden: int = 8192,
+    embedding_multiplier: float = 12.0,
+    attention_multiplier: float = 0.015625,
+    residual_multiplier: float = 0.22,
+    logits_scaling: float = 8.0,
+    eps: float = 1e-5,
+    token_file: str = "",
+    batch_size: int = 1,
+    num_round: int = 10,
+    dev: str = "tpu",
+    compute_dtype: str = "bfloat16",
+    eta: float = 0.0003,
+    scan_steps: int = 8,
+) -> str:
+    """A Granite-4.0-H style hybrid language model (ibm-granite,
+    ``model_type: granitemoehybrid``, dense): per layer a Mamba-2 mixer
+    (``m``) or a position-free grouped-query attention (``a``), then a
+    gated MLP, every branch pre-normed by ``rms_norm`` and added back
+    times ``residual_multiplier``; the embedding scaled by
+    ``embedding_multiplier`` and tied to the head, whose logits are
+    divided by ``logits_scaling``.  The defaults are the published
+    widths of granite-4.0-h-micro, one period of its stack deep, over an
+    eighth of its vocabulary: what one 16 GB chip holds under adam.
+
+    Trains on packed token rows (``iter = tokens``) in which a document
+    begins after every separator id 0; mixers and attention read the
+    starts from the ids (their second input).  Written for memory:
+    ``remat = 1`` keeps one ``(N, T, hidden)`` input a branch, and
+    ``eval_train = 0`` because 8 steps of ``(T, vocab)`` outputs cannot
+    be fetched — chunks then run double-buffered and asynchronous.
+    """
+    data = ""
+    if token_file:
+        data = (
+            "data = train\n"
+            "iter = tokens\n"
+            f"  filename = {token_file}\n"
+            f"  seq_len = {seq_len}\n"
+            "iter = end\n"
+        )
+    branch = (f"  prenorm = 1\n  eps = {eps!r}\n"
+              f"  residual_scale = {residual_multiplier!r}\n"
+              "  init_sigma = 0.02\n")
+    s = (
+        "netconfig = start\n"
+        "layer[0->h0] = embedding:embed\n"
+        f"  nvocab = {vocab}\n"
+        f"  nhidden = {hidden}\n"
+        f"  multiplier = {embedding_multiplier!r}\n"
+        "  init_sigma = 0.02\n"
+    )
+    for i, kind in enumerate(layer_types):
+        if kind == "m":
+            s += (
+                f"layer[h{i},0->x{i}] = mamba2:mixer{i}\n"
+                f"  nhead = {mamba_heads}\n"
+                f"  head_dim = {mamba_head_dim}\n"
+                f"  nstate = {mamba_state}\n"
+                f"  conv_width = {mamba_conv}\n"
+                f"  chunk = {mamba_chunk}\n" + branch
+            )
+        elif kind == "a":
+            s += (
+                f"layer[h{i},0->x{i}] = attention:attn{i}\n"
+                f"  nhead = {attn_heads}\n"
+                f"  nkvhead = {attn_kv_heads}\n"
+                f"  score_scale = {attention_multiplier!r}\n"
+                "  causal = 1\n  no_bias = 1\n" + branch
+            )
+        else:
+            raise ValueError(
+                f"granite_h_conf: layer_types is a string of m and a, "
+                f"got {kind!r}")
+        s += (
+            f"layer[x{i}->h{i + 1}] = gated_mlp:mlp{i}\n"
+            f"  nhidden = {mlp_hidden}\n" + branch
+        )
+    s += (
+        f"layer[h{len(layer_types)}->nf] = rms_norm:norm_f\n"
+        f"  eps = {eps!r}\n"
+        "layer[nf->logits] = lm_head:head\n"
+        "  tied = embed\n"
+        f"  nhidden = {vocab}\n"
+        f"  divisor = {logits_scaling!r}\n"
+        "layer[logits->logits] = softmax\n"
+        # the mean over all positions: the loss sums over T
+        f"  grad_scale = {1.0 / seq_len!r}\n"
+        "netconfig = end\n"
+    )
+    extra = (
+        f"compute_dtype = {compute_dtype}\n"
+        f"label_width = {seq_len}\n"
+        f"label_vec[0,{seq_len}) = label\n"
+        "metric = logloss\n"
+        "updater = adam\n"
+        "wd = 0.0\n"
+        "remat = 1\n"
+        "eval_train = 0\n"
+    )
+    return data + s + _tail(
+        batch_size, f"1,1,{seq_len}", num_round, eta=eta, dev=dev,
+        extra=extra, scan_steps=scan_steps,
+    )
+
+
 def _res_bottleneck(prev: str, name: str, cin: int, cmid: int, cout: int,
                     stride: int) -> str:
     """Bottleneck residual block: 1x1 reduce -> 3x3 -> 1x1 expand, each

@@ -106,16 +106,25 @@ class FunctionalNet:
             tag = spec.name if spec.name else spec.type_name
             self.param_key.append(f"l{i}_{tag}")
         self._configure_layers()
+        # a layer that names another by ``tied = <name>`` (lm_head)
+        # computes with that layer's parameters: one key, one leaf, one
+        # gradient, the sum of both uses
+        for i, lay in enumerate(self.layer_objs):
+            if getattr(lay, "tied", "") and \
+                    graph.layers[i].type_name != "shared":
+                self.param_key[i] = self.param_key[
+                    graph.layer_index_of(lay.tied)]
         self.node_shapes: List[Optional[Tuple[int, ...]]] = []
         # params kept in f32 even under mixed precision (norm layers,
         # whose math runs in f32 — a bf16 round-trip would only lose bits)
         from ..layers.conv import BatchNormLayer
-        from ..layers.sequence import LayerNormLayer
+        from ..layers.sequence import LayerNormLayer, RMSNormLayer
 
         self._f32_param_keys = {
             self.param_key[i]
             for i, lay in enumerate(self.layer_objs)
-            if isinstance(lay, (BatchNormLayer, LayerNormLayer))
+            if isinstance(lay, (BatchNormLayer, LayerNormLayer,
+                                RMSNormLayer))
         }
         # per-tag exemptions (e.g. pipe_transformer's stacked LN params)
         self._f32_tag_map = {

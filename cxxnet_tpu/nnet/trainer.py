@@ -522,7 +522,10 @@ class NetTrainer:
                     new_p[key][tag] = w2
                     new_s[key][tag] = {"m": m2}
                     continue
-                w2, s2 = up.apply(w, g, ustates[key][tag], epoch)
+                # a profiler trace names the update's operations by the
+                # updater's type (the layers' carry l<index>_<name>)
+                with jax.named_scope(f"update_{up.type_name}"):
+                    w2, s2 = up.apply(w, g, ustates[key][tag], epoch)
                 new_p[key][tag] = w2
                 new_s[key][tag] = s2
         return new_p, new_s

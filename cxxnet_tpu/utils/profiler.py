@@ -70,6 +70,18 @@ class PipelineStats:
         self._window = window
         self._lock = threading.Lock()
         self._stages: Dict[str, list] = {}  # name -> [tracker, total_s, rows]
+        self._counts: Dict[str, int] = {}
+
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to the round's counter ``name``: what an iterator
+        counts that is no stage's time (``io/tokens.py``: tokens,
+        documents, documents cut)."""
+        with self._lock:
+            self._counts[name] = self._counts.get(name, 0) + int(n)
+
+    def counters(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._counts)
 
     def add(self, stage: str, dt_s: float, rows: int = 1) -> None:
         # the whole record happens under the lock: a concurrent reset()
@@ -94,6 +106,7 @@ class PipelineStats:
         reach."""
         with self._lock:
             self._stages = {}
+            self._counts = {}
 
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         """``{stage: {count, rows, total_s, rows_per_sec, mean_ms,

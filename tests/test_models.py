@@ -23,7 +23,13 @@ def _global_cfg(conf_text: str):
 def test_model_shapes(name):
     """Parse + init at tiny batch; checks graph wiring and shape rules."""
     builder = MODEL_BUILDERS[name]
-    if name.startswith("mnist") or name in ("kaggle_bowl", "transformer_lm"):
+    if name == "granite_h":  # the defaults are the published widths
+        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
+                       hidden=32, mamba_heads=4, mamba_head_dim=16,
+                       mamba_state=8, mamba_chunk=8, attn_heads=4,
+                       attn_kv_heads=2, mlp_hidden=48, layer_types="mam")
+    elif name.startswith("mnist") or name in ("kaggle_bowl",
+                                              "transformer_lm"):
         text = builder(batch_size=4, dev="cpu")
     else:
         text = builder(batch_size=4, dev="cpu", nsample=8)
@@ -38,6 +44,7 @@ def test_model_shapes(name):
               "googlenet": 1000, "vgg16": 1000, "vgg19": 1000,
               "kaggle_bowl": 121,
               "transformer": 10, "transformer_lm": 256,
+              "granite_h": 64,
               "resnet50": 1000, "resnet101": 1000,
               "resnet152": 1000}[name]
     assert out[-1] == expect
