@@ -45,11 +45,17 @@ from ..parallel import MeshPlan, make_mesh
 from ..parallel.distributed import fetch_array, fetch_local_rows
 from ..updater import Updater, create_updater
 from ..utils import checkpoint as ckpt
+from ..utils import metric_device
 from ..utils.checkpoint import MODEL_MAGIC, DivergenceError  # noqa: F401
 from ..utils.metric import MetricSet
-from ..utils.profiler import stage
+from ..utils.profiler import pipeline_stats, stage
 from .graph import NetGraph
 from .net import FunctionalNet
+
+# what a scanned step folds into its own key for the train metrics'
+# tie-break draws: no layer's index (net.forward folds those), so the
+# draws share no key with the step and consume nothing of its stream
+_METRIC_FOLD = 0x6D657472
 
 
 class NetTrainer:
@@ -806,8 +812,17 @@ class NetTrainer:
         ``per_step_data=False`` closes over ONE staged batch reused every
         step (synthetic/benchmark mode); otherwise ``xs`` is the
         ``[K, B, ...]`` step-stacked data/labels.
+
+        ``with_out`` (``eval_train``): each step reduces its out node to
+        the train metrics' sums (``utils/metric_device.py``) and ``ys``
+        is ``(losses [K], sums [K, n_metric])`` — no ``[K, B, ...]``
+        array leaves the program.  ``rec@n``'s tie-break is drawn from
+        the step's key folded with a constant, so the training stream is
+        the one ``with_out=False`` consumes.
         """
-        key = ("scan", n_steps, per_step_data, with_out)
+        mset = self.train_metric
+        key = ("scan", n_steps, per_step_data,
+               mset.signature() if with_out else None)
         if key not in self._jit_cache:
             updaters = dict(self.updaters)
             rep, dsh, _ = self._sh()
@@ -818,6 +833,7 @@ class NetTrainer:
             gspec = self._grad_spec()
             ukern = self._update_kernels()
             det_grad = self._det_grad_fn() if self._det_active() else None
+            label_ranges = self._label_ranges()
 
             def one_step(params, ustates, aux, data, labels, rng, epoch):
                 if det_grad is not None:
@@ -844,8 +860,12 @@ class NetTrainer:
                     k, sub = jax.random.split(k)
                     d, l = xs if per_step_data else (data, labels)
                     p, s, a, loss, out = one_step(p, s, a, d, l, sub, e)
-                    y = (loss, out) if with_out else loss
-                    return (p, s, a, k, e + 1), y
+                    if not with_out:
+                        return (p, s, a, k, e + 1), loss
+                    sums = metric_device.set_sums(
+                        mset, out, l, label_ranges,
+                        jax.random.fold_in(sub, _METRIC_FOLD))
+                    return (p, s, a, k, e + 1), (loss, sums)
 
                 carry, ys = jax.lax.scan(
                     body, (params, ustates, aux, rng, epoch),
@@ -856,7 +876,7 @@ class NetTrainer:
 
             data_sh = (sdsh, sdsh) if per_step_data else (dsh, dsh)
 
-            ys_sh = (rep, sdsh) if with_out else rep
+            ys_sh = (rep, rep) if with_out else rep
             self._jit_cache[key] = self._jit(
                 step,
                 (psh, ush, rep) + data_sh + (rep, rep),
@@ -880,10 +900,16 @@ class NetTrainer:
 
         Returns the per-step f32 losses, shape ``[K]`` — a host
         ``np.ndarray`` when ``sync=True``, a ``jax.Array`` otherwise.
-        With ``sync=False`` (requires ``eval_train`` off — per-step train
-        metrics must fetch outputs, which is a full sync, so the combo
-        raises instead of silently serializing) the losses come back as a
-        device array WITHOUT draining the dispatch queue — the caller
+        With ``eval_train`` the program also returns each step's sums of
+        the train metrics, ``[K, n_metric]`` (``_scan_step_fn``), and
+        the host adds them to ``train_metric``'s accumulators: no
+        output row is fetched.  ``sync=False`` still requires
+        ``eval_train`` off and raises otherwise — not because the sums
+        need a sync any more (they are device arrays like the losses),
+        but because nothing collects them at a later fence yet; that
+        refusal is what is left to lift.  With ``sync=False`` the
+        losses come back as a device array WITHOUT draining the
+        dispatch queue — the caller
         overlaps host work (decode/augment of the next chunk) with the
         device scan and fences later (``sync()`` or ``np.asarray`` on the
         result).  This is the two-stage ThreadBuffer overlap
@@ -896,8 +922,9 @@ class NetTrainer:
         if not sync and self.eval_train:
             raise ValueError(
                 "update_scan(sync=False) cannot overlap with eval_train: "
-                "per-step train metrics fetch the scan outputs (a full "
-                "sync); pass sync=True or set eval_train = 0"
+                "the train metrics' per-step sums are collected at the "
+                "chunk's own fence (a full sync); pass sync=True or set "
+                "eval_train = 0"
             )
         if self.update_period != 1:
             raise ValueError("update_scan requires update_period == 1")
@@ -965,7 +992,7 @@ class NetTrainer:
                         f"({sorted(set(int(v) for v in ks))}); every "
                         "process must scan the same K"
                     )
-        with_out = bool(self.eval_train)
+        with_out = bool(self.eval_train and len(self.train_metric))
         first_epoch = self.epoch_counter
         rows = k * self.batch_size
         # host stages of the chunk, on the caller's thread; with the
@@ -986,29 +1013,26 @@ class NetTrainer:
             )
             del data_dev, labels_dev
         self.epoch_counter += k
-        losses, outs = ys if with_out else (ys, None)
+        losses, sums = ys if with_out else (ys, None)
         if self.divergence_policy:
             # guard fetches the per-step losses — with sync=False this
             # serializes the async overlap (the cost of the check)
             with stage("device_wait", step=first_epoch):
                 self._guard_loss(losses, first_epoch, k)
-        if not with_out and not sync:
+        if not sync:
             return losses  # async: device array, queue not drained
         with stage("device_wait", rows=rows, step=first_epoch):
-            if with_out:
-                outs_np = self._local_scan_rows(outs)
-            losses_np = np.asarray(jax.device_get(losses))
+            losses_np, sums_np = jax.device_get((losses, sums))
         if with_out:
             with stage("metric", rows=rows, step=first_epoch):
-                labels_np = np.asarray(labels)
-                if not per_step:
-                    labels_np = np.broadcast_to(
-                        labels_np, (k,) + labels_np.shape
-                    )
-                for i in range(k):
-                    self.train_metric.add_eval(
-                        outs_np[i], labels_np[i], self._label_ranges()
-                    )
+                # a step scores every instance of its out node: the
+                # global batch, times the positions of a (N, T, V) node
+                out_shape = self.net.node_shapes[self.net.out_node_index()]
+                self.train_metric.add_sums(
+                    sums_np, int(np.prod(out_shape[:-1])))
+                stats = pipeline_stats()
+                stats.count("metric_rows", rows)
+                stats.count("metric_rows_device", rows)
         return losses_np
 
     def _stage_scan(self, x, per_step: bool):
@@ -1023,13 +1047,6 @@ class NetTrainer:
         return jax.make_array_from_process_local_data(
             self.mesh_plan.data_sharding(axis=1), np.asarray(x)
         )
-
-    @staticmethod
-    def _local_scan_rows(outs) -> np.ndarray:
-        """[K, B, ...] global scan output → this process's batch rows."""
-        if jax.process_count() == 1:
-            return np.asarray(jax.device_get(outs))
-        return fetch_local_rows(outs, axis=1)
 
     def _grad_fn(self):
         if "grad" not in self._jit_cache:
@@ -1796,15 +1813,18 @@ class NetTrainer:
                 )[:n_real]
         return cache
 
-    def _train_metric_preds(self, out, n_real, node_cache):
-        """Per-metric predictions for eval_train: the step's own output
-        for default entries, the precomputed node forwards for
-        ``metric[field,node]`` entries (no extra compute otherwise)."""
-        base = fetch_local_rows(out)[:n_real]
-        if not node_cache:
-            return base
-        cache = {None: base, **node_cache}
-        return [cache[node] for node in self.train_metric.nodes]
+    def _score_train_batch(self, out, batch, n_real, node_cache):
+        """eval_train on the per-batch path: fetch the step's output and
+        score it on the host — the step's own output for default
+        entries, the precomputed node forwards for ``metric[field,node]``
+        entries (no extra compute otherwise)."""
+        preds = fetch_local_rows(out)[:n_real]
+        if node_cache:
+            cache = {None: preds, **node_cache}
+            preds = [cache[node] for node in self.train_metric.nodes]
+        self.train_metric.add_eval(
+            preds, np.asarray(batch.label)[:n_real], self._label_ranges())
+        pipeline_stats().count("metric_rows", n_real)
 
     def _maybe_quantize(self) -> None:
         """Apply the conf's ``quant`` scheme to freshly built f32 params
@@ -1869,11 +1889,7 @@ class NetTrainer:
                 if self.divergence_policy:
                     self._guard_loss(losses, self.epoch_counter)
                 if self.eval_train:
-                    self.train_metric.add_eval(
-                        self._train_metric_preds(out, n_real, node_cache),
-                        np.asarray(batch.label)[:n_real],
-                        self._label_ranges(),
-                    )
+                    self._score_train_batch(out, batch, n_real, node_cache)
                 stepper.add_blocked(time.perf_counter() - t0)
             self.epoch_counter += 1
             obs_device.maybe_sample_step(self.epoch_counter, self.sync)
@@ -1889,11 +1905,7 @@ class NetTrainer:
             if self.divergence_policy:
                 self._guard_loss(loss, self.epoch_counter)
             if self.eval_train:
-                self.train_metric.add_eval(
-                    self._train_metric_preds(out, n_real, node_cache),
-                    np.asarray(batch.label)[:n_real],
-                    self._label_ranges(),
-                )
+                self._score_train_batch(out, batch, n_real, node_cache)
             self.epoch_counter += 1
             # sampled device fence (device_sample_every = N): every Nth
             # update blocks here and the wait lands in the
@@ -1918,11 +1930,7 @@ class NetTrainer:
             # the NaN prediction — logloss refuses one on its own)
             self._guard_loss(loss, self.epoch_counter)
         if self.eval_train:
-            self.train_metric.add_eval(
-                self._train_metric_preds(out, n_real, node_cache),
-                np.asarray(batch.label)[:n_real],
-                self._label_ranges(),
-            )
+            self._score_train_batch(out, batch, n_real, node_cache)
         if self._grad_accum is None:
             self._grad_accum = grads
         else:

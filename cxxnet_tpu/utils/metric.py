@@ -46,8 +46,13 @@ class Metric:
 
     def add_eval(self, pred: np.ndarray, label: np.ndarray) -> None:
         """pred: (N, K) scores; label: (N, L) field columns."""
-        self.sum_metric += float(self._batch_sum(pred, label))
-        self.cnt_inst += pred.shape[0]
+        self.add_sum(self._batch_sum(pred, label), pred.shape[0])
+
+    def add_sum(self, total: float, rows: int) -> None:
+        """Add a sum taken elsewhere over ``rows`` instances: a step
+        program's own reduction (``utils/metric_device.py``)."""
+        self.sum_metric += float(total)
+        self.cnt_inst += int(rows)
 
     def get(self) -> float:
         return self.sum_metric / max(self.cnt_inst, 1)
@@ -94,6 +99,13 @@ class MetricLogloss(Metric):
         if np.isnan(res).any():
             raise FloatingPointError("logloss: NaN detected!")
         return np.sum(res)
+
+    def add_sum(self, total, rows):
+        # a device sum arrives here without its rows: one NaN row is a
+        # NaN sum
+        if np.isnan(total):
+            raise FloatingPointError("logloss: NaN detected!")
+        super().add_sum(total, rows)
 
 
 class MetricRecall(Metric):
@@ -198,6 +210,13 @@ class MetricSet:
         ``pred`` is one (N, K) array applied to every metric, or a list
         with one prediction per metric (the reference's per-metric
         ``eval_req`` scores, metric.h AddEval)."""
+        for mt, p, lab in self.views(pred, labels, label_ranges):
+            mt.add_eval(p, lab)
+
+    def views(self, pred, labels, label_ranges):
+        """Yield ``(metric, (N, K) prediction, (N, L) field columns)``
+        per metric.  Slices and reshapes only, so the arrays may be
+        numpy's or a traced program's (``metric_device.set_sums``)."""
         if labels.ndim == 1:
             labels = labels[:, None]
         if isinstance(pred, (list, tuple)):
@@ -225,12 +244,21 @@ class MetricSet:
                         f" positions need a label field of width {t}, got"
                         f" columns [{a},{b})"
                     )
-                mt.add_eval(
-                    pred.reshape(n * t, v),
-                    labels[:, a:b].reshape(n * t, 1),
-                )
+                yield (mt, pred.reshape(n * t, v),
+                       labels[:, a:b].reshape(n * t, 1))
             else:
-                mt.add_eval(pred, labels[:, a:b])
+                yield mt, pred, labels[:, a:b]
+
+    def add_sums(self, sums: np.ndarray, rows: int) -> None:
+        """``sums``: ``[steps, n_metric]``, each step's sum of every
+        metric over ``rows`` instances (``metric_device.set_sums``)."""
+        for step in np.asarray(sums, np.float64):
+            for mt, total in zip(self.metrics, step):
+                mt.add_sum(total, rows)
+
+    def signature(self) -> Tuple[Tuple[str, str], ...]:
+        """What a program that computes this set's sums is built from."""
+        return tuple((m.name, f) for m, f in zip(self.metrics, self.fields))
 
     def reduce_across_processes(self) -> None:
         """Sum (sum_metric, cnt_inst) over all processes of a

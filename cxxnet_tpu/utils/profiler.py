@@ -73,9 +73,11 @@ class PipelineStats:
         self._counts: Dict[str, int] = {}
 
     def count(self, name: str, n: int = 1) -> None:
-        """Add ``n`` to the round's counter ``name``: what an iterator
-        counts that is no stage's time (``io/tokens.py``: tokens,
-        documents, documents cut)."""
+        """Add ``n`` to the round's counter ``name``: what is counted
+        and is no stage's time (``io/tokens.py``: tokens, documents,
+        documents cut; ``nnet/trainer.py``: ``metric_rows``, the rows
+        whose train metrics were scored, and ``metric_rows_device``,
+        those scored inside a step program)."""
         with self._lock:
             self._counts[name] = self._counts.get(name, 0) + int(n)
 

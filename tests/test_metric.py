@@ -2,6 +2,7 @@
 reference's random tie-break (src/utils/metric.h:150-170)."""
 
 import numpy as np
+import pytest
 
 from cxxnet_tpu.utils.metric import create_metric
 
@@ -55,8 +56,6 @@ def test_logloss_raises_on_nan_in_both_branches():
     """A diverged net must stop the run: np.clip passes NaN through, so
     the multiclass branch used to print ``logloss:nan`` round after
     round where the binary branch raised."""
-    import pytest
-
     from cxxnet_tpu.utils.metric import MetricLogloss
 
     m = MetricLogloss()
@@ -69,3 +68,150 @@ def test_logloss_raises_on_nan_in_both_branches():
         m._batch_sum(bad, label)
     with pytest.raises(FloatingPointError, match="NaN"):
         m._batch_sum(np.array([[np.nan], [0.5]], np.float32), label)
+
+
+# ----------------------------------------------------------------------
+# the device twins (utils/metric_device.py): a step program's own row
+# sums against Metric._batch_sum
+def _probs(rng, n, c):
+    p = rng.rand(n, c).astype(np.float32) + 0.05
+    return p / p.sum(axis=1, keepdims=True)
+
+
+def _case(name):
+    """(metric, pred, labels, label_ranges, exact) on tie-free rows."""
+    rng = np.random.RandomState(11)
+    n, c = 96, 20
+    pred = _probs(rng, n, c)
+    one = {"label": (0, 1)}
+    target = rng.randint(0, c, (n, 1)).astype(np.float32)
+    if name == "error_argmax":
+        return "error", pred, target, one, True
+    if name == "error_one_column":
+        return ("error", rng.randn(n, 1).astype(np.float32),
+                rng.randint(0, 2, (n, 1)).astype(np.float32), one, True)
+    if name == "rmse":
+        return ("rmse", pred, rng.rand(n, c).astype(np.float32),
+                {"label": (0, c)}, False)
+    if name == "logloss_multiclass":
+        return "logloss", pred, target, one, False
+    if name == "logloss_binary":
+        return ("logloss", _probs(rng, n, 2)[:, :1],
+                rng.randint(0, 2, (n, 1)).astype(np.float32), one, False)
+    if name == "perplexity":
+        return "perplexity", pred, target, one, False
+    if name in ("rec@1", "rec@5"):
+        return name, pred, target, one, True
+    if name == "rec@3_two_columns":
+        # the field is columns [1, 3) of a three-column label
+        labels = rng.randint(0, c, (n, 3)).astype(np.float32)
+        return "rec@3", pred, labels, {"label": (0, 1), "tags": (1, 3)}, True
+    if name == "sequence_error":
+        t = 6
+        return ("error", _probs(rng, n * t, c).reshape(n, t, c),
+                rng.randint(0, c, (n, t)).astype(np.float32),
+                {"label": (0, t)}, True)
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "error_argmax", "error_one_column", "rmse", "logloss_multiclass",
+    "logloss_binary", "perplexity", "rec@1", "rec@5", "rec@3_two_columns",
+    "sequence_error"])
+def test_device_row_sum_matches_the_host_metric(name):
+    import jax
+
+    from cxxnet_tpu.utils import metric_device
+    from cxxnet_tpu.utils.metric import MetricSet
+
+    kind, pred, labels, ranges, exact = _case(name)
+    field = "tags" if "tags" in ranges else "label"
+    host, dev = MetricSet(), MetricSet()
+    for m in (host, dev):
+        m.add_metric(kind, field)
+    host.add_eval(pred, labels, ranges)
+    sums = jax.jit(
+        lambda p, l, k: metric_device.set_sums(dev, p, l, ranges, k)
+    )(pred, labels, jax.random.PRNGKey(5))
+    assert sums.shape == (1,) and sums.dtype == np.float32
+    rows = int(np.prod(pred.shape[:-1]))
+    dev.add_sums(np.asarray(sums)[None], rows)
+    h, d = host.metrics[0], dev.metrics[0]
+    assert d.cnt_inst == h.cnt_inst == rows
+    if exact:
+        assert d.sum_metric == h.sum_metric
+        assert dev.print("train") == host.print("train")
+    else:
+        assert d.sum_metric == pytest.approx(h.sum_metric, rel=1e-6)
+
+
+def test_device_sequence_metric_checks_the_field_width():
+    import jax.numpy as jnp
+
+    from cxxnet_tpu.utils import metric_device
+    from cxxnet_tpu.utils.metric import MetricSet
+
+    ms = MetricSet()
+    ms.add_metric("error")
+    with pytest.raises(ValueError, match="label field of width 6"):
+        metric_device.set_sums(ms, jnp.zeros((4, 6, 5)), jnp.zeros((4, 5)),
+                               {"label": (0, 5)}, None)
+
+
+def test_device_rec_at_n_tiebreak():
+    """The device twins of the two tie-break tests above: a net that
+    starts at zero ties all 1000 classes and must read rec@5 = 5/1000,
+    not 1 and not 0; strictly ordered scores are not disturbed."""
+    import jax
+
+    from cxxnet_tpu.utils import metric_device
+
+    m = create_metric("rec@5")
+    n, c = 4096, 1000
+    f = jax.jit(lambda p, l, k: metric_device.row_sum(m, p, l, k))
+    key = jax.random.PRNGKey(0)
+    tied = f(np.ones((n, c), np.float32), np.full((n, 1), 7.0, np.float32),
+             key)
+    assert abs(float(tied) / n - 0.005) < 0.004
+    # seeded: the same key draws the same ties
+    assert float(tied) == float(f(np.ones((n, c), np.float32),
+                                  np.full((n, 1), 7.0, np.float32), key))
+
+    rng = np.random.RandomState(3)
+    pred = rng.rand(64, 12).astype(np.float32)
+    label = np.argmax(pred, axis=1).astype(np.float32)[:, None]
+    m1 = create_metric("rec@1")
+    assert float(metric_device.row_sum(m1, pred, label, key)) == 64.0
+    # a label outside the classes is in no top n (np.isin finds none)
+    label[:8] = 12.0
+    label[8:16] = -1.0
+    assert float(metric_device.row_sum(m1, pred, label, key)) == 48.0
+
+
+def test_update_scan_raises_on_nan_probabilities():
+    """A diverged net stops the run through the scanned path too: the
+    step's logloss sum is NaN and the host refuses it."""
+    from cxxnet_tpu import config as C
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+
+    cfg = """
+netconfig=start
+layer[+1:fc1] = fullc:fc1
+  nhidden = 4
+  init_sigma = 0.1
+layer[+0] = softmax
+netconfig=end
+input_shape = 1,1,8
+batch_size = 16
+eta = 0.1
+metric = logloss
+"""
+    tr = NetTrainer()
+    tr.set_params(C.parse_pairs(cfg))
+    tr.init_model()
+    rng = np.random.RandomState(0)
+    data = rng.randn(2, 16, 8).astype(np.float32)
+    data[1, 3, 2] = np.nan
+    labels = rng.randint(0, 4, (2, 16, 1)).astype(np.float32)
+    with pytest.raises(FloatingPointError, match="logloss: NaN detected!"):
+        tr.update_scan(data, labels)

@@ -441,22 +441,29 @@ def test_num_batch_padd_rows_masked_in_training():
                 rtol=1e-5, atol=1e-6, err_msg=f"{key}/{tag}")
 
 
+def _train_line_values(line):
+    return {k: float(v) for k, v in
+            (item.split(":") for item in line.strip().split("\t"))}
+
+
 def test_update_scan_matches_sequential_updates():
     """update_scan (lax.scan over the fused step, ONE device program)
     must advance params/epoch exactly like K sequential update() calls.
     The scan path is how a TPU training loop amortizes per-dispatch host
-    cost (doc/performance.md)."""
+    cost (doc/performance.md).  Its train metrics are summed inside the
+    program (utils/metric_device.py): on tie-free data the line they
+    print is the line the host's metrics print over the K updates."""
     K = 5
     rng = np.random.RandomState(3)
     data = rng.randn(K, 16, 8).astype(np.float32)
     w = rng.randn(8, 4).astype(np.float32)
     labels = (data @ w).argmax(-1).astype(np.float32)[..., None]
 
-    tr_seq = make_trainer()
+    tr_seq = make_trainer("metric = rec@1\n")
     for i in range(K):
         tr_seq.update(DataBatch(data=data[i], label=labels[i]))
 
-    tr_scan = make_trainer()
+    tr_scan = make_trainer("metric = rec@1\n")
     losses = tr_scan.update_scan(data, labels)
     assert losses.shape == (K,)
     assert tr_scan.epoch_counter == K == tr_seq.epoch_counter
@@ -466,9 +473,96 @@ def test_update_scan_matches_sequential_updates():
                 np.asarray(tr_seq.params[key][tag]),
                 np.asarray(tr_scan.params[key][tag]),
                 rtol=1e-5, atol=1e-5, err_msg=f"{key}/{tag}")
-    # train metrics were accumulated for all K steps
-    line = tr_scan.train_metric.print("train")
-    assert "train-error" in line
+    # train metrics were accumulated for all K steps: counts are equal,
+    # the float sum to what two programs' float32 outputs allow
+    for m_seq, m_scan in zip(tr_seq.train_metric.metrics,
+                             tr_scan.train_metric.metrics):
+        assert m_scan.cnt_inst == m_seq.cnt_inst == K * 16
+    got = _train_line_values(tr_scan.train_metric.print("train"))
+    want = _train_line_values(tr_seq.train_metric.print("train"))
+    assert list(got) == ["train-error", "train-logloss", "train-rec@1"]
+    assert got["train-error"] == want["train-error"]
+    assert got["train-rec@1"] == want["train-rec@1"]
+    assert got["train-rec@1"] == pytest.approx(1 - got["train-error"])
+    assert got["train-logloss"] == pytest.approx(want["train-logloss"],
+                                                 rel=1e-5)
+
+
+DROPOUT_CFG = MLP_CFG.replace(
+    "layer[+1:a1] = relu",
+    "layer[+1:a1] = relu\nlayer[+0] = dropout\n  threshold = 0.3",
+) + "metric = rec@1\nmetric = rec@3\n"
+
+
+def _scan_chunk(eval_train):
+    tr = NetTrainer()
+    tr.set_params(C.parse_pairs(DROPOUT_CFG + f"eval_train = {eval_train}\n"))
+    tr.init_model()
+    rng = np.random.RandomState(5)
+    data = rng.randn(4, 16, 8).astype(np.float32)
+    labels = rng.randint(0, 4, (4, 16, 1)).astype(np.float32)
+    return tr, tr.update_scan(data, labels)
+
+
+def test_update_scan_train_metrics_leave_the_training_stream_alone():
+    """rec@n's tie-break is drawn from the step's key folded with a
+    constant: with dropout on, weights, momentum and losses after a
+    chunk are bit for bit what eval_train = 0 trains from the seed."""
+    tr1, losses1 = _scan_chunk(1)
+    tr0, losses0 = _scan_chunk(0)
+    np.testing.assert_array_equal(losses1, losses0)
+    for a, b in zip(jax.tree_util.tree_leaves((tr1.params, tr1.ustates)),
+                    jax.tree_util.tree_leaves((tr0.params, tr0.ustates))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(tr1._rng_key),
+                                  np.asarray(tr0._rng_key))
+    assert tr1.train_metric.metrics[0].cnt_inst == 64
+    assert tr0.train_metric.metrics[0].cnt_inst == 0
+
+
+def test_update_scan_program_returns_no_batch_axis():
+    """The compiled scan hands back losses [K] and sums [K, n_metric]:
+    no [K, B, classes] output to fetch."""
+    import jax.numpy as jnp
+
+    tr = make_trainer("metric = rec@1\n")
+    K = 3
+    fn = tr._scan_step_fn(K, True, True)
+    # the jitted step under the device-telemetry wrapper
+    out = jax.eval_shape(
+        getattr(fn, "fn", fn), tr.params, tr.ustates, tr.aux,
+        jax.ShapeDtypeStruct((K, 16, 8), jnp.float32),
+        jax.ShapeDtypeStruct((K, 16, 1), jnp.float32),
+        tr._rng_key, jnp.asarray(0, jnp.int32))
+    losses, sums = out[-1]
+    assert losses.shape == (K,)
+    assert sums.shape == (K, 3) and sums.dtype == jnp.float32
+    # and the program is keyed by the metric set it sums
+    assert tr._scan_step_fn(K, True, True) is fn
+    tr.train_metric.add_metric("rec@2")
+    assert tr._scan_step_fn(K, True, True) is not fn
+
+
+def test_metric_rows_counters_say_where_the_train_metrics_were_scored():
+    from cxxnet_tpu.utils.profiler import pipeline_stats
+
+    x, y = toy_data(16)
+    tr = make_trainer()
+    stats = pipeline_stats()
+    stats.reset()
+    tr.update_scan(np.stack([x] * 3), np.stack([y] * 3))
+    got = stats.counters()
+    assert got["metric_rows"] == got["metric_rows_device"] == 48
+    stats.reset()
+    tr.update(DataBatch(data=x, label=y))
+    got = stats.counters()
+    assert got["metric_rows"] == 16
+    assert got.get("metric_rows_device", 0) == 0
+    stats.reset()
+    tr.eval_train = 0
+    tr.update_scan(np.stack([x] * 3), np.stack([y] * 3))
+    assert "metric_rows" not in stats.counters()
+    stats.reset()
 
 
 def test_update_scan_single_batch_mode():
