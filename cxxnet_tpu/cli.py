@@ -63,7 +63,7 @@ class LearnTask:
         self.extract_node_name = ""
         self.output_format = 1
         self.scan_steps = 1
-        self._chunks = None  # io.chunk.ChunkAssembler of the scanned path
+        self._loop = None  # train_loop.RoundLoop, made by task_train
         self.gen_prompt = ""
         self.gen_prompt_file = ""
         self.gen_len = 256
@@ -784,7 +784,7 @@ class LearnTask:
         bs = float(self.net_trainer.batch_size or 1)
         return self._start_controller(
             knobs,
-            objective=lambda: float(getattr(self, "_global_step", 0)) * bs,
+            objective=lambda: float(self._loop.global_step) * bs,
             name="train",
         )
 
@@ -1231,7 +1231,9 @@ class LearnTask:
         self._print_mesh_summary()
         obs_emit("train.start", task=self.task, round=self.start_counter,
                  num_round=self.num_round)
-        self._global_step = 0
+        from .train_loop import RoundLoop
+
+        self._loop = RoundLoop(self.scan_steps, test_io=bool(self.test_io))
         self._divergence_retries = 0
         self._lr_scale = 1.0
         # integrity plane (doc/robustness.md "Integrity plane"): one
@@ -1350,7 +1352,7 @@ class LearnTask:
             if self.elastic_member is not None:
                 self._elastic_quiet_teardown()
         tracer.close()
-        obs_trace.tracer().flush_window(self._global_step)
+        obs_trace.tracer().flush_window(self._loop.global_step)
         if self._elastic_left:
             obs_emit("mesh.left", round=self.start_counter)
             print(
@@ -1516,31 +1518,19 @@ class LearnTask:
     def _train_one_round(self, timer, tracer) -> bool:
         """Run one training round; returns False when a preemption
         request stopped the round early (single-process only — see
-        task_train), True when the round ran to completion."""
+        task_train), True when the round ran to completion.  The
+        round's entry and exit; what lies between the rewind and the
+        last fence is ``train_loop.RoundLoop``."""
         if not self.silent:
             print(f"update round {self.start_counter - 1}", flush=True)
-        from .parallel.distributed import process_info
-
-        from .io.chunk import ChunkAssembler
         from .obs import trace as obs_trace
+        from .parallel.distributed import process_info
         from .utils.profiler import pipeline_stats, stage
 
-        nproc = process_info()[1]
-        check_preempt = nproc == 1
         trainer = self.net_trainer
-        # fence to fence (benchmarks/lib/window.py's edges): a chunk's
-        # period runs from the previous fence — for a round's first
-        # chunk from here — to its own; next / copy / stack (below) and
-        # h2d / dispatch / device_wait / metric (trainer) tile it
-        chunk = stage("chunk", step=trainer.epoch_counter).begin()
-        # async data-parallel (doc/parallel.md "Async data-parallel"):
-        # per-step fences move to the round boundary — the loop must
-        # not sync after every update or the overlap is gone
-        async_on = (self.test_io == 0
-                    and self.net_trainer._async_active())
-        preempted = False
-        sample_counter = 0
-        self.net_trainer.start_round(self.start_counter)
+        loop = self._loop
+        loop.begin(trainer)  # the first chunk's period starts here
+        trainer.start_round(self.start_counter)
         pipeline_stats().reset()  # per-round stage breakdown
         with stage("next", step=trainer.epoch_counter):
             self.itr_train.before_first()
@@ -1552,239 +1542,35 @@ class LearnTask:
             self.itr_train.set_param("augment_epoch",
                                      str(self.start_counter))
         timer.clear()
-        pipe_mark = time.perf_counter()  # last fence (lap start)
-        # scan_steps>1: batches staged for ONE dispatch, each copied once
-        # into a [K, B, ...] host block that outlives the round and is
-        # written again only when nothing references the chunk it was
-        # handed out as (io/chunk.py)
-        if self._chunks is None:
-            self._chunks = ChunkAssembler(self.scan_steps)
-        chunks = self._chunks
-        chunks.reset()
-        in_flight: List = []  # async (handle, n_steps) chunks in flight
+        check_preempt = process_info()[1] == 1
 
-        def _lap(n_steps: int) -> None:
-            """Fold the span since the last fence into the timer —
-            decode + dispatch + device wait for one chunk.  The laps
-            (plus the round-end drain) tile the round's wall time
-            exactly, so samples/sec is the true PIPELINE rate (max of
-            host and device time per chunk), not just device time."""
-            nonlocal pipe_mark
-            _chunk_fence(n_steps)
-            now = time.perf_counter()
-            timer.add(now - pipe_mark, n_steps)
-            pipe_mark = now
-
-        def _chunk_fence(n_steps: int) -> None:
-            """Bill the chunk that ends at this fence and open the
-            next; called just before the timer records the fence."""
-            nonlocal chunk
-            chunk.end(rows=n_steps * trainer.batch_size)
-            if "first_fence_s" not in self._setup:
-                self._setup["first_fence_s"] = (
-                    time.perf_counter() - self._train_t0)
-            chunk = stage("chunk", step=trainer.epoch_counter).begin()
-
-        def _fence(drain_all: bool) -> None:
-            """Block on finished chunks, recording a lap per chunk.
-            ``drain_all=False`` keeps the newest chunk running — the
-            double buffer (chunk k-1 must land before k+2 stages)."""
-            import jax as _jx
-
-            while len(in_flight) > (0 if drain_all else 1):
-                handle, ns = in_flight.pop(0)
-                with stage("device_wait", rows=ns * trainer.batch_size,
-                           step=trainer.epoch_counter):
-                    _jx.block_until_ready(handle)
-                _lap(ns)
-
-        def _flush_pending() -> None:
-            """Run staged batches as one device program (lax.scan over
-            the fused step) — amortizes per-dispatch host cost
-            exactly like bench.py (doc/performance.md).
-
-            With ``eval_train = 0`` the scan dispatch is ASYNC: the
-            device chews chunk k while the host decodes/augments
-            chunk k+1 (the reference's two-stage ThreadBuffer
-            overlap, here via XLA's async dispatch queue).  At most
-            two chunks stay in flight — a double buffer — so host
-            memory stays bounded.  Timing is fence-to-fence (_lap):
-            each recorded span covers a chunk's host decode AND its
-            device wait, so the round statistics report the honest
-            pipeline rate.  With ``eval_train = 1`` every chunk is
-            synchronous (metrics fetch outputs) and the timer spans
-            just the dispatch+wait, the plain step-time metric.
-
-            The chunk is the assembler's block as it stands: ``copy``
-            put each batch in its slot, ``stack`` only closes the chunk
-            (a short tail is a leading slice), and ``update_scan`` gets
-            the very bytes ``np.stack`` of the batches would hold."""
-            n = len(chunks)
-            if not n:
-                return
-            tracer.step(self._global_step)
-            obs_trace.step(self._global_step)
-            sync_mode = bool(self.net_trainer.eval_train)
-            if sync_mode:
-                timer.start()
-            with stage("stack", rows=n * trainer.batch_size,
-                       step=trainer.epoch_counter):
-                data, labels = chunks.take()
-            if n == 1:
-                from .io.data import DataBatch as _DB
-
-                if not sync_mode:
-                    _fence(drain_all=True)  # update() syncs anyway
-                self.net_trainer.update(_DB(data=data[0], label=labels[0]))
-                if not sync_mode:
-                    with stage("device_wait", rows=trainer.batch_size,
-                               step=trainer.epoch_counter):
-                        self.net_trainer.sync()
-                    _lap(1)
-            else:
-                handle = self.net_trainer.update_scan(
-                    data, labels,
-                    sync=sync_mode,
-                    # sharded iterators guarantee equal K per process
-                    # (equal-steps contract) — skip the collective
-                    # K-check so the async overlap stays unbroken
-                    check_steps=False,
-                )
-                if not sync_mode:
-                    in_flight.append((handle, n))
-                    _fence(drain_all=False)
-            if sync_mode:
-                _chunk_fence(n)
-                timer.stop(n_steps=n)
-            self._global_step += n
-
-        def _drain_in_flight() -> None:
-            _fence(drain_all=True)
-
-        # multi-process scan is safe from the CLI: sharded train
-        # iterators run equal batch counts per round (equal-steps
-        # contract), so every process flushes identical [K, ...]
-        # stacks at the same points
-        scan_ok = (
-            self.scan_steps > 1
-            and not async_on  # the scan program is the fused sync step
-            and self.net_trainer.update_period == 1
-            and not self.net_trainer._n_extras()
-            # node-bound train metrics need the per-step node
-            # forwards only update() provides (irrelevant when
-            # eval_train is off — train metrics never run then)
-            and not (self.net_trainer.eval_train
-                     and self.net_trainer.train_metric.need_nodes())
-        )
-        # double-buffered device feed (doc/performance.md): in the
-        # per-batch path with no metric fetch in the way, batch N+1 is
-        # decoded AND transferred (stage_batch: async sharding-aware
-        # device_put) while step N still executes, then step N is
-        # fenced — h2d no longer serializes with dispatch.  The staged
-        # copy is owned (iterator buffers are reused by next()).  The
-        # timed span becomes fence-to-fence, i.e. the honest pipeline
-        # rate, exactly like the scan path.
-        db_ok = (
-            self.test_io == 0
-            and not scan_ok
-            and nproc == 1
-            and not self.net_trainer.eval_train
-        )
-        staged_next = None  # owned copy of batch N+1, H2D in flight
-        exhausted = False   # next() returned False — NEVER call it
-        # again this epoch (a ThreadBufferIterator delivers exactly one
-        # end marker per generation; a second next() would block)
-        while True:
-            if staged_next is not None:
-                batch, staged_next = staged_next, None
-            elif exhausted:
-                break
-            else:
-                with stage("next", step=trainer.epoch_counter) as st:
-                    more = self.itr_train.next()
-                    batch = (self.itr_train.value()
-                             if more and self.test_io == 0 else None)
-                    st.rows = trainer.batch_size if more else 0
-                if not more:
-                    break
-            if self.test_io == 0:
-                if scan_ok and not batch.num_batch_padd:
-                    # the one copy: iterator buffers are reused by next()
-                    with stage("copy", rows=trainer.batch_size,
-                               step=trainer.epoch_counter):
-                        chunks.add(batch.data, batch.label)
-                    if len(chunks) >= self.scan_steps:
-                        _flush_pending()
-                else:
-                    _flush_pending()  # keep update order
-                    _fence(drain_all=True)  # update()'s sync would
-                    # fence leftovers inside the timed span otherwise
-                    tracer.step(self._global_step)
-                    obs_trace.step(self._global_step)
-                    timer.start()
-                    self.net_trainer.update(batch)
-                    if not self.net_trainer.eval_train:
-                        if db_ok and not exhausted:
-                            with stage("next",
-                                       step=trainer.epoch_counter) as st:
-                                more = self.itr_train.next()
-                                st.rows = trainer.batch_size if more else 0
-                            if more:
-                                import numpy as _np
-
-                                from .io.data import DataBatch as _DB
-
-                                v = self.itr_train.value()
-                                with stage("copy", rows=trainer.batch_size,
-                                           step=trainer.epoch_counter):
-                                    staged_next = _DB(
-                                        data=_np.array(v.data),
-                                        label=_np.array(v.label),
-                                        num_batch_padd=v.num_batch_padd,
-                                        extra_data=[_np.array(e)
-                                                    for e in v.extra_data],
-                                    )
-                                self.net_trainer.stage_batch(staged_next)
-                            else:
-                                exhausted = True
-                        if not async_on:
-                            # async mode: NO per-step fence — the
-                            # dispatch pipeline runs free until the
-                            # round-boundary async_round_end below
-                            with stage("device_wait",
-                                       rows=trainer.batch_size,
-                                       step=trainer.epoch_counter):
-                                self.net_trainer.sync()
-                    _chunk_fence(1)
-                    timer.stop()
-                    self._global_step += 1
-                    pipe_mark = time.perf_counter()  # span was timed
-            sample_counter += 1
-            if (self.print_step > 0 and sample_counter % self.print_step == 0
+        def on_batch(n_batches: int) -> bool:
+            if (self.print_step > 0 and n_batches % self.print_step == 0
                     and not self.silent):
                 elapsed = int(time.time() - self._train_start)
-                print(
-                    f"round {self.start_counter - 1:8d}:"
-                    f"[{sample_counter:8d}] {elapsed} sec elapsed",
-                    flush=True,
-                )
-            if check_preempt and self._preempt.requested:
-                preempted = True
-                break
-        _flush_pending()  # tail chunk shorter than scan_steps
-        _drain_in_flight()  # round/preemption boundary: queue empty
-        chunk.drop()  # what follows the round's last fence is in no chunk
-        if async_on:
-            # round-boundary fence (and, on resync rounds, the hard
-            # barrier draining the staleness buffers); billed as one
-            # device_wait lap so the round timing stays honest
+                print(f"round {self.start_counter - 1:8d}:"
+                      f"[{n_batches:8d}] {elapsed} sec elapsed", flush=True)
+            return check_preempt and self._preempt.requested
+
+        sample_counter, preempted = loop.run(
+            self.itr_train, timer, (tracer, obs_trace), on_batch)
+        if loop.first_fence_at is not None:
+            self._setup.setdefault(
+                "first_fence_s", loop.first_fence_at - self._train_t0)
+        if self.test_io == 0 and trainer.fence_at_round_end:
+            # async data-parallel (doc/parallel.md "Async
+            # data-parallel"): the round-boundary fence (and, on resync
+            # rounds, the hard barrier draining the staleness buffers);
+            # billed as one device_wait lap so the round timing stays
+            # honest
             wait = stage("device_wait",
                          rows=sample_counter * trainer.batch_size,
                          step=trainer.epoch_counter).begin()
-            self.net_trainer.async_round_end(self.start_counter)
+            trainer.async_round_end(self.start_counter)
             timer.add(wait.end(), 0)
         if preempted:
             return False
+        chunks = loop.chunks
         stage_line = pipeline_stats().report()
         if not self.silent and stage_line:
             # per-stage host-pipeline breakdown (decode/augment/batch/
@@ -1802,21 +1588,21 @@ class LearnTask:
             if not self.silent and timer.count:
                 print(
                     f"round {self.start_counter - 1:8d}: "
-                    + timer.report(self.net_trainer.batch_size),
+                    + timer.report(trainer.batch_size),
                     flush=True,
                 )
             sys.stderr.write(f"[{self.start_counter}]")
             eval_text = ""
             if not self.itr_evals:
-                eval_text += self.net_trainer.evaluate(None, "train")
+                eval_text += trainer.evaluate(None, "train")
             for it, nm in zip(self.itr_evals, self.eval_names):
-                eval_text += self.net_trainer.evaluate(it, nm)
+                eval_text += trainer.evaluate(it, nm)
             sys.stderr.write(eval_text)
             sys.stderr.write("\n")
             sys.stderr.flush()
             self._write_telemetry(timer, eval_text, sample_counter)
             if self.test_on_server:
-                dev = self.net_trainer.check_weight_sync()
+                dev = trainer.check_weight_sync()
                 sys.stderr.write(
                     f"[{self.start_counter}]\tweight-sync:"
                     f"max_dev={dev:g} ok\n"
@@ -1871,12 +1657,11 @@ class LearnTask:
             "counters": pipeline_stats().counters(),
             # host blocks the scanned path's chunk assembler mapped anew
             # and took back from its free list this round (io/chunk.py)
-            "chunks": {"allocated": self._chunks.allocated,
-                       "recycled": self._chunks.recycled},
+            "chunks": {"allocated": self._loop.chunks.allocated,
+                       "recycled": self._loop.chunks.recycled},
             # device plane (doc/observability.md): programs compiled so
-            # far, their estimated FLOPs/bytes, cumulative XLA compile
-            # seconds, sampled step fences — lifetime totals, so per-
-            # round deltas are computable between records
+            # far, cumulative XLA compile seconds, sampled step fences —
+            # lifetime totals: deltas are computable between records
             "device": obs_device.summary(),
             # set-up by phase, lifetime too: conf parse and arming, the
             # iterators' init, trainer + model init or load, and

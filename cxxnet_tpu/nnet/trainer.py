@@ -112,7 +112,6 @@ class NetTrainer:
         self._grad_accum = None
         self._rng_key = None
         self._jit_cache: Dict[tuple, object] = {}
-        self._staged = None  # double-buffered device feed (stage_batch)
 
     # ------------------------------------------------------------------
     def set_param(self, name: str, val: str) -> None:
@@ -280,7 +279,6 @@ class NetTrainer:
         graph.configure(self.cfg)
         self.graph = graph
         self._jit_cache.clear()  # drop closures over any previous net/mesh
-        self._staged = None      # staged transfers belong to the old net
         self._async = None       # async programs close over the old net
         self.net = FunctionalNet(graph)
         if self.net.batch_size:
@@ -570,6 +568,13 @@ class NetTrainer:
         return bool(self.det_reduce and self.mesh_plan is not None
                     and self.mesh_plan.n_devices > 1)
 
+    @property
+    def fence_at_round_end(self) -> bool:
+        """Does :meth:`update` leave its fence to
+        :meth:`async_round_end`?  True with the async stepper in effect:
+        a caller that syncs after every update undoes the overlap."""
+        return self._async_active()
+
     def _async_active(self) -> bool:
         """Is the overlapped per-group exchange (``async_overlap = 1``)
         in effect?  Same 1-device no-op contract as ``det_reduce`` —
@@ -737,8 +742,8 @@ class NetTrainer:
 
         Every program is wrapped for device telemetry
         (``obs/device.py``): the first call per argument-shape
-        signature records the program's estimated FLOPs/bytes and
-        cold-call time as ``xla_program_*{kind,bucket}``, where
+        signature records the program's cold-call time as
+        ``xla_program_compile_seconds{kind,bucket}``, where
         ``bucket`` is the leading dim of argument ``data_arg``.  A
         straight pass-through when ``device_telemetry = 0``.
         """
@@ -886,15 +891,36 @@ class NetTrainer:
             )
         return self._jit_cache[key]
 
+    def scan_refusal(self) -> Optional[str]:
+        """Why a chunk cannot go through :meth:`update_scan` and has to
+        take :meth:`update` batch by batch, or None when it can: the one
+        rule the round loop asks and ``update_scan`` raises."""
+        if self.update_period != 1:
+            return "update_scan requires update_period == 1"
+        if self.fence_at_round_end:
+            return ("update_scan is the fused multi-step program — it "
+                    "cannot interleave the per-group async exchange; use "
+                    "update() (scan_steps=1) with async_overlap=1")
+        if self._n_extras():
+            return ("update_scan does not support extra_data nodes; use "
+                    "update()")
+        # node-bound train metrics need the per-step node forwards only
+        # update() provides (irrelevant when eval_train is off — train
+        # metrics never run then)
+        if self.eval_train and self.train_metric.need_nodes():
+            return ("update_scan cannot score node-bound train metrics "
+                    "(metric[field,node] with eval_train); use update()")
+        return None
+
     def update_scan(self, data, labels, n_steps: Optional[int] = None,
                     sync: bool = True, check_steps: bool = True):
         """Run K train steps in ONE dispatched device program.
 
-        Two modes, both requiring full ``batch_size`` batches and
-        ``update_period == 1`` (use :meth:`update` otherwise):
+        Two modes, both of full ``batch_size`` batches, where
+        :meth:`scan_refusal` is None (use :meth:`update` otherwise):
 
         * ``data`` of shape ``[K, B, ...]`` — each scan step consumes its
-          own micro-batch (the staged-chunk training path);
+          own micro-batch (the round loop's chunks);
         * ``data`` of shape ``[B, ...]`` with ``n_steps=K`` — the same
           staged batch is reused every step (synthetic benchmark mode).
 
@@ -903,19 +929,14 @@ class NetTrainer:
         With ``eval_train`` the program also returns each step's sums of
         the train metrics, ``[K, n_metric]`` (``_scan_step_fn``), and
         the host adds them to ``train_metric``'s accumulators: no
-        output row is fetched.  ``sync=False`` still requires
-        ``eval_train`` off and raises otherwise — not because the sums
-        need a sync any more (they are device arrays like the losses),
-        but because nothing collects them at a later fence yet; that
-        refusal is what is left to lift.  With ``sync=False`` the
-        losses come back as a device array WITHOUT draining the
-        dispatch queue — the caller
-        overlaps host work (decode/augment of the next chunk) with the
-        device scan and fences later (``sync()`` or ``np.asarray`` on the
-        result).  This is the two-stage ThreadBuffer overlap
-        (``iter_thread_imbin_x-inl.hpp:203-354``) in its TPU form: the
-        host side of the double buffer is the input pipeline, the device
-        side is the in-flight scan program.
+        output row is fetched.  ``sync=False`` returns WITHOUT draining
+        the dispatch queue: the caller overlaps host work (the next
+        chunk's decode and copy) with the device scan and fences later
+        — the two-stage ThreadBuffer overlap
+        (``iter_thread_imbin_x-inl.hpp:203-354``) in its TPU form.  It
+        still requires ``eval_train`` off: the sums are device arrays
+        like the losses, but nothing collects them at a later fence yet
+        (ROADMAP S1).
         """
         assert self.net is not None, "init_model/load_model first"
         self._check_trainable()
@@ -926,23 +947,9 @@ class NetTrainer:
                 "chunk's own fence (a full sync); pass sync=True or set "
                 "eval_train = 0"
             )
-        if self.update_period != 1:
-            raise ValueError("update_scan requires update_period == 1")
-        if self._async_active():
-            raise ValueError(
-                "update_scan is the fused multi-step program — it "
-                "cannot interleave the per-group async exchange; use "
-                "update() (scan_steps=1) with async_overlap=1"
-            )
-        if self._n_extras():
-            raise ValueError(
-                "update_scan does not support extra_data nodes; use update()"
-            )
-        if self.eval_train and self.train_metric.need_nodes():
-            raise ValueError(
-                "update_scan cannot score node-bound train metrics "
-                "(metric[field,node] with eval_train); use update()"
-            )
+        refusal = self.scan_refusal()
+        if refusal is not None:
+            raise ValueError(refusal)
         in_ndim = len(self.net.input_node_shape(self.batch_size))
         data_arr = data if hasattr(data, "ndim") else np.asarray(data)
         per_step = data_arr.ndim == in_ndim + 1
@@ -1644,8 +1651,8 @@ class NetTrainer:
         plan = self.mesh_plan
         return plan.data_sharding() if plan is not None else None
 
-    def _to_device(self, x: np.ndarray, count_rows: bool = False,
-                   own: bool = False) -> jax.Array:
+    def _to_device(self, x: np.ndarray,
+                   count_rows: bool = False) -> jax.Array:
         """Batch-major host array → (possibly multi-process) global array.
 
         Single process: explicit sharding-aware ``jax.device_put`` onto
@@ -1653,9 +1660,8 @@ class NetTrainer:
         ``jnp.asarray`` — the exact site of the bisected jaxlib
         ``batched_device_put`` flake), so the array arrives already
         placed where jit's in_shardings want it.  ``device_put`` may
-        ALIAS host memory (CPU zero-copy), so the source is copied
-        first unless ``own=True`` promises the caller's buffer is never
-        reused/mutated (iterator buffers ARE reused by ``next()``).
+        ALIAS host memory (CPU zero-copy) and iterator buffers are
+        reused by ``next()``, so the source is copied first.
         Multi-process (jax.distributed job): this process holds only its
         shard of the global batch; assemble the global array over the
         data axis (the DCN-spanning-mesh analog of the reference's
@@ -1670,68 +1676,42 @@ class NetTrainer:
         """
         rows = (x.shape[0] if count_rows and getattr(x, "ndim", 0) else 0)
         with stage("h2d", rows=rows, step=self.epoch_counter):
-            return self._place(x, own=own)
+            return self._place(x)
 
-    def _place(self, x: np.ndarray, own: bool = False) -> jax.Array:
+    def _place(self, x: np.ndarray) -> jax.Array:
         """:meth:`_to_device` without the bill."""
         if jax.process_count() == 1:
             sh = self._h2d_sharding()
             if sh is None:
                 return jnp.asarray(x)
-            src = x if own else np.array(x, copy=True)
-            return jax.device_put(src, sh)
+            return jax.device_put(np.array(x, copy=True), sh)
         return jax.make_array_from_process_local_data(
             self.mesh_plan.data_sharding(), np.asarray(x)
         )
 
-    def _transfer_batch(self, data_np, label_np, mask_np, extras_np,
-                        own: bool = False):
+    def _transfer_batch(self, data_np, label_np, mask_np, extras_np):
         """One sharding-aware H2D for a whole train batch.
 
         Single-process with a mesh: ONE batched ``jax.device_put`` of
         the (data, labels, mask, extras) pytree onto the data sharding
-        — one dispatch instead of four, and the natural unit the
-        double-buffered feed stages ahead of time.  Other
-        configurations fall back to per-array :meth:`_to_device`.
-        Returns ``(data, labels, mask, extras)`` device arrays; billed
-        to the ``h2d`` stage with the batch's row count."""
+        — one dispatch instead of four.  Other configurations fall back
+        to per-array :meth:`_to_device`.  Returns ``(data, labels, mask,
+        extras)`` device arrays; billed to the ``h2d`` stage with the
+        batch's row count."""
         sh = self._h2d_sharding()
         if jax.process_count() != 1 or sh is None:
-            data = self._to_device(data_np, count_rows=True, own=own)
-            labels = self._to_device(label_np, own=own)
-            mask = self._to_device(mask_np, own=own)
-            extras = tuple(self._to_device(e, own=own) for e in extras_np)
+            data = self._to_device(data_np, count_rows=True)
+            labels = self._to_device(label_np)
+            mask = self._to_device(mask_np)
+            extras = tuple(self._to_device(e) for e in extras_np)
             return data, labels, mask, extras
         with stage("h2d", rows=data_np.shape[0], step=self.epoch_counter):
-            leaves = (data_np, label_np, mask_np) + tuple(extras_np)
-            if not own:
-                # device_put may alias host memory (CPU zero-copy); copy
-                # anything we do not own — same cost jnp.asarray paid
-                leaves = tuple(np.array(a, copy=True) for a in leaves)
+            # device_put may alias host memory (CPU zero-copy) and the
+            # iterator reuses its buffers: copy, as jnp.asarray did
+            leaves = tuple(np.array(a, copy=True) for a in
+                           (data_np, label_np, mask_np) + tuple(extras_np))
             placed = jax.device_put(leaves, sh)
         return placed[0], placed[1], placed[2], tuple(placed[3:])
-
-    def stage_batch(self, batch: DataBatch) -> bool:
-        """Double-buffered device feed: begin the (async) H2D of the
-        NEXT batch while the current step still executes, so transfer
-        overlaps compute instead of serializing with the next dispatch.
-
-        The caller MUST own ``batch``'s arrays (no iterator buffer
-        reuse) — the transfer aliases them zero-copy where the backend
-        allows.  The staged transfer is consumed by the next
-        :meth:`update` call carrying the SAME batch object; any other
-        batch simply transfers normally and the staged arrays are
-        dropped.  Returns True when staged (single-process with a mesh
-        only — the multi-process assembly path fences internally)."""
-        if jax.process_count() != 1 or self._h2d_sharding() is None:
-            return False
-        data_np, label_np, extras_np, mask_np, n_real = (
-            self._pad_train_batch(batch)
-        )
-        arrays = self._transfer_batch(data_np, label_np, mask_np,
-                                      extras_np, own=True)
-        self._staged = (batch, arrays, n_real)
-        return True
 
     def _pad_train_batch(self, batch: DataBatch):
         """Zero-pad a short final train batch to the compiled batch size.
@@ -1856,18 +1836,12 @@ class NetTrainer:
         """One micro-batch: fwd/bwd + (every update_period-th call) update."""
         assert self.net is not None, "init_model/load_model first"
         self._check_trainable()
-        staged, self._staged = self._staged, None
-        if staged is not None and staged[0] is batch:
-            # double-buffered feed: this batch's H2D was issued by
-            # stage_batch while the PREVIOUS step executed
-            (data, labels, mask, extras), n_real = staged[1], staged[2]
-        else:
-            data_np, label_np, extras_np, mask_np, n_real = (
-                self._pad_train_batch(batch)
-            )
-            data, labels, mask, extras = self._transfer_batch(
-                data_np, label_np, mask_np, extras_np
-            )
+        data_np, label_np, extras_np, mask_np, n_real = (
+            self._pad_train_batch(batch)
+        )
+        data, labels, mask, extras = self._transfer_batch(
+            data_np, label_np, mask_np, extras_np
+        )
         step = jnp.asarray(self.epoch_counter, jnp.int32)
         node_cache = {}
         if self.eval_train and self.train_metric.need_nodes():

@@ -14,7 +14,7 @@ from any layer:
   for lifecycle facts (``event_log`` / ``event_log_max_bytes`` /
   ``event_log_backups``), with an always-on in-memory ring;
 * :mod:`~cxxnet_tpu.obs.device` — device-plane telemetry: per-program
-  XLA FLOPs/bytes, cumulative compile seconds, device-memory
+  cold-call and cumulative compile seconds, device-memory
   watermarks, sampled step fences (``device_telemetry`` /
   ``device_sample_every``);
 * :mod:`~cxxnet_tpu.obs.alerts` — declarative threshold alerts over

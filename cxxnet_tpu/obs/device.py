@@ -1,21 +1,18 @@
-"""Device-plane telemetry: XLA program costs, compile time, memory.
+"""Device-plane telemetry: XLA programs, compile time, memory.
 
 The fourth observability pillar (doc/observability.md).  The host-side
 pillars (registry / spans / events) say what the PROCESS is doing; this
-module says what the CHIP is being asked to do — per-program FLOPs and
-bytes from XLA's own cost analysis, wall-clock compile time for every
-program the trainer / serve cache / loop fine-tuner jits, live and peak
-device-memory watermarks where the backend reports them, and sampled
-per-step device timing via periodic blocking fences.  All of it lands in
-the shared metrics registry, so ``GET /metricsz`` exposes the device
-plane next to the host plane:
+module says what the CHIP is being asked to do — wall-clock compile
+time for every program the trainer / serve cache / loop fine-tuner
+jits, live and peak device-memory watermarks where the backend reports
+them, and sampled per-step device timing via periodic blocking fences.
+All of it lands in the shared metrics registry, so ``GET /metricsz``
+exposes the device plane next to the host plane:
 
-* ``xla_program_flops{kind,bucket}`` / ``xla_program_bytes{kind,bucket}``
-  — estimated FLOPs / bytes accessed of the most recently compiled
-  program of that kind and leading data dimension (``bucket``), from
-  ``Lowered.cost_analysis()`` (no extra backend compile);
-* ``xla_program_compile_seconds{kind,bucket}`` — cold-call wall time of
-  that program's first dispatch (trace + backend compile + first run);
+* ``xla_program_compile_seconds{kind,bucket}`` — cold-call wall time
+  (trace + backend compile + first run) of the first dispatch of the
+  most recent program of that kind and leading data dimension
+  (``bucket``); ``xla_programs_total{kind}`` counts them;
 * ``xla_compile_seconds_total`` / ``xla_compiles_total`` — cumulative
   backend-compile time and count, process-wide, captured exactly via
   ``jax.monitoring``'s compile-duration events (cache hits from the
@@ -32,10 +29,12 @@ plane next to the host plane:
 Instrumentation is wrapper-based and fail-open: :func:`instrument` wraps
 a jitted callable; the wrapped call is a straight pass-through except
 the FIRST call per argument-shape signature, which is timed (the cold
-call) and then re-lowered once for cost analysis.  Any failure inside
-the accounting path is event-logged once and disables that wrapper —
-telemetry must never take down the program it measures.  With
-``device_telemetry = 0`` the wrapper is a single flag check per call.
+call).  A program is lowered once, by that call; XLA's cost analysis
+is not asked (it reads 0 FLOPs on the TPU, and cost a second lowering
+of every program).  Any failure inside the accounting path is
+event-logged once — telemetry must never take down the program it
+measures.  With ``device_telemetry = 0`` the wrapper is a single flag
+check per call.
 """
 
 from __future__ import annotations
@@ -85,19 +84,15 @@ class _State:
         self.sample_every = 0
         self.lock = threading.Lock()
         self.programs = 0
-        self.flops = 0.0
-        self.bytes = 0.0
         self.compiles = 0
         self.compile_seconds = 0.0
         self.cold_call_seconds = 0.0
         self.sampled_steps = 0
         # what a program costs before and beside its backend compile:
-        # jax's own trace / lowering / cache-load durations, and this
-        # module's extra lowering for the cost analysis
+        # jax's own trace / lowering / cache-load durations
         self.trace_seconds = 0.0
         self.lower_seconds = 0.0
         self.cache_retrieval_seconds = 0.0
-        self.cost_analysis_seconds = 0.0
 
 
 _STATE = _State()
@@ -108,18 +103,6 @@ class _DeviceMetrics:
 
     def __init__(self) -> None:
         reg = obs_registry()
-        self.program_flops = reg.gauge(
-            "xla_program_flops",
-            "Estimated FLOPs of the most recently compiled XLA program "
-            "of this kind/bucket (HLO cost analysis).",
-            labelnames=("kind", "bucket"),
-        )
-        self.program_bytes = reg.gauge(
-            "xla_program_bytes",
-            "Estimated bytes accessed by the most recently compiled XLA "
-            "program of this kind/bucket.",
-            labelnames=("kind", "bucket"),
-        )
         self.program_compile = reg.gauge(
             "xla_program_compile_seconds",
             "Cold-call wall time (trace + compile + first run) of this "
@@ -189,8 +172,6 @@ def reset() -> None:
     _STATE.sample_every = 0
     with _STATE.lock:
         _STATE.programs = 0
-        _STATE.flops = 0.0
-        _STATE.bytes = 0.0
         _STATE.compiles = 0
         _STATE.compile_seconds = 0.0
         _STATE.cold_call_seconds = 0.0
@@ -198,7 +179,6 @@ def reset() -> None:
         _STATE.trace_seconds = 0.0
         _STATE.lower_seconds = 0.0
         _STATE.cache_retrieval_seconds = 0.0
-        _STATE.cost_analysis_seconds = 0.0
     with _METRICS_LOCK:
         _METRICS = None
 
@@ -331,30 +311,17 @@ def _shape_key(args) -> tuple:
     return (treedef, tuple(sig))
 
 
-def _cost_of(lowered) -> Tuple[float, float]:
-    """(flops, bytes accessed) from a Lowered's cost analysis; handles
-    the dict and list-of-dict spellings across jax versions."""
-    ca = lowered.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return 0.0, 0.0
-    return (float(ca.get("flops", 0.0) or 0.0),
-            float(ca.get("bytes accessed", 0.0) or 0.0))
-
-
 class InstrumentedJit:
     """Accounting wrapper around one jitted callable.
 
     Dispatch is untouched — every call goes to the wrapped function, so
     jax's own compilation cache (and the persistent on-disk cache)
     behaves exactly as without the wrapper.  The first call per argument
-    signature is additionally timed (the cold call, compile included)
-    and the function is re-lowered ONCE for HLO cost analysis (tracing
-    only; no second backend compile).  Everything lands in the shared
-    registry labeled ``{kind, bucket}`` where ``bucket`` is the leading
-    dimension of the designated data argument (the serve cache's
-    power-of-two bucket; the trainer's batch size / scan depth).
+    signature is additionally timed (the cold call, compile included).
+    Everything lands in the shared registry labeled ``{kind, bucket}``
+    where ``bucket`` is the leading dimension of the designated data
+    argument (the serve cache's power-of-two bucket; the trainer's
+    batch size / scan depth).
     """
 
     __slots__ = ("fn", "kind", "data_arg", "_seen", "_fast", "_lock",
@@ -420,53 +387,25 @@ class InstrumentedJit:
             if fk is not None:
                 self._fast.add(fk)
             return self.fn(*args)
-        # ALL C++-side accounting runs BEFORE the call: lowering after
-        # it would re-trace over donated (deleted) argument buffers,
-        # and HLO cost analysis after it runs concurrently with the
-        # program's own first, async-dispatched execution — both were
-        # observed as rare segfaults on the CPU backend.  Lowering and
-        # cost analysis are abstract (avals and HLO only, no buffers),
-        # so running them first costs one extra trace per program and
-        # nothing else; everything after the call is pure-Python
-        # metric/event writes.
-        cost = None
-        t0 = time.perf_counter()
-        try:
-            cost = _cost_of(self.fn.lower(*args))
-        except Exception as e:  # noqa: BLE001 - accounting is best-effort
-            obs_events.log_exception_once(
-                f"obs.device.lower:{self.kind}", e,
-                kind="obs.device_error", program=self.kind)
-        with _STATE.lock:
-            _STATE.cost_analysis_seconds += time.perf_counter() - t0
         bucket = self._bucket(args)
         t0 = time.perf_counter()
         out = self.fn(*args)
         cold_s = time.perf_counter() - t0
-        if cost is not None:
-            try:
-                self._account(cost, bucket, cold_s)
-            except Exception as e:  # noqa: BLE001 - best-effort
-                obs_events.log_exception_once(
-                    f"obs.device.account:{self.kind}", e,
-                    kind="obs.device_error", program=self.kind)
+        try:  # after the call: pure-Python metric and event writes
+            m = device_metrics()
+            m.program_compile.labels(kind=self.kind,
+                                     bucket=bucket).set(cold_s)
+            m.programs.labels(kind=self.kind).inc()
+            with _STATE.lock:
+                _STATE.programs += 1
+                _STATE.cold_call_seconds += cold_s
+            obs_events.emit("device.program", kind=self.kind,
+                            bucket=bucket, cold_call_s=cold_s)
+        except Exception as e:  # noqa: BLE001 - best-effort
+            obs_events.log_exception_once(
+                f"obs.device.account:{self.kind}", e,
+                kind="obs.device_error", program=self.kind)
         return out
-
-    def _account(self, cost: Tuple[float, float], bucket: str,
-                 cold_s: float) -> None:
-        flops, nbytes = cost
-        m = device_metrics()
-        m.program_flops.labels(kind=self.kind, bucket=bucket).set(flops)
-        m.program_bytes.labels(kind=self.kind, bucket=bucket).set(nbytes)
-        m.program_compile.labels(kind=self.kind, bucket=bucket).set(cold_s)
-        m.programs.labels(kind=self.kind).inc()
-        with _STATE.lock:
-            _STATE.programs += 1
-            _STATE.flops += flops
-            _STATE.bytes += nbytes
-            _STATE.cold_call_seconds += cold_s
-        obs_events.emit("device.program", kind=self.kind, bucket=bucket,
-                        flops=flops, bytes=nbytes, cold_call_s=cold_s)
 
 
 def instrument(fn: Callable, kind: str,
@@ -563,16 +502,12 @@ def maybe_sample_step(step: int, sync_fn: Callable[[], None]) -> bool:
 # ----------------------------------------------------------------------
 def summary() -> Dict[str, float]:
     """Lifetime totals for the per-round telemetry record (cli.py):
-    programs instrumented, estimated FLOPs/bytes across them, backend
-    compiles and their cumulative seconds, sampled fences, and the
-    seconds jax spent tracing, lowering and loading programs from the
-    persistent cache, beside this module's own extra lowering for the
-    cost analysis (``cost_analysis_seconds``)."""
+    programs instrumented, backend compiles and their cumulative
+    seconds, sampled fences, and the seconds jax spent tracing,
+    lowering and loading programs from the persistent cache."""
     with _STATE.lock:
         return {
             "programs": _STATE.programs,
-            "flops": _STATE.flops,
-            "bytes": _STATE.bytes,
             "compiles": _STATE.compiles,
             "compile_seconds": round(_STATE.compile_seconds, 6),
             "cold_call_seconds": round(_STATE.cold_call_seconds, 6),
@@ -581,6 +516,4 @@ def summary() -> Dict[str, float]:
             "lower_seconds": round(_STATE.lower_seconds, 6),
             "cache_retrieval_seconds": round(
                 _STATE.cache_retrieval_seconds, 6),
-            "cost_analysis_seconds": round(
-                _STATE.cost_analysis_seconds, 6),
         }

@@ -615,7 +615,13 @@ class ReplicaSupervisor:
         with self._lock:
             if role is not None:
                 r.role = role
-            r.state = "gone"
+            # ``backoff`` with no restart time: the probe loop leaves
+            # the replica alone until _spawn below.  As ``gone``, a
+            # sweep landing between the kill and the spawn saw a dead
+            # process, recorded a crash over ``reason`` and scheduled a
+            # restart of its own
+            r.state = "backoff"
+            r.restart_at = float("inf")
             r.down_reason = reason
             if r.down_since is None:
                 r.down_since = time.monotonic()

@@ -1,0 +1,212 @@
+"""The round loop: one round's feed, from the train iterator to the fence.
+
+    iterator --next--> batch --copy--> ChunkAssembler --stack--> [K,B,...]
+        --update_scan--> step program --fence--> timer
+
+``scan_steps`` batches are copied once each into a recycled host block
+(io/chunk.py) and run as ONE device program (``NetTrainer.update_scan``,
+a ``lax.scan`` over the fused step): host dispatch cost is per program,
+not per step.  What cannot be scanned — the trainer says what
+(``scan_refusal``), and a padded tail batch — goes through ``update``
+one batch at a time, after the open chunk, so update order is kept.
+
+With ``eval_train = 0`` the scan dispatch is asynchronous: the device
+chews chunk k while the host decodes and copies chunk k+1 (the
+reference's two-stage ThreadBuffer overlap, here through XLA's dispatch
+queue), and chunk k is fenced only after k+1 is dispatched — at most
+two chunks in flight, so host memory stays bounded.  With ``eval_train
+= 1`` every chunk is synchronous: its metric sums are fetched at its
+own fence.
+
+A fence ends a ``chunk`` stage (the fence-to-fence period that ``next``
+/ ``copy`` / ``stack`` here and ``h2d`` / ``dispatch`` / ``device_wait``
+/ ``metric`` in the trainer tile; benchmarks/lib/window.py takes its
+edges from the same fences) and is one ``timer.add(dt, n_steps)``, in
+:meth:`RoundLoop._lap` and nowhere else.
+
+This module knows the trainer by its public methods only and nothing of
+the CLI: ``cli.LearnTask._train_one_round`` is the round's entry and
+exit, and calls in here for what lies between.
+"""
+
+from __future__ import annotations
+
+import time
+from typing import Callable, List, Optional, Sequence, Tuple
+
+import jax
+
+from .io.chunk import ChunkAssembler
+from .io.data import DataBatch
+from .utils.profiler import stage
+
+
+class RoundLoop:
+    """One per task, kept across rounds: the assembler's blocks outlive
+    a round, ``global_step`` counts the task's steps, ``first_fence_at``
+    is the ``perf_counter`` of the task's first fence.
+
+    A round is ``begin(trainer)`` — its first chunk's period starts
+    there, so the caller's rewind of the iterator lies inside it — and
+    ``run(itr, timer, ...)``.  ``update_scan`` / ``update`` / ``sync``
+    are looked up on the trainer at each call: whoever replaced one on
+    the instance is called."""
+
+    def __init__(self, scan_steps: int, test_io: bool = False) -> None:
+        self.scan_steps = int(scan_steps)
+        self.test_io = bool(test_io)  # pull batches, train nothing
+        self.chunks = ChunkAssembler(self.scan_steps)
+        self.global_step = 0
+        self.first_fence_at: Optional[float] = None
+        # the round in progress
+        self.trainer = None
+        self.timer = None
+        self.tracers: Sequence = ()
+        self.in_flight: List[Tuple[object, int]] = []  # (handle, n_steps)
+        self.chunk = None       # the open ``chunk`` stage
+        self.pipe_mark = 0.0    # the last fence
+
+    def begin(self, trainer) -> None:
+        """Open the round and its first chunk's period."""
+        self.trainer = trainer
+        self.chunks.reset()
+        self.in_flight = []
+        self.chunk = stage("chunk", step=trainer.epoch_counter).begin()
+
+    def run(self, itr, timer, tracers: Sequence = (),
+            on_batch: Optional[Callable[[int], bool]] = None,
+            ) -> Tuple[int, bool]:
+        """Feed the round's batches; returns ``(batches taken, stopped
+        early)``.  ``tracers`` each get ``.step(global_step)`` before a
+        dispatch.  ``on_batch(n)`` is called at every batch boundary and
+        stops the round there by returning True: no further batch is
+        taken, the open chunk is trained and everything in flight is
+        fenced before this returns."""
+        trainer = self.trainer
+        self.timer, self.tracers = timer, tracers
+        self.pipe_mark = time.perf_counter()
+        # multi-process scan is safe: sharded train iterators run equal
+        # batch counts per round (equal-steps contract), so every
+        # process flushes identical [K, ...] stacks at the same points
+        scan = (not self.test_io and self.scan_steps > 1
+                and trainer.scan_refusal() is None)
+        n_batches, stopped = 0, False
+        while not stopped:
+            with stage("next", step=trainer.epoch_counter) as st:
+                more = itr.next()
+                batch = itr.value() if more and not self.test_io else None
+                st.rows = trainer.batch_size if more else 0
+            if not more:
+                break
+            if batch is None:
+                pass  # test_io: the pull was the work
+            elif scan and not batch.num_batch_padd:
+                # the one copy: iterator buffers are reused by next()
+                with stage("copy", rows=trainer.batch_size,
+                           step=trainer.epoch_counter):
+                    self.chunks.add(batch.data, batch.label)
+                if len(self.chunks) >= self.scan_steps:
+                    self._flush()
+            else:
+                self._step(batch)
+            n_batches += 1
+            stopped = on_batch is not None and bool(on_batch(n_batches))
+        self._flush()  # tail chunk shorter than scan_steps
+        self._fence(drain_all=True)  # round boundary: the queue is empty
+        self.chunk.drop()  # what follows the last fence is in no chunk
+        return n_batches, stopped
+
+    # ------------------------------------------------------------------
+    def _lap(self, n_steps: int, since: Optional[float] = None) -> None:
+        """A fence: bill the chunk that ends here, open the next and
+        give the timer its span — the one place the timer is written,
+        and so the one place that says what it records in each mode.
+        ``since=None`` is fence to fence (asynchronous chunks and the
+        drain): decode, copy, dispatch and device wait of one chunk, so
+        the laps tile the round's wall time and samples/sec is the
+        PIPELINE rate.  ``since=t`` is a synchronous span from ``t``
+        (``stack`` + ``update_scan``, or ``update`` + ``sync``): the
+        step time, with the host's feed before ``t`` in no span."""
+        trainer = self.trainer
+        self.chunk.end(rows=n_steps * trainer.batch_size)
+        self.chunk = stage("chunk", step=trainer.epoch_counter).begin()
+        now = time.perf_counter()
+        if self.first_fence_at is None:
+            self.first_fence_at = now
+        self.timer.add(now - (self.pipe_mark if since is None else since),
+                       n_steps)
+        self.pipe_mark = now
+
+    def _fence(self, drain_all: bool) -> None:
+        """Block on dispatched chunks, oldest first, a lap each.
+        ``drain_all=False`` keeps the newest running — the double
+        buffer: chunk k lands only after k+1 is dispatched."""
+        trainer = self.trainer
+        while len(self.in_flight) > (0 if drain_all else 1):
+            handle, n = self.in_flight.pop(0)
+            with stage("device_wait", rows=n * trainer.batch_size,
+                       step=trainer.epoch_counter):
+                jax.block_until_ready(handle)
+            self._lap(n)
+
+    def _mark_step(self) -> None:
+        for tracer in self.tracers:
+            tracer.step(self.global_step)
+
+    def _flush(self) -> None:
+        """The open chunk as one device program.  The chunk is the
+        assembler's block as it stands: ``copy`` put each batch in its
+        slot, ``stack`` only closes it (a short tail is a leading
+        slice), and ``update_scan`` gets the very bytes ``np.stack`` of
+        the batches would hold.  A one-batch tail goes through
+        ``update``: a scan of one step would be a program of its own."""
+        n = len(self.chunks)
+        if not n:
+            return
+        trainer = self.trainer
+        self._mark_step()
+        sync_mode = bool(trainer.eval_train)
+        since = time.perf_counter() if sync_mode else None
+        with stage("stack", rows=n * trainer.batch_size,
+                   step=trainer.epoch_counter):
+            data, labels = self.chunks.take()
+        if n == 1:
+            if not sync_mode:
+                self._fence(drain_all=True)  # update()'s sync would anyway
+            self._update(DataBatch(data=data[0], label=labels[0]), since)
+        else:
+            handle = trainer.update_scan(
+                data, labels, sync=sync_mode,
+                # sharded iterators guarantee equal K per process — skip
+                # the collective K-check so the overlap stays unbroken
+                check_steps=False)
+            if sync_mode:
+                self._lap(n, since)
+            else:
+                self.in_flight.append((handle, n))
+                self._fence(drain_all=False)
+        self.global_step += n
+
+    def _step(self, batch) -> None:
+        """One batch through ``update``: the only path for
+        ``update_period > 1``, extras, node-bound train metrics, the
+        async stepper and a padded tail batch."""
+        self._flush()  # keep update order
+        self._fence(drain_all=True)  # or update()'s sync would fence
+        # leftovers inside the timed span
+        self._mark_step()
+        self._update(batch, time.perf_counter())
+        self.global_step += 1
+
+    def _update(self, batch, since: Optional[float]) -> None:
+        """``update`` and its fence: a ``sync`` here, unless the step
+        fetched its metrics (``eval_train``) or the trainer fences at
+        the round's end (the async stepper's dispatches run free until
+        ``async_round_end``)."""
+        trainer = self.trainer
+        trainer.update(batch)
+        if not trainer.eval_train and not trainer.fence_at_round_end:
+            with stage("device_wait", rows=trainer.batch_size,
+                       step=trainer.epoch_counter):
+                trainer.sync()
+        self._lap(1, since)

@@ -265,29 +265,17 @@ class StepTimer:
 
     def __init__(self) -> None:
         self._times: List[float] = []
-        self._t0: Optional[float] = None
-
-    def start(self) -> None:
-        self._t0 = time.perf_counter()
-
-    def stop(self, n_steps: int = 1) -> None:
-        """``n_steps > 1``: the timed span covered a multi-step device
-        program (update_scan); record the per-step average so the round
-        statistics stay per-step comparable."""
-        if self._t0 is not None:
-            self.add(time.perf_counter() - self._t0, n_steps)
-            self._t0 = None
 
     def add(self, dt: float, n_steps: int = 1) -> None:
-        """Record an externally measured span covering ``n_steps`` steps
-        (the async-overlap train loop times fence-to-fence laps itself
-        so the spans sum to the round's wall time)."""
+        """Record a span covering ``n_steps`` steps — the round loop
+        calls this at every fence (``train_loop.RoundLoop._lap``) — as
+        ``n_steps`` entries of the per-step average, so the round
+        statistics stay per-step comparable."""
         per = dt / max(1, n_steps)
         self._times.extend([per] * max(1, n_steps))
 
     def clear(self) -> None:
         self._times = []
-        self._t0 = None
 
     @property
     def count(self) -> int:

@@ -181,15 +181,14 @@ def test_test_io_mode(tmp_path):
 
 def test_profiler_utils(tmp_path):
     """StepTimer stats + TraceController trace files on disk."""
-    import time as _time
-
     from cxxnet_tpu.utils.profiler import StepTimer, TraceController
 
     t = StepTimer()
-    for _ in range(6):
-        t.start(); _time.sleep(0.002); t.stop()
+    for _ in range(3):
+        t.add(0.002)
+    t.add(0.006, n_steps=3)  # a 3-step chunk: three per-step entries
     s = t.summary(batch_size=16)
-    assert s["steps"] == 6 and s["mean_ms"] >= 1.5
+    assert s["steps"] == 6 and s["mean_ms"] == pytest.approx(2.0)
     assert s["samples_per_sec"] > 0
     assert "p99" in t.report(16)
 

@@ -3,8 +3,8 @@
 The trainer's jitted programs, the serve bucket cache's compiled
 predicts, and the loop fine-tuner all flow through the same
 instrumentation, so these tests assert the acceptance surface on the
-CPU backend: per-program FLOPs/bytes gauges labeled {kind,bucket},
-cumulative compile seconds from the jax.monitoring listener, sampled
+CPU backend: per-program compile-time gauges labeled {kind,bucket},
+the programs count, cumulative compile seconds from the jax.monitoring listener, sampled
 step fences, disabled-path passthrough, and the telemetry summary.
 """
 
@@ -74,14 +74,11 @@ def test_trainer_programs_report_flops_bytes_and_compile_time():
     tr.update_all(x, y)
     tr.sync()
     # the fused train step registered under its kind with the batch
-    # size as the bucket, with positive cost estimates
-    flops = _sample("xla_program_flops", kind="train_fused", bucket="32")
-    nbytes = _sample("xla_program_bytes", kind="train_fused", bucket="32")
+    # size as the bucket, with its cold-call time
     cold = _sample("xla_program_compile_seconds",
                    kind="train_fused", bucket="32")
-    assert flops and flops > 0
-    assert nbytes and nbytes > 0
     assert cold and cold > 0
+    assert _sample("xla_programs_total", kind="train_fused") >= 1
     # the monitoring listener accounted the backend compile
     after = obs_device.summary()
     assert after["programs"] > before["programs"]
@@ -98,6 +95,7 @@ def test_trainer_programs_report_flops_bytes_and_compile_time():
 def test_eval_program_and_serve_buckets_labeled_by_batch_dim():
     tr = make_trainer(seed=1)
     eng = serve.Engine(trainer=tr, max_batch_size=32, batch_timeout_ms=1)
+    evals = _sample("xla_programs_total", kind="eval") or 0
     try:
         eng.predict(np.random.RandomState(1).randn(3, 16)
                     .astype(np.float32))
@@ -106,12 +104,13 @@ def test_eval_program_and_serve_buckets_labeled_by_batch_dim():
     finally:
         eng.close()
     # 3 rows pad to bucket 4, 7 rows to bucket 8 — each bucket is its
-    # own compiled program and its own labeled gauge sample
-    assert _sample("xla_program_flops", kind="eval", bucket="4") > 0
-    assert _sample("xla_program_flops", kind="eval", bucket="8") > 0
-    # bigger bucket, more estimated work
-    assert (_sample("xla_program_flops", kind="eval", bucket="8")
-            > _sample("xla_program_flops", kind="eval", bucket="4"))
+    # own compiled program, its own labeled gauge sample and one more
+    # in the count of its kind
+    assert _sample("xla_program_compile_seconds",
+                   kind="eval", bucket="4") > 0
+    assert _sample("xla_program_compile_seconds",
+                   kind="eval", bucket="8") > 0
+    assert _sample("xla_programs_total", kind="eval") == evals + 2
 
 
 def test_sampled_step_fences_feed_histogram():
@@ -151,22 +150,45 @@ def test_disabled_telemetry_is_passthrough():
 def test_instrumented_wrapper_fails_open():
     calls = []
 
-    class BrokenLower:
-        def __call__(self, *args):
-            calls.append(args)
-            return "out"
+    class NoShape:
+        """An argument the shape signature cannot be read from."""
 
-        def lower(self, *args):
-            raise RuntimeError("no lowering here")
+        @property
+        def shape(self):
+            raise RuntimeError("no shape here")
 
-    fn = obs_device.InstrumentedJit(BrokenLower(), kind="t_broken")
-    assert fn(np.zeros(3)) == "out"      # accounting failed, call fine
-    assert fn(np.zeros(3)) == "out"
+    def fn(*args):
+        calls.append(args)
+        return "out"
+
+    wrapped = obs_device.InstrumentedJit(fn, kind="t_broken")
+    arg = NoShape()
+    assert wrapped(arg) == "out"      # accounting failed, call fine
+    assert wrapped(arg) == "out"
     assert len(calls) == 2
     # the failure was event-logged once, not raised
     from cxxnet_tpu.obs import event_log
 
-    assert event_log().suppressed_count("obs.device.lower:t_broken") >= 1
+    assert event_log().suppressed_count("obs.device.key:t_broken") >= 1
+
+
+def test_a_program_is_lowered_once():
+    """The wrapper never lowers: the first call is the program's one
+    trace, and later calls of the same shapes none."""
+    import jax
+
+    traces = []
+
+    def f(x):
+        traces.append(x.shape)
+        return x * 2
+
+    wrapped = obs_device.instrument(jax.jit(f), kind="t_once", data_arg=0)
+    assert isinstance(wrapped, obs_device.InstrumentedJit)
+    for _ in range(3):
+        wrapped(np.ones(5, np.float32))
+    assert traces == [(5,)]
+    assert _sample("xla_programs_total", kind="t_once") == 1
 
 
 def test_memory_collector_absent_on_cpu_but_scrape_valid():
@@ -190,6 +212,7 @@ def test_summary_totals_monotonic_and_jsonable():
 
     s = obs_device.summary()
     json.dumps(s)
-    for key in ("programs", "flops", "bytes", "compiles",
-                "compile_seconds", "cold_call_seconds", "sampled_steps"):
+    for key in ("programs", "compiles", "compile_seconds",
+                "cold_call_seconds", "sampled_steps", "trace_seconds",
+                "lower_seconds", "cache_retrieval_seconds"):
         assert key in s and s[key] >= 0

@@ -31,11 +31,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture(autouse=True)
 def _clean_alerts():
-    """Canary tests arm a global alert rule + evaluator; no test leaks
-    it into the next one."""
-    yield
+    """Canary tests arm a rule on the process-wide alert evaluator; no
+    test takes over what an earlier file of its xdist worker left in
+    it, nor leaks its own into the next.  The metric families are
+    process-wide too: the tests read deltas of counters they own."""
     from cxxnet_tpu.obs import alerts as obs_alerts
 
+    obs_alerts.reset()
+    yield
     obs_alerts.reset()
 
 
@@ -515,6 +518,37 @@ def test_canary_rollback_through_alert_and_pointer(tmp_path):
         ev = obs_alerts.evaluator()
         ev.evaluate_once()
         assert "canary_agreement" not in ev.firing()
+    finally:
+        fleet.close(drain_timeout_s=0.0)
+
+
+def test_a_probe_sweep_inside_a_deliberate_restart_records_no_crash(
+        monkeypatch):
+    """What made the rollback test fail one run in some: the probe loop
+    (every 0.1 s here) sweeping between ``restart_replica``'s kill and
+    its spawn saw a dead process, wrote ``crash`` over the restart's
+    own reason and scheduled a second restart.  Held open for three
+    probe periods, the window is hit every time."""
+    from cxxnet_tpu.serve.fleet import ReplicaSupervisor
+
+    kill = ReplicaSupervisor._kill
+
+    def slow_kill(self, r):
+        kill(self, r)
+        time.sleep(0.35)
+
+    m = fleet_metrics()
+    crashes0 = m.restarts.labels(reason="crash").value
+    fleet = start_stub_fleet(make_opts(replicas=2))
+    try:
+        r = fleet.supervisor.replicas[0]
+        monkeypatch.setattr(ReplicaSupervisor, "_kill", slow_kill)
+        fleet.supervisor.restart_replica(r, reason="canary_rollback")
+        monkeypatch.undo()
+        assert r.down_reason == "canary_rollback" and r.restarts == 1
+        assert fleet.supervisor.wait_ready(timeout_s=30.0)
+        assert r.restarts == 1  # and no second one of the sweep's own
+        assert m.restarts.labels(reason="crash").value == crashes0
     finally:
         fleet.close(drain_timeout_s=0.0)
 

@@ -1,0 +1,282 @@
+"""The round loop (cxxnet_tpu/train_loop.py) on its own: a fake trainer
+that records its calls, a list for an iterator, a timer that records
+its fences.  No conf, no files, no program — what is pinned here is the
+ORDER of calls and what each fence is told; that the surviving paths
+train the same weights is ``test_cli.py::test_scan_steps_trains_identically``
+and ``test_chunk.py::test_cli_round_trains_what_the_stack_by_hand_trains``.
+"""
+
+import time
+
+import numpy as np
+import pytest
+
+from cxxnet_tpu.io.data import DataBatch
+from cxxnet_tpu.train_loop import RoundLoop
+from cxxnet_tpu.utils.profiler import pipeline_stats
+
+B = 4  # rows a batch
+
+
+class FakeTrainer:
+    """The trainer as the loop sees it.  Every method call lands in the
+    shared ``log``; attribute reads do not."""
+
+    def __init__(self, log, eval_train=1, refusal=None,
+                 fence_at_round_end=False):
+        self.log = log
+        self.batch_size = B
+        self.epoch_counter = 0
+        self.eval_train = eval_train
+        self.fence_at_round_end = fence_at_round_end
+        self._refusal = refusal
+
+    def scan_refusal(self):
+        self.log.append(("scan_refusal",))
+        return self._refusal
+
+    def update_scan(self, data, labels, sync=True, check_steps=True):
+        # values, not views: the block is the assembler's to recycle
+        self.log.append(("update_scan", np.array(data), np.array(labels),
+                         sync, check_steps))
+        self.epoch_counter += len(data)
+        return np.zeros(len(data), np.float32)
+
+    def update(self, batch):
+        self.log.append(("update", np.array(batch.data),
+                         batch.num_batch_padd))
+        self.epoch_counter += 1
+
+    def sync(self):
+        self.log.append(("sync",))
+
+
+class ListIter:
+    """``next`` / ``value`` over a list; like a real iterator it hands
+    out ONE buffer and overwrites it at every ``next``."""
+
+    def __init__(self, batches):
+        self.batches, self.at = batches, -1
+        self.buf = np.zeros((B, 3), np.float32)
+        self.lab = np.zeros((B, 1), np.float32)
+
+    def next(self):
+        self.at += 1
+        if self.at >= len(self.batches):
+            return False
+        value, padd = self.batches[self.at]
+        self.buf[:] = value
+        self.lab[:] = value
+        self.cur = DataBatch(data=self.buf, label=self.lab,
+                             num_batch_padd=padd)
+        return True
+
+    def value(self):
+        return self.cur
+
+
+class Timer:
+    """What ``StepTimer`` is to the loop: ``add`` at every fence."""
+
+    def __init__(self, log):
+        self.log, self.laps = log, []
+
+    def add(self, dt, n_steps=1):
+        self.laps.append((dt, n_steps))
+        self.log.append(("fence", n_steps))
+
+
+class Tracer:
+    def __init__(self):
+        self.steps = []
+
+    def step(self, g):
+        self.steps.append(g)
+
+
+def run_round(n_batches, scan_steps=4, padded=(), on_batch=None,
+              test_io=False, **trainer_kw):
+    """One round over batches whose every value is their index."""
+    log = []
+    tr = FakeTrainer(log, **trainer_kw)
+    it = ListIter([(i, 1 if i in padded else 0) for i in range(n_batches)])
+    timer, tracer = Timer(log), Tracer()
+    loop = RoundLoop(scan_steps, test_io=test_io)
+    pipeline_stats().reset()
+    loop.begin(tr)
+    got = loop.run(it, timer, (tracer,), on_batch)
+    return loop, log, timer, tracer, got
+
+
+def calls(log, *names):
+    return [e for e in log if e[0] in names]
+
+
+def batch_values(entry):
+    """The batch indices an ``update_scan`` / ``update`` entry trained."""
+    data = entry[1]
+    return ([int(data[0, 0])] if entry[0] == "update"
+            else [int(d[0, 0]) for d in data])
+
+
+# ----------------------------------------------------------------------
+def test_k_full_batches_make_one_scan_and_one_fence_of_k_steps():
+    loop, log, timer, tracer, got = run_round(4, scan_steps=4)
+    assert got == (4, False)
+    assert [e[0] for e in log] == ["scan_refusal", "update_scan", "fence"]
+    _, data, labels, sync, check_steps = log[1]
+    assert data.shape == (4, B, 3) and labels.shape == (4, B, 1)
+    # each slot holds its own batch, though the iterator reused its buffer
+    assert batch_values(log[1]) == [0, 1, 2, 3]
+    assert sync is True and check_steps is False
+    assert timer.laps[0][1] == 4
+    assert loop.global_step == 4 and tracer.steps == [0]
+    assert loop.first_fence_at is not None
+    st = pipeline_stats().snapshot()
+    assert st["chunk"]["count"] == 1 and st["chunk"]["rows"] == 4 * B
+    assert st["copy"]["count"] == 4 and st["stack"]["count"] == 1
+    assert st["next"]["count"] == 5  # the fifth found the end
+
+
+def test_a_tail_shorter_than_scan_steps_is_a_shorter_scan():
+    loop, log, timer, tracer, got = run_round(7, scan_steps=4)
+    scans = calls(log, "update_scan")
+    assert [batch_values(e) for e in scans] == [[0, 1, 2, 3], [4, 5, 6]]
+    assert [n for _, n in timer.laps] == [4, 3]
+    assert not calls(log, "update")
+    assert loop.global_step == 7 and tracer.steps == [0, 4]
+
+
+@pytest.mark.parametrize("eval_train", [1, 0])
+def test_a_tail_of_one_batch_goes_through_update(eval_train):
+    loop, log, timer, _, _ = run_round(5, scan_steps=4,
+                                       eval_train=eval_train)
+    names = [e[0] for e in log if e[0] != "scan_refusal"]
+    if eval_train:
+        assert names == ["update_scan", "fence", "update", "fence"]
+    else:
+        # the first chunk is fenced before update(), which syncs anyway
+        assert names == ["update_scan", "fence", "update", "sync", "fence"]
+    assert batch_values(calls(log, "update")[0]) == [4]
+    assert [n for _, n in timer.laps] == [4, 1]
+    assert loop.global_step == 5
+
+
+def test_a_padded_batch_flushes_the_open_chunk_first():
+    loop, log, timer, _, _ = run_round(6, scan_steps=4, padded={2})
+    trained = [(e[0], batch_values(e))
+               for e in calls(log, "update_scan", "update")]
+    assert trained == [("update_scan", [0, 1]), ("update", [2]),
+                       ("update_scan", [3, 4, 5])]
+    assert calls(log, "update")[0][2] == 1  # handed on with its padding
+    assert [n for _, n in timer.laps] == [2, 1, 3]
+    assert loop.global_step == 6
+
+
+def test_async_chunks_fence_k_only_after_k_plus_1_is_dispatched():
+    loop, log, timer, _, _ = run_round(12, scan_steps=4, eval_train=0)
+    assert all(e[3] is False for e in calls(log, "update_scan"))
+    in_flight = deepest = 0
+    for e in log:
+        in_flight += {"update_scan": 1, "fence": -1}.get(e[0], 0)
+        deepest = max(deepest, in_flight)
+    assert deepest == 2 and in_flight == 0
+    assert [e[0] for e in log if e[0] != "scan_refusal"] == [
+        "update_scan", "update_scan", "fence", "update_scan", "fence",
+        "fence"]
+    assert not loop.in_flight and not calls(log, "sync")
+
+
+def test_the_laps_and_the_drain_tile_the_round():
+    log = []
+    tr = FakeTrainer(log, eval_train=0)
+    it = ListIter([(i, 0) for i in range(10)])
+    timer = Timer(log)
+    loop = RoundLoop(4)
+    loop.begin(tr)
+    t0 = time.perf_counter()
+    loop.run(it, timer)
+    wall = time.perf_counter() - t0
+    assert [n for _, n in timer.laps] == [4, 4, 2]
+    covered = sum(dt for dt, _ in timer.laps)
+    assert 0 < covered <= wall
+    assert wall - covered < 0.05  # what is before run()'s mark and after
+
+
+def test_a_stop_request_trains_the_open_chunk_drains_and_says_so():
+    taken = []
+
+    def on_batch(n):
+        taken.append(n)
+        return n == 6
+
+    loop, log, timer, _, got = run_round(12, scan_steps=4, eval_train=0,
+                                         on_batch=on_batch)
+    assert got == (6, True) and taken == [1, 2, 3, 4, 5, 6]
+    assert [batch_values(e) for e in calls(log, "update_scan")] == [
+        [0, 1, 2, 3], [4, 5]]
+    assert [n for _, n in timer.laps] == [4, 2] and not loop.in_flight
+    assert loop.global_step == 6
+
+
+@pytest.mark.parametrize("eval_train,at_round_end,syncs", [
+    (1, False, 0),  # the step fetched its metrics: fenced already
+    (0, False, 3),
+    (0, True, 0),   # the async stepper: async_round_end fences
+])
+def test_a_refusal_sends_every_batch_through_update(eval_train,
+                                                    at_round_end, syncs):
+    loop, log, timer, tracer, _ = run_round(
+        3, scan_steps=4, eval_train=eval_train,
+        refusal="update_scan requires update_period == 1",
+        fence_at_round_end=at_round_end)
+    assert not calls(log, "update_scan")
+    assert [batch_values(e) for e in calls(log, "update")] == [[0], [1], [2]]
+    assert len(calls(log, "sync")) == syncs
+    assert [n for _, n in timer.laps] == [1, 1, 1]
+    assert tracer.steps == [0, 1, 2] and len(loop.chunks) == 0
+    assert pipeline_stats().snapshot()["copy"]["count"] == 0
+
+
+def test_scan_steps_1_never_asks_and_never_scans():
+    _, log, timer, _, _ = run_round(3, scan_steps=1)
+    assert [e[0] for e in log] == ["update", "fence"] * 3
+
+
+def test_test_io_calls_no_trainer_method():
+    loop, log, timer, tracer, got = run_round(5, scan_steps=4, test_io=True)
+    assert got == (5, False)
+    assert log == [] and timer.laps == [] and tracer.steps == []
+    assert loop.global_step == 0 and loop.first_fence_at is None
+    assert pipeline_stats().snapshot()["next"]["count"] == 6
+
+
+def test_the_loop_outlives_a_round_and_recycles_its_block():
+    log = []
+    tr = FakeTrainer(log)
+    loop = RoundLoop(4)
+    for _ in range(3):
+        loop.begin(tr)
+        loop.run(ListIter([(i, 0) for i in range(4)]), Timer(log))
+    # reset() zeroes the counts each round; the third round's one block
+    # is the first round's, which nobody holds
+    assert (loop.chunks.allocated, loop.chunks.recycled) == (0, 1)
+    assert loop.global_step == 12
+
+
+def test_a_method_replaced_on_the_instance_is_the_one_called():
+    """benchmarks/run.py puts its own ``update_scan`` on the trainer
+    after init(): the loop looks the name up at every call."""
+    log = []
+    tr = FakeTrainer(log)
+    loop = RoundLoop(2)
+    loop.begin(tr)
+    inner, seen = tr.update_scan, []
+
+    def update_scan(data, labels, **kw):
+        seen.append(len(data))
+        return inner(data, labels, **kw)
+
+    tr.update_scan = update_scan
+    loop.run(ListIter([(i, 0) for i in range(4)]), Timer(log))
+    assert seen == [2, 2]

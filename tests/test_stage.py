@@ -216,7 +216,6 @@ def test_telemetry_record_validates_and_carries_the_setup_block(telemetry):
     assert recs[-1]["setup"] == setup  # lifetime block, like ``device``
     dev = recs[-1]["device"]
     assert dev["trace_seconds"] > 0 and dev["lower_seconds"] > 0
-    assert dev["cost_analysis_seconds"] > 0
     assert dev["cache_retrieval_seconds"] == 0  # the suite runs uncached
 
 

@@ -493,71 +493,3 @@ def test_batcher_knobs_bind_engine():
         assert eng.batcher.batch_timeout == pytest.approx(4e-3)
     finally:
         eng.close()
-
-
-# ----------------------------------------------------------------------
-# double-buffered device feed
-def test_stage_batch_bitwise_neutral():
-    from cxxnet_tpu.io.data import DataBatch
-    from cxxnet_tpu.nnet.trainer import NetTrainer
-
-    def make():
-        tr = NetTrainer()
-        tr.set_params(cfgmod.parse_pairs(MLP_CFG))
-        tr.set_param("seed", "0")
-        tr.set_param("eval_train", "0")
-        tr.set_param("batch_size", "8")
-        tr.init_model()
-        return tr
-
-    rng = np.random.RandomState(0)
-    batches = [
-        (rng.randn(8, 16).astype(np.float32),
-         rng.randint(0, 4, (8, 1)).astype(np.float32))
-        for _ in range(5)
-    ]
-    plain = make()
-    for d, l in batches:
-        plain.update(DataBatch(data=d, label=l))
-    plain.sync()
-
-    staged = make()
-    prev = None
-    for d, l in batches:
-        nxt = DataBatch(data=d.copy(), label=l.copy())
-        if prev is not None:
-            staged.update(prev)       # step N dispatched...
-            assert staged.stage_batch(nxt)  # ...H2D of N+1 overlaps it
-        prev = nxt
-    staged.update(prev)
-    staged.sync()
-
-    import jax
-
-    for key in plain.params:
-        for tag in plain.params[key]:
-            wa = np.asarray(jax.device_get(plain.params[key][tag]))
-            wb = np.asarray(jax.device_get(staged.params[key][tag]))
-            assert np.array_equal(wa, wb), (key, tag)
-
-
-def test_stage_batch_mismatch_falls_back():
-    from cxxnet_tpu.io.data import DataBatch
-    from cxxnet_tpu.nnet.trainer import NetTrainer
-
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(MLP_CFG))
-    tr.set_param("seed", "0")
-    tr.set_param("eval_train", "0")
-    tr.set_param("batch_size", "8")
-    tr.init_model()
-    rng = np.random.RandomState(1)
-    a = DataBatch(data=rng.randn(8, 16).astype(np.float32),
-                  label=np.zeros((8, 1), np.float32))
-    b = DataBatch(data=rng.randn(8, 16).astype(np.float32),
-                  label=np.ones((8, 1), np.float32))
-    assert tr.stage_batch(a)
-    tr.update(b)   # a DIFFERENT batch: staged arrays must be dropped
-    assert tr._staged is None
-    tr.update(a)   # and this transfers fresh (no stale reuse)
-    tr.sync()

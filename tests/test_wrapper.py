@@ -193,7 +193,11 @@ eta = 0.1
 
 def test_predict_ndarray_trims_to_request_rows():
     """Raw-array predict/extract must return exactly the requested rows
-    (bucket padding trimmed) and match the full-batch rows bit-exactly."""
+    (bucket padding trimmed) and agree with the full-batch rows: the
+    predicted labels exactly, the features to float32 rounding.  The
+    n-row bucket and the 32-row batch are two XLA programs of different
+    batch shape, free to order a dot product's sums differently (one
+    ulp in ``fc1`` on this CPU), so they owe no bit equality."""
     net = Net(dev="cpu", cfg=MLP_CFG)
     net.init_model()
     x, _ = toy_xy(32)
@@ -205,7 +209,8 @@ def test_predict_ndarray_trims_to_request_rows():
         np.testing.assert_array_equal(pred, full[:n])
         feat = net.extract(x[:n], "fc1")
         assert feat.shape[0] == n
-        np.testing.assert_array_equal(feat, full_feat[:n])
+        np.testing.assert_allclose(feat, full_feat[:n],
+                                   rtol=1e-5, atol=1e-6)
 
 
 def test_predict_ndarray_bucket_cache_no_rejit():

@@ -419,7 +419,7 @@ def run_overlap_bench(dev: str, steps: int, hidden: int) -> dict:
                                    ("staleness", "1"),
                                    ("async_resync_period", "1")])):
         tr = build(extra)
-        if name == "async" and not tr._async_active():
+        if name == "async" and not tr.fence_at_round_end:
             raise SystemExit(
                 f"overlap-bench: async mode inactive on dev={dev!r} "
                 "(1-device mesh?) — the measurement would time a no-op")

@@ -61,7 +61,7 @@ def _median(vals):
 
 def _instrumented(fn, name):
     """A standalone jit accounted as ``kind=kernel_<name>`` — the
-    per-kernel ``xla_program_flops/bytes/compile_seconds`` families."""
+    per-kernel ``xla_program_compile_seconds`` family."""
     import jax
 
     from cxxnet_tpu.obs import device as obs_device

@@ -25,7 +25,7 @@ asserts.  Three artifact kinds:
 
 ``--require fam1,fam2`` additionally asserts that the exposition text
 carries those metric families — how the CI lane pins the device-plane
-families (``xla_program_flops``, ``xla_compile_seconds_total``, ...).
+families (``xla_program_compile_seconds``, ``xla_compile_seconds_total``, ...).
 
 ``--lineage MODEL_DIR [--feedback DIR]`` answers "which requests
 trained the model now serving": reads ``PUBLISHED.json``'s lineage
@@ -36,7 +36,7 @@ those records.
 Usage:
   python tools/obs_dump.py --check --metrics /tmp/metricsz.txt \\
       --telemetry telemetry.jsonl --events events.jsonl \\
-      --alertz /tmp/alertz.json --require xla_program_flops
+      --alertz /tmp/alertz.json --require xla_program_compile_seconds
   python tools/obs_dump.py --tail 20 --events events.jsonl
   python tools/obs_dump.py --summary --events events.jsonl
   python tools/obs_dump.py --lineage models/ --feedback loop/feedback

@@ -179,7 +179,7 @@ def serve_and_scrape(out: str) -> None:
     for needle in ("serve_request_outcomes_total", "serve_batch_rows_total",
                    "serve_request_latency_seconds_bucket",
                    "serve_model_reloads_total", "obs_events_total",
-                   "obs_alerts_firing", "xla_program_flops",
+                   "obs_alerts_firing", "xla_program_compile_seconds",
                    "xla_compile_seconds_total"):
         if needle not in text:
             raise SystemExit(f"obs_smoke: {needle!r} missing from /metricsz")
