@@ -110,6 +110,10 @@ class NetTrainer:
         self.metric = MetricSet()
         self.train_metric = MetricSet()
         self._grad_accum = None
+        # (sums [K, n_metric], rows, first_epoch) of every chunk
+        # update_scan dispatched whose train-metric sums train_metric
+        # has not been given yet, oldest first (collect_scan_metrics)
+        self._scan_sums: List[Tuple[jax.Array, int, int]] = []
         self._rng_key = None
         self._jit_cache: Dict[tuple, object] = {}
 
@@ -924,29 +928,27 @@ class NetTrainer:
         * ``data`` of shape ``[B, ...]`` with ``n_steps=K`` — the same
           staged batch is reused every step (synthetic benchmark mode).
 
-        Returns the per-step f32 losses, shape ``[K]`` — a host
-        ``np.ndarray`` when ``sync=True``, a ``jax.Array`` otherwise.
-        With ``eval_train`` the program also returns each step's sums of
-        the train metrics, ``[K, n_metric]`` (``_scan_step_fn``), and
-        the host adds them to ``train_metric``'s accumulators: no
-        output row is fetched.  ``sync=False`` returns WITHOUT draining
-        the dispatch queue: the caller overlaps host work (the next
-        chunk's decode and copy) with the device scan and fences later
-        — the two-stage ThreadBuffer overlap
-        (``iter_thread_imbin_x-inl.hpp:203-354``) in its TPU form.  It
-        still requires ``eval_train`` off: the sums are device arrays
-        like the losses, but nothing collects them at a later fence yet
-        (ROADMAP S1).
+        Returns the per-step f32 losses, shape ``[K]``, and nothing else
+        — a host ``np.ndarray`` when ``sync=True``, a ``jax.Array``
+        otherwise.  With ``eval_train`` the program also returns each
+        step's sums of the train metrics, ``[K, n_metric]``
+        (``_scan_step_fn``): no output row is fetched.  The sums stay
+        on the device, pending inside the trainer, until
+        :meth:`collect_scan_metrics` adds them to ``train_metric``.
+
+        There is one path: dispatch, then collect.  ``sync=True`` does
+        both at once — it blocks on the losses and collects every
+        pending chunk, oldest first.  ``sync=False`` returns WITHOUT
+        draining the dispatch queue: the caller overlaps host work (the
+        next chunk's decode, copy and upload) with the device scan,
+        fences the returned losses later and calls
+        :meth:`collect_scan_metrics` there, once a chunk — the
+        two-stage ThreadBuffer overlap
+        (``iter_thread_imbin_x-inl.hpp:203-354``) in its TPU form, and
+        what ``train_loop.RoundLoop`` does whatever ``eval_train`` says.
         """
         assert self.net is not None, "init_model/load_model first"
         self._check_trainable()
-        if not sync and self.eval_train:
-            raise ValueError(
-                "update_scan(sync=False) cannot overlap with eval_train: "
-                "the train metrics' per-step sums are collected at the "
-                "chunk's own fence (a full sync); pass sync=True or set "
-                "eval_train = 0"
-            )
         refusal = self.scan_refusal()
         if refusal is not None:
             raise ValueError(refusal)
@@ -1026,11 +1028,30 @@ class NetTrainer:
             # serializes the async overlap (the cost of the check)
             with stage("device_wait", step=first_epoch):
                 self._guard_loss(losses, first_epoch, k)
+        if with_out:
+            self._scan_sums.append((sums, rows, first_epoch))
         if not sync:
             return losses  # async: device array, queue not drained
         with stage("device_wait", rows=rows, step=first_epoch):
-            losses_np, sums_np = jax.device_get((losses, sums))
-        if with_out:
+            losses_np = jax.device_get(losses)
+        self.collect_scan_metrics(all_pending=True)
+        return losses_np
+
+    def collect_scan_metrics(self, all_pending: bool = False) -> None:
+        """Add to ``train_metric`` the sums of the oldest chunk
+        :meth:`update_scan` dispatched and nobody collected yet — of
+        every such chunk, oldest first, with ``all_pending`` — so the
+        accumulators see the chunks in the order they were trained.
+        Nothing to do where none is pending (``eval_train = 0``).  The
+        caller has fenced the chunk (its losses are ready, and the sums
+        with them: one program wrote both), so the fetch is K x
+        n_metric numbers coming down.  Under ``jax.distributed`` they
+        come back replicated: every process adds the GLOBAL sums and
+        ``cnt_inst`` counts the global batch."""
+        while self._scan_sums:
+            sums, rows, first_epoch = self._scan_sums.pop(0)
+            with stage("device_wait", step=first_epoch):
+                sums_np = jax.device_get(sums)
             with stage("metric", rows=rows, step=first_epoch):
                 # a step scores every instance of its out node: the
                 # global batch, times the positions of a (N, T, V) node
@@ -1040,7 +1061,8 @@ class NetTrainer:
                 stats = pipeline_stats()
                 stats.count("metric_rows", rows)
                 stats.count("metric_rows_device", rows)
-        return losses_np
+            if not all_pending:
+                return
 
     def _stage_scan(self, x, per_step: bool):
         """Host stack → device array for update_scan; multi-process runs
@@ -1969,6 +1991,9 @@ class NetTrainer:
         printing, so the line reports the GLOBAL metric on each rank."""
         ret = ""
         if self.eval_train:
+            # a caller that left scanned chunks uncollected still prints
+            # every row it trained
+            self.collect_scan_metrics(all_pending=True)
             self.train_metric.reduce_across_processes()
             ret += self.train_metric.print("train")
             self.train_metric.clear()

@@ -10,18 +10,22 @@ not per step.  What cannot be scanned — the trainer says what
 (``scan_refusal``), and a padded tail batch — goes through ``update``
 one batch at a time, after the open chunk, so update order is kept.
 
-With ``eval_train = 0`` the scan dispatch is asynchronous: the device
-chews chunk k while the host decodes and copies chunk k+1 (the
-reference's two-stage ThreadBuffer overlap, here through XLA's dispatch
-queue), and chunk k is fenced only after k+1 is dispatched — at most
-two chunks in flight, so host memory stays bounded.  With ``eval_train
-= 1`` every chunk is synchronous: its metric sums are fetched at its
-own fence.
+Every scan dispatch is asynchronous: the device chews chunk k while the
+host decodes, copies and uploads chunk k+1 (the reference's two-stage
+ThreadBuffer overlap, here through XLA's dispatch queue), and chunk k
+is fenced only after k+1 is dispatched — at most two chunks in flight,
+so host and device memory stay bounded.  With ``eval_train = 1`` the
+chunk's train-metric sums are device arrays the trainer keeps pending;
+they are collected at the chunk's fence, oldest first, so the
+accumulators see the chunks in the order they were trained.  Before a
+batch goes through ``update`` and before the round returns, everything
+in flight is fenced, and so collected: a round's printed train metrics
+hold exactly the rows it trained.
 
 A fence ends a ``chunk`` stage (the fence-to-fence period that ``next``
-/ ``copy`` / ``stack`` here and ``h2d`` / ``dispatch`` / ``device_wait``
-/ ``metric`` in the trainer tile; benchmarks/lib/window.py takes its
-edges from the same fences) and is one ``timer.add(dt, n_steps)``, in
+/ ``copy`` / ``stack`` and the fence's ``device_wait`` here and ``h2d``
+/ ``dispatch`` / ``metric`` in the trainer tile; benchmarks/lib/window.py
+takes its edges from the same fences) and is one ``timer.add(dt, n_steps)``, in
 :meth:`RoundLoop._lap` and nowhere else.
 
 This module knows the trainer by its public methods only and nothing of
@@ -38,7 +42,7 @@ import jax
 
 from .io.chunk import ChunkAssembler
 from .io.data import DataBatch
-from .utils.profiler import stage
+from .utils.profiler import pipeline_stats, stage
 
 
 class RoundLoop:
@@ -120,13 +124,13 @@ class RoundLoop:
     def _lap(self, n_steps: int, since: Optional[float] = None) -> None:
         """A fence: bill the chunk that ends here, open the next and
         give the timer its span — the one place the timer is written,
-        and so the one place that says what it records in each mode.
-        ``since=None`` is fence to fence (asynchronous chunks and the
-        drain): decode, copy, dispatch and device wait of one chunk, so
-        the laps tile the round's wall time and samples/sec is the
-        PIPELINE rate.  ``since=t`` is a synchronous span from ``t``
-        (``stack`` + ``update_scan``, or ``update`` + ``sync``): the
-        step time, with the host's feed before ``t`` in no span."""
+        and so the one place that says what it records.  ``since=None``
+        is fence to fence (every scanned chunk, the drain and a tail of
+        one batch): decode, copy, dispatch and device wait of one chunk,
+        so the laps tile the round's wall time and samples/sec is the
+        PIPELINE rate.  ``since=t`` is the per-batch path's own span
+        from ``t`` (``update`` + ``sync``): the step time, with the
+        host's feed before ``t`` in no span."""
         trainer = self.trainer
         self.chunk.end(rows=n_steps * trainer.batch_size)
         self.chunk = stage("chunk", step=trainer.epoch_counter).begin()
@@ -138,15 +142,22 @@ class RoundLoop:
         self.pipe_mark = now
 
     def _fence(self, drain_all: bool) -> None:
-        """Block on dispatched chunks, oldest first, a lap each.
+        """Block on dispatched chunks, oldest first, a lap each, and
+        have the trainer add each one's train-metric sums there.
         ``drain_all=False`` keeps the newest running — the double
-        buffer: chunk k lands only after k+1 is dispatched."""
+        buffer: chunk k lands only after k+1 is dispatched, and is
+        counted in ``chunks_overlapped`` of ``chunks_fenced``."""
         trainer = self.trainer
+        stats = pipeline_stats()
         while len(self.in_flight) > (0 if drain_all else 1):
             handle, n = self.in_flight.pop(0)
             with stage("device_wait", rows=n * trainer.batch_size,
                        step=trainer.epoch_counter):
                 jax.block_until_ready(handle)
+            trainer.collect_scan_metrics()
+            stats.count("chunks_fenced")
+            if self.in_flight:
+                stats.count("chunks_overlapped")
             self._lap(n)
 
     def _mark_step(self) -> None:
@@ -165,26 +176,22 @@ class RoundLoop:
             return
         trainer = self.trainer
         self._mark_step()
-        sync_mode = bool(trainer.eval_train)
-        since = time.perf_counter() if sync_mode else None
         with stage("stack", rows=n * trainer.batch_size,
                    step=trainer.epoch_counter):
             data, labels = self.chunks.take()
         if n == 1:
-            if not sync_mode:
-                self._fence(drain_all=True)  # update()'s sync would anyway
-            self._update(DataBatch(data=data[0], label=labels[0]), since)
+            # update() fetches or syncs: what is in flight lands first,
+            # with its sums, and keeps its own lap
+            self._fence(drain_all=True)
+            self._update(DataBatch(data=data[0], label=labels[0]), None)
         else:
             handle = trainer.update_scan(
-                data, labels, sync=sync_mode,
+                data, labels, sync=False,
                 # sharded iterators guarantee equal K per process — skip
                 # the collective K-check so the overlap stays unbroken
                 check_steps=False)
-            if sync_mode:
-                self._lap(n, since)
-            else:
-                self.in_flight.append((handle, n))
-                self._fence(drain_all=False)
+            self.in_flight.append((handle, n))
+            self._fence(drain_all=False)
         self.global_step += n
 
     def _step(self, batch) -> None:
@@ -192,8 +199,9 @@ class RoundLoop:
         ``update_period > 1``, extras, node-bound train metrics, the
         async stepper and a padded tail batch."""
         self._flush()  # keep update order
-        self._fence(drain_all=True)  # or update()'s sync would fence
-        # leftovers inside the timed span
+        # or update()'s sync would fence leftovers inside the timed
+        # span, and score this batch before their sums are collected
+        self._fence(drain_all=True)
         self._mark_step()
         self._update(batch, time.perf_counter())
         self.global_step += 1

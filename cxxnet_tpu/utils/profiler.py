@@ -77,7 +77,10 @@ class PipelineStats:
         and is no stage's time (``io/tokens.py``: tokens, documents,
         documents cut; ``nnet/trainer.py``: ``metric_rows``, the rows
         whose train metrics were scored, and ``metric_rows_device``,
-        those scored inside a step program)."""
+        those scored inside a step program; ``train_loop.py``:
+        ``chunks_fenced``, the scanned chunks the loop blocked on, and
+        ``chunks_overlapped``, those whose fence found a later chunk
+        already dispatched)."""
         with self._lock:
             self._counts[name] = self._counts.get(name, 0) + int(n)
 

@@ -39,15 +39,47 @@ def test_train_metric_device_pct_reads_the_two_row_counters(rounds, want):
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
-def test_benchmark_json_names_the_reader_that_exists():
+# chunk_overlap_pct (PR 32): the round loop counts every scanned chunk
+# it fences and those whose fence found a later chunk dispatched
+@pytest.mark.parametrize("rounds, want", [
+    # a round of three chunks: its last has nothing behind it
+    ([{"chunks_fenced": 3, "chunks_overlapped": 2}] * 2, 100.0 * 2 / 3),
+    ([{"chunks_fenced": 3, "chunks_overlapped": 2,
+       "metric_rows": 6144, "metric_rows_device": 6144},
+      {"chunks_fenced": 1}], 50.0),
+    # every chunk fenced inside its own call (the parent's eval_train = 1)
+    ([{"chunks_fenced": 3}], 0.0),
+    # the parent counts no fence; the per-batch path fences no chunk
+    ([{"metric_rows": 6144, "metric_rows_device": 6144}], None),
+    ([{"tokens": 196608, "docs": 120}], None),
+    ([{}], None),
+    ([], None),
+])
+def test_chunk_overlap_pct_reads_the_two_fence_counters(rounds, want):
+    read = run.load_metric("chunk_overlap_pct").read
+    assert read(_counted(*rounds)) == pytest.approx(want)
+    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
+
+
+ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
+             "granite_4_0_h_micro_train_packed8k"]
+
+
+@pytest.mark.parametrize("name, cells", [
+    ("train_metric_device_pct", ALL_CELLS[:2]),
+    ("chunk_overlap_pct", ALL_CELLS),
+])
+def test_benchmark_json_names_the_reader_that_exists(name, cells):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
-    assert entry["name"] == "train_metric_device_pct"
-    mod = run.load_metric(entry["name"])
-    assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
-        entry["layer"], entry["unit"], entry["source"], entry["moves"])
-    assert entry["workloads"] == ["googlenet_train_synth",
-                                  "resnet50_train_synth"]
-    cells = {w["name"] for w in bench["workloads"]}
-    assert set(entry["workloads"]) <= cells
+    entries = [m for m in bench["per_layer"] if m["name"] == name]
+    assert len(entries) == 1
+    entry = entries[0]
+    mod = run.load_metric(name)
+    assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES, "higher") == (
+        entry["layer"], entry["unit"], entry["source"], entry["moves"],
+        entry["better"])
+    assert entry["workloads"] == cells
+    assert set(cells) <= {w["name"] for w in bench["workloads"]}
+    # a new entry goes to the end of the list, behind those it found
+    assert bench["per_layer"][-1]["name"] == "chunk_overlap_pct"

@@ -172,7 +172,7 @@ layer[0->f1] = fullc:fc1
   init_sigma = 0.1
 layer[f1->r1] = relu
 layer[r1->f2] = fullc:fc2
-  nhidden = 3
+  nhidden = {nclass}
   init_sigma = 0.1
 layer[+0] = softmax
 netconfig=end
@@ -196,20 +196,35 @@ eval_train = {eval_train}
 """
 
 
-def _write_conf(tmp_path, eval_train, more=""):
-    rows = np.hstack([np.arange(51)[:, None] % 3,
+def _write_conf(tmp_path, eval_train, more="", nclass=3):
+    rows = np.hstack([np.arange(51)[:, None] % nclass,
                       np.random.RandomState(0).randn(51, 12)])
     np.savetxt(tmp_path / "d.csv", rows, delimiter=",")
     text = CONF.format(csv=tmp_path / "d.csv", out=tmp_path,
-                       eval_train=eval_train) + more
+                       eval_train=eval_train, nclass=nclass) + more
     conf = tmp_path / "scan.conf"
     conf.write_text(text)
     return str(conf), text
 
 
-def _by_hand(text):
+class ByHand:
+    """What :func:`_by_hand` trained and what it saw on the way."""
+
+    def __init__(self):
+        self.fed, self.losses, self.printed = [], [], []
+        self.params = self.ustates = self.metric_state = None
+
+
+def _metric_state(tr):
+    return [(m.sum_metric, m.cnt_inst) for m in tr.train_metric.metrics]
+
+
+def _by_hand(text, stop_after_chunk=None):
     """The same conf without the round loop: ``update_scan`` fed the
-    ``np.stack`` of each chunk's batches, ``update`` the padded one."""
+    ``np.stack`` of each chunk's batches and drained at once
+    (``sync=True``), ``update`` the padded one.  ``stop_after_chunk=n``
+    ends the run after its n-th chunk, where a stop request found the
+    CLI, with that round's train metrics still in the accumulators."""
     import jax
 
     from cxxnet_tpu import config as cfgmod
@@ -225,7 +240,7 @@ def _by_hand(text):
     for n, v in split.global_entries:
         it.set_param(n, v)
     it.init()
-    fed = []
+    got = ByHand()
     for rnd in (1, 2):
         tr.start_round(rnd)
         it.before_first()
@@ -238,13 +253,65 @@ def _by_hand(text):
         for lo, hi in ((0, 4), (4, 6)):
             data = np.stack([d for d, _, _ in full[lo:hi]])
             labels = np.stack([l for _, l, _ in full[lo:hi]])
-            fed.append((data, labels))
-            jax.block_until_ready(tr.update_scan(
-                data, labels, sync=bool(tr.eval_train)))
-        tr.update(padded[0][2])
-        tr.evaluate(None, "train")
+            got.fed.append((data, labels))
+            got.losses.append(tr.update_scan(data, labels, sync=True))
+            if len(got.fed) == stop_after_chunk:
+                break
+        else:
+            tr.update(padded[0][2])
+            got.printed.append(tr.evaluate(None, "train"))
+            continue
+        break
     it.close()
-    return jax.device_get(tr.params), fed
+    got.params = jax.device_get(tr.params)
+    got.ustates = jax.device_get(tr.ustates)
+    got.metric_state = _metric_state(tr)
+    return got
+
+
+def _same_bytes(got, want):
+    import jax
+
+    got, want = (jax.tree_util.tree_leaves_with_path(jax.device_get(t))
+                 for t in (got, want))
+    assert [k for k, _ in got] == [k for k, _ in want]
+    for (key, g), (_, w) in zip(got, want):
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes(), key
+
+
+def _cli_task(conf, on_chunk=None):
+    """A ``LearnTask`` whose trainer's ``update_scan`` and ``evaluate``
+    are recorded: ``seen`` holds what the loop handed ``update_scan`` by
+    reference, ``handles`` what it returned, ``printed`` every round's
+    train-metric text.  ``on_chunk(task, n)`` runs before the n-th
+    ``update_scan``."""
+    from cxxnet_tpu.cli import LearnTask
+
+    task = LearnTask()
+    task.seen, task.handles, task.printed = [], [], []
+    create = task._create_trainer
+
+    def create_trainer():
+        tr = create()
+        inner, inner_eval = tr.update_scan, tr.evaluate
+
+        def update_scan(data, labels, *a, **kw):
+            task.seen.append((data, labels))
+            if on_chunk is not None:
+                on_chunk(task, len(task.seen))
+            task.handles.append(inner(data, labels, *a, **kw))
+            return task.handles[-1]
+
+        def evaluate(*a, **kw):
+            task.printed.append(inner_eval(*a, **kw))
+            return task.printed[-1]
+
+        tr.update_scan, tr.evaluate = update_scan, evaluate
+        return tr
+
+    task._create_trainer = create_trainer
+    assert task.run([conf]) == 0
+    return task
 
 
 @pytest.mark.parametrize("eval_train", [1, 0])
@@ -253,47 +320,22 @@ def test_cli_round_trains_what_the_stack_by_hand_trains(tmp_path,
     import os
     import sys
 
-    import jax
-
-    from cxxnet_tpu.cli import LearnTask
-
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, os.path.join(repo, "tools"))
     import obs_dump
 
     conf, text = _write_conf(tmp_path, eval_train)
-
-    task = LearnTask()
-    seen = []  # what the loop handed update_scan, held by reference
-    inner = None
-
-    def update_scan(data, labels, *a, **kw):
-        seen.append((data, labels))
-        return inner(data, labels, *a, **kw)
-
-    create = task._create_trainer
-
-    def create_trainer():
-        nonlocal inner
-        tr = create()
-        inner, tr.update_scan = tr.update_scan, update_scan
-        return tr
-
-    task._create_trainer = create_trainer
-    assert task.run([conf]) == 0
-    got = jax.device_get(task.net_trainer.params)
-    want, fed = _by_hand(text)
+    task = _cli_task(conf)
+    seen = task.seen
+    want = _by_hand(text)
 
     # every chunk, kept by reference through both rounds, still reads as
     # the stack of its batches: no held block was written again
     assert [d.shape[0] for d, _ in seen] == [4, 2, 4, 2]
-    for (data, labels), (want_d, want_l) in zip(seen, fed):
+    for (data, labels), (want_d, want_l) in zip(seen, want.fed):
         assert data.tobytes() == want_d.tobytes()
         assert labels.tobytes() == want_l.tobytes()
-    for key, tags in want.items():
-        for tag, w in tags.items():
-            assert np.asarray(got[key][tag]).tobytes() == \
-                np.asarray(w).tobytes(), (key, tag)
+    _same_bytes(task.net_trainer.params, want.params)
 
     path = str(tmp_path / "telemetry.jsonl")
     assert obs_dump.validate_telemetry(path) == []
@@ -308,6 +350,103 @@ def test_cli_round_trains_what_the_stack_by_hand_trains(tmp_path,
     # the test holds all four chunks: each one forced a block of its own
     assert sum(r["chunks"]["allocated"] for r in recs) == 4
     task.itr_train.close()
+
+
+METRICS = "metric = rec@1\nmetric = rec@5\n"  # beside CONF's error
+
+
+def test_cli_round_with_train_metrics_is_update_scan_drained_by_hand(
+        tmp_path):
+    """``eval_train = 1`` down the asynchronous chunk path: a chunk's
+    sums are collected a chunk later than ``update_scan(sync=True)``
+    adds them, and the weights, the momentum, every loss and the
+    printed error / rec@1 / rec@5 come out bit for bit the same —
+    through a tail chunk of 2 at ``scan_steps = 4``, the padded batch's
+    ``update()`` behind it, and a stop request that finds round 2's
+    first chunk in flight."""
+    import jax
+
+    conf, text = _write_conf(tmp_path, 1, METRICS, nclass=6)
+
+    def on_chunk(task, n):
+        if n == 3:  # round 2's first chunk is about to be dispatched:
+            # the batch boundary behind it reads the request
+            task._preempt.requested = True
+
+    task = _cli_task(conf, on_chunk)
+    want = _by_hand(text, stop_after_chunk=3)
+    tr = task.net_trainer
+
+    assert [d.shape[0] for d, _ in task.seen] == [4, 2, 4]
+    # undrained, the loop got device arrays; drained, the hand got numpy
+    assert all(isinstance(h, jax.Array) for h in task.handles)
+    assert all(isinstance(l, np.ndarray) for l in want.losses)
+    for got_l, want_l in zip(task.handles, want.losses):
+        assert np.asarray(got_l).tobytes() == want_l.tobytes()
+    _same_bytes(tr.params, want.params)
+    _same_bytes(tr.ustates, want.ustates)
+    # round 1 printed what the hand printed; round 2 was stopped before
+    # it printed, with its one chunk's sums already in the accumulators
+    assert task.printed == want.printed and len(want.printed) == 1
+    for name in ("train-error:", "train-rec@1:", "train-rec@5:"):
+        assert name in task.printed[0]
+    assert not tr._scan_sums
+    assert _metric_state(tr) == want.metric_state
+    assert [cnt for _, cnt in want.metric_state] == [4 * 8] * 3
+    with open(tmp_path / "telemetry.jsonl") as f:
+        recs = [json.loads(x) for x in f if x.strip()]
+    assert len(recs) == 1  # the stopped round writes no record
+    c = recs[0]["counters"]
+    # two chunks, the first fenced behind the second's dispatch
+    assert (c["chunks_fenced"], c["chunks_overlapped"]) == (2, 1)
+    assert c["metric_rows_device"] == 6 * 8
+    assert c["metric_rows"] == 6 * 8 + 3  # and the padded batch's rows
+    task.itr_train.close()
+
+
+def test_update_scan_undrained_with_train_metrics_returns_the_losses(
+        tmp_path):
+    """``update_scan(sync=False)`` with ``eval_train = 1`` raised until
+    PR 32.  It returns the ``[K]`` losses and nothing else, keeps the
+    sums pending, and ``collect_scan_metrics`` adds them oldest first:
+    the accumulators end where ``sync=True`` leaves them."""
+    import jax
+
+    from cxxnet_tpu import config as cfgmod
+    from cxxnet_tpu.nnet.trainer import NetTrainer
+
+    _, text = _write_conf(tmp_path, 1, METRICS, nclass=6)
+    rs = np.random.RandomState(3)
+    chunks = [(rs.randn(k, 8, 12).astype(np.float32),
+               rs.randint(0, 6, (k, 8, 1)).astype(np.float32))
+              for k in (4, 2, 4)]
+
+    def trainer():
+        tr = NetTrainer()
+        tr.set_params(cfgmod.parse_pairs(text))
+        tr.init_model()
+        return tr
+
+    a, b = trainer(), trainer()
+    for data, labels in chunks:
+        losses = a.update_scan(data, labels, sync=False)
+        assert isinstance(losses, jax.Array) and losses.shape == (len(data),)
+        want = b.update_scan(data, labels)  # sync=True: collected at once
+        assert isinstance(want, np.ndarray) and not b._scan_sums
+        assert np.asarray(losses).tobytes() == want.tobytes()
+    assert len(a._scan_sums) == 3 and _metric_state(a) == [(0.0, 0)] * 3
+    a.collect_scan_metrics()  # the oldest chunk alone
+    assert len(a._scan_sums) == 2
+    assert [cnt for _, cnt in _metric_state(a)] == [4 * 8] * 3
+    a.collect_scan_metrics(all_pending=True)
+    assert not a._scan_sums and _metric_state(a) == _metric_state(b)
+    a.collect_scan_metrics()  # nothing pending: nothing to do
+    assert _metric_state(a) == _metric_state(b)
+    # a caller that never collects still prints every row it trained
+    a.update_scan(*chunks[0], sync=False)
+    b.update_scan(*chunks[0])
+    assert a.evaluate(None, "train") == b.evaluate(None, "train")
+    _same_bytes(a.params, b.params)
 
 
 def test_cli_recycles_the_block_of_a_chunk_nobody_holds(tmp_path):

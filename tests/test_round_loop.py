@@ -30,6 +30,7 @@ class FakeTrainer:
         self.eval_train = eval_train
         self.fence_at_round_end = fence_at_round_end
         self._refusal = refusal
+        self.pending = []  # chunks whose sums nobody collected yet
 
     def scan_refusal(self):
         self.log.append(("scan_refusal",))
@@ -40,11 +41,19 @@ class FakeTrainer:
         self.log.append(("update_scan", np.array(data), np.array(labels),
                          sync, check_steps))
         self.epoch_counter += len(data)
+        if self.eval_train:
+            self.pending.append([int(d[0, 0]) for d in data])
         return np.zeros(len(data), np.float32)
+
+    def collect_scan_metrics(self, all_pending=False):
+        # like the real one: the oldest pending chunk's sums, or nothing
+        assert not all_pending  # a fence collects its own chunk only
+        if self.pending:
+            self.log.append(("collect", self.pending.pop(0)))
 
     def update(self, batch):
         self.log.append(("update", np.array(batch.data),
-                         batch.num_batch_padd))
+                         batch.num_batch_padd, len(self.pending)))
         self.epoch_counter += 1
 
     def sync(self):
@@ -112,6 +121,11 @@ def calls(log, *names):
     return [e for e in log if e[0] in names]
 
 
+def names(log):
+    """The order of the calls that train or fence."""
+    return [e[0] for e in log if e[0] not in ("scan_refusal", "collect")]
+
+
 def batch_values(entry):
     """The batch indices an ``update_scan`` / ``update`` entry trained."""
     data = entry[1]
@@ -123,12 +137,14 @@ def batch_values(entry):
 def test_k_full_batches_make_one_scan_and_one_fence_of_k_steps():
     loop, log, timer, tracer, got = run_round(4, scan_steps=4)
     assert got == (4, False)
-    assert [e[0] for e in log] == ["scan_refusal", "update_scan", "fence"]
+    assert [e[0] for e in log] == ["scan_refusal", "update_scan", "collect",
+                                   "fence"]
     _, data, labels, sync, check_steps = log[1]
     assert data.shape == (4, B, 3) and labels.shape == (4, B, 1)
     # each slot holds its own batch, though the iterator reused its buffer
     assert batch_values(log[1]) == [0, 1, 2, 3]
-    assert sync is True and check_steps is False
+    # never drained inside the call, whatever eval_train says
+    assert sync is False and check_steps is False
     assert timer.laps[0][1] == 4
     assert loop.global_step == 4 and tracer.steps == [0]
     assert loop.first_fence_at is not None
@@ -151,12 +167,11 @@ def test_a_tail_shorter_than_scan_steps_is_a_shorter_scan():
 def test_a_tail_of_one_batch_goes_through_update(eval_train):
     loop, log, timer, _, _ = run_round(5, scan_steps=4,
                                        eval_train=eval_train)
-    names = [e[0] for e in log if e[0] != "scan_refusal"]
-    if eval_train:
-        assert names == ["update_scan", "fence", "update", "fence"]
-    else:
-        # the first chunk is fenced before update(), which syncs anyway
-        assert names == ["update_scan", "fence", "update", "sync", "fence"]
+    # the first chunk is fenced before update(), which fetches (its
+    # metrics) or syncs anyway
+    assert names(log) == (
+        ["update_scan", "fence", "update", "fence"] if eval_train else
+        ["update_scan", "fence", "update", "sync", "fence"])
     assert batch_values(calls(log, "update")[0]) == [4]
     assert [n for _, n in timer.laps] == [4, 1]
     assert loop.global_step == 5
@@ -173,23 +188,30 @@ def test_a_padded_batch_flushes_the_open_chunk_first():
     assert loop.global_step == 6
 
 
-def test_async_chunks_fence_k_only_after_k_plus_1_is_dispatched():
-    loop, log, timer, _, _ = run_round(12, scan_steps=4, eval_train=0)
+@pytest.mark.parametrize("eval_train", [1, 0])
+def test_async_chunks_fence_k_only_after_k_plus_1_is_dispatched(eval_train):
+    loop, log, timer, _, _ = run_round(12, scan_steps=4,
+                                       eval_train=eval_train)
     assert all(e[3] is False for e in calls(log, "update_scan"))
     in_flight = deepest = 0
     for e in log:
         in_flight += {"update_scan": 1, "fence": -1}.get(e[0], 0)
         deepest = max(deepest, in_flight)
     assert deepest == 2 and in_flight == 0
-    assert [e[0] for e in log if e[0] != "scan_refusal"] == [
+    assert names(log) == [
         "update_scan", "update_scan", "fence", "update_scan", "fence",
         "fence"]
     assert not loop.in_flight and not calls(log, "sync")
+    # two of the three fences found a later chunk already dispatched
+    counters = pipeline_stats().counters()
+    assert counters["chunks_fenced"] == 3
+    assert counters["chunks_overlapped"] == 2
 
 
-def test_the_laps_and_the_drain_tile_the_round():
+@pytest.mark.parametrize("eval_train", [1, 0])
+def test_the_laps_and_the_drain_tile_the_round(eval_train):
     log = []
-    tr = FakeTrainer(log, eval_train=0)
+    tr = FakeTrainer(log, eval_train=eval_train)
     it = ListIter([(i, 0) for i in range(10)])
     timer = Timer(log)
     loop = RoundLoop(4)
@@ -203,20 +225,78 @@ def test_the_laps_and_the_drain_tile_the_round():
     assert wall - covered < 0.05  # what is before run()'s mark and after
 
 
-def test_a_stop_request_trains_the_open_chunk_drains_and_says_so():
+@pytest.mark.parametrize("eval_train", [1, 0])
+def test_a_stop_request_trains_the_open_chunk_drains_and_says_so(eval_train):
     taken = []
 
     def on_batch(n):
         taken.append(n)
         return n == 6
 
-    loop, log, timer, _, got = run_round(12, scan_steps=4, eval_train=0,
+    loop, log, timer, _, got = run_round(12, scan_steps=4,
+                                         eval_train=eval_train,
                                          on_batch=on_batch)
     assert got == (6, True) and taken == [1, 2, 3, 4, 5, 6]
     assert [batch_values(e) for e in calls(log, "update_scan")] == [
         [0, 1, 2, 3], [4, 5]]
     assert [n for _, n in timer.laps] == [4, 2] and not loop.in_flight
     assert loop.global_step == 6
+    # the request found chunk 1 in flight: chunk 2 is dispatched behind
+    # it, then both are fenced, and collected, before run() returns
+    assert names(log) == ["update_scan", "update_scan", "fence", "fence"]
+    assert loop.trainer.pending == []
+    assert [e[1] for e in calls(log, "collect")] == (
+        [[0, 1, 2, 3], [4, 5]] if eval_train else [])
+
+
+def test_the_sums_are_collected_once_a_chunk_at_its_fence_in_order():
+    _, log, _, _, _ = run_round(14, scan_steps=4)
+    # a chunk's sums are asked for after the NEXT chunk's dispatch,
+    # straight before its own lap, and nowhere else
+    assert [e[0] for e in log if e[0] != "scan_refusal"] == [
+        "update_scan", "update_scan", "collect", "fence",
+        "update_scan", "collect", "fence",
+        "update_scan", "collect", "fence", "collect", "fence"]
+    assert [e[1] for e in calls(log, "collect")] == [
+        [0, 1, 2, 3], [4, 5, 6, 7], [8, 9, 10, 11], [12, 13]]
+    counters = pipeline_stats().counters()
+    assert (counters["chunks_fenced"], counters["chunks_overlapped"]) == (4, 3)
+
+
+def test_eval_train_0_leaves_nothing_to_collect():
+    loop, log, _, _, _ = run_round(12, scan_steps=4, eval_train=0)
+    assert not calls(log, "collect") and loop.trainer.pending == []
+    assert pipeline_stats().counters()["chunks_fenced"] == 3
+
+
+@pytest.mark.parametrize("n_batches,padded,scanned", [
+    (9, (), [[0, 1, 2, 3], [4, 5, 6, 7]]),       # a tail of one batch
+    (10, {9}, [[0, 1, 2, 3], [4, 5, 6, 7], [8]]),  # a padded last batch:
+    # the open chunk of one batch goes through update() too
+    (11, {6}, [[0, 1, 2, 3], [4, 5], [7, 8, 9, 10]]),  # padded mid-round
+])
+def test_every_pending_sum_is_collected_before_update_is_called(
+        n_batches, padded, scanned):
+    loop, log, timer, _, _ = run_round(n_batches, scan_steps=4,
+                                       padded=padded)
+    chunks = [c for c in scanned if len(c) > 1]
+    assert [batch_values(e) for e in calls(log, "update_scan")] == chunks
+    assert [e[1] for e in calls(log, "collect")] == chunks
+    updates = calls(log, "update")
+    assert updates and all(e[3] == 0 for e in updates)  # none pending
+    # and every chunk trained before an update() was collected before it
+    for i, e in enumerate(log):
+        before = [x[0] for x in log[:i]]
+        assert e[0] != "update" or (
+            before.count("collect") == before.count("update_scan"))
+    assert loop.trainer.pending == [] and not loop.in_flight
+    assert sum(n for _, n in timer.laps) == n_batches
+
+
+def test_the_round_returns_with_every_sum_collected():
+    loop, log, _, _, _ = run_round(8, scan_steps=4)
+    assert log[-2][0] == "collect" and log[-1] == ("fence", 4)
+    assert loop.trainer.pending == []
 
 
 @pytest.mark.parametrize("eval_train,at_round_end,syncs", [
