@@ -1570,6 +1570,10 @@ class LearnTask:
             timer.add(wait.end(), 0)
         if preempted:
             return False
+        if self.test_io == 0:
+            # what the layers counted inside the step programs, into the
+            # round's counters: after the last fence, in no chunk's period
+            trainer.count_layer_state()
         chunks = loop.chunks
         stage_line = pipeline_stats().report()
         if not self.silent and stage_line:

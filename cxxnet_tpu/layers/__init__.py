@@ -17,8 +17,10 @@ from . import (  # noqa: F401
     conv,
     elemwise,
     embed,
+    gdn,
     linear,
     loss,
+    moe,
     sequence,
     ssm,
     structure,

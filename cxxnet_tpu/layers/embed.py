@@ -13,12 +13,14 @@ inputs (SURVEY §5); this follows the framework's own conventions
   scoping works) or fixed sinusoidal (Vaswani et al. 2017)
 * ``multiplier`` — the looked-up rows are scaled by it (default 1)
 
-``lm_head`` is the head that uses an embedding's matrix transposed:
-``logits = x E^T / divisor`` with ``tied = <the embedding's name>`` and
-``divisor`` (default 1).  It owns no parameter: the net hands it the
-named layer's, so the matrix is one leaf with one gradient, the sum of
-both uses (``shared[...]`` aliases a layer of the same type, which a
-head is not).
+``lm_head`` is the head over the vocabulary, ``logits = x W^T /
+divisor`` with ``nhidden`` the vocabulary and ``divisor`` (default 1).
+With ``tied = <the embedding's name>`` ``W`` is that embedding's matrix
+and the head owns no parameter: the net hands it the named layer's, so
+the matrix is one leaf with one gradient, the sum of both uses
+(``shared[...]`` aliases a layer of the same type, which a head is
+not).  Without ``tied`` the head is untied: ``wmat (nhidden, D)`` is its
+own, started at ``init_sigma``.
 
 Input is a flat ``(N, T)`` node of token ids (the text iterator emits
 ids as float32 — exact for any realistic vocab); output is the
@@ -174,11 +176,18 @@ class LMHeadLayer(Layer):
 
     def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
         self._check_arity(in_shapes, 1)
-        if not self.tied or self.param.num_hidden <= 0:
+        if self.param.num_hidden <= 0:
             raise ValueError(
-                "lm_head: set tied (the embedding's name) and nhidden "
-                "(its nvocab)")
+                "lm_head: set nhidden (the vocabulary) and, for a head "
+                "tied to the embedding, tied (the embedding's name)")
         return [tuple(in_shapes[0][:-1]) + (self.param.num_hidden,)]
+
+    def init_params(self, key, in_shapes) -> Params:
+        if self.tied:
+            return {}  # the embedding's, by FunctionalNet's key
+        return {"wmat": jax.random.normal(
+            key, (self.param.num_hidden, in_shapes[0][-1]), jnp.float32)
+            * self.param.init_sigma}
 
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
         x = inputs[0]

@@ -1,0 +1,185 @@
+"""Linear-attention layers whose decay is a matrix: ``gated_deltanet``,
+the Gated DeltaNet mixer (Yang, Kautz & Hatamizadeh 2024) as the
+Qwen3-Next family uses it.
+
+New TPU-first scope, beside ``ssm.py``.  The layer follows the
+published block, per value head ``j`` (its key head is ``j // (Hv /
+Hk)``)
+
+    [q | k | v | z] = u W_in             widths HkDk | HkDk | HvDv | HvDv
+    [b | a] = u W_ba                     widths Hv | Hv
+    [q | k | v] = silu(conv([q | k | v]))    depthwise, causal, K wide, no bias
+    q = q / |q| / sqrt(Dk);  k = k / |k|       (eps 1e-6 under the root)
+    beta = sigmoid(b);  g = -exp(a_log) * softplus(a + dt_bias)
+    S_t = e^{g_t} S_{t-1} + beta_t k_t (v_t - (e^{g_t} S_{t-1})^T k_t)^T
+    o_t = S_t^T q_t
+    y = rms_norm_Dv(o) * gate_norm * silu(z)       one norm a head
+    out = y W_out
+
+with the scan in its chunked form (``ops/gdn.py``: one implementation,
+plain ``jax.numpy``, differentiated by ``jax.grad``).  The rows of
+``W_in`` are ``q | k | v | z``, each head-major: a permutation of the
+family's checkpoints, which interleave the four by key-head group.
+
+``gated_deltanet`` config keys:
+
+* ``nkhead`` (Hk), ``nvhead`` (Hv, a multiple of Hk), ``key_dim`` (Dk),
+  ``value_dim`` (Dv) — required
+* ``conv_width`` (K, default 4), ``chunk`` (default 64, a power of
+  two up to ``SEGMENT``), ``eps`` (1e-5: the pre-norm's and the gated
+  norm's)
+* ``prenorm`` / ``residual_scale`` — the residual branch in one layer
+  (``sequence.Branch``)
+* ``init_sigma`` for the three matrices; ``a_log``, ``dt_bias`` and the
+  conv start as ``mamba2``'s do (a decay rate uniform in [1, 16], a
+  step log-uniform in [1e-3, 1e-1] through the inverse of softplus,
+  the conv uniform at 1/sqrt(K)); the norms start at 1
+* a second input, the net's token ids (``layer[x,0->y] =
+  gated_deltanet``): at a document's first token (one begins after
+  every separator id 0) the scan starts from ``S = 0`` and the
+  convolution sees zeros before it.  With one input a row is one
+  document.
+
+Parameters (tags): ``wmat`` (2 HkDk + 2 HvDv, D), ``wba`` (2 Hv, D),
+``conv`` (2 HkDk + HvDv, K), ``a_log`` (Hv), ``dt_bias`` (Hv),
+``gate_norm`` (Dv), ``wproj`` (D, HvDv), and ``norm`` (D) with
+``prenorm``.  All stay float32 at rest under mixed precision and are
+cast where they are used, as ``mamba2``'s.
+
+Every stage runs under a ``jax.named_scope`` of its own (``in_proj``,
+``conv``, ``scan``, ``gate_norm``, ``out_proj``) inside the layer's, so
+a profiler trace splits the mixer.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import List, Sequence
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.gdn import gated_delta_scan
+from ..ops.ssd import doc_index
+from .base import Layer, Params, Shape, register
+from .sequence import Branch, _check_ids_input, rms_norm
+from .ssm import causal_conv
+
+
+#: the scan walks a longer row in segments of this many tokens, each
+#: under ``jax.checkpoint``, so its backward holds one segment's chunk
+#: matrices and not the row's (``ops/gdn.gated_delta_scan``)
+SEGMENT = 2048
+
+
+def unit(x, eps: float = 1e-6):
+    """``x / sqrt(sum(x^2) + eps)`` over the last axis, in float32."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt((xf * xf).sum(axis=-1, keepdims=True)
+                              + jnp.float32(eps))
+
+
+@register
+class GatedDeltaNetLayer(Layer, Branch):
+    type_name = "gated_deltanet"
+    f32_tags = frozenset({"wmat", "wba", "conv", "dt_bias", "a_log",
+                          "gate_norm", "wproj", "norm"})
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.nkhead = 0
+        self.nvhead = 0
+        self.key_dim = 0
+        self.value_dim = 0
+        self.conv_width = 4
+        self.chunk = 64
+
+    #: every one must be set positive
+    _INT_KEYS = ("nkhead", "nvhead", "key_dim", "value_dim", "conv_width",
+                 "chunk")
+
+    def set_param(self, name, val):
+        if name in self._INT_KEYS:
+            setattr(self, name, int(val))
+        elif not self.set_branch_param(name, val):
+            super().set_param(name, val)
+
+    def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
+        _check_ids_input("gated_deltanet", in_shapes)
+        if self.chunk & (self.chunk - 1) or self.chunk > SEGMENT:
+            raise ValueError(
+                f"gated_deltanet: chunk={self.chunk} must be a power of "
+                f"two, at most {SEGMENT}")
+        if len(in_shapes[0]) != 3:
+            raise ValueError("gated_deltanet: input must be a sequence "
+                             "node (N, T, D)")
+        if min(getattr(self, k) for k in self._INT_KEYS) <= 0:
+            raise ValueError("gated_deltanet: set nkhead, nvhead, key_dim "
+                             "and value_dim")
+        if self.nvhead % self.nkhead:
+            raise ValueError(
+                f"gated_deltanet: nkhead={self.nkhead} must divide "
+                f"nvhead={self.nvhead}")
+        return [tuple(in_shapes[0])]
+
+    def _widths(self):
+        return self.nkhead * self.key_dim, self.nvhead * self.value_dim
+
+    def init_params(self, key, in_shapes) -> Params:
+        d = in_shapes[0][2]
+        ek, ev = self._widths()
+        hv, k = self.nvhead, self.conv_width
+        k1, k2, k3, k4, k5, k6 = jax.random.split(key, 6)
+        sigma = self.param.init_sigma
+        step = jnp.exp(jax.random.uniform(
+            k4, (hv,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        bound = 1.0 / math.sqrt(k)
+        out = {
+            "wmat": jax.random.normal(
+                k1, (2 * ek + 2 * ev, d), jnp.float32) * sigma,
+            "wba": jax.random.normal(k6, (2 * hv, d), jnp.float32) * sigma,
+            "conv": jax.random.uniform(
+                k2, (2 * ek + ev, k), jnp.float32, -bound, bound),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "a_log": jnp.log(jax.random.uniform(
+                k5, (hv,), jnp.float32, 1.0, 16.0)),
+            "gate_norm": jnp.ones((self.value_dim,), jnp.float32),
+            "wproj": jax.random.normal(k3, (d, ev), jnp.float32) * sigma,
+        }
+        out.update(self.branch_params(d))
+        return out
+
+    def apply(self, params, inputs, *, train=False, rng=None, step=None):
+        x0 = inputs[0]
+        n, t, _ = x0.shape
+        hk, hv, dk, dv = self.nkhead, self.nvhead, self.key_dim, self.value_dim
+        ek, ev = self._widths()
+        cdt = x0.dtype
+        f32 = jnp.float32
+        doc = doc_index(inputs[1]) if len(inputs) > 1 else None
+        u = self.branch_in(params, x0)
+        with jax.named_scope("in_proj"):
+            qkvz = u @ params["wmat"].astype(cdt).T
+            qkv, z = qkvz[..., :2 * ek + ev], qkvz[..., 2 * ek + ev:]
+            ba = (u @ params["wba"].astype(cdt).T).astype(f32)
+        with jax.named_scope("conv"):
+            qkv = jax.nn.silu(causal_conv(
+                qkv, params["conv"].astype(cdt), jnp.zeros((), cdt), doc))
+        with jax.named_scope("scan"):
+            rep = hv // hk
+            q = jnp.repeat(qkv[..., :ek].reshape(n, t, hk, dk), rep, axis=2)
+            k = jnp.repeat(qkv[..., ek:2 * ek].reshape(n, t, hk, dk), rep,
+                           axis=2)
+            v = qkv[..., 2 * ek:].reshape(n, t, hv, dv)
+            q = (unit(q) * f32(1.0 / math.sqrt(dk))).astype(cdt)
+            k = unit(k).astype(cdt)
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(params["a_log"].astype(f32)) * jax.nn.softplus(
+                ba[..., hv:] + params["dt_bias"].astype(f32))
+            o = gated_delta_scan(q, k, v, g, beta, doc, self.chunk, SEGMENT)
+        with jax.named_scope("gate_norm"):
+            y = rms_norm(o, params["gate_norm"], self.eps).reshape(
+                n, t, ev) * jax.nn.silu(z)
+        with jax.named_scope("out_proj"):
+            out = y @ params["wproj"].astype(cdt).T
+        return [self.branch_out(x0, out)]

@@ -13,6 +13,17 @@ layout, NHWC-style batch-major nodes).  Sequence nodes are ``(N, T, D)``
   ``nhead / nkvhead`` query heads; default ``nhead``).  The fused
   projection ``wmat`` is then ``((nhead + 2 nkvhead) * Dh, D)``
 * ``score_scale`` — the score multiplier (default ``1 / sqrt(Dh)``)
+* ``head_dim`` — a head's width ``Dh`` where it is not ``D / nhead``
+  (``wproj`` is then ``(D, nhead * Dh)``)
+* ``qk_norm`` — 1 norms every query and key head with an ``rms_norm``
+  over ``Dh`` before the rotation (tags ``q_norm``, ``k_norm``, ``eps``)
+* ``rotary_dim`` / ``rope_theta`` — rotate-half rotary positions on the
+  first ``rotary_dim`` of each query and key head (``ops/attention.
+  rotary``; theta default 10000).  With the ids input a position is
+  counted from its document's first token
+* ``out_gate`` — 1 doubles the query projection: head ``h``'s rows of
+  ``wmat`` are its ``Dh`` of query then its ``Dh`` of gate, and the
+  attention output is multiplied by ``sigmoid(gate)`` before ``wproj``
 * ``no_bias`` — 1 drops ``bias`` and ``bproj``
 * ``causal`` — 1 for autoregressive masking
 * a second input, the net's token ids ``layer[x,0->y] = attention``:
@@ -193,13 +204,18 @@ def _check_ids_input(who: str, in_shapes: Sequence[Shape]) -> None:
 @register
 class AttentionLayer(Layer, Branch):
     type_name = "attention"
-    f32_tags = frozenset({"norm"})
+    f32_tags = frozenset({"norm", "q_norm", "k_norm"})
 
     def __init__(self) -> None:
         super().__init__()
         self.nhead = 1
         self.nkvhead = 0  # 0: as many as nhead
         self.scale = 0.0  # 0: 1 / sqrt(Dh)
+        self.head_dim = 0  # 0: D / nhead
+        self.qk_norm = 0
+        self.rotary_dim = 0
+        self.rope_theta = 10000.0
+        self.out_gate = 0
         self.causal = 0
         self.seq_parallel = 0
         self.attn_impl = "auto"
@@ -217,6 +233,10 @@ class AttentionLayer(Layer, Branch):
             self.nkvhead = int(val)
         elif name == "score_scale":
             self.scale = float(val)
+        elif name in ("head_dim", "qk_norm", "rotary_dim", "out_gate"):
+            setattr(self, name, int(val))
+        elif name == "rope_theta":
+            self.rope_theta = float(val)
         elif self.set_branch_param(name, val):
             pass
         elif name == "causal":
@@ -390,7 +410,9 @@ class AttentionLayer(Layer, Branch):
         """The layer as it was before grouped heads, a stated scale and
         documents: every path but the masked ``mha`` knows only this."""
         return (n_in == 1 and not self.scale and not self.param.no_bias
-                and self.nkvhead in (0, self.nhead))
+                and self.nkvhead in (0, self.nhead)
+                and not (self.head_dim or self.qk_norm or self.rotary_dim
+                         or self.out_gate))
 
     def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
         _check_ids_input("attention", in_shapes)
@@ -401,10 +423,15 @@ class AttentionLayer(Layer, Branch):
                 "input_layout = seq"
             )
         n, t, d = shape
-        if self.nhead <= 0 or d % self.nhead != 0:
+        if self.nhead <= 0 or (d % self.nhead != 0 and not self.head_dim):
             raise ValueError(
                 f"attention: nhead={self.nhead} must divide model dim {d}"
             )
+        if self.rotary_dim % 2 or self.rotary_dim > (
+                self.head_dim or d // self.nhead):
+            raise ValueError(
+                f"attention: rotary_dim={self.rotary_dim} must be even "
+                "and no wider than a head")
         if self.nkvhead and self.nhead % self.nkvhead:
             raise ValueError(
                 f"attention: nkvhead={self.nkvhead} must divide "
@@ -414,7 +441,8 @@ class AttentionLayer(Layer, Branch):
                 self.seq_parallel or self.decode
                 or self.attn_impl == "pallas"):
             raise ValueError(
-                "attention: nkvhead, score_scale, no_bias and a document input run the "
+                "attention: nkvhead, score_scale, no_bias, head_dim, "
+                "qk_norm, rotary_dim, out_gate and a document input run the "
                 "masked XLA path (ops/attention.mha); seq_parallel, "
                 "decode and attn_impl = pallas know none of them"
             )
@@ -437,12 +465,18 @@ class AttentionLayer(Layer, Branch):
         p = self.param
         k1, k2 = jax.random.split(key)
         sigma = p.init_sigma  # framework default 0.01; set via init_sigma
-        nqkv = d + 2 * (self.nkvhead or self.nhead) * (d // self.nhead)
+        dh = self.head_dim or d // self.nhead
+        nq = self.nhead * dh
+        nqkv = nq * (2 if self.out_gate else 1) + 2 * (
+            self.nkvhead or self.nhead) * dh
         out = {
             # framework (nout, nin) layout: fused qkv then output proj
             "wmat": jax.random.normal(k1, (nqkv, d), jnp.float32) * sigma,
-            "wproj": jax.random.normal(k2, (d, d), jnp.float32) * sigma,
+            "wproj": jax.random.normal(k2, (d, nq), jnp.float32) * sigma,
         }
+        if self.qk_norm:
+            out["q_norm"] = jnp.ones((dh,), jnp.float32)
+            out["k_norm"] = jnp.ones((dh,), jnp.float32)
         if not p.no_bias:
             out["bias"] = jnp.zeros((nqkv,), jnp.float32)
             out["bproj"] = jnp.zeros((d,), jnp.float32)
@@ -450,26 +484,46 @@ class AttentionLayer(Layer, Branch):
         return out
 
     def _apply_masked(self, params, x, ids):
-        """Grouped-query heads, a stated scale, documents: q, k and v
+        """Grouped-query heads, a stated scale or head width, q/k
+        norms, rotary positions, an output gate, documents: q, k and v
         from the one fused projection, then ``mha`` with its mask, in
         row blocks once the sequence is long."""
-        from ..ops.attention import mha
+        from ..ops.attention import doc_positions, mha, rotary
         from ..ops.ssd import doc_index
 
         n, t, d = x.shape
         h, hk = self.nhead, self.nkvhead or self.nhead
-        dh = d // h
+        dh = self.head_dim or d // h
+        nq = h * dh
         qkv = x @ params["wmat"].astype(x.dtype).T
         if "bias" in params:
             qkv = qkv + params["bias"].astype(x.dtype)
-        q = qkv[..., :d].reshape(n, t, h, dh)
-        k = qkv[..., d:d + hk * dh].reshape(n, t, hk, dh)
-        v = qkv[..., d + hk * dh:].reshape(n, t, hk, dh)
+        gate = None
+        if self.out_gate:
+            qg = qkv[..., :2 * nq].reshape(n, t, h, 2 * dh)
+            q, gate = qg[..., :dh], qg[..., dh:].reshape(n, t, nq)
+            qkv = qkv[..., nq:]
+        else:
+            q = qkv[..., :nq].reshape(n, t, h, dh)
+        k = qkv[..., nq:nq + hk * dh].reshape(n, t, hk, dh)
+        v = qkv[..., nq + hk * dh:].reshape(n, t, hk, dh)
+        doc = None if ids is None else doc_index(ids)
+        if self.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = rms_norm(q, params["q_norm"], self.eps)
+                k = rms_norm(k, params["k_norm"], self.eps)
+        if self.rotary_dim:
+            with jax.named_scope("rotary"):
+                pos = doc_positions(doc, n, t)
+                q = rotary(q, pos, self.rotary_dim, self.rope_theta)
+                k = rotary(k, pos, self.rotary_dim, self.rope_theta)
         o = mha(q, k, v, causal=bool(self.causal),
-                scale=self.scale or None,
-                doc=None if ids is None else doc_index(ids),
+                scale=self.scale or None, doc=doc,
                 block_q=512 if t >= self._AUTO_FLASH_MIN_T else 0)
-        out = o.reshape(n, t, d) @ params["wproj"].astype(x.dtype).T
+        o = o.reshape(n, t, nq)
+        if gate is not None:
+            o = o * jax.nn.sigmoid(gate)
+        out = o @ params["wproj"].astype(x.dtype).T
         if "bproj" in params:
             out = out + params["bproj"].astype(x.dtype)
         return out
@@ -626,15 +680,24 @@ class SeqPoolLayer(Layer):
 
 @register
 class MoELayer(Layer):
-    """Mixture-of-experts projection with expert parallelism.
+    """``moe``: a DENSE mixture of linear expert projections.
 
     New TPU-first scope (no reference analog).  ``nexpert`` expert
     projections ``(nhidden, D)`` live in one ``(E, nhidden, D)`` tensor
     whose expert dim is sharded over the mesh ``model`` axis
-    (``MeshPlan.param_sharding`` 3-D rule) — GSPMD partitions the expert
-    einsums across devices and inserts the combine reduction, which IS
-    expert parallelism.  Routing is a softmax gate, optionally top-k
-    masked (``topk = 0`` keeps the dense soft mixture).
+    (``MeshPlan.param_sharding`` 3-D rule): every expert runs on every
+    token, and GSPMD partitions the expert einsums across devices and
+    inserts the combine reduction.  That shards the experts' weights
+    and their FLOPs; it is not what a published mixture-of-experts
+    block does — no token is dispatched, and ``topk`` only zero-weights
+    the rest.  Routing is a softmax gate, optionally top-k masked
+    (``topk = 0`` keeps the dense soft mixture).
+
+    The routed layer is ``routed_experts`` (``layers/moe.py``): gated
+    experts, sorted dispatch with grouped products, a device's held
+    share.  The two are different functions with different parameters
+    (this one projects to another width through one matrix an expert),
+    so both remain; ROADMAP's design debts say when this one can go.
 
     Works on flat ``(N, D)`` and sequence ``(N, T, D)`` nodes.
     """

@@ -23,6 +23,7 @@ from .builders import (  # noqa: F401
     kaggle_bowl_conf,
     mnist_conv_conf,
     mnist_mlp_conf,
+    qwen3_next_conf,
     resnet50_conf,
     resnet101_conf,
     resnet152_conf,
@@ -46,4 +47,5 @@ MODEL_BUILDERS = {
     "transformer": transformer_conf,
     "transformer_lm": transformer_lm_conf,
     "granite_h": granite_h_conf,
+    "qwen3_next": qwen3_next_conf,
 }

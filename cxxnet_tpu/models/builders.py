@@ -565,6 +565,155 @@ def granite_h_conf(
     )
 
 
+def qwen3_next_conf(
+    vocab: int = 18992,
+    seq_len: int = 8192,
+    hidden: int = 2048,
+    layer_types: str = "lllf",
+    linear_key_heads: int = 16,
+    linear_value_heads: int = 32,
+    linear_key_dim: int = 128,
+    linear_value_dim: int = 128,
+    linear_conv: int = 4,
+    linear_chunk: int = 64,
+    attn_heads: int = 16,
+    attn_kv_heads: int = 2,
+    head_dim: int = 256,
+    partial_rotary_factor: float = 0.25,
+    rope_theta: float = 1e7,
+    num_experts: int = 512,
+    experts_per_tok: int = 10,
+    expert_hidden: int = 512,
+    shared_hidden: int = 512,
+    first_expert: int = 0,
+    experts_held: int = 32,
+    eps: float = 1e-6,
+    token_file: str = "",
+    batch_size: int = 1,
+    num_round: int = 10,
+    dev: str = "tpu",
+    compute_dtype: str = "bfloat16",
+    eta: float = 0.0003,
+    scan_steps: int = 8,
+) -> str:
+    """A Qwen3-Next style hybrid mixture-of-experts language model
+    (Qwen, ``model_type: qwen3_next``): per layer a Gated DeltaNet
+    linear-attention mixer (``l``) or a gated softmax attention with
+    q/k norms and partial rotary positions (``f``), then a routed
+    feed-forward part — ``num_experts`` SwiGLU experts of width
+    ``expert_hidden`` behind a top-``experts_per_tok`` router with
+    renormalised weights, plus one shared expert under a sigmoid gate —
+    every branch pre-normed by ``rms_norm`` and added back; an untied
+    head.  The defaults are the published widths of
+    Qwen3-Next-80B-A3B, one period of its stack deep (``lllf``), with
+    ONE RANK'S SHARE of a 16-way expert-parallel layout —
+    ``experts_held`` = 32 of the 512 experts of every layer, from
+    ``first_expert`` on (the router still ranks all 512; the layer adds
+    the held experts' terms only, ``layers/moe.py``) — over an eighth
+    of the vocabulary: 625.7M parameters, what one 16 GB chip holds
+    under adam.
+
+    The published norm is ``x / rms(x) * (1 + w)`` with ``w`` started
+    at 0; ``rms_norm``'s weight started at 1 is the same function with
+    the same gradients under adam without decay, so it serves as it is.
+    ``a_log`` and ``dt_bias`` start as ``mamba2``'s (``layers/gdn.py``).
+
+    Every parameter is under adam at ``eta``, the routers too.  In a
+    share a router's gradient is zero all the same: the layer takes the
+    routing weights as constants of the backward pass there
+    (``layers/moe.py`` says why), so the routers of a share stay where
+    they started and the held experts' load stays the seed's.
+
+    Trains on packed token rows (``iter = tokens``) in which a document
+    begins after every separator id 0; mixers and attention read the
+    starts from the ids (their second input), and rotary positions are
+    counted from a document's first token.  Written for memory as
+    ``granite_h_conf`` is: ``remat = 1``, ``eval_train = 0``.
+    """
+    data = ""
+    if token_file:
+        data = (
+            "data = train\n"
+            "iter = tokens\n"
+            f"  filename = {token_file}\n"
+            f"  seq_len = {seq_len}\n"
+            "iter = end\n"
+        )
+    branch = (f"  prenorm = 1\n  eps = {eps!r}\n"
+              "  residual_scale = 1.0\n"
+              "  init_sigma = 0.02\n")
+    rotary_dim = int(head_dim * partial_rotary_factor)
+    s = (
+        "netconfig = start\n"
+        "layer[0->h0] = embedding:embed\n"
+        f"  nvocab = {vocab}\n"
+        f"  nhidden = {hidden}\n"
+        "  init_sigma = 0.02\n"
+    )
+    for i, kind in enumerate(layer_types):
+        if kind == "l":
+            s += (
+                f"layer[h{i},0->x{i}] = gated_deltanet:gdn{i}\n"
+                f"  nkhead = {linear_key_heads}\n"
+                f"  nvhead = {linear_value_heads}\n"
+                f"  key_dim = {linear_key_dim}\n"
+                f"  value_dim = {linear_value_dim}\n"
+                f"  conv_width = {linear_conv}\n"
+                f"  chunk = {linear_chunk}\n" + branch
+            )
+        elif kind == "f":
+            s += (
+                f"layer[h{i},0->x{i}] = attention:attn{i}\n"
+                f"  nhead = {attn_heads}\n"
+                f"  nkvhead = {attn_kv_heads}\n"
+                f"  head_dim = {head_dim}\n"
+                "  qk_norm = 1\n"
+                f"  rotary_dim = {rotary_dim}\n"
+                f"  rope_theta = {rope_theta!r}\n"
+                "  out_gate = 1\n"
+                "  causal = 1\n  no_bias = 1\n" + branch
+            )
+        else:
+            raise ValueError(
+                f"qwen3_next_conf: layer_types is a string of l and f, "
+                f"got {kind!r}")
+        s += (
+            f"layer[x{i}->h{i + 1}] = routed_experts:moe{i}\n"
+            f"  nexpert = {num_experts}\n"
+            f"  topk = {experts_per_tok}\n"
+            f"  nhidden = {expert_hidden}\n"
+            f"  first_expert = {first_expert}\n"
+            f"  nheld = {experts_held}\n"
+            f"  shared_hidden = {shared_hidden}\n"
+            "  norm_topk = 1\n" + branch
+        )
+    s += (
+        f"layer[h{len(layer_types)}->nf] = rms_norm:norm_f\n"
+        f"  eps = {eps!r}\n"
+        "layer[nf->logits] = lm_head:head\n"
+        f"  nhidden = {vocab}\n"
+        "  init_sigma = 0.02\n"
+        "layer[logits->logits] = softmax\n"
+        # the mean over all positions: the loss sums over T
+        f"  grad_scale = {1.0 / seq_len!r}\n"
+        "netconfig = end\n"
+    )
+    extra = (
+        f"compute_dtype = {compute_dtype}\n"
+        f"label_width = {seq_len}\n"
+        f"label_vec[0,{seq_len}) = label\n"
+        "metric = logloss\n"
+        "updater = adam\n"
+        "wd = 0.0\n"
+        "remat = 1\n"
+        "eval_train = 0\n"
+    )
+    return data + s + _tail(
+        batch_size, f"1,1,{seq_len}", num_round, eta=eta, dev=dev,
+        extra=extra, scan_steps=scan_steps,
+    )
+
+
 def _res_bottleneck(prev: str, name: str, cin: int, cmid: int, cout: int,
                     stride: int) -> str:
     """Bottleneck residual block: 1x1 reduce -> 3x3 -> 1x1 expand, each

@@ -107,6 +107,9 @@ class NetTrainer:
         self._quant_requested = ""
         self.mesh_plan: Optional[MeshPlan] = None
         self.aux = {}  # non-gradient layer state (BN running stats)
+        # (params key, state leaf) -> the value count_layer_state last
+        # read of a counter a layer keeps in its aux state
+        self._aux_counted: Dict[Tuple[str, str], int] = {}
         self.metric = MetricSet()
         self.train_metric = MetricSet()
         self._grad_accum = None
@@ -343,6 +346,7 @@ class NetTrainer:
         self._rng_key, sub = jax.random.split(self._rng_key)
         self.params = self.net.init_params(sub, self.batch_size)
         self.aux = self.net.init_aux(self.batch_size)
+        self._aux_counted = {}
         self._validate_det_reduce()
         self._build_updaters()
         self.epoch_counter = 0
@@ -1310,6 +1314,36 @@ class NetTrainer:
         if self._async is None:
             return None
         return self._async.snapshot()
+
+    def _layer_counters(self):
+        """(params key, state leaf, counter name) of every counter a
+        layer keeps in its aux state: a layer type lists them as
+        ``aux_counters = {leaf: name}`` (``layers/moe.py``)."""
+        for i, lay in enumerate(self.net.layer_objs):
+            key = self.net.param_key[i]
+            if key in self.aux:
+                for leaf, name in getattr(lay, "aux_counters", {}).items():
+                    yield key, leaf, name
+
+    def count_layer_state(self) -> None:
+        """Add to the round's ``PipelineStats`` counters what the
+        layers counted inside the step programs since this was last
+        called (``routed_experts``: ``expert_pairs``,
+        ``expert_pairs_max``, ``expert_pairs_dropped``, summed over the
+        layers).  The counts ride the programs' ``aux`` state as
+        wrapping uint32; this is their one fetch, a few scalars, made by
+        the CLI once a round after the round's last fence — no program
+        is in flight and no chunk's period holds it."""
+        found = list(self._layer_counters())
+        if not found:
+            return
+        now = jax.device_get({(k, l): self.aux[k][l] for k, l, _ in found})
+        stats = pipeline_stats()
+        for key, leaf, name in found:
+            val = int(now[(key, leaf)])
+            last = self._aux_counted.get((key, leaf), 0)
+            self._aux_counted[(key, leaf)] = val
+            stats.count(name, (val - last) % (1 << 32))
 
     def start_round(self, round_: int) -> None:
         self.round = round_
@@ -2328,6 +2362,11 @@ class NetTrainer:
         for key, tags in raw_aux.items():
             if key in self.aux:
                 self.aux[key] = {t: jnp.asarray(w) for t, w in tags.items()}
+        # what a checkpoint's counters had counted is not this run's
+        self._aux_counted = {
+            (key, leaf): int(raw_aux[key][leaf])
+            for key, leaf, _ in self._layer_counters()
+            if leaf in raw_aux.get(key, {})}
         self.net.infer_shapes(self.batch_size)
         self._validate_det_reduce()
         self._build_updaters()

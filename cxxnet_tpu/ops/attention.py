@@ -19,6 +19,8 @@ sharded on ``axis_name``.
 from __future__ import annotations
 
 import functools
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -78,6 +80,38 @@ def _softmax(s: jnp.ndarray) -> jnp.ndarray:
         lax.stop_gradient(s.max(axis=-1, keepdims=True)))
     e = jnp.exp(s - m)
     return e / e.sum(axis=-1, keepdims=True)
+
+
+def doc_positions(doc, n: int, t: int) -> jnp.ndarray:
+    """``(N, T)`` int32 positions counted from each document's first
+    token (``doc``: a non-decreasing document index a token; ``None``:
+    one document a row)."""
+    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (n, t))
+    if doc is None:
+        return pos
+    start = jnp.concatenate(
+        [jnp.ones((n, 1), bool), doc[:, 1:] != doc[:, :-1]], axis=1)
+    return pos - lax.cummax(jnp.where(start, pos, 0), axis=1)
+
+
+def rotary(x: jnp.ndarray, pos: jnp.ndarray, dim: int,
+           theta: float = 10000.0) -> jnp.ndarray:
+    """Rotate-half rotary positions (Su et al. 2021, as GPT-NeoX and the
+    Qwen families apply them) on the first ``dim`` of each head of ``x
+    (N, T, H, Dh)``: with ``x1 | x2`` the two halves of those ``dim``,
+    ``x1 cos - x2 sin | x2 cos + x1 sin`` at the angles ``pos *
+    theta^(-2i/dim)``; the rest of the head passes.  Angles and the
+    rotation in float32."""
+    half = dim // 2
+    freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                   * (-2.0 * math.log(theta) / dim))
+    ang = pos.astype(jnp.float32)[..., None] * freq          # (N, T, half)
+    cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
+    xf = x[..., :dim].astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    turned = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    return jnp.concatenate([turned, x[..., dim:]], axis=-1)
 
 
 def mha(

@@ -62,7 +62,8 @@ def test_chunk_overlap_pct_reads_the_two_fence_counters(rounds, want):
 
 
 ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
-             "granite_4_0_h_micro_train_packed8k"]
+             "granite_4_0_h_micro_train_packed8k",
+             "qwen3_next_80b_a3b_train_packed8k"]
 
 
 @pytest.mark.parametrize("name, cells", [
@@ -81,5 +82,7 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells):
         entry["better"])
     assert entry["workloads"] == cells
     assert set(cells) <= {w["name"] for w in bench["workloads"]}
-    # a new entry goes to the end of the list, behind those it found
-    assert bench["per_layer"][-1]["name"] == "chunk_overlap_pct"
+    # a new entry goes to the end of the list, behind those it found:
+    # the 31st when PR 32 added it, and PR 33's ten behind it
+    assert [m["name"] for m in bench["per_layer"]].index(
+        "chunk_overlap_pct") == 30

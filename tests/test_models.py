@@ -28,6 +28,14 @@ def test_model_shapes(name):
                        hidden=32, mamba_heads=4, mamba_head_dim=16,
                        mamba_state=8, mamba_chunk=8, attn_heads=4,
                        attn_kv_heads=2, mlp_hidden=48, layer_types="mam")
+    elif name == "qwen3_next":  # the defaults are the published widths
+        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
+                       hidden=32, layer_types="lf", linear_key_heads=2,
+                       linear_value_heads=4, linear_key_dim=8,
+                       linear_value_dim=8, linear_chunk=8, attn_heads=4,
+                       attn_kv_heads=2, head_dim=16, num_experts=8,
+                       experts_per_tok=2, expert_hidden=16, shared_hidden=16,
+                       experts_held=4)
     elif name.startswith("mnist") or name in ("kaggle_bowl",
                                               "transformer_lm"):
         text = builder(batch_size=4, dev="cpu")
@@ -44,7 +52,7 @@ def test_model_shapes(name):
               "googlenet": 1000, "vgg16": 1000, "vgg19": 1000,
               "kaggle_bowl": 121,
               "transformer": 10, "transformer_lm": 256,
-              "granite_h": 64,
+              "granite_h": 64, "qwen3_next": 64,
               "resnet50": 1000, "resnet101": 1000,
               "resnet152": 1000}[name]
     assert out[-1] == expect
