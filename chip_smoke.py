@@ -352,6 +352,8 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
     from cxxnet_tpu.ops import quant as opsq
     from cxxnet_tpu.ops.attention import mha
     from cxxnet_tpu.ops.flash import flash_mha, flash_mha_lse
+    from cxxnet_tpu.ops.gdn import gated_delta_recurrence
+    from cxxnet_tpu.ops.gdn_fused import gated_delta_fused
     from cxxnet_tpu.ops.kernels import conv_block, int8_gemm, update_step
     from cxxnet_tpu.ops.lrn import lrn, lrn_xla
     from cxxnet_tpu.ops.maxpool import maxpool_bwd_s1, maxpool_fused
@@ -442,7 +444,30 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
         w2, s2 = up.apply(w, g, {"m": m}, epoch)
         return w2, s2["m"]
 
+    # -- the gated delta rule at Qwen3-Next's head widths (128 x 128,
+    # two value heads a key head), documents inside a row; unit-length
+    # keys, a decay and a write strength are made of the raw operands
+    # on both sides
+    td, hkd = (128, 1) if toy else (2048, 2)
+    doc = jnp.asarray(np.cumsum(
+        np.random.RandomState(4).rand(2, td) < 4.0 / td, axis=1), jnp.int32)
+
+    def delta(scan):
+        def run(q, k, v, g, b):
+            unit = lambda a: a * lax.rsqrt(  # noqa: E731
+                (a.astype(jnp.float32) ** 2).sum(-1, keepdims=True)
+                + 1e-6).astype(a.dtype)
+            return (scan(unit(q) * q.dtype.type(128 ** -0.5), unit(k), v,
+                         -jax.nn.softplus(g), jax.nn.sigmoid(b), doc),)
+        return with_grads(run, 5)
+
     return [
+        ("gated_delta_fused fwd+bwd", "ok",
+         delta(lambda *a: gated_delta_fused(*a, interpret=interpret)),
+         delta(gated_delta_recurrence),
+         tuple(arr(2, td, h, 128) for h in (hkd, hkd, 2 * hkd)) + tuple(
+             arr(2, td, 2 * hkd, dtype=jnp.float32) for _ in range(2)),
+         2 * BF16),
         ("flash_mha fwd+bwd", "ok",
          with_grads(lambda q, k, v: (flash_mha(
              q, k, v, True, 512, 512, interpret),), 3),

@@ -16,10 +16,16 @@ Hk)``)
     y = rms_norm_Dv(o) * gate_norm * silu(z)       one norm a head
     out = y W_out
 
-with the scan in its chunked form (``ops/gdn.py``: one implementation,
-plain ``jax.numpy``, differentiated by ``jax.grad``).  The rows of
-``W_in`` are ``q | k | v | z``, each head-major: a permutation of the
-family's checkpoints, which interleave the four by key-head group.
+with the scan in its chunked form (``ops/gdn.gated_delta_scan``: on a
+TPU, at the widths they are written for, fused Pallas kernels with
+their own backward; everywhere else the same algorithm in plain
+``jax.numpy`` differentiated by ``jax.grad``.  The program reads which
+from the platform it is lowered for and from the shapes; no conf key
+chooses, and the layer counts what ran: below).  ``q`` and ``k`` go to
+the scan with their own ``Hk`` heads; a value head reads its key head.
+The rows of ``W_in`` are ``q | k | v | z``, each head-major: a
+permutation of the family's checkpoints, which interleave the four by
+key-head group.
 
 ``gated_deltanet`` config keys:
 
@@ -46,9 +52,19 @@ Parameters (tags): ``wmat`` (2 HkDk + 2 HvDv, D), ``wba`` (2 Hv, D),
 ``prenorm``.  All stay float32 at rest under mixed precision and are
 cast where they are used, as ``mamba2``'s.
 
+State (``aux``, carried through the step programs and read once a
+round by ``NetTrainer.count_layer_state``, as ``routed_experts``'):
+``scan_tokens`` — tokens through the scan; ``scan_tokens_fused`` —
+those of them the fused kernels computed, which the branch that ran
+says for itself (``ops/gdn.gated_delta_scan_counted``).  uint32,
+wrapping: the reader takes differences.  The round's counters
+``gdn_scan_tokens`` and ``gdn_scan_tokens_fused`` sum them over the
+layers.
+
 Every stage runs under a ``jax.named_scope`` of its own (``in_proj``,
 ``conv``, ``scan``, ``gate_norm``, ``out_proj``) inside the layer's, so
-a profiler trace splits the mixer.
+a profiler trace splits the mixer; the kernels, forward and backward,
+run under ``scan``.
 """
 
 from __future__ import annotations
@@ -59,29 +75,45 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..ops.gdn import gated_delta_scan
+from ..ops.gdn import gated_delta_scan_counted
 from ..ops.ssd import doc_index
 from .base import Layer, Params, Shape, register
 from .sequence import Branch, _check_ids_input, rms_norm
 from .ssm import causal_conv
 
 
-#: the scan walks a longer row in segments of this many tokens, each
-#: under ``jax.checkpoint``, so its backward holds one segment's chunk
-#: matrices and not the row's (``ops/gdn.gated_delta_scan``)
+#: the ``jax.numpy`` scan walks a longer row in segments of this many
+#: tokens, each under ``jax.checkpoint``, so its backward holds one
+#: segment's chunk matrices and not the row's (``ops/gdn.gated_delta_xla``;
+#: the kernels keep no chunk matrices and walk the row whole)
 SEGMENT = 2048
 
+#: the layer's ``aux`` state: what the scan counts
+COUNTERS = ("scan_tokens", "scan_tokens_fused")
 
-def unit(x, eps: float = 1e-6):
-    """``x / sqrt(sum(x^2) + eps)`` over the last axis, in float32."""
-    xf = x.astype(jnp.float32)
-    return xf * jax.lax.rsqrt((xf * xf).sum(axis=-1, keepdims=True)
-                              + jnp.float32(eps))
+
+def head_norm(o, w, eps: float):
+    """``rms_norm`` over each head's ``Dv`` of ``o (N, T, H, Dv)``, taken
+    on the view ``(N, T/8, H, 8, Dv)``: its row-major order is the order
+    of the ``(N, T, H Dv)`` rows the scan's kernels write and read (eight
+    tokens of one head are one tile), so on the TPU the norm and its
+    gradient run in place, where the ``(N, T, H, Dv)`` view costs a
+    float32 copy of the whole tensor each way — three of 134 MB a layer
+    and step at 8192 tokens and 32 heads, outside every scope (read from
+    a compile for a described v5e, PR 34).  The same numbers either way."""
+    n, t, h, d = o.shape
+    if t % 8:
+        return rms_norm(o, w, eps)
+    rows = o.reshape(n, t // 8, 8, h, d).transpose(0, 1, 3, 2, 4)
+    return rms_norm(rows, w, eps).transpose(0, 1, 3, 2, 4).reshape(o.shape)
 
 
 @register
 class GatedDeltaNetLayer(Layer, Branch):
     type_name = "gated_deltanet"
+    #: state leaf -> the round's counter it is added to
+    #: (``NetTrainer.count_layer_state``)
+    aux_counters = {name: "gdn_" + name for name in COUNTERS}
     f32_tags = frozenset({"wmat", "wba", "conv", "dt_bias", "a_log",
                           "gate_norm", "wproj", "norm"})
 
@@ -149,7 +181,23 @@ class GatedDeltaNetLayer(Layer, Branch):
         out.update(self.branch_params(d))
         return out
 
+    def init_aux(self, in_shapes):
+        return {name: jnp.zeros((), jnp.uint32) for name in COUNTERS}
+
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
+        return self._run(params, inputs)[0]
+
+    def apply_stateful(self, params, aux, inputs, *, train=False, rng=None,
+                       step=None):
+        outs, fused = self._run(params, inputs)
+        tokens = jnp.uint32(inputs[0].shape[0] * inputs[0].shape[1])
+        return outs, {
+            "scan_tokens": aux["scan_tokens"] + tokens,
+            "scan_tokens_fused": aux["scan_tokens_fused"] + tokens * fused,
+        }
+
+    def _run(self, params, inputs):
+        """``([out], 1 if the fused kernels computed the scan else 0)``."""
         x0 = inputs[0]
         n, t, _ = x0.shape
         hk, hv, dk, dv = self.nkhead, self.nvhead, self.key_dim, self.value_dim
@@ -166,20 +214,20 @@ class GatedDeltaNetLayer(Layer, Branch):
             qkv = jax.nn.silu(causal_conv(
                 qkv, params["conv"].astype(cdt), jnp.zeros((), cdt), doc))
         with jax.named_scope("scan"):
-            rep = hv // hk
-            q = jnp.repeat(qkv[..., :ek].reshape(n, t, hk, dk), rep, axis=2)
-            k = jnp.repeat(qkv[..., ek:2 * ek].reshape(n, t, hk, dk), rep,
-                           axis=2)
+            q = qkv[..., :ek].reshape(n, t, hk, dk)
+            k = qkv[..., ek:2 * ek].reshape(n, t, hk, dk)
             v = qkv[..., 2 * ek:].reshape(n, t, hv, dv)
-            q = (unit(q) * f32(1.0 / math.sqrt(dk))).astype(cdt)
-            k = unit(k).astype(cdt)
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(params["a_log"].astype(f32)) * jax.nn.softplus(
                 ba[..., hv:] + params["dt_bias"].astype(f32))
-            o = gated_delta_scan(q, k, v, g, beta, doc, self.chunk, SEGMENT)
+            # q and k go as the convolution left them: the scan brings
+            # them to unit length, and q to 1 / sqrt(Dk)
+            o, fused = gated_delta_scan_counted(
+                q, k, v, g, beta, doc, self.chunk, SEGMENT, unit=1e-6,
+                q_scale=1.0 / math.sqrt(dk))
         with jax.named_scope("gate_norm"):
-            y = rms_norm(o, params["gate_norm"], self.eps).reshape(
+            y = head_norm(o, params["gate_norm"], self.eps).reshape(
                 n, t, ev) * jax.nn.silu(z)
         with jax.named_scope("out_proj"):
             out = y @ params["wproj"].astype(cdt).T
-        return [self.branch_out(x0, out)]
+        return [self.branch_out(x0, out)], fused

@@ -29,11 +29,29 @@ chunk's start to ``l``, ``S_0`` the state that enters the chunk).  So
   one ``(Dk, Dk) @ (Dk, Dv)`` product a step, in float32;
 * and every output is ``(q c) S_0 + (q k^T . decay) (U0 - W S_0)``.
 
-One implementation for the program: plain ``jax.numpy`` that XLA fuses,
-differentiated by ``jax.grad``.  Decays are kept in float32 and in log
-space until the one ``exp`` of a difference that is never positive; the
-products take the activations' dtype and accumulate in float32; the
-solve and the carried state are float32.
+One algorithm and one call site, ``gated_delta_scan``, in two forms.
+Decays are kept in float32 and in log space until the one ``exp`` of a
+difference that is never positive; the products take the activations'
+dtype and accumulate in float32; the solve and the carried state are
+float32 — in both:
+
+* plain ``jax.numpy`` that XLA fuses, differentiated by ``jax.grad``
+  (``gated_delta_xla``): what runs wherever the other does not, and
+  what the kernels are tested against, with the token-by-token
+  ``gated_delta_recurrence``;
+* fused Pallas kernels with their own backward (``ops/gdn_fused.py``),
+  which keep a chunk's matrices and the state on the chip where the
+  first writes each to HBM (193 ms of a 508 ms step at 1% of the scan's
+  roofline, ledger PR 33).
+
+Which runs is read from what the code can observe, and no conf key
+chooses: the platform the program is LOWERED for
+(``jax.lax.platform_dependent``: a TPU takes the kernels, also when the
+lowering host is a CPU that compiles for a described chip; everything
+else the ``jax.numpy`` form) and the shapes the kernels are written for
+(``gdn_fused.supported``: chunks of 64, ``Dk`` and ``Dv`` multiples of
+128, bfloat16 or float32).  ``gated_delta_scan_counted`` also returns
+which branch ran, from inside the branch, for the layer's counter.
 
 Documents: ``doc`` is a non-decreasing document index a token; no
 state, and nothing inside a chunk, crosses from one index to the next.
@@ -52,11 +70,63 @@ def gated_delta_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      g: jnp.ndarray, beta: jnp.ndarray,
                      doc: Optional[jnp.ndarray] = None,
                      chunk: int = 64, segment: int = 0) -> jnp.ndarray:
-    """``q``/``k (N,T,H,Dk)`` (``k`` of unit length for the rule to be
-    a contraction), ``v (N,T,H,Dv)``, ``g (N,T,H)`` float32 and never
-    positive (the log of the decay), ``beta (N,T,H)`` float32, ``doc
-    (N,T)`` int32 or ``None`` (one document a row) -> ``o (N,T,H,Dv)``
-    in ``v``'s dtype.
+    """``q``/``k (N,T,Hk,Dk)`` (``k`` of unit length for the rule to be
+    a contraction), ``v (N,T,H,Dv)`` with ``Hk`` dividing ``H`` (value
+    head ``j`` reads key head ``j // (H / Hk)``), ``g (N,T,H)`` float32
+    and never positive (the log of the decay), ``beta (N,T,H)``
+    float32, ``doc (N,T)`` int32 or ``None`` (one document a row) ->
+    ``o (N,T,H,Dv)`` in ``v``'s dtype.
+
+    ``segment`` is the ``jax.numpy`` form's (``gated_delta_xla``); the
+    kernels hold no chunk matrices through the backward and walk the
+    row whole."""
+    return gated_delta_scan_counted(q, k, v, g, beta, doc, chunk, segment)[0]
+
+
+def unit_rows(x, eps: float = 1e-6):
+    """``x / sqrt(sum(x^2) + eps)`` over the last axis, in float32."""
+    xf = x.astype(jnp.float32)
+    return xf * lax.rsqrt((xf * xf).sum(axis=-1, keepdims=True)
+                          + jnp.float32(eps))
+
+
+def gated_delta_scan_counted(q, k, v, g, beta, doc=None, chunk: int = 64,
+                             segment: int = 0, unit=None,
+                             q_scale: float = 1.0):
+    """``(gated_delta_scan's o, 1 if the fused kernels computed it else
+    0)``: the second is a uint32 scalar each branch returns for itself,
+    so it says what ran where the program was lowered for.
+
+    ``unit`` (an eps) hands over ``q`` and ``k`` as they come: the scan
+    takes ``unit_rows(k, unit)`` and ``unit_rows(q, unit) * q_scale``,
+    rounded to their dtype — the kernels a tile at a time in VMEM, the
+    ``jax.numpy`` form before it starts."""
+    from . import gdn_fused
+
+    def xla(q, k, v, g, beta, doc):
+        if unit is not None:
+            q = (unit_rows(q, unit) * jnp.float32(q_scale)).astype(q.dtype)
+            k = unit_rows(k, unit).astype(k.dtype)
+        return (gated_delta_xla(q, k, v, g, beta, doc, chunk, segment),
+                jnp.uint32(0))
+
+    def fused(q, k, v, g, beta, doc):
+        return (gdn_fused.gated_delta_fused(q, k, v, g, beta, doc, unit,
+                                            q_scale), jnp.uint32(1))
+
+    if doc is None:
+        doc = jnp.zeros(q.shape[:2], jnp.int32)
+    if not gdn_fused.supported(q, k, v, chunk):
+        return xla(q, k, v, g, beta, doc)
+    return lax.platform_dependent(q, k, v, g, beta, doc, tpu=fused,
+                                  default=xla)
+
+
+def gated_delta_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    g: jnp.ndarray, beta: jnp.ndarray,
+                    doc: Optional[jnp.ndarray] = None,
+                    chunk: int = 64, segment: int = 0) -> jnp.ndarray:
+    """``gated_delta_scan`` in plain ``jax.numpy``.
 
     ``segment`` > 0 (a multiple of ``chunk``) walks the row in segments
     of that many tokens, one after the other, each under
@@ -65,7 +135,10 @@ def gated_delta_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     not the row's (at 8192 tokens, 32 heads of 128 x 128 and chunks of
     64 that is 4 GB a layer against 1 GB at 2048), for one more
     forward pass of the scan."""
-    n, t, h, dk = q.shape
+    h = v.shape[2]
+    if q.shape[2] != h:
+        q, k = (jnp.repeat(a, h // a.shape[2], axis=2) for a in (q, k))
+    n, t, _, dk = q.shape
     dv = v.shape[-1]
     c = int(chunk)
     if c < 1 or c & (c - 1):
@@ -210,7 +283,10 @@ def gated_delta_recurrence(q, k, v, g, beta, doc=None):
     """The same function token by token, in float32: one ``lax.scan``
     over the tokens.  What ``gated_delta_scan`` and its gradient are
     held against in the tests; nothing in the program calls it."""
-    n, t, h, dk = q.shape
+    h = v.shape[2]
+    if q.shape[2] != h:
+        q, k = (jnp.repeat(a, h // a.shape[2], axis=2) for a in (q, k))
+    n, t, _, dk = q.shape
     f32 = jnp.float32
     if doc is None:
         doc = jnp.zeros((n, t), jnp.int32)

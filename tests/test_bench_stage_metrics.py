@@ -61,6 +61,31 @@ def test_chunk_overlap_pct_reads_the_two_fence_counters(rounds, want):
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
+# gdn_scan_fused_pct (PR 34): the gated_deltanet layers count the tokens
+# through their scan and those the fused kernels computed, inside the
+# step programs
+@pytest.mark.parametrize("rounds, want", [
+    # three layers x 24 steps x 8192 tokens a round, every one fused
+    ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824,
+       "expert_pairs": 61440}] * 2, 100.0),
+    # a round whose programs were lowered for another platform
+    ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824},
+      {"gdn_scan_tokens": 589824}], 50.0),
+    # the jax.numpy form: counted, none fused
+    ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 0}], 0.0),
+    ([{"gdn_scan_tokens": 589824}], 0.0),
+    # the parent counts neither; other layers' counters are not tokens
+    ([{"expert_pairs": 61440, "tokens": 196608}], None),
+    ([{"gdn_scan_tokens": 0}], None),
+    ([{}], None),
+    ([], None),
+])
+def test_gdn_scan_fused_pct_reads_the_two_scan_counters(rounds, want):
+    read = run.load_metric("gdn_scan_fused_pct").read
+    assert read(_counted(*rounds)) == want
+    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
+
+
 ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
              "granite_4_0_h_micro_train_packed8k",
              "qwen3_next_80b_a3b_train_packed8k"]
@@ -69,6 +94,7 @@ ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
 @pytest.mark.parametrize("name, cells", [
     ("train_metric_device_pct", ALL_CELLS[:2]),
     ("chunk_overlap_pct", ALL_CELLS),
+    ("gdn_scan_fused_pct", ALL_CELLS[3:]),
 ])
 def test_benchmark_json_names_the_reader_that_exists(name, cells):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -84,5 +110,7 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells):
     assert set(cells) <= {w["name"] for w in bench["workloads"]}
     # a new entry goes to the end of the list, behind those it found:
     # the 31st when PR 32 added it, and PR 33's ten behind it
-    assert [m["name"] for m in bench["per_layer"]].index(
-        "chunk_overlap_pct") == 30
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.index("chunk_overlap_pct") == 30
+    # PR 34's one entry is the last
+    assert names.index("gdn_scan_fused_pct") == len(names) - 1 == 41

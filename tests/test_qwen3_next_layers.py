@@ -20,7 +20,10 @@ from cxxnet_tpu.layers.moe import held_experts, route
 from cxxnet_tpu.models import qwen3_next_conf
 from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.ops.attention import doc_positions, rotary
-from cxxnet_tpu.ops.gdn import gated_delta_recurrence, gated_delta_scan
+from cxxnet_tpu.ops.gdn import (gated_delta_recurrence, gated_delta_scan,
+                                gated_delta_scan_counted, gated_delta_xla,
+                                unit_rows)
+from cxxnet_tpu.ops.gdn_fused import gated_delta_fused, supported
 from cxxnet_tpu.ops.ssd import doc_index
 from cxxnet_tpu.utils.profiler import pipeline_stats
 
@@ -105,6 +108,124 @@ def test_checkpointed_segments_carry_the_state_between_them(chunk, segment):
     np.testing.assert_allclose(ga, gb, atol=5e-6)
     with pytest.raises(ValueError, match="multiple of chunk"):
         gated_delta_scan(*xs, doc, chunk, chunk + 1)
+
+
+# ----------------------------------------------------------------------
+# the fused kernels (ops/gdn_fused.py), on the CPU's interpreter, against
+# the token-by-token recurrence and the jax.numpy chunked form
+def fused_inputs(case, dtype):
+    """Heads of 128 x 128, the width the kernels are written for."""
+    r = np.random.RandomState(5)
+    n, t, hk, hv = {"documents": (1, 640, 1, 2), "ragged": (2, 150, 2, 2)}[
+        case]
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    q = unit(r.randn(n, t, hk, 128)) / math.sqrt(128)
+    k = unit(r.randn(n, t, hk, 128))
+    v = r.randn(n, t, hv, 128)
+    g = -0.3 * np.abs(r.randn(n, t, hv))
+    beta = 1 / (1 + np.exp(-r.randn(n, t, hv)))
+    doc = None
+    if case == "documents":
+        # a boundary inside a chunk, two at chunks' edges, a document
+        # longer than a stretch of 8 chunks (it crosses the stretch's
+        # edge at 512), one more inside the last chunk
+        doc = np.zeros((n, t), np.int32)
+        for start in (30, 64, 128, 128 + 530 - 40, 630):
+            doc[:, start:] += 1
+    xs = tuple(jnp.asarray(a, jnp.float32).astype(dtype) for a in (q, k, v))
+    return xs + (jnp.asarray(g, jnp.float32),
+                 jnp.asarray(beta, jnp.float32)), doc
+
+
+def through_cos(fn, xs):
+    """``fn``'s output in float32 and the cotangents of ``xs`` under
+    ``cos`` of it: forward and all five gradients of a scan."""
+    out, back = jax.vjp(lambda *a: fn(*a).astype(jnp.float32), *xs)
+    return (out,) + back(jnp.cos(out))
+
+
+def assert_near_the_recurrence(got, form, want, dtype):
+    """The kernels' ``got`` against the recurrence's ``want``: to
+    float32 rounding, or, in bfloat16, as near as the jax.numpy
+    ``form`` on the same operands is, within a half."""
+    for name, a, b, c in zip(("o", "dq", "dk", "dv", "dg", "dbeta"),
+                             got, form, want):
+        a, b, c = (np.asarray(x, np.float32) for x in (a, b, c))
+        assert np.isfinite(a).all(), name
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, c, atol=3e-6 * np.abs(c).max(),
+                                       err_msg=name)
+            np.testing.assert_allclose(a, b, atol=3e-6 * np.abs(c).max(),
+                                       err_msg=name)
+        else:
+            err = np.linalg.norm(a - c) / np.linalg.norm(c)
+            ref = np.linalg.norm(b - c) / np.linalg.norm(c)
+            assert err < 1.5 * ref + 1e-3, (name, err, ref)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["documents", "ragged"])
+def test_fused_kernels_are_the_recurrence_and_the_chunked_form(case, dtype):
+    xs, doc = fused_inputs(case, dtype)
+    assert supported(*xs[:3], 64)
+    with jax.default_matmul_precision("highest"):
+        got = through_cos(
+            lambda *a: gated_delta_fused(*a, doc, interpret=True), xs)
+        form = through_cos(lambda *a: gated_delta_xla(*a, doc, 64), xs)
+        want = through_cos(lambda *a: gated_delta_recurrence(*a, doc), xs)
+    assert got[0].shape == want[0].shape
+    assert_near_the_recurrence(got, form, want, dtype)
+    if dtype == jnp.bfloat16:
+        # the forward rounds where the jax.numpy form rounds
+        np.testing.assert_allclose(
+            np.asarray(got[0]), np.asarray(form[0]),
+            atol=2e-2 * np.abs(np.asarray(want[0])).max())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_fused_kernels_bring_q_and_k_to_unit_length_themselves(dtype):
+    """``unit``: rows of any length go in, the kernels normalise a tile
+    at a time and take the gradient back through it; the jax.numpy
+    branch does the same before it starts."""
+    (q, k, v, g, beta), doc = fused_inputs("ragged", dtype)
+    xs = ((3.0 * q).astype(dtype), (0.4 * k).astype(dtype), v, g, beta)
+    scale = 1.0 / math.sqrt(128)
+
+    def plain(scan):
+        return lambda q, k, *a: scan(
+            (unit_rows(q, 1e-6) * jnp.float32(scale)).astype(dtype),
+            unit_rows(k, 1e-6).astype(dtype), *a)
+
+    with jax.default_matmul_precision("highest"):
+        got = through_cos(lambda *a: gated_delta_fused(
+            *a, doc, unit=1e-6, q_scale=scale, interpret=True), xs)
+        form = through_cos(lambda *a: gated_delta_scan_counted(
+            *a, doc, unit=1e-6, q_scale=scale)[0], xs)
+        want = through_cos(
+            plain(lambda *a: gated_delta_recurrence(*a, doc)), xs)
+    assert_near_the_recurrence(got, form, want, dtype)
+
+
+def test_the_platform_and_the_shapes_choose_the_path_and_say_so():
+    """Off the TPU the jax.numpy form runs and counts 0 fused, whatever
+    the widths; widths the kernels are not written for never reach
+    them."""
+    xs, doc = fused_inputs("ragged", jnp.float32)
+    o, fused = jax.jit(lambda *a: gated_delta_scan_counted(*a, doc))(*xs)
+    assert int(fused) == 0 and fused.dtype == jnp.uint32
+    np.testing.assert_array_equal(
+        o, jax.jit(lambda *a: gated_delta_xla(*a, doc))(*xs))
+    narrow, _ = delta_inputs()
+    assert not supported(*narrow[:3], 8)
+    assert not supported(*xs[:3], 32)
+    assert not supported(xs[0], xs[1].astype(jnp.bfloat16), xs[2], 64)
+    # and what a TPU would be handed holds the kernels: Mosaic lowers here
+    exported = jax.export.export(
+        jax.jit(lambda *a: gated_delta_scan_counted(*a, doc)),
+        platforms=["tpu"])(*xs)
+    assert "tpu_custom_call" in exported.mlir_module()
 
 
 def test_gated_deltanet_layer_shapes_and_document_reset():
@@ -405,7 +526,7 @@ def test_the_builder_s_conf_trains_and_counts_its_pairs():
     tr.set_params(cfgmod.parse_pairs(text))
     tr.set_param("silent", "1")
     tr.init_model()
-    assert set(tr.aux) == {"l2_moe0", "l4_moe1"}
+    assert set(tr.aux) == {"l1_gdn0", "l2_moe0", "l4_moe1"}
     r = np.random.RandomState(0)
     ids = r.randint(0, 64, (4, 1, 64)).astype(np.float32)
     router = np.asarray(tr.params["l2_moe0"]["wgate"]).copy()
@@ -427,6 +548,29 @@ def test_the_builder_s_conf_trains_and_counts_its_pairs():
     assert got.get("expert_pairs_dropped", 0) == 0
     tr.count_layer_state()               # nothing new: nothing added
     assert stats.counters()["expert_pairs"] - before == pairs
+
+
+def test_a_cpu_run_counts_the_scan_s_tokens_and_none_fused():
+    """The gated_deltanet layer's counters: tokens x layers through the
+    scan, and those the kernels computed - none off the TPU."""
+    tr = NetTrainer()
+    tr.set_params(cfgmod.parse_pairs(qwen3_next_conf(**TINY)))
+    tr.set_param("silent", "1")
+    tr.init_model()
+    assert set(tr.aux["l1_gdn0"]) == {"scan_tokens", "scan_tokens_fused"}
+    stats = pipeline_stats()
+    before = dict(stats.counters())
+    ids = np.random.RandomState(1).randint(0, 64, (4, 1, 64)).astype(
+        np.float32)
+    tr.update_scan(ids, np.roll(ids, -1, axis=2))
+    tr.count_layer_state()
+    got = stats.counters()
+    # 4 steps of one row of 64 tokens, one delta-rule layer
+    assert got["gdn_scan_tokens"] - before.get("gdn_scan_tokens", 0) == 256
+    assert got.get("gdn_scan_tokens_fused", 0) == before.get(
+        "gdn_scan_tokens_fused", 0)
+    tr.count_layer_state()
+    assert stats.counters()["gdn_scan_tokens"] == got["gdn_scan_tokens"]
 
 
 def test_the_published_defaults_are_what_the_issue_reckoned():

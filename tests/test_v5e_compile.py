@@ -6,9 +6,13 @@ on-chip-measurement guide, section 2: nothing runs, no chip is needed).
   kernels named ``ragged-dot-*`` whose layer scope is dropped — the
   name ``benchmarks/lib/stage_scopes.py`` reads the grouped products'
   time by;
-* the chunked gated delta rule of ``ops/gdn.py``, walked in
-  checkpointed segments, keeps its backward's temporaries under the
-  room a 16 GB chip has beside 10 GB of state.
+* the chunked gated delta rule of ``ops/gdn.py`` in its ``jax.numpy``
+  form, walked in checkpointed segments, keeps its backward's
+  temporaries under the room a 16 GB chip has beside 10 GB of state;
+* lowered for a TPU, ``gated_delta_scan`` IS the fused kernels of
+  ``ops/gdn_fused.py`` (PR 34): three Mosaic custom calls under the
+  caller's ``scan`` scope, the backward's too, no whole-row chunk
+  matrices, and less scratch than the segmented form with no segments.
 
 The topology is described inside a fixture, in this one file: only one
 process at a time may load the TPU's library.
@@ -62,7 +66,7 @@ def test_grouped_products_become_ragged_dot_kernels(one_chip):
 
 
 def test_the_segmented_delta_rule_fits_beside_the_state(one_chip):
-    from cxxnet_tpu.ops.gdn import gated_delta_scan
+    from cxxnet_tpu.ops.gdn import gated_delta_xla as gated_delta_scan
 
     t, h, dk = 8192, 32, 128
 
@@ -80,3 +84,44 @@ def test_the_segmented_delta_rule_fits_beside_the_state(one_chip):
         head, head, head, gate, gate).compile()
     seg = compiled.memory_analysis().temp_size_in_bytes
     assert seg < 1.6e9 < whole.memory_analysis().temp_size_in_bytes
+
+
+def test_the_delta_rule_lowered_for_a_tpu_is_the_fused_kernels(one_chip):
+    """The published widths: a row of 8192 tokens, 16 key and 32 value
+    heads of 128 x 128, bfloat16."""
+    import re
+
+    from cxxnet_tpu.ops.gdn import gated_delta_scan_counted
+
+    t, hk, hv, d = 8192, 16, 32, 128
+
+    def loss(q, k, v, g, beta):
+        with jax.named_scope("l1_gdn0"), jax.named_scope("scan"):
+            o, fused = gated_delta_scan_counted(q, k, v, g, beta, None, 64,
+                                                2048)
+        return jnp.sum(o.astype(jnp.float32)), fused
+
+    key = _shaped(one_chip, (1, t, hk, d))
+    val = _shaped(one_chip, (1, t, hv, d))
+    gate = _shaped(one_chip, (1, t, hv), jnp.float32)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+                       ).lower(key, key, val, gate, gate).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = {k: [c for c in calls if f"/{k}/pallas_call" in c]
+             for k in ("gdn_solve", "gdn_scan", "gdn_scan_bwd")}
+    assert [len(v) for v in names.values()] == [1, 1, 1], names
+    # forward and backward alike are billed to the layer's scan scope
+    for call in calls:
+        op = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert "l1_gdn0" in op and "/scan/" in op, op
+    assert "transpose(jvp(l1_gdn0))" in names["gdn_scan_bwd"][0]
+    # none of the jax.numpy form's whole-row chunk matrices is left
+    # ((1, 128 chunks, 32 heads, 64, 64) float32: decay, A, the
+    # doubling's operands, q k^T); what the kernels keep for the backward
+    # is the inverse a chunk, (1, 32, 8192, 64), and the entering states
+    assert not re.search(r"f32\[[0-9,]*,64,64\]", text)
+    assert "f32[1,32,8192,64]" in text and "f32[1,32,128,128,128]" in text
+    # and the whole backward needs less scratch than the segmented form
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9 < 1.6e9
