@@ -348,6 +348,7 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
     import numpy as np
     from jax import lax
 
+    from cxxnet_tpu.layers import create_layer
     from cxxnet_tpu.layers.conv import _maxpool_eq
     from cxxnet_tpu.ops import quant as opsq
     from cxxnet_tpu.ops.attention import mha
@@ -461,7 +462,30 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
                          -jax.nn.softplus(g), jax.nn.sigmoid(b), doc),)
         return with_grads(run, 5)
 
+    # -- latent attention at JoyAI-LLM-Flash's widths, a quarter of its
+    # heads: no Pallas kernel, the whole layer through the masked XLA row
+    # blocks in bf16 against itself in f32; two documents a row
+    tm, dm, hm = (128, 64, 2) if toy else (2048, 2048, 8)
+    mla = create_layer("latent_attention")
+    mla_cfg = dict(nhead=hm, q_rank=32, kv_rank=16, nope_dim=16, rope_dim=8,
+                   v_dim=16) if toy else dict(
+        nhead=hm, q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+        v_dim=128)
+    for key, val in dict(mla_cfg, rope_theta=3.2e7, causal=1).items():
+        mla.set_param(key, str(val))
+    mla.infer_shape([(1, tm, dm), (1, tm)])
+    mla_shapes = jax.eval_shape(
+        lambda: mla.init_params(jax.random.PRNGKey(0),
+                                [(1, tm, dm), (1, tm)]))
+    mla_p = {t: arr(*v.shape, scale=1.0 if v.ndim == 1 else 0.02)
+             for t, v in sorted(mla_shapes.items())}
+    mla_ids = jnp.ones((1, tm), jnp.float32).at[0, tm // 3].set(0.0)
+    mla_run = with_grads(
+        lambda x, p: (mla.apply(p, [x, mla_ids])[0],), 2)
+
     return [
+        ("latent_attention fwd+bwd", "ok", mla_run, mla_run,
+         (arr(1, tm, dm), mla_p), 4 * BF16),
         ("gated_delta_fused fwd+bwd", "ok",
          delta(lambda *a: gated_delta_fused(*a, interpret=interpret)),
          delta(gated_delta_recurrence),

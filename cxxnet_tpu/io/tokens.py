@@ -26,9 +26,13 @@ Emits ``data (N, T)`` and ``label (N, T)`` as float32 ids (exact to
 the device unrounded).  Every batch counts into the round's
 ``PipelineStats`` counters (the telemetry record's ``counters``):
 ``tokens``, ``docs`` (separators in the rows fed, i.e. documents that
-end there) and ``docs_cut`` (rows whose last token is no separator: the
-document running there is cut by the row's end); its own work is
-billed to the ``batch`` stage.
+end there), ``docs_cut`` (rows whose last token is no separator: the
+document running there is cut by the row's end) and ``attn_pairs`` (the
+(query, key) pairs a causal query of its own document may see: the sum
+over a row's documents of ``L (L + 1) / 2``, a document beginning at a
+row's first token and after every separator as ``ops/ssd.doc_index``
+has it — the work of masked attention whatever computes it); its own
+work is billed to the ``batch`` stage.
 """
 
 from __future__ import annotations
@@ -43,6 +47,18 @@ from ..utils.profiler import pipeline_stats
 from .data import DataBatch, DataIter
 
 SEP_ID = 0  # closes a document; the layers read starts from it (ops/ssd.py)
+
+
+def attn_pairs(rows: np.ndarray) -> int:
+    """(query, key) pairs of ``rows (N, T)`` of ids under a causal mask
+    inside documents: a document of ``L`` tokens (its closing separator
+    among them; the row's end cuts the last) has ``L (L + 1) / 2``."""
+    # a row's documents end at its separators and at its last token, so
+    # in the flat order every document begins where the last one ended
+    ends = rows == SEP_ID
+    ends[:, -1] = True
+    length = np.diff(np.flatnonzero(ends), prepend=-1)
+    return int((length * (length + 1) // 2).sum())
 
 
 class TokenIterator(DataIter):
@@ -142,6 +158,7 @@ class TokenIterator(DataIter):
         stats.count("tokens", fed.size)
         stats.count("docs", int((fed == SEP_ID).sum()))
         stats.count("docs_cut", int((fed[:, -1] != SEP_ID).sum()))
+        stats.count("attn_pairs", attn_pairs(fed))
         win = win.astype(np.float32)
         batch = DataBatch(
             data=win[:, :-1],

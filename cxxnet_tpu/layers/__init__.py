@@ -20,6 +20,7 @@ from . import (  # noqa: F401
     gdn,
     linear,
     loss,
+    mla,
     moe,
     sequence,
     ssm,

@@ -22,6 +22,11 @@ the matrix is one leaf with one gradient, the sum of both uses
 not).  Without ``tied`` the head is untied: ``wmat (nhidden, D)`` is its
 own, started at ``init_sigma``.
 
+``token_shift`` moves a row of ids on by one: ``out[t] = ids[t + 1]``,
+id 0 — the separator — at a row's last position.  It is how a
+multi-token-prediction module reads "the next token" from the batch the
+iterator feeds; no keys, no parameters.
+
 Input is a flat ``(N, T)`` node of token ids (the text iterator emits
 ids as float32 — exact for any realistic vocab); output is the
 ``(N, T, D)`` sequence node the attention stack consumes.  The layer
@@ -198,3 +203,23 @@ class LMHeadLayer(Layer):
                 f"needs ({self.param.num_hidden}, {x.shape[-1]})")
         y = x @ table.astype(x.dtype).T
         return [y / jnp.asarray(self.divisor, y.dtype)]
+
+
+@register
+class TokenShiftLayer(Layer):
+    type_name = "token_shift"
+
+    #: reads raw ids, like ``embedding``
+    integer_input = True
+
+    def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
+        self._check_arity(in_shapes, 1)
+        (shape,) = in_shapes
+        if len(shape) != 2 or shape[1] < 2:
+            raise ValueError(
+                f"token_shift: input must be a flat (N, T) id node with "
+                f"T > 1, got {shape}")
+        return [tuple(shape)]
+
+    def apply(self, params, inputs, *, train=False, rng=None, step=None):
+        return [jnp.pad(inputs[0][:, 1:], ((0, 0), (0, 1)))]

@@ -32,6 +32,16 @@ to bring (ROADMAP R2).
             w * W_d^e (silu(W_g^e u) * W_u^e u)
       + sigmoid(u . w_s) * W_sd (silu(W_sg u) * W_su u)     with shared_hidden
 
+With ``score_func = sigmoid`` (the DeepSeek-V3 family's router) ``p =
+sigmoid(u W_r^T)``; with ``select_bias = 1`` the ``topk`` are the
+largest of ``p + b`` for a leaf ``score_bias`` ``b (nexpert,)`` while
+the weights stay the unbiased ``p`` of the chosen, then ``w = w /
+(sum(w) + 1e-20)`` and, last, ``w = routed_scale * w``.  ``b`` enters
+the selection only: its gradient is exactly zero and no updater moves
+it (the family moves it by a balance rule between steps, from all
+ranks' loads; that rule is not here).  ``shared_gate = 0`` adds the
+shared expert ungated.
+
 **No pair is dropped and no expert has a capacity.**  The (token,
 expert) pairs are sorted by expert, pairs of experts held elsewhere
 last; the tokens' rows are gathered in that order, the two grouped
@@ -49,8 +59,12 @@ such (a gather each way, never a scatter).
   width) — required
 * ``first_expert`` (default 0), ``nheld`` (default: all from
   ``first_expert`` on) — this device's share
-* ``shared_hidden`` — width of the always-on shared expert under its
-  sigmoid gate (default 0: none); ``norm_topk`` (default 1)
+* ``shared_hidden`` — width of the always-on shared expert (default 0:
+  none), under its sigmoid gate unless ``shared_gate = 0`` (default 1);
+  ``norm_topk`` (default 1)
+* ``score_func`` — ``softmax`` (default) or ``sigmoid``; ``select_bias``
+  (default 0) — 1 chooses by score + ``score_bias``; ``routed_scale``
+  (default 1) multiplies the chosen weights
 * ``prenorm`` / ``residual_scale`` / ``eps`` — the residual branch in
   one layer (``sequence.Branch``); ``init_sigma`` for every matrix
 
@@ -64,7 +78,9 @@ expert's weight and of both its adam moments at the loop's edges
 (4.5 GB at 4 x 32 experts; read from compiles for a described v5e, PR
 33); and with a shared expert
 ``shared_wmat`` (2 shared_hidden, D), ``shared_wproj`` (D,
-shared_hidden), ``shared_gate`` (1, D); ``norm`` (D) with ``prenorm``.
+shared_hidden), ``shared_gate`` (1, D) unless ``shared_gate = 0``;
+``score_bias`` (nexpert,) with ``select_bias``, started at 0; ``norm``
+(D) with ``prenorm``.
 All float32 at rest, cast where used; the router's product is float32
 at the highest precision.
 
@@ -140,15 +156,31 @@ def _unsort_bwd(order, g):
 _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 
 
-def route(logits, topk: int, norm_topk: bool = True):
+def route(logits, topk: int, norm_topk: bool = True, *,
+          score_func: str = "softmax", bias=None, scale: float = 1.0):
     """``logits (M, E)`` float32 -> (weights ``(M, k)`` float32, expert
     ids ``(M, k)`` int32): the ``topk`` largest of the softmax over all
     ``E``, by index so a tie admits no extra expert, divided by their
-    sum with ``norm_topk``."""
-    w, idx = lax.top_k(jax.nn.softmax(logits.astype(jnp.float32), axis=-1),
-                       topk)
-    if norm_topk:
-        w = w / w.sum(axis=-1, keepdims=True)
+    sum with ``norm_topk``.  ``score_func = "sigmoid"`` scores each
+    expert alone; ``bias (E,)`` is added for the choice only — the
+    weights are the unbiased scores of the chosen; ``scale`` multiplies
+    them last."""
+    logits = logits.astype(jnp.float32)
+    if score_func == "softmax" and bias is None:
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), topk)
+        if norm_topk:
+            w = w / w.sum(axis=-1, keepdims=True)
+    else:
+        p = (jax.nn.sigmoid(logits) if score_func == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+        _, idx = lax.top_k(
+            p if bias is None
+            else p + lax.stop_gradient(bias.astype(jnp.float32)), topk)
+        w = jnp.take_along_axis(p, idx, axis=-1)
+        if norm_topk:
+            w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        w = w * jnp.float32(scale)
     return w, idx.astype(jnp.int32)
 
 
@@ -196,7 +228,8 @@ class RoutedExpertsLayer(Layer, Branch):
     #: (``NetTrainer.count_layer_state``)
     aux_counters = {name: "expert_" + name for name in COUNTERS}
     f32_tags = frozenset({"wgate", "wmat", "wproj", "shared_wmat",
-                          "shared_wproj", "shared_gate", "norm"})
+                          "shared_wproj", "shared_gate", "score_bias",
+                          "norm"})
 
     def __init__(self) -> None:
         super().__init__()
@@ -205,14 +238,26 @@ class RoutedExpertsLayer(Layer, Branch):
         self.first_expert = 0
         self.nheld = 0  # 0: all from first_expert on
         self.shared_hidden = 0
+        self.shared_gate = 1
         self.norm_topk = 1
+        self.score_func = "softmax"
+        self.select_bias = 0
+        self.routed_scale = 1.0
 
     _INT_KEYS = ("nexpert", "topk", "first_expert", "nheld",
-                 "shared_hidden", "norm_topk")
+                 "shared_hidden", "shared_gate", "norm_topk", "select_bias")
 
     def set_param(self, name, val):
         if name in self._INT_KEYS:
             setattr(self, name, int(val))
+        elif name == "score_func":
+            if val not in ("softmax", "sigmoid"):
+                raise ValueError(
+                    f"routed_experts: score_func is softmax or sigmoid, "
+                    f"got {val!r}")
+            self.score_func = val
+        elif name == "routed_scale":
+            self.routed_scale = float(val)
         elif not self.set_branch_param(name, val):
             super().set_param(name, val)
 
@@ -250,8 +295,11 @@ class RoutedExpertsLayer(Layer, Branch):
                "wproj": normal(ks[2], (g, f, d))}
         if sh:
             out.update({"shared_wmat": normal(ks[3], (2 * sh, d)),
-                        "shared_wproj": normal(ks[4], (d, sh)),
-                        "shared_gate": normal(ks[5], (1, d))})
+                        "shared_wproj": normal(ks[4], (d, sh))})
+            if self.shared_gate:
+                out["shared_gate"] = normal(ks[5], (1, d))
+        if self.select_bias:
+            out["score_bias"] = jnp.zeros((self.nexpert,), jnp.float32)
         out.update(self.branch_params(d))
         return out
 
@@ -278,7 +326,10 @@ class RoutedExpertsLayer(Layer, Branch):
         with jax.named_scope("route"):
             logits = jnp.dot(x.astype(jnp.float32), params["wgate"].T,
                              precision=lax.Precision.HIGHEST)
-            w, idx = route(logits, self.topk, bool(self.norm_topk))
+            w, idx = route(logits, self.topk, bool(self.norm_topk),
+                           score_func=self.score_func,
+                           bias=params.get("score_bias"),
+                           scale=self.routed_scale)
             if self._held() < self.nexpert:
                 # a share: the weights' cotangent needs the other ranks'
                 w = lax.stop_gradient(w)
@@ -291,6 +342,8 @@ class RoutedExpertsLayer(Layer, Branch):
                 gu = x @ params["shared_wmat"].astype(cdt).T
                 s = (jax.nn.silu(gu[:, :sh]) * gu[:, sh:]) @ params[
                     "shared_wproj"].astype(cdt).T
-                y = y + jax.nn.sigmoid(
-                    x @ params["shared_gate"].astype(cdt).T) * s
+                if self.shared_gate:
+                    s = jax.nn.sigmoid(
+                        x @ params["shared_gate"].astype(cdt).T) * s
+                y = y + s
         return [self.branch_out(x0, y.reshape(x0.shape))], counts

@@ -20,6 +20,7 @@ from .builders import (  # noqa: F401
     alexnet_conf,
     googlenet_conf,
     granite_h_conf,
+    joyai_llm_flash_conf,
     kaggle_bowl_conf,
     mnist_conv_conf,
     mnist_mlp_conf,
@@ -48,4 +49,5 @@ MODEL_BUILDERS = {
     "transformer_lm": transformer_lm_conf,
     "granite_h": granite_h_conf,
     "qwen3_next": qwen3_next_conf,
+    "joyai_llm_flash": joyai_llm_flash_conf,
 }

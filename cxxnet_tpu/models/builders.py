@@ -714,6 +714,194 @@ def qwen3_next_conf(
     )
 
 
+def joyai_llm_flash_conf(
+    vocab: int = 16160,
+    seq_len: int = 8192,
+    hidden: int = 2048,
+    num_layers: int = 5,
+    first_k_dense: int = 1,
+    attn_heads: int = 32,
+    q_lora_rank: int = 1536,
+    kv_lora_rank: int = 512,
+    qk_nope_head_dim: int = 128,
+    qk_rope_head_dim: int = 64,
+    v_head_dim: int = 128,
+    rope_theta: float = 3.2e7,
+    rope_interleave: int = 1,
+    mlp_hidden: int = 7168,
+    num_experts: int = 256,
+    experts_per_tok: int = 8,
+    expert_hidden: int = 768,
+    shared_hidden: int = 768,
+    routed_scaling_factor: float = 2.5,
+    first_expert: int = 0,
+    experts_held: int = 16,
+    num_nextn_predict_layers: int = 1,
+    mtp_loss_weight: float = 0.3,
+    eps: float = 1e-6,
+    token_file: str = "",
+    batch_size: int = 1,
+    num_round: int = 10,
+    dev: str = "tpu",
+    compute_dtype: str = "bfloat16",
+    eta: float = 0.0003,
+    scan_steps: int = 8,
+) -> str:
+    """A JoyAI-LLM-Flash style language model (jdopensource,
+    ``model_type: joyai_llm_flash`` — the DeepSeek-V3 layout): every
+    layer a multi-head latent attention (``latent_attention``: low-rank
+    queries and keys/values, one shared rotary key head, interleaved
+    rotary positions); the first ``first_k_dense`` layers a dense gated
+    MLP of ``mlp_hidden``, the others ``num_experts`` SwiGLU experts of
+    ``expert_hidden`` behind a sigmoid router that chooses its
+    top-``experts_per_tok`` by score + bias and weighs them by the
+    unbiased scores, renormalised and times ``routed_scaling_factor``,
+    plus one ungated shared expert; every branch pre-normed by
+    ``rms_norm`` and added back; an untied head.  With
+    ``num_nextn_predict_layers = 1`` a multi-token-prediction module
+    (DeepSeek-V3, section 2.2) follows the main head and loss, its
+    layers named ``mtp_*``: the shared embedding of the NEXT token and
+    the last layer's output, each normed, joined by ``mtp_eh_proj``
+    (embedding's columns first), one more attention + expert block, a
+    last norm, the shared head, and a loss on the token after next at
+    ``mtp_loss_weight`` (``softmax`` with ``target_shift = 1`` over the
+    same ``label`` field).
+
+    The defaults are the published widths, ``num_layers`` = 5 deep (the
+    leading dense layer and four of the 39 that follow), with ONE
+    RANK'S SHARE of a 16-way expert-parallel layout — ``experts_held``
+    = 16 of the 256 experts of every layer, from ``first_expert`` on
+    (the router still ranks all 256; ``layers/moe.py``) — over an
+    eighth of the vocabulary: 680.4M parameters with the module.  In a
+    share the routing weights are constants of the backward pass, and
+    the selection bias gets no gradient anywhere, so routers' choices
+    stay the seed's.
+
+    The embedding starts at normal(0, 1), the other matrices at 0.02:
+    a freshly drawn attention puts the running mean of its values into
+    the stream (0.58 / sqrt(n) an entry at these widths), and under an
+    embedding of 0.02 every token of a document then looks the same to
+    the routers, which send them all to the same eight experts.
+
+    The out node is the LAST layer's, so with the module it is the
+    module's prediction; the module is a training device and is left
+    out (``num_nextn_predict_layers = 0``) of a net that predicts.
+    Written for memory as ``granite_h_conf`` is: ``remat = 1``,
+    ``eval_train = 0``; documents and positions as ``qwen3_next_conf``.
+    """
+    if num_nextn_predict_layers not in (0, 1):
+        raise ValueError("joyai_llm_flash_conf: a multi-token-prediction "
+                         "depth of 0 or 1")
+    data = ""
+    if token_file:
+        data = (
+            "data = train\n"
+            "iter = tokens\n"
+            f"  filename = {token_file}\n"
+            f"  seq_len = {seq_len}\n"
+            "iter = end\n"
+        )
+    branch = (f"  prenorm = 1\n  eps = {eps!r}\n"
+              "  residual_scale = 1.0\n"
+              "  init_sigma = 0.02\n")
+
+    def mla(src: str, out: str, name: str) -> str:
+        return (
+            f"layer[{src},0->{out}] = latent_attention:{name}\n"
+            f"  nhead = {attn_heads}\n"
+            f"  q_rank = {q_lora_rank}\n"
+            f"  kv_rank = {kv_lora_rank}\n"
+            f"  nope_dim = {qk_nope_head_dim}\n"
+            f"  rope_dim = {qk_rope_head_dim}\n"
+            f"  v_dim = {v_head_dim}\n"
+            f"  rope_theta = {rope_theta!r}\n"
+            f"  rope_interleave = {rope_interleave}\n"
+            "  causal = 1\n" + branch
+        )
+
+    def moe(src: str, out: str, name: str) -> str:
+        return (
+            f"layer[{src}->{out}] = routed_experts:{name}\n"
+            f"  nexpert = {num_experts}\n"
+            f"  topk = {experts_per_tok}\n"
+            f"  nhidden = {expert_hidden}\n"
+            f"  first_expert = {first_expert}\n"
+            f"  nheld = {experts_held}\n"
+            f"  shared_hidden = {shared_hidden}\n"
+            "  shared_gate = 0\n"
+            "  score_func = sigmoid\n"
+            "  select_bias = 1\n"
+            f"  routed_scale = {routed_scaling_factor!r}\n"
+            "  norm_topk = 1\n" + branch
+        )
+
+    s = (
+        "netconfig = start\n"
+        "layer[0->h0] = embedding:embed\n"
+        f"  nvocab = {vocab}\n"
+        f"  nhidden = {hidden}\n"
+        # a token's own row has to stand out of the stream: see above
+        "  init_sigma = 1.0\n"
+    )
+    for i in range(num_layers):
+        s += mla(f"h{i}", f"x{i}", f"mla{i}")
+        if i < first_k_dense:
+            s += (
+                f"layer[x{i}->h{i + 1}] = gated_mlp:mlp{i}\n"
+                f"  nhidden = {mlp_hidden}\n" + branch
+            )
+        else:
+            s += moe(f"x{i}", f"h{i + 1}", f"moe{i}")
+    last = f"h{num_layers}"
+    s += (
+        f"layer[{last}->nf] = rms_norm:norm_f\n"
+        f"  eps = {eps!r}\n"
+        "layer[nf->logits] = lm_head:head\n"
+        f"  nhidden = {vocab}\n"
+        "  init_sigma = 0.02\n"
+        "layer[logits->logits] = softmax\n"
+        # the mean over all positions: the loss sums over T
+        f"  grad_scale = {1.0 / seq_len!r}\n"
+    )
+    if num_nextn_predict_layers:
+        s += (
+            "layer[0->mtp_ids] = token_shift:mtp_shift\n"
+            "layer[mtp_ids->mtp_e] = shared[embed]\n"
+            "layer[mtp_e->mtp_en] = rms_norm:mtp_enorm\n"
+            f"  eps = {eps!r}\n"
+            f"layer[{last}->mtp_hn] = rms_norm:mtp_hnorm\n"
+            f"  eps = {eps!r}\n"
+            "layer[mtp_en,mtp_hn->mtp_eh] = concat:mtp_cat\n"
+            "layer[mtp_eh->mtp_h0] = fullc:mtp_eh_proj\n"
+            f"  nhidden = {hidden}\n"
+            "  no_bias = 1\n"
+            "  init_sigma = 0.02\n"
+            + mla("mtp_h0", "mtp_x", "mtp_mla")
+            + moe("mtp_x", "mtp_h1", "mtp_moe")
+            + "layer[mtp_h1->mtp_nf] = rms_norm:mtp_norm_f\n"
+            f"  eps = {eps!r}\n"
+            "layer[mtp_nf->mtp_logits] = shared[head]\n"
+            "layer[mtp_logits->mtp_logits] = softmax\n"
+            "  target_shift = 1\n"
+            f"  grad_scale = {mtp_loss_weight / seq_len!r}\n"
+        )
+    s += "netconfig = end\n"
+    extra = (
+        f"compute_dtype = {compute_dtype}\n"
+        f"label_width = {seq_len}\n"
+        f"label_vec[0,{seq_len}) = label\n"
+        "metric = logloss\n"
+        "updater = adam\n"
+        "wd = 0.0\n"
+        "remat = 1\n"
+        "eval_train = 0\n"
+    )
+    return data + s + _tail(
+        batch_size, f"1,1,{seq_len}", num_round, eta=eta, dev=dev,
+        extra=extra, scan_steps=scan_steps,
+    )
+
+
 def _res_bottleneck(prev: str, name: str, cin: int, cmid: int, cout: int,
                     stride: int) -> str:
     """Bottleneck residual block: 1x1 reduce -> 3x3 -> 1x1 expand, each

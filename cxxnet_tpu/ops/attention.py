@@ -95,22 +95,31 @@ def doc_positions(doc, n: int, t: int) -> jnp.ndarray:
 
 
 def rotary(x: jnp.ndarray, pos: jnp.ndarray, dim: int,
-           theta: float = 10000.0) -> jnp.ndarray:
-    """Rotate-half rotary positions (Su et al. 2021, as GPT-NeoX and the
-    Qwen families apply them) on the first ``dim`` of each head of ``x
-    (N, T, H, Dh)``: with ``x1 | x2`` the two halves of those ``dim``,
-    ``x1 cos - x2 sin | x2 cos + x1 sin`` at the angles ``pos *
-    theta^(-2i/dim)``; the rest of the head passes.  Angles and the
-    rotation in float32."""
+           theta: float = 10000.0, interleave: bool = False) -> jnp.ndarray:
+    """Rotary positions (Su et al. 2021) on the first ``dim`` of each
+    head of ``x (N, T, H, Dh)``, at the angles ``pos * theta^(-2i/dim)``;
+    the rest of the head passes.  Rotate-half (as GPT-NeoX and the Qwen
+    families apply them): with ``x1 | x2`` the two halves of those
+    ``dim``, ``x1 cos - x2 sin | x2 cos + x1 sin``.  ``interleave``
+    (the original pairing, as the DeepSeek-V3 family's checkpoints keep
+    it): the pairs are ``(x[2i], x[2i+1])``, each turned in place.
+    Angles and the rotation in float32."""
     half = dim // 2
     freq = jnp.exp(jnp.arange(half, dtype=jnp.float32)
                    * (-2.0 * math.log(theta) / dim))
     ang = pos.astype(jnp.float32)[..., None] * freq          # (N, T, half)
     cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
     xf = x[..., :dim].astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    turned = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    if interleave:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        turned = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+            axis=-1).reshape(xf.shape).astype(x.dtype)
+    else:
+        x1, x2 = xf[..., :half], xf[..., half:]
+        turned = jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+            axis=-1).astype(x.dtype)
     return jnp.concatenate([turned, x[..., dim:]], axis=-1)
 
 

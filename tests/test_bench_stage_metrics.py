@@ -88,13 +88,14 @@ def test_gdn_scan_fused_pct_reads_the_two_scan_counters(rounds, want):
 
 ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
              "granite_4_0_h_micro_train_packed8k",
-             "qwen3_next_80b_a3b_train_packed8k"]
+             "qwen3_next_80b_a3b_train_packed8k",
+             "joyai_llm_flash_train_packed8k"]
 
 
 @pytest.mark.parametrize("name, cells", [
     ("train_metric_device_pct", ALL_CELLS[:2]),
     ("chunk_overlap_pct", ALL_CELLS),
-    ("gdn_scan_fused_pct", ALL_CELLS[3:]),
+    ("gdn_scan_fused_pct", ALL_CELLS[3:4]),
 ])
 def test_benchmark_json_names_the_reader_that_exists(name, cells):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -112,5 +113,7 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells):
     # the 31st when PR 32 added it, and PR 33's ten behind it
     names = [m["name"] for m in bench["per_layer"]]
     assert names.index("chunk_overlap_pct") == 30
-    # PR 34's one entry is the last
-    assert names.index("gdn_scan_fused_pct") == len(names) - 1 == 41
+    # PR 34's one entry behind them, and PR 36's four behind that
+    assert names.index("gdn_scan_fused_pct") == 41
+    assert names[42:] == ["mla_ms_step", "mla_core_ms_step",
+                          "mla_core_roofline_pct", "mtp_ms_step"]

@@ -309,7 +309,11 @@ def test_token_rows_are_the_stream_and_labels_the_next_token(tmp_path):
     # separators stay where the file has them
     assert sorted(np.flatnonzero(data.ravel() == 0)) == [5, 16, 17, 40]
     counts = pipeline_stats().counters()
-    assert counts == {"tokens": 64, "docs": 4, "docs_cut": 4}
+    # the pairs a causal query of its own document sees: documents of
+    # 6 and a cut 10; 1, 1 and a cut 14; 9 and a cut 7; one cut 16
+    assert counts == {"tokens": 64, "docs": 4, "docs_cut": 4,
+                      "attn_pairs": (21 + 55) + (1 + 1 + 105) + (45 + 28)
+                      + 136}
     assert pipeline_stats().snapshot()["batch"]["rows"] == 4
 
 

@@ -36,6 +36,13 @@ def test_model_shapes(name):
                        attn_kv_heads=2, head_dim=16, num_experts=8,
                        experts_per_tok=2, expert_hidden=16, shared_hidden=16,
                        experts_held=4)
+    elif name == "joyai_llm_flash":  # the defaults are the published widths
+        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
+                       hidden=32, num_layers=2, attn_heads=4, q_lora_rank=24,
+                       kv_lora_rank=16, qk_nope_head_dim=8,
+                       qk_rope_head_dim=4, v_head_dim=8, mlp_hidden=48,
+                       num_experts=8, experts_per_tok=2, expert_hidden=16,
+                       shared_hidden=16, experts_held=4)
     elif name.startswith("mnist") or name in ("kaggle_bowl",
                                               "transformer_lm"):
         text = builder(batch_size=4, dev="cpu")
@@ -52,7 +59,7 @@ def test_model_shapes(name):
               "googlenet": 1000, "vgg16": 1000, "vgg19": 1000,
               "kaggle_bowl": 121,
               "transformer": 10, "transformer_lm": 256,
-              "granite_h": 64, "qwen3_next": 64,
+              "granite_h": 64, "qwen3_next": 64, "joyai_llm_flash": 64,
               "resnet50": 1000, "resnet101": 1000,
               "resnet152": 1000}[name]
     assert out[-1] == expect

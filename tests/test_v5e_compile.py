@@ -14,9 +14,18 @@ on-chip-measurement guide, section 2: nothing runs, no chip is needed).
   caller's ``scan`` scope, the backward's too, no whole-row chunk
   matrices, and less scratch than the segmented form with no segments.
 
+* the whole scanned step of ``joyai_llm_flash_conf()`` at its defaults
+  (PR 36: six latent-attention layers, five expert layers at 16 held
+  experts, the prediction module; 680M parameters under adam) holds at
+  most 14.4 GB at its fullest — the number that decided between 16 held
+  experts and the fallback of 8 (ISSUE 36).
+
 The topology is described inside a fixture, in this one file: only one
 process at a time may load the TPU's library.
 """
+
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -125,3 +134,30 @@ def test_the_delta_rule_lowered_for_a_tpu_is_the_fused_kernels(one_chip):
     assert "f32[1,32,8192,64]" in text and "f32[1,32,128,128,128]" in text
     # and the whole backward needs less scratch than the segmented form
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9 < 1.6e9
+
+
+def test_the_joyai_step_fits_a_chip_with_sixteen_held_experts(one_chip):
+    """``tools/compile_for_v5e.py``'s compile of the conf the builder
+    writes, from shapes alone: 8.17 GB of weights and adam's moments
+    aliased to the outputs, the rest temporaries of one 8192-token row."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from tools.compile_for_v5e import compile_step, live_at_peak_bytes
+
+    from cxxnet_tpu.models import joyai_llm_flash_conf
+
+    compiled = compile_step(joyai_llm_flash_conf())
+    m = compiled.memory_analysis()
+    # 680.44M parameters x 12 B (weight and two moments; the gradients
+    # are temporaries), all of it updated in place
+    assert abs(m.argument_size_in_bytes - 680_441_088 * 12) < 2e6
+    assert m.alias_size_in_bytes > 0.999 * m.output_size_in_bytes
+    assert live_at_peak_bytes(compiled) <= 14.4e9
+    text = compiled.as_text()
+    # the new layer's scopes reach the operations' metadata, the
+    # module's too, and the grouped products are the compiler's kernels
+    for scope in ("l1_mla0)/core/", "l1_mla0)/q_proj/", "l1_mla0)/kv_proj/",
+                  "l1_mla0)/rotary/", "l1_mla0)/out_proj/",
+                  "l20_mtp_mla)/core/", "l19_mtp_eh_proj", "l21_mtp_moe)/route/"):
+        assert scope in text, scope
+    assert 'op_name="ragged-dot-none"' in text
