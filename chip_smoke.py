@@ -352,7 +352,8 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
     from cxxnet_tpu.layers.conv import _maxpool_eq
     from cxxnet_tpu.ops import quant as opsq
     from cxxnet_tpu.ops.attention import mha
-    from cxxnet_tpu.ops.flash import flash_mha, flash_mha_lse
+    from cxxnet_tpu.ops.flash import (flash_attention, flash_mha,
+                                      flash_mha_lse)
     from cxxnet_tpu.ops.gdn import gated_delta_recurrence
     from cxxnet_tpu.ops.gdn_fused import gated_delta_fused
     from cxxnet_tpu.ops.kernels import conv_block, int8_gemm, update_step
@@ -462,9 +463,16 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
                          -jax.nn.softplus(g), jax.nn.sigmoid(b), doc),)
         return with_grads(run, 5)
 
+    # -- the masked kernels at latent attention's two widths, four query
+    # heads a key-value head, a stated scale, ~4 documents a row, against
+    # ``mha`` with the same mask
+    def masked(attn):
+        return with_grads(lambda q, k, v: (attn(q, k, v),), 3)
+
     # -- latent attention at JoyAI-LLM-Flash's widths, a quarter of its
-    # heads: no Pallas kernel, the whole layer through the masked XLA row
-    # blocks in bf16 against itself in f32; two documents a row
+    # heads: the whole layer in bf16 against itself in f32, two documents
+    # a row; on the chip its ``core`` is the flash kernels (a row of 2048
+    # tokens: ``ops/attention.attend``), in the rehearsal ``mha``
     tm, dm, hm = (128, 64, 2) if toy else (2048, 2048, 8)
     mla = create_layer("latent_attention")
     mla_cfg = dict(nhead=hm, q_rank=32, kv_rank=16, nope_dim=16, rope_dim=8,
@@ -492,6 +500,14 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
          tuple(arr(2, td, h, 128) for h in (hkd, hkd, 2 * hkd)) + tuple(
              arr(2, td, 2 * hkd, dtype=jnp.float32) for _ in range(2)),
          2 * BF16),
+        ("flash_attention masked fwd+bwd", "ok",
+         masked(lambda q, k, v: flash_attention(
+             q, k, v, causal=True, scale=0.07, doc=doc, block_q=512,
+             block_k=512, interpret=interpret)[0]),
+         masked(lambda q, k, v: mha(q, k, v, causal=True, scale=0.07,
+                                    doc=doc)),
+         (arr(2, td, 4 * hkd, 192), arr(2, td, hkd, 192),
+          arr(2, td, hkd, 128)), BF16),
         ("flash_mha fwd+bwd", "ok",
          with_grads(lambda q, k, v: (flash_mha(
              q, k, v, True, 512, 512, interpret),), 3),

@@ -20,9 +20,14 @@ rope_dim`` wide and the value product ``v_dim`` wide.  With ``u`` the
     y = concat_h(o_h) W_o^T
 
 Training runs the **expanded** form: keys and values are made from the
-latent for every head and go through ``ops/attention.mha`` — the masked
-XLA path in checkpointed row blocks of 512 queries that ``attention``'s
-document path uses.  The absorbed form (``W_kvb`` folded into the query
+latent for every head and go through ``ops/attention.attend``, the one
+chooser ``attention``'s masked path uses too: lowered for a TPU, a long
+row runs the flash kernels of ``ops/flash.py`` (document mask with whole
+blocks skipped, the score product ``nope_dim + rope_dim`` wide beside
+values ``v_dim`` wide, bf16 into the MXU, float32 softmax; forward, the
+layer's ``remat`` recompute and both backward kernels); everywhere else
+``ops/attention.mha``, in checkpointed row blocks of 512 queries once
+the row is long.  The absorbed form (``W_kvb`` folded into the query
 and the output so that a decode step reads the latent cache alone) is
 decode's; this layer has no cache and no decode path.
 
@@ -48,9 +53,18 @@ kv_rank) — a head's rows are its keys' ``nope_dim`` then its ``v_dim``;
 ``wproj`` (D, nhead v_dim); ``norm`` (D) with ``prenorm``.  All float32
 at rest, cast where used.
 
+State (``aux``, carried through the step programs and read once a
+round by ``NetTrainer.count_layer_state``, as ``gated_deltanet``'s):
+``attn_tokens`` — tokens through ``core``; ``attn_tokens_flash`` — those
+of them the flash kernels computed, which the branch that ran says for
+itself.  uint32, wrapping; the round's counters of the same names sum
+them over the layers (``attention``'s masked path counts into the same
+two).
+
 Scopes inside the layer's: ``q_proj``, ``kv_proj``, ``rotary``, ``core``
-(scores, mask, softmax, values — forward, recomputed and backward),
-``out_proj``.
+(scores, mask, softmax, values — forward, recomputed and backward; on a
+TPU the kernels ``flash_fwd``, ``flash_dq``, ``flash_dkv`` and the
+layout changes around them), ``out_proj``.
 """
 
 from __future__ import annotations
@@ -60,15 +74,18 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import doc_positions, mha, rotary
+from ..ops.attention import attend, doc_positions, rotary
 from ..ops.ssd import doc_index
 from .base import Layer, Params, Shape, register
-from .sequence import AttentionLayer, Branch, _check_ids_input, rms_norm
+from .sequence import (ATTN_COUNTERS, Branch, _check_ids_input,
+                       count_attention, rms_norm)
 
 
 @register
 class LatentAttentionLayer(Layer, Branch):
     type_name = "latent_attention"
+    #: state leaf -> the round's counter it is added to
+    aux_counters = {name: name for name in ATTN_COUNTERS}
     f32_tags = frozenset({"wqa", "q_norm", "wqb", "wkva", "kv_norm", "wkvb",
                           "wproj", "norm"})
 
@@ -126,7 +143,19 @@ class LatentAttentionLayer(Layer, Branch):
         out.update(self.branch_params(d))
         return out
 
+    def init_aux(self, in_shapes):
+        return {name: jnp.zeros((), jnp.uint32) for name in ATTN_COUNTERS}
+
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
+        return self._run(params, inputs)[0]
+
+    def apply_stateful(self, params, aux, inputs, *, train=False, rng=None,
+                       step=None):
+        outs, flash = self._run(params, inputs)
+        return outs, count_attention(aux, inputs[0], flash)
+
+    def _run(self, params, inputs):
+        """``([out], 1 if the flash kernels computed ``core`` else 0)``."""
         x0 = inputs[0]
         n, t, _ = x0.shape
         h, dn, dr, dv = self.nhead, self.nope_dim, self.rope_dim, self.v_dim
@@ -155,9 +184,8 @@ class LatentAttentionLayer(Layer, Branch):
                     rotary(k_rope, pos, dr, self.rope_theta, turn),
                     (n, t, h, dr))], axis=-1)
         with jax.named_scope("core"):
-            o = mha(q, k, kv[..., dn:], causal=bool(self.causal), doc=doc,
-                    block_q=512 if t >= AttentionLayer._AUTO_FLASH_MIN_T
-                    else 0)
+            o, flash = attend(q, k, kv[..., dn:], causal=bool(self.causal),
+                              doc=doc)
         with jax.named_scope("out_proj"):
             out = o.reshape(n, t, h * dv) @ params["wproj"].astype(cdt).T
-        return [self.branch_out(x0, out)]
+        return [self.branch_out(x0, out)], flash

@@ -46,6 +46,18 @@ layout, NHWC-style batch-major nodes).  Sequence nodes are ``(N, T, D)``
     per head subset; needs T % model_axis == 0 AND
     nhead % model_axis == 0.  Two activation collectives vs the ring's
     n kv hops — usually cheaper when heads divide the axis.
+
+The masked path (any of ``nkvhead``, ``score_scale``, ``head_dim``,
+``qk_norm``, ``rotary_dim``, ``out_gate``, ``no_bias``, the ids input)
+goes through ``ops/attention.attend``, the one chooser
+``latent_attention`` uses too: lowered for a TPU a long row runs the
+flash kernels of ``ops/flash.py`` (document mask with whole blocks
+skipped, grouped heads by the index map, the stated scale), everywhere
+else ``ops/attention.mha``.  Such a layer counts in its ``aux`` state
+(``ATTN_COUNTERS``, read once a round by
+``NetTrainer.count_layer_state``) ``attn_tokens``, the tokens through
+it, and ``attn_tokens_flash``, those of them the kernels computed — the
+branch that ran says so for itself.
 """
 
 from __future__ import annotations
@@ -56,6 +68,19 @@ import jax
 import jax.numpy as jnp
 
 from .base import Layer, Params, Shape, register
+
+
+#: the masked attention layers' ``aux`` state (``attention``'s masked
+#: path, ``latent_attention``), and the round's counters they add to
+ATTN_COUNTERS = ("attn_tokens", "attn_tokens_flash")
+
+
+def count_attention(aux, x, flash):
+    """``aux`` after ``x (N, T, D)`` went through attention, ``flash`` 1
+    where the flash kernels computed it (uint32, wrapping)."""
+    tokens = jnp.uint32(x.shape[0] * x.shape[1])
+    return {"attn_tokens": aux["attn_tokens"] + tokens,
+            "attn_tokens_flash": aux["attn_tokens_flash"] + tokens * flash}
 
 
 def _layer_norm(x, w, b, eps: float):
@@ -204,6 +229,8 @@ def _check_ids_input(who: str, in_shapes: Sequence[Shape]) -> None:
 @register
 class AttentionLayer(Layer, Branch):
     type_name = "attention"
+    #: state leaf -> the round's counter it is added to (the masked path)
+    aux_counters = {name: name for name in ATTN_COUNTERS}
     f32_tags = frozenset({"norm", "q_norm", "k_norm"})
 
     def __init__(self) -> None:
@@ -264,11 +291,6 @@ class AttentionLayer(Layer, Branch):
         else:
             super().set_param(name, val)
 
-    # XLA mha materializes (B,H,T,T) scores in HBM; past this T the
-    # flash kernel's O(T) memory is the difference between running and
-    # OOM, and its fused VMEM pipeline wins on step time too.
-    _AUTO_FLASH_MIN_T = 1024
-
     def _local_attn(self, causal_override=None):
         """Per-device full-sequence attention fn ``(q,k,v,causal)->o``.
 
@@ -279,7 +301,7 @@ class AttentionLayer(Layer, Branch):
         reference path.  On CPU the identical kernel runs in interpret
         mode (tests).
         """
-        from ..ops.attention import mha
+        from ..ops.attention import LONG_T, mha
 
         def xla_attn(q, k, v, causal=bool(self.causal)):
             return mha(q, k, v, causal=causal)
@@ -301,8 +323,10 @@ class AttentionLayer(Layer, Branch):
             if self.attn_impl == "auto":
                 # auto never takes the interpret-mode emulation (an
                 # orders-of-magnitude slowdown off-TPU), and short
-                # sequences are the XLA path's home ground
-                if not on_tpu or t < self._AUTO_FLASH_MIN_T:
+                # sequences are the XLA path's home ground: mha holds
+                # (B,H,T,T) scores in HBM, which from LONG_T on is the
+                # difference between running and OOM
+                if not on_tpu or t < LONG_T:
                     return xla_attn(q, k, v, causal)
                 # past here auto MEANS flash; landing on mha is reported
                 if (_pick_block(t, 512) < 128
@@ -341,9 +365,13 @@ class AttentionLayer(Layer, Branch):
 
     def init_aux(self, in_shapes):
         """KV cache state for ``decode = 1``: keys/values for all past
-        positions, written at the loop's ``step`` offset."""
+        positions, written at the loop's ``step`` offset.  The masked
+        path's two counters otherwise; nothing for the plain layer."""
         if not self.decode:
-            return {}
+            if self._plain(len(in_shapes)):
+                return {}
+            return {name: jnp.zeros((), jnp.uint32)
+                    for name in ATTN_COUNTERS}
         if self.seq_parallel:
             raise ValueError(
                 "attention: decode=1 (single-token KV caching) does not "
@@ -371,9 +399,16 @@ class AttentionLayer(Layer, Branch):
                        step=None):
         """Incremental attention: write this call's k/v into the cache
         at positions ``step..step+t-1`` and attend q against everything
-        up to its own position (the causal rule against the cache)."""
+        up to its own position (the causal rule against the cache).
+        Without ``decode`` the state is the masked path's counters."""
         from jax import lax
 
+        if not self.decode:
+            y, flash = self._apply_masked(
+                params, self.branch_in(params, inputs[0]),
+                inputs[1] if len(inputs) > 1 else None)
+            return ([self.branch_out(inputs[0], y)],
+                    count_attention(aux, inputs[0], flash))
         x = inputs[0]
         n, t, d = x.shape
         h, dh = self.nhead, d // self.nhead
@@ -443,8 +478,10 @@ class AttentionLayer(Layer, Branch):
             raise ValueError(
                 "attention: nkvhead, score_scale, no_bias, head_dim, "
                 "qk_norm, rotary_dim, out_gate and a document input run the "
-                "masked XLA path (ops/attention.mha); seq_parallel, "
-                "decode and attn_impl = pallas know none of them"
+                "masked path, which chooses between the flash kernels and "
+                "the XLA row blocks itself (ops/attention.attend); "
+                "seq_parallel and decode know none of them, and "
+                "attn_impl = pallas forces the plain layer's kernel only"
             )
         if self.seq_parallel and self.mesh_plan is not None:
             nm = self.mesh_plan.n_model
@@ -486,9 +523,10 @@ class AttentionLayer(Layer, Branch):
     def _apply_masked(self, params, x, ids):
         """Grouped-query heads, a stated scale or head width, q/k
         norms, rotary positions, an output gate, documents: q, k and v
-        from the one fused projection, then ``mha`` with its mask, in
-        row blocks once the sequence is long."""
-        from ..ops.attention import doc_positions, mha, rotary
+        from the one fused projection, then ``ops/attention.attend``
+        with its mask — the flash kernels or ``mha``'s row blocks.
+        Returns the output and the flag of the branch that ran."""
+        from ..ops.attention import attend, doc_positions, rotary
         from ..ops.ssd import doc_index
 
         n, t, d = x.shape
@@ -517,23 +555,22 @@ class AttentionLayer(Layer, Branch):
                 pos = doc_positions(doc, n, t)
                 q = rotary(q, pos, self.rotary_dim, self.rope_theta)
                 k = rotary(k, pos, self.rotary_dim, self.rope_theta)
-        o = mha(q, k, v, causal=bool(self.causal),
-                scale=self.scale or None, doc=doc,
-                block_q=512 if t >= self._AUTO_FLASH_MIN_T else 0)
+        o, flash = attend(q, k, v, causal=bool(self.causal),
+                          scale=self.scale or None, doc=doc)
         o = o.reshape(n, t, nq)
         if gate is not None:
             o = o * jax.nn.sigmoid(gate)
         out = o @ params["wproj"].astype(x.dtype).T
         if "bproj" in params:
             out = out + params["bproj"].astype(x.dtype)
-        return out
+        return out, flash
 
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
         x = self.branch_in(params, inputs[0])
         if self._plain(len(inputs)):
             y = self._apply_plain(params, x)
         else:
-            y = self._apply_masked(
+            y, _ = self._apply_masked(
                 params, x, inputs[1] if len(inputs) > 1 else None)
         return [self.branch_out(inputs[0], y)]
 

@@ -26,6 +26,9 @@ import jax.numpy as jnp
 from jax import lax
 
 NEG_INF = -1e30
+#: from this length on the score matrix is never whole: ``attend`` takes
+#: the flash kernels or ``mha``'s row blocks
+LONG_T = 1024
 
 
 def _scores(q: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
@@ -162,6 +165,44 @@ def mha(
             doc_q=None if doc is None else doc[:, lo:hi],
             doc_k=None if doc is None else doc[:, :end]))
     return jnp.concatenate(outs, axis=1)
+
+
+def attend(q, k, v, *, causal: bool = False, scale: float | None = None,
+           doc: jnp.ndarray | None = None):
+    """``(mha(q, k, v, ...), 1 if the flash kernels computed it else 0)``
+    — the one place masked attention chooses its path, for every layer
+    (``attention``'s masked path, ``latent_attention``).
+
+    One algorithm in two forms, chosen from what the code can observe and
+    by no conf key: the platform the program is LOWERED for
+    (``jax.lax.platform_dependent``: a TPU takes ``ops/flash.py``'s
+    kernels, also when the lowering host is a CPU that compiles for a
+    described chip; everything else ``mha``, in checkpointed row blocks of
+    512 queries from ``LONG_T`` tokens on) and the shapes the kernels are
+    written for (``flash.block_for``: a long sequence that a block of 128
+    or more divides, head widths the kernels take, bfloat16 or float32;
+    one block size serves every head width).  The flag is a uint32
+    scalar each branch returns for itself, so it says what ran where the
+    program was lowered for (the layers' ``attn_tokens_flash``)."""
+    from . import flash
+
+    t = q.shape[1]
+    docs = () if doc is None else (doc,)
+    block = flash.block_for(q, k, v)
+
+    def rows(q, k, v, *doc):
+        return (mha(q, k, v, causal=causal, scale=scale,
+                    doc=doc[0] if doc else None,
+                    block_q=512 if t >= LONG_T else 0), jnp.uint32(0))
+
+    def kernels(q, k, v, *doc):
+        return (flash.flash_attention(
+            q, k, v, causal=causal, scale=scale, doc=doc[0] if doc else None,
+            block_q=block, block_k=block)[0], jnp.uint32(1))
+
+    if block is None:
+        return rows(q, k, v, *docs)
+    return lax.platform_dependent(q, k, v, *docs, tpu=kernels, default=rows)
 
 
 def ring_attention(

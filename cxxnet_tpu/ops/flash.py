@@ -1,30 +1,59 @@
 """Fused flash attention as Pallas TPU kernels.
 
-``ops/attention.mha`` is the golden model: it materializes the full
-``(B, H, T, T)`` score matrix in HBM, which is both the memory ceiling
-for long sequences (8k tokens at b8/h16 is ~32 GB of scores in f32) and
-an extra HBM round-trip per step.  This kernel runs the standard
-flash-attention recurrence — blockwise scores with an online
-(log-sum-exp) softmax — entirely in VMEM: scores never touch HBM, and
-memory is O(T) instead of O(T^2).
+``ops/attention.mha`` is the golden model: it materializes the score
+matrix in HBM — ``(B, H, T, T)``, or ``(B, H, 512, <=T)`` float32 row
+blocks once the sequence is long — which is both the memory ceiling for
+long sequences and an HBM round-trip per step, forward, recomputed and
+backward.  These kernels run the standard flash-attention recurrence —
+blockwise scores with an online (log-sum-exp) softmax — entirely in
+VMEM: scores never touch HBM, and memory is O(T) instead of O(T^2).
 
 The backward pass is the flash recomputation scheme: the forward saves
-only the per-row LSE (``m + log l``); two backward kernels re-derive the
-probability blocks from (q, k, lse) and accumulate
+``(q, k, v, o)`` and the per-row LSE (``m + log l``); two backward
+kernels re-derive the probability blocks from (q, k, lse) and accumulate
 
 * ``dq_i  = sum_j  [p_ij * (do_i . v_j - delta_i)] k_j * scale``
 * ``dk_j  = sum_i  [p_ij * (do_i . v_j - delta_i)] q_i * scale``
 * ``dv_j  = sum_i  p_ij^T do_i``
 
-with ``delta_i = sum_d dO_id O_id`` computed once in XLA.
+with ``delta_i = sum_d dO_id O_id`` computed once in XLA.  The ``dk`` /
+``dv`` kernel works on the transposed block (keys along the sublanes),
+so every product is a plain ``a @ b`` or ``a @ b^T`` and the per-query
+scalars arrive as rows.
+
+What the three kernels (``flash_fwd``, ``flash_dq``, ``flash_dkv``) take,
+behind ONE ``jax.custom_vjp`` (``_flash``):
+
+* **a document mask**: ``doc (B, T)``, the non-decreasing document
+  index of ``ops/ssd.doc_index``; a query sees the keys of its own
+  document (and, causal, not the later ones).  It reaches a kernel as
+  two small operands, the query block's column and the key block's row.
+* **whole-block skipping**: documents are runs along T, so the key
+  blocks a query block may see are ONE range ``[lo, hi]`` (``_ranges``:
+  the causal edge and the documents' first/last index a block, made by
+  XLA, scalar-prefetched into SMEM).  A step outside it computes nothing
+  and its index map is clamped into the range, so it names the block
+  that is already there and issues no DMA.  A causal grid does not even
+  visit the blocks above the diagonal: the grid is ``(heads, steps)``
+  over a static table of the (query block, key block) pairs.
+* **two widths**: q and k ``(..., Dqk)``, v and o ``(..., Dv)``.
+* **a stated scale** (default ``1 / sqrt(Dqk)``).
+* **grouped heads by the index map**: query head ``h`` reads key-value
+  head ``h // (H / Hkv)``; nothing is repeated in HBM, and ``dk`` /
+  ``dv`` are summed over the group in their float32 accumulators before
+  they leave.
+* **dynamic position offsets** for the causal mask (ring hops,
+  ``flash_mha_lse``).
+
+Precision is that of ``ops/attention._attend``: the products take the
+operands as they come (bf16 into the MXU, float32 stays float32) and
+accumulate in float32; scores, softmax and the running statistics are
+float32; probabilities (and ``ds``) are cast to the operands' dtype
+before their products.
 
 Layout contract matches ``ops/attention``: ``q, k, v`` are
 ``(B, T, H, Dh)``; internally heads fold into the grid's batch dim and
-blocks are ``(block, Dh)`` tiles.  Causal masking predicates whole
-skipped blocks (``pl.when``), so the causal kernel does ~half the FLOPs.
-All accumulation is f32 regardless of input dtype (bf16 in, bf16 out,
-f32 recurrence — the same discipline as the XLA path's
-``preferred_element_type``).
+blocks are ``(block, Dh)`` tiles.
 
 ``interpret=True`` runs the identical kernels on CPU for golden tests
 (the PairTest discipline, SURVEY §4.1).
@@ -37,71 +66,81 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 
 NEG_INF = -1e30
+#: the block ``block_for`` asks for, queries and keys alike: on a v5e a
+#: row of 8192 tokens in ~5 documents read 20.4 / 13.7 / 12.3 ms forward
+#: and backward at 1024 against 23.8 / 17.6 / 12.7 at 512 and 26.6 / 17.6
+#: / 18.0 at 2048 (heads of 192 + 128, 64, 256; PERF.md, PR 37) — fewer
+#: steps and rescalings a pair against more dead pairs inside live blocks
+BLOCK = 1024
+_VMEM_LIMIT = 100 * 1024 * 1024
+
+_NN = (((1,), (0,)), ((), ()))    # a @ b
+_NT = (((1,), (1,)), ((), ()))    # a @ b^T
+
+#: bits of a step's flag: the first / the last step of its output block
+_FIRST, _LAST = 1, 2
 
 
-def _dims(seq):
-    return dict(dimension_semantics=seq)
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _mask(tq: int, tk: int, q_off, k_off):
-    from jax import lax
-
-    qi = q_off + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-    ki = k_off + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-    return qi >= ki
-
-
-def _live(qo_ref, ko_ref, iq, ik, bq, bk, causal, dyn):
-    """Causal block-liveness: can this (iq, ik) block contribute at all?
-    Static offsets fold at trace time (the plain flash path); dynamic
-    offsets read the SMEM scalars — ``pl.when`` accepts traced
-    predicates, so a fully-future ring hop skips all compute."""
-    if not causal:
-        return True
-    if dyn:
-        return (qo_ref[0, 0] + iq * bq + bq - 1
-                >= ko_ref[0, 0] + ik * bk)
-    return iq * bq + bq - 1 >= ik * bk
+def _mask(shape, q_axis, q_pos, k_pos, causal, doc_q, doc_k):
+    """May attend, for a block whose queries run along ``q_axis``:
+    causal and same document (``doc_q``, ``doc_k``: the refs of a column
+    and a row, or ``None``s); ``None`` where neither applies."""
+    ok = None
+    if causal:
+        ok = (q_pos + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+              >= k_pos + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+    if doc_q is not None:
+        same = doc_q[0] == doc_k[0]
+        ok = same if ok is None else ok & same
+    return ok
 
 
-def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc, m, l, *, bq, bk, causal, dyn, scale):
+def _split(refs, n_in, has_doc):
+    """A kernel's refs after the scalars: its ``n_in`` tensor inputs, the
+    queries' and the keys' document refs (or ``None``s), the rest."""
+    ins, rest = refs[:n_in], refs[n_in:]
+    if has_doc:
+        return ins, rest[0], rest[1], rest[2:]
+    return ins, None, None, rest
+
+
+def _fwd_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
+                bq, bk, n, tb, causal, has_doc, scale):
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+    (q_ref, k_ref, v_ref), doc_q, doc_k, (o_ref, lse_ref, acc, m, l) = (
+        _split(refs, 3, has_doc))
+    s_i = pl.program_id(1)
+    iq, ik, fl = iq_t[s_i], ik_t[s_i], fl_t[s_i]
+    row = (pl.program_id(0) // tb) * n + iq if tb else iq
 
-    @pl.when(ik == 0)
+    @pl.when((fl & _FIRST) != 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m[:] = jnp.full_like(m, NEG_INF)
         l[:] = jnp.zeros_like(l)
 
-    live = _live(qo_ref, ko_ref, iq, ik, bq, bk, causal, dyn)
-
-    @pl.when(live)
+    @pl.when((ik >= lo_t[row]) & (ik <= hi_t[row]))
     def _block():
-        qb = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            s = jnp.where(
-                _mask(bq, bk, qo_ref[0, 0] + iq * bq,
-                      ko_ref[0, 0] + ik * bk),
-                s, NEG_INF,
-            )
+        s = _dot(q_ref[0], k_ref[0], _NT) * scale
+        ok = _mask((bq, bk), 0, offs[0] + iq * bq, offs[1] + ik * bk,
+                   causal, doc_q, doc_k)
+        if ok is not None:
+            s = jnp.where(ok, s, NEG_INF)
         m_prev = m[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        if causal:
+        if ok is not None:
             # a query row fully masked within a live block leaves m_new at
             # NEG_INF, making exp(s - m_new) = 1 for every masked entry;
             # zero such rows so `out` alone is valid even under the
@@ -109,114 +148,82 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             p = jnp.where(m_new > NEG_INF * 0.5, p, 0.0)
         l[:, :1] = l[:, :1] * corr + p.sum(axis=-1, keepdims=True)
         m[:, :1] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc[:] = acc[:] * corr + pv
+        acc[:] = acc[:] * corr + _dot(p.astype(v_ref.dtype), v_ref[0], _NN)
 
-    @pl.when(ik == nk - 1)
+    @pl.when((fl & _LAST) != 0)
     def _done():
         lf = jnp.maximum(l[:, :1], 1e-30)
         o_ref[0] = (acc[:] / lf).astype(o_ref.dtype)
         lse_ref[0] = m[:, :1] + jnp.log(lf)
 
 
-def _dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-               dl_ref, dq_ref, acc, *, bq, bk, causal, dyn, scale):
+def _dq_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
+               bq, bk, n, tb, causal, has_doc, scale):
     from jax.experimental import pallas as pl
 
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref), doc_q, doc_k,
+     (out_ref, acc)) = _split(refs, 6, has_doc)
+    s_i = pl.program_id(1)
+    iq, ik, fl = iq_t[s_i], ik_t[s_i], fl_t[s_i]
+    row = (pl.program_id(0) // tb) * n + iq if tb else iq
 
-    @pl.when(ik == 0)
+    @pl.when((fl & _FIRST) != 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
-    live = _live(qo_ref, ko_ref, iq, ik, bq, bk, causal, dyn)
-
-    @pl.when(live)
+    @pl.when((ik >= lo_t[row]) & (ik <= hi_t[row]))
     def _block():
-        qb = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        kb = k_ref[0]
+        s = _dot(q_ref[0], kb, _NT) * scale
         p = jnp.exp(s - lse_ref[0])
-        if causal:
-            p = jnp.where(
-                _mask(bq, bk, qo_ref[0, 0] + iq * bq,
-                      ko_ref[0, 0] + ik * bk),
-                p, 0.0,
-            )
-        dob = do_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            dob, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        ok = _mask((bq, bk), 0, offs[0] + iq * bq, offs[1] + ik * bk,
+                   causal, doc_q, doc_k)
+        if ok is not None:
+            p = jnp.where(ok, p, 0.0)
+        dp = _dot(do_ref[0], v_ref[0], _NT)
         ds = p * (dp - dl_ref[0])
-        acc[:] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        acc[:] += _dot(ds.astype(kb.dtype), kb, _NN)
 
-    @pl.when(ik == nk - 1)
+    @pl.when((fl & _LAST) != 0)
     def _done():
-        dq_ref[0] = acc[:].astype(dq_ref.dtype)
+        out_ref[0] = (acc[:] * scale).astype(out_ref.dtype)
 
 
-def _dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                dl_ref, dk_ref, dv_ref, kacc, vacc,
-                *, bq, bk, causal, dyn, scale):
+def _dkv_kernel(offs, ik_t, iq_t, fl_t, lo_t, hi_t, g_t, *refs,
+                bq, bk, n, tb, causal, has_doc, scale):
+    """The transposed block: keys along the sublanes, queries along the
+    lanes; ``lse``, ``delta`` and the queries' documents are rows."""
     from jax.experimental import pallas as pl
 
-    ik = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref), doc_q, doc_k,
+     (dk_out, dv_out, kacc, vacc)) = _split(refs, 6, has_doc)
+    s_i = pl.program_id(1)
+    iq, ik, fl = iq_t[s_i], ik_t[s_i], fl_t[s_i]
+    row = (pl.program_id(0) // tb) * n + ik if tb else ik
 
-    @pl.when(iq == 0)
+    @pl.when((fl & _FIRST) != 0)
     def _init():
         kacc[:] = jnp.zeros_like(kacc)
         vacc[:] = jnp.zeros_like(vacc)
 
-    live = _live(qo_ref, ko_ref, iq, ik, bq, bk, causal, dyn)
-
-    @pl.when(live)
+    @pl.when((iq >= lo_t[row]) & (iq <= hi_t[row]))
     def _block():
-        qb = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        qb, dob = q_ref[0], do_ref[0]
+        s = _dot(k_ref[0], qb, _NT) * scale
         p = jnp.exp(s - lse_ref[0])
-        if causal:
-            p = jnp.where(
-                _mask(bq, bk, qo_ref[0, 0] + iq * bq,
-                      ko_ref[0, 0] + ik * bk),
-                p, 0.0,
-            )
-        dob = do_ref[0].astype(jnp.float32)
-        vacc[:] += jax.lax.dot_general(
-            p, dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            dob, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        ok = _mask((bk, bq), 1, offs[0] + iq * bq, offs[1] + ik * bk,
+                   causal, doc_q, doc_k)
+        if ok is not None:
+            p = jnp.where(ok, p, 0.0)
+        vacc[:] += _dot(p.astype(dob.dtype), dob, _NN)
+        dp = _dot(v_ref[0], dob, _NT)
         ds = p * (dp - dl_ref[0])
-        kacc[:] += jax.lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        kacc[:] += _dot(ds.astype(qb.dtype), qb, _NN)
 
-    @pl.when(iq == nq - 1)
+    @pl.when((fl & _LAST) != 0)
     def _done():
-        dk_ref[0] = kacc[:].astype(dk_ref.dtype)
-        dv_ref[0] = vacc[:].astype(dv_ref.dtype)
+        dk_out[0] = (kacc[:] * scale).astype(dk_out.dtype)
+        dv_out[0] = vacc[:].astype(dv_out.dtype)
 
 
 def _pick_block(t: int, want: int) -> int:
@@ -226,131 +233,316 @@ def _pick_block(t: int, want: int) -> int:
     return max(b, 1)
 
 
+# -- which blocks a step visits, and which of them are live ---------------
+@functools.lru_cache(maxsize=None)
+def _steps(nq: int, nk: int, bq: int, bk: int, tri: bool, group: int):
+    """The static tables of a grid's second axis.
+
+    ``(iq, ik, flags)`` for the forward and ``dq`` kernels — query
+    blocks in order, each with its key blocks — and ``(ik, iq, flags)``
+    for the ``dk``/``dv`` kernel — key blocks in order, each with the
+    ``group`` query heads of its key-value head and their query blocks;
+    the fourth table is the step's query head within the group.  ``tri``
+    (causal, positions from 0 on both sides, one length) leaves out the
+    pairs above the diagonal."""
+    def live(i, j):
+        return not tri or j * bk <= i * bq + bq - 1
+
+    def flags(n):
+        f = np.zeros(n, np.int32)
+        f[0] |= _FIRST
+        f[-1] |= _LAST
+        return f
+
+    fwd = [(i, j) for i in range(nq) for j in range(nk) if live(i, j)]
+    fl = np.concatenate([flags(sum(1 for p in fwd if p[0] == i))
+                         for i in range(nq)])
+    fwd_t = (np.array([p[0] for p in fwd], np.int32),
+             np.array([p[1] for p in fwd], np.int32), fl,
+             np.zeros(1, np.int32))
+    bwd = [(j, g, i) for j in range(nk) for g in range(group)
+           for i in range(nq) if live(i, j)]
+    fl = np.concatenate([flags(sum(1 for p in bwd if p[0] == j))
+                         for j in range(nk)])
+    bwd_t = (np.array([p[0] for p in bwd], np.int32),
+             np.array([p[2] for p in bwd], np.int32), fl,
+             np.array([p[1] for p in bwd], np.int32))
+    return fwd_t, bwd_t
+
+
+def _ranges(doc, offs, nq: int, nk: int, bq: int, bk: int, causal: bool):
+    """``(lo, hi, qlo, qhi)``, flat int32: for query block ``i`` of table
+    row ``b`` the key blocks ``lo[b nq + i] .. hi[b nq + i]`` hold every
+    key one of its queries may see (``hi = -1``: none), and for key block
+    ``j`` the query blocks ``qlo[b nk + j] .. qhi[b nk + j]``.  One table
+    row a batch row with documents, one in all without.
+
+    Causal: key block ``j`` is in reach of query block ``i`` when its
+    first key is not after the block's last query.  Documents: ``doc``
+    does not decrease along T, so a block's first and last entries are
+    its smallest and largest index, and a key block is in reach when the
+    two blocks' index ranges overlap — both conditions are monotone in
+    ``j`` (and in ``i``), so the reach is one range."""
+    i = jnp.arange(nq, dtype=jnp.int32)[None]
+    j = jnp.arange(nk, dtype=jnp.int32)[None]
+    lo, hi = jnp.zeros_like(i), jnp.full_like(i, nk - 1)
+    qlo, qhi = jnp.zeros_like(j), jnp.full_like(j, nq - 1)
+    if causal:
+        gap = offs[0] - offs[1]
+        hi = jnp.minimum(hi, (gap + i * bq + bq - 1) // bk)
+        qlo = jnp.maximum(qlo, (j * bk - gap) // bq)
+    if doc is not None:
+        n = doc.shape[0]
+        dq, dk = doc.reshape(n, nq, bq), doc.reshape(n, nk, bk)
+        qmin, qmax = dq[:, :, :1], dq[:, :, -1:]            # (n, nq, 1)
+        kmin, kmax = dk[:, None, :, 0], dk[:, None, :, -1]  # (n, 1, nk)
+        count = lambda c, axis: c.sum(axis, dtype=jnp.int32)
+        lo = jnp.maximum(lo, count(kmax < qmin, 2))
+        hi = jnp.minimum(hi, count(kmin <= qmax, 2) - 1)
+        qlo = jnp.maximum(qlo, count(qmax < kmin, 1))
+        qhi = jnp.minimum(qhi, count(qmin <= kmax, 1) - 1)
+    return (jnp.clip(lo, 0, nk - 1).reshape(-1),
+            jnp.clip(hi, -1, nk - 1).reshape(-1),
+            jnp.clip(qlo, 0, nq - 1).reshape(-1),
+            jnp.clip(qhi, -1, nq - 1).reshape(-1))
+
+
+def _clamp(x, lo, hi):
+    """``x`` into ``[lo, hi]``, and ``lo`` where the range is empty."""
+    return jnp.minimum(jnp.maximum(x, lo), jnp.maximum(hi, lo))
+
+
 def _offs(q_off, k_off):
-    """Normalize offsets to the (1,1) int32 SMEM operands the kernels
-    read; None → zeros (the plain static path)."""
-    z = jnp.zeros((1, 1), jnp.int32)
-    qo = z if q_off is None else jnp.asarray(q_off, jnp.int32).reshape(1, 1)
-    ko = z if k_off is None else jnp.asarray(k_off, jnp.int32).reshape(1, 1)
-    return qo, ko
+    """The ``(2,)`` int32 SMEM operand the kernels read their position
+    offsets from; None -> 0 (the plain static path)."""
+    return jnp.stack([jnp.asarray(0 if o is None else o, jnp.int32)
+                      .reshape(()) for o in (q_off, k_off)])
 
 
-def _smem_spec():
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+class _Geometry:
+    """What the three calls share: block counts, the head grouping, the
+    step tables and the live ranges, and the block specs over them."""
 
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
+    def __init__(self, q, k, v, doc, q_off, k_off, causal, scale, bq, bk,
+                 heads):
+        self.bh, self.t, self.dqk = q.shape
+        self.bhk, self.tk, self.dv = v.shape
+        self.bq, self.bk = bq, bk
+        self.nq, self.nk = self.t // bq, self.tk // bk
+        self.group = self.bh // self.bhk
+        self.causal, self.scale = causal, scale
+        self.has_doc = doc is not None
+        dyn = q_off is not None or k_off is not None
+        if self.has_doc and (dyn or self.t != self.tk):
+            raise ValueError("flash: a document mask needs queries and "
+                             "keys of one length and no position offsets")
+        self.offs = _offs(q_off, k_off)
+        self.ranges = _ranges(doc, self.offs, self.nq, self.nk, bq, bk,
+                              causal)
+        #: heads of the grid's first axis that one row of ``ranges``
+        #: serves, forward and backward (0: the one row serves all)
+        self.tb = ((heads, heads // self.group) if self.has_doc else (0, 0))
+        self.fwd_t, self.bwd_t = _steps(
+            self.nq, self.nk, bq, bk,
+            causal and not dyn and self.t == self.tk, self.group)
+        if self.has_doc:
+            doc = doc.astype(jnp.int32)
+            self.doc_col, self.doc_row = doc[:, :, None], doc[:, None, :]
+
+    def kernel(self, fn, bwd: bool):
+        return functools.partial(
+            fn, bq=self.bq, bk=self.bk, tb=self.tb[bwd],
+            n=self.nk if bwd else self.nq, causal=self.causal,
+            has_doc=self.has_doc, scale=self.scale)
+
+    def specs(self, bwd: bool):
+        """``(q-side spec of a width, k-side spec of a width, the spec of a
+        query block's per-row scalars, [the documents' two specs])`` for
+        the forward and ``dq`` grids (heads of q, the query block
+        resident; the scalars are ``offs, iq_t, ik_t, fl_t, lo, hi, g_t``)
+        or the ``dk``/``dv`` grid (heads of k, the key block resident;
+        ``offs, ik_t, iq_t, fl_t, qlo, qhi, g_t``).  The block that moves
+        is clamped into the resident block's live range."""
+        from jax.experimental import pallas as pl
+
+        g, tb = self.group, self.tb[bwd]
+        n = self.nk if bwd else self.nq
+
+        def moving(b, s, offs, own_t, other_t, fl_t, lo, hi, g_t):
+            r = (b // tb) * n + own_t[s] if tb else own_t[s]
+            return _clamp(other_t[s], lo[r], hi[r])
+
+        def resident(b, s, offs, own_t, *_):
+            return own_t[s]
+
+        q_blk, k_blk = (moving, resident) if bwd else (resident, moving)
+        if bwd:
+            q_head = lambda b, s, *sc: b * g + sc[-1][s]
+            k_head = lambda b, s, *sc: b
+        else:
+            q_head = lambda b, s, *sc: b
+            k_head = lambda b, s, *sc: b // g
+        doc_b = lambda b, s, *sc: b // tb
+
+        def spec(block, head, blk, t_axis):
+            """A ``(1, ...)`` block whose T axis is ``t_axis``."""
+            def index(b, s, *sc):
+                idx = [head(b, s, *sc), 0, 0]
+                idx[t_axis] = blk(b, s, *sc)
+                return tuple(idx)
+            return pl.BlockSpec(block, index)
+
+        qs = lambda width: spec((1, self.bq, width), q_head, q_blk, 1)
+        ks = lambda width: spec((1, self.bk, width), k_head, k_blk, 1)
+        # a query block's per-row scalars: a column beside the resident
+        # query block, a row beside the transposed one
+        if bwd:
+            qrow = spec((1, 1, self.bq), q_head, q_blk, 2)
+            docs = [spec((1, 1, self.bq), doc_b, q_blk, 2),
+                    spec((1, self.bk, 1), doc_b, k_blk, 1)]
+        else:
+            qrow = spec((1, self.bq, 1), q_head, q_blk, 1)
+            docs = [spec((1, self.bq, 1), doc_b, q_blk, 1),
+                    spec((1, 1, self.bk), doc_b, k_blk, 2)]
+        return qs, ks, qrow, (docs if self.has_doc else [])
+
+    def docs(self, bwd: bool):
+        """The queries' and the keys' documents as ``specs`` reads them."""
+        if not self.has_doc:
+            return ()
+        return ((self.doc_row, self.doc_col) if bwd
+                else (self.doc_col, self.doc_row))
+
+    def call(self, kern, bwd, in_specs, out_specs, out_shape, scratch,
+             operands, name, interpret):
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        tabs = self.bwd_t if bwd else self.fwd_t
+        scalars = (self.offs, *tabs[:3], *self.ranges[2 * bwd:2 * bwd + 2],
+                   tabs[3])
+        return pl.pallas_call(
+            self.kernel(kern, bwd),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(scalars),
+                grid=(self.bhk if bwd else self.bh, len(tabs[0])),
+                in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=interpret, name=name,
+        )(*scalars, *operands)
 
 
-def _flash_fwd_raw(q, k, v, causal, bq, bk, interpret,
-                   q_off=None, k_off=None):
-    """(BH, T, D) folded layout -> (out, lse).  lse is (BH, T, 1) f32 —
+def _flash_fwd_raw(q, k, v, geo: _Geometry, interpret):
+    """Folded layout -> ``(out (BH, T, Dv), lse (BH, T, 1) float32)`` —
     the lane-1 layout keeps T in sublanes so the kernel writes it
-    without a relayout.  ``q_off``/``k_off`` are dynamic global
-    position offsets for the causal mask (ring hops); None keeps the
-    static-offset fast path (block-level causal skip)."""
-    from jax.experimental import pallas as pl
+    without a relayout."""
     from jax.experimental.pallas import tpu as pltpu
 
-    dyn = q_off is not None or k_off is not None
-    qo, ko = _offs(q_off, k_off)
-    bh, t, d = q.shape
-    tk = k.shape[1]
-    nq, nk = t // bq, tk // bk
-    scale = 1.0 / math.sqrt(d)
-    kern = functools.partial(
-        _fwd_kernel, bq=bq, bk=bk, causal=causal, dyn=dyn, scale=scale
-    )
-    qspec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        kern,
-        grid=(bh, nq, nk),
-        in_specs=[_smem_spec(), _smem_spec(), qspec, kspec, kspec],
-        out_specs=[
-            qspec,
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            **_dims(("parallel", "parallel", "arbitrary"))
-        ),
-        interpret=interpret,
-    )(qo, ko, q, k, v)
+    qs, ks, qrow, docs = geo.specs(False)
+    bq, dv = geo.bq, geo.dv
+    return geo.call(
+        _fwd_kernel, False,
+        [qs(geo.dqk), ks(geo.dqk), ks(dv), *docs],
+        [qs(dv), qrow],
+        [jax.ShapeDtypeStruct((geo.bh, geo.t, dv), q.dtype),
+         jax.ShapeDtypeStruct((geo.bh, geo.t, 1), jnp.float32)],
+        [pltpu.VMEM((bq, dv), jnp.float32),
+         pltpu.VMEM((bq, 128), jnp.float32),
+         pltpu.VMEM((bq, 128), jnp.float32)],
+        (q, k, v, *geo.docs(False)), "flash_fwd", interpret)
 
 
-def _flash_bwd_raw(q, k, v, do, lse, delta, causal, bq, bk, interpret,
-                   q_off=None, k_off=None):
-    from jax.experimental import pallas as pl
+def _flash_bwd_raw(q, k, v, do, lse, dl, geo: _Geometry, interpret):
+    """``lse`` and ``dl`` (``delta``, less a cotangent of ``lse``) as the
+    forward's ``(BH, T, 1)`` columns."""
     from jax.experimental.pallas import tpu as pltpu
 
-    dyn = q_off is not None or k_off is not None
-    qo, ko = _offs(q_off, k_off)
-    bh, t, d = q.shape
-    tk = k.shape[1]
-    nq, nk = t // bq, tk // bk
-    scale = 1.0 / math.sqrt(d)
+    bq, bk, dqk, dv = geo.bq, geo.bk, geo.dqk, geo.dv
+    qs, ks, qrow, docs = geo.specs(False)
+    dq = geo.call(
+        _dq_kernel, False,
+        [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *docs],
+        qs(dqk), jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((bq, dqk), jnp.float32)],
+        (q, k, v, do, lse, dl, *geo.docs(False)), "flash_dq", interpret)
 
-    qspec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM)
-    rspec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, bq=bq, bk=bk, causal=causal,
-                          dyn=dyn, scale=scale),
-        grid=(bh, nq, nk),
-        in_specs=[_smem_spec(), _smem_spec(),
-                  qspec, kspec, kspec, qspec, rspec, rspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            **_dims(("parallel", "parallel", "arbitrary"))
-        ),
-        interpret=interpret,
-    )(qo, ko, q, k, v, do, lse, delta)
+    # the key block is the resident operand; the query heads of its
+    # group and their query blocks sweep innermost
+    qs, ks, qrow, docs = geo.specs(True)
+    as_rows = lambda x: x.reshape(geo.bh, 1, geo.t)
+    dk, dv_ = geo.call(
+        _dkv_kernel, True,
+        [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *docs],
+        [ks(dqk), ks(dv)],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((bk, dqk), jnp.float32),
+         pltpu.VMEM((bk, dv), jnp.float32)],
+        (q, k, v, do, as_rows(lse), as_rows(dl), *geo.docs(True)),
+        "flash_dkv", interpret)
+    return dq, dk, dv_
 
-    # k/v grid: kv block is the resident operand, q sweeps innermost
-    qspec2 = pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    kspec2 = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
-                          memory_space=pltpu.VMEM)
-    rspec2 = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, bq=bq, bk=bk, causal=causal,
-                          dyn=dyn, scale=scale),
-        grid=(bh, nk, nq),
-        in_specs=[_smem_spec(), _smem_spec(),
-                  qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
-        out_specs=[kspec2, kspec2],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            **_dims(("parallel", "parallel", "arbitrary"))
-        ),
-        interpret=interpret,
-    )(qo, ko, q, k, v, do, lse, delta)
-    return dq, dk, dv
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 12)))
+def _flash(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
+           interpret):
+    """The kernels on the folded layout: ``q (B H, T, Dqk)``, ``k (B Hkv,
+    Tk, Dqk)``, ``v (B Hkv, Tk, Dv)``, ``doc (B, T)`` or ``None``,
+    traced position offsets or ``None`` -> ``(o (B H, T, Dv), lse (B H,
+    T, 1))``.  Cotangents of BOTH outputs are taken: ``dL/dlse`` folds
+    into the backward kernels as ``ds = p * (dp - (delta - dlse))``."""
+    return _flash_fwd(q, k, v, doc, q_off, k_off, causal, scale, bq, bk,
+                      heads, interpret)[0]
+
+
+def _flash_fwd(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
+               interpret):
+    geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads)
+    out, lse = _flash_fwd_raw(q, k, v, geo, interpret)
+    return (out, lse), (q, k, v, doc, q_off, k_off, out, lse)
+
+
+def _flash_bwd(causal, scale, bq, bk, heads, interpret, res, cts):
+    q, k, v, doc, q_off, k_off, out, lse = res
+    g, g_lse = cts
+    geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads)
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(
+        -1, keepdims=True)
+    # dL/dlse_i adds p_ij * dlse_i to ds_ij; the kernels compute
+    # ds = p * (dp - dl) so dl = delta - dlse absorbs it
+    dq, dk, dv = _flash_bwd_raw(q, k, v, g, lse, delta - g_lse, geo,
+                                interpret)
+    return dq, dk, dv, None, None, None
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def block_for(q, k, v):
+    """The block, queries and keys alike, for ``q (B, T, H, Dqk)``, ``k``,
+    ``v (B, T, Hkv, Dv)`` — or ``None``: not a shape the kernels are
+    written for, ``ops/attention.attend`` leaves it to ``mha``.  They
+    take one long sequence on both sides (``attention.LONG_T`` tokens or
+    more, divided by a block of 128 or more), head widths that are
+    multiples of 64 up to 256, grouped heads, bfloat16 or float32.  The
+    largest block up to ``BLOCK`` that divides T, whatever the widths:
+    measured at heads of 64, 192 + 128 and 256 (``tools/attn_ab.py``,
+    PERF.md PR 37), one size won at all three."""
+    from .attention import LONG_T
+
+    t, h, dqk = q.shape[1:]
+    dv = v.shape[-1]
+    block = _pick_block(t, BLOCK)
+    ok = (t >= LONG_T and k.shape[1] == t and block >= 128
+          and h % k.shape[2] == 0 and k.shape[:3] == v.shape[:3]
+          and all(d % 64 == 0 and d <= 256 for d in (dqk, dv))
+          and q.dtype == k.dtype == v.dtype
+          and v.dtype in (jnp.bfloat16, jnp.float32))
+    return block if ok else None
 
 
 def _fold(x):
@@ -363,7 +555,26 @@ def _unfold(x, b, h):
     return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention(q, k, v, *, causal: bool = False, scale=None, doc=None,
+                    q_off=None, k_off=None, block_q: int = 512,
+                    block_k: int = 512, interpret: bool = False):
+    """``ops/attention.mha`` as the flash kernels: ``q (B, T, H, Dqk)``,
+    ``k (B, Tk, Hkv, Dqk)``, ``v (B, Tk, Hkv, Dv)`` with ``Hkv`` dividing
+    ``H``, ``scale`` in place of ``1 / sqrt(Dqk)``, ``doc (B, T)`` the
+    document index a token -> ``(o (B, T, H, Dv), lse (B, T, H))``.
+    ``_pick_block`` halves a block until it divides its length."""
+    b, t, h, dqk = q.shape
+    if h % k.shape[2] or k.shape[:3] != v.shape[:3]:
+        raise ValueError(f"flash: q {q.shape}, k {k.shape}, v {v.shape}")
+    out, lse = _flash(
+        _fold(q), _fold(k), _fold(v), doc, q_off, k_off, bool(causal),
+        float(1.0 / math.sqrt(dqk) if scale is None else scale),
+        _pick_block(t, block_q), _pick_block(k.shape[1], block_k), h,
+        bool(interpret))
+    return _unfold(out, b, h), lse[:, :, 0].reshape(b, h, t).transpose(
+        0, 2, 1)
+
+
 def flash_mha(q, k, v, causal: bool = False, block_q: int = 512,
               block_k: int = 512, interpret: bool = False):
     """Flash attention on ``(B, T, H, Dh)`` tensors — drop-in for
@@ -371,41 +582,10 @@ def flash_mha(q, k, v, causal: bool = False, block_q: int = 512,
     divides T; callers (the layer's ``auto`` dispatch) should route T
     whose largest dividing block is tiny back to ``mha`` — a block-1
     kernel is valid but pathologically slow."""
-    out, _ = _flash_fwd(q, k, v, causal, block_q, block_k, interpret)
-    return out
+    return flash_attention(q, k, v, causal=causal, block_q=block_q,
+                           block_k=block_k, interpret=interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    b, t, h, d = q.shape
-    bq = _pick_block(t, block_q)
-    bk = _pick_block(k.shape[1], block_k)
-    out, lse = _flash_fwd_raw(
-        _fold(q), _fold(k), _fold(v), causal, bq, bk, interpret
-    )
-    return _unfold(out, b, h), (q, k, v, _unfold(out, b, h), lse)
-
-
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
-    q, k, v, out, lse = res
-    b, t, h, d = q.shape
-    bq = _pick_block(t, block_q)
-    bk = _pick_block(k.shape[1], block_k)
-    gf = _fold(g)
-    of = _fold(out)
-    delta = (gf.astype(jnp.float32) * of.astype(jnp.float32)).sum(
-        -1, keepdims=True
-    )
-    dq, dk, dv = _flash_bwd_raw(
-        _fold(q), _fold(k), _fold(v), gf, lse, delta, causal, bq, bk,
-        interpret,
-    )
-    return _unfold(dq, b, h), _unfold(dk, b, h), _unfold(dv, b, h)
-
-
-flash_mha.defvjp(_flash_fwd, _flash_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def flash_mha_lse(q, k, v, q_off, k_off, causal: bool = True,
                   block_q: int = 512, block_k: int = 512,
                   interpret: bool = False):
@@ -418,61 +598,9 @@ def flash_mha_lse(q, k, v, q_off, k_off, causal: bool = True,
     o_b e^{lse_b-lse'})``.  ``q_off``/``k_off`` are traced scalars: the
     global positions of this call's first query/key row, consumed by
     the causal mask (a hop whose keys all sit after the queries yields
-    lse ~ -1e30 and washes out of the merge).
-
-    The VJP accepts cotangents for BOTH outputs: ``dL/dlse`` folds into
-    the backward kernels as ``ds = p * (dp - (delta - dlse))`` — the
-    same two kernels serve both flash entry points.
+    lse ~ -1e30 and washes out of the merge).  The VJP accepts
+    cotangents for BOTH outputs.
     """
-    out, lse, _ = _flash_lse_fwd_impl(
-        q, k, v, q_off, k_off, causal, block_q, block_k, interpret
-    )
-    return out, lse
-
-
-def _flash_lse_fwd_impl(q, k, v, q_off, k_off, causal, block_q, block_k,
-                        interpret):
-    b, t, h, d = q.shape
-    bq = _pick_block(t, block_q)
-    bk = _pick_block(k.shape[1], block_k)
-    out, lse = _flash_fwd_raw(
-        _fold(q), _fold(k), _fold(v), causal, bq, bk, interpret,
-        q_off=q_off, k_off=k_off,
-    )
-    # lse (BH, T, 1) -> (B, T, H)
-    lse_o = lse[:, :, 0].reshape(b, h, t).transpose(0, 2, 1)
-    return _unfold(out, b, h), lse_o, (out, lse)
-
-
-def _flash_lse_fwd(q, k, v, q_off, k_off, causal, block_q, block_k,
-                   interpret):
-    out_u, lse_o, (out_f, lse_f) = _flash_lse_fwd_impl(
-        q, k, v, q_off, k_off, causal, block_q, block_k, interpret
-    )
-    return (out_u, lse_o), (q, k, v, q_off, k_off, out_f, lse_f)
-
-
-def _flash_lse_bwd(causal, block_q, block_k, interpret, res, cts):
-    g, g_lse = cts
-    q, k, v, q_off, k_off, out_f, lse_f = res
-    b, t, h, d = q.shape
-    bq = _pick_block(t, block_q)
-    bk = _pick_block(k.shape[1], block_k)
-    gf = _fold(g)
-    # dL/dlse_i adds p_ij * dlse_i to ds_ij; the kernels compute
-    # ds = p * (dp - dl) so dl = delta - dlse absorbs it
-    dlse = jnp.zeros((b * h, t, 1), jnp.float32) if g_lse is None else (
-        g_lse.transpose(0, 2, 1).reshape(b * h, t, 1).astype(jnp.float32)
-    )
-    delta = (gf.astype(jnp.float32) * out_f.astype(jnp.float32)).sum(
-        -1, keepdims=True
-    )
-    dq, dk, dv = _flash_bwd_raw(
-        _fold(q), _fold(k), _fold(v), gf, lse_f, delta - dlse,
-        causal, bq, bk, interpret, q_off=q_off, k_off=k_off,
-    )
-    return (_unfold(dq, b, h), _unfold(dk, b, h), _unfold(dv, b, h),
-            None, None)
-
-
-flash_mha_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+    return flash_attention(q, k, v, causal=causal, q_off=q_off, k_off=k_off,
+                           block_q=block_q, block_k=block_k,
+                           interpret=interpret)

@@ -86,6 +86,32 @@ def test_gdn_scan_fused_pct_reads_the_two_scan_counters(rounds, want):
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
+# attn_flash_pct (PR 37): the masked attention layers (``attention``'s
+# masked path, ``latent_attention``) count the tokens through them and
+# those the flash kernels computed, inside the step programs
+@pytest.mark.parametrize("rounds, want", [
+    # six latent layers x 24 steps x 8192 tokens a round, every one flash
+    ([{"attn_tokens": 1179648, "attn_tokens_flash": 1179648,
+       "attn_pairs": 288000000, "expert_pairs": 61440}] * 2, 100.0),
+    # a round whose programs were lowered for another platform
+    ([{"attn_tokens": 196608, "attn_tokens_flash": 196608},
+      {"attn_tokens": 196608}], 50.0),
+    # a shape the chooser left to mha's row blocks: counted, none flash
+    ([{"attn_tokens": 196608, "attn_tokens_flash": 0}], 0.0),
+    ([{"attn_tokens": 196608}], 0.0),
+    # the parent counts neither; the iterator's pairs are not tokens
+    ([{"attn_pairs": 288000000, "tokens": 196608,
+       "gdn_scan_tokens": 589824}], None),
+    ([{"attn_tokens": 0}], None),
+    ([{}], None),
+    ([], None),
+])
+def test_attn_flash_pct_reads_the_two_attention_counters(rounds, want):
+    read = run.load_metric("attn_flash_pct").read
+    assert read(_counted(*rounds)) == want
+    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
+
+
 ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
              "granite_4_0_h_micro_train_packed8k",
              "qwen3_next_80b_a3b_train_packed8k",
@@ -96,6 +122,7 @@ ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
     ("train_metric_device_pct", ALL_CELLS[:2]),
     ("chunk_overlap_pct", ALL_CELLS),
     ("gdn_scan_fused_pct", ALL_CELLS[3:4]),
+    ("attn_flash_pct", ALL_CELLS[2:]),
 ])
 def test_benchmark_json_names_the_reader_that_exists(name, cells):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -115,5 +142,7 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells):
     assert names.index("chunk_overlap_pct") == 30
     # PR 34's one entry behind them, and PR 36's four behind that
     assert names.index("gdn_scan_fused_pct") == 41
-    assert names[42:] == ["mla_ms_step", "mla_core_ms_step",
-                          "mla_core_roofline_pct", "mtp_ms_step"]
+    assert names[42:46] == ["mla_ms_step", "mla_core_ms_step",
+                            "mla_core_roofline_pct", "mtp_ms_step"]
+    # and PR 37's one behind them, the last
+    assert names[46:] == ["attn_flash_pct"]

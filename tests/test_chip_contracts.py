@@ -245,19 +245,10 @@ def _smoke_kernel_cases():
         abstract=True)
 
 
-#: smoke cases that hold a layer on the XLA path against itself in
-#: float32: they lower for the TPU with no Mosaic call in them
-XLA_ONLY = {"latent_attention fwd+bwd"}
-
-
 @pytest.mark.parametrize(
     "case", _smoke_kernel_cases(), ids=lambda c: c[0].replace(" ", "_"))
 def test_pallas_kernel_lowers_for_tpu_at_smoke_shapes(case):
     name, expect, kernel, _reference, args, _tol = case
-    if name in XLA_ONLY:
-        exp = jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)
-        assert "tpu_custom_call" not in exp.mlir_module()
-        return
     if expect == "raises":
         with pytest.raises(NotImplementedError):
             jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)

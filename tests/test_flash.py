@@ -233,3 +233,278 @@ def test_flash_lse_fully_masked_rows_are_zero():
     assert np.all(np.asarray(lse)[:, :8] < -1e29)
     # live rows are real attention outputs
     assert np.abs(out[:, 8:]).max() > 0
+
+
+# ------------------------------------------------ the masked kernels (PR 37)
+def _docs(cuts, t):
+    """``(B, T)`` document index from a row's cut positions."""
+    d = np.zeros((len(cuts), t), np.int32)
+    for r, cs in enumerate(cuts):
+        for c in cs:
+            d[r, c:] += 1
+    return jnp.asarray(d)
+
+
+def _masked(h, hk, dqk, dv, t=64, dtype=jnp.float32, seed=0, b=2):
+    rng = np.random.RandomState(seed)
+    mk = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32), dtype)
+    return mk(b, t, h, dqk), mk(b, t, hk, dqk), mk(b, t, hk, dv)
+
+
+def _hold_against_mha(q, k, v, doc, causal, scale, bq, bk, tol):
+    """Forward and the gradients of q, k and v, the kernels against
+    ``mha`` with the same mask, each within ``tol`` of the reference
+    tensor's own scale."""
+    from cxxnet_tpu.ops.flash import flash_attention
+
+    def kern(q, k, v):
+        return flash_attention(q, k, v, causal=causal, scale=scale, doc=doc,
+                               block_q=bq, block_k=bk, interpret=True)[0]
+
+    def ref(q, k, v):
+        return mha(q, k, v, causal=causal, scale=scale, doc=doc)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) ** 2).sum()
+
+    got, want = kern(q, k, v), ref(q, k, v)
+    assert got.shape == want.shape == q.shape[:3] + v.shape[3:]
+    assert got.dtype == want.dtype
+    pairs = [("o", got, want)] + [
+        ("d" + n, a, r) for n, a, r in zip(
+            "qkv", jax.grad(loss(kern), (0, 1, 2))(q, k, v),
+            jax.grad(loss(ref), (0, 1, 2))(q, k, v))]
+    for name, a, r in pairs:
+        assert a.shape == r.shape and a.dtype == r.dtype, name
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        assert np.isfinite(a).all(), name
+        assert np.abs(a - r).max() <= tol * np.abs(r).max(), name
+
+
+#: rows of 64 tokens under blocks of 16: where the documents end
+DOC_CASES = {
+    "inside_a_block": [[5, 40], [27]],
+    "at_a_block_s_edge": [[16, 48], [32]],
+    "longer_than_several_blocks": [[56], [3, 60]],
+    "one_document_a_row": [[], []],
+    "a_last_document_of_one_token": [[63], [20, 63]],
+    "a_document_a_token_then_one_long": [[1, 2, 3], [62, 63]],
+}
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("case", sorted(DOC_CASES))
+def test_document_mask_matches_mha(case, causal):
+    q, k, v = _masked(2, 2, 16, 16)
+    _hold_against_mha(q, k, v, _docs(DOC_CASES[case], 64), causal, None,
+                      16, 16, 2e-5)
+
+
+@pytest.mark.parametrize("h, hk, dqk, dv, scale, bq, bk", [
+    (2, 2, 192, 128, None, 16, 16),        # latent attention's two widths
+    (2, 1, 24, 16, 0.3, 16, 32),           # ... grouped, unequal blocks
+    (32, 8, 16, 16, 0.015625, 32, 16),     # granite: 32 / 8, a stated scale
+    (16, 2, 32, 32, None, 16, 16),         # qwen3_next: 16 / 2
+    (4, 4, 16, 16, 0.5, 64, 64),           # one block a row
+], ids=["192x128", "grouped_24x16", "32over8_scale", "16over2", "one_block"])
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+def test_widths_groups_and_scale_match_mha(h, hk, dqk, dv, scale, bq, bk,
+                                           dtype, tol):
+    q, k, v = _masked(h, hk, dqk, dv, dtype=dtype, seed=1)
+    _hold_against_mha(q, k, v, _docs([[5, 16, 40], [63]], 64), True, scale,
+                      bq, bk, tol)
+
+
+def test_grouped_heads_without_documents_and_a_cotangent_of_lse():
+    """The plain callers' contract, on grouped heads: ``(o, lse)`` with
+    a cotangent into both."""
+    from cxxnet_tpu.ops.flash import flash_attention
+
+    q, k, v = _masked(4, 2, 16, 16, t=32)
+
+    def ref(q, k, v):
+        kk, vv = (jnp.repeat(x, 2, axis=2) for x in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 0.2
+        s = jnp.where(jnp.tril(jnp.ones((32, 32), bool)), s, -1e30)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        return (jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]),
+                           vv), lse.transpose(0, 2, 1))
+
+    def kern(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=0.2, block_q=16,
+                               block_k=8, interpret=True)
+
+    loss = lambda fn: lambda *a: sum((x ** 2).sum() for x in fn(*a))
+    for a, r in zip(kern(q, k, v) + jax.grad(loss(kern), (0, 1, 2))(q, k, v),
+                    ref(q, k, v) + jax.grad(loss(ref), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_a_document_mask_refuses_offsets_and_two_lengths():
+    from cxxnet_tpu.ops.flash import flash_attention
+
+    q, k, v = _masked(2, 2, 16, 16, t=32)
+    doc = _docs([[5], [9]], 32)
+    with pytest.raises(ValueError, match="one length"):
+        flash_attention(q, k, v, causal=True, doc=doc, q_off=jnp.int32(4),
+                        interpret=True)
+    with pytest.raises(ValueError, match="one length"):
+        flash_attention(q[:, :16], k, v, doc=doc[:, :16], interpret=True)
+    with pytest.raises(ValueError, match="flash: q"):
+        flash_attention(q, k[:, :, :1], v, interpret=True)
+
+
+# ------------------------------------- which blocks are live, and visited
+def _brute_live(doc, t, bq, bk, causal):
+    """``(B, nq, nk)`` bool: the block holds a pair that may attend."""
+    pos = np.arange(t)
+    ok = np.ones((doc.shape[0], t, t), bool)
+    if causal:
+        ok &= (pos[:, None] >= pos[None, :])[None]
+    ok &= doc[:, :, None] == doc[:, None, :]
+    b = doc.shape[0]
+    return ok.reshape(b, t // bq, bq, t // bk, bk).any(axis=(2, 4))
+
+
+@pytest.mark.parametrize("bq, bk", [(16, 16), (32, 16), (16, 32), (8, 64)])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_live_ranges_are_the_brute_force_mask_s_blocks(bq, bk, causal):
+    """Every block with a pair that may attend lies inside ``[lo, hi]``
+    (and ``[qlo, qhi]`` from the keys' side), and the ranges hold no
+    other block: documents are runs, so the live blocks of a row of
+    blocks ARE one range."""
+    from cxxnet_tpu.ops.flash import _offs, _ranges
+
+    t = 128
+    rng = np.random.RandomState(bq + bk + causal)
+    cuts = [sorted(rng.choice(np.arange(1, t), n, replace=False))
+            for n in (0, 1, 3, 7, 20)] + [[16, 32, 64], [127], [1]]
+    doc = _docs(cuts, t)
+    nq, nk = t // bq, t // bk
+    lo, hi, qlo, qhi = (np.asarray(x) for x in _ranges(
+        doc, _offs(None, None), nq, nk, bq, bk, causal))
+    live = _brute_live(np.asarray(doc), t, bq, bk, causal)
+    b = len(cuts)
+    j = np.arange(nk)[None, None, :]
+    got = (j >= lo.reshape(b, nq, 1)) & (j <= hi.reshape(b, nq, 1))
+    np.testing.assert_array_equal(got, live)
+    i = np.arange(nq)[None, :, None]
+    got = (i >= qlo.reshape(b, 1, nk)) & (i <= qhi.reshape(b, 1, nk))
+    np.testing.assert_array_equal(got, live)
+    # without documents one table row serves every batch row
+    lo, hi, qlo, qhi = (np.asarray(x) for x in _ranges(
+        None, _offs(None, None), nq, nk, bq, bk, causal))
+    assert lo.shape == hi.shape == (nq,) and qlo.shape == qhi.shape == (nk,)
+    whole = _brute_live(np.zeros((1, t), np.int32), t, bq, bk, causal)[0]
+    np.testing.assert_array_equal(
+        (j[0] >= lo[:, None]) & (j[0] <= hi[:, None]), whole)
+
+
+@pytest.mark.parametrize("nq, nk, bq, bk, group", [
+    (4, 4, 16, 16, 1), (2, 4, 32, 16, 4), (4, 2, 16, 32, 2), (1, 1, 64, 64, 8)])
+def test_a_causal_grid_visits_the_lower_triangle_once(nq, nk, bq, bk, group):
+    from cxxnet_tpu.ops.flash import _FIRST, _LAST, _steps
+
+    (iq, ik, fl, _), (bk_t, bq_t, bfl, bg) = _steps(nq, nk, bq, bk, True,
+                                                   group)
+    reach = {(i, j) for i in range(nq) for j in range(nk)
+             if j * bk <= i * bq + bq - 1}
+    assert sorted(zip(iq.tolist(), ik.tolist())) == sorted(reach)
+    # a query block's steps are together, first and last flagged once
+    assert (np.diff(iq) >= 0).all()
+    assert int((fl & _FIRST != 0).sum()) == int((fl & _LAST != 0).sum()) == nq
+    # the keys' side: every query head of the group, every pair once
+    assert sorted(zip(bq_t.tolist(), bk_t.tolist(), bg.tolist())) == sorted(
+        (i, j, g) for (i, j) in reach for g in range(group))
+    assert (np.diff(bk_t) >= 0).all()
+    assert int((bfl & _FIRST != 0).sum()) == int(
+        (bfl & _LAST != 0).sum()) == nk
+    # not causal: the whole square
+    full = _steps(nq, nk, bq, bk, False, group)[0]
+    assert len(full[0]) == nq * nk
+
+
+def test_a_dead_step_names_the_block_it_holds():
+    """Clamped into the live range a dead step's index is the range's
+    nearest end, so consecutive steps name one block and nothing moves;
+    an empty range names its ``lo``."""
+    from cxxnet_tpu.ops.flash import _clamp
+
+    lo, hi = jnp.int32(2), jnp.int32(4)
+    assert [int(_clamp(jnp.int32(x), lo, hi)) for x in range(7)] == [
+        2, 2, 2, 3, 4, 4, 4]
+    assert int(_clamp(jnp.int32(5), jnp.int32(3), jnp.int32(-1))) == 3
+
+
+# -------------------------------------------- the chooser (ops/attention)
+@pytest.mark.parametrize("shape, want", [
+    # (T, H, Hkv, Dqk, Dv, dtype) -> block
+    ((8192, 32, 32, 192, 128, jnp.bfloat16), 1024),   # JoyAI
+    ((8192, 32, 8, 64, 64, jnp.bfloat16), 1024),      # granite
+    ((8192, 16, 2, 256, 256, jnp.bfloat16), 1024),    # qwen3_next
+    ((1024, 4, 4, 64, 64, jnp.float32), 1024),
+    ((1536, 4, 4, 64, 64, jnp.bfloat16), 512),
+    ((512, 4, 4, 64, 64, jnp.bfloat16), None),          # short: mha whole
+    ((1000, 4, 4, 64, 64, jnp.bfloat16), None),         # no block >= 128
+    ((2048, 4, 4, 48, 48, jnp.bfloat16), None),         # a width of 48
+    ((2048, 4, 4, 512, 512, jnp.bfloat16), None),       # wider than 256
+    ((2048, 6, 4, 64, 64, jnp.bfloat16), None),         # heads do not group
+    ((2048, 4, 4, 64, 64, jnp.float16), None),
+], ids=lambda v: "x".join(str(getattr(x, "__name__", x)) for x in v)
+    if isinstance(v, tuple) and len(v) == 6 else None)
+def test_block_for_decides_from_shapes(shape, want):
+    from cxxnet_tpu.ops.flash import block_for
+
+    t, h, hk, dqk, dv, dtype = shape
+    sds = jax.ShapeDtypeStruct
+    got = block_for(sds((1, t, h, dqk), dtype), sds((1, t, hk, dqk), dtype),
+                     sds((1, t, hk, dv), dtype))
+    assert got == want
+    if want is not None:
+        assert t % want == 0 and want >= 128
+
+
+@pytest.mark.parametrize("t, d, kernels", [(1024, 64, True), (256, 64, False),
+                                           (1024, 48, False)])
+def test_attend_lowers_the_kernels_for_a_tpu_and_mha_elsewhere(t, d, kernels):
+    """The platform the program is LOWERED for decides, and the shapes:
+    for a TPU a long row is Mosaic calls, on the CPU it is ``mha`` and
+    the flag says so."""
+    from cxxnet_tpu.ops.attention import attend
+
+    sds = jax.ShapeDtypeStruct
+    q = sds((1, t, 4, d), jnp.bfloat16)
+    kv = sds((1, t, 2, d), jnp.bfloat16)
+    doc = sds((1, t), jnp.int32)
+
+    def f(q, k, v, doc):
+        return attend(q, k, v, causal=True, scale=0.1, doc=doc)
+
+    tpu = jax.export.export(jax.jit(f), platforms=["tpu"])(q, kv, kv, doc)
+    assert ("tpu_custom_call" in tpu.mlir_module()) == kernels
+    cpu = jax.export.export(jax.jit(f), platforms=["cpu"])(q, kv, kv, doc)
+    assert "tpu_custom_call" not in cpu.mlir_module()
+    assert [a.dtype for a in tpu.out_avals] == [jnp.bfloat16, jnp.uint32]
+
+
+def test_attend_on_the_cpu_is_mha_and_says_so():
+    from cxxnet_tpu.ops.attention import attend
+
+    q, k, v = _masked(4, 2, 64, 64, t=1024, b=1, dtype=jnp.bfloat16)
+    doc = _docs([[100, 700]], 1024)
+    o, flash = jax.jit(lambda *a: attend(*a, causal=True, doc=doc))(q, k, v)
+    assert int(flash) == 0 and flash.dtype == jnp.uint32
+    want = mha(q, k, v, causal=True, doc=doc, block_q=512)
+    np.testing.assert_array_equal(np.asarray(o, np.float32),
+                                  np.asarray(want, np.float32))
+    # a short row: the same function, whole
+    o, flash = attend(q[:, :64], k[:, :64], v[:, :64], causal=True,
+                      doc=doc[:, :64])
+    assert int(flash) == 0
+    np.testing.assert_array_equal(
+        np.asarray(o, np.float32), np.asarray(mha(
+            q[:, :64], k[:, :64], v[:, :64], causal=True, doc=doc[:, :64]),
+            np.float32))

@@ -363,7 +363,8 @@ def test_the_builder_s_conf_trains_and_counts_its_pairs():
     assert "rope_theta = 32000000.0" in text and "routed_scale = 2.5" in text
     assert "wgate" not in text and ":lr" not in text
     tr = trainer(text)
-    assert set(tr.aux) == {"l4_moe1", "l15_mtp_moe"}
+    assert set(tr.aux) == {"l4_moe1", "l15_mtp_moe",
+                           "l1_mla0", "l3_mla1", "l14_mtp_mla"}
     r = np.random.RandomState(0)
     ids = r.randint(0, 64, (4, 1, 64)).astype(np.float32)
     router = np.asarray(tr.params["l4_moe1"]["wgate"]).copy()
@@ -382,10 +383,16 @@ def test_the_builder_s_conf_trains_and_counts_its_pairs():
                               expert)
     stats = pipeline_stats()
     before = stats.counters().get("expert_pairs", 0)
+    tokens = stats.counters().get("attn_tokens", 0)
+    flash = stats.counters().get("attn_tokens_flash", 0)
     tr.count_layer_state()
     pairs = stats.counters()["expert_pairs"] - before
     # 8 steps x 64 tokens x 3 picks x 2 layers, a quarter of them held
     assert 0.5 * 768 < pairs < 1.5 * 768
+    # the latent layers count their tokens, and none by the kernels off
+    # the TPU: 8 steps x 64 tokens x 3 layers
+    assert stats.counters()["attn_tokens"] - tokens == 8 * 64 * 3
+    assert stats.counters().get("attn_tokens_flash", 0) == flash
     # without the module: the main model alone
     bare = joyai_llm_flash_conf(**dict(TINY, num_nextn_predict_layers=0))
     assert "mtp_" not in bare and bare.count("= softmax") == 1
@@ -431,3 +438,42 @@ def test_attn_pairs_counts_what_a_causal_query_of_its_document_sees():
             if tok == 0:
                 run = 0
     assert attn_pairs(rows) == slow
+
+
+# ----------------------------------------------------------------------
+# the masked attention layers' counters (PR 37)
+@pytest.mark.parametrize("kind, shapes, cfg", [
+    ("latent_attention", [(2, 16, 32), (2, 16)],
+     dict(nhead=2, q_rank=16, kv_rank=8, nope_dim=8, rope_dim=4, v_dim=8,
+          causal=1)),
+    ("attention", [(2, 16, 32), (2, 16)],
+     dict(nhead=4, nkvhead=2, causal=1, rotary_dim=8, out_gate=1,
+          no_bias=1)),
+    ("attention", [(2, 16, 32)], dict(nhead=4, score_scale=0.125, causal=1)),
+], ids=["latent", "masked_with_ids", "masked_by_a_scale"])
+def test_masked_attention_counts_its_tokens_and_none_flash_on_a_cpu(
+        kind, shapes, cfg):
+    lay, p, _ = make(kind, shapes, **cfg)
+    aux = lay.init_aux(shapes)
+    assert set(aux) == set(lay.aux_counters) == {"attn_tokens",
+                                                 "attn_tokens_flash"}
+    assert all(v.dtype == jnp.uint32 and v.shape == () for v in aux.values())
+    r = np.random.RandomState(0)
+    ins = [jnp.asarray(r.randn(*shapes[0]), jnp.float32)]
+    if len(shapes) > 1:
+        ids = np.ones(shapes[1], np.float32)
+        ids[0, 5] = ids[1, 11] = 0
+        ins.append(jnp.asarray(ids))
+    (want,) = lay.apply(p, ins)
+    step = jax.jit(lambda p, aux, ins: lay.apply_stateful(p, aux, ins))
+    for n in (1, 2):
+        (got,), aux = step(p, aux, ins)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+        assert int(aux["attn_tokens"]) == n * 2 * 16
+        assert int(aux["attn_tokens_flash"]) == 0
+
+
+def test_the_plain_attention_layer_keeps_no_counter():
+    lay, _, _ = make("attention", [(2, 16, 32)], nhead=4)
+    assert lay._plain() and lay.init_aux([(2, 16, 32)]) == {}

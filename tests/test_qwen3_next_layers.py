@@ -346,7 +346,9 @@ def test_positions_restart_at_each_document():
 def test_attention_refuses_what_the_masked_path_cannot_do():
     with pytest.raises(ValueError, match="rotary_dim"):
         make("attention", [(2, 8, 16)], nhead=4, rotary_dim=3)
-    with pytest.raises(ValueError, match="masked XLA path"):
+    # the masked path chooses its own form (ops/attention.attend):
+    # attn_impl = pallas forces the plain layer's kernel only
+    with pytest.raises(ValueError, match="chooses between the flash kernels"):
         make("attention", [(2, 8, 16)], nhead=4, head_dim=8,
              attn_impl="pallas")
     # and a layer that sets none of the new keys is the layer it was
@@ -526,7 +528,7 @@ def test_the_builder_s_conf_trains_and_counts_its_pairs():
     tr.set_params(cfgmod.parse_pairs(text))
     tr.set_param("silent", "1")
     tr.init_model()
-    assert set(tr.aux) == {"l1_gdn0", "l2_moe0", "l4_moe1"}
+    assert set(tr.aux) == {"l1_gdn0", "l2_moe0", "l4_moe1", "l3_attn1"}
     r = np.random.RandomState(0)
     ids = r.randint(0, 64, (4, 1, 64)).astype(np.float32)
     router = np.asarray(tr.params["l2_moe0"]["wgate"]).copy()
@@ -569,6 +571,11 @@ def test_a_cpu_run_counts_the_scan_s_tokens_and_none_fused():
     assert got["gdn_scan_tokens"] - before.get("gdn_scan_tokens", 0) == 256
     assert got.get("gdn_scan_tokens_fused", 0) == before.get(
         "gdn_scan_tokens_fused", 0)
+    # and the gated attention layer's (its masked path), likewise
+    assert set(tr.aux["l3_attn1"]) == {"attn_tokens", "attn_tokens_flash"}
+    assert got["attn_tokens"] - before.get("attn_tokens", 0) == 256
+    assert got.get("attn_tokens_flash", 0) == before.get(
+        "attn_tokens_flash", 0)
     tr.count_layer_state()
     assert stats.counters()["gdn_scan_tokens"] == got["gdn_scan_tokens"]
 

@@ -20,11 +20,22 @@ on-chip-measurement guide, section 2: nothing runs, no chip is needed).
   most 14.4 GB at its fullest — the number that decided between 16 held
   experts and the fallback of 8 (ISSUE 36).
 
+* lowered for a TPU, masked attention IS the flash kernels of
+  ``ops/flash.py`` (PR 37): ``flash_fwd`` (forward and the ``remat``
+  recompute), ``flash_dq`` and ``flash_dkv`` under the latent layers'
+  ``core`` scope and under an ``attention`` layer's own at granite's and
+  qwen3_next's head shapes, and no float32 ``(…, 512, <= 8192)`` score
+  block of ``mha``'s is left.  The JoyAI step is compiled as the CLI
+  compiles it and held to the 14.4 GB that fit a chip: it reads 14.21
+  GB with the kernels for 13.60 with the row blocks (ISSUE 37's "no
+  higher than 13.60" is NOT met: PERF.md section 6, PR 37).
+
 The topology is described inside a fixture, in this one file: only one
 process at a time may load the TPU's library.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -98,8 +109,6 @@ def test_the_segmented_delta_rule_fits_beside_the_state(one_chip):
 def test_the_delta_rule_lowered_for_a_tpu_is_the_fused_kernels(one_chip):
     """The published widths: a row of 8192 tokens, 16 key and 32 value
     heads of 128 x 128, bfloat16."""
-    import re
-
     from cxxnet_tpu.ops.gdn import gated_delta_scan_counted
 
     t, hk, hv, d = 8192, 16, 32, 128
@@ -161,3 +170,73 @@ def test_the_joyai_step_fits_a_chip_with_sixteen_held_experts(one_chip):
                   "l20_mtp_mla)/core/", "l19_mtp_eh_proj", "l21_mtp_moe)/route/"):
         assert scope in text, scope
     assert 'op_name="ragged-dot-none"' in text
+    # PR 37: every latent layer's core is four Mosaic calls (forward, the
+    # remat recompute, dq, dk/dv), all billed to its core scope; mha's
+    # float32 score blocks (1, 32, 512, <= 8192) are gone
+    calls = _mosaic_calls(text)
+    assert len(calls) == 6 * 4, [c[-60:] for c in calls]
+    assert all("/core/" in c and ("mla" in c) for c in calls), calls
+    for kern, n in (("flash_fwd", 12), ("flash_dq", 6), ("flash_dkv", 6)):
+        assert sum(f"/{kern}/pallas_call" in c for c in calls) == n, kern
+    assert not _SCORE_BLOCK.search(text)
+
+
+_SCORE_BLOCK = re.compile(r"f32\[[0-9,]*,512,(?:512|1024|[1-8][0-9]{3})\]")
+
+
+def _mosaic_calls(text):
+    """The ``op_name`` of every Pallas kernel's Mosaic custom call of a
+    compiled text (the compiler's own ``ragged-dot-*`` are not ours)."""
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return [n for n in names if n.endswith("/pallas_call")]
+
+
+@pytest.mark.parametrize("cfg", [
+    # granite 4.0-H micro: 32 query heads over 8 of width 64, scale 1/64
+    dict(nhead=32, nkvhead=8, score_scale=0.015625),
+    # qwen3_next: 16 over 2 of width 256, partial rotary, an output gate
+    dict(nhead=16, nkvhead=2, head_dim=256, qk_norm=1, rotary_dim=64,
+         rope_theta=10000000.0, out_gate=1),
+], ids=["granite", "qwen3_next"])
+def test_an_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
+        one_chip, cfg):
+    """One ``attention`` layer on a packed row of 8192 tokens, bfloat16,
+    under ``remat`` as the step programs run it."""
+    from cxxnet_tpu.layers import create_layer
+
+    lay = create_layer("attention")
+    for k, v in dict(cfg, causal=1, no_bias=1, prenorm=1).items():
+        lay.set_param(k, str(v))
+    shapes = [(1, 8192, 2048), (1, 8192)]
+    lay.infer_shape(shapes)
+    params = jax.eval_shape(lambda k: lay.init_params(k, shapes),
+                            jax.random.PRNGKey(0))
+    aux = jax.eval_shape(lambda: lay.init_aux(shapes))
+
+    def loss(p, aux, x, ids):
+        def run(p, x):
+            with jax.named_scope("l3_attn1"):
+                (y,), new = lay.apply_stateful(p, aux, [x, ids])
+            return jnp.sum(y.astype(jnp.float32)), new
+        return jax.checkpoint(run)(p, x)
+
+    shaped = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda v: _shaped(one_chip, v.shape, v.dtype), t)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 2), has_aux=True)
+                       ).lower(
+        shaped(params), shaped(aux), _shaped(one_chip, shapes[0]),
+        _shaped(one_chip, shapes[1], jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = _mosaic_calls(text)
+    assert sorted(c.split("/")[-2] for c in calls) == [
+        "flash_dkv", "flash_dq", "flash_fwd", "flash_fwd"], calls
+    assert all("l3_attn1" in c for c in calls), calls
+    assert not _SCORE_BLOCK.search(text)
+    # grouped heads are read by the index map: no key or value repeated
+    # to the query heads' count in HBM
+    h, hk = cfg["nhead"], cfg["nkvhead"]
+    dh = cfg.get("head_dim", 2048 // h)
+    assert f"bf16[{hk},8192,{dh}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9

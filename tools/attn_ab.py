@@ -1,0 +1,133 @@
+"""On-chip A/B of masked attention's two forms at the token cells' head
+shapes: ``ops/attention.mha``'s checkpointed row blocks against the
+flash kernels of ``ops/flash.py`` at a list of block sizes.
+
+One packed row of 8192 tokens cut into documents as the cells' traffic
+cuts it; forward + backward (the gradient of a weighted sum of the
+output with respect to q, k and v) under one ``jax.jit``, the median
+wall time of ``--reps`` calls that end in ``block_until_ready``.  One
+JSON line a reading, also written to ``chiprun_out/attn_ab.jsonl``; the
+kernels' outputs are held against ``mha``'s before anything is timed.
+A measurement path: it refuses a host without a TPU.
+
+Usage:
+    python tools/attn_ab.py [--shapes joyai,granite,qwen3_next]
+        [--blocks 512x512,1024x512] [--docs 5] [--reps 10] [--no-xla]
+        [--cpu-rehearsal --tokens 256]
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+#: heads, key-value heads, q·k width, v width, scale
+SHAPES = {
+    "joyai": (32, 32, 192, 128, None),
+    "granite": (32, 8, 64, 64, 0.015625),
+    "qwen3_next": (16, 2, 256, 256, None),
+}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--blocks", default="512x512")
+    ap.add_argument("--tokens", type=int, default=8192)
+    ap.add_argument("--docs", type=int, default=5)
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--no-xla", action="store_true")
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="the same code on the CPU with the kernels "
+                    "interpreted: finds faults, measures nothing")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cxxnet_tpu.ops.attention import mha
+    from cxxnet_tpu.ops.flash import flash_attention
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.cpu_rehearsal:
+        print(f"attn_ab: needs a TPU, found {dev.platform}", file=sys.stderr)
+        return 2
+    t = args.tokens
+    rng = np.random.RandomState(args.seed)
+    cuts = np.sort(rng.choice(np.arange(1, t), args.docs - 1, replace=False))
+    doc_np = np.searchsorted(cuts, np.arange(t), side="right").astype(
+        np.int32)
+    bounds = np.concatenate([[0], cuts, [t]])
+    pairs = int(sum(n * (n + 1) // 2 for n in np.diff(bounds)))
+    doc = jnp.asarray(doc_np)[None]
+    os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
+    out = open(os.path.join(REPO, "chiprun_out", "attn_ab.jsonl"), "a")
+
+    def timed(fn, *a):
+        fn(*a)[0].block_until_ready()
+        walls = []
+        for _ in range(args.reps):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*a))
+            walls.append(time.perf_counter() - t0)
+        return float(np.median(walls)) * 1e3
+
+    for name in args.shapes.split(","):
+        h, hk, dqk, dv, scale = SHAPES[name]
+        mk = lambda *s: jnp.asarray(rng.randn(*s), jnp.bfloat16)  # noqa: E731
+        q, k, v = mk(1, t, h, dqk), mk(1, t, hk, dqk), mk(1, t, hk, dv)
+        w = mk(1, t, h, dv)
+        flops = 2 * pairs * h * (dqk + dv)     # the forward's, on the docs
+
+        def grads(attn):
+            def loss(q, k, v, w, doc):
+                return jnp.sum((attn(q, k, v, doc) * w).astype(jnp.float32))
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+        def fwd(attn):
+            return jax.jit(lambda q, k, v, w, doc: (attn(q, k, v, doc),))
+
+        ref_attn = lambda q, k, v, doc: mha(  # noqa: E731
+            q, k, v, causal=True, scale=scale, doc=doc, block_q=512)
+        a = (q, k, v, w, doc)
+        ref = fwd(ref_attn)(*a)[0].astype(jnp.float32)
+        ref_g = grads(ref_attn)(*a)
+        rows = [] if args.no_xla else [("xla_rows_512", ref_attn)]
+        for blk in args.blocks.split(","):
+            bq, bk = (int(x) for x in blk.split("x"))
+            rows.append((f"flash_{bq}x{bk}", lambda q, k, v, doc, bq=bq,
+                         bk=bk: flash_attention(
+                             q, k, v, causal=True, scale=scale, doc=doc,
+                             block_q=bq, block_k=bk,
+                             interpret=args.cpu_rehearsal)[0]))
+        for label, attn in rows:
+            try:
+                got = fwd(attn)(*a)[0].astype(jnp.float32)
+                err = float(jnp.abs(got - ref).max())
+                g_err = max(float(jnp.abs(x.astype(jnp.float32)
+                                          - y.astype(jnp.float32)).max()
+                                  / (jnp.abs(y.astype(jnp.float32)).max()))
+                            for x, y in zip(grads(attn)(*a), ref_g))
+                line = {"shape": name, "form": label, "docs": args.docs,
+                        "pairs": pairs, "fwd_ms": timed(fwd(attn), *a),
+                        "fwd_bwd_ms": timed(grads(attn), *a),
+                        "max_abs_err": err, "grad_rel_err": g_err,
+                        "fwd_tflops_needed": flops / 1e12,
+                        "device": dev.device_kind}
+            except Exception as e:  # noqa: BLE001 - a reading, reported
+                line = {"shape": name, "form": label,
+                        "error": f"{type(e).__name__}: {e}"[:2000]}
+            print(json.dumps(line), flush=True)
+            out.write(json.dumps(line) + "\n")
+            out.flush()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
