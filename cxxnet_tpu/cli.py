@@ -1261,12 +1261,10 @@ class LearnTask:
                 if self.elastic_member is not None:
                     self.elastic_member.report_round(self.start_counter)
                 try:
-                    with obs_trace.span("train.round",
-                                        round=self.start_counter):
-                        completed = self._elastic_guard(
-                            lambda: self._train_one_round(timer, tracer),
-                            what=f"train round {self.start_counter}",
-                        )
+                    completed = self._elastic_guard(
+                        lambda: self._train_one_round(timer, tracer),
+                        what=f"train round {self.start_counter}",
+                    )
                 except DivergenceError as e:
                     if self._handle_divergence(e):
                         cc += 1  # the aborted attempt keeps its budget
@@ -1346,6 +1344,7 @@ class LearnTask:
                             break  # this rank left (planned shrink)
                         continue  # rebuilt onto the new mesh
         finally:
+            self._loop.close()  # the last boundary: no round bills it
             if tuner is not None:
                 tuner.stop()
             self._preempt.uninstall()
@@ -1520,7 +1519,17 @@ class LearnTask:
         request stopped the round early (single-process only — see
         task_train), True when the round ran to completion.  The
         round's entry and exit; what lies between the rewind and the
-        last fence is ``train_loop.RoundLoop``."""
+        last fence is ``train_loop.RoundLoop``, which owns the spans
+        ``train.round`` (to the last fence) and ``train.boundary``
+        (from there to the next round's ``begin``): a round that
+        raises closes them unbilled."""
+        try:
+            return self._round(timer, tracer)
+        except BaseException:
+            self._loop.close()
+            raise
+
+    def _round(self, timer, tracer) -> bool:
         if not self.silent:
             print(f"update round {self.start_counter - 1}", flush=True)
         from .obs import trace as obs_trace
@@ -1529,9 +1538,12 @@ class LearnTask:
 
         trainer = self.net_trainer
         loop = self._loop
-        loop.begin(trainer)  # the first chunk's period starts here
         trainer.start_round(self.start_counter)
         pipeline_stats().reset()  # per-round stage breakdown
+        # the boundary behind the last round ends here, billed to this
+        # one; the round's span, its first chunk's period and its head
+        # start
+        loop.begin(trainer, self.start_counter)
         with stage("next", step=trainer.epoch_counter):
             self.itr_train.before_first()
             # anchor the augmentation epoch to the ROUND counter (after

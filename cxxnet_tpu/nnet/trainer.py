@@ -1011,7 +1011,8 @@ class NetTrainer:
         # host stages of the chunk, on the caller's thread; with the
         # round loop's next / copy / stack they tile its period
         # (doc/observability.md).  h2d is the ENQUEUE: the transfer's
-        # tail ends inside device_wait and only a trace can split it
+        # tail ends inside device_wait; where it is exposed (a round's
+        # first chunk) the round loop bills it in ``run_exposed``
         fed = data_arr.shape[0] * (data_arr.shape[1] if per_step else 1)
         with stage("h2d", rows=fed, step=first_epoch):
             data_dev = self._stage_scan(data, per_step)
@@ -1922,7 +1923,6 @@ class NetTrainer:
                     self._score_train_batch(out, batch, n_real, node_cache)
                 stepper.add_blocked(time.perf_counter() - t0)
             self.epoch_counter += 1
-            obs_device.maybe_sample_step(self.epoch_counter, self.sync)
             return
         if self.update_period == 1:
             # fused SPMD fast path: fwd+bwd+update in one donated program
@@ -1937,11 +1937,6 @@ class NetTrainer:
             if self.eval_train:
                 self._score_train_batch(out, batch, n_real, node_cache)
             self.epoch_counter += 1
-            # sampled device fence (device_sample_every = N): every Nth
-            # update blocks here and the wait lands in the
-            # train_step_device_seconds histogram; off by default — a
-            # fence breaks the async dispatch overlap
-            obs_device.maybe_sample_step(self.epoch_counter, self.sync)
             return
         if self.eval_train:
             loss, out, self.aux, grads = self._fwd_train_fn()(
@@ -1978,7 +1973,6 @@ class NetTrainer:
             self._grad_accum = None
             self.sample_counter = 0
             self.epoch_counter += 1
-            obs_device.maybe_sample_step(self.epoch_counter, self.sync)
 
     def update_all(self, data: np.ndarray, labels: np.ndarray) -> None:
         """numpy-in convenience (wrapper API ``CXNNetUpdateBatch``)."""

@@ -15,8 +15,8 @@ from any layer:
   ``event_log_backups``), with an always-on in-memory ring;
 * :mod:`~cxxnet_tpu.obs.device` — device-plane telemetry: per-program
   cold-call and cumulative compile seconds, device-memory
-  watermarks, sampled step fences (``device_telemetry`` /
-  ``device_sample_every``);
+  watermarks, the step programs' device time from the round loop's
+  fences (``device_telemetry``);
 * :mod:`~cxxnet_tpu.obs.alerts` — declarative threshold alerts over
   registry snapshots (``alert=<name>:<metric>:<op>:<threshold>[:for_s]``
   / ``alert_period_s``), surfaced at ``GET /alertz`` and in

@@ -5,7 +5,8 @@ pillars (registry / spans / events) say what the PROCESS is doing; this
 module says what the CHIP is being asked to do — wall-clock compile
 time for every program the trainer / serve cache / loop fine-tuner
 jits, live and peak device-memory watermarks where the backend reports
-them, and sampled per-step device timing via periodic blocking fences.
+them, and the step programs' device time as the round loop reads it
+from its own fences.
 All of it lands in the shared metrics registry, so ``GET /metricsz``
 exposes the device plane next to the host plane:
 
@@ -21,10 +22,11 @@ exposes the device plane next to the host plane:
   peak (``peak_bytes_in_use``) allocator watermarks from
   ``device.memory_stats()``, sampled at scrape time; absent on backends
   that do not report them (CPU);
-* ``train_step_device_seconds`` — a histogram of sampled step fences
-  (``device_sample_every = N``: every Nth update blocks until the device
-  finishes and the wait is observed).  Default off — a fence breaks the
-  async dispatch overlap, so it is an opt-in diagnostic.
+* ``train_step_device_seconds`` — a histogram of the step program's
+  device time per step, observed once for every scanned chunk the round
+  loop bills a ``run`` for (``train_loop.RoundLoop``: the time between
+  two fences that both blocked, ÷ the chunk's steps).  No fence of its
+  own and no key: the loop's fences are there anyway.
 
 Instrumentation is wrapper-based and fail-open: :func:`instrument` wraps
 a jitted callable; the wrapped call is a straight pass-through except
@@ -53,7 +55,7 @@ __all__ = [
     "InstrumentedJit",
     "install_compile_listener",
     "register_memory_collector",
-    "maybe_sample_step",
+    "observe_step",
     "mark_kernel_selected",
     "set_train_state_bytes",
     "summary",
@@ -66,7 +68,7 @@ ConfigEntry = Tuple[str, str]
 #: compile-fence buckets (seconds): cold XLA compiles run 10ms-minutes
 COMPILE_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
                    30.0, 60.0, 120.0, 300.0)
-#: sampled step-fence buckets (seconds)
+#: device-step buckets (seconds)
 STEP_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 
@@ -81,13 +83,11 @@ class _State:
 
         self.enabled = os.environ.get(
             "CXXNET_DEVICE_TELEMETRY", "1") != "0"
-        self.sample_every = 0
         self.lock = threading.Lock()
         self.programs = 0
         self.compiles = 0
         self.compile_seconds = 0.0
         self.cold_call_seconds = 0.0
-        self.sampled_steps = 0
         # what a program costs before and beside its backend compile:
         # jax's own trace / lowering / cache-load durations
         self.trace_seconds = 0.0
@@ -127,8 +127,8 @@ class _DeviceMetrics:
         )
         self.step_seconds = reg.histogram(
             "train_step_device_seconds",
-            "Sampled per-step device fence time "
-            "(device_sample_every = N).",
+            "Device time of one train step, from the round loop's "
+            "fences (a scanned chunk's run / its steps).",
             buckets=STEP_BUCKETS,
         )
 
@@ -148,13 +148,11 @@ def device_metrics() -> _DeviceMetrics:
 # ----------------------------------------------------------------------
 # config
 def configure(cfg: Sequence[ConfigEntry]) -> None:
-    """Arm from the ordered config stream (``device_telemetry``,
-    ``device_sample_every``); unknown keys ignored."""
+    """Arm from the ordered config stream (``device_telemetry``);
+    unknown keys ignored."""
     for name, val in cfg:
         if name == "device_telemetry":
             _STATE.enabled = bool(int(val))
-        elif name == "device_sample_every":
-            _STATE.sample_every = max(0, int(val))
     if _STATE.enabled:
         install_compile_listener()
         register_memory_collector()
@@ -169,13 +167,11 @@ def reset() -> None:
     (registered listeners/collectors stay — they are idempotent)."""
     global _METRICS
     _STATE.enabled = True
-    _STATE.sample_every = 0
     with _STATE.lock:
         _STATE.programs = 0
         _STATE.compiles = 0
         _STATE.compile_seconds = 0.0
         _STATE.cold_call_seconds = 0.0
-        _STATE.sampled_steps = 0
         _STATE.trace_seconds = 0.0
         _STATE.lower_seconds = 0.0
         _STATE.cache_retrieval_seconds = 0.0
@@ -476,34 +472,24 @@ def set_train_state_bytes(per_device, total: float) -> None:
 
 
 # ----------------------------------------------------------------------
-# sampled step fences
-def maybe_sample_step(step: int, sync_fn: Callable[[], None]) -> bool:
-    """Every ``device_sample_every``-th step (and only when the key is
-    set), block on ``sync_fn`` and observe the wait as
-    ``train_step_device_seconds``.  Off (the default) this is one int
-    compare — the hot-path cost the <1% bar allows."""
-    n = _STATE.sample_every
-    if n <= 0 or (step % n) != 0:
-        return False
-    t0 = time.perf_counter()
+# the step programs' device time
+def observe_step(seconds: float) -> None:
+    """One observation of ``train_step_device_seconds``: a train step's
+    device time as the round loop read it from its own fences (a
+    scanned chunk's ``run`` ÷ its steps)."""
+    if not _STATE.enabled:
+        return
     try:
-        sync_fn()
-    finally:
-        dt = time.perf_counter() - t0
-        try:
-            device_metrics().step_seconds.observe(dt)
-            with _STATE.lock:
-                _STATE.sampled_steps += 1
-        except Exception:  # noqa: BLE001 - telemetry must never raise
-            pass
-    return True
+        device_metrics().step_seconds.observe(seconds)
+    except Exception:  # noqa: BLE001 - telemetry must never raise
+        pass
 
 
 # ----------------------------------------------------------------------
 def summary() -> Dict[str, float]:
     """Lifetime totals for the per-round telemetry record (cli.py):
     programs instrumented, backend compiles and their cumulative
-    seconds, sampled fences, and the seconds jax spent tracing,
+    seconds, and the seconds jax spent tracing,
     lowering and loading programs from the persistent cache."""
     with _STATE.lock:
         return {
@@ -511,7 +497,6 @@ def summary() -> Dict[str, float]:
             "compiles": _STATE.compiles,
             "compile_seconds": round(_STATE.compile_seconds, 6),
             "cold_call_seconds": round(_STATE.cold_call_seconds, 6),
-            "sampled_steps": _STATE.sampled_steps,
             "trace_seconds": round(_STATE.trace_seconds, 6),
             "lower_seconds": round(_STATE.lower_seconds, 6),
             "cache_retrieval_seconds": round(

@@ -28,6 +28,24 @@ A fence ends a ``chunk`` stage (the fence-to-fence period that ``next``
 takes its edges from the same fences) and is one ``timer.add(dt, n_steps)``, in
 :meth:`RoundLoop._lap` and nowhere else.
 
+The same fences bill the DEVICE's time, untraced (doc/observability.md
+has the table).  A chunk dispatched while its predecessor ran starts
+when the predecessor ends, so where the loop blocked at both fences the
+time between the two returns of ``block_until_ready`` is the step
+program's run: stage ``run`` (and whatever the device waited in it for
+the chunk's rows, where an upload outlasts the run before it: a host
+cannot tell the two apart, a trace can).  A chunk dispatched onto an empty device —
+a round's first, one after a drain, or one whose predecessor had landed
+before the dispatch (counted ``chunks_starved``) — is the exposed tail
+of its upload plus its run, from the dispatch's return to its fence:
+``run_exposed``.  A chunk that had landed before the loop asked
+(``chunks_late``) has no known end: it bills neither, and nor does its
+successor.  ``head`` is the host's feed of a round's first dispatch,
+with the chip empty, and ``boundary`` what lies between a round's last
+fence and the next round's :meth:`RoundLoop.begin`, billed to that next
+round.  The spans ``train.round`` (``begin`` to the last fence) and
+``train.boundary`` tile the loop's thread and never overlap.
+
 This module knows the trainer by its public methods only and nothing of
 the CLI: ``cli.LearnTask._train_one_round`` is the round's entry and
 exit, and calls in here for what lies between.
@@ -42,7 +60,15 @@ import jax
 
 from .io.chunk import ChunkAssembler
 from .io.data import DataBatch
+from .obs import device as obs_device
 from .utils.profiler import pipeline_stats, stage
+
+
+def _landed(handle) -> bool:
+    """Whether every leaf of a dispatched chunk's handle is ready; a
+    leaf that is no device array (a host value) is."""
+    return all(leaf.is_ready() for leaf in jax.tree_util.tree_leaves(handle)
+               if hasattr(leaf, "is_ready"))
 
 
 class RoundLoop:
@@ -52,9 +78,11 @@ class RoundLoop:
 
     A round is ``begin(trainer)`` — its first chunk's period starts
     there, so the caller's rewind of the iterator lies inside it — and
-    ``run(itr, timer, ...)``.  ``update_scan`` / ``update`` / ``sync``
-    are looked up on the trainer at each call: whoever replaced one on
-    the instance is called."""
+    ``run(itr, timer, ...)``, which leaves ``boundary`` open for the
+    next ``begin`` to bill; :meth:`close` where no round follows, or
+    one raised.  ``update_scan`` / ``update`` / ``sync`` are looked up
+    on the trainer at each call: whoever replaced one on the instance
+    is called."""
 
     def __init__(self, scan_steps: int, test_io: bool = False) -> None:
         self.scan_steps = int(scan_steps)
@@ -66,16 +94,40 @@ class RoundLoop:
         self.trainer = None
         self.timer = None
         self.tracers: Sequence = ()
-        self.in_flight: List[Tuple[object, int]] = []  # (handle, n_steps)
-        self.chunk = None       # the open ``chunk`` stage
+        # (handle, n_steps, the dispatch's return, onto an empty device)
+        self.in_flight: List[Tuple[object, int, float, bool]] = []
+        # the open stages, outermost first: ``round`` or ``boundary``,
+        # and inside a round ``chunk``, and ``head`` inside its first
+        self.round = self.boundary = self.chunk = self.head = None
         self.pipe_mark = 0.0    # the last fence
+        self.ran_until: Optional[float] = None  # the last fenced chunk's
+        # end, where the loop saw it: None after one that had landed
 
-    def begin(self, trainer) -> None:
-        """Open the round and its first chunk's period."""
+    def begin(self, trainer, round_no: int = 0) -> None:
+        """Open the round, its first chunk's period and its head; the
+        boundary behind the last round ends here, billed to this one
+        (the caller has reset the round's stages by now)."""
+        if self.boundary is not None:
+            self.boundary.end()
+            self.boundary = None
         self.trainer = trainer
         self.chunks.reset()
         self.in_flight = []
-        self.chunk = stage("chunk", step=trainer.epoch_counter).begin()
+        self.ran_until = None
+        step = trainer.epoch_counter
+        self.round = stage("round", round=round_no).begin()
+        self.chunk = stage("chunk", step=step).begin()
+        self.head = stage("head", step=step).begin()
+
+    def close(self) -> None:
+        """Close the spans the loop holds open, innermost first, and
+        bill none: the round's at its last fence, a round's that
+        raised, the task's last boundary."""
+        for name in ("head", "chunk", "round", "boundary"):
+            held = getattr(self, name)
+            if held is not None:
+                held.drop()
+                setattr(self, name, None)
 
     def run(self, itr, timer, tracers: Sequence = (),
             on_batch: Optional[Callable[[int], bool]] = None,
@@ -117,7 +169,10 @@ class RoundLoop:
             stopped = on_batch is not None and bool(on_batch(n_batches))
         self._flush()  # tail chunk shorter than scan_steps
         self._fence(drain_all=True)  # round boundary: the queue is empty
-        self.chunk.drop()  # what follows the last fence is in no chunk
+        # what follows the last fence is in no chunk and not the round's
+        # span: it is the boundary, which the next begin() bills
+        self.close()
+        self.boundary = stage("boundary", step=trainer.epoch_counter).begin()
         return n_batches, stopped
 
     # ------------------------------------------------------------------
@@ -150,15 +205,40 @@ class RoundLoop:
         trainer = self.trainer
         stats = pipeline_stats()
         while len(self.in_flight) > (0 if drain_all else 1):
-            handle, n = self.in_flight.pop(0)
+            handle, n, sent, onto_empty = self.in_flight.pop(0)
+            late = _landed(handle)  # before the host asked: end unknown
             with stage("device_wait", rows=n * trainer.batch_size,
                        step=trainer.epoch_counter):
                 jax.block_until_ready(handle)
+            self._bill_run(n, sent, onto_empty, late, time.perf_counter())
             trainer.collect_scan_metrics()
             stats.count("chunks_fenced")
             if self.in_flight:
                 stats.count("chunks_overlapped")
             self._lap(n)
+
+    def _bill_run(self, n_steps: int, sent: float, onto_empty: bool,
+                  late: bool, done: float) -> None:
+        """The device's time for the chunk whose fence returned at
+        ``done``, from the loop's own stamps (the module's docstring
+        says which interval is what); a ``run`` is also one
+        observation of ``train_step_device_seconds``, per step."""
+        stats = pipeline_stats()
+        rows = n_steps * self.trainer.batch_size
+        if late:
+            stats.count("chunks_late")
+        elif onto_empty:
+            stats.add("run_exposed", done - sent, rows)
+        elif self.ran_until is not None:
+            stats.add("run", done - self.ran_until, rows)
+            obs_device.observe_step((done - self.ran_until) / n_steps)
+        self.ran_until = None if late else done
+
+    def _end_head(self) -> None:
+        """The round's first dispatch has returned."""
+        if self.head is not None:
+            self.head.end()
+            self.head = None
 
     def _mark_step(self) -> None:
         for tracer in self.tracers:
@@ -185,12 +265,22 @@ class RoundLoop:
             self._fence(drain_all=True)
             self._update(DataBatch(data=data[0], label=labels[0]), None)
         else:
+            # mid-round the chunk before this one should still be
+            # running: if it has landed, the device starved
+            starved = bool(self.in_flight) and _landed(self.in_flight[-1][0])
+            onto_empty = starved or not self.in_flight
             handle = trainer.update_scan(
                 data, labels, sync=False,
                 # sharded iterators guarantee equal K per process — skip
                 # the collective K-check so the overlap stays unbroken
                 check_steps=False)
-            self.in_flight.append((handle, n))
+            self.in_flight.append(
+                (handle, n, time.perf_counter(), onto_empty))
+            self._end_head()
+            stats = pipeline_stats()
+            stats.count("chunks_dispatched")
+            if starved:
+                stats.count("chunks_starved")
             self._fence(drain_all=False)
         self.global_step += n
 
@@ -213,6 +303,7 @@ class RoundLoop:
         ``async_round_end``)."""
         trainer = self.trainer
         trainer.update(batch)
+        self._end_head()
         if not trainer.eval_train and not trainer.fence_at_round_end:
             with stage("device_wait", rows=trainer.batch_size,
                        step=trainer.epoch_counter):

@@ -8,7 +8,10 @@ profiler traces (xplane protos viewable in TensorBoard/XProf).
 
 Config keys (all global):
 
-* ``profile = 1`` — capture a jax.profiler trace window
+* ``profile = 1`` — capture a jax.profiler trace window: the device's
+  planes and the program's own spans (``train.*``, the stages below) on
+  one clock; no Python frames, which slow the host they are meant to
+  show
 * ``profile_dir = <dir>`` — trace output dir (default ``profile_out``)
 * ``profile_start = 5`` — global step index to start the trace
 * ``profile_steps = 10`` — number of steps to trace
@@ -51,7 +54,11 @@ class PipelineStats:
     ``copy`` / ``stack`` / ``h2d`` / ``dispatch`` / ``device_wait`` /
     ``metric`` are billed on the round loop's thread through
     :func:`stage` and tile ``chunk``, the fence-to-fence period of one
-    dispatch (doc/observability.md has the table).
+    dispatch; ``head`` (a round's first feed, inside its first chunk)
+    and ``boundary`` (between rounds, in no chunk) are the loop's too,
+    and ``run`` / ``run_exposed`` are the DEVICE's time as the loop
+    reads it from its own fences, with no span on the host
+    (doc/observability.md has the table).
 
     One process-wide instance (:func:`pipeline_stats`) so the io/ chain,
     the trainer's transfer path, and the CLI's round loop all record
@@ -64,7 +71,8 @@ class PipelineStats:
     """
 
     STAGES = ("decode", "augment", "batch", "next", "copy", "stack",
-              "h2d", "dispatch", "device_wait", "metric", "chunk")
+              "h2d", "dispatch", "device_wait", "metric", "chunk",
+              "head", "run", "run_exposed", "boundary")
 
     def __init__(self, window: int = 2048) -> None:
         self._window = window
@@ -78,9 +86,11 @@ class PipelineStats:
         documents cut; ``nnet/trainer.py``: ``metric_rows``, the rows
         whose train metrics were scored, and ``metric_rows_device``,
         those scored inside a step program; ``train_loop.py``:
-        ``chunks_fenced``, the scanned chunks the loop blocked on, and
+        ``chunks_fenced``, the scanned chunks the loop blocked on,
         ``chunks_overlapped``, those whose fence found a later chunk
-        already dispatched)."""
+        already dispatched, ``chunks_dispatched``, ``chunks_starved``,
+        those dispatched mid-round onto a device that had run dry, and
+        ``chunks_late``, those whose fence found them done)."""
         with self._lock:
             self._counts[name] = self._counts.get(name, 0) + int(n)
 
@@ -215,9 +225,10 @@ class Stage:
     no-op annotation.
 
     Use :func:`stage` as a context manager.  A stage that cannot be a
-    ``with`` block (the round loop's fence-to-fence ``chunk``) calls
-    :meth:`begin` and then :meth:`end`, or :meth:`drop` to close the
-    spans without billing."""
+    ``with`` block (the round loop's fence-to-fence ``chunk``, its
+    ``head`` and ``boundary``) calls :meth:`begin` and then :meth:`end`,
+    or :meth:`drop` to close the spans without billing (``round``: a
+    span and an annotation only)."""
 
     __slots__ = ("name", "rows", "args", "_t0", "_span", "_ann")
 
@@ -350,7 +361,14 @@ class TraceController:
         import jax
 
         if not self._active and global_step >= self.start_step:
-            jax.profiler.start_trace(self.trace_dir)
+            opts = jax.profiler.ProfileOptions()
+            # the Python tracer doubled a chunk's period and host level
+            # 2 adds the runtime's own waits (PERF.md, PR 24): a session
+            # that slows the host misreports the device's idle time, and
+            # the stages name the gaps
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
+            jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
             self._active = True
             self._stop_at = global_step + self.num_steps
         elif self._active and global_step >= self._stop_at:

@@ -112,26 +112,123 @@ def test_attn_flash_pct_reads_the_two_attention_counters(rounds, want):
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
+# the round loop's bill of the device's time from its own fences (PR 38):
+# ``run`` / ``run_exposed`` / ``head`` stages and three counters
+def _billed(*rounds, batch=256):
+    """Records of 24 steps; a stage is (count, seconds, steps)."""
+    return {"batch": batch, "telemetry": [
+        {"round": i, "steps": 24,
+         "stages": {k: {"count": c, "total_s": s, "rows": n * batch}
+                    for k, (c, s, n) in st.items()},
+         "counters": dict(counters)}
+        for i, (st, counters) in enumerate(rounds)]}
+
+
+# a round of three chunks of 8 steps at 85 ms a step: a head of 80 ms,
+# the first chunk's upload exposed for 220 ms
+ROUND = ({"head": (1, 0.08, 0), "run_exposed": (1, 0.90, 8),
+          "run": (2, 1.36, 16), "chunk": (3, 2.34, 24)},
+         {"chunks_dispatched": 3, "chunks_fenced": 3})
+# one whose second chunk had landed before its fence: one run less, and
+# its third chunk's none either
+LATE = ({"head": (1, 0.08, 0), "run_exposed": (1, 0.90, 8),
+         "chunk": (3, 2.40, 24)},
+        {"chunks_dispatched": 3, "chunks_late": 1})
+# a starved round: every chunk onto an empty device, two of them late
+STARVED = ({"head": (1, 0.50, 0), "run_exposed": (1, 0.70, 8),
+            "chunk": (3, 4.00, 24)},
+           {"chunks_dispatched": 3, "chunks_starved": 2, "chunks_late": 2})
+# an upload that hid whole: the exposed run is shorter than 8 steps by
+# the clock's grain
+HIDDEN = ({"head": (1, 0.08, 0), "run_exposed": (1, 0.679, 8),
+           "run": (2, 1.36, 16), "chunk": (3, 2.119, 24)},
+          {"chunks_dispatched": 3})
+# the parent's record: the eleven stages it knew, its two counters
+PARENT = ({"chunk": (3, 2.34, 24), "device_wait": (3, 2.0, 24)},
+          {"chunks_fenced": 3, "chunks_overlapped": 2})
+
+
+@pytest.mark.parametrize("name, rounds, want", [
+    ("loop_device_step_ms", [ROUND] * 2, 85.0),
+    # sums over the rounds, not a mean of rounds: 1.36 s over 16 steps
+    ("loop_device_step_ms", [ROUND, LATE], 85.0),
+    ("loop_device_step_ms", [LATE, STARVED], None),
+    ("loop_device_step_ms", [PARENT], None),
+    ("loop_device_step_ms", [], None),
+    # 1 - 0.085 x 24 / 2.34
+    ("loop_device_idle_pct", [ROUND] * 2, 100.0 * (1 - 2.04 / 2.34)),
+    ("loop_device_idle_pct", [ROUND, LATE], 100.0 * (1 - 4.08 / 4.74)),
+    ("loop_device_idle_pct", [STARVED], None),
+    ("loop_device_idle_pct", [PARENT], None),
+    ("round_head_ms_step", [ROUND] * 2, 80.0 / 24),
+    ("round_head_ms_step", [ROUND, STARVED], 580.0 / 48),
+    ("round_head_ms_step", [PARENT], None),
+    ("round_head_ms_step", [], None),
+    # (0.90 - 8 x 0.085) s once in 24 steps
+    ("h2d_tail_ms_step", [ROUND] * 2, 220.0 / 24),
+    ("h2d_tail_ms_step", [ROUND, LATE], 440.0 / 48),
+    # the floor: never under 0
+    ("h2d_tail_ms_step", [HIDDEN], 0.0),
+    # no run to take off the exposed one
+    ("h2d_tail_ms_step", [STARVED], None),
+    ("h2d_tail_ms_step", [PARENT], None),
+    ("chunk_starved_pct", [ROUND] * 2, 0.0),
+    ("chunk_starved_pct", [ROUND, STARVED], 100.0 * 2 / 6),
+    ("chunk_starved_pct", [STARVED], 100.0 * 2 / 3),
+    # the parent counts no dispatch; the per-batch path dispatches none
+    ("chunk_starved_pct", [PARENT], None),
+    ("chunk_starved_pct", [({}, {"tokens": 196608})], None),
+    ("chunk_starved_pct", [], None),
+])
+def test_the_loops_bill_of_the_device_is_read_over_the_rounds(
+        name, rounds, want):
+    read = run.load_metric(name).read
+    assert read(_billed(*rounds)) == (
+        want if want is None else pytest.approx(want))
+    # a record with no ``stages`` and no ``counters`` at all
+    assert read({"batch": 256,
+                 "telemetry": [{"round": 1, "steps": 24}]}) is None
+
+
+def test_head_and_tail_close_on_the_loops_idle_time():
+    """What the two explain is the whole of what the loop's reading of
+    the device leaves of a chunk period, where no chunk was late or
+    starved."""
+    rec = _billed(ROUND, ROUND)
+    read = {n: run.load_metric(n).read(rec) for n in (
+        "loop_device_step_ms", "loop_device_idle_pct",
+        "round_head_ms_step", "h2d_tail_ms_step")}
+    period_ms = 1e3 * 2.34 / 24
+    assert read["round_head_ms_step"] + read["h2d_tail_ms_step"] == (
+        pytest.approx(read["loop_device_idle_pct"] / 100 * period_ms))
+    assert period_ms - read["loop_device_step_ms"] == pytest.approx(
+        read["round_head_ms_step"] + read["h2d_tail_ms_step"])
+
+
 ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
              "granite_4_0_h_micro_train_packed8k",
              "qwen3_next_80b_a3b_train_packed8k",
              "joyai_llm_flash_train_packed8k"]
 
 
-@pytest.mark.parametrize("name, cells", [
-    ("train_metric_device_pct", ALL_CELLS[:2]),
-    ("chunk_overlap_pct", ALL_CELLS),
-    ("gdn_scan_fused_pct", ALL_CELLS[3:4]),
-    ("attn_flash_pct", ALL_CELLS[2:]),
-])
-def test_benchmark_json_names_the_reader_that_exists(name, cells):
+LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
+             "round_head_ms_step", "h2d_tail_ms_step", "chunk_starved_pct"]
+
+
+@pytest.mark.parametrize("name, cells, better", [
+    ("train_metric_device_pct", ALL_CELLS[:2], "higher"),
+    ("chunk_overlap_pct", ALL_CELLS, "higher"),
+    ("gdn_scan_fused_pct", ALL_CELLS[3:4], "higher"),
+    ("attn_flash_pct", ALL_CELLS[2:], "higher"),
+] + [(name, ALL_CELLS, "lower") for name in LOOP_BILL])
+def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = [m for m in bench["per_layer"] if m["name"] == name]
     assert len(entries) == 1
     entry = entries[0]
     mod = run.load_metric(name)
-    assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES, "higher") == (
+    assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES, better) == (
         entry["layer"], entry["unit"], entry["source"], entry["moves"],
         entry["better"])
     assert entry["workloads"] == cells
@@ -144,5 +241,6 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells):
     assert names.index("gdn_scan_fused_pct") == 41
     assert names[42:46] == ["mla_ms_step", "mla_core_ms_step",
                             "mla_core_roofline_pct", "mtp_ms_step"]
-    # and PR 37's one behind them, the last
-    assert names[46:] == ["attn_flash_pct"]
+    # PR 37's one behind them, and PR 38's five behind that, the last
+    assert names[46:47] == ["attn_flash_pct"]
+    assert names[47:52] == LOOP_BILL and len(names) == 52

@@ -4,8 +4,8 @@ The trainer's jitted programs, the serve bucket cache's compiled
 predicts, and the loop fine-tuner all flow through the same
 instrumentation, so these tests assert the acceptance surface on the
 CPU backend: per-program compile-time gauges labeled {kind,bucket},
-the programs count, cumulative compile seconds from the jax.monitoring listener, sampled
-step fences, disabled-path passthrough, and the telemetry summary.
+the programs count, cumulative compile seconds from the jax.monitoring listener, the
+step histogram fed by the round loop's fences, disabled-path passthrough, and the telemetry summary.
 """
 
 import numpy as np
@@ -37,13 +37,11 @@ eta = 0.1
 
 @pytest.fixture(autouse=True)
 def _default_device_state():
-    """Every test starts from the defaults (telemetry on, sampling off)
-    and leaks neither a sample_every nor a disabled flag."""
-    obs_device.configure([("device_telemetry", "1"),
-                          ("device_sample_every", "0")])
+    """Every test starts from the default (telemetry on) and leaks no
+    disabled flag."""
+    obs_device.configure([("device_telemetry", "1")])
     yield
-    obs_device.configure([("device_telemetry", "1"),
-                          ("device_sample_every", "0")])
+    obs_device.configure([("device_telemetry", "1")])
 
 
 def make_trainer(seed=0):
@@ -113,19 +111,61 @@ def test_eval_program_and_serve_buckets_labeled_by_batch_dim():
     assert _sample("xla_programs_total", kind="eval") == evals + 2
 
 
-def test_sampled_step_fences_feed_histogram():
-    hist_before = _family("train_step_device_seconds").get(
-        "train_step_device_seconds_count", 0.0)
-    obs_device.configure([("device_sample_every", "2")])
-    tr = make_trainer(seed=2)
-    x = np.random.RandomState(3).rand(32, 1, 1, 16).astype(np.float32)
-    y = np.zeros((32, 1), np.float32)
-    for _ in range(4):
-        tr.update_all(x, y)
-    count = _family("train_step_device_seconds").get(
-        "train_step_device_seconds_count", 0.0)
-    assert count == hist_before + 2  # every 2nd of 4 updates fenced
-    assert obs_device.summary()["sampled_steps"] >= 2
+def test_the_round_loops_fences_feed_the_step_histogram():
+    """``train_step_device_seconds`` is filled by a plain scanned round,
+    with no key set and no fence of its own: one observation for every
+    ``run`` the round loop bills (a chunk that ran back to back with the
+    one before, fenced at both ends), the run / the chunk's steps."""
+    from cxxnet_tpu.io.data import DataBatch
+    from cxxnet_tpu.train_loop import RoundLoop
+    from cxxnet_tpu.utils.profiler import StepTimer, pipeline_stats
+
+    def hist(field):
+        return _family("train_step_device_seconds").get(
+            "train_step_device_seconds_" + field, 0.0)
+
+    class Batches:
+        """24 batches of one buffer, like a real iterator's."""
+
+        def __init__(self, n):
+            self.left = n
+            self.cur = DataBatch(
+                data=np.random.RandomState(3).rand(
+                    256, 1024).astype(np.float32),
+                label=np.zeros((256, 1), np.float32))
+
+        def next(self):
+            self.left -= 1
+            return self.left >= 0
+
+        def value(self):
+            return self.cur
+
+    tr = NetTrainer()
+    tr.set_params(cfgmod.parse_pairs(
+        MLP_CFG.replace("nhidden = 16", "nhidden = 1024")
+        .replace("1,1,16", "1,1,1024").replace("= 32", "= 256")))
+    tr.init_model()
+    loop = RoundLoop(8)
+    count, total = hist("count"), hist("sum")
+    runs = seconds = 0
+    for _ in range(2):  # the first round compiles inside its head
+        pipeline_stats().reset()
+        loop.begin(tr)
+        loop.run(Batches(24), StepTimer())
+        st = pipeline_stats().snapshot()["run"]
+        runs += int(st["count"])
+        seconds += st["total_s"]
+    loop.close()
+    # a round's last chunk is fenced the moment the one before lands
+    assert runs >= 1
+    assert hist("count") == count + runs
+    assert hist("sum") - total == pytest.approx(seconds / 8)
+    obs_device.configure([("device_telemetry", "0")])
+    loop.begin(tr)
+    loop.run(Batches(24), StepTimer())
+    loop.close()
+    assert hist("count") == count + runs  # the plane's switch holds
 
 
 def test_disabled_telemetry_is_passthrough():
@@ -213,6 +253,6 @@ def test_summary_totals_monotonic_and_jsonable():
     s = obs_device.summary()
     json.dumps(s)
     for key in ("programs", "compiles", "compile_seconds",
-                "cold_call_seconds", "sampled_steps", "trace_seconds",
+                "cold_call_seconds", "trace_seconds",
                 "lower_seconds", "cache_retrieval_seconds"):
         assert key in s and s[key] >= 0

@@ -360,3 +360,192 @@ def test_a_method_replaced_on_the_instance_is_the_one_called():
     tr.update_scan = update_scan
     loop.run(ListIter([(i, 0) for i in range(4)]), Timer(log))
     assert seen == [2, 2]
+
+
+# ----------------------------------------------------------------------
+# The device's time from the loop's own fences: a simulated clock that
+# only the fakes move, so every stamp the loop takes is known exactly.
+class Clock:
+    """``perf_counter`` in train_loop's and the stage helper's place."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        return self.now
+
+
+class Handle:
+    """What ``update_scan`` hands back: ready once the clock passes the
+    chunk's end; blocking on it moves the clock there."""
+
+    def __init__(self, clock, end):
+        self.clock, self.end = clock, end
+
+    def is_ready(self):
+        return self.clock.now >= self.end
+
+    def block_until_ready(self):
+        self.clock.now = max(self.clock.now, self.end)
+        return self
+
+
+class SimTrainer(FakeTrainer):
+    """A device that runs one chunk at a time: a dispatch costs the
+    host ``DISPATCH``, a chunk sent onto an idle device waits ``UPLOAD``
+    for its rows, then runs for the next of ``runs`` seconds."""
+
+    DISPATCH, UPLOAD = 2.0, 30.0
+
+    def __init__(self, log, clock, runs, **kw):
+        super().__init__(log, **kw)
+        self.clock, self.runs = clock, list(runs)
+        self.free_at = 0.0
+        self.sent, self.ends = [], []  # D_k and the chunks' true ends
+
+    def update_scan(self, data, labels, sync=True, check_steps=True):
+        super().update_scan(data, labels, sync, check_steps)
+        self.clock.now += self.DISPATCH
+        sent = self.clock.now
+        start = self.free_at if self.free_at > sent else sent + self.UPLOAD
+        self.free_at = start + self.runs.pop(0)
+        self.sent.append(sent)
+        self.ends.append(self.free_at)
+        return Handle(self.clock, self.free_at)
+
+    def update(self, batch):
+        super().update(batch)
+        self.clock.now += self.DISPATCH
+
+
+class SlowIter(ListIter):
+    """Every ``next`` costs the host ``feed`` seconds."""
+
+    def __init__(self, batches, clock, feed):
+        super().__init__(batches)
+        self.clock, self.feed = clock, feed
+
+    def next(self):
+        self.clock.now += self.feed
+        return super().next()
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    from cxxnet_tpu import train_loop
+    from cxxnet_tpu.utils import profiler
+
+    c = Clock()
+    monkeypatch.setattr(train_loop, "time", c)
+    monkeypatch.setattr(profiler, "time", c)
+    return c
+
+
+def sim_round(clock, n_batches, runs, feed=1.0, loop=None, **trainer_kw):
+    log = []
+    tr = SimTrainer(log, clock, runs, **trainer_kw)
+    loop = loop or RoundLoop(4)
+    pipeline_stats().reset()
+    began = clock.now
+    loop.begin(tr)
+    loop.run(SlowIter([(i, 0) for i in range(n_batches)], clock, feed),
+             Timer(log))
+    st = pipeline_stats().snapshot()
+    bills = {k: (int(st[k]["count"]), st[k]["total_s"], int(st[k]["rows"]))
+             for k in ("head", "run", "run_exposed", "boundary")}
+    return loop, tr, began, bills, pipeline_stats().counters()
+
+
+def test_a_round_of_three_chunks_bills_a_head_an_exposed_run_and_two_runs(
+        clock):
+    clock.now = 1000.0
+    _, tr, began, bills, counters = sim_round(clock, 12, [100.0] * 3)
+    # the head: begin() to the first dispatch's return, the chip empty
+    assert bills["head"] == (1, tr.sent[0] - began, 0)
+    assert tr.sent[0] - began == 4 * 1.0 + SimTrainer.DISPATCH
+    # chunk 1 went onto an empty device: upload's tail + run, from its
+    # dispatch's return to its fence
+    assert bills["run_exposed"] == (1, tr.ends[0] - tr.sent[0], 4 * B)
+    assert tr.ends[0] - tr.sent[0] == SimTrainer.UPLOAD + 100.0
+    # chunks 2 and 3 ran back to back with the one before: fence to fence
+    assert bills["run"] == (
+        2, (tr.ends[1] - tr.ends[0]) + (tr.ends[2] - tr.ends[1]), 8 * B)
+    assert bills["run"][1] == 200.0
+    assert bills["boundary"] == (0, 0.0, 0)  # a first round has none
+    assert counters["chunks_dispatched"] == 3
+    assert "chunks_starved" not in counters and "chunks_late" not in counters
+    # head + exposed run + runs tile the round's chunk periods
+    chunk_s = pipeline_stats().snapshot()["chunk"]["total_s"]
+    assert chunk_s == pytest.approx(
+        bills["head"][1] + bills["run_exposed"][1] + bills["run"][1])
+
+
+def test_a_starved_chunk_counts_and_bills_an_exposed_run(clock):
+    # the feed of a chunk (4 x 40) outlasts a chunk's run (100): every
+    # chunk after the first is dispatched onto a device that ran dry,
+    # and every chunk but the last has landed before its fence
+    _, tr, _, bills, counters = sim_round(clock, 12, [100.0] * 3, feed=40.0)
+    assert counters["chunks_dispatched"] == 3
+    assert counters["chunks_starved"] == 2
+    assert counters["chunks_late"] == 2
+    assert bills["run"] == (0, 0.0, 0)
+    # the last chunk, starved too, was still running at its fence
+    assert bills["run_exposed"] == (1, tr.ends[2] - tr.sent[2], 4 * B)
+    assert tr.ends[2] - tr.sent[2] == SimTrainer.UPLOAD + 100.0
+
+
+def test_a_late_chunk_bills_no_run_and_poisons_its_successors(clock):
+    # chunk 2 runs 5 s: still running when chunk 3 is dispatched behind
+    # it, landed when the loop asks — its end is unknown, so neither it
+    # nor chunk 3 (whose start is that end) has a run; chunk 4 has
+    _, tr, _, bills, counters = sim_round(
+        clock, 16, [100.0, 5.0, 100.0, 100.0])
+    assert counters["chunks_dispatched"] == 4
+    assert counters["chunks_late"] == 1
+    assert "chunks_starved" not in counters
+    assert bills["run_exposed"] == (1, tr.ends[0] - tr.sent[0], 4 * B)
+    assert bills["run"] == (1, tr.ends[3] - tr.ends[2], 4 * B)
+    assert bills["run"][1] == 100.0
+
+
+def test_the_boundary_lands_in_the_next_rounds_record(clock):
+    loop, _, _, bills, _ = sim_round(clock, 8, [100.0] * 2)
+    assert bills["boundary"] == (0, 0.0, 0) and loop.boundary is not None
+    ended = clock.now  # the round's last fence
+    clock.now += 50.0  # metric line, evaluation, checkpoint, ...
+    _, _, began, bills, _ = sim_round(clock, 8, [100.0] * 2, loop=loop)
+    assert bills["boundary"] == (1, began - ended, 0)
+    assert began - ended == 50.0
+    assert bills["head"][0] == 1 and bills["run"][0] == 1
+    # where no round follows, the open boundary is closed and not billed
+    loop.close()
+    assert loop.boundary is None
+    assert pipeline_stats().snapshot()["boundary"]["count"] == 1
+
+
+def test_the_per_batch_path_bills_a_head_and_no_run(clock):
+    loop, tr, began, bills, counters = sim_round(
+        clock, 3, [], eval_train=0,
+        refusal="update_scan requires update_period == 1")
+    # the head ends where the first update() returns
+    assert bills["head"] == (1, 1.0 + SimTrainer.DISPATCH, 0)
+    assert bills["run"] == bills["run_exposed"] == (0, 0.0, 0)
+    assert "chunks_dispatched" not in counters
+    assert loop.head is None and loop.chunk is None and loop.round is None
+
+
+def test_a_round_that_dispatched_nothing_drops_its_head(clock):
+    loop, _, _, bills, _ = sim_round(clock, 0, [])
+    assert bills["head"] == (0, 0.0, 0) and loop.head is None
+
+
+def test_every_run_is_one_observation_of_the_device_step(clock, monkeypatch):
+    """``train_step_device_seconds``: the run / the chunk's steps, with
+    no key set and no fence of the loop's own (test_device_obs.py holds
+    the histogram's count against the runs billed)."""
+    from cxxnet_tpu.obs import device as obs_device
+
+    seen = []
+    monkeypatch.setattr(obs_device, "observe_step", seen.append)
+    sim_round(clock, 11, [100.0, 80.0, 60.0])
+    assert seen == [80.0 / 4, 60.0 / 3]  # the tail chunk has three steps

@@ -118,9 +118,72 @@ def test_stage_is_a_host_plane_event_in_a_profiler_session(tmp_path):
     assert found[0][0].startswith("/host:") and found[0][1] == {"step": 24}
 
 
+def test_the_loops_spans_tile_its_thread_in_a_profiler_session(tmp_path):
+    """``train.round`` and ``train.boundary`` never overlap, the chunks
+    lie inside the round and ``train.head`` inside its first chunk, on
+    the profiler's clock: an idle gap of the device can be laid over
+    them."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from cxxnet_tpu.train_loop import RoundLoop
+    from test_round_loop import FakeTrainer, ListIter, Timer
+
+    log = []
+    loop = RoundLoop(4)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for rnd in range(2):
+            loop.begin(FakeTrainer(log), rnd)
+            loop.run(ListIter([(i, 0) for i in range(8)]), Timer(log))
+        loop.close()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("train."):
+                    spans.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+    for rows in spans.values():
+        rows.sort()
+    rounds, bounds = spans["train.round"], spans["train.boundary"]
+    assert [r[2] for r in rounds] == [{"round": 0}, {"round": 1}]
+    assert len(bounds) == 2  # the second is the one close() dropped
+    # round, boundary, round, boundary: each starts where the last ended
+    tiles = sorted(rounds + bounds)
+    assert [t[2].keys() for t in tiles] == [
+        {"round"}, {"step"}, {"round"}, {"step"}]
+    for a, b in zip(tiles, tiles[1:]):
+        assert a[1] <= b[0] and b[0] - a[1] < 5e6  # < 5 ms of no span
+    chunks, heads = spans["train.chunk"], spans["train.head"]
+    # a round's last fence opens a chunk that the round's end drops
+    assert len(heads) == 2 and len(chunks) == 6
+    for rnd, head in zip(rounds, heads):
+        first = min(c for c in chunks if c[0] >= rnd[0])
+        assert rnd[0] <= first[0] <= head[0] and head[1] <= first[1]
+        assert first[1] <= rnd[1]
+        # the head holds the first chunk's feed and dispatch, and ends
+        # before that chunk's fence
+        inside = [n for n in ("train.next", "train.copy", "train.stack")
+                  if any(head[0] <= x[0] and x[1] <= head[1]
+                         for x in spans[n])]
+        assert inside == ["train.next", "train.copy", "train.stack"]
+        assert all(w[0] >= head[1] for w in spans["train.device_wait"]
+                   if w[0] >= rnd[0] and w[1] <= rnd[1])
+
+
 def test_snapshot_and_validator_know_the_same_stages():
     assert PipelineStats.STAGES == obs_dump.TELEMETRY_STAGES
     assert set(CHILDREN) | {"chunk"} <= set(PipelineStats.STAGES)
+    assert PipelineStats.STAGES[-4:] == ("head", "run", "run_exposed",
+                                         "boundary")
     snap = PipelineStats().snapshot()
     assert all(snap[s]["count"] == 0 for s in PipelineStats.STAGES)
 
@@ -195,6 +258,29 @@ def test_scanned_round_bills_all_eight_stages(telemetry):
         assert st["chunk"]["count"] == 3 and rec["steps"] == 24
         assert st["chunk"]["rows"] == st["metric"]["rows"] == 24 * 32
         assert st["copy"]["count"] == 24 and st["stack"]["count"] == 3
+
+
+def test_scanned_round_bills_the_device_from_its_fences(telemetry):
+    """One head a round, a boundary in every record but the first, and
+    every chunk either an exposed run, a run, late, or behind a late
+    one: a plain scanned round on the CPU, no key set."""
+    _, recs = telemetry
+    for i, rec in enumerate(recs):
+        st, counters = rec["stages"], rec["counters"]
+        assert st["head"]["count"] == 1
+        assert st["boundary"]["count"] == (1 if i else 0)
+        assert counters["chunks_dispatched"] == 3
+        assert counters.get("chunks_starved", 0) <= 2
+        billed = st["run"]["count"] + st["run_exposed"]["count"]
+        late = counters.get("chunks_late", 0)
+        assert billed + late <= 3 <= billed + 2 * late
+        assert st["head"]["total_s"] + st["run_exposed"]["total_s"] + st[
+            "run"]["total_s"] <= st["chunk"]["total_s"]
+        assert st["run"]["rows"] == st["run"]["count"] * 8 * 32
+    # the last chunk of a round is fenced as soon as the one before has
+    # landed: it is still running then, whatever the machine's load
+    assert sum(r["stages"]["run"]["count"]
+               + r["stages"]["run_exposed"]["count"] for r in recs) >= 1
 
 
 def test_the_seven_stages_tile_the_chunk(telemetry):

@@ -66,7 +66,8 @@ _METRIC_KINDS = ("counter", "gauge", "histogram", "summary", "untyped")
 TELEMETRY_REQUIRED = ("ts", "round", "steps", "eval", "stages")
 #: canonical pipeline stages every record's ``stages`` must include
 TELEMETRY_STAGES = ("decode", "augment", "batch", "next", "copy", "stack",
-                    "h2d", "dispatch", "device_wait", "metric", "chunk")
+                    "h2d", "dispatch", "device_wait", "metric", "chunk",
+                    "head", "run", "run_exposed", "boundary")
 
 
 def _parse_labels(text: str) -> Optional[Dict[str, str]]:

@@ -4,10 +4,12 @@ The driver behind the ``OBS=1`` lane of ``tools/run_tier1.sh``
 (doc/observability.md).  One process:
 
 1. generates a tiny synthetic MNIST-style dataset and trains it for a
-   couple of rounds with ``telemetry=1``, ``event_log``, ``trace_dir``,
-   ``device_sample_every`` and a deliberately-tripped ``alert=`` rule
-   armed — producing ``telemetry.jsonl`` (with per-round ``device``
-   totals), ``events.jsonl`` and a Chrome host trace;
+   couple of scanned rounds (``scan_steps = 2``: the round loop's own
+   fences bill ``head`` / ``run`` / ``run_exposed`` / ``boundary`` and
+   feed ``train_step_device_seconds``) with ``telemetry=1``,
+   ``event_log``, ``trace_dir`` and a deliberately-tripped ``alert=``
+   rule armed — producing ``telemetry.jsonl`` (with per-round
+   ``device`` totals), ``events.jsonl`` and a Chrome host trace;
 2. serves the checkpoint it just wrote (``serve/`` engine + HTTP
    front-end), drives a few ``/predict`` requests through the
    micro-batcher, walks the latency alert through fire (degraded
@@ -71,7 +73,7 @@ telemetry_path = {out}/telemetry.jsonl
 event_log = {out}/events.jsonl
 trace_dir = {out}/traces
 trace_steps = 3
-device_sample_every = 2
+scan_steps = 2
 alert = smoke_latency:serve_request_latency_seconds_mean:>:0:0
 silent = 1
 """
