@@ -348,7 +348,7 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
     import numpy as np
     from jax import lax
 
-    from cxxnet_tpu.layers import create_layer
+    from cxxnet_tpu.layers import create_layer, moe
     from cxxnet_tpu.layers.conv import _maxpool_eq
     from cxxnet_tpu.ops import quant as opsq
     from cxxnet_tpu.ops.attention import mha
@@ -491,7 +491,46 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
     mla_run = with_grads(
         lambda x, p: (mla.apply(p, [x, mla_ids])[0],), 2)
 
+    # -- a share of routed experts at Qwen3-Next's widths (32 of 512
+    # held, top-10), a row of 2048 tokens, against the dense masked loop:
+    # once as the router sends them (~1/16 of the picks held, one slab)
+    # and once with the held experts chosen first by a selection bias
+    # (every pick held: eight slabs through the loop of layers/moe.py)
+    te, de, fe, ne, ke, ge = (256, 64, 32, 32, 4, 4) if toy else (
+        2048, 2048, 512, 512, 10, 32)
+    experts = create_layer("routed_experts")
+    for key, val in dict(nexpert=ne, topk=ke, nhidden=fe, nheld=ge,
+                         select_bias=1).items():
+        experts.set_param(key, str(val))
+    experts.infer_shape([(te, de)])
+    exp_shapes = jax.eval_shape(
+        lambda: experts.init_params(jax.random.PRNGKey(0), [(te, de)]))
+    exp_p = {t: arr(*v.shape, scale=0.02)
+             for t, v in sorted(exp_shapes.items()) if t != "score_bias"}
+    biases = (jnp.zeros((ne,), jnp.float32),
+              jnp.where(jnp.arange(ne) < ge, 2.0, 0.0))
+    assert moe.slab_rows(te * ke, ge, ne) < te * ke      # there is a loop
+
+    def experts_dense(x, p, bias):
+        w, idx = moe.route(
+            jnp.dot(x.astype(jnp.float32), p["wgate"].astype(jnp.float32).T,
+                    precision=lax.Precision.HIGHEST), ke, True, bias=bias)
+        w = lax.stop_gradient(w)
+        y = jnp.zeros(x.shape, jnp.float32)
+        for j in range(ge):
+            gu = x @ p["wmat"][j]
+            y = y + jnp.where(idx == j, w, 0.0).sum(-1)[:, None] * (
+                (jax.nn.silu(gu[:, :fe]) * gu[:, fe:]) @ p["wproj"][j])
+        return y
+
+    experts_run, experts_ref = (with_grads(lambda x, p, fn=fn: tuple(
+        fn(x, p, b) for b in biases), 2) for fn in (
+        lambda x, p, b: experts.apply(dict(p, score_bias=b), [x])[0],
+        experts_dense))
+
     return [
+        ("routed_experts share fwd+bwd", "ok", experts_run, experts_ref,
+         (arr(te, de), exp_p), BF16),
         ("latent_attention fwd+bwd", "ok", mla_run, mla_run,
          (arr(1, tm, dm), mla_p), 4 * BF16),
         ("gated_delta_fused fwd+bwd", "ok",

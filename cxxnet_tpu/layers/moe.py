@@ -44,14 +44,25 @@ shared expert ungated.
 
 **No pair is dropped and no expert has a capacity.**  The (token,
 expert) pairs are sorted by expert, pairs of experts held elsewhere
-last; the tokens' rows are gathered in that order, the two grouped
-products (``jax.lax.ragged_dot``, which the TPU compiler turns into a
-kernel that visits the tiles of rows the groups really hold) run over
-the held groups, and the rows go back to their tokens by the inverse
-permutation.  Every buffer has room for all ``tokens * topk`` pairs, so
-whatever the router does, every pair routed to a held expert is
-computed.  Both reorderings are permutations and are differentiated as
-such (a gather each way, never a scatter).
+last.  Every array between that sort and a token's sum has the ``C``
+rows of a SLAB, ``C`` fixed by the layer's shapes (``slab_rows``):
+``SLAB_FACTOR`` times the pairs an even router sends the held experts,
+in whole row tiles of the grouped product, and never more than ``tokens
+* topk``.  The first ``C`` sorted pairs' rows are gathered, the two
+grouped products (``jax.lax.ragged_dot``, which the TPU compiler turns
+into a kernel that visits the tiles of rows the groups really hold) run
+over the held groups' rows in the slab, and the rows, times their
+weights, are summed onto their tokens in float32.  Whatever the router
+sends beyond ``C`` goes through the same code on further slabs, in ONE
+loop whose trip count is read from the run's own counts (0 in a step
+whose held pairs fit the first slab), so whatever the router does,
+every pair routed to a held expert is computed.  A loop with a run-time
+trip count has no reverse mode: ``_slabs`` is one ``custom_vjp`` whose
+residuals are its inputs (nothing with ``tokens * topk`` rows but the
+int32 order) and whose backward walks the same slabs, gathering a
+slab's rows and putting them through the first product once more.
+With ``nheld = nexpert`` (or ``SLAB_FACTOR`` x the share >= 1) ``C`` is
+``tokens * topk``: one slab and no loop.
 
 ``routed_experts`` config keys:
 
@@ -76,7 +87,9 @@ transposed for every product, and the compiler then carried them
 through the scanned step in the transposed layout, with a copy of every
 expert's weight and of both its adam moments at the loop's edges
 (4.5 GB at 4 x 32 experts; read from compiles for a described v5e, PR
-33); and with a shared expert
+33; with the loop over further slabs in the step the compiler turned
+them again, so the layer now states the layout, ``_as_kept``); and with
+a shared expert
 ``shared_wmat`` (2 shared_hidden, D), ``shared_wproj`` (D,
 shared_hidden), ``shared_gate`` (1, D) unless ``shared_gate = 0``;
 ``score_bias`` (nexpert,) with ``select_bias``, started at 0; ``norm``
@@ -88,12 +101,15 @@ State (``aux``, carried through the step programs like batch-norm's
 running statistics and read once a round by
 ``NetTrainer.count_layer_state``): ``pairs`` — pairs routed to held
 experts; ``pairs_max`` — each step's fullest held expert's pairs,
-summed; ``pairs_dropped`` — 0, by the construction above.  uint32,
-wrapping: the reader takes differences.
+summed; ``pairs_dropped`` — 0, by the construction above;
+``pairs_overflow`` — pairs beyond the first slab, computed by the loop.
+uint32, wrapping: the reader takes differences.
 
 Scopes inside the layer's: ``route`` (router, softmax, top-k),
-``dispatch`` (sort and gather), ``experts`` (the grouped products),
-``combine`` (the way back and the weights), ``shared``.
+``dispatch`` (the sort, a slab's plan and gather; backward: ``dx``),
+``experts`` (the grouped products), ``combine`` (the weights and the sum
+onto the tokens), ``shared``.  The slabs after the first name the same
+three under the loop's ``while/body``.
 
 The older ``moe`` type (``sequence.MoELayer``) stays beside this one:
 it is a different function (one linear projection an expert to another
@@ -105,55 +121,45 @@ what a published mixture-of-experts block is.
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .base import Layer, Params, Shape, register
 from .sequence import Branch
 
-COUNTERS = ("pairs", "pairs_max", "pairs_dropped")
+COUNTERS = ("pairs", "pairs_max", "pairs_dropped", "pairs_overflow")
+
+#: a slab has room for this many times the pairs an even router sends
+#: the held experts.  Chosen on the chip (tools/moe_ab.py; PERF.md section
+#: 6, PR 39): the layer forward + backward at qwen3_next's shapes reads
+#: 5.66 / 6.19 / 6.96 ms at 1.5 / 2 / 3 with one slab and 10.95 with two;
+#: the cells' loads read 1.5-1.9 times their mean and drifted +37%
+SLAB_FACTOR = 2
+#: a slab is whole row tiles of the grouped product's kernel
+ROW_TILE = 512
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inv, k):
-    """Row ``r`` of the result is token ``order[r] // k``'s: the pairs
-    in sorted order, a token's row once for each of its ``k`` picks.
-    ``inv`` is the inverse of the permutation ``order``."""
-    del inv
-    return x[order // k]
+def slab_rows(pairs: int, nheld: int, nexpert: int) -> int:
+    """Rows of a slab, from the layer's shapes alone: ``SLAB_FACTOR``
+    times the share of the ``pairs`` (tokens x topk) that ``nheld`` of
+    ``nexpert`` experts get from an even router, in whole row tiles, and
+    never more than all pairs — a whole layer's one slab."""
+    tiles = math.ceil(SLAB_FACTOR * pairs * nheld / (nexpert * ROW_TILE))
+    return min(pairs, tiles * ROW_TILE)
 
 
-def _dispatch_fwd(x, order, inv, k):
-    return x[order // k], (inv, x.shape[0])
-
-
-def _dispatch_bwd(k, res, g):
-    inv, m = res
-    return g[inv].reshape(m, k, g.shape[-1]).sum(axis=1), None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _unsort(y, order, inv):
-    """``y`` back in the pairs' own order: row ``p`` is ``y[inv[p]]``."""
-    del order
-    return y[inv]
-
-
-def _unsort_fwd(y, order, inv):
-    return y[inv], order
-
-
-def _unsort_bwd(order, g):
-    return g[order], None, None
-
-
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+def _onto_tokens(rows, tok, m: int):
+    """``rows (C, D)`` float32, row ``r`` token ``tok[r]``'s: the sum of
+    each token's rows, ``(m, D)`` float32.  A scatter-add: on a v5e 1.8
+    ms for 10 240 rows of 2048, where a gather of all ``tokens x topk``
+    pairs through the inverse order took 6.3 and sorting the rows
+    token-major first changed nothing (tools/moe_ab.py; PERF.md, PR 39)."""
+    return jax.ops.segment_sum(rows, tok, num_segments=m)
 
 
 def route(logits, topk: int, norm_topk: bool = True, *,
@@ -184,41 +190,145 @@ def route(logits, topk: int, norm_topk: bool = True, *,
     return w, idx.astype(jnp.int32)
 
 
-def held_experts(x, w, idx, wmat, wproj, first: int):
+def _experts(xs, wmat, wproj, sizes, valid):
+    """A slab's rows ``xs (c, D)`` through their experts, ``(c, D)`` in
+    the activations' dtype and zero past the held pairs (whatever the
+    grouped product left there is put out).  The kernels accumulate in
+    float32.  It names no scope: the backward calls it under
+    ``jax.vjp``, which would wrap one (``jvp(experts)``) where the
+    trace's readers look for the plain name."""
+    f = wmat.shape[-1] // 2
+    gu = lax.ragged_dot(xs, wmat, sizes, preferred_element_type=xs.dtype)
+    h = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+         * gu[:, f:].astype(jnp.float32)).astype(xs.dtype)
+    ys = lax.ragged_dot(jnp.where(valid, h, 0), wproj, sizes,
+                        preferred_element_type=xs.dtype)
+    return jnp.where(valid, ys, 0)
+
+
+def _slab_inputs(x, w, order, counts, s, c: int):
+    """Slab ``s`` of the sorted pairs, rows ``s c .. s c + c - 1``: its
+    pairs ``(c,)``, the rows each held expert has in it ``(G,)``, which
+    of its rows are a held pair's ``(c, 1)``, their tokens' rows of ``x``
+    ``(c, D)`` and their weights ``(c,)``."""
+    with jax.named_scope("dispatch"):
+        lo = s * c
+        pair = lax.dynamic_slice(order, (lo,), (c,))
+        ends = jnp.cumsum(counts)
+        sizes = jnp.maximum(
+            jnp.minimum(ends, lo + c) - jnp.maximum(ends - counts, lo), 0)
+        valid = (lo + lax.iota(jnp.int32, c) < ends[-1])[:, None]
+        xs = jnp.where(valid, x[pair // w.shape[1]], 0)
+        return pair, sizes, valid, xs, w.reshape(-1)[pair]
+
+
+def _slab(x, w, wmat, wproj, order, counts, s, c: int):
+    """Slab ``s``'s terms of every token's sum: ``(M, D)`` float32."""
+    pair, sizes, valid, xs, wrow = _slab_inputs(x, w, order, counts, s, c)
+    with jax.named_scope("experts"):
+        ys = _experts(xs, wmat, wproj, sizes, valid)
+    with jax.named_scope("combine"):
+        return _onto_tokens(ys.astype(jnp.float32) * wrow[:, None],
+                            pair // w.shape[1], w.shape[0])
+
+
+def _slab_grads(x, w, wmat, wproj, order, counts, s, c: int, g):
+    """Slab ``s``'s terms of the cotangents of ``x`` (float32), ``w``
+    (flat), ``wmat`` and ``wproj`` for ``g (M, D)``, the output's: the
+    slab's rows are gathered and put through their experts once more."""
+    m, k = w.shape
+    pair, sizes, valid, xs, wrow = _slab_inputs(x, w, order, counts, s, c)
+    with jax.named_scope("experts"):
+        ys, vjp = jax.vjp(
+            lambda *a: _experts(*a, sizes, valid), xs, wmat, wproj)
+    with jax.named_scope("combine"):
+        grow = g[pair // k].astype(jnp.float32)
+        dys = (grow * wrow[:, None]).astype(ys.dtype)
+        dw = jnp.zeros((m * k,), jnp.float32).at[pair].add(
+            (grow * ys.astype(jnp.float32)).sum(axis=-1))
+    with jax.named_scope("experts"):
+        dxs, dwmat, dwproj = vjp(dys)
+    with jax.named_scope("dispatch"):
+        dx = _onto_tokens(jnp.where(valid, dxs, 0).astype(jnp.float32),
+                          pair // k, m)
+    return dx, dw, dwmat, dwproj
+
+
+def _slabs_held(counts, c: int):
+    """Slabs that hold a pair, read from the run's own routing."""
+    return (counts.sum() + (c - 1)) // c
+
+
+def _slabs_impl(x, w, wmat, wproj, order, counts, c):
+    y = _slab(x, w, wmat, wproj, order, counts, 0, c)
+    if c < w.size:
+        y = lax.fori_loop(
+            1, _slabs_held(counts, c),
+            lambda s, y: y + _slab(x, w, wmat, wproj, order, counts, s, c),
+            y)
+    return y.astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _slabs(x, w, wmat, wproj, order, counts, c: int):
+    """The sum over the slabs of ``c`` sorted pairs that hold one: the
+    first always, the others in a loop whose trip count the routing
+    gives.  A loop has no reverse mode, so the backward is written
+    out: it keeps the inputs and walks the same slabs."""
+    return _slabs_impl(x, w, wmat, wproj, order, counts, c)
+
+
+def _slabs_fwd(x, w, wmat, wproj, order, counts, c):
+    return (_slabs_impl(x, w, wmat, wproj, order, counts, c),
+            (x, w, wmat, wproj, order, counts))
+
+
+def _slabs_bwd(c, res, g):
+    x, w = res[:2]
+    grads = _slab_grads(*res, 0, c, g)
+    if c < w.size:
+        grads = lax.fori_loop(
+            1, _slabs_held(res[5], c),
+            lambda s, acc: jax.tree_util.tree_map(
+                jnp.add, acc, _slab_grads(*res, s, c, g)),
+            grads)
+    dx, dw, dwmat, dwproj = grads
+    return (dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype), dwmat,
+            dwproj, None, None)
+
+
+_slabs.defvjp(_slabs_fwd, _slabs_bwd)
+
+
+def _as_kept(w):
+    """The held experts' matrix ``w (G, in, out)`` in the layout it is
+    kept in, row-major.  A row's way back through a matrix wants it
+    turned, and with those products inside a loop the TPU compiler chose
+    to KEEP every expert's float32 weight and both its adam moments
+    turned through the scanned step, with a copy of each at the scan's
+    edges (+5.0 GB of temporaries in the qwen3_next step, +3.3 in
+    JoyAI's, which then does not fit a chip; read from compiles for a
+    described v5e, PR 39).  This says where the layout's choice ends."""
+    return with_layout_constraint(w, Layout(major_to_minor=(0, 1, 2)))
+
+
+def held_experts(x, w, idx, wmat, wproj, first: int, nexpert: int):
     """The held experts' part of the layer: ``x (M, D)``, the router's
-    ``w`` / ``idx (M, k)``, ``wmat (G, D, 2F)`` and ``wproj (G, F, D)``
-    of the ``G`` experts ``first .. first + G - 1`` -> (``y (M, D)``,
-    pairs a held expert ``(G,)`` int32)."""
+    ``w`` / ``idx (M, k)`` over ``nexpert`` experts, ``wmat (G, D, 2F)``
+    and ``wproj (G, F, D)`` of the ``G`` experts ``first .. first + G -
+    1`` -> (``y (M, D)``, pairs a held expert ``(G,)`` int32)."""
     m, k = idx.shape
-    g, _, f2 = wmat.shape
-    f = f2 // 2
+    g = wmat.shape[0]
+    c = slab_rows(m * k, g, nexpert)
     with jax.named_scope("dispatch"):
         local = idx.reshape(-1) - first
         key = jnp.where((local >= 0) & (local < g), local, g)
-        pair = lax.iota(jnp.int32, m * k)
-        skey, order = lax.sort((key, pair), num_keys=1)
-        _, inv = lax.sort((order, pair), num_keys=1)
+        _, order = lax.sort((key, lax.iota(jnp.int32, m * k)), num_keys=1)
         counts = (key[:, None] == lax.iota(jnp.int32, g)[None]).sum(
             axis=0, dtype=jnp.int32)
-        valid = (skey < g)[:, None]
-        xs = jnp.where(valid, _dispatch(x, order, inv, k), 0)
-    with jax.named_scope("experts"):
-        # the kernels accumulate in float32 and put out the rows in the
-        # activations' dtype: (tokens * topk, 2F) and (tokens * topk, D)
-        # in float32 would be a gigabyte a layer
-        gu = lax.ragged_dot(xs, wmat, counts,
-                            preferred_element_type=x.dtype)
-        h = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-             * gu[:, f:].astype(jnp.float32)).astype(x.dtype)
-        ys = lax.ragged_dot(jnp.where(valid, h, 0), wproj, counts,
-                            preferred_element_type=x.dtype)
-    with jax.named_scope("combine"):
-        # rows past the held groups are no group's: whatever the
-        # grouped product left there is put out
-        ys = jnp.where(valid, ys, 0)
-        y = (_unsort(ys, order, inv).reshape(m, k, -1)
-             * w.astype(x.dtype)[..., None]).sum(axis=1)
-    return y, counts
+        # whole slabs: a slice that starts in the last one stays inside
+        order = jnp.pad(order, (0, -(m * k) % c))
+    return _slabs(x, w, wmat, wproj, order, counts, c), counts
 
 
 @register
@@ -312,11 +422,15 @@ class RoutedExpertsLayer(Layer, Branch):
     def apply_stateful(self, params, aux, inputs, *, train=False, rng=None,
                        step=None):
         outs, counts = self._run(params, inputs[0])
-        counts = counts.astype(jnp.uint32)
+        pairs = counts.sum()
+        tokens = inputs[0].size // inputs[0].shape[-1]
+        slab = slab_rows(tokens * self.topk, self._held(), self.nexpert)
         return outs, {
-            "pairs": aux["pairs"] + counts.sum(),
-            "pairs_max": aux["pairs_max"] + counts.max(),
+            "pairs": aux["pairs"] + pairs.astype(jnp.uint32),
+            "pairs_max": aux["pairs_max"] + counts.max().astype(jnp.uint32),
             "pairs_dropped": aux["pairs_dropped"],
+            "pairs_overflow": aux["pairs_overflow"] + jnp.maximum(
+                pairs - slab, 0).astype(jnp.uint32),
         }
 
     def _run(self, params, x0):
@@ -333,9 +447,13 @@ class RoutedExpertsLayer(Layer, Branch):
             if self._held() < self.nexpert:
                 # a share: the weights' cotangent needs the other ranks'
                 w = lax.stop_gradient(w)
-        y, counts = held_experts(
-            x, w, idx, params["wmat"].astype(cdt),
-            params["wproj"].astype(cdt), self.first_expert)
+        with jax.named_scope("experts"):
+            # the turned copies the backward's products read are made
+            # from these: billed where the parent's were
+            wmat, wproj = _as_kept(params["wmat"]), _as_kept(params["wproj"])
+        y, counts = held_experts(x, w, idx, wmat.astype(cdt),
+                                 wproj.astype(cdt), self.first_expert,
+                                 self.nexpert)
         if self.shared_hidden:
             with jax.named_scope("shared"):
                 sh = self.shared_hidden

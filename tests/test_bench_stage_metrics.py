@@ -112,6 +112,33 @@ def test_attn_flash_pct_reads_the_two_attention_counters(rounds, want):
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
+# expert_dispatch_compact_pct (PR 39): the routed expert layers count the
+# pairs they computed in slabs after the first, inside the step programs
+@pytest.mark.parametrize("rounds, want", [
+    # four layers x 24 steps x ~5 100 held pairs, every one in the first
+    # slab: the loop never ran
+    ([{"expert_pairs": 491520, "expert_pairs_overflow": 0,
+       "expert_pairs_dropped": 0}] * 2, 100.0),
+    # a round whose router sent a tenth of the pairs past the slab
+    ([{"expert_pairs": 491520, "expert_pairs_overflow": 0},
+      {"expert_pairs": 491520, "expert_pairs_overflow": 98304}], 90.0),
+    # every held pair beyond a slab of none: nothing compact
+    ([{"expert_pairs": 1000, "expert_pairs_overflow": 1000}], 0.0),
+    # the parent counts pairs and no overflow; no pairs, no share
+    ([{"expert_pairs": 491520, "expert_pairs_dropped": 0}], None),
+    ([{"expert_pairs": 0, "expert_pairs_overflow": 0}], None),
+    ([{"tokens": 196608, "attn_tokens": 196608}], None),
+    ([{}], None),
+    ([], None),
+])
+def test_expert_dispatch_compact_pct_reads_the_two_pair_counters(
+        rounds, want):
+    read = run.load_metric("expert_dispatch_compact_pct").read
+    assert read(_counted(*rounds)) == (
+        want if want is None else pytest.approx(want))
+    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
+
+
 # the round loop's bill of the device's time from its own fences (PR 38):
 # ``run`` / ``run_exposed`` / ``head`` stages and three counters
 def _billed(*rounds, batch=256):
@@ -220,6 +247,7 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
     ("chunk_overlap_pct", ALL_CELLS, "higher"),
     ("gdn_scan_fused_pct", ALL_CELLS[3:4], "higher"),
     ("attn_flash_pct", ALL_CELLS[2:], "higher"),
+    ("expert_dispatch_compact_pct", ALL_CELLS[3:], "higher"),
 ] + [(name, ALL_CELLS, "lower") for name in LOOP_BILL])
 def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -241,6 +269,8 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
     assert names.index("gdn_scan_fused_pct") == 41
     assert names[42:46] == ["mla_ms_step", "mla_core_ms_step",
                             "mla_core_roofline_pct", "mtp_ms_step"]
-    # PR 37's one behind them, and PR 38's five behind that, the last
+    # PR 37's one behind them, PR 38's five behind that, and PR 39's
+    # one, the last
     assert names[46:47] == ["attn_flash_pct"]
-    assert names[47:52] == LOOP_BILL and len(names) == 52
+    assert names[47:52] == LOOP_BILL
+    assert names[52:] == ["expert_dispatch_compact_pct"]

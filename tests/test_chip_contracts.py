@@ -253,6 +253,18 @@ def test_pallas_kernel_lowers_for_tpu_at_smoke_shapes(case):
         with pytest.raises(NotImplementedError):
             jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)
         return
+    if name.startswith("routed_experts"):
+        # no kernel of ours: the grouped products are the compiler's
+        # (tests/test_v5e_compile.py), the slabs after the first a loop;
+        # the layer states the kept matrices' layout, a custom call the
+        # exporter has to be told of
+        text = jax.export.export(
+            jax.jit(kernel), platforms=["tpu"], disabled_checks=[
+                jax.export.DisabledSafetyCheck.custom_call(
+                    "LayoutConstraint")])(*args).mlir_module()
+        assert "ragged_dot" in text and "stablehlo.while" in text
+        assert "LayoutConstraint" in text
+        return
     exp = jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)
     assert "tpu_custom_call" in exp.mlir_module(), (
         f"{name}: lowered without a Mosaic call")

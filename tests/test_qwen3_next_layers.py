@@ -484,7 +484,7 @@ def test_a_share_takes_its_routing_weights_as_constants():
         def detached(q, a):
             w, idx = route(logits, 3)
             u = a.reshape(-1, 8)
-            y, _ = held_experts(u, w, idx, q["wmat"], q["wproj"], 4)
+            y, _ = held_experts(u, w, idx, q["wmat"], q["wproj"], 4, 16)
             gu = u @ q["shared_wmat"].T
             sh = (jax.nn.silu(gu[:, :6]) * gu[:, 6:]) @ q["shared_wproj"].T
             y = y + jax.nn.sigmoid(u @ q["shared_gate"].T) * sh

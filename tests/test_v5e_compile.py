@@ -5,7 +5,11 @@ on-chip-measurement guide, section 2: nothing runs, no chip is needed).
 * ``jax.lax.ragged_dot`` inside ``layers/moe.held_experts`` becomes
   kernels named ``ragged-dot-*`` whose layer scope is dropped — the
   name ``benchmarks/lib/stage_scopes.py`` reads the grouped products'
-  time by;
+  time by; since PR 39 they run on slabs of 10 240 rows at qwen3_next's
+  shapes, under one ``while`` for the slabs after the first, and no
+  array with a feature axis has room for all 81 920 (token, pick) pairs;
+  in the whole JoyAI step the held experts' float32 matrices keep their
+  row-major layout through the scan (``moe._as_kept``);
 * the chunked gated delta rule of ``ops/gdn.py`` in its ``jax.numpy``
   form, walked in checkpointed segments, keeps its backward's
   temporaries under the room a 16 GB chip has beside 10 GB of state;
@@ -60,13 +64,17 @@ def _shaped(one_chip, shape, dtype=jnp.bfloat16):
 
 
 def test_grouped_products_become_ragged_dot_kernels(one_chip):
-    from cxxnet_tpu.layers.moe import held_experts
+    """qwen3_next's share: 8192 tokens pick 10 of 512, 32 held.  Since PR
+    39 every array between the sort and a token's sum has a slab's 10 240
+    rows, not the 81 920 of all (token, pick) pairs."""
+    from cxxnet_tpu.layers.moe import held_experts, slab_rows
 
-    m, k, d, f, g = 8192, 10, 2048, 512, 32
+    m, k, d, f, g, e = 8192, 10, 2048, 512, 32, 512
+    assert slab_rows(m * k, g, e) == 10240
 
     def loss(x, w, idx, wmat, wproj):
         with jax.named_scope("l2_moe0"):
-            y, counts = held_experts(x, w, idx, wmat, wproj, 0)
+            y, counts = held_experts(x, w, idx, wmat, wproj, 0, e)
         return jnp.sum(y.astype(jnp.float32)), counts
 
     args = (_shaped(one_chip, (m, d)), _shaped(one_chip, (m, k), jnp.float32),
@@ -75,14 +83,25 @@ def test_grouped_products_become_ragged_dot_kernels(one_chip):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 3, 4), has_aux=True)
                        ).lower(*args).compile()
     text = compiled.as_text()
-    # two products forward and their gradients, every one a kernel
+    # two products forward and their gradients, every one a kernel, in
+    # the first slab and in the loop's body alike
     assert text.count('op_name="ragged-dot-none"') >= 4
-    assert "ragged_dot_tiling" in text
+    assert 'ragged_dot_tiling="512,' in text       # moe.ROW_TILE
     assert 'op_name="ragged-dot-metadata"' in text
     assert "experts/ragged_dot" not in text        # their scope is gone
-    assert "l2_moe0)/dispatch/" in text            # the others keep theirs
-    assert "l2_moe0)/experts/" in text             # silu, gate x up
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+    for scope in ("dispatch", "experts", "combine"):
+        assert f"l2_moe0))/{scope}/" in text       # the others keep theirs
+        # and inside the loop over further slabs too
+        assert re.search(rf"l2_moe0\)\)/while/body/[^\"]*{scope}/", text)
+    # ONE run-time construct, and no conditional
+    assert len(re.findall(r" while\(", text)) == 1
+    assert " conditional(" not in text
+    # no array with tokens x topk rows and a feature axis is left, in or
+    # out of the loop: what has that many rows is the int32 plan
+    assert not re.search(rf"(?:bf16|f32)\[{m * k},\d+\]", text)
+    assert re.search(rf"(?:bf16|f32)\[10240,{d}\]", text)
+    # 0.46 GB of temporaries where buffers for all pairs took 0.97
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
 def test_the_segmented_delta_rule_fits_beside_the_state(one_chip):
@@ -170,6 +189,13 @@ def test_the_joyai_step_fits_a_chip_with_sixteen_held_experts(one_chip):
                   "l20_mtp_mla)/core/", "l19_mtp_eh_proj", "l21_mtp_moe)/route/"):
         assert scope in text, scope
     assert 'op_name="ragged-dot-none"' in text
+    # PR 39: the slabs after the first are loops inside the scanned step,
+    # and every held expert's float32 matrices (weight and both moments)
+    # stay in the layout they are kept in: turned ({1,2,0}), with a copy
+    # of each at the scan's edges, the step read 17.4 GB
+    assert len(re.findall(r" while\(", text)) > 1
+    assert re.search(r"f32\[16,2048,1536\]\{2,1,0", text)
+    assert not re.search(r"f32\[16,(?:2048,1536|768,2048)\]\{1,2,0", text)
     # PR 37: every latent layer's core is four Mosaic calls (forward, the
     # remat recompute, dq, dk/dv), all billed to its core scope; mha's
     # float32 score blocks (1, 32, 512, <= 8192) are gone
