@@ -1,6 +1,7 @@
-"""Routed experts: ``routed_experts``, a feed-forward layer of gated
-(SwiGLU) experts behind a top-k router, computing the share of the
-experts THIS device holds.
+"""Routed experts: ``routed_experts``, a feed-forward layer of experts —
+gated (SwiGLU) or ungated (``relu(.)^2``), in the stream's own width or
+in a narrower latent — behind a top-k router, computing the share of
+the experts THIS device holds.
 
 New TPU-first scope.  It is the layer expert parallelism asks for: the
 router keeps its full width, its top-k and its weights over all
@@ -42,6 +43,22 @@ it (the family moves it by a balance rule between steps, from all
 ranks' loads; that rule is not here).  ``shared_gate = 0`` adds the
 shared expert ungated.
 
+With ``expert_act = relu2`` (the Nemotron-H family's ``mlp_hidden_act``)
+an expert, and the shared expert, is two matrices and no gate: ``W_d
+relu(W_u x)^2``.  With ``latent_hidden = L`` (LatentMoE) the held experts
+live in an ``L``-wide latent behind two projections of the layer's own::
+
+    l = u W_in^T                                  (D -> L), once a token
+    r = sum over the pairs (token, e) with e held of  w * expert_e(l)
+    y = r W_out^T + shared(u)                     (L -> D), once a token
+
+— the router and the shared expert keep reading ``u``; route, dispatch,
+the grouped products and the sum onto the tokens all run on ``L``-wide
+rows, and the projection back comes after that sum.  In a share the two
+projections are computed by every rank alike (replicated, like the
+router): ``r`` is linear in the experts' terms, so the ranks' ``r
+W_out^T`` add up to the whole layer's.
+
 **No pair is dropped and no expert has a capacity.**  The (token,
 expert) pairs are sorted by expert, pairs of experts held elsewhere
 last.  Every array between that sort and a token's sum has the ``C``
@@ -76,12 +93,17 @@ With ``nheld = nexpert`` (or ``SLAB_FACTOR`` x the share >= 1) ``C`` is
 * ``score_func`` — ``softmax`` (default) or ``sigmoid``; ``select_bias``
   (default 0) — 1 chooses by score + ``score_bias``; ``routed_scale``
   (default 1) multiplies the chosen weights
+* ``expert_act`` — ``swiglu`` (default) or ``relu2``, for the held
+  experts and the shared one alike; ``latent_hidden`` (default 0: none)
+  — the width ``L`` the held experts read and write
 * ``prenorm`` / ``residual_scale`` / ``eps`` — the residual branch in
   one layer (``sequence.Branch``); ``init_sigma`` for every matrix
 
-Parameters (tags): ``wgate`` (nexpert, D); the held experts' matrices
+Parameters (tags), with ``W`` the experts' width — ``latent_hidden``, or
+D without — and ``c`` = 2 (``swiglu``: gate | up fused) or 1
+(``relu2``): ``wgate`` (nexpert, D); the held experts' matrices
 as the grouped product reads them, ``(expert, in, out)`` — ``wmat``
-(nheld, D, 2 nhidden) gate | up and ``wproj`` (nheld, nhidden, D): kept
+(nheld, W, c nhidden) and ``wproj`` (nheld, nhidden, W): kept
 ``(out, in)`` like the framework's other matrices they would be
 transposed for every product, and the compiler then carried them
 through the scanned step in the transposed layout, with a copy of every
@@ -90,10 +112,11 @@ expert's weight and of both its adam moments at the loop's edges
 33; with the loop over further slabs in the step the compiler turned
 them again, so the layer now states the layout, ``_as_kept``); and with
 a shared expert
-``shared_wmat`` (2 shared_hidden, D), ``shared_wproj`` (D,
+``shared_wmat`` (c shared_hidden, D), ``shared_wproj`` (D,
 shared_hidden), ``shared_gate`` (1, D) unless ``shared_gate = 0``;
-``score_bias`` (nexpert,) with ``select_bias``, started at 0; ``norm``
-(D) with ``prenorm``.
+``score_bias`` (nexpert,) with ``select_bias``, started at 0;
+``latent_in`` (L, D) and ``latent_out`` (D, L) with ``latent_hidden``;
+``norm`` (D) with ``prenorm``.
 All float32 at rest, cast where used; the router's product is float32
 at the highest precision.
 
@@ -108,8 +131,9 @@ uint32, wrapping: the reader takes differences.
 Scopes inside the layer's: ``route`` (router, softmax, top-k),
 ``dispatch`` (the sort, a slab's plan and gather; backward: ``dx``),
 ``experts`` (the grouped products), ``combine`` (the weights and the sum
-onto the tokens), ``shared``.  The slabs after the first name the same
-three under the loop's ``while/body``.
+onto the tokens), ``shared``, and with a latent ``latent_in`` and
+``latent_out`` (the two projections).  The slabs after the first name
+``dispatch`` / ``experts`` / ``combine`` under the loop's ``while/body``.
 
 The older ``moe`` type (``sequence.MoELayer``) stays beside this one:
 it is a different function (one linear projection an expert to another
@@ -190,17 +214,22 @@ def route(logits, topk: int, norm_topk: bool = True, *,
     return w, idx.astype(jnp.int32)
 
 
-def _experts(xs, wmat, wproj, sizes, valid):
+def _experts(xs, wmat, wproj, sizes, valid, act: str = "swiglu"):
     """A slab's rows ``xs (c, D)`` through their experts, ``(c, D)`` in
     the activations' dtype and zero past the held pairs (whatever the
-    grouped product left there is put out).  The kernels accumulate in
-    float32.  It names no scope: the backward calls it under
-    ``jax.vjp``, which would wrap one (``jvp(experts)``) where the
-    trace's readers look for the plain name."""
-    f = wmat.shape[-1] // 2
+    grouped product left there is put out): ``act = "swiglu"`` on a
+    fused ``wmat (G, D, 2F)`` gate | up, ``"relu2"`` (``relu(.)^2``, no
+    gate) on ``wmat (G, D, F)``.  The kernels accumulate in float32.  It
+    names no scope: the backward calls it under ``jax.vjp``, which
+    would wrap one (``jvp(experts)``) where the trace's readers look
+    for the plain name."""
     gu = lax.ragged_dot(xs, wmat, sizes, preferred_element_type=xs.dtype)
-    h = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-         * gu[:, f:].astype(jnp.float32)).astype(xs.dtype)
+    if act == "relu2":
+        h = jnp.square(jax.nn.relu(gu.astype(jnp.float32))).astype(xs.dtype)
+    else:
+        f = wmat.shape[-1] // 2
+        h = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+             * gu[:, f:].astype(jnp.float32)).astype(xs.dtype)
     ys = lax.ragged_dot(jnp.where(valid, h, 0), wproj, sizes,
                         preferred_element_type=xs.dtype)
     return jnp.where(valid, ys, 0)
@@ -222,17 +251,17 @@ def _slab_inputs(x, w, order, counts, s, c: int):
         return pair, sizes, valid, xs, w.reshape(-1)[pair]
 
 
-def _slab(x, w, wmat, wproj, order, counts, s, c: int):
+def _slab(x, w, wmat, wproj, order, counts, s, c: int, act: str):
     """Slab ``s``'s terms of every token's sum: ``(M, D)`` float32."""
     pair, sizes, valid, xs, wrow = _slab_inputs(x, w, order, counts, s, c)
     with jax.named_scope("experts"):
-        ys = _experts(xs, wmat, wproj, sizes, valid)
+        ys = _experts(xs, wmat, wproj, sizes, valid, act)
     with jax.named_scope("combine"):
         return _onto_tokens(ys.astype(jnp.float32) * wrow[:, None],
                             pair // w.shape[1], w.shape[0])
 
 
-def _slab_grads(x, w, wmat, wproj, order, counts, s, c: int, g):
+def _slab_grads(x, w, wmat, wproj, order, counts, s, c: int, act: str, g):
     """Slab ``s``'s terms of the cotangents of ``x`` (float32), ``w``
     (flat), ``wmat`` and ``wproj`` for ``g (M, D)``, the output's: the
     slab's rows are gathered and put through their experts once more."""
@@ -240,7 +269,7 @@ def _slab_grads(x, w, wmat, wproj, order, counts, s, c: int, g):
     pair, sizes, valid, xs, wrow = _slab_inputs(x, w, order, counts, s, c)
     with jax.named_scope("experts"):
         ys, vjp = jax.vjp(
-            lambda *a: _experts(*a, sizes, valid), xs, wmat, wproj)
+            lambda *a: _experts(*a, sizes, valid, act), xs, wmat, wproj)
     with jax.named_scope("combine"):
         grow = g[pair // k].astype(jnp.float32)
         dys = (grow * wrow[:, None]).astype(ys.dtype)
@@ -259,38 +288,39 @@ def _slabs_held(counts, c: int):
     return (counts.sum() + (c - 1)) // c
 
 
-def _slabs_impl(x, w, wmat, wproj, order, counts, c):
-    y = _slab(x, w, wmat, wproj, order, counts, 0, c)
+def _slabs_impl(x, w, wmat, wproj, order, counts, c, act):
+    y = _slab(x, w, wmat, wproj, order, counts, 0, c, act)
     if c < w.size:
         y = lax.fori_loop(
             1, _slabs_held(counts, c),
-            lambda s, y: y + _slab(x, w, wmat, wproj, order, counts, s, c),
+            lambda s, y: y + _slab(x, w, wmat, wproj, order, counts, s, c,
+                                   act),
             y)
     return y.astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _slabs(x, w, wmat, wproj, order, counts, c: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _slabs(x, w, wmat, wproj, order, counts, c: int, act: str):
     """The sum over the slabs of ``c`` sorted pairs that hold one: the
     first always, the others in a loop whose trip count the routing
     gives.  A loop has no reverse mode, so the backward is written
     out: it keeps the inputs and walks the same slabs."""
-    return _slabs_impl(x, w, wmat, wproj, order, counts, c)
+    return _slabs_impl(x, w, wmat, wproj, order, counts, c, act)
 
 
-def _slabs_fwd(x, w, wmat, wproj, order, counts, c):
-    return (_slabs_impl(x, w, wmat, wproj, order, counts, c),
+def _slabs_fwd(x, w, wmat, wproj, order, counts, c, act):
+    return (_slabs_impl(x, w, wmat, wproj, order, counts, c, act),
             (x, w, wmat, wproj, order, counts))
 
 
-def _slabs_bwd(c, res, g):
+def _slabs_bwd(c, act, res, g):
     x, w = res[:2]
-    grads = _slab_grads(*res, 0, c, g)
+    grads = _slab_grads(*res, 0, c, act, g)
     if c < w.size:
         grads = lax.fori_loop(
             1, _slabs_held(res[5], c),
             lambda s, acc: jax.tree_util.tree_map(
-                jnp.add, acc, _slab_grads(*res, s, c, g)),
+                jnp.add, acc, _slab_grads(*res, s, c, act, g)),
             grads)
     dx, dw, dwmat, dwproj = grads
     return (dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype), dwmat,
@@ -312,11 +342,14 @@ def _as_kept(w):
     return with_layout_constraint(w, Layout(major_to_minor=(0, 1, 2)))
 
 
-def held_experts(x, w, idx, wmat, wproj, first: int, nexpert: int):
+def held_experts(x, w, idx, wmat, wproj, first: int, nexpert: int,
+                 act: str = "swiglu"):
     """The held experts' part of the layer: ``x (M, D)``, the router's
     ``w`` / ``idx (M, k)`` over ``nexpert`` experts, ``wmat (G, D, 2F)``
-    and ``wproj (G, F, D)`` of the ``G`` experts ``first .. first + G -
-    1`` -> (``y (M, D)``, pairs a held expert ``(G,)`` int32)."""
+    (``(G, D, F)`` with ``act = "relu2"``) and ``wproj (G, F, D)`` of
+    the ``G`` experts ``first .. first + G - 1`` -> (``y (M, D)``, pairs
+    a held expert ``(G,)`` int32).  ``D`` is whatever width the experts
+    live in: the stream's, or a latent's."""
     m, k = idx.shape
     g = wmat.shape[0]
     c = slab_rows(m * k, g, nexpert)
@@ -328,7 +361,7 @@ def held_experts(x, w, idx, wmat, wproj, first: int, nexpert: int):
             axis=0, dtype=jnp.int32)
         # whole slabs: a slice that starts in the last one stays inside
         order = jnp.pad(order, (0, -(m * k) % c))
-    return _slabs(x, w, wmat, wproj, order, counts, c), counts
+    return _slabs(x, w, wmat, wproj, order, counts, c, act), counts
 
 
 @register
@@ -339,7 +372,7 @@ class RoutedExpertsLayer(Layer, Branch):
     aux_counters = {name: "expert_" + name for name in COUNTERS}
     f32_tags = frozenset({"wgate", "wmat", "wproj", "shared_wmat",
                           "shared_wproj", "shared_gate", "score_bias",
-                          "norm"})
+                          "latent_in", "latent_out", "norm"})
 
     def __init__(self) -> None:
         super().__init__()
@@ -353,9 +386,12 @@ class RoutedExpertsLayer(Layer, Branch):
         self.score_func = "softmax"
         self.select_bias = 0
         self.routed_scale = 1.0
+        self.latent_hidden = 0  # 0: the experts read the stream itself
+        self.expert_act = "swiglu"
 
     _INT_KEYS = ("nexpert", "topk", "first_expert", "nheld",
-                 "shared_hidden", "shared_gate", "norm_topk", "select_bias")
+                 "shared_hidden", "shared_gate", "norm_topk", "select_bias",
+                 "latent_hidden")
 
     def set_param(self, name, val):
         if name in self._INT_KEYS:
@@ -368,6 +404,12 @@ class RoutedExpertsLayer(Layer, Branch):
             self.score_func = val
         elif name == "routed_scale":
             self.routed_scale = float(val)
+        elif name == "expert_act":
+            if val not in ("swiglu", "relu2"):
+                raise ValueError(
+                    f"routed_experts: expert_act is swiglu or relu2, got "
+                    f"{val!r}")
+            self.expert_act = val
         elif not self.set_branch_param(name, val):
             super().set_param(name, val)
 
@@ -389,11 +431,15 @@ class RoutedExpertsLayer(Layer, Branch):
                 f"routed_experts: experts {self.first_expert}.."
                 f"{self.first_expert + self._held() - 1} are not among "
                 f"the {self.nexpert} routed")
+        if self.latent_hidden < 0:
+            raise ValueError("routed_experts: latent_hidden >= 0")
         return [tuple(in_shapes[0])]
 
     def init_params(self, key, in_shapes) -> Params:
         d = in_shapes[0][-1]
         f, g, sh = self.param.num_hidden, self._held(), self.shared_hidden
+        lat = self.latent_hidden or d  # the width the held experts live in
+        fused = 1 if self.expert_act == "relu2" else 2  # gate | up, or up
         ks = jax.random.split(key, 6)
         sigma = self.param.init_sigma
 
@@ -401,13 +447,17 @@ class RoutedExpertsLayer(Layer, Branch):
             return jax.random.normal(k, shape, jnp.float32) * sigma
 
         out = {"wgate": normal(ks[0], (self.nexpert, d)),
-               "wmat": normal(ks[1], (g, d, 2 * f)),
-               "wproj": normal(ks[2], (g, f, d))}
+               "wmat": normal(ks[1], (g, lat, fused * f)),
+               "wproj": normal(ks[2], (g, f, lat))}
         if sh:
-            out.update({"shared_wmat": normal(ks[3], (2 * sh, d)),
+            out.update({"shared_wmat": normal(ks[3], (fused * sh, d)),
                         "shared_wproj": normal(ks[4], (d, sh))})
             if self.shared_gate:
                 out["shared_gate"] = normal(ks[5], (1, d))
+        if self.latent_hidden:
+            kin, kout = jax.random.split(jax.random.fold_in(key, 6))
+            out.update({"latent_in": normal(kin, (lat, d)),
+                        "latent_out": normal(kout, (d, lat))})
         if self.select_bias:
             out["score_bias"] = jnp.zeros((self.nexpert,), jnp.float32)
         out.update(self.branch_params(d))
@@ -451,15 +501,25 @@ class RoutedExpertsLayer(Layer, Branch):
             # the turned copies the backward's products read are made
             # from these: billed where the parent's were
             wmat, wproj = _as_kept(params["wmat"]), _as_kept(params["wproj"])
-        y, counts = held_experts(x, w, idx, wmat.astype(cdt),
+        xe = x
+        if self.latent_hidden:
+            with jax.named_scope("latent_in"):
+                xe = x @ params["latent_in"].astype(cdt).T
+        y, counts = held_experts(xe, w, idx, wmat.astype(cdt),
                                  wproj.astype(cdt), self.first_expert,
-                                 self.nexpert)
+                                 self.nexpert, self.expert_act)
+        if self.latent_hidden:
+            # once a token, after the sum over its picks
+            with jax.named_scope("latent_out"):
+                y = y @ params["latent_out"].astype(cdt).T
         if self.shared_hidden:
             with jax.named_scope("shared"):
                 sh = self.shared_hidden
                 gu = x @ params["shared_wmat"].astype(cdt).T
-                s = (jax.nn.silu(gu[:, :sh]) * gu[:, sh:]) @ params[
-                    "shared_wproj"].astype(cdt).T
+                hid = (jnp.square(jax.nn.relu(gu))
+                       if self.expert_act == "relu2"
+                       else jax.nn.silu(gu[:, :sh]) * gu[:, sh:])
+                s = hid @ params["shared_wproj"].astype(cdt).T
                 if self.shared_gate:
                     s = jax.nn.sigmoid(
                         x @ params["shared_gate"].astype(cdt).T) * s

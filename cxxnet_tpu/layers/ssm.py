@@ -5,10 +5,13 @@ no sequence axis.  The layer follows the published block
 
     [z | xBC | dt] = u W_in                  widths E | E + 2GS | H
     xBC = silu(conv(xBC) + b)                depthwise, causal, width K
-    [x | B | C] = xBC                        x as H heads of P, E = H P
+    [x | B | C] = xBC                        x as H heads of P, E = H P;
+                                             B, C as G groups of S: head
+                                             h reads group h G // H
     dt = softplus(dt + dt_bias);  a = -exp(a_log)      one scalar a head
     S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + d x_t
-    y = rms_norm(y * silu(z)) * gate_norm
+    y = rms_norm(y * silu(z)) * gate_norm    over each group's E / G
+                                             columns alone
     out = y W_out
 
 with the scan in its chunked form (``ops/ssd.py``: one implementation,
@@ -17,8 +20,14 @@ plain ``jax.numpy``, differentiated by ``jax.grad``).
 ``mamba2`` config keys:
 
 * ``nhead`` (H), ``head_dim`` (P), ``nstate`` (S) — required
-* ``conv_width`` (K, default 4), ``ngroup`` (G: only 1, ``B`` and ``C``
-  shared by all heads), ``chunk`` (default 256), ``eps`` (1e-5)
+* ``conv_width`` (K, default 4), ``ngroup`` (G, default 1: ``B`` and
+  ``C`` shared by all heads; G must divide H), ``chunk`` (default 256),
+  ``eps`` (1e-5).  The groups are what Megatron's tensor parallelism
+  divides a mixer by: rank ``r`` of ``G`` holds group ``r``, its ``H /
+  G`` heads' columns of ``z``, ``x`` and ``dt``, their rows of ``W_out``
+  and a gated norm of its own, so ONE RANK'S SHARE of a ``G``-group
+  mixer is this layer at ``nhead = H / G``, ``ngroup = 1``, and the
+  shares' branches add up to the whole layer's
 * ``prenorm`` / ``residual_scale`` — the residual branch in one layer
   (``sequence.Branch``): ``y = x + residual_scale * f(rms_norm(x))``
 * ``init_sigma`` for the two matrices; ``a_log``, ``dt_bias``, ``d``,
@@ -82,6 +91,7 @@ class Mamba2Layer(Layer, Branch):
         self.head_dim = 0
         self.nstate = 0
         self.conv_width = 4
+        self.ngroup = 1
         self.chunk = 256
 
     def set_param(self, name, val):
@@ -95,9 +105,8 @@ class Mamba2Layer(Layer, Branch):
             self.conv_width = int(val)
         elif name == "chunk":
             self.chunk = int(val)
-        elif name == "ngroup" and int(val) != 1:
-            raise ValueError("mamba2: one group only (B and C shared by "
-                             "all heads)")
+        elif name == "ngroup":
+            self.ngroup = int(val)
         elif not self.set_branch_param(name, val):
             super().set_param(name, val)
 
@@ -109,12 +118,16 @@ class Mamba2Layer(Layer, Branch):
         if min(self.nhead, self.head_dim, self.nstate, self.conv_width,
                self.chunk) <= 0:
             raise ValueError("mamba2: set nhead, head_dim and nstate")
+        if self.ngroup < 1 or self.nhead % self.ngroup:
+            raise ValueError(
+                f"mamba2: ngroup={self.ngroup} must divide "
+                f"nhead={self.nhead}")
         return [tuple(in_shapes[0])]
 
     def init_params(self, key, in_shapes) -> Params:
         d = in_shapes[0][2]
-        h, e, s, k = (self.nhead, self.nhead * self.head_dim, self.nstate,
-                      self.conv_width)
+        h, e, s, k = (self.nhead, self.nhead * self.head_dim,
+                      self.ngroup * self.nstate, self.conv_width)
         k1, k2, k3, k4, k5 = jax.random.split(key, 5)
         sigma = self.param.init_sigma
         # a step drawn log-uniform in [1e-3, 1e-1], through the inverse
@@ -141,8 +154,8 @@ class Mamba2Layer(Layer, Branch):
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
         x0 = inputs[0]
         n, t, _ = x0.shape
-        h, p, s = self.nhead, self.head_dim, self.nstate
-        e = h * p
+        h, p, g = self.nhead, self.head_dim, self.ngroup
+        e, s = h * p, g * self.nstate
         cdt = x0.dtype
         f32 = jnp.float32
         doc = doc_index(inputs[1]) if len(inputs) > 1 else None
@@ -157,14 +170,21 @@ class Mamba2Layer(Layer, Branch):
                 params["conv_bias"].astype(cdt), doc))
             x = xbc[..., :e].reshape(n, t, h, p)
             b, c = xbc[..., e:e + s], xbc[..., e + s:]
+            if g > 1:
+                b, c = (v.reshape(n, t, g, self.nstate) for v in (b, c))
         with jax.named_scope("scan"):
             dt = jax.nn.softplus(dt.astype(f32) + params["dt_bias"])
             y = ssd_scan(x, dt, -jnp.exp(params["a_log"].astype(f32)), b, c,
                          doc, self.chunk)
             y = y + params["d"].astype(cdt)[:, None] * x
         with jax.named_scope("gate_norm"):
-            y = rms_norm(y.reshape(n, t, e) * jax.nn.silu(z),
-                         params["gate_norm"], self.eps)
+            y = y.reshape(n, t, e) * jax.nn.silu(z)
+            if g > 1:  # each group's columns under a norm of their own
+                y = rms_norm(y.reshape(n, t, g, e // g),
+                             params["gate_norm"].reshape(g, e // g),
+                             self.eps).reshape(n, t, e)
+            else:
+                y = rms_norm(y, params["gate_norm"], self.eps)
         with jax.named_scope("out_proj"):
             out = y @ params["wproj"].astype(cdt).T
         return [self.branch_out(x0, out)]

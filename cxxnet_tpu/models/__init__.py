@@ -24,6 +24,7 @@ from .builders import (  # noqa: F401
     kaggle_bowl_conf,
     mnist_conv_conf,
     mnist_mlp_conf,
+    nemotron_h_conf,
     qwen3_next_conf,
     resnet50_conf,
     resnet101_conf,
@@ -50,4 +51,5 @@ MODEL_BUILDERS = {
     "granite_h": granite_h_conf,
     "qwen3_next": qwen3_next_conf,
     "joyai_llm_flash": joyai_llm_flash_conf,
+    "nemotron_h": nemotron_h_conf,
 }

@@ -448,6 +448,71 @@ def transformer_lm_conf(
 GRANITE_H_PERIOD = "mmmmmammmm"
 
 
+def _packed_lm(net: str, token_file: str, seq_len: int, batch_size: int,
+               num_round: int, dev: str, compute_dtype: str, eta: float,
+               scan_steps: int) -> str:
+    """The conf of a language model on packed token rows around its
+    ``netconfig`` block ``net``: the ``iter = tokens`` feed (where a
+    file is named) and the run settings the token builders share — a
+    label a position, logloss, adam without decay, ``remat = 1`` and
+    ``eval_train = 0`` (written for memory: ``granite_h_conf``)."""
+    data = ""
+    if token_file:
+        data = (
+            "data = train\n"
+            "iter = tokens\n"
+            f"  filename = {token_file}\n"
+            f"  seq_len = {seq_len}\n"
+            "iter = end\n"
+        )
+    extra = (
+        f"compute_dtype = {compute_dtype}\n"
+        f"label_width = {seq_len}\n"
+        f"label_vec[0,{seq_len}) = label\n"
+        "metric = logloss\n"
+        "updater = adam\n"
+        "wd = 0.0\n"
+        "remat = 1\n"
+        "eval_train = 0\n"
+    )
+    return data + net + _tail(
+        batch_size, f"1,1,{seq_len}", num_round, eta=eta, dev=dev,
+        extra=extra, scan_steps=scan_steps,
+    )
+
+
+def _mtp_module(last: str, hidden: int, eps: float, block: str, out: str,
+                seq_len: int, loss_weight: float) -> str:
+    """A multi-token-prediction module of depth 1 (DeepSeek-V3, section
+    2.2) behind the main head and loss, its layers named ``mtp_*``: the
+    shared embedding of the NEXT token and the node ``last``, each
+    normed, joined by ``mtp_eh_proj`` (the embedding's columns first),
+    then ``block`` — the conf layers from node ``mtp_h0`` to node
+    ``out`` — a last norm, the shared head, and a loss on the token
+    after next at ``loss_weight`` (``softmax`` with ``target_shift =
+    1`` over the same ``label`` field)."""
+    return (
+        "layer[0->mtp_ids] = token_shift:mtp_shift\n"
+        "layer[mtp_ids->mtp_e] = shared[embed]\n"
+        "layer[mtp_e->mtp_en] = rms_norm:mtp_enorm\n"
+        f"  eps = {eps!r}\n"
+        f"layer[{last}->mtp_hn] = rms_norm:mtp_hnorm\n"
+        f"  eps = {eps!r}\n"
+        "layer[mtp_en,mtp_hn->mtp_eh] = concat:mtp_cat\n"
+        "layer[mtp_eh->mtp_h0] = fullc:mtp_eh_proj\n"
+        f"  nhidden = {hidden}\n"
+        "  no_bias = 1\n"
+        "  init_sigma = 0.02\n"
+        + block
+        + f"layer[{out}->mtp_nf] = rms_norm:mtp_norm_f\n"
+        f"  eps = {eps!r}\n"
+        "layer[mtp_nf->mtp_logits] = shared[head]\n"
+        "layer[mtp_logits->mtp_logits] = softmax\n"
+        "  target_shift = 1\n"
+        f"  grad_scale = {loss_weight / seq_len!r}\n"
+    )
+
+
 def granite_h_conf(
     vocab: int = 12544,
     seq_len: int = 8192,
@@ -491,15 +556,6 @@ def granite_h_conf(
     ``eval_train = 0`` because 8 steps of ``(T, vocab)`` outputs cannot
     be fetched — chunks then run double-buffered and asynchronous.
     """
-    data = ""
-    if token_file:
-        data = (
-            "data = train\n"
-            "iter = tokens\n"
-            f"  filename = {token_file}\n"
-            f"  seq_len = {seq_len}\n"
-            "iter = end\n"
-        )
     branch = (f"  prenorm = 1\n  eps = {eps!r}\n"
               f"  residual_scale = {residual_multiplier!r}\n"
               "  init_sigma = 0.02\n")
@@ -549,20 +605,8 @@ def granite_h_conf(
         f"  grad_scale = {1.0 / seq_len!r}\n"
         "netconfig = end\n"
     )
-    extra = (
-        f"compute_dtype = {compute_dtype}\n"
-        f"label_width = {seq_len}\n"
-        f"label_vec[0,{seq_len}) = label\n"
-        "metric = logloss\n"
-        "updater = adam\n"
-        "wd = 0.0\n"
-        "remat = 1\n"
-        "eval_train = 0\n"
-    )
-    return data + s + _tail(
-        batch_size, f"1,1,{seq_len}", num_round, eta=eta, dev=dev,
-        extra=extra, scan_steps=scan_steps,
-    )
+    return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
+                      compute_dtype, eta, scan_steps)
 
 
 def qwen3_next_conf(
@@ -630,15 +674,6 @@ def qwen3_next_conf(
     counted from a document's first token.  Written for memory as
     ``granite_h_conf`` is: ``remat = 1``, ``eval_train = 0``.
     """
-    data = ""
-    if token_file:
-        data = (
-            "data = train\n"
-            "iter = tokens\n"
-            f"  filename = {token_file}\n"
-            f"  seq_len = {seq_len}\n"
-            "iter = end\n"
-        )
     branch = (f"  prenorm = 1\n  eps = {eps!r}\n"
               "  residual_scale = 1.0\n"
               "  init_sigma = 0.02\n")
@@ -698,20 +733,8 @@ def qwen3_next_conf(
         f"  grad_scale = {1.0 / seq_len!r}\n"
         "netconfig = end\n"
     )
-    extra = (
-        f"compute_dtype = {compute_dtype}\n"
-        f"label_width = {seq_len}\n"
-        f"label_vec[0,{seq_len}) = label\n"
-        "metric = logloss\n"
-        "updater = adam\n"
-        "wd = 0.0\n"
-        "remat = 1\n"
-        "eval_train = 0\n"
-    )
-    return data + s + _tail(
-        batch_size, f"1,1,{seq_len}", num_round, eta=eta, dev=dev,
-        extra=extra, scan_steps=scan_steps,
-    )
+    return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
+                      compute_dtype, eta, scan_steps)
 
 
 def joyai_llm_flash_conf(
@@ -792,15 +815,6 @@ def joyai_llm_flash_conf(
     if num_nextn_predict_layers not in (0, 1):
         raise ValueError("joyai_llm_flash_conf: a multi-token-prediction "
                          "depth of 0 or 1")
-    data = ""
-    if token_file:
-        data = (
-            "data = train\n"
-            "iter = tokens\n"
-            f"  filename = {token_file}\n"
-            f"  seq_len = {seq_len}\n"
-            "iter = end\n"
-        )
     branch = (f"  prenorm = 1\n  eps = {eps!r}\n"
               "  residual_scale = 1.0\n"
               "  init_sigma = 0.02\n")
@@ -864,42 +878,173 @@ def joyai_llm_flash_conf(
         f"  grad_scale = {1.0 / seq_len!r}\n"
     )
     if num_nextn_predict_layers:
-        s += (
-            "layer[0->mtp_ids] = token_shift:mtp_shift\n"
-            "layer[mtp_ids->mtp_e] = shared[embed]\n"
-            "layer[mtp_e->mtp_en] = rms_norm:mtp_enorm\n"
-            f"  eps = {eps!r}\n"
-            f"layer[{last}->mtp_hn] = rms_norm:mtp_hnorm\n"
-            f"  eps = {eps!r}\n"
-            "layer[mtp_en,mtp_hn->mtp_eh] = concat:mtp_cat\n"
-            "layer[mtp_eh->mtp_h0] = fullc:mtp_eh_proj\n"
-            f"  nhidden = {hidden}\n"
-            "  no_bias = 1\n"
-            "  init_sigma = 0.02\n"
-            + mla("mtp_h0", "mtp_x", "mtp_mla")
-            + moe("mtp_x", "mtp_h1", "mtp_moe")
-            + "layer[mtp_h1->mtp_nf] = rms_norm:mtp_norm_f\n"
-            f"  eps = {eps!r}\n"
-            "layer[mtp_nf->mtp_logits] = shared[head]\n"
-            "layer[mtp_logits->mtp_logits] = softmax\n"
-            "  target_shift = 1\n"
-            f"  grad_scale = {mtp_loss_weight / seq_len!r}\n"
-        )
+        s += _mtp_module(
+            last, hidden, eps,
+            mla("mtp_h0", "mtp_x", "mtp_mla")
+            + moe("mtp_x", "mtp_h1", "mtp_moe"),
+            "mtp_h1", seq_len, mtp_loss_weight)
     s += "netconfig = end\n"
-    extra = (
-        f"compute_dtype = {compute_dtype}\n"
-        f"label_width = {seq_len}\n"
-        f"label_vec[0,{seq_len}) = label\n"
-        "metric = logloss\n"
-        "updater = adam\n"
-        "wd = 0.0\n"
-        "remat = 1\n"
-        "eval_train = 0\n"
+    return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
+                      compute_dtype, eta, scan_steps)
+
+
+NEMOTRON_H_STAGE = "MEMEMEM*EME"
+
+
+def nemotron_h_conf(
+    vocab: int = 16384,
+    seq_len: int = 8192,
+    hidden: int = 4096,
+    pattern: str = NEMOTRON_H_STAGE,
+    mamba_heads: int = 16,
+    mamba_head_dim: int = 64,
+    mamba_groups: int = 1,
+    mamba_state: int = 128,
+    mamba_conv: int = 4,
+    mamba_chunk: int = 128,
+    attn_heads: int = 4,
+    attn_kv_heads: int = 1,
+    head_dim: int = 128,
+    num_experts: int = 512,
+    experts_per_tok: int = 22,
+    expert_hidden: int = 2688,
+    latent_hidden: int = 1024,
+    shared_hidden: int = 5376,
+    routed_scaling_factor: float = 5.0,
+    first_expert: int = 0,
+    experts_held: int = 8,
+    num_nextn_predict_layers: int = 0,
+    mtp_pattern: str = "*E",
+    mtp_loss_weight: float = 0.3,
+    eps: float = 1e-5,
+    token_file: str = "",
+    batch_size: int = 1,
+    num_round: int = 10,
+    dev: str = "tpu",
+    compute_dtype: str = "bfloat16",
+    eta: float = 0.0003,
+    scan_steps: int = 8,
+) -> str:
+    """A Nemotron-H style hybrid language model (NVIDIA, ``model_type:
+    nemotron_h``; arXiv:2504.03624): ``pattern`` is the published
+    ``hybrid_override_pattern``, ONE conf layer a letter, each alone
+    under its pre-norm (``rms_norm``) and residual add — ``M`` a Mamba-2
+    mixer with ``mamba_groups`` groups of ``B`` and ``C`` and a gated
+    norm a group, ``*`` a position-free grouped-query attention with
+    heads of ``head_dim``, ``E`` a LatentMoE layer: ``num_experts``
+    ungated ``relu(.)^2`` experts of ``expert_hidden`` that live in a
+    ``latent_hidden``-wide latent behind two projections, a sigmoid
+    router that chooses its top-``experts_per_tok`` by score + bias and
+    weighs them by the unbiased scores, renormalised and times
+    ``routed_scaling_factor``, plus one ungated ``relu(.)^2`` shared
+    expert on the stream itself.  No MLP follows a mixer.  An untied
+    head.  With ``num_nextn_predict_layers = 1`` (the published model has
+    one) a prediction module follows the main head and loss, its layers
+    named ``mtp_*`` and its block ``mtp_pattern`` (``_mtp_module`` has
+    the form).
+
+    The defaults are the published widths of Nemotron-3-Super-120B-A12B
+    as ONE RANK of the first of eight pipeline stages holds them: the
+    stage's 11 layers, and of each layer what an 8-way tensor-parallel,
+    64-way expert-parallel rank has — ``mamba_heads`` 16 of 128 in
+    ``mamba_groups`` 1 of 8 (a group, its heads and a gated norm of its
+    own: what the groups are for, ``layers/ssm.py``), ``attn_heads`` 4
+    of 32 on ``attn_kv_heads`` 1 of 2, ``experts_held`` 8 of 512 from
+    ``first_expert`` on, an eighth of the vocabulary.  The shared expert
+    is WHOLE (``shared_hidden`` 5376: a feed-forward width is never a
+    rank's to cut; every rank computes it alike and a sum over ranks
+    counts it once), and the module is left out (0): with both, the step
+    compiles to 15.4 GB at its fullest and a 16 GB chip has no room to
+    spare.  The share is spelt in the layers' own keys: each branch is
+    the partial sum this rank's heads and experts give, and the ranks'
+    partial sums add up to the whole layer's (a test does the sum).  The
+    whole model is the same builder at 128 / 8, 32 / 2, 512 held, one
+    prediction layer.
+
+    Routers, selection bias and documents as ``joyai_llm_flash_conf``;
+    written for memory as ``granite_h_conf`` is (``remat = 1``,
+    ``eval_train = 0``).
+    """
+    if num_nextn_predict_layers not in (0, 1):
+        raise ValueError("nemotron_h_conf: a prediction depth of 0 or 1")
+    branch = (f"  prenorm = 1\n  eps = {eps!r}\n"
+              "  residual_scale = 1.0\n"
+              "  init_sigma = 0.02\n")
+
+    def layer(kind: str, src: str, out: str, name: str) -> str:
+        if kind == "M":
+            return (
+                f"layer[{src},0->{out}] = mamba2:{name}\n"
+                f"  nhead = {mamba_heads}\n"
+                f"  head_dim = {mamba_head_dim}\n"
+                f"  ngroup = {mamba_groups}\n"
+                f"  nstate = {mamba_state}\n"
+                f"  conv_width = {mamba_conv}\n"
+                f"  chunk = {mamba_chunk}\n" + branch
+            )
+        if kind == "*":
+            return (
+                f"layer[{src},0->{out}] = attention:{name}\n"
+                f"  nhead = {attn_heads}\n"
+                f"  nkvhead = {attn_kv_heads}\n"
+                f"  head_dim = {head_dim}\n"
+                "  causal = 1\n  no_bias = 1\n" + branch
+            )
+        if kind == "E":
+            return (
+                f"layer[{src}->{out}] = routed_experts:{name}\n"
+                f"  nexpert = {num_experts}\n"
+                f"  topk = {experts_per_tok}\n"
+                f"  nhidden = {expert_hidden}\n"
+                f"  latent_hidden = {latent_hidden}\n"
+                "  expert_act = relu2\n"
+                f"  first_expert = {first_expert}\n"
+                f"  nheld = {experts_held}\n"
+                f"  shared_hidden = {shared_hidden}\n"
+                "  shared_gate = 0\n"
+                "  score_func = sigmoid\n"
+                "  select_bias = 1\n"
+                f"  routed_scale = {routed_scaling_factor!r}\n"
+                "  norm_topk = 1\n" + branch
+            )
+        raise ValueError(
+            f"nemotron_h_conf: a pattern is a string of M, * and E, got "
+            f"{kind!r}")
+
+    names = {"M": "mixer", "*": "attn", "E": "moe"}
+    s = (
+        "netconfig = start\n"
+        "layer[0->h0] = embedding:embed\n"
+        f"  nvocab = {vocab}\n"
+        f"  nhidden = {hidden}\n"
+        # a token's own row has to stand out of the stream, as in
+        # joyai_llm_flash_conf
+        "  init_sigma = 1.0\n"
     )
-    return data + s + _tail(
-        batch_size, f"1,1,{seq_len}", num_round, eta=eta, dev=dev,
-        extra=extra, scan_steps=scan_steps,
+    for i, kind in enumerate(pattern):
+        s += layer(kind, f"h{i}", f"h{i + 1}", f"{names.get(kind, '')}{i}")
+    last = f"h{len(pattern)}"
+    s += (
+        f"layer[{last}->nf] = rms_norm:norm_f\n"
+        f"  eps = {eps!r}\n"
+        "layer[nf->logits] = lm_head:head\n"
+        f"  nhidden = {vocab}\n"
+        "  init_sigma = 0.02\n"
+        "layer[logits->logits] = softmax\n"
+        # the mean over all positions: the loss sums over T
+        f"  grad_scale = {1.0 / seq_len!r}\n"
     )
+    if num_nextn_predict_layers:
+        block = "".join(
+            layer(kind, f"mtp_h{j}", f"mtp_h{j + 1}",
+                  f"mtp_{names.get(kind, '')}{j}")
+            for j, kind in enumerate(mtp_pattern))
+        s += _mtp_module(last, hidden, eps, block,
+                         f"mtp_h{len(mtp_pattern)}", seq_len,
+                         mtp_loss_weight)
+    s += "netconfig = end\n"
+    return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
+                      compute_dtype, eta, scan_steps)
 
 
 def _res_bottleneck(prev: str, name: str, cin: int, cmid: int, cout: int,

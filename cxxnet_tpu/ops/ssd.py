@@ -24,10 +24,14 @@ product of the same kind).  Decays are kept in float32 and in log
 space until the one ``exp`` of a difference that is never positive;
 the products take the activations' dtype and accumulate in float32.
 
-``B`` and ``C`` are shared by all heads (one group, as the published
-Mamba-2 hybrids have it).  Documents: ``doc`` is a non-decreasing
-document index a token; no state, and nothing inside a chunk, crosses
-from one index to the next.
+``B`` and ``C`` are shared by the heads of a GROUP: with ``(N,T,S)``
+operands every head is in the one group (as the dense Mamba-2 hybrids
+have it, and as one tensor-parallel rank of a grouped mixer holds it);
+with ``(N,T,G,S)`` head ``h`` of ``H`` reads group ``h G // H``, and the
+scan is the one-group scan mapped over the groups — one group's program
+is bit for bit what it was before groups came.  Documents: ``doc`` is a
+non-decreasing document index a token; no state, and nothing inside a
+chunk, crosses from one index to the next.
 """
 
 from __future__ import annotations
@@ -55,9 +59,19 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
              doc: Optional[jnp.ndarray] = None,
              chunk: int = 256) -> jnp.ndarray:
     """``x (N,T,H,P)``, ``dt (N,T,H)`` float32 and positive, ``a (H,)``
-    float32 and negative, ``b``/``c (N,T,S)``, ``doc (N,T)`` int32 or
-    ``None`` (one document a row) -> ``y (N,T,H,P)`` in ``x``'s dtype."""
+    float32 and negative, ``b``/``c (N,T,S)`` or, in ``G`` groups of
+    ``H / G`` heads, ``(N,T,G,S)``, ``doc (N,T)`` int32 or ``None`` (one
+    document a row) -> ``y (N,T,H,P)`` in ``x``'s dtype."""
     n, t, h, p = x.shape
+    if b.ndim == 4:
+        g = b.shape[2]
+        by_group = jax.vmap(
+            lambda xg, dtg, ag, bg, cg: ssd_scan(xg, dtg, ag, bg, cg, doc,
+                                                 chunk),
+            in_axes=(2, 2, 0, 2, 2), out_axes=2)
+        return by_group(x.reshape(n, t, g, h // g, p),
+                        dt.reshape(n, t, g, h // g), a.reshape(g, h // g),
+                        b, c).reshape(n, t, h, p)
     s = b.shape[-1]
     q = int(chunk)
     pad = (-t) % q
@@ -128,6 +142,11 @@ def ssd_recurrence(x, dt, a, b, c, doc=None):
     the tests; nothing in the program calls it."""
     n, t, h, p = x.shape
     f32 = jnp.float32
+    if b.ndim == 4:  # head h reads group h G // H: a row a head
+        b, c = (jnp.repeat(v, h // v.shape[2], axis=2) for v in (b, c))
+    else:
+        b, c = (jnp.broadcast_to(v[:, :, None], (n, t, h, v.shape[-1]))
+                for v in (b, c))
     if doc is None:
         doc = jnp.zeros((n, t), jnp.int32)
     start = jnp.concatenate(
@@ -137,8 +156,8 @@ def ssd_recurrence(x, dt, a, b, c, doc=None):
         xt, dtt, bt, ct, st = inp
         keep = jnp.where(st[:, None], 0.0, jnp.exp(dtt * a))  # (N,H)
         state = (keep[..., None, None] * state
-                 + (dtt[..., None] * xt)[..., None] * bt[:, None, None, :])
-        return state, jnp.einsum("nhpk,nk->nhp", state, ct)
+                 + (dtt[..., None] * xt)[..., None] * bt[:, :, None, :])
+        return state, jnp.einsum("nhpk,nhk->nhp", state, ct)
 
     seq = tuple(jnp.moveaxis(v, 1, 0) for v in (
         x.astype(f32), dt.astype(f32), b.astype(f32), c.astype(f32), start))

@@ -43,6 +43,14 @@ def test_model_shapes(name):
                        qk_rope_head_dim=4, v_head_dim=8, mlp_hidden=48,
                        num_experts=8, experts_per_tok=2, expert_hidden=16,
                        shared_hidden=16, experts_held=4)
+    elif name == "nemotron_h":  # the defaults are the published widths
+        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
+                       hidden=32, pattern="ME*E", mamba_heads=4,
+                       mamba_head_dim=8, mamba_groups=2, mamba_state=8,
+                       mamba_chunk=8, attn_heads=4, attn_kv_heads=2,
+                       head_dim=16, num_experts=8, experts_per_tok=3,
+                       expert_hidden=16, latent_hidden=16, shared_hidden=24,
+                       experts_held=4, num_nextn_predict_layers=1)
     elif name.startswith("mnist") or name in ("kaggle_bowl",
                                               "transformer_lm"):
         text = builder(batch_size=4, dev="cpu")
@@ -60,7 +68,7 @@ def test_model_shapes(name):
               "kaggle_bowl": 121,
               "transformer": 10, "transformer_lm": 256,
               "granite_h": 64, "qwen3_next": 64, "joyai_llm_flash": 64,
-              "resnet50": 1000, "resnet101": 1000,
+              "nemotron_h": 64, "resnet50": 1000, "resnet101": 1000,
               "resnet152": 1000}[name]
     assert out[-1] == expect
     if name in ("resnet101", "resnet152", "vgg19"):
@@ -172,3 +180,50 @@ def test_googlenet_fuse_1x1_prediction_parity():
     p1 = t1.extract_feature(b, "top[-1]")
     np.testing.assert_allclose(np.asarray(p0), np.asarray(p1),
                                rtol=2e-4, atol=2e-5)
+
+
+#: sha256 (16 hex digits) of the conf text each token builder wrote on
+#: the commit before ``_packed_lm`` and ``_mtp_module`` were pulled out
+#: of the three (2b18f56, PR 39; taken from a ``git archive`` of it):
+#: what the accepted cell trains (``benchmarks/run.net_text`` at the
+#: configuration's ``args``, which are the defaults), the same at its
+#: ``rehearsal_args``, and every argument of the shared tail set.
+PARENT_CONF_TEXT = {
+    "granite_h": ("granite_4_0_h_micro", dict(
+        cell="0cc1133a75c753a8", rehearsal="86660b77f1a45d2e",
+        tail="c9652877e02ed91b")),
+    "qwen3_next": ("qwen3_next_80b_a3b", dict(
+        cell="e0569a494367e6ef", rehearsal="cee57487c6665880",
+        tail="cfcc76031b218ebe")),
+    "joyai_llm_flash": ("joyai_llm_flash", dict(
+        cell="6fcfc3c618b3970b", rehearsal="b0fb223ded4f0985",
+        tail="0a6ce8cf6464fc5e")),
+}
+
+
+@pytest.mark.parametrize("what", ["cell", "rehearsal", "tail"])
+@pytest.mark.parametrize("name", sorted(PARENT_CONF_TEXT))
+def test_an_accepted_token_builder_writes_the_text_it_wrote(name, what):
+    """The conf text to the byte, layers and tail (``remat``,
+    ``eval_train``, ``wd``, ``label_vec``, the updater, the iterator
+    block): the accepted cells train the conf they trained."""
+    import hashlib
+    import os
+
+    from benchmarks import run
+
+    config_name, want = PARENT_CONF_TEXT[name]
+    build = MODEL_BUILDERS[name]
+    config = run.load_json(os.path.join(
+        run.ROOT, "benchmarks", "configs", config_name + ".json"))
+    if what == "tail":
+        text = build(token_file="t.bin", batch_size=2, num_round=3,
+                     dev="cpu", compute_dtype="float32", eta=0.001,
+                     scan_steps=4)
+    elif what == "rehearsal":
+        text = run.net_text(config, dict(config["args"],
+                                         **config["rehearsal_args"]), "cpu")
+    else:
+        text = run.net_text(config, dict(config["args"]), "tpu")
+        assert text == build()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want[what]

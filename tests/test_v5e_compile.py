@@ -34,6 +34,16 @@ on-chip-measurement guide, section 2: nothing runs, no chip is needed).
   GB with the kernels for 13.60 with the row blocks (ISSUE 37's "no
   higher than 13.60" is NOT met: PERF.md section 6, PR 37).
 
+* the whole scanned step of ``nemotron_h_conf()`` at its defaults (PR
+  40: one rank's share of a Nemotron-H stage — five mixers at 16 heads
+  in one group, an attention at 4 query heads on 1 key/value head of
+  128, five latent expert layers at 8 held ``relu2`` experts, top-22 of
+  512 and the shared expert whole at 5376; 701M parameters under adam)
+  fits a chip: 11.81 GB at its fullest, held to the JoyAI step's 14.4
+  (with the prediction module too it read 15.37 and the cell leaves the
+  module out); its attention is the flash kernels, its grouped products
+  the compiler's, the held experts' matrices row-major.
+
 The topology is described inside a fixture, in this one file: only one
 process at a time may load the TPU's library.
 """
@@ -207,6 +217,53 @@ def test_the_joyai_step_fits_a_chip_with_sixteen_held_experts(one_chip):
     assert not _SCORE_BLOCK.search(text)
 
 
+def test_the_nemotron_step_fits_a_chip_at_one_rank_s_share(one_chip):
+    """``tools/compile_for_v5e.py``'s compile of the conf the builder
+    writes, from shapes alone: 8.41 GB of weights and adam's moments
+    aliased to the outputs, the rest temporaries of one 8192-token row
+    (11.81 GB live at the peak when this was written)."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from tools.compile_for_v5e import compile_step, live_at_peak_bytes
+
+    from cxxnet_tpu.models import nemotron_h_conf
+
+    compiled = compile_step(nemotron_h_conf())
+    m = compiled.memory_analysis()
+    assert abs(m.argument_size_in_bytes - 700_865_520 * 12) < 2e6
+    assert m.alias_size_in_bytes > 0.999 * m.output_size_in_bytes
+    assert live_at_peak_bytes(compiled) <= 14.4e9
+    text = compiled.as_text()
+    # the mixer's five scopes with one group as with many, the expert
+    # layer's two new ones beside the five it had
+    for scope in ("l1_mixer0)/in_proj/", "l1_mixer0)/conv/",
+                  "l1_mixer0)/scan/", "l1_mixer0)/gate_norm/",
+                  "l1_mixer0)/out_proj/", "l2_moe1)/route/",
+                  "l2_moe1)/dispatch/", "l2_moe1)/experts/",
+                  "l2_moe1)/combine/", "l2_moe1)/shared/",
+                  "l2_moe1)/latent_in/", "l2_moe1)/latent_out/",
+                  "l11_moe10)/latent_in/"):
+        assert scope in text, scope
+    assert "mtp_" not in text
+    assert 'op_name="ragged-dot-none"' in text
+    # the held experts live in the latent: (8, 1024, 2688) up, no fused
+    # half, row-major through the scan like the accepted cells'
+    assert re.search(r"f32\[8,1024,2688\]\{2,1,0", text)
+    assert not re.search(r"f32\[8,(?:1024,2688|2688,1024)\]\{1,2,0", text)
+    assert "f32[8,1024,5376]" not in text
+    # a slab of 5632 of the 180 224 (token, pick) pairs, in the latent
+    assert "bf16[5632,1024]" in text and "bf16[5632,2688]" in text
+    assert not re.search(r"bf16\[180224,(?:1024|2688|4096)\]", text)
+    # the shared expert is whole: (5376, 4096) up, no 672-column share
+    assert "f32[5376,4096]" in text and "f32[672,4096]" not in text
+    # the attention layer (4 query heads on 1 key/value head of 128) is
+    # the flash kernels: four Mosaic calls
+    calls = _mosaic_calls(text)
+    assert len(calls) == 4, [c[-60:] for c in calls]
+    assert all("attn" in c for c in calls), calls
+    assert not _SCORE_BLOCK.search(text)
+
+
 _SCORE_BLOCK = re.compile(r"f32\[[0-9,]*,512,(?:512|1024|[1-8][0-9]{3})\]")
 
 
@@ -225,7 +282,9 @@ def _mosaic_calls(text):
     # qwen3_next: 16 over 2 of width 256, partial rotary, an output gate
     dict(nhead=16, nkvhead=2, head_dim=256, qk_norm=1, rotary_dim=64,
          rope_theta=10000000.0, out_gate=1),
-], ids=["granite", "qwen3_next"])
+    # one rank's share of a Nemotron-H attention: 4 over 1 of width 128
+    dict(nhead=4, nkvhead=1, head_dim=128),
+], ids=["granite", "qwen3_next", "nemotron_h_share"])
 def test_an_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
         one_chip, cfg):
     """One ``attention`` layer on a packed row of 8192 tokens, bfloat16,
