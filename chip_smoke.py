@@ -358,6 +358,8 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
     from cxxnet_tpu.ops.gdn_fused import gated_delta_fused
     from cxxnet_tpu.ops.kernels import conv_block, int8_gemm, update_step
     from cxxnet_tpu.ops.lrn import lrn, lrn_xla
+    from cxxnet_tpu.ops.ssd import ssd_recurrence
+    from cxxnet_tpu.ops.ssd_fused import ssd_fused
     from cxxnet_tpu.ops.maxpool import maxpool_bwd_s1, maxpool_fused
     from cxxnet_tpu.updater import SGDUpdater
 
@@ -463,6 +465,19 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
                          -jax.nn.softplus(g), jax.nn.sigmoid(b), doc),)
         return with_grads(run, 5)
 
+    # -- the Mamba-2 scan at granite's head widths (64 columns on a state
+    # of 128, sixteen heads in two grid steps, chunks of 256), the same
+    # documents; a positive step and a negative rate are made of the raw
+    # operands on both sides (the rate is not differentiated: its
+    # gradient is one number a head, what is left of sums that cancel)
+    hs, qs = (2, 128) if toy else (16, 256)
+
+    def ssd(scan):
+        def run(x, dt, b, c, a):
+            return (scan(x, jax.nn.softplus(dt - 3.0), -jnp.exp(a), b, c,
+                         doc),)
+        return with_grads(run, 4)
+
     # -- the masked kernels at latent attention's two widths, four query
     # heads a key-value head, a stated scale, ~4 documents a row, against
     # ``mha`` with the same mask
@@ -539,6 +554,12 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
          tuple(arr(2, td, h, 128) for h in (hkd, hkd, 2 * hkd)) + tuple(
              arr(2, td, 2 * hkd, dtype=jnp.float32) for _ in range(2)),
          2 * BF16),
+        ("ssd_fused fwd+bwd", "ok",
+         ssd(lambda *a: ssd_fused(*a, qs, interpret=interpret)),
+         ssd(ssd_recurrence),
+         (arr(2, td, hs, 64), arr(2, td, hs, dtype=jnp.float32),
+          arr(2, td, 128, scale=0.1), arr(2, td, 128),
+          arr(hs, dtype=jnp.float32)), 2 * BF16),
         ("flash_attention masked fwd+bwd", "ok",
          masked(lambda q, k, v: flash_attention(
              q, k, v, causal=True, scale=0.07, doc=doc, block_q=512,

@@ -14,8 +14,13 @@ no sequence axis.  The layer follows the published block
                                              columns alone
     out = y W_out
 
-with the scan in its chunked form (``ops/ssd.py``: one implementation,
-plain ``jax.numpy``, differentiated by ``jax.grad``).
+with the scan in its chunked form (``ops/ssd.py``): lowered for a TPU,
+at heads of 64 or 128 columns on a state a multiple of 128 wide, whole
+chunks of 128 or 256 tokens and one group, it is two Pallas kernels with
+their own backward that keep a chunk's decay and score matrices and the
+state on the chip (``ops/ssd_fused.py``); everywhere else, and with
+``ngroup > 1``, plain ``jax.numpy`` differentiated by ``jax.grad``.  No
+key chooses: the platform the program is lowered for and the shapes do.
 
 ``mamba2`` config keys:
 
@@ -44,9 +49,18 @@ float32 at rest under mixed precision and are cast where they are
 used, inside the layer's ``jax.checkpoint`` under ``remat``, so no
 bfloat16 copy of a matrix outlives its layer.
 
+State (``aux``, carried through the step programs and read once a
+round by ``NetTrainer.count_layer_state``, as ``gated_deltanet``'s):
+``scan_tokens`` — tokens through the scan; ``scan_tokens_fused`` —
+those of them the fused kernels computed, which the branch that ran
+says for itself (``ops/ssd.ssd_scan_counted``).  uint32, wrapping: the
+reader takes differences.  The round's counters ``ssd_scan_tokens`` and
+``ssd_scan_tokens_fused`` sum them over the layers.
+
 Every stage runs under a ``jax.named_scope`` of its own (``in_proj``,
 ``conv``, ``scan``, ``gate_norm``, ``out_proj``) inside the layer's, so
-a profiler trace splits the mixer.
+a profiler trace splits the mixer; the kernels, forward and backward,
+run under ``scan``.
 """
 
 from __future__ import annotations
@@ -57,9 +71,13 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..ops.ssd import doc_index, ssd_scan
+from ..ops.ssd import doc_index, ssd_scan_counted
 from .base import Layer, Params, Shape, register
 from .sequence import Branch, _check_ids_input, rms_norm
+
+
+#: the layer's ``aux`` state: what the scan counts
+COUNTERS = ("scan_tokens", "scan_tokens_fused")
 
 
 def causal_conv(x, w, bias, doc=None):
@@ -82,6 +100,9 @@ def causal_conv(x, w, bias, doc=None):
 @register
 class Mamba2Layer(Layer, Branch):
     type_name = "mamba2"
+    #: state leaf -> the round's counter it is added to
+    #: (``NetTrainer.count_layer_state``)
+    aux_counters = {name: "ssd_" + name for name in COUNTERS}
     f32_tags = frozenset({"wmat", "conv", "conv_bias", "dt_bias", "a_log",
                           "d", "gate_norm", "wproj", "norm"})
 
@@ -151,7 +172,23 @@ class Mamba2Layer(Layer, Branch):
         out.update(self.branch_params(d))
         return out
 
+    def init_aux(self, in_shapes):
+        return {name: jnp.zeros((), jnp.uint32) for name in COUNTERS}
+
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
+        return self._run(params, inputs)[0]
+
+    def apply_stateful(self, params, aux, inputs, *, train=False, rng=None,
+                       step=None):
+        outs, fused = self._run(params, inputs)
+        tokens = jnp.uint32(inputs[0].shape[0] * inputs[0].shape[1])
+        return outs, {
+            "scan_tokens": aux["scan_tokens"] + tokens,
+            "scan_tokens_fused": aux["scan_tokens_fused"] + tokens * fused,
+        }
+
+    def _run(self, params, inputs):
+        """``([out], 1 if the fused kernels computed the scan else 0)``."""
         x0 = inputs[0]
         n, t, _ = x0.shape
         h, p, g = self.nhead, self.head_dim, self.ngroup
@@ -174,9 +211,11 @@ class Mamba2Layer(Layer, Branch):
                 b, c = (v.reshape(n, t, g, self.nstate) for v in (b, c))
         with jax.named_scope("scan"):
             dt = jax.nn.softplus(dt.astype(f32) + params["dt_bias"])
-            y = ssd_scan(x, dt, -jnp.exp(params["a_log"].astype(f32)), b, c,
-                         doc, self.chunk)
-            y = y + params["d"].astype(cdt)[:, None] * x
+            # the D skip goes with the scan: each form adds it on the
+            # view of ``x`` it works on
+            y, fused = ssd_scan_counted(
+                x, dt, -jnp.exp(params["a_log"].astype(f32)), b, c, doc,
+                self.chunk, skip=params["d"])
         with jax.named_scope("gate_norm"):
             y = y.reshape(n, t, e) * jax.nn.silu(z)
             if g > 1:  # each group's columns under a norm of their own
@@ -187,4 +226,4 @@ class Mamba2Layer(Layer, Branch):
                 y = rms_norm(y, params["gate_norm"], self.eps)
         with jax.named_scope("out_proj"):
             out = y @ params["wproj"].astype(cdt).T
-        return [self.branch_out(x0, out)]
+        return [self.branch_out(x0, out)], fused

@@ -17,25 +17,47 @@ sequence into chunks of ``chunk`` tokens and computes
 * the states that enter each chunk by a short scan over the chunks,
 * and what the entering state adds to each token of the chunk,
 
-so nearly all the work is matrix products of chunk size.  One
-implementation for the program: plain ``jax.numpy`` that XLA fuses,
-differentiated by ``jax.grad`` (the backward of every product is a
-product of the same kind).  Decays are kept in float32 and in log
-space until the one ``exp`` of a difference that is never positive;
-the products take the activations' dtype and accumulate in float32.
+so nearly all the work is matrix products of chunk size.
+
+One algorithm and one call site, ``ssd_scan``, in two forms.  Decays are
+kept in float32 and in log space until the one ``exp`` of a difference
+that is never positive; the products take the activations' dtype and
+accumulate in float32; the carried state is float32 — in both:
+
+* plain ``jax.numpy`` that XLA fuses, differentiated by ``jax.grad``
+  (``ssd_xla``; the backward of every product is a product of the same
+  kind): what runs wherever the other does not, and what the kernels
+  are tested against, with the token-by-token ``ssd_recurrence``;
+* fused Pallas kernels with their own backward (``ops/ssd_fused.py``),
+  which keep a chunk's decay and score matrices and the state on the
+  chip where the first writes each to HBM as a whole-row float32 tensor
+  (75 ms of a 480 ms step at 5% of the scan's roofline, ledger PR 40).
+
+Which runs is read from what the code can observe, and no conf key
+chooses: the platform the program is LOWERED for
+(``jax.lax.platform_dependent``: a TPU takes the kernels, also when the
+lowering host is a CPU that compiles for a described chip; everything
+else the ``jax.numpy`` form) and the shapes the kernels are written for
+(``ssd_fused.supported``: heads of 64 or 128 columns, a state that is a
+multiple of 128 wide, whole chunks of 128 or 256 tokens, one group's
+``(N,T,S)`` operands, bfloat16 or float32).  ``ssd_scan_counted`` also
+returns which branch ran, from inside the branch, for the layer's
+counter.
 
 ``B`` and ``C`` are shared by the heads of a GROUP: with ``(N,T,S)``
 operands every head is in the one group (as the dense Mamba-2 hybrids
 have it, and as one tensor-parallel rank of a grouped mixer holds it);
 with ``(N,T,G,S)`` head ``h`` of ``H`` reads group ``h G // H``, and the
-scan is the one-group scan mapped over the groups — one group's program
-is bit for bit what it was before groups came.  Documents: ``doc`` is a
+scan is the one-group ``jax.numpy`` scan mapped over the groups — one
+group's program is bit for bit what it was before groups came; the
+kernels take one group.  Documents: ``doc`` is a
 non-decreasing document index a token; no state, and nothing inside a
 chunk, crosses from one index to the next.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -62,12 +84,69 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     float32 and negative, ``b``/``c (N,T,S)`` or, in ``G`` groups of
     ``H / G`` heads, ``(N,T,G,S)``, ``doc (N,T)`` int32 or ``None`` (one
     document a row) -> ``y (N,T,H,P)`` in ``x``'s dtype."""
+    return ssd_scan_counted(x, dt, a, b, c, doc, chunk)[0]
+
+
+def ssd_scan_counted(x, dt, a, b, c, doc=None, chunk: int = 256, skip=None):
+    """``(ssd_scan's y, 1 if the fused kernels computed it else 0)``:
+    the second is a uint32 scalar each branch returns for itself, so it
+    says what ran where the program was lowered for.
+
+    ``skip (H,)`` adds the mixer's ``D`` term, ``y + skip x`` a head on
+    ``x``'s dtype: the ``jax.numpy`` form on the ``(N,T,H,P)`` view as it
+    always did, the kernels' branch on the ``(N,T,H P)`` rows they write
+    (beside a row-major kernel a per-head broadcast costs a float32 copy
+    of the whole tensor each way: PERF.md, PR 34)."""
+    from . import ssd_fused
+
+    if not ssd_fused.supported(x, b, c, chunk):
+        return _xla_branch(x, dt, a, b, c, doc, skip, chunk)
+    if doc is None:
+        doc = jnp.zeros(x.shape[:2], jnp.int32)
+    return _by_platform(x, dt, a, b, c, doc, skip, chunk=int(chunk))
+
+
+def _xla_branch(x, dt, a, b, c, doc, skip, chunk):
+    y = ssd_xla(x, dt, a, b, c, doc, chunk)
+    if skip is not None:
+        y = y + skip.astype(x.dtype)[:, None] * x
+    return y, jnp.uint32(0)
+
+
+def _fused_branch(x, dt, a, b, c, doc, skip, chunk):
+    from . import ssd_fused
+
+    y = ssd_fused.ssd_fused(x, dt, a, b, c, doc, chunk)
+    if skip is not None:
+        n, t, h, p = x.shape
+        y = (y.reshape(n, t, h * p) + jnp.repeat(skip.astype(x.dtype), p)
+             * x.reshape(n, t, h * p)).reshape(x.shape)
+    return y, jnp.uint32(1)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _by_platform(x, dt, a, b, c, doc, skip, chunk):
+    """Both forms under ``lax.platform_dependent``, in a module-level
+    ``jax.jit``: the mixers of a stack are one traced function, one
+    derivative of it and one lowering — a trace a layer of both branches
+    cost granite's nine mixers 3.6 s of set-up (PERF.md, PR 41)."""
+    return lax.platform_dependent(
+        x, dt, a, b, c, doc, skip,
+        tpu=functools.partial(_fused_branch, chunk=chunk),
+        default=functools.partial(_xla_branch, chunk=chunk))
+
+
+def ssd_xla(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
+            b: jnp.ndarray, c: jnp.ndarray,
+            doc: Optional[jnp.ndarray] = None,
+            chunk: int = 256) -> jnp.ndarray:
+    """``ssd_scan`` in plain ``jax.numpy``."""
     n, t, h, p = x.shape
     if b.ndim == 4:
         g = b.shape[2]
         by_group = jax.vmap(
-            lambda xg, dtg, ag, bg, cg: ssd_scan(xg, dtg, ag, bg, cg, doc,
-                                                 chunk),
+            lambda xg, dtg, ag, bg, cg: ssd_xla(xg, dtg, ag, bg, cg, doc,
+                                                chunk),
             in_axes=(2, 2, 0, 2, 2), out_axes=2)
         return by_group(x.reshape(n, t, g, h // g, p),
                         dt.reshape(n, t, g, h // g), a.reshape(g, h // g),

@@ -86,6 +86,32 @@ def test_gdn_scan_fused_pct_reads_the_two_scan_counters(rounds, want):
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
+# ssd_scan_fused_pct (PR 41): the mamba2 layers count the tokens through
+# their scan and those the fused kernels computed, inside the step
+# programs — a twin of the delta rule's reader
+@pytest.mark.parametrize("rounds, want", [
+    # nine mixers x 24 steps x 8192 tokens a round, every one fused
+    ([{"ssd_scan_tokens": 1769472, "ssd_scan_tokens_fused": 1769472,
+       "attn_tokens": 196608}] * 2, 100.0),
+    # a round whose programs were lowered for another platform
+    ([{"ssd_scan_tokens": 1769472, "ssd_scan_tokens_fused": 1769472},
+      {"ssd_scan_tokens": 1769472}], 50.0),
+    # the jax.numpy form: counted, none fused
+    ([{"ssd_scan_tokens": 983040, "ssd_scan_tokens_fused": 0}], 0.0),
+    ([{"ssd_scan_tokens": 983040}], 0.0),
+    # the parent counts neither; the delta rule's tokens are not these
+    ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824,
+       "tokens": 196608}], None),
+    ([{"ssd_scan_tokens": 0}], None),
+    ([{}], None),
+    ([], None),
+])
+def test_ssd_scan_fused_pct_reads_the_two_scan_counters(rounds, want):
+    read = run.load_metric("ssd_scan_fused_pct").read
+    assert read(_counted(*rounds)) == want
+    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
+
+
 # attn_flash_pct (PR 37): the masked attention layers (``attention``'s
 # masked path, ``latent_attention``) count the tokens through them and
 # those the flash kernels computed, inside the step programs
@@ -247,6 +273,7 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
     ("train_metric_device_pct", ALL_CELLS[:2], "higher"),
     ("chunk_overlap_pct", ALL_CELLS, "higher"),
     ("gdn_scan_fused_pct", ALL_CELLS[3:4], "higher"),
+    ("ssd_scan_fused_pct", [ALL_CELLS[2], ALL_CELLS[5]], "higher"),
     ("moe_latent_proj_ms_step", ALL_CELLS[5:], "lower"),
     ("latent_expert_matmul_roofline_pct", ALL_CELLS[5:], "higher"),
     ("ssd_scan_grouped_roofline_pct", ALL_CELLS[5:], "higher"),
@@ -274,10 +301,11 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
     assert names[42:46] == ["mla_ms_step", "mla_core_ms_step",
                             "mla_core_roofline_pct", "mtp_ms_step"]
     # PR 37's one behind them, PR 38's five behind that, and PR 39's
-    # one; PR 40's three, the last
+    # one; PR 40's three; PR 41's one, the last
     assert names[46:47] == ["attn_flash_pct"]
     assert names[47:52] == LOOP_BILL
     assert names[52:] == ["expert_dispatch_compact_pct",
                           "moe_latent_proj_ms_step",
                           "latent_expert_matmul_roofline_pct",
-                          "ssd_scan_grouped_roofline_pct"]
+                          "ssd_scan_grouped_roofline_pct",
+                          "ssd_scan_fused_pct"]

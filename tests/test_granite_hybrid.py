@@ -210,6 +210,33 @@ def test_the_attention_layer_counts_its_tokens_into_the_round(ref):
         "attn_tokens_flash", 0)
 
 
+def test_the_mixers_count_their_tokens_into_the_round(ref):
+    """PR 41: the scan's two counters, summed over the mixers into the
+    round's once ``count_layer_state`` reads the layers' state — every
+    token counted, none by the fused kernels off the TPU (and at these
+    widths on none)."""
+    from cxxnet_tpu.utils.profiler import pipeline_stats
+
+    text = granite_h_conf(seq_len=48, batch_size=2, layer_types="mam",
+                          compute_dtype="float32", **SMALL)
+    tr, net = _trainer(text, ref, 5, 2)
+    keys = [k for k, v in tr.aux.items() if "scan_tokens" in v]
+    assert len(keys) == 2
+    for key in keys:
+        assert set(tr.aux[key]) == {"scan_tokens", "scan_tokens_fused"}
+    stats = pipeline_stats()
+    before = dict(stats.counters())
+    data, labels = _rows(ref, net, 3, 4)
+    tr.update_scan(data, labels, sync=True)
+    tr.count_layer_state()
+    got = stats.counters()
+    # 4 steps x 2 rows x 48 tokens, two mixers
+    assert got["ssd_scan_tokens"] - before.get("ssd_scan_tokens", 0) == (
+        2 * 4 * 2 * 48)
+    assert got.get("ssd_scan_tokens_fused", 0) == before.get(
+        "ssd_scan_tokens_fused", 0)
+
+
 def test_a_bfloat16_run_fails_the_float32_tolerances(ref):
     """So the tolerances above would catch a lower precision."""
     text = granite_h_conf(seq_len=48, batch_size=2, layer_types="mam",

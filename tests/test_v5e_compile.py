@@ -44,6 +44,15 @@ on-chip-measurement guide, section 2: nothing runs, no chip is needed).
   module out); its attention is the flash kernels, its grouped products
   the compiler's, the held experts' matrices row-major.
 
+* lowered for a TPU, a ``mamba2`` layer's scan IS the fused kernels of
+  ``ops/ssd_fused.py`` (PR 41) at both cells' shapes — granite's 64
+  heads at chunks of 256, one Nemotron-H rank's 16 at chunks of 128:
+  ``ssd_scan`` (forward and the ``remat`` recompute) and ``ssd_scan_bwd``
+  under the layer's ``scan`` scope, and no ``(…, Q, Q)`` float32 decay or
+  score tensor of the ``jax.numpy`` form is left; the whole granite step
+  (ten layers under adam, 772M parameters) holds no more at its fullest
+  than the parent's 15.06 GB: 14.65.
+
 The topology is described inside a fixture, in this one file: only one
 process at a time may load the TPU's library.
 """
@@ -257,11 +266,91 @@ def test_the_nemotron_step_fits_a_chip_at_one_rank_s_share(one_chip):
     # the shared expert is whole: (5376, 4096) up, no 672-column share
     assert "f32[5376,4096]" in text and "f32[672,4096]" not in text
     # the attention layer (4 query heads on 1 key/value head of 128) is
-    # the flash kernels: four Mosaic calls
+    # the flash kernels: four Mosaic calls; since PR 41 the five mixers'
+    # scans are the kernels of ops/ssd_fused.py (forward, recompute,
+    # backward), billed to their scan scopes
     calls = _mosaic_calls(text)
-    assert len(calls) == 4, [c[-60:] for c in calls]
-    assert all("attn" in c for c in calls), calls
+    ssd = [c for c in calls if "/ssd_scan" in c]
+    assert len(ssd) == 15 and len(calls) == 19, [c[-60:] for c in calls]
+    assert all("mixer" in c and "/scan/" in c for c in ssd), ssd
+    assert all("attn" in c for c in calls if c not in ssd), calls
     assert not _SCORE_BLOCK.search(text)
+
+
+@pytest.mark.parametrize("cfg, d", [
+    (dict(nhead=64, head_dim=64, nstate=128, chunk=256), 2048),
+    (dict(nhead=16, head_dim=64, nstate=128, chunk=128), 4096),
+], ids=["granite", "nemotron_h_share"])
+def test_a_mamba2_layer_lowered_for_a_tpu_is_the_fused_kernels(one_chip, cfg,
+                                                               d):
+    """One ``mamba2`` layer on a packed row of 8192 tokens, bfloat16,
+    under ``remat`` as the step programs run it."""
+    from cxxnet_tpu.layers import create_layer
+
+    lay = create_layer("mamba2")
+    for k, v in dict(cfg, prenorm=1, residual_scale=0.22).items():
+        lay.set_param(k, str(v))
+    shapes = [(1, 8192, d), (1, 8192)]
+    lay.infer_shape(shapes)
+    params = jax.eval_shape(lambda k: lay.init_params(k, shapes),
+                            jax.random.PRNGKey(0))
+    aux = jax.eval_shape(lambda: lay.init_aux(shapes))
+
+    def loss(p, aux, x, ids):
+        def run(p, x):
+            with jax.named_scope("l1_mixer0"):
+                (y,), new = lay.apply_stateful(p, aux, [x, ids])
+            return jnp.sum(y.astype(jnp.float32)), new
+        return jax.checkpoint(run)(p, x)
+
+    shaped = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda v: _shaped(one_chip, v.shape, v.dtype), t)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 2), has_aux=True)
+                       ).lower(
+        shaped(params), shaped(aux), _shaped(one_chip, shapes[0]),
+        _shaped(one_chip, shapes[1], jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = _mosaic_calls(text)
+    assert sorted(c.split("/")[-2] for c in calls) == [
+        "ssd_scan", "ssd_scan", "ssd_scan_bwd"], calls
+    # forward, recompute and backward alike are billed to the layer's
+    # scan scope
+    assert all("l1_mixer0" in c and "/scan/" in c for c in calls), calls
+    (bwd,) = [c for c in calls if "ssd_scan_bwd" in c]
+    assert "transpose(" in bwd and "rematted_computation" not in bwd
+    # none of the jax.numpy form's whole-row chunk tensors is left
+    # ((chunks, heads, Q, Q) float32: diff, exp(diff), m — the parent's
+    # compile holds 22 fusions that write or read one); what the
+    # kernels keep for the backward is the state that entered each chunk,
+    # a unit of two heads side by side
+    q, h = cfg["chunk"], cfg["nhead"]
+    nc = 8192 // q
+    assert not re.search(
+        rf"(?:f32|bf16)\[(?:1,)?(?:{nc},{h}|{h},{nc}),{q},{q}\]", text)
+    assert f"f32[1,{h // 2},{nc},128,128]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_the_granite_step_holds_no_more_than_the_parent_s(one_chip):
+    """The builder's defaults are the cell's conf, compiled as the CLI
+    compiles it: the parent's step (the ``jax.numpy`` scan) read 15.054
+    GB live at its fullest, the kernels' 14.650 (PR 41) — the float32
+    chunk tensors of one layer's backward are gone."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from tools.compile_for_v5e import compile_step, live_at_peak_bytes
+
+    from cxxnet_tpu.models import granite_h_conf
+
+    compiled = compile_step(granite_h_conf())
+    assert live_at_peak_bytes(compiled) <= 15.06e9
+    calls = _mosaic_calls(compiled.as_text())
+    # nine mixers x (forward, recompute, backward) and the attention
+    # layer's four flash kernels
+    assert sum("/ssd_scan/" in c for c in calls) == 18
+    assert sum("/ssd_scan_bwd/" in c for c in calls) == 9
+    assert len(calls) == 31
+    assert all("/scan/" in c for c in calls if "ssd_scan" in c)
 
 
 _SCORE_BLOCK = re.compile(r"f32\[[0-9,]*,512,(?:512|1024|[1-8][0-9]{3})\]")
