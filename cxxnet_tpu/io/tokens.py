@@ -18,6 +18,9 @@ themselves (``ops/ssd.doc_index``), so the batch stays two arrays.
 * ``batch_size``
 * ``shuffle`` / ``seed_data`` — one-shot shuffle of the rows
 * ``round_batch`` — 1 wraps a short last batch (flagged as padding)
+* ``attn_window`` — W > 0: the net's sliding-window attention layers'
+  ``window``; the feed then counts ``attn_window_pairs`` too (default
+  0: not counted)
 * ``dist_num_worker`` / ``dist_worker_rank`` — equal-truncated row
   sharding (``data.shard_rows``)
 
@@ -31,8 +34,11 @@ document running there is cut by the row's end) and ``attn_pairs`` (the
 (query, key) pairs a causal query of its own document may see: the sum
 over a row's documents of ``L (L + 1) / 2``, a document beginning at a
 row's first token and after every separator as ``ops/ssd.doc_index``
-has it — the work of masked attention whatever computes it); its own
-work is billed to the ``batch`` stage.
+has it — the work of masked attention whatever computes it) and, with
+``attn_window = W``, ``attn_window_pairs`` (those of them less than W
+positions apart, what a windowed layer's query may see: a document of
+``L <= W`` gives ``L (L + 1) / 2``, a longer one ``W (W + 1) / 2 + (L -
+W) W``); its own work is billed to the ``batch`` stage.
 """
 
 from __future__ import annotations
@@ -49,16 +55,26 @@ from .data import DataBatch, DataIter
 SEP_ID = 0  # closes a document; the layers read starts from it (ops/ssd.py)
 
 
-def attn_pairs(rows: np.ndarray) -> int:
-    """(query, key) pairs of ``rows (N, T)`` of ids under a causal mask
-    inside documents: a document of ``L`` tokens (its closing separator
-    among them; the row's end cuts the last) has ``L (L + 1) / 2``."""
+def doc_lengths(rows: np.ndarray) -> np.ndarray:
+    """The lengths of the documents of ``rows (N, T)`` of ids, a row's
+    closing separators among them; the row's end cuts the last."""
     # a row's documents end at its separators and at its last token, so
     # in the flat order every document begins where the last one ended
     ends = rows == SEP_ID
     ends[:, -1] = True
-    length = np.diff(np.flatnonzero(ends), prepend=-1)
-    return int((length * (length + 1) // 2).sum())
+    return np.diff(np.flatnonzero(ends), prepend=-1)
+
+
+def attn_pairs(rows: np.ndarray, window: int = 0, lengths=None) -> int:
+    """(query, key) pairs of ``rows (N, T)`` of ids under a causal mask
+    inside documents (``lengths``: their ``doc_lengths``, where the
+    caller has them): a document of ``L`` tokens has ``L (L + 1) / 2`` —
+    under a ``window`` W > 0 (a query sees itself and the W - 1 before)
+    the first ``min(L, W)`` queries see that triangle and every later
+    one W keys."""
+    length = doc_lengths(rows) if lengths is None else lengths
+    head = np.minimum(length, window) if window > 0 else length
+    return int((head * (head + 1) // 2 + (length - head) * window).sum())
 
 
 class TokenIterator(DataIter):
@@ -76,6 +92,7 @@ class TokenIterator(DataIter):
         self.dist_num_worker = 1
         self.dist_worker_rank = 0
         self.round_batch = 1
+        self.attn_window = 0
         self._retry_cfg: list = []
         self._raw: np.ndarray | None = None
         self._rows: np.ndarray | None = None
@@ -87,7 +104,7 @@ class TokenIterator(DataIter):
             self.filename = val
         elif name in ("token_bytes", "seq_len", "batch_size",
                       "shuffle", "silent", "dist_num_worker",
-                      "dist_worker_rank", "round_batch"):
+                      "dist_worker_rank", "round_batch", "attn_window"):
             setattr(self, name, int(val))
         elif name == "seed_data":
             self.seed = int(val)
@@ -158,7 +175,11 @@ class TokenIterator(DataIter):
         stats.count("tokens", fed.size)
         stats.count("docs", int((fed == SEP_ID).sum()))
         stats.count("docs_cut", int((fed[:, -1] != SEP_ID).sum()))
-        stats.count("attn_pairs", attn_pairs(fed))
+        lengths = doc_lengths(fed)
+        stats.count("attn_pairs", attn_pairs(fed, lengths=lengths))
+        if self.attn_window > 0:
+            stats.count("attn_window_pairs",
+                        attn_pairs(fed, self.attn_window, lengths))
         win = win.astype(np.float32)
         batch = DataBatch(
             data=win[:, :-1],

@@ -115,7 +115,7 @@ class GatedDeltaNetLayer(Layer, Branch):
     #: (``NetTrainer.count_layer_state``)
     aux_counters = {name: "gdn_" + name for name in COUNTERS}
     f32_tags = frozenset({"wmat", "wba", "conv", "dt_bias", "a_log",
-                          "gate_norm", "wproj", "norm"})
+                          "gate_norm", "wproj", "norm", "postnorm"})
 
     def __init__(self) -> None:
         super().__init__()
@@ -230,4 +230,4 @@ class GatedDeltaNetLayer(Layer, Branch):
                 n, t, ev) * jax.nn.silu(z)
         with jax.named_scope("out_proj"):
             out = y @ params["wproj"].astype(cdt).T
-        return [self.branch_out(x0, out)], fused
+        return [self.branch_out(params, x0, out)], fused

@@ -8,9 +8,10 @@ Parity sources:
   Shazeer 2020 ("GLU Variants Improve Transformer") with silu,
   ``(silu(x W_g) * (x W_v)) W_2``.  ``wmat`` is ``[W_g; W_v]`` fused,
   ``(2 * nhidden, nin)``, ``wproj`` is ``(nin, nhidden)``; no biases.
-  ``prenorm`` / ``residual_scale`` / ``eps`` keep the residual branch
-  in the one layer (``sequence.Branch``), so that under ``remat`` the
-  ``2 * nhidden``-wide activation is recomputed and never kept
+  ``prenorm`` / ``postnorm`` / ``residual_scale`` / ``eps`` keep the
+  residual branch in the one layer (``sequence.Branch``), so that under
+  ``remat`` the ``2 * nhidden``-wide activation is recomputed and never
+  kept
 * fixconn — ``/root/reference/src/layer/fixconn_layer-inl.hpp`` (frozen
   sparse weight loaded from a ``nrow ncol nnz`` + ``row col val`` text
   file; never updated)
@@ -71,7 +72,7 @@ class FullConnectLayer(Layer):
 class GatedMLPLayer(Layer, Branch):
     type_name = "gated_mlp"
     # cast where they are used, inside the layer's checkpoint
-    f32_tags = frozenset({"wmat", "wproj", "norm"})
+    f32_tags = frozenset({"wmat", "wproj", "norm", "postnorm"})
 
     def set_param(self, name, val):
         if not self.set_branch_param(name, val):
@@ -101,7 +102,7 @@ class GatedMLPLayer(Layer, Branch):
         gv = self.branch_in(params, x) @ params["wmat"].astype(x.dtype).T
         y = (jax.nn.silu(gv[..., :nh]) * gv[..., nh:]) @ params[
             "wproj"].astype(x.dtype).T
-        return [self.branch_out(x, y)]
+        return [self.branch_out(params, x, y)]
 
 
 @register

@@ -87,7 +87,7 @@ class LatentAttentionLayer(Layer, Branch):
     #: state leaf -> the round's counter it is added to
     aux_counters = {name: name for name in ATTN_COUNTERS}
     f32_tags = frozenset({"wqa", "q_norm", "wqb", "wkva", "kv_norm", "wkvb",
-                          "wproj", "norm"})
+                          "wproj", "norm", "postnorm"})
 
     #: every one must be set positive
     _INT_KEYS = ("nhead", "q_rank", "kv_rank", "nope_dim", "rope_dim",
@@ -188,4 +188,4 @@ class LatentAttentionLayer(Layer, Branch):
                               doc=doc)
         with jax.named_scope("out_proj"):
             out = o.reshape(n, t, h * dv) @ params["wproj"].astype(cdt).T
-        return [self.branch_out(x0, out)], flash
+        return [self.branch_out(params, x0, out)], flash

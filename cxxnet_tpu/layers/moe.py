@@ -96,8 +96,13 @@ With ``nheld = nexpert`` (or ``SLAB_FACTOR`` x the share >= 1) ``C`` is
 * ``expert_act`` — ``swiglu`` (default) or ``relu2``, for the held
   experts and the shared one alike; ``latent_hidden`` (default 0: none)
   — the width ``L`` the held experts read and write
-* ``prenorm`` / ``residual_scale`` / ``eps`` — the residual branch in
-  one layer (``sequence.Branch``); ``init_sigma`` for every matrix
+* ``prenorm`` / ``postnorm`` / ``residual_scale`` / ``eps`` — the
+  residual branch in one layer (``sequence.Branch``); with ``postnorm``
+  the layer's output — in a share the PARTIAL sum of the held experts
+  and the shared one — goes through an ``rms_norm`` of its own before
+  the residual add, as it is: that norm is not linear, so the ranks'
+  parts add up to the whole layer's before it, not after;
+  ``init_sigma`` for every matrix
 
 Parameters (tags), with ``W`` the experts' width — ``latent_hidden``, or
 D without — and ``c`` = 2 (``swiglu``: gate | up fused) or 1
@@ -116,7 +121,7 @@ a shared expert
 shared_hidden), ``shared_gate`` (1, D) unless ``shared_gate = 0``;
 ``score_bias`` (nexpert,) with ``select_bias``, started at 0;
 ``latent_in`` (L, D) and ``latent_out`` (D, L) with ``latent_hidden``;
-``norm`` (D) with ``prenorm``.
+``norm`` (D) with ``prenorm``, ``postnorm`` (D) with ``postnorm``.
 All float32 at rest, cast where used; the router's product is float32
 at the highest precision.
 
@@ -372,7 +377,7 @@ class RoutedExpertsLayer(Layer, Branch):
     aux_counters = {name: "expert_" + name for name in COUNTERS}
     f32_tags = frozenset({"wgate", "wmat", "wproj", "shared_wmat",
                           "shared_wproj", "shared_gate", "score_bias",
-                          "latent_in", "latent_out", "norm"})
+                          "latent_in", "latent_out", "norm", "postnorm"})
 
     def __init__(self) -> None:
         super().__init__()
@@ -524,4 +529,4 @@ class RoutedExpertsLayer(Layer, Branch):
                     s = jax.nn.sigmoid(
                         x @ params["shared_gate"].astype(cdt).T) * s
                 y = y + s
-        return [self.branch_out(x0, y.reshape(x0.shape))], counts
+        return [self.branch_out(params, x0, y.reshape(x0.shape))], counts

@@ -31,9 +31,18 @@ layout, NHWC-style batch-major nodes).  Sequence nodes are ``(N, T, D)``
   separator id 0, ``ops/ssd.doc_index``).  No positional encoding is
   added anywhere: a net without ``pos`` on its embedding is
   position-free
-* ``prenorm`` / ``residual_scale`` / ``eps`` — the residual branch in
-  one layer, ``y = x + residual_scale * f(rms_norm(x))``, as ``mamba2``
-  and ``gated_mlp`` have it (``Branch`` below)
+* ``window`` — W > 0: a query sees the keys of its own document less
+  than W positions before it, itself among them (``i - j < W``; the
+  sliding-window layers of a local/global stack; default 0: none).  In
+  the masked path only: the window is inside the flash kernels
+  (``ops/flash.py``) and ``mha``'s row blocks; ``decode``,
+  ``seq_parallel`` and position offsets have no windowed path (ROADMAP
+  R3)
+* ``prenorm`` / ``postnorm`` / ``residual_scale`` / ``eps`` — the
+  residual branch in one layer, ``y = x + residual_scale *
+  f(rms_norm(x))``, as ``mamba2`` and ``gated_mlp`` have it; with
+  ``postnorm = 1`` a sandwich, ``y = x + residual_scale *
+  rms_norm(f(rms_norm(x)))`` (``Branch`` below)
 * ``seq_parallel`` — sequence/context parallelism over the mesh's
   ``model`` axis (``ops/attention.py``; off the mesh, or with a model
   axis of 1, both fall back to plain attention):
@@ -48,12 +57,15 @@ layout, NHWC-style batch-major nodes).  Sequence nodes are ``(N, T, D)``
     n kv hops — usually cheaper when heads divide the axis.
 
 The masked path (any of ``nkvhead``, ``score_scale``, ``head_dim``,
-``qk_norm``, ``rotary_dim``, ``out_gate``, ``no_bias``, the ids input)
+``qk_norm``, ``rotary_dim``, ``out_gate``, ``no_bias``, ``window``, the
+ids input)
 goes through ``ops/attention.attend``, the one chooser
 ``latent_attention`` uses too: lowered for a TPU a long row runs the
-flash kernels of ``ops/flash.py`` (document mask with whole blocks
-skipped, grouped heads by the index map, the stated scale), everywhere
-else ``ops/attention.mha``.  Such a layer counts in its ``aux`` state
+flash kernels of ``ops/flash.py`` (document mask and window with whole
+blocks skipped, grouped heads by the index map, the stated scale),
+everywhere else ``ops/attention.mha``; the call sits under a scope of
+its own inside the layer's, ``core_window`` on a windowed layer and
+``core_full`` on any other.  Such a layer counts in its ``aux`` state
 (``ATTN_COUNTERS``, read once a round by
 ``NetTrainer.count_layer_state``) ``attn_tokens``, the tokens through
 it, and ``attn_tokens_flash``, those of them the kernels computed — the
@@ -107,19 +119,22 @@ class Branch:
     """The keys of a residual branch kept in ONE layer, for the layer
     types a pre-norm residual net is made of (``mamba2``, ``attention``,
     ``gated_mlp``): ``prenorm = 1`` norms the input with an ``rms_norm``
-    of the layer's own (tag ``norm``, ``eps``), ``residual_scale = r``
-    returns ``x + r * f(...)`` and not ``f(...)`` alone.  Under
+    of the layer's own (tag ``norm``, ``eps``), ``postnorm = 1`` the
+    branch's output with another (tag ``postnorm``: the sandwich norms
+    of the afmoe family, inside the residual add), ``residual_scale =
+    r`` returns ``x + r * f(...)`` and not ``f(...)`` alone.  Under
     ``remat = 1`` a conf layer is one ``jax.checkpoint``: a branch in
     one layer keeps one ``(N, T, D)`` input alive for the backward, not
     the norm's, the mixer's and the sum's."""
 
     prenorm = 0
+    postnorm = 0
     residual_scale = 0.0
     eps = 1e-5
 
     def set_branch_param(self, name: str, val: str) -> bool:
-        if name == "prenorm":
-            self.prenorm = int(val)
+        if name in ("prenorm", "postnorm"):
+            setattr(self, name, int(val))
         elif name == "residual_scale":
             self.residual_scale = float(val)
         elif name == "eps":
@@ -129,12 +144,16 @@ class Branch:
         return True
 
     def branch_params(self, d: int) -> Params:
-        return {"norm": jnp.ones((d,), jnp.float32)} if self.prenorm else {}
+        return {tag: jnp.ones((d,), jnp.float32)
+                for tag, on in (("norm", self.prenorm),
+                                ("postnorm", self.postnorm)) if on}
 
     def branch_in(self, params, x):
         return rms_norm(x, params["norm"], self.eps) if self.prenorm else x
 
-    def branch_out(self, x, y):
+    def branch_out(self, params, x, y):
+        if self.postnorm:
+            y = rms_norm(y, params["postnorm"], self.eps)
         if not self.residual_scale:
             return y
         return x + jnp.asarray(self.residual_scale, y.dtype) * y
@@ -231,7 +250,7 @@ class AttentionLayer(Layer, Branch):
     type_name = "attention"
     #: state leaf -> the round's counter it is added to (the masked path)
     aux_counters = {name: name for name in ATTN_COUNTERS}
-    f32_tags = frozenset({"norm", "q_norm", "k_norm"})
+    f32_tags = frozenset({"norm", "postnorm", "q_norm", "k_norm"})
 
     def __init__(self) -> None:
         super().__init__()
@@ -243,6 +262,7 @@ class AttentionLayer(Layer, Branch):
         self.rotary_dim = 0
         self.rope_theta = 10000.0
         self.out_gate = 0
+        self.window = 0  # 0: every key of the document
         self.causal = 0
         self.seq_parallel = 0
         self.attn_impl = "auto"
@@ -260,7 +280,8 @@ class AttentionLayer(Layer, Branch):
             self.nkvhead = int(val)
         elif name == "score_scale":
             self.scale = float(val)
-        elif name in ("head_dim", "qk_norm", "rotary_dim", "out_gate"):
+        elif name in ("head_dim", "qk_norm", "rotary_dim", "out_gate",
+                      "window"):
             setattr(self, name, int(val))
         elif name == "rope_theta":
             self.rope_theta = float(val)
@@ -407,7 +428,7 @@ class AttentionLayer(Layer, Branch):
             y, flash = self._apply_masked(
                 params, self.branch_in(params, inputs[0]),
                 inputs[1] if len(inputs) > 1 else None)
-            return ([self.branch_out(inputs[0], y)],
+            return ([self.branch_out(params, inputs[0], y)],
                     count_attention(aux, inputs[0], flash))
         x = inputs[0]
         n, t, d = x.shape
@@ -447,7 +468,7 @@ class AttentionLayer(Layer, Branch):
         return (n_in == 1 and not self.scale and not self.param.no_bias
                 and self.nkvhead in (0, self.nhead)
                 and not (self.head_dim or self.qk_norm or self.rotary_dim
-                         or self.out_gate))
+                         or self.out_gate or self.window))
 
     def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
         _check_ids_input("attention", in_shapes)
@@ -472,14 +493,31 @@ class AttentionLayer(Layer, Branch):
                 f"attention: nkvhead={self.nkvhead} must divide "
                 f"nhead={self.nhead}"
             )
+        if self.window < 0:
+            raise ValueError(
+                f"attention: window={self.window} counts the keys a query "
+                "sees back from itself, 0 for all")
+        if self.window and self.decode:
+            raise ValueError(
+                f"attention: window = {self.window} with decode = 1: the "
+                "KV cache (nnet/generate.py) has one shape, every past "
+                "position; a window's and a full layer's caches side by "
+                "side are ROADMAP R3")
+        if self.window and self.seq_parallel:
+            raise ValueError(
+                f"attention: window = {self.window} with seq_parallel: the "
+                "ring hops and the all-to-all know no window and no "
+                "position offsets under one (ops/flash.py refuses them); "
+                "the masked and windowed path across chips is ROADMAP R3")
         if not self._plain(len(in_shapes)) and (
                 self.seq_parallel or self.decode
                 or self.attn_impl == "pallas"):
             raise ValueError(
                 "attention: nkvhead, score_scale, no_bias, head_dim, "
-                "qk_norm, rotary_dim, out_gate and a document input run the "
-                "masked path, which chooses between the flash kernels and "
-                "the XLA row blocks itself (ops/attention.attend); "
+                "qk_norm, rotary_dim, out_gate, window and a document input "
+                "run the masked path, which chooses between the flash "
+                "kernels and the XLA row blocks itself "
+                "(ops/attention.attend); "
                 "seq_parallel and decode know none of them, and "
                 "attn_impl = pallas forces the plain layer's kernel only"
             )
@@ -555,8 +593,10 @@ class AttentionLayer(Layer, Branch):
                 pos = doc_positions(doc, n, t)
                 q = rotary(q, pos, self.rotary_dim, self.rope_theta)
                 k = rotary(k, pos, self.rotary_dim, self.rope_theta)
-        o, flash = attend(q, k, v, causal=bool(self.causal),
-                          scale=self.scale or None, doc=doc)
+        with jax.named_scope("core_window" if self.window else "core_full"):
+            o, flash = attend(q, k, v, causal=bool(self.causal),
+                              scale=self.scale or None, doc=doc,
+                              window=self.window)
         o = o.reshape(n, t, nq)
         if gate is not None:
             o = o * jax.nn.sigmoid(gate)
@@ -572,7 +612,7 @@ class AttentionLayer(Layer, Branch):
         else:
             y, _ = self._apply_masked(
                 params, x, inputs[1] if len(inputs) > 1 else None)
-        return [self.branch_out(inputs[0], y)]
+        return [self.branch_out(params, inputs[0], y)]
 
     def _apply_plain(self, params, x):
         from ..ops.attention import ring_self_attention

@@ -104,7 +104,7 @@ class Mamba2Layer(Layer, Branch):
     #: (``NetTrainer.count_layer_state``)
     aux_counters = {name: "ssd_" + name for name in COUNTERS}
     f32_tags = frozenset({"wmat", "conv", "conv_bias", "dt_bias", "a_log",
-                          "d", "gate_norm", "wproj", "norm"})
+                          "d", "gate_norm", "wproj", "norm", "postnorm"})
 
     def __init__(self) -> None:
         super().__init__()
@@ -226,4 +226,4 @@ class Mamba2Layer(Layer, Branch):
                 y = rms_norm(y, params["gate_norm"], self.eps)
         with jax.named_scope("out_proj"):
             out = y @ params["wproj"].astype(cdt).T
-        return [self.branch_out(x0, out)], fused
+        return [self.branch_out(params, x0, out)], fused

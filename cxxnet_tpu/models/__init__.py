@@ -17,6 +17,7 @@ Parity sources (structure, hyper-parameters, schedules):
 """
 
 from .builders import (  # noqa: F401
+    afmoe_conf,
     alexnet_conf,
     googlenet_conf,
     granite_h_conf,
@@ -52,4 +53,5 @@ MODEL_BUILDERS = {
     "qwen3_next": qwen3_next_conf,
     "joyai_llm_flash": joyai_llm_flash_conf,
     "nemotron_h": nemotron_h_conf,
+    "afmoe": afmoe_conf,
 }

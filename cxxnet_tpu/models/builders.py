@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import List
 
 
@@ -450,12 +451,13 @@ GRANITE_H_PERIOD = "mmmmmammmm"
 
 def _packed_lm(net: str, token_file: str, seq_len: int, batch_size: int,
                num_round: int, dev: str, compute_dtype: str, eta: float,
-               scan_steps: int) -> str:
+               scan_steps: int, feed: str = "") -> str:
     """The conf of a language model on packed token rows around its
     ``netconfig`` block ``net``: the ``iter = tokens`` feed (where a
     file is named) and the run settings the token builders share — a
     label a position, logloss, adam without decay, ``remat = 1`` and
-    ``eval_train = 0`` (written for memory: ``granite_h_conf``)."""
+    ``eval_train = 0`` (written for memory: ``granite_h_conf``).
+    ``feed``: further lines of the iterator's block."""
     data = ""
     if token_file:
         data = (
@@ -463,6 +465,7 @@ def _packed_lm(net: str, token_file: str, seq_len: int, batch_size: int,
             "iter = tokens\n"
             f"  filename = {token_file}\n"
             f"  seq_len = {seq_len}\n"
+            + feed +
             "iter = end\n"
         )
     extra = (
@@ -886,6 +889,145 @@ def joyai_llm_flash_conf(
     s += "netconfig = end\n"
     return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
                       compute_dtype, eta, scan_steps)
+
+
+AFMOE_STAGE = "ssssf"
+
+
+def afmoe_conf(
+    vocab: int = 25024,
+    seq_len: int = 16384,
+    hidden: int = 2048,
+    layer_types: str = AFMOE_STAGE,
+    num_dense_layers: int = 1,
+    sliding_window: int = 2048,
+    attn_heads: int = 32,
+    attn_kv_heads: int = 4,
+    head_dim: int = 128,
+    rope_theta: float = 10000.0,
+    mlp_hidden: int = 6144,
+    num_experts: int = 128,
+    experts_per_tok: int = 8,
+    expert_hidden: int = 1024,
+    shared_hidden: int = 1024,
+    route_scale: float = 2.826,
+    first_expert: int = 0,
+    experts_held: int = 8,
+    mup_enabled: int = 1,
+    eps: float = 1e-5,
+    token_file: str = "",
+    batch_size: int = 1,
+    num_round: int = 10,
+    dev: str = "tpu",
+    compute_dtype: str = "bfloat16",
+    eta: float = 0.0003,
+    scan_steps: int = 8,
+) -> str:
+    """An AFMoE style language model (arcee-ai, ``model_type: afmoe``,
+    the Trinity family): every layer a grouped-query attention with q/k
+    norms and an element-wise sigmoid output gate — on a SLIDING layer
+    (``s``) under a window of ``sliding_window`` keys with rotate-half
+    rotary positions over the whole head, on a FULL layer (``f``) over
+    the whole document with no positions at all — then, in the first
+    ``num_dense_layers`` layers, a dense gated MLP of ``mlp_hidden`` and
+    in the others ``num_experts`` SwiGLU experts of ``expert_hidden``
+    behind a sigmoid router that chooses its top-``experts_per_tok`` by
+    score + bias and weighs them by the unbiased scores, renormalised
+    and times ``route_scale``, plus one ungated shared expert.  Both
+    parts are SANDWICHED: ``x + rms_norm(f(rms_norm(x)))``, four norms a
+    layer (``prenorm`` and ``postnorm`` of ``sequence.Branch``).  With
+    ``mup_enabled`` the embedding's rows are multiplied by
+    ``sqrt(hidden)``; an untied head.
+
+    The defaults are the published widths of Trinity-Mini (26B-A3B),
+    five layers deep — its layer 0 (dense, sliding) and its layers 4-7
+    (experts: sliding, sliding, sliding, full; one whole period of
+    ``layer_types``) — with ONE RANK'S SHARE of a 16-way expert-parallel
+    layout: ``experts_held`` = 8 of the 128 experts of every layer from
+    ``first_expert`` on (the router still ranks all 128;
+    ``layers/moe.py``), over an eighth of the vocabulary: 504.1M
+    parameters (with 16 held the scanned step compiled to 14.97 GB live
+    for a described v5e, over the 14.4 GB the token cells are held to:
+    ``benchmarks/configs/trinity_mini.json``).  In a share the routing
+    weights are constants of the backward pass and the selection bias
+    gets no gradient anywhere, as in ``joyai_llm_flash_conf``; the
+    embedding starts at normal(0, 1) as there, the other matrices at
+    0.02.
+
+    Rows of ``seq_len`` = 16384 packed tokens (``iter = tokens``); with
+    a ``token_file`` the feed is told the window (``attn_window``) and
+    counts ``attn_window_pairs`` beside ``attn_pairs``.  Written for
+    memory as ``granite_h_conf`` is; documents and positions as
+    ``qwen3_next_conf``.
+    """
+    if not 0 <= num_dense_layers <= len(layer_types):
+        raise ValueError("afmoe_conf: num_dense_layers counts leading "
+                         "layers of layer_types")
+    branch = (f"  prenorm = 1\n  postnorm = 1\n  eps = {eps!r}\n"
+              "  residual_scale = 1.0\n"
+              "  init_sigma = 0.02\n")
+    s = (
+        "netconfig = start\n"
+        "layer[0->h0] = embedding:embed\n"
+        f"  nvocab = {vocab}\n"
+        f"  nhidden = {hidden}\n"
+        + (f"  multiplier = {math.sqrt(hidden)!r}\n" if mup_enabled else "")
+        # a token's own row has to stand out of the stream
+        # (joyai_llm_flash_conf)
+        + "  init_sigma = 1.0\n"
+    )
+    for i, kind in enumerate(layer_types):
+        if kind not in "sf":
+            raise ValueError(
+                f"afmoe_conf: layer_types is a string of s and f, got "
+                f"{kind!r}")
+        s += (
+            f"layer[h{i},0->x{i}] = attention:attn{i}\n"
+            f"  nhead = {attn_heads}\n"
+            f"  nkvhead = {attn_kv_heads}\n"
+            f"  head_dim = {head_dim}\n"
+            "  qk_norm = 1\n"
+            + (f"  window = {sliding_window}\n"
+               f"  rotary_dim = {head_dim}\n"
+               f"  rope_theta = {rope_theta!r}\n" if kind == "s" else "")
+            + "  out_gate = 1\n"
+            "  causal = 1\n  no_bias = 1\n" + branch
+        )
+        if i < num_dense_layers:
+            s += (
+                f"layer[x{i}->h{i + 1}] = gated_mlp:mlp{i}\n"
+                f"  nhidden = {mlp_hidden}\n" + branch
+            )
+        else:
+            s += (
+                f"layer[x{i}->h{i + 1}] = routed_experts:moe{i}\n"
+                f"  nexpert = {num_experts}\n"
+                f"  topk = {experts_per_tok}\n"
+                f"  nhidden = {expert_hidden}\n"
+                f"  first_expert = {first_expert}\n"
+                f"  nheld = {experts_held}\n"
+                f"  shared_hidden = {shared_hidden}\n"
+                "  shared_gate = 0\n"
+                "  score_func = sigmoid\n"
+                "  select_bias = 1\n"
+                f"  routed_scale = {route_scale!r}\n"
+                "  norm_topk = 1\n" + branch
+            )
+    s += (
+        f"layer[h{len(layer_types)}->nf] = rms_norm:norm_f\n"
+        f"  eps = {eps!r}\n"
+        "layer[nf->logits] = lm_head:head\n"
+        f"  nhidden = {vocab}\n"
+        "  init_sigma = 0.02\n"
+        "layer[logits->logits] = softmax\n"
+        # the mean over all positions: the loss sums over T
+        f"  grad_scale = {1.0 / seq_len!r}\n"
+        "netconfig = end\n"
+    )
+    feed = (f"  attn_window = {sliding_window}\n"
+            if "s" in layer_types else "")
+    return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
+                      compute_dtype, eta, scan_steps, feed=feed)
 
 
 NEMOTRON_H_STAGE = "MEMEMEM*EME"

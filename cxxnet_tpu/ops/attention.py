@@ -47,10 +47,12 @@ def _causal_mask(tq: int, tk: int, q_off, k_off) -> jnp.ndarray:
     return qi >= ki
 
 
-def _attend(q, k, v, q_off: int, causal: bool, scale, doc_q, doc_k):
+def _attend(q, k, v, q_off: int, causal: bool, scale, doc_q, doc_k,
+            window: int = 0, k_off: int = 0):
     """Softmax attention of a block of queries that starts at position
-    ``q_off`` against keys from position 0: the full masked score
-    matrix, (B,H,Tq,Tk) in f32."""
+    ``q_off`` against keys from position ``k_off``: the full masked
+    score matrix, (B,H,Tq,Tk) in f32.  ``window``: a query sees the keys
+    less than ``window`` positions before it (0: all)."""
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     )
@@ -58,7 +60,13 @@ def _attend(q, k, v, q_off: int, causal: bool, scale, doc_q, doc_k):
                         else scale)
     mask = None
     if causal:
-        mask = _causal_mask(q.shape[1], k.shape[1], q_off, 0)[None, None]
+        mask = _causal_mask(q.shape[1], k.shape[1], q_off, k_off)[None, None]
+    if window:
+        shape = (q.shape[1], k.shape[1])
+        near = (q_off + lax.broadcasted_iota(jnp.int32, shape, 0)
+                - k_off - lax.broadcasted_iota(jnp.int32, shape, 1)
+                < window)[None, None]
+        mask = near if mask is None else mask & near
     if doc_q is not None:
         same = (doc_q[:, :, None] == doc_k[:, None, :])[:, None]
         mask = same if mask is None else mask & same
@@ -135,6 +143,7 @@ def mha(
     scale: float | None = None,
     doc: jnp.ndarray | None = None,
     block_q: int = 0,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Plain softmax attention — the golden model for the ring variant.
 
@@ -142,33 +151,41 @@ def mha(
     attention: each serves ``H / Hkv`` consecutive query heads).
     ``scale`` replaces ``1 / sqrt(Dh)``.  ``doc`` ``(B, T)`` is a
     document index a token: a query sees only keys of its own document.
+    ``window`` > 0: a query at position ``i`` sees the keys ``j`` with
+    ``i - j < window`` (causal: itself and the ``window - 1`` before).
     ``block_q`` > 0 computes the rows ``block_q`` queries at a time,
     each block under ``jax.checkpoint`` and, where causal, against the
-    keys up to its own end only — the score matrix is then
-    ``(B, H, block_q, <=T)`` and never ``(B, H, T, T)``.
+    keys up to its own end only (and, windowed, from its first query's
+    reach on) — the score matrix is then ``(B, H, block_q, <=T)`` and
+    never ``(B, H, T, T)``.
     """
     h, hk = q.shape[2], k.shape[2]
     if hk != h:
         k = jnp.repeat(k, h // hk, axis=2)
         v = jnp.repeat(v, h // hk, axis=2)
     t = q.shape[1]
+    if window < 0 or (window and k.shape[1] != t):
+        raise ValueError(f"mha: window = {window} needs queries and keys of "
+                         "one length")
     if not block_q or t <= block_q or t % block_q or k.shape[1] != t:
-        return _attend(q, k, v, 0, causal, scale, doc, doc)
+        return _attend(q, k, v, 0, causal, scale, doc, doc, window)
     outs = []
     for lo in range(0, t, block_q):
         hi = lo + block_q
         end = hi if causal else t
+        beg = max(0, lo - window + 1) if window else 0
         block = jax.checkpoint(functools.partial(
-            _attend, q_off=lo, causal=causal, scale=scale))
+            _attend, q_off=lo, causal=causal, scale=scale, window=window,
+            k_off=beg))
         outs.append(block(
-            q[:, lo:hi], k[:, :end], v[:, :end],
+            q[:, lo:hi], k[:, beg:end], v[:, beg:end],
             doc_q=None if doc is None else doc[:, lo:hi],
-            doc_k=None if doc is None else doc[:, :end]))
+            doc_k=None if doc is None else doc[:, beg:end]))
     return jnp.concatenate(outs, axis=1)
 
 
 def attend(q, k, v, *, causal: bool = False, scale: float | None = None,
-           doc: jnp.ndarray | None = None):
+           doc: jnp.ndarray | None = None, window: int = 0):
     """``(mha(q, k, v, ...), 1 if the flash kernels computed it else 0)``
     — the one place masked attention chooses its path, for every layer
     (``attention``'s masked path, ``latent_attention``).
@@ -181,7 +198,8 @@ def attend(q, k, v, *, causal: bool = False, scale: float | None = None,
     512 queries from ``LONG_T`` tokens on) and the shapes the kernels are
     written for (``flash.block_for``: a long sequence that a block of 128
     or more divides, head widths the kernels take, bfloat16 or float32;
-    one block size serves every head width).  The flag is a uint32
+    one block size serves every head width, with a window or without).
+    ``window`` goes to either form.  The flag is a uint32
     scalar each branch returns for itself, so it says what ran where the
     program was lowered for (the layers' ``attn_tokens_flash``)."""
     from . import flash
@@ -193,12 +211,13 @@ def attend(q, k, v, *, causal: bool = False, scale: float | None = None,
     def rows(q, k, v, *doc):
         return (mha(q, k, v, causal=causal, scale=scale,
                     doc=doc[0] if doc else None,
-                    block_q=512 if t >= LONG_T else 0), jnp.uint32(0))
+                    block_q=512 if t >= LONG_T else 0, window=window),
+                jnp.uint32(0))
 
     def kernels(q, k, v, *doc):
         return (flash.flash_attention(
             q, k, v, causal=causal, scale=scale, doc=doc[0] if doc else None,
-            block_q=block, block_k=block)[0], jnp.uint32(1))
+            block_q=block, block_k=block, window=window)[0], jnp.uint32(1))
 
     if block is None:
         return rows(q, k, v, *docs)

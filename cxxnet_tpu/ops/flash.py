@@ -44,6 +44,18 @@ behind ONE ``jax.custom_vjp`` (``_flash``):
   they leave.
 * **dynamic position offsets** for the causal mask (ring hops,
   ``flash_mha_lse``).
+* **a window** (static, 0 = none): a query at position ``i`` sees the
+  keys ``j`` with ``i - j < window`` — causal, itself and the ``window
+  - 1`` before it.  The edge is monotone in the block index like the
+  causal edge and the documents', so the reach stays ONE range (its
+  ``lo`` raised, ``qhi`` lowered) and the static step table leaves out
+  the (query block, key block) pairs that lie wholly beyond the window,
+  forward, ``dq`` and ``dkv`` alike: at T 16384, window 2048 and blocks
+  of 1024 a query block visits at most 3 key blocks, 45 steps for 136.
+  Positions are the row's own, from 0 on both sides: a window with
+  position offsets (``flash_mha_lse``, ring hops) is refused, as a
+  document mask with them is (ROADMAP R3).  ``window = 0`` builds the
+  tables, operands and kernels this module built before it had one.
 
 Precision is that of ``ops/attention._attend``: the products take the
 operands as they come (bf16 into the MXU, float32 stays float32) and
@@ -75,7 +87,14 @@ NEG_INF = -1e30
 #: row of 8192 tokens in ~5 documents read 20.4 / 13.7 / 12.3 ms forward
 #: and backward at 1024 against 23.8 / 17.6 / 12.7 at 512 and 26.6 / 17.6
 #: / 18.0 at 2048 (heads of 192 + 128, 64, 256; PERF.md, PR 37) — fewer
-#: steps and rescalings a pair against more dead pairs inside live blocks
+#: steps and rescalings a pair against more dead pairs inside live blocks.
+#: Under a window too: a row of 16384 tokens in 4 documents, 32 query
+#: heads on 4 of 128, window 2048 read 26.6 ms forward and backward at
+#: 1024 (a third of the visited pairs dead) against 27.6 at 512 (a
+#: fifth), 29.8 at 1024 x 512 and 33.9 at 2048, and 8.2 / 9.9 ms forward
+#: alone; without the window 34.3 at 1024 against 44.7 at 512
+#: (``tools/attn_ab.py --window``; PERF.md, PR 42) — so a windowed
+#: layer takes the same block and ``block_for`` does not ask for it
 BLOCK = 1024
 _VMEM_LIMIT = 100 * 1024 * 1024
 
@@ -90,14 +109,20 @@ def _dot(a, b, dims):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _mask(shape, q_axis, q_pos, k_pos, causal, doc_q, doc_k):
+def _mask(shape, q_axis, q_pos, k_pos, causal, doc_q, doc_k, window=0):
     """May attend, for a block whose queries run along ``q_axis``:
-    causal and same document (``doc_q``, ``doc_k``: the refs of a column
-    and a row, or ``None``s); ``None`` where neither applies."""
+    causal, within ``window`` (query position - key position < window; 0:
+    none) and same document (``doc_q``, ``doc_k``: the refs of a column
+    and a row, or ``None``s); ``None`` where none applies."""
     ok = None
     if causal:
         ok = (q_pos + lax.broadcasted_iota(jnp.int32, shape, q_axis)
               >= k_pos + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+    if window:
+        near = (q_pos + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+                - k_pos - lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+                < window)
+        ok = near if ok is None else ok & near
     if doc_q is not None:
         same = doc_q[0] == doc_k[0]
         ok = same if ok is None else ok & same
@@ -114,7 +139,7 @@ def _split(refs, n_in, has_doc):
 
 
 def _fwd_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
-                bq, bk, n, tb, causal, has_doc, scale):
+                bq, bk, n, tb, causal, has_doc, scale, window=0):
     from jax.experimental import pallas as pl
 
     (q_ref, k_ref, v_ref), doc_q, doc_k, (o_ref, lse_ref, acc, m, l) = (
@@ -133,7 +158,7 @@ def _fwd_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
     def _block():
         s = _dot(q_ref[0], k_ref[0], _NT) * scale
         ok = _mask((bq, bk), 0, offs[0] + iq * bq, offs[1] + ik * bk,
-                   causal, doc_q, doc_k)
+                   causal, doc_q, doc_k, window)
         if ok is not None:
             s = jnp.where(ok, s, NEG_INF)
         m_prev = m[:, :1]
@@ -158,7 +183,7 @@ def _fwd_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
 
 
 def _dq_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
-               bq, bk, n, tb, causal, has_doc, scale):
+               bq, bk, n, tb, causal, has_doc, scale, window=0):
     from jax.experimental import pallas as pl
 
     ((q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref), doc_q, doc_k,
@@ -177,7 +202,7 @@ def _dq_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
         s = _dot(q_ref[0], kb, _NT) * scale
         p = jnp.exp(s - lse_ref[0])
         ok = _mask((bq, bk), 0, offs[0] + iq * bq, offs[1] + ik * bk,
-                   causal, doc_q, doc_k)
+                   causal, doc_q, doc_k, window)
         if ok is not None:
             p = jnp.where(ok, p, 0.0)
         dp = _dot(do_ref[0], v_ref[0], _NT)
@@ -190,7 +215,7 @@ def _dq_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
 
 
 def _dkv_kernel(offs, ik_t, iq_t, fl_t, lo_t, hi_t, g_t, *refs,
-                bq, bk, n, tb, causal, has_doc, scale):
+                bq, bk, n, tb, causal, has_doc, scale, window=0):
     """The transposed block: keys along the sublanes, queries along the
     lanes; ``lse``, ``delta`` and the queries' documents are rows."""
     from jax.experimental import pallas as pl
@@ -212,7 +237,7 @@ def _dkv_kernel(offs, ik_t, iq_t, fl_t, lo_t, hi_t, g_t, *refs,
         s = _dot(k_ref[0], qb, _NT) * scale
         p = jnp.exp(s - lse_ref[0])
         ok = _mask((bk, bq), 1, offs[0] + iq * bq, offs[1] + ik * bk,
-                   causal, doc_q, doc_k)
+                   causal, doc_q, doc_k, window)
         if ok is not None:
             p = jnp.where(ok, p, 0.0)
         vacc[:] += _dot(p.astype(dob.dtype), dob, _NN)
@@ -235,7 +260,8 @@ def _pick_block(t: int, want: int) -> int:
 
 # -- which blocks a step visits, and which of them are live ---------------
 @functools.lru_cache(maxsize=None)
-def _steps(nq: int, nk: int, bq: int, bk: int, tri: bool, group: int):
+def _steps(nq: int, nk: int, bq: int, bk: int, tri: bool, group: int,
+           window: int = 0):
     """The static tables of a grid's second axis.
 
     ``(iq, ik, flags)`` for the forward and ``dq`` kernels — query
@@ -244,9 +270,11 @@ def _steps(nq: int, nk: int, bq: int, bk: int, tri: bool, group: int):
     ``group`` query heads of its key-value head and their query blocks;
     the fourth table is the step's query head within the group.  ``tri``
     (causal, positions from 0 on both sides, one length) leaves out the
-    pairs above the diagonal."""
+    pairs above the diagonal; ``window`` those whose nearest pair (the
+    block's first query, the key block's last key) is beyond it."""
     def live(i, j):
-        return not tri or j * bk <= i * bq + bq - 1
+        return ((not tri or j * bk <= i * bq + bq - 1)
+                and (not window or i * bq - (j * bk + bk - 1) < window))
 
     def flags(n):
         f = np.zeros(n, np.int32)
@@ -270,7 +298,8 @@ def _steps(nq: int, nk: int, bq: int, bk: int, tri: bool, group: int):
     return fwd_t, bwd_t
 
 
-def _ranges(doc, offs, nq: int, nk: int, bq: int, bk: int, causal: bool):
+def _ranges(doc, offs, nq: int, nk: int, bq: int, bk: int, causal: bool,
+            window: int = 0):
     """``(lo, hi, qlo, qhi)``, flat int32: for query block ``i`` of table
     row ``b`` the key blocks ``lo[b nq + i] .. hi[b nq + i]`` hold every
     key one of its queries may see (``hi = -1``: none), and for key block
@@ -281,8 +310,12 @@ def _ranges(doc, offs, nq: int, nk: int, bq: int, bk: int, causal: bool):
     first key is not after the block's last query.  Documents: ``doc``
     does not decrease along T, so a block's first and last entries are
     its smallest and largest index, and a key block is in reach when the
-    two blocks' index ranges overlap — both conditions are monotone in
-    ``j`` (and in ``i``), so the reach is one range."""
+    two blocks' index ranges overlap.  A window (positions from 0 on
+    both sides): the first key block in reach holds the earliest key the
+    block's FIRST query sees, ``i bq - window + 1``, and the last query
+    block the latest query that sees the key block's LAST key, ``j bk +
+    bk - 1 + window - 1``.  All three conditions are monotone in ``j``
+    (and in ``i``), so the reach is one range."""
     i = jnp.arange(nq, dtype=jnp.int32)[None]
     j = jnp.arange(nk, dtype=jnp.int32)[None]
     lo, hi = jnp.zeros_like(i), jnp.full_like(i, nk - 1)
@@ -291,6 +324,9 @@ def _ranges(doc, offs, nq: int, nk: int, bq: int, bk: int, causal: bool):
         gap = offs[0] - offs[1]
         hi = jnp.minimum(hi, (gap + i * bq + bq - 1) // bk)
         qlo = jnp.maximum(qlo, (j * bk - gap) // bq)
+    if window:
+        lo = jnp.maximum(lo, (i * bq - window + 1) // bk)
+        qhi = jnp.minimum(qhi, (j * bk + bk + window - 2) // bq)
     if doc is not None:
         n = doc.shape[0]
         dq, dk = doc.reshape(n, nq, bq), doc.reshape(n, nk, bk)
@@ -324,27 +360,32 @@ class _Geometry:
     step tables and the live ranges, and the block specs over them."""
 
     def __init__(self, q, k, v, doc, q_off, k_off, causal, scale, bq, bk,
-                 heads):
+                 heads, window=0):
         self.bh, self.t, self.dqk = q.shape
         self.bhk, self.tk, self.dv = v.shape
         self.bq, self.bk = bq, bk
         self.nq, self.nk = self.t // bq, self.tk // bk
         self.group = self.bh // self.bhk
-        self.causal, self.scale = causal, scale
+        self.causal, self.scale, self.window = causal, scale, window
         self.has_doc = doc is not None
         dyn = q_off is not None or k_off is not None
         if self.has_doc and (dyn or self.t != self.tk):
             raise ValueError("flash: a document mask needs queries and "
                              "keys of one length and no position offsets")
+        if window < 0 or (window and (dyn or self.t != self.tk)):
+            raise ValueError(
+                f"flash: window = {window} needs queries and keys of one "
+                "length and no position offsets (flash_mha_lse and the "
+                "ring hops have no windowed path: ROADMAP R3)")
         self.offs = _offs(q_off, k_off)
         self.ranges = _ranges(doc, self.offs, self.nq, self.nk, bq, bk,
-                              causal)
+                              causal, window)
         #: heads of the grid's first axis that one row of ``ranges``
         #: serves, forward and backward (0: the one row serves all)
         self.tb = ((heads, heads // self.group) if self.has_doc else (0, 0))
         self.fwd_t, self.bwd_t = _steps(
             self.nq, self.nk, bq, bk,
-            causal and not dyn and self.t == self.tk, self.group)
+            causal and not dyn and self.t == self.tk, self.group, window)
         if self.has_doc:
             doc = doc.astype(jnp.int32)
             self.doc_col, self.doc_row = doc[:, :, None], doc[:, None, :]
@@ -353,7 +394,7 @@ class _Geometry:
         return functools.partial(
             fn, bq=self.bq, bk=self.bk, tb=self.tb[bwd],
             n=self.nk if bwd else self.nq, causal=self.causal,
-            has_doc=self.has_doc, scale=self.scale)
+            has_doc=self.has_doc, scale=self.scale, window=self.window)
 
     def specs(self, bwd: bool):
         """``(q-side spec of a width, k-side spec of a width, the spec of a
@@ -487,29 +528,31 @@ def _flash_bwd_raw(q, k, v, do, lse, dl, geo: _Geometry, interpret):
     return dq, dk, dv_
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 12)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 13)))
 def _flash(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
-           interpret):
+           interpret, window=0):
     """The kernels on the folded layout: ``q (B H, T, Dqk)``, ``k (B Hkv,
     Tk, Dqk)``, ``v (B Hkv, Tk, Dv)``, ``doc (B, T)`` or ``None``,
     traced position offsets or ``None`` -> ``(o (B H, T, Dv), lse (B H,
     T, 1))``.  Cotangents of BOTH outputs are taken: ``dL/dlse`` folds
     into the backward kernels as ``ds = p * (dp - (delta - dlse))``."""
     return _flash_fwd(q, k, v, doc, q_off, k_off, causal, scale, bq, bk,
-                      heads, interpret)[0]
+                      heads, interpret, window)[0]
 
 
 def _flash_fwd(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
-               interpret):
-    geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads)
+               interpret, window=0):
+    geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
+                    window)
     out, lse = _flash_fwd_raw(q, k, v, geo, interpret)
     return (out, lse), (q, k, v, doc, q_off, k_off, out, lse)
 
 
-def _flash_bwd(causal, scale, bq, bk, heads, interpret, res, cts):
+def _flash_bwd(causal, scale, bq, bk, heads, interpret, window, res, cts):
     q, k, v, doc, q_off, k_off, out, lse = res
     g, g_lse = cts
-    geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads)
+    geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
+                    window)
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(
         -1, keepdims=True)
     # dL/dlse_i adds p_ij * dlse_i to ds_ij; the kernels compute
@@ -531,7 +574,8 @@ def block_for(q, k, v):
     multiples of 64 up to 256, grouped heads, bfloat16 or float32.  The
     largest block up to ``BLOCK`` that divides T, whatever the widths:
     measured at heads of 64, 192 + 128 and 256 (``tools/attn_ab.py``,
-    PERF.md PR 37), one size won at all three."""
+    PERF.md PR 37), one size won at all three, and under a window of
+    2048 in rows of 16384 again (PR 42: ``BLOCK`` has the reading)."""
     from .attention import LONG_T
 
     t, h, dqk = q.shape[1:]
@@ -557,11 +601,13 @@ def _unfold(x, b, h):
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None, doc=None,
                     q_off=None, k_off=None, block_q: int = 512,
-                    block_k: int = 512, interpret: bool = False):
+                    block_k: int = 512, interpret: bool = False,
+                    window: int = 0):
     """``ops/attention.mha`` as the flash kernels: ``q (B, T, H, Dqk)``,
     ``k (B, Tk, Hkv, Dqk)``, ``v (B, Tk, Hkv, Dv)`` with ``Hkv`` dividing
     ``H``, ``scale`` in place of ``1 / sqrt(Dqk)``, ``doc (B, T)`` the
-    document index a token -> ``(o (B, T, H, Dv), lse (B, T, H))``.
+    document index a token, ``window`` the keys a query sees counted
+    back from itself (0: all) -> ``(o (B, T, H, Dv), lse (B, T, H))``.
     ``_pick_block`` halves a block until it divides its length."""
     b, t, h, dqk = q.shape
     if h % k.shape[2] or k.shape[:3] != v.shape[:3]:
@@ -570,7 +616,7 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None, doc=None,
         _fold(q), _fold(k), _fold(v), doc, q_off, k_off, bool(causal),
         float(1.0 / math.sqrt(dqk) if scale is None else scale),
         _pick_block(t, block_q), _pick_block(k.shape[1], block_k), h,
-        bool(interpret))
+        bool(interpret), int(window))
     return _unfold(out, b, h), lse[:, :, 0].reshape(b, h, t).transpose(
         0, 2, 1)
 

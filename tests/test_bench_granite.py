@@ -20,7 +20,8 @@ def test_the_cell_is_the_one_the_issue_names():  # noqa: F811
     ``mlp_ms_step`` — its net has no MLP — and, the first to do so, for
     the mixer's ``ssd_scan_ms_step`` and ``mamba_mixer_ms_step``, leaving
     ``ssd_scan_roofline_pct``, whose reader names this configuration's
-    reference), which the test under
+    reference; PR 42 for ``attention_ms_step``, ``mlp_ms_step`` and the
+    shared four — its net has no mixer), which the test under
     ``benchmarks/`` forbids and a PR that adds a cell may not edit; a
     ``benchmark`` PR folds this back."""
     from benchmarks.tests import test_granite as g
@@ -36,13 +37,14 @@ def test_the_cell_is_the_one_the_issue_names():  # noqa: F811
     pr33 = "qwen3_next_80b_a3b_train_packed8k"
     pr36 = "joyai_llm_flash_train_packed8k"
     pr40 = "nemotron_3_super_120b_a12b_train_packed8k"
-    want = {"mlp_ms_step": [g.CELL, pr36],     # no MLP in PR 33's, PR 40's
-            "attention_ms_step": [g.CELL, pr33, pr40],   # none in PR 36's
+    pr42 = "trinity_mini_train_packed16k"
+    want = {"mlp_ms_step": [g.CELL, pr36, pr42],  # no MLP in PR 33's, 40's
+            "attention_ms_step": [g.CELL, pr33, pr40, pr42],  # not PR 36's
             "ssd_scan_ms_step": [g.CELL, pr40],
             "mamba_mixer_ms_step": [g.CELL, pr40],
             "ssd_scan_roofline_pct": [g.CELL]}
     for m in bench["per_layer"]:
         if m["name"] in g.NEW_METRICS:
             assert m["workloads"] == want.get(
-                m["name"], [g.CELL, pr33, pr36, pr40])
+                m["name"], [g.CELL, pr33, pr36, pr40, pr42])
             assert m["moves"] == "train_samples_s_chip"

@@ -16,8 +16,10 @@ def test_the_cell_is_the_one_the_issue_names():  # noqa: F811
     left: the cell, its configuration, its three metrics and the shared
     ones it is listed under.  PR 41's ``ssd_scan_fused_pct`` goes behind
     PR 40's three, which the test under ``benchmarks/`` holds to be the
-    LAST three and a PR that adds a metric may not edit; a ``benchmark``
-    PR folds this back."""
+    LAST three and a PR that adds a metric may not edit; PR 42's four
+    readers of the windowed attention's scopes and counters go behind
+    that, and its cell lists ``mlp_ms_step``; a ``benchmark`` PR folds
+    this back."""
     from benchmarks.tests import test_nemotron_h as n
 
     bench = n.run.load_json(os.path.join(n.ROOT, "BENCHMARK.json"))
@@ -43,4 +45,8 @@ def test_the_cell_is_the_one_the_issue_names():  # noqa: F811
                  "train_metric_ms_step", "dispatch_gap_ms_step"):
         assert n.CELL not in by_name[name]["workloads"]
     listed = [m["name"] for m in bench["per_layer"]]
-    assert listed[-4:] == n.NEW_METRICS + ["ssd_scan_fused_pct"]
+    at = listed.index(n.NEW_METRICS[0])
+    assert listed[at:at + 4] == n.NEW_METRICS + ["ssd_scan_fused_pct"]
+    assert listed[at + 4:] == [
+        "attn_window_core_ms_step", "attn_full_core_ms_step",
+        "attn_window_pairs_pct", "attn_core_roofline_pct"]

@@ -262,7 +262,8 @@ ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
              "granite_4_0_h_micro_train_packed8k",
              "qwen3_next_80b_a3b_train_packed8k",
              "joyai_llm_flash_train_packed8k",
-             "nemotron_3_super_120b_a12b_train_packed8k"]
+             "nemotron_3_super_120b_a12b_train_packed8k",
+             "trinity_mini_train_packed16k"]
 
 
 LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
@@ -274,9 +275,13 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
     ("chunk_overlap_pct", ALL_CELLS, "higher"),
     ("gdn_scan_fused_pct", ALL_CELLS[3:4], "higher"),
     ("ssd_scan_fused_pct", [ALL_CELLS[2], ALL_CELLS[5]], "higher"),
-    ("moe_latent_proj_ms_step", ALL_CELLS[5:], "lower"),
-    ("latent_expert_matmul_roofline_pct", ALL_CELLS[5:], "higher"),
-    ("ssd_scan_grouped_roofline_pct", ALL_CELLS[5:], "higher"),
+    ("moe_latent_proj_ms_step", ALL_CELLS[5:6], "lower"),
+    ("latent_expert_matmul_roofline_pct", ALL_CELLS[5:6], "higher"),
+    ("ssd_scan_grouped_roofline_pct", ALL_CELLS[5:6], "higher"),
+    ("attn_window_core_ms_step", ALL_CELLS[6:], "lower"),
+    ("attn_full_core_ms_step", ALL_CELLS[6:], "lower"),
+    ("attn_window_pairs_pct", ALL_CELLS[6:], "lower"),
+    ("attn_core_roofline_pct", ALL_CELLS[6:], "higher"),
     ("attn_flash_pct", ALL_CELLS[2:], "higher"),
     ("expert_dispatch_compact_pct", ALL_CELLS[3:], "higher"),
 ] + [(name, ALL_CELLS, "lower") for name in LOOP_BILL])
@@ -301,11 +306,15 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
     assert names[42:46] == ["mla_ms_step", "mla_core_ms_step",
                             "mla_core_roofline_pct", "mtp_ms_step"]
     # PR 37's one behind them, PR 38's five behind that, and PR 39's
-    # one; PR 40's three; PR 41's one, the last
+    # one; PR 40's three; PR 41's one; PR 42's four, the last
     assert names[46:47] == ["attn_flash_pct"]
     assert names[47:52] == LOOP_BILL
     assert names[52:] == ["expert_dispatch_compact_pct",
                           "moe_latent_proj_ms_step",
                           "latent_expert_matmul_roofline_pct",
                           "ssd_scan_grouped_roofline_pct",
-                          "ssd_scan_fused_pct"]
+                          "ssd_scan_fused_pct",
+                          "attn_window_core_ms_step",
+                          "attn_full_core_ms_step",
+                          "attn_window_pairs_pct",
+                          "attn_core_roofline_pct"]

@@ -51,6 +51,13 @@ def test_model_shapes(name):
                        head_dim=16, num_experts=8, experts_per_tok=3,
                        expert_hidden=16, latent_hidden=16, shared_hidden=24,
                        experts_held=4, num_nextn_predict_layers=1)
+    elif name == "afmoe":  # the defaults are the published widths
+        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
+                       hidden=32, layer_types="sf", num_dense_layers=1,
+                       sliding_window=8, attn_heads=4, attn_kv_heads=2,
+                       head_dim=16, mlp_hidden=48, num_experts=8,
+                       experts_per_tok=2, expert_hidden=16, shared_hidden=16,
+                       experts_held=4)
     elif name.startswith("mnist") or name in ("kaggle_bowl",
                                               "transformer_lm"):
         text = builder(batch_size=4, dev="cpu")
@@ -68,7 +75,7 @@ def test_model_shapes(name):
               "kaggle_bowl": 121,
               "transformer": 10, "transformer_lm": 256,
               "granite_h": 64, "qwen3_next": 64, "joyai_llm_flash": 64,
-              "nemotron_h": 64, "resnet50": 1000, "resnet101": 1000,
+              "nemotron_h": 64, "afmoe": 64, "resnet50": 1000, "resnet101": 1000,
               "resnet152": 1000}[name]
     assert out[-1] == expect
     if name in ("resnet101", "resnet152", "vgg19"):

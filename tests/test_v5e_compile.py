@@ -53,6 +53,17 @@ on-chip-measurement guide, section 2: nothing runs, no chip is needed).
   (ten layers under adam, 772M parameters) holds no more at its fullest
   than the parent's 15.06 GB: 14.65.
 
+* the whole scanned step of ``afmoe_conf()`` at its defaults (PR 42:
+  one rank's share of a Trinity-Mini stage — five gated attention layers
+  of 32 query heads on 4 key/value heads of 128, four of them under a
+  window of 2048, sandwich norms, a dense layer and four expert layers
+  at 8 held experts, rows of 16384 tokens; 504M parameters under adam)
+  fits a chip: 12.35 GB at its fullest, held to the 14.4 GB that decided
+  between 16 held experts (14.97 GB: over) and 8 (ISSUE 42's memory
+  rule); lowered for a TPU a windowed layer IS the flash kernels, under
+  its ``core_window`` scope, on grids of the window's 45 (query block,
+  key block) steps and not the diagonal's 136.
+
 The topology is described inside a fixture, in this one file: only one
 process at a time may load the TPU's library.
 """
@@ -363,6 +374,97 @@ def _mosaic_calls(text):
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     return [n for n in names if n.endswith("/pallas_call")]
+
+
+def test_the_trinity_step_fits_a_chip_at_eight_held_experts(one_chip):
+    """``tools/compile_for_v5e.py``'s compile of the conf the builder
+    writes, from shapes alone: 6.05 GB of weights and adam's moments
+    aliased to the outputs, the rest temporaries of one 16384-token row
+    (12.35 GB live at the peak when this was written; with 16 held
+    experts it read 14.97, over the line: the configuration's
+    ``memory_analysis_v5e``)."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from tools.compile_for_v5e import compile_step, live_at_peak_bytes
+
+    from cxxnet_tpu.models import afmoe_conf
+
+    compiled = compile_step(afmoe_conf())
+    m = compiled.memory_analysis()
+    assert abs(m.argument_size_in_bytes - 504_147_712 * 12) < 2e6
+    assert m.alias_size_in_bytes > 0.999 * m.output_size_in_bytes
+    assert live_at_peak_bytes(compiled) <= 14.4e9
+    text = compiled.as_text()
+    for scope in ("l1_attn0)/core_window/", "l9_attn4)/core_full/",
+                  "l1_attn0)/qk_norm/", "l1_attn0)/rotary/",
+                  "l4_moe1)/route/", "l4_moe1)/dispatch/",
+                  "l4_moe1)/experts/", "l4_moe1)/combine/",
+                  "l4_moe1)/shared/"):
+        assert scope in text, scope
+    # the full layer rotates nothing
+    assert "l9_attn4)/rotary/" not in text
+    assert 'op_name="ragged-dot-none"' in text
+    # the held experts row-major through the scan like the accepted cells'
+    assert re.search(r"f32\[8,2048,2048\]\{2,1,0", text)
+    assert not re.search(r"f32\[8,(?:2048,2048|1024,2048)\]\{1,2,0", text)
+    # all five attention layers are the flash kernels, four calls each,
+    # each under its layer's own core scope
+    calls = _mosaic_calls(text)
+    assert len(calls) == 20, [c[-60:] for c in calls]
+    assert sum("/core_window/" in c for c in calls) == 16
+    assert sum("/core_full/" in c and "l9_attn4" in c for c in calls) == 4
+    assert not _SCORE_BLOCK.search(text)
+
+
+@pytest.mark.parametrize("window, steps", [(2048, 45), (0, 136)],
+                         ids=["sliding", "full"])
+def test_a_trinity_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
+        one_chip, window, steps):
+    """One sandwiched ``attention`` layer of the afmoe family on a packed
+    row of 16384 tokens, bfloat16, under ``remat``: the kernels' grids
+    walk the window's steps on a sliding layer."""
+    from cxxnet_tpu.layers import create_layer
+    from cxxnet_tpu.ops.flash import BLOCK
+
+    cfg = dict(nhead=32, nkvhead=4, head_dim=128, qk_norm=1, out_gate=1,
+               causal=1, no_bias=1, prenorm=1, postnorm=1,
+               residual_scale=1.0)
+    if window:
+        cfg.update(window=window, rotary_dim=128)
+    lay = create_layer("attention")
+    for k, v in cfg.items():
+        lay.set_param(k, str(v))
+    shapes = [(1, 16384, 2048), (1, 16384)]
+    lay.infer_shape(shapes)
+    params = jax.eval_shape(lambda k: lay.init_params(k, shapes),
+                            jax.random.PRNGKey(0))
+    aux = jax.eval_shape(lambda: lay.init_aux(shapes))
+
+    def loss(p, aux, x, ids):
+        def run(p, x):
+            with jax.named_scope("l3_attn1"):
+                (y,), new = lay.apply_stateful(p, aux, [x, ids])
+            return jnp.sum(y.astype(jnp.float32)), new
+        return jax.checkpoint(run)(p, x)
+
+    shaped = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda v: _shaped(one_chip, v.shape, v.dtype), t)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 2), has_aux=True)
+                       ).lower(
+        shaped(params), shaped(aux), _shaped(one_chip, shapes[0]),
+        _shaped(one_chip, shapes[1], jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = _mosaic_calls(text)
+    assert sorted(c.split("/")[-2] for c in calls) == [
+        "flash_dkv", "flash_dq", "flash_fwd", "flash_fwd"], calls
+    scope = "core_window" if window else "core_full"
+    assert all("l3_attn1" in c and f"/{scope}/" in c for c in calls), calls
+    assert not _SCORE_BLOCK.search(text)
+    assert "bf16[4,16384,128]" in text
+    # the step tables are operands of the calls: their length is the grid's
+    assert BLOCK == 1024
+    assert f"s32[{steps}]" in text and f"s32[{8 * steps}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.4e9
 
 
 @pytest.mark.parametrize("cfg", [

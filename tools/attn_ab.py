@@ -2,8 +2,10 @@
 shapes: ``ops/attention.mha``'s checkpointed row blocks against the
 flash kernels of ``ops/flash.py`` at a list of block sizes.
 
-One packed row of 8192 tokens cut into documents as the cells' traffic
-cuts it; forward + backward (the gradient of a weighted sum of the
+One packed row of 8192 tokens (``--tokens``) cut into documents as the
+cells' traffic cuts it, under ``--window W`` each query seeing its
+document's last W keys only (the sliding layers of ``trinity``);
+forward + backward (the gradient of a weighted sum of the
 output with respect to q, k and v) under one ``jax.jit``, the median
 wall time of ``--reps`` calls that end in ``block_until_ready``.  One
 JSON line a reading, also written to ``chiprun_out/attn_ab.jsonl``; the
@@ -13,6 +15,7 @@ A measurement path: it refuses a host without a TPU.
 Usage:
     python tools/attn_ab.py [--shapes joyai,granite,qwen3_next]
         [--blocks 512x512,1024x512] [--docs 5] [--reps 10] [--no-xla]
+        [--tokens 16384 --window 2048]
         [--cpu-rehearsal --tokens 256]
 """
 
@@ -30,6 +33,7 @@ SHAPES = {
     "joyai": (32, 32, 192, 128, None),
     "granite": (32, 8, 64, 64, 0.015625),
     "qwen3_next": (16, 2, 256, 256, None),
+    "trinity": (32, 4, 128, 128, None),
 }
 
 
@@ -39,6 +43,7 @@ def main() -> int:
     ap.add_argument("--blocks", default="512x512")
     ap.add_argument("--tokens", type=int, default=8192)
     ap.add_argument("--docs", type=int, default=5)
+    ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-xla", action="store_true")
@@ -64,7 +69,10 @@ def main() -> int:
     doc_np = np.searchsorted(cuts, np.arange(t), side="right").astype(
         np.int32)
     bounds = np.concatenate([[0], cuts, [t]])
-    pairs = int(sum(n * (n + 1) // 2 for n in np.diff(bounds)))
+    win = {"window": args.window} if args.window else {}
+    lens = np.diff(bounds)
+    head = np.minimum(lens, args.window) if args.window else lens
+    pairs = int((head * (head + 1) // 2 + (lens - head) * args.window).sum())
     doc = jnp.asarray(doc_np)[None]
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
     out = open(os.path.join(REPO, "chiprun_out", "attn_ab.jsonl"), "a")
@@ -94,7 +102,7 @@ def main() -> int:
             return jax.jit(lambda q, k, v, w, doc: (attn(q, k, v, doc),))
 
         ref_attn = lambda q, k, v, doc: mha(  # noqa: E731
-            q, k, v, causal=True, scale=scale, doc=doc, block_q=512)
+            q, k, v, causal=True, scale=scale, doc=doc, block_q=512, **win)
         a = (q, k, v, w, doc)
         ref = fwd(ref_attn)(*a)[0].astype(jnp.float32)
         ref_g = grads(ref_attn)(*a)
@@ -105,7 +113,7 @@ def main() -> int:
                          bk=bk: flash_attention(
                              q, k, v, causal=True, scale=scale, doc=doc,
                              block_q=bq, block_k=bk,
-                             interpret=args.cpu_rehearsal)[0]))
+                             interpret=args.cpu_rehearsal, **win)[0]))
         for label, attn in rows:
             try:
                 got = fwd(attn)(*a)[0].astype(jnp.float32)
@@ -115,7 +123,8 @@ def main() -> int:
                                   / (jnp.abs(y.astype(jnp.float32)).max()))
                             for x, y in zip(grads(attn)(*a), ref_g))
                 line = {"shape": name, "form": label, "docs": args.docs,
-                        "pairs": pairs, "fwd_ms": timed(fwd(attn), *a),
+                        "tokens": t, "window": args.window, "pairs": pairs,
+                        "fwd_ms": timed(fwd(attn), *a),
                         "fwd_bwd_ms": timed(grads(attn), *a),
                         "max_abs_err": err, "grad_rel_err": g_err,
                         "fwd_tflops_needed": flops / 1e12,
