@@ -352,8 +352,8 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
     from cxxnet_tpu.layers.conv import _maxpool_eq
     from cxxnet_tpu.ops import quant as opsq
     from cxxnet_tpu.ops.attention import mha
-    from cxxnet_tpu.ops.flash import (flash_attention, flash_mha,
-                                      flash_mha_lse)
+    from cxxnet_tpu.ops.flash import (count_blocks, flash_attention,
+                                      flash_mha, flash_mha_lse)
     from cxxnet_tpu.ops.gdn import gated_delta_recurrence
     from cxxnet_tpu.ops.gdn_fused import gated_delta_fused
     from cxxnet_tpu.ops.kernels import conv_block, int8_gemm, update_step
@@ -479,10 +479,34 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
         return with_grads(run, 4)
 
     # -- the masked kernels at latent attention's two widths, four query
-    # heads a key-value head, a stated scale, ~4 documents a row, against
-    # ``mha`` with the same mask
-    def masked(attn):
-        return with_grads(lambda q, k, v: (attn(q, k, v),), 3)
+    # heads a key-value head, a stated scale, against ``mha`` with the
+    # same mask: rows of four blocks whose documents make a call run all
+    # three kinds of block — no mask at all, one document crossed by the
+    # diagonal or the window's edge, a document boundary — once without a
+    # window and once under one of two and a half blocks
+    blk = td // 4
+    doc_m = jnp.asarray(
+        np.searchsorted([int(.63 * td), int(.93 * td)], np.arange(td),
+                        side="right")[None]
+        + np.array([[0], [1]]) * (np.arange(td) >= td // 20), jnp.int32)
+    masked_args = (arr(2, td, 4 * hkd, 192), arr(2, td, hkd, 192),
+                   arr(2, td, hkd, 128))
+
+    def masked(attn, window):
+        mask = dict(causal=True, doc=doc_m, window=window)
+        visited, free, one = (int(n) for n in count_blocks(
+            *masked_args, block_q=blk, block_k=blk, **mask))
+        assert 0 < free < one < visited, (visited, free, one)
+        return with_grads(lambda q, k, v: (attn(q, k, v, scale=0.07,
+                                                **mask),), 3)
+
+    masked_pairs = [
+        (name, "ok", masked(lambda *a, **kw: flash_attention(
+            *a, block_q=blk, block_k=blk, interpret=interpret, **kw)[0], w),
+         masked(mha, w), masked_args, BF16)
+        for name, w in (("flash_attention masked fwd+bwd", 0),
+                        ("flash_attention masked window fwd+bwd",
+                         5 * blk // 2))]
 
     # -- latent attention at JoyAI-LLM-Flash's widths, a quarter of its
     # heads: the whole layer in bf16 against itself in f32, two documents
@@ -560,14 +584,7 @@ def _kernel_cases(sz: Sizes, interpret: bool, abstract: bool = False):
          (arr(2, td, hs, 64), arr(2, td, hs, dtype=jnp.float32),
           arr(2, td, 128, scale=0.1), arr(2, td, 128),
           arr(hs, dtype=jnp.float32)), 2 * BF16),
-        ("flash_attention masked fwd+bwd", "ok",
-         masked(lambda q, k, v: flash_attention(
-             q, k, v, causal=True, scale=0.07, doc=doc, block_q=512,
-             block_k=512, interpret=interpret)[0]),
-         masked(lambda q, k, v: mha(q, k, v, causal=True, scale=0.07,
-                                    doc=doc)),
-         (arr(2, td, 4 * hkd, 192), arr(2, td, hkd, 192),
-          arr(2, td, hkd, 128)), BF16),
+        *masked_pairs,
         ("flash_mha fwd+bwd", "ok",
          with_grads(lambda q, k, v: (flash_mha(
              q, k, v, True, 512, 512, interpret),), 3),

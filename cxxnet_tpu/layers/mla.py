@@ -57,9 +57,11 @@ State (``aux``, carried through the step programs and read once a
 round by ``NetTrainer.count_layer_state``, as ``gated_deltanet``'s):
 ``attn_tokens`` — tokens through ``core``; ``attn_tokens_flash`` — those
 of them the flash kernels computed, which the branch that ran says for
-itself.  uint32, wrapping; the round's counters of the same names sum
-them over the layers (``attention``'s masked path counts into the same
-two).
+itself; ``attn_blocks`` / ``attn_blocks_unmasked`` — the blocks the
+forward kernel then visits over all heads, and those of them whose
+every pair may attend.  uint32, wrapping; the round's counters of the
+same names sum them over the layers (``attention``'s masked path counts
+into the same four).
 
 Scopes inside the layer's: ``q_proj``, ``kv_proj``, ``rotary``, ``core``
 (scores, mask, softmax, values — forward, recomputed and backward; on a
@@ -74,11 +76,11 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attend, doc_positions, rotary
+from ..ops.attention import doc_positions, rotary
 from ..ops.ssd import doc_index
 from .base import Layer, Params, Shape, register
 from .sequence import (ATTN_COUNTERS, Branch, _check_ids_input,
-                       count_attention, rms_norm)
+                       attend_counted, count_attention, rms_norm)
 
 
 @register
@@ -151,11 +153,11 @@ class LatentAttentionLayer(Layer, Branch):
 
     def apply_stateful(self, params, aux, inputs, *, train=False, rng=None,
                        step=None):
-        outs, flash = self._run(params, inputs)
-        return outs, count_attention(aux, inputs[0], flash)
+        outs, ran = self._run(params, inputs)
+        return outs, count_attention(aux, inputs[0], ran)
 
     def _run(self, params, inputs):
-        """``([out], 1 if the flash kernels computed ``core`` else 0)``."""
+        """``([out], what ran in ``core``, as ``attend_counted`` says)``."""
         x0 = inputs[0]
         n, t, _ = x0.shape
         h, dn, dr, dv = self.nhead, self.nope_dim, self.rope_dim, self.v_dim
@@ -183,9 +185,8 @@ class LatentAttentionLayer(Layer, Branch):
                 [kv[..., :dn], jnp.broadcast_to(
                     rotary(k_rope, pos, dr, self.rope_theta, turn),
                     (n, t, h, dr))], axis=-1)
-        with jax.named_scope("core"):
-            o, flash = attend(q, k, kv[..., dn:], causal=bool(self.causal),
-                              doc=doc)
+        o, ran = attend_counted("core", q, k, kv[..., dn:],
+                                causal=bool(self.causal), doc=doc)
         with jax.named_scope("out_proj"):
             out = o.reshape(n, t, h * dv) @ params["wproj"].astype(cdt).T
-        return [self.branch_out(params, x0, out)], flash
+        return [self.branch_out(params, x0, out)], ran

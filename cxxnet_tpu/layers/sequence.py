@@ -69,7 +69,11 @@ its own inside the layer's, ``core_window`` on a windowed layer and
 (``ATTN_COUNTERS``, read once a round by
 ``NetTrainer.count_layer_state``) ``attn_tokens``, the tokens through
 it, and ``attn_tokens_flash``, those of them the kernels computed — the
-branch that ran says so for itself.
+branch that ran says so for itself — and, where they did,
+``attn_blocks``, the (query block, key block) steps the forward kernel
+computed over all heads, and ``attn_blocks_unmasked``, those of them
+whose every pair may attend (``ops/flash.count_blocks``, from the
+tables the kernels read).
 """
 
 from __future__ import annotations
@@ -84,15 +88,33 @@ from .base import Layer, Params, Shape, register
 
 #: the masked attention layers' ``aux`` state (``attention``'s masked
 #: path, ``latent_attention``), and the round's counters they add to
-ATTN_COUNTERS = ("attn_tokens", "attn_tokens_flash")
+ATTN_COUNTERS = ("attn_tokens", "attn_tokens_flash", "attn_blocks",
+                 "attn_blocks_unmasked")
 
 
-def count_attention(aux, x, flash):
-    """``aux`` after ``x (N, T, D)`` went through attention, ``flash`` 1
-    where the flash kernels computed it (uint32, wrapping)."""
+def attend_counted(scope, q, k, v, *, causal=False, scale=None, doc=None,
+                   window=0):
+    """``ops/attention.attend`` under the scope ``scope`` -> ``(o,
+    ran)``, ``ran`` uint32 ``(3,)``: 1 where the flash kernels computed
+    it, and then the blocks their forward visits and those of them whose
+    every pair may attend (``ops/flash.count_blocks``; 0 where ``mha``
+    ran)."""
+    from ..ops.attention import attend
+    from ..ops.flash import count_blocks
+
+    with jax.named_scope(scope):
+        o, flash = attend(q, k, v, causal=causal, scale=scale, doc=doc,
+                          window=window)
+    blocks = count_blocks(q, k, v, causal=causal, doc=doc, window=window)
+    return o, jnp.concatenate([flash[None], blocks[:2] * flash])
+
+
+def count_attention(aux, x, ran):
+    """``aux`` after ``x (N, T, D)`` went through attention, ``ran`` as
+    ``attend_counted`` gives it (uint32, wrapping)."""
     tokens = jnp.uint32(x.shape[0] * x.shape[1])
-    return {"attn_tokens": aux["attn_tokens"] + tokens,
-            "attn_tokens_flash": aux["attn_tokens_flash"] + tokens * flash}
+    add = (tokens, tokens * ran[0], ran[1], ran[2])
+    return {name: aux[name] + n for name, n in zip(ATTN_COUNTERS, add)}
 
 
 def _layer_norm(x, w, b, eps: float):
@@ -387,7 +409,7 @@ class AttentionLayer(Layer, Branch):
     def init_aux(self, in_shapes):
         """KV cache state for ``decode = 1``: keys/values for all past
         positions, written at the loop's ``step`` offset.  The masked
-        path's two counters otherwise; nothing for the plain layer."""
+        path's counters otherwise; nothing for the plain layer."""
         if not self.decode:
             if self._plain(len(in_shapes)):
                 return {}
@@ -425,11 +447,11 @@ class AttentionLayer(Layer, Branch):
         from jax import lax
 
         if not self.decode:
-            y, flash = self._apply_masked(
+            y, ran = self._apply_masked(
                 params, self.branch_in(params, inputs[0]),
                 inputs[1] if len(inputs) > 1 else None)
             return ([self.branch_out(params, inputs[0], y)],
-                    count_attention(aux, inputs[0], flash))
+                    count_attention(aux, inputs[0], ran))
         x = inputs[0]
         n, t, d = x.shape
         h, dh = self.nhead, d // self.nhead
@@ -563,8 +585,8 @@ class AttentionLayer(Layer, Branch):
         norms, rotary positions, an output gate, documents: q, k and v
         from the one fused projection, then ``ops/attention.attend``
         with its mask — the flash kernels or ``mha``'s row blocks.
-        Returns the output and the flag of the branch that ran."""
-        from ..ops.attention import attend, doc_positions, rotary
+        Returns the output and what ran (``attend_counted``)."""
+        from ..ops.attention import doc_positions, rotary
         from ..ops.ssd import doc_index
 
         n, t, d = x.shape
@@ -593,17 +615,17 @@ class AttentionLayer(Layer, Branch):
                 pos = doc_positions(doc, n, t)
                 q = rotary(q, pos, self.rotary_dim, self.rope_theta)
                 k = rotary(k, pos, self.rotary_dim, self.rope_theta)
-        with jax.named_scope("core_window" if self.window else "core_full"):
-            o, flash = attend(q, k, v, causal=bool(self.causal),
-                              scale=self.scale or None, doc=doc,
-                              window=self.window)
+        o, ran = attend_counted(
+            "core_window" if self.window else "core_full", q, k, v,
+            causal=bool(self.causal), scale=self.scale or None, doc=doc,
+            window=self.window)
         o = o.reshape(n, t, nq)
         if gate is not None:
             o = o * jax.nn.sigmoid(gate)
         out = o @ params["wproj"].astype(x.dtype).T
         if "bproj" in params:
             out = out + params["bproj"].astype(x.dtype)
-        return out, flash
+        return out, ran
 
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
         x = self.branch_in(params, inputs[0])

@@ -26,8 +26,29 @@ behind ONE ``jax.custom_vjp`` (``_flash``):
 
 * **a document mask**: ``doc (B, T)``, the non-decreasing document
   index of ``ops/ssd.doc_index``; a query sees the keys of its own
-  document (and, causal, not the later ones).  It reaches a kernel as
-  two small operands, the query block's column and the key block's row.
+  document (and, causal, not the later ones).
+* **the whole mask as two bounds a query** (PR 43).  Documents are runs
+  along T and the diagonal and the window are intervals, so "may attend"
+  is ``lo_q <= key position <= hi_q`` with two integers a QUERY that XLA
+  makes once a row (``_bounds``); they reach a kernel as two small
+  operands beside the query block, and a tile compares ONE row of key
+  positions with them (``_may_attend``) — no ``(block, block)`` iota, no
+  position arithmetic on the tile, no document compare.  A body with no
+  mask at all for the blocks whose every pair may attend was measured
+  beside it and paid nothing (PERF.md, PR 43: the compares hide behind
+  the products), so there is none; ``count_blocks`` says how many such
+  blocks a row has.
+* **a block the diagonal or the window's edge crosses pays for the
+  parts it needs** (PR 43).  What bounds a live block on a v5e is its
+  products, not its softmax (PERF.md, PR 43), so the pairs an edge leaves
+  dead are worth leaving out: a step's body is chosen from its scalars
+  (``_tiles``) — each half of one block (the queries' in the forward,
+  the keys' in ``dq`` and ``dkv``) runs against the halves of the other
+  it can see, as one tile, and a half wholly above the diagonal or
+  beyond the window is in no tile.  A quarter of such a block's
+  products, its ``exp`` and its passes are not done; a block no edge
+  crosses is its two halves against the whole other block.  Same live
+  pairs, same float32 statistics.
 * **whole-block skipping**: documents are runs along T, so the key
   blocks a query block may see are ONE range ``[lo, hi]`` (``_ranges``:
   the causal edge and the documents' first/last index a block, made by
@@ -43,7 +64,7 @@ behind ONE ``jax.custom_vjp`` (``_flash``):
   ``dv`` are summed over the group in their float32 accumulators before
   they leave.
 * **dynamic position offsets** for the causal mask (ring hops,
-  ``flash_mha_lse``).
+  ``flash_mha_lse``): they move ``hi_q`` and the parts a step computes.
 * **a window** (static, 0 = none): a query at position ``i`` sees the
   keys ``j`` with ``i - j < window`` — causal, itself and the ``window
   - 1`` before it.  The edge is monotone in the block index like the
@@ -97,6 +118,14 @@ NEG_INF = -1e30
 #: layer takes the same block and ``block_for`` does not ask for it
 BLOCK = 1024
 _VMEM_LIMIT = 100 * 1024 * 1024
+#: a block is computed in ``_PARTS`` parts a side, those the diagonal or
+#: the window's edge leaves no pair in left out (``_tiles``) — where a
+#: part is whole lane tiles (``_LANES``: a v5e's vector registers are 8 x
+#: 128).  On the chip halves won: quarters of 256 read 30.2 ms forward and
+#: backward for the halves' 22.6 on Trinity's windowed row, the whole
+#: block 24.8 (``tools/attn_ab.py``; PERF.md, PR 43)
+_PARTS = 2
+_LANES = 128
 
 _NN = (((1,), (0,)), ((), ()))    # a @ b
 _NT = (((1,), (1,)), ((), ()))    # a @ b^T
@@ -109,41 +138,84 @@ def _dot(a, b, dims):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _mask(shape, q_axis, q_pos, k_pos, causal, doc_q, doc_k, window=0):
-    """May attend, for a block whose queries run along ``q_axis``:
-    causal, within ``window`` (query position - key position < window; 0:
-    none) and same document (``doc_q``, ``doc_k``: the refs of a column
-    and a row, or ``None``s); ``None`` where none applies."""
-    ok = None
+def _may_attend(k0, nk, k_axis, lo_q, hi_q):
+    """A tile's mask: its ``nk`` keys, at the positions ``k0 ..`` along
+    ``k_axis``, against its queries' two bounds (two columns, or two rows
+    beside a transposed tile) — one iota of a row or a column, two
+    compares and an ``and``."""
+    kpos = k0 + lax.broadcasted_iota(
+        jnp.int32, (1, nk) if k_axis else (nk, 1), k_axis)
+    return (kpos >= lo_q) & (kpos <= hi_q)
+
+
+def _parts(bq: int, bk: int) -> int:
+    """How many parts a side a block is computed in where the diagonal
+    or the window's edge crosses it: ``_PARTS`` where that cuts both
+    sides into whole lane tiles, else 1."""
+    whole = _PARTS * _LANES
+    return 1 if bq % whole or bk % whole else _PARTS
+
+
+def _part_seen(q0, k0, a, b, hq, hk, causal, window):
+    """Whether part ``(a, b)`` — ``hq`` queries by ``hk`` keys — of the
+    block whose first query stands at ``q0`` and first key at ``k0`` holds
+    a pair the diagonal and the window let attend (documents aside)."""
+    ok = True
     if causal:
-        ok = (q_pos + lax.broadcasted_iota(jnp.int32, shape, q_axis)
-              >= k_pos + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+        ok = k0 + b * hk <= q0 + a * hq + (hq - 1)
     if window:
-        near = (q_pos + lax.broadcasted_iota(jnp.int32, shape, q_axis)
-                - k_pos - lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-                < window)
-        ok = near if ok is None else ok & near
-    if doc_q is not None:
-        same = doc_q[0] == doc_k[0]
-        ok = same if ok is None else ok & same
+        near = q0 + a * hq - (k0 + b * hk + (hk - 1)) < window
+        ok = near if ok is True else ok & near
     return ok
 
 
-def _split(refs, n_in, has_doc):
+def _tiles(tile, live, offs, iq, ik, bq, bk, causal, window, by_keys):
+    """Run a live step as calls of ``tile(qs, ks)`` on slices of its query
+    and key blocks, chosen from the step's scalars: each part of one
+    block — the key block's with ``by_keys``, else the query block's —
+    against the parts of the other it can see, as ONE tile — they are a
+    run, the diagonal and the window's edge being monotone — and the
+    parts wholly above the diagonal or beyond the window in no tile.  A
+    block neither edge crosses is one side's parts against the whole
+    other block; documents are the bounds' (``_may_attend``).  The
+    forward walks the queries' parts (its recurrence is a row's); the two
+    backward kernels, which have none, the keys': on the chip ``dq`` read
+    3% less that way and ``dkv`` 9% less than on the whole block."""
+    from jax.experimental import pallas as pl
+
+    n = _parts(bq, bk) if causal or window else 1
+    hq, hk = bq // n, bk // n
+    q0, k0 = offs[0] + iq * bq, offs[1] + ik * bk
+    hr, hc = (hk, hq) if by_keys else (hq, hk)
+    for r in range(n):
+        seen = [_part_seen(q0, k0, *((c, r) if by_keys else (r, c)),
+                           hq, hk, causal, window) for c in range(n)]
+        for c0, c1 in ((a, b) for a in range(n) for b in range(a, n)):
+            # the other block's parts c0 .. c1 are seen, and no other
+            run = live
+            for c in range(n):
+                run = run & (seen[c] if c0 <= c <= c1
+                             else jnp.logical_not(seen[c]))
+            rows, cols = pl.ds(r * hr, hr), pl.ds(c0 * hc, (c1 - c0 + 1) * hc)
+            pl.when(run)(functools.partial(
+                tile, *((cols, rows) if by_keys else (rows, cols))))
+
+
+def _split(refs, n_in, masked):
     """A kernel's refs after the scalars: its ``n_in`` tensor inputs, the
-    queries' and the keys' document refs (or ``None``s), the rest."""
+    queries' two bounds (or ``None``s), the rest."""
     ins, rest = refs[:n_in], refs[n_in:]
-    if has_doc:
+    if masked:
         return ins, rest[0], rest[1], rest[2:]
     return ins, None, None, rest
 
 
 def _fwd_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
-                bq, bk, n, tb, causal, has_doc, scale, window=0):
+                bq, bk, n, tb, causal, window, masked, scale):
     from jax.experimental import pallas as pl
 
-    (q_ref, k_ref, v_ref), doc_q, doc_k, (o_ref, lse_ref, acc, m, l) = (
-        _split(refs, 3, has_doc))
+    (q_ref, k_ref, v_ref), lo_q, hi_q, (o_ref, lse_ref, acc, m, l) = (
+        _split(refs, 3, masked))
     s_i = pl.program_id(1)
     iq, ik, fl = iq_t[s_i], ik_t[s_i], fl_t[s_i]
     row = (pl.program_id(0) // tb) * n + iq if tb else iq
@@ -154,26 +226,31 @@ def _fwd_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
         m[:] = jnp.full_like(m, NEG_INF)
         l[:] = jnp.zeros_like(l)
 
-    @pl.when((ik >= lo_t[row]) & (ik <= hi_t[row]))
-    def _block():
-        s = _dot(q_ref[0], k_ref[0], _NT) * scale
-        ok = _mask((bq, bk), 0, offs[0] + iq * bq, offs[1] + ik * bk,
-                   causal, doc_q, doc_k, window)
-        if ok is not None:
-            s = jnp.where(ok, s, NEG_INF)
-        m_prev = m[:, :1]
+    def tile(qs, ks):
+        s = _dot(q_ref[0, qs, :], k_ref[0, ks, :], _NT) * scale
+        if masked:
+            s = jnp.where(_may_attend(ik * bk + ks.start, ks.size, 1,
+                                      lo_q[0, qs, :], hi_q[0, qs, :]),
+                          s, NEG_INF)
+        m_prev = m[qs, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        if ok is not None:
-            # a query row fully masked within a live block leaves m_new at
-            # NEG_INF, making exp(s - m_new) = 1 for every masked entry;
-            # zero such rows so `out` alone is valid even under the
-            # non-block-aligned offsets the public flash_mha_lse allows
-            p = jnp.where(m_new > NEG_INF * 0.5, p, 0.0)
-        l[:, :1] = l[:, :1] * corr + p.sum(axis=-1, keepdims=True)
-        m[:, :1] = m_new
-        acc[:] = acc[:] * corr + _dot(p.astype(v_ref.dtype), v_ref[0], _NN)
+        if masked:
+            # a query row masked whole within a live tile leaves m_new at
+            # NEG_INF; with 0 in its place (a column's pass, not the
+            # tile's) exp(s - .) = 0 for its entries, so `out` alone is
+            # valid even under the non-block-aligned offsets the public
+            # flash_mha_lse allows
+            p = jnp.exp(s - jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0))
+        else:
+            p = jnp.exp(s - m_new)
+        l[qs, :1] = l[qs, :1] * corr + p.sum(axis=-1, keepdims=True)
+        m[qs, :1] = m_new
+        acc[qs, :] = acc[qs, :] * corr + _dot(p.astype(v_ref.dtype),
+                                              v_ref[0, ks, :], _NN)
+
+    _tiles(tile, (ik >= lo_t[row]) & (ik <= hi_t[row]), offs, iq, ik, bq, bk,
+           causal, window, False)
 
     @pl.when((fl & _LAST) != 0)
     def _done():
@@ -183,11 +260,11 @@ def _fwd_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
 
 
 def _dq_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
-               bq, bk, n, tb, causal, has_doc, scale, window=0):
+               bq, bk, n, tb, causal, window, masked, scale):
     from jax.experimental import pallas as pl
 
-    ((q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref), doc_q, doc_k,
-     (out_ref, acc)) = _split(refs, 6, has_doc)
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref), lo_q, hi_q,
+     (out_ref, acc)) = _split(refs, 6, masked)
     s_i = pl.program_id(1)
     iq, ik, fl = iq_t[s_i], ik_t[s_i], fl_t[s_i]
     row = (pl.program_id(0) // tb) * n + iq if tb else iq
@@ -196,18 +273,19 @@ def _dq_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
-    @pl.when((ik >= lo_t[row]) & (ik <= hi_t[row]))
-    def _block():
-        kb = k_ref[0]
-        s = _dot(q_ref[0], kb, _NT) * scale
-        p = jnp.exp(s - lse_ref[0])
-        ok = _mask((bq, bk), 0, offs[0] + iq * bq, offs[1] + ik * bk,
-                   causal, doc_q, doc_k, window)
-        if ok is not None:
-            p = jnp.where(ok, p, 0.0)
-        dp = _dot(do_ref[0], v_ref[0], _NT)
-        ds = p * (dp - dl_ref[0])
-        acc[:] += _dot(ds.astype(kb.dtype), kb, _NN)
+    def tile(qs, ks):
+        kb = k_ref[0, ks, :]
+        s = _dot(q_ref[0, qs, :], kb, _NT) * scale
+        p = jnp.exp(s - lse_ref[0, qs, :])
+        if masked:
+            p = jnp.where(_may_attend(ik * bk + ks.start, ks.size, 1,
+                                      lo_q[0, qs, :], hi_q[0, qs, :]), p, 0.0)
+        dp = _dot(do_ref[0, qs, :], v_ref[0, ks, :], _NT)
+        ds = p * (dp - dl_ref[0, qs, :])
+        acc[qs, :] += _dot(ds.astype(kb.dtype), kb, _NN)
+
+    _tiles(tile, (ik >= lo_t[row]) & (ik <= hi_t[row]), offs, iq, ik, bq, bk,
+           causal, window, True)
 
     @pl.when((fl & _LAST) != 0)
     def _done():
@@ -215,13 +293,13 @@ def _dq_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
 
 
 def _dkv_kernel(offs, ik_t, iq_t, fl_t, lo_t, hi_t, g_t, *refs,
-                bq, bk, n, tb, causal, has_doc, scale, window=0):
+                bq, bk, n, tb, causal, window, masked, scale):
     """The transposed block: keys along the sublanes, queries along the
-    lanes; ``lse``, ``delta`` and the queries' documents are rows."""
+    lanes; ``lse``, ``delta`` and the queries' bounds are rows."""
     from jax.experimental import pallas as pl
 
-    ((q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref), doc_q, doc_k,
-     (dk_out, dv_out, kacc, vacc)) = _split(refs, 6, has_doc)
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref), lo_q, hi_q,
+     (dk_out, dv_out, kacc, vacc)) = _split(refs, 6, masked)
     s_i = pl.program_id(1)
     iq, ik, fl = iq_t[s_i], ik_t[s_i], fl_t[s_i]
     row = (pl.program_id(0) // tb) * n + ik if tb else ik
@@ -231,19 +309,20 @@ def _dkv_kernel(offs, ik_t, iq_t, fl_t, lo_t, hi_t, g_t, *refs,
         kacc[:] = jnp.zeros_like(kacc)
         vacc[:] = jnp.zeros_like(vacc)
 
-    @pl.when((iq >= lo_t[row]) & (iq <= hi_t[row]))
-    def _block():
-        qb, dob = q_ref[0], do_ref[0]
-        s = _dot(k_ref[0], qb, _NT) * scale
-        p = jnp.exp(s - lse_ref[0])
-        ok = _mask((bk, bq), 1, offs[0] + iq * bq, offs[1] + ik * bk,
-                   causal, doc_q, doc_k, window)
-        if ok is not None:
-            p = jnp.where(ok, p, 0.0)
-        vacc[:] += _dot(p.astype(dob.dtype), dob, _NN)
-        dp = _dot(v_ref[0], dob, _NT)
-        ds = p * (dp - dl_ref[0])
-        kacc[:] += _dot(ds.astype(qb.dtype), qb, _NN)
+    def tile(qs, ks):
+        qb, dob = q_ref[0, qs, :], do_ref[0, qs, :]
+        s = _dot(k_ref[0, ks, :], qb, _NT) * scale
+        p = jnp.exp(s - lse_ref[0, :, qs])
+        if masked:
+            p = jnp.where(_may_attend(ik * bk + ks.start, ks.size, 0,
+                                      lo_q[0, :, qs], hi_q[0, :, qs]), p, 0.0)
+        vacc[ks, :] += _dot(p.astype(dob.dtype), dob, _NN)
+        dp = _dot(v_ref[0, ks, :], dob, _NT)
+        ds = p * (dp - dl_ref[0, :, qs])
+        kacc[ks, :] += _dot(ds.astype(qb.dtype), qb, _NN)
+
+    _tiles(tile, (iq >= lo_t[row]) & (iq <= hi_t[row]), offs, iq, ik, bq, bk,
+           causal, window, True)
 
     @pl.when((fl & _LAST) != 0)
     def _done():
@@ -343,6 +422,32 @@ def _ranges(doc, offs, nq: int, nk: int, bq: int, bk: int, causal: bool,
             jnp.clip(qhi, -1, nq - 1).reshape(-1))
 
 
+def _bounds(doc, offs, t: int, tk: int, causal: bool, window: int = 0):
+    """``(lo_q, hi_q)``, int32 ``(B, T)`` with documents and ``(1, T)``
+    without: query ``i`` may attend exactly the keys at ``lo_q[i] <= j <=
+    hi_q[i]``, ``j`` counted from the keys' own first row.  Documents are
+    runs along T and the causal edge and the window are intervals, so the
+    whole mask is two integers a query: ``lo_q`` the later of its
+    document's first position and ``i - window + 1``, ``hi_q`` the
+    earlier of its document's last position and (causal) its own — under
+    position offsets ``i + q_off - k_off``, below 0 for a query before
+    every key."""
+    pos = jnp.arange(t, dtype=jnp.int32)[None]
+    lo, hi = jnp.zeros_like(pos), jnp.full_like(pos, tk - 1)
+    if doc is not None:
+        edge = doc[:, 1:] != doc[:, :-1]
+        ends = jnp.ones_like(doc[:, :1], bool)
+        lo = lax.cummax(jnp.where(jnp.concatenate([ends, edge], 1), pos, 0),
+                        axis=1)
+        hi = lax.cummin(jnp.where(jnp.concatenate([edge, ends], 1), pos,
+                                  t - 1), axis=1, reverse=True)
+    if causal:
+        hi = jnp.minimum(hi, pos + (offs[0] - offs[1]))
+    if window:
+        lo = jnp.maximum(lo, pos - (window - 1))
+    return lo, hi
+
+
 def _clamp(x, lo, hi):
     """``x`` into ``[lo, hi]``, and ``lo`` where the range is empty."""
     return jnp.minimum(jnp.maximum(x, lo), jnp.maximum(hi, lo))
@@ -386,15 +491,47 @@ class _Geometry:
         self.fwd_t, self.bwd_t = _steps(
             self.nq, self.nk, bq, bk,
             causal and not dyn and self.t == self.tk, self.group, window)
-        if self.has_doc:
-            doc = doc.astype(jnp.int32)
-            self.doc_col, self.doc_row = doc[:, :, None], doc[:, None, :]
+        #: nothing masks a call that is not causal and has no window and
+        #: no documents: no bounds, no operands
+        self.masked = bool(causal or window or self.has_doc)
+        self.doc = doc
+        if self.masked:
+            self.bounds = _bounds(doc, self.offs, self.t, self.tk, causal,
+                                  window)
 
     def kernel(self, fn, bwd: bool):
         return functools.partial(
             fn, bq=self.bq, bk=self.bk, tb=self.tb[bwd],
             n=self.nk if bwd else self.nq, causal=self.causal,
-            has_doc=self.has_doc, scale=self.scale, window=self.window)
+            window=self.window, masked=self.masked, scale=self.scale)
+
+    def classes(self):
+        """``(live, full, one)``, bool ``(table rows, forward steps)``: the
+        steps of the forward table that compute (the key block in the
+        query block's live range); those of them whose EVERY pair may
+        attend — both blocks in one and the same document, the key block
+        wholly under the diagonal and inside the window; those whose two
+        blocks lie in one document (the second kind, and the blocks only
+        the diagonal or the window's edge crosses)."""
+        iq, ik = self.fwd_t[0], self.fwd_t[1]
+        lo, hi = (r.reshape(-1, self.nq)[:, iq] for r in self.ranges[:2])
+        live = (ik >= lo) & (ik <= hi)
+        one = full = jnp.ones_like(live)
+        if self.has_doc:
+            # ``doc`` does not decrease along T: a block's first and last
+            # entries say whether it lies in one document, and which
+            ends = lambda n, b: self.doc.reshape(-1, n, b)[:, :, (0, -1)]
+            dq = ends(self.nq, self.bq)[:, iq]
+            dk = ends(self.nk, self.bk)[:, ik]
+            one = full = ((dq[..., 0] == dq[..., 1])
+                          & (dk[..., 0] == dk[..., 1])
+                          & (dq[..., 0] == dk[..., 0]))
+        q0, k0 = self.offs[0] + iq * self.bq, self.offs[1] + ik * self.bk
+        if self.causal:
+            full = full & (k0 + (self.bk - 1) <= q0)
+        if self.window:
+            full = full & (q0 + (self.bq - 1) - k0 < self.window)
+        return live, live & full, live & one
 
     def specs(self, bwd: bool):
         """``(q-side spec of a width, k-side spec of a width, the spec of a
@@ -423,7 +560,7 @@ class _Geometry:
         else:
             q_head = lambda b, s, *sc: b
             k_head = lambda b, s, *sc: b // g
-        doc_b = lambda b, s, *sc: b // tb
+        row_b = lambda b, s, *sc: b // tb if tb else 0
 
         def spec(block, head, blk, t_axis):
             """A ``(1, ...)`` block whose T axis is ``t_axis``."""
@@ -435,24 +572,23 @@ class _Geometry:
 
         qs = lambda width: spec((1, self.bq, width), q_head, q_blk, 1)
         ks = lambda width: spec((1, self.bk, width), k_head, k_blk, 1)
-        # a query block's per-row scalars: a column beside the resident
-        # query block, a row beside the transposed one
+        # a query block's per-row scalars (its two bounds: one row of
+        # them a table row): a column beside the resident query block, a
+        # row beside the transposed one
         if bwd:
             qrow = spec((1, 1, self.bq), q_head, q_blk, 2)
-            docs = [spec((1, 1, self.bq), doc_b, q_blk, 2),
-                    spec((1, self.bk, 1), doc_b, k_blk, 1)]
+            bound = spec((1, 1, self.bq), row_b, q_blk, 2)
         else:
             qrow = spec((1, self.bq, 1), q_head, q_blk, 1)
-            docs = [spec((1, self.bq, 1), doc_b, q_blk, 1),
-                    spec((1, 1, self.bk), doc_b, k_blk, 2)]
-        return qs, ks, qrow, (docs if self.has_doc else [])
+            bound = spec((1, self.bq, 1), row_b, q_blk, 1)
+        return qs, ks, qrow, ([bound, bound] if self.masked else [])
 
-    def docs(self, bwd: bool):
-        """The queries' and the keys' documents as ``specs`` reads them."""
-        if not self.has_doc:
+    def bound_operands(self, bwd: bool):
+        """The queries' two bounds as ``specs`` reads them."""
+        if not self.masked:
             return ()
-        return ((self.doc_row, self.doc_col) if bwd
-                else (self.doc_col, self.doc_row))
+        return tuple(x[:, None, :] if bwd else x[:, :, None]
+                     for x in self.bounds)
 
     def call(self, kern, bwd, in_specs, out_specs, out_shape, scratch,
              operands, name, interpret):
@@ -483,18 +619,18 @@ def _flash_fwd_raw(q, k, v, geo: _Geometry, interpret):
     without a relayout."""
     from jax.experimental.pallas import tpu as pltpu
 
-    qs, ks, qrow, docs = geo.specs(False)
+    qs, ks, qrow, bounds = geo.specs(False)
     bq, dv = geo.bq, geo.dv
     return geo.call(
         _fwd_kernel, False,
-        [qs(geo.dqk), ks(geo.dqk), ks(dv), *docs],
+        [qs(geo.dqk), ks(geo.dqk), ks(dv), *bounds],
         [qs(dv), qrow],
         [jax.ShapeDtypeStruct((geo.bh, geo.t, dv), q.dtype),
          jax.ShapeDtypeStruct((geo.bh, geo.t, 1), jnp.float32)],
         [pltpu.VMEM((bq, dv), jnp.float32),
          pltpu.VMEM((bq, 128), jnp.float32),
          pltpu.VMEM((bq, 128), jnp.float32)],
-        (q, k, v, *geo.docs(False)), "flash_fwd", interpret)
+        (q, k, v, *geo.bound_operands(False)), "flash_fwd", interpret)
 
 
 def _flash_bwd_raw(q, k, v, do, lse, dl, geo: _Geometry, interpret):
@@ -503,27 +639,28 @@ def _flash_bwd_raw(q, k, v, do, lse, dl, geo: _Geometry, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     bq, bk, dqk, dv = geo.bq, geo.bk, geo.dqk, geo.dv
-    qs, ks, qrow, docs = geo.specs(False)
+    qs, ks, qrow, bounds = geo.specs(False)
     dq = geo.call(
         _dq_kernel, False,
-        [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *docs],
+        [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *bounds],
         qs(dqk), jax.ShapeDtypeStruct(q.shape, q.dtype),
         [pltpu.VMEM((bq, dqk), jnp.float32)],
-        (q, k, v, do, lse, dl, *geo.docs(False)), "flash_dq", interpret)
+        (q, k, v, do, lse, dl, *geo.bound_operands(False)), "flash_dq",
+        interpret)
 
     # the key block is the resident operand; the query heads of its
     # group and their query blocks sweep innermost
-    qs, ks, qrow, docs = geo.specs(True)
+    qs, ks, qrow, bounds = geo.specs(True)
     as_rows = lambda x: x.reshape(geo.bh, 1, geo.t)
     dk, dv_ = geo.call(
         _dkv_kernel, True,
-        [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *docs],
+        [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *bounds],
         [ks(dqk), ks(dv)],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
         [pltpu.VMEM((bk, dqk), jnp.float32),
          pltpu.VMEM((bk, dv), jnp.float32)],
-        (q, k, v, do, as_rows(lse), as_rows(dl), *geo.docs(True)),
+        (q, k, v, do, as_rows(lse), as_rows(dl), *geo.bound_operands(True)),
         "flash_dkv", interpret)
     return dq, dk, dv_
 
@@ -540,25 +677,45 @@ def _flash(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
                       heads, interpret, window)[0]
 
 
-def _flash_fwd(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
-               interpret, window=0):
+#: what a call is compiled for, beside its operands' shapes
+_STATIC = ("causal", "scale", "bq", "bk", "heads", "interpret", "window")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _forward(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
+             interpret, window):
+    """The forward call under ONE ``jax.jit``: the layers of a net that
+    call it with the same shapes and settings share one trace of the
+    kernel and one lowering of it in a program (a step program's set-up
+    pays a Mosaic lowering a distinct call, not a layer)."""
     geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
                     window)
-    out, lse = _flash_fwd_raw(q, k, v, geo, interpret)
-    return (out, lse), (q, k, v, doc, q_off, k_off, out, lse)
+    return _flash_fwd_raw(q, k, v, geo, interpret)
 
 
-def _flash_bwd(causal, scale, bq, bk, heads, interpret, window, res, cts):
-    q, k, v, doc, q_off, k_off, out, lse = res
-    g, g_lse = cts
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _backward(q, k, v, doc, q_off, k_off, out, lse, g, g_lse, causal, scale,
+              bq, bk, heads, interpret, window):
+    """The two backward calls, shared the same way."""
     geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
                     window)
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(
         -1, keepdims=True)
     # dL/dlse_i adds p_ij * dlse_i to ds_ij; the kernels compute
     # ds = p * (dp - dl) so dl = delta - dlse absorbs it
-    dq, dk, dv = _flash_bwd_raw(q, k, v, g, lse, delta - g_lse, geo,
-                                interpret)
+    return _flash_bwd_raw(q, k, v, g, lse, delta - g_lse, geo, interpret)
+
+
+def _flash_fwd(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
+               interpret, window=0):
+    out, lse = _forward(q, k, v, doc, q_off, k_off, causal, scale, bq, bk,
+                        heads, interpret, window)
+    return (out, lse), (q, k, v, doc, q_off, k_off, out, lse)
+
+
+def _flash_bwd(causal, scale, bq, bk, heads, interpret, window, res, cts):
+    dq, dk, dv = _backward(*res, *cts, causal, scale, bq, bk, heads,
+                           interpret, window)
     return dq, dk, dv, None, None, None
 
 
@@ -587,6 +744,34 @@ def block_for(q, k, v):
           and q.dtype == k.dtype == v.dtype
           and v.dtype in (jnp.bfloat16, jnp.float32))
     return block if ok else None
+
+
+def count_blocks(q, k, v, *, causal: bool = False, doc=None, window: int = 0,
+                 block_q: int | None = None, block_k: int | None = None):
+    """What the forward kernel visits for ``flash_attention(q, k, v,
+    ...)``, uint32 ``(3,)``: the live steps of its table (a query block
+    against a key block of its live range) over all query heads and rows;
+    those of them whose every pair may attend (no mask would be needed);
+    those whose two blocks lie in one document (the second kind, and the
+    ones only the diagonal or the window's edge crosses).  Summed in XLA
+    from the tables the kernels read (``_Geometry.classes``).  The blocks
+    default to ``block_for``'s, as ``ops/attention.attend`` calls the
+    kernels; zeros where it has none (``mha`` computes such a call)."""
+    b, t, h, dqk = q.shape
+    if block_q is None:
+        block_q = block_k = block_for(q, k, v)
+        if block_q is None:
+            return jnp.zeros(3, jnp.uint32)
+    geo = _Geometry(
+        jax.ShapeDtypeStruct((b * h, t, dqk), q.dtype), None,
+        jax.ShapeDtypeStruct((b * k.shape[2], k.shape[1], v.shape[-1]),
+                             v.dtype),
+        doc, None, None, bool(causal), 1.0, _pick_block(t, block_q),
+        _pick_block(k.shape[1], block_k), h, int(window))
+    classes = geo.classes()
+    rows = classes[0].shape[0]
+    return jnp.stack([c.sum(dtype=jnp.uint32) for c in classes]) * jnp.uint32(
+        b * h // rows)
 
 
 def _fold(x):

@@ -34,6 +34,19 @@ def rng():
     return np.random.RandomState(42)
 
 
+@pytest.fixture
+def split(request, monkeypatch):
+    """The flash kernels compute a block in parts, those above the
+    diagonal or beyond the window left out (``ops/flash._tiles``), only
+    where a part is whole lane tiles; ``split = True`` (an indirect
+    parameter) lets the tests' blocks of 16 and 32 split too."""
+    from cxxnet_tpu.ops import flash
+
+    if request.param:
+        monkeypatch.setattr(flash, "_LANES", 8)
+    return request.param
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process / long-running tests"

@@ -394,8 +394,11 @@ def test_the_builder_s_conf_trains_and_counts_its_pairs(tmp_path):
         np.asarray(tr.params["l4_moe1"]["postnorm"]), post)
     stats = pipeline_stats()
     tokens = stats.counters().get("attn_tokens", 0)
+    blocks = stats.counters().get("attn_blocks", 0)
     tr.count_layer_state()
     assert stats.counters()["attn_tokens"] - tokens == 8 * 64 * 3
+    # mha's rows computed them off the TPU: no block of the kernels'
+    assert stats.counters().get("attn_blocks", 0) == blocks
     with pytest.raises(ValueError, match="string of s and f"):
         afmoe_conf(layer_types="sxf")
     with pytest.raises(ValueError, match="num_dense_layers"):
@@ -503,11 +506,13 @@ def test_the_layer_says_what_a_window_does_not_go_with():
     # a window alone takes the masked path and its counters
     lay, p, _ = make("attention", [(1, 16, 32)], nhead=4, causal=1, window=8)
     assert not lay._plain(1) and set(lay.init_aux([(1, 16, 32)])) == {
-        "attn_tokens", "attn_tokens_flash"}
+        "attn_tokens", "attn_tokens_flash", "attn_blocks",
+        "attn_blocks_unmasked"}
     x = jnp.asarray(np.random.RandomState(2).randn(1, 16, 32), jnp.float32)
     (y,), aux = lay.apply_stateful(p, lay.init_aux([(1, 16, 32)]), [x])
     assert int(aux["attn_tokens"]) == 16 and int(
-        aux["attn_tokens_flash"]) == 0
+        aux["attn_tokens_flash"]) == 0 and int(aux["attn_blocks"]) == int(
+            aux["attn_blocks_unmasked"]) == 0
     # and names its scope for the trace's readers
     hlo = jax.jit(lambda a: lay.apply(p, [a])[0]).lower(x).as_text(
         debug_info=True)

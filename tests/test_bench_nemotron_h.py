@@ -18,8 +18,9 @@ def test_the_cell_is_the_one_the_issue_names():  # noqa: F811
     PR 40's three, which the test under ``benchmarks/`` holds to be the
     LAST three and a PR that adds a metric may not edit; PR 42's four
     readers of the windowed attention's scopes and counters go behind
-    that, and its cell lists ``mlp_ms_step``; a ``benchmark`` PR folds
-    this back."""
+    that, and its cell lists ``mlp_ms_step``; PR 43's share of the flash
+    kernels' blocks whose every pair may attend is the last; a ``benchmark`` PR
+    folds this back."""
     from benchmarks.tests import test_nemotron_h as n
 
     bench = n.run.load_json(os.path.join(n.ROOT, "BENCHMARK.json"))
@@ -49,4 +50,6 @@ def test_the_cell_is_the_one_the_issue_names():  # noqa: F811
     assert listed[at:at + 4] == n.NEW_METRICS + ["ssd_scan_fused_pct"]
     assert listed[at + 4:] == [
         "attn_window_core_ms_step", "attn_full_core_ms_step",
-        "attn_window_pairs_pct", "attn_core_roofline_pct"]
+        "attn_window_pairs_pct", "attn_core_roofline_pct",
+        "attn_unmasked_blocks_pct"]
+    assert by_name["attn_unmasked_blocks_pct"]["workloads"][3] == n.CELL

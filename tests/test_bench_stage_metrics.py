@@ -138,6 +138,32 @@ def test_attn_flash_pct_reads_the_two_attention_counters(rounds, want):
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
+# attn_unmasked_blocks_pct (PR 43): the same layers count the blocks the
+# flash kernels' forward visits and those of them whose every pair may
+# attend
+@pytest.mark.parametrize("rounds, want", [
+    # Trinity's five layers x 32 heads x 24 steps: a third wholly live
+    ([{"attn_blocks": 180000, "attn_blocks_unmasked": 60000,
+       "attn_tokens": 1966080, "attn_tokens_flash": 1966080}] * 2,
+     100.0 / 3),
+    # a round of one long document a row, and one of short documents
+    ([{"attn_blocks": 1000, "attn_blocks_unmasked": 1000},
+      {"attn_blocks": 3000, "attn_blocks_unmasked": 0}], 25.0),
+    ([{"attn_blocks": 1000}], 0.0),
+    # the parent counts tokens and no blocks; mha's rows visit none
+    ([{"attn_tokens": 196608, "attn_tokens_flash": 196608}], None),
+    ([{"attn_tokens": 196608, "attn_blocks": 0,
+       "attn_blocks_unmasked": 0}], None),
+    ([{}], None),
+    ([], None),
+])
+def test_attn_unmasked_blocks_pct_reads_the_two_block_counters(rounds, want):
+    read = run.load_metric("attn_unmasked_blocks_pct").read
+    assert read(_counted(*rounds)) == (
+        want if want is None else pytest.approx(want))
+    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
+
+
 # expert_dispatch_compact_pct (PR 39): the routed expert layers count the
 # pairs they computed in slabs after the first, inside the step programs
 @pytest.mark.parametrize("rounds, want", [
@@ -283,6 +309,7 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
     ("attn_window_pairs_pct", ALL_CELLS[6:], "lower"),
     ("attn_core_roofline_pct", ALL_CELLS[6:], "higher"),
     ("attn_flash_pct", ALL_CELLS[2:], "higher"),
+    ("attn_unmasked_blocks_pct", ALL_CELLS[2:], "higher"),
     ("expert_dispatch_compact_pct", ALL_CELLS[3:], "higher"),
 ] + [(name, ALL_CELLS, "lower") for name in LOOP_BILL])
 def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
@@ -306,7 +333,7 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
     assert names[42:46] == ["mla_ms_step", "mla_core_ms_step",
                             "mla_core_roofline_pct", "mtp_ms_step"]
     # PR 37's one behind them, PR 38's five behind that, and PR 39's
-    # one; PR 40's three; PR 41's one; PR 42's four, the last
+    # one; PR 40's three; PR 41's one; PR 42's four; PR 43's one, the last
     assert names[46:47] == ["attn_flash_pct"]
     assert names[47:52] == LOOP_BILL
     assert names[52:] == ["expert_dispatch_compact_pct",
@@ -317,4 +344,5 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
                           "attn_window_core_ms_step",
                           "attn_full_core_ms_step",
                           "attn_window_pairs_pct",
-                          "attn_core_roofline_pct"]
+                          "attn_core_roofline_pct",
+                          "attn_unmasked_blocks_pct"]

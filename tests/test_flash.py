@@ -31,8 +31,9 @@ def test_flash_forward_matches_mha(causal):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("split", [False, True], indirect=True)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_grads_match_mha(causal):
+def test_flash_grads_match_mha(causal, split):
     q, k, v = _qkv(t=32, d=8)
 
     def loss_ref(q, k, v):
@@ -78,7 +79,8 @@ def test_pick_block():
     assert _pick_block(7, 128) == 7
 
 
-def test_flash_cross_attention_lengths():
+@pytest.mark.parametrize("split", [False, True], indirect=True)
+def test_flash_cross_attention_lengths(split):
     # Tq != Tk (e.g. decoder cross-attention)
     rng = np.random.RandomState(3)
     q = jnp.asarray(rng.randn(2, 16, 2, 8).astype(np.float32))
@@ -211,11 +213,16 @@ def test_attention_layer_ring_pallas_matches_xla_ring():
     )
 
 
-def test_flash_lse_fully_masked_rows_are_zero():
+@pytest.mark.parametrize("k_off, dead", [(8, 8), (40, 32)],
+                         ids=["some_rows", "every_key_after_every_query"])
+@pytest.mark.parametrize("split", [False, True], indirect=True)
+def test_flash_lse_fully_masked_rows_are_zero(k_off, dead, split):
     """Misaligned offsets can fully mask a query row inside a live block
     (causal, keys strictly in the row's future): `out` must be zeros for
     that row — not a mean of v (the exp(s - NEG_INF)=1 failure) — so
-    `out` is valid standalone, not only jointly with lse."""
+    `out` is valid standalone, not only jointly with lse.  A hop whose
+    keys ALL lie after its queries is such rows only, and its gradients
+    are finite (and zero)."""
     from cxxnet_tpu.ops.flash import flash_mha_lse
 
     b, t, h, d = 1, 32, 2, 16
@@ -223,16 +230,25 @@ def test_flash_lse_fully_masked_rows_are_zero():
     q = jnp.asarray(rng.randn(b, t, h, d).astype(np.float32))
     k = jnp.asarray(rng.randn(b, t, h, d).astype(np.float32))
     v = jnp.asarray(rng.randn(b, t, h, d).astype(np.float32))
-    # keys start 8 positions after the queries: query rows 0..7 see no
-    # key at all under the causal mask
-    out, lse = flash_mha_lse(q, k, v, q_off=0, k_off=8, causal=True,
+
+    # keys start k_off positions after the queries: the first `dead`
+    # query rows see no key at all under the causal mask
+    def hop(q, k, v):
+        return flash_mha_lse(q, k, v, q_off=0, k_off=k_off, causal=True,
                              block_q=16, block_k=16, interpret=True)
+
+    out, lse = hop(q, k, v)
     out = np.asarray(out)
-    np.testing.assert_array_equal(out[:, :8], np.zeros_like(out[:, :8]))
+    np.testing.assert_array_equal(out[:, :dead], np.zeros_like(out[:, :dead]))
     # the masked rows' lse stays ~NEG_INF so a ring merge washes them out
-    assert np.all(np.asarray(lse)[:, :8] < -1e29)
+    assert np.all(np.asarray(lse)[:, :dead] < -1e29)
     # live rows are real attention outputs
-    assert np.abs(out[:, 8:]).max() > 0
+    assert dead == t or np.abs(out[:, dead:]).max() > 0
+    grads = jax.grad(lambda *a: hop(*a)[0].sum() + jnp.where(
+        hop(*a)[1] > -1e29, hop(*a)[1], 0.0).sum(), (0, 1, 2))(q, k, v)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+        assert dead < t or not np.asarray(g).any()
 
 
 # ------------------------------------------------ the masked kernels (PR 37)
@@ -297,6 +313,15 @@ DOC_CASES = {
 def test_document_mask_matches_mha(case, causal):
     q, k, v = _masked(2, 2, 16, 16)
     _hold_against_mha(q, k, v, _docs(DOC_CASES[case], 64), causal, None,
+                      16, 16, 2e-5)
+
+
+@pytest.mark.parametrize("split", [True], indirect=True)
+@pytest.mark.parametrize("case", sorted(DOC_CASES))
+def test_document_mask_in_parts_matches_mha(case, split):
+    """The diagonal's blocks in parts of 8, the dead ones left out."""
+    q, k, v = _masked(2, 2, 16, 16)
+    _hold_against_mha(q, k, v, _docs(DOC_CASES[case], 64), True, None,
                       16, 16, 2e-5)
 
 

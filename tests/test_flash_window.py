@@ -6,6 +6,13 @@ and larger than a block, forward and gradients; the static step table
 visits every block that holds a live pair and none wholly outside the
 window; the live ranges are the brute-force mask's blocks; ``window =
 0`` builds the tables and the program the parent built.
+
+A step's class (ISSUE 43): the forward table's live steps counted as
+needing no mask are exactly the blocks whose every pair may attend under
+a mask written out pair by pair, the two bounds a query ARE that mask, a
+part of a block is left out only where no pair of it may attend, and
+rows with wholly live, position-only and document-boundary blocks in one
+call give ``mha``'s outputs, log-sum-exp and gradients.
 """
 
 import jax
@@ -14,8 +21,9 @@ import numpy as np
 import pytest
 
 from cxxnet_tpu.ops.attention import attend, mha
-from cxxnet_tpu.ops.flash import (_FIRST, _LAST, _offs, _ranges, _steps,
-                                  block_for, flash_attention, flash_mha_lse)
+from cxxnet_tpu.ops.flash import (_FIRST, _LAST, _Geometry, _bounds, _offs,
+                                  _ranges, _steps, block_for, count_blocks,
+                                  flash_attention, flash_mha_lse)
 
 T = 64
 
@@ -84,6 +92,19 @@ DOCS = {"one_document": None,
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("case", sorted(DOCS))
 def test_the_kernels_under_a_window_are_mha_s(case, causal, window):
+    _hold_windowed(case, causal, window)
+
+
+# the same in parts of 8: the diagonal's and the window's edge inside a
+# block, at its edge, and a window no wider than a part
+@pytest.mark.parametrize("split", [True], indirect=True)
+@pytest.mark.parametrize("window", [5, 16, 24])
+@pytest.mark.parametrize("case", sorted(DOCS))
+def test_the_kernels_in_parts_under_a_window_are_mha_s(case, window, split):
+    _hold_windowed(case, True, window)
+
+
+def _hold_windowed(case, causal, window):
     q, k, v = _qkv(4, 2)
     doc = None if DOCS[case] is None else _docs(DOCS[case])
 
@@ -114,7 +135,9 @@ def test_mha_s_window_is_the_mask_written_out(causal, block_q, window):
     (16, 32, 4, 4, jnp.float32, 2e-5),
     (16, 16, 4, 1, jnp.bfloat16, 3e-2),
 ])
-def test_unequal_blocks_groups_and_bfloat16(bq, bk, h, hk, dtype, tol):
+@pytest.mark.parametrize("split", [False, True], indirect=True)
+def test_unequal_blocks_groups_and_bfloat16(bq, bk, h, hk, dtype, tol,
+                                            split):
     q, k, v = _qkv(h, hk, dtype=dtype, seed=2)
     doc = _docs([[20], [7, 50]])
 
@@ -315,3 +338,203 @@ def test_attend_lowers_the_windowed_kernels_for_a_tpu(window):
     want = mha(q, k, v, causal=True, doc=doc, window=window, block_q=512)
     np.testing.assert_array_equal(np.asarray(o, np.float32),
                                   np.asarray(want, np.float32))
+
+
+# ------------------------------- a step's class, and a query's bounds
+def _brute_mask(doc, t, tk, causal, window, q_off=0, k_off=0):
+    """``(B, T, Tk)`` bool, written out pair by pair."""
+    back = (q_off + np.arange(t))[:, None] - (k_off + np.arange(tk))[None, :]
+    ok = np.ones((1 if doc is None else doc.shape[0], t, tk), bool)
+    if causal:
+        ok &= (back >= 0)[None]
+    if window:
+        ok &= (back < window)[None]
+    if doc is not None:
+        ok &= doc[:, :, None] == doc[:, None, :]
+    return ok
+
+
+def _geometry(b, t, tk, h, hk, doc, q_off, k_off, causal, bq, bk, window=0):
+    shape = lambda n, length: jax.ShapeDtypeStruct((n, length, 16),
+                                                   jnp.float32)
+    return _Geometry(shape(b * h, t), None, shape(b * hk, tk), doc, q_off,
+                     k_off, causal, 1.0, bq, bk, h, window)
+
+
+def _hold_classes(geo, ok):
+    """``geo.classes()`` and ``_bounds`` against the mask ``ok``."""
+    blocks = ok.reshape(ok.shape[0], geo.nq, geo.bq, geo.nk, geo.bk)
+    iq, ik = geo.fwd_t[0], geo.fwd_t[1]
+    live, full, one = (np.asarray(c) for c in geo.classes())
+    # a visited step computes where its block holds a live pair, and is
+    # counted as needing no mask where — and ONLY where — every pair of it
+    # is live
+    np.testing.assert_array_equal(live, blocks.any(axis=(2, 4))[:, iq, ik])
+    np.testing.assert_array_equal(full, blocks.all(axis=(2, 4))[:, iq, ik])
+    assert not (full & ~one).any() and not (one & ~live).any()
+    return live, full, one
+
+
+#: rows of 64 tokens: documents inside a block of 16, at a block's edge,
+#: over several blocks, one a row, a last one of one token
+CLASS_DOCS = {"no_documents": None,
+              "inside_a_block": [[5, 40], [27]],
+              "at_a_block_s_edge": [[16, 48], [32]],
+              "over_several_blocks": [[56], [3, 60]],
+              "one_a_row": [[], []],
+              "a_last_one_of_one_token": [[63], [20, 63]]}
+
+
+# no window, one smaller than, equal to and larger than a block
+@pytest.mark.parametrize("window", [0, 7, 16, 40])
+@pytest.mark.parametrize("bq, bk", [(16, 16), (32, 16), (16, 32)])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("case", sorted(CLASS_DOCS))
+def test_a_step_is_counted_wholly_live_only_where_every_pair_may_attend(
+        case, causal, bq, bk, window):
+    doc = None if CLASS_DOCS[case] is None else _docs(CLASS_DOCS[case])
+    b, h = 2, 4
+    geo = _geometry(b, T, T, h, 2, doc, None, None, causal, bq, bk, window)
+    ok = _brute_mask(None if doc is None else np.asarray(doc), T, T, causal,
+                     window)
+    live, full, one = _hold_classes(geo, ok)
+    if doc is not None:
+        d = np.asarray(doc)
+        iq, ik = geo.fwd_t[0], geo.fwd_t[1]
+        qd, kd = d.reshape(b, -1, bq), d.reshape(b, -1, bk)
+        same = ((qd.min(2) == qd.max(2))[:, iq] & (kd.min(2) == kd.max(2))[
+            :, ik] & (qd[:, iq, 0] == kd[:, ik, 0]))
+        np.testing.assert_array_equal(one, live & same)
+    if geo.masked:
+        # the two bounds a query ARE the mask
+        lo, hi = (np.asarray(x)[:, :, None] for x in geo.bounds)
+        j = np.arange(T)[None, None, :]
+        np.testing.assert_array_equal((j >= lo) & (j <= hi), ok)
+    else:
+        assert full.all()
+    # the layers' counters: the same classes over all heads and rows
+    q, k, v = _qkv(h, 2)
+    rows = live.shape[0]
+    np.testing.assert_array_equal(
+        count_blocks(q, k, v, causal=causal, doc=doc, window=window,
+                     block_q=bq, block_k=bk),
+        [c.sum() * (b * h // rows) for c in (live, full, one)])
+
+
+@pytest.mark.parametrize("q_off, k_off", [
+    (0, 0), (8, 0), (0, 8), (64, 0), (0, 64), (16, 16), (37, 5), (5, 37)])
+@pytest.mark.parametrize("t, tk, bq, bk", [(64, 64, 16, 16), (32, 64, 16, 32)])
+def test_a_step_s_class_under_traced_offsets(q_off, k_off, t, tk, bq, bk):
+    """``flash_mha_lse``'s hops: the class from offsets only the running
+    program knows, and bounds below 0 for a query before every key."""
+    @jax.jit
+    def classes(q_off, k_off):
+        geo = _geometry(1, t, tk, 2, 2, None, q_off, k_off, True, bq, bk)
+        return geo.classes(), geo.bounds
+
+    got, (lo, hi) = classes(jnp.int32(q_off), jnp.int32(k_off))
+    geo = _geometry(1, t, tk, 2, 2, None, jnp.int32(q_off), jnp.int32(k_off),
+                    True, bq, bk)
+    ok = _brute_mask(None, t, tk, True, 0, q_off, k_off)
+    for a, b in zip(got, _hold_classes(geo, ok)):
+        np.testing.assert_array_equal(a, b)
+    j = np.arange(tk)[None, None, :]
+    np.testing.assert_array_equal(
+        (j >= np.asarray(lo)[:, :, None]) & (j <= np.asarray(hi)[:, :, None]),
+        ok)
+
+
+def test_the_cells_rows_run_all_three_classes():
+    """A row of 16384 tokens in four documents under a window of 2048 and
+    blocks of 1024, as ``tools/attn_ab.py`` cuts it: 45 visited steps at
+    most, some of every class, and without the window the full layers'."""
+    t = 16384
+    doc = _docs([[3000, 9000, 11000]], t)
+    shape = lambda h, d=128: jax.ShapeDtypeStruct((1, t, h, d), jnp.bfloat16)
+    for window, most in ((2048, 45), (0, 136)):
+        visited, free, one = (int(n) // 32 for n in count_blocks(
+            shape(32), shape(4), shape(4), causal=True, doc=doc,
+            window=window))
+        assert 0 < free < one < visited <= most
+
+
+@pytest.mark.parametrize("h, hk, dqk, dv, window", [
+    (2, 2, 192, 128, 0),        # latent attention's two widths
+    (32, 8, 16, 16, 0),         # granite: 32 / 8
+    (16, 2, 32, 32, 0),         # qwen3_next: 16 / 2
+    (32, 4, 16, 16, 40),        # the afmoe family's sliding layers: 32 / 4
+    (32, 4, 16, 16, 0),         # ... and its full ones
+], ids=["192x128", "32over8", "16over2", "32over4_window", "32over4"])
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("split", [True], indirect=True)
+def test_all_three_classes_in_one_call_are_mha_s(h, hk, dqk, dv, window,
+                                                 dtype, tol, split):
+    """Rows with blocks whose every pair may attend, blocks only the
+    diagonal or the window's edge crosses (computed in parts, the dead
+    ones left out) and blocks with a document boundary in ONE call:
+    outputs, log-sum-exp and the three gradients."""
+    rng = np.random.RandomState(4)
+    mk = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32), dtype)
+    q, k, v = mk(2, T, h, dqk), mk(2, T, hk, dqk), mk(2, T, hk, dv)
+    doc = _docs([[40], [5, 23]])
+    mask = dict(causal=True, doc=doc, window=window)
+    visited, free, one = (int(n) for n in count_blocks(
+        q, k, v, block_q=16, block_k=16, **mask))
+    assert 0 < free < one < visited
+
+    def kern(q, k, v):
+        return flash_attention(q, k, v, block_q=16, block_k=16,
+                               interpret=True, **mask)[0]
+
+    _hold(kern, lambda q, k, v: mha(q, k, v, **mask), q, k, v, tol)
+    lse = flash_attention(q, k, v, block_q=16, block_k=16, interpret=True,
+                          **mask)[1]
+    kk = jnp.repeat(k, h // hk, axis=2).astype(jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bqhk", q.astype(jnp.float32), kk) / np.sqrt(
+        dqk)
+    ok = jnp.asarray(_brute_mask(np.asarray(doc), T, T, True, window))
+    want = jax.nn.logsumexp(jnp.where(ok[:, :, None, :], s, -jnp.inf), axis=-1)
+    assert lse.dtype == jnp.float32
+    np.testing.assert_allclose(lse, want, rtol=tol, atol=tol)
+
+
+# ------------------- the parts of a block an edge crosses (ISSUE 43)
+@pytest.mark.parametrize("window", [0, 1, 7, 16, 24, 33, 64, 500])
+@pytest.mark.parametrize("bq, bk, n", [(32, 32, 2), (32, 64, 2), (64, 32, 4)])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("q_off, k_off", [(0, 0), (8, 0), (0, 24), (37, 5)])
+def test_a_part_is_left_out_only_where_no_pair_of_it_may_attend(
+        q_off, k_off, causal, bq, bk, n, window):
+    """``_part_seen`` against the diagonal and the window written out
+    pair by pair: a part is computed where ANY pair of it may attend, a
+    block is computed whole where every part is."""
+    from cxxnet_tpu.ops.flash import _part_seen
+
+    t = 128
+    hq, hk = bq // n, bk // n
+    ok = _brute_mask(None, t, t, causal, window, q_off, k_off)[0]
+    any_ = ok.reshape(t // hq, hq, t // hk, hk).any(axis=(1, 3))
+    for iq in range(t // bq):
+        for ik in range(t // bk):
+            q0, k0 = q_off + iq * bq, k_off + ik * bk
+            seen = np.array([[bool(_part_seen(q0, k0, a, b, hq, hk, causal,
+                                              window))
+                              for b in range(n)] for a in range(n)])
+            np.testing.assert_array_equal(
+                seen, any_[iq * n:(iq + 1) * n, ik * n:(ik + 1) * n])
+            # monotone: the two far corners say whether any part is dead
+            assert seen.all() == (seen[0, n - 1] and seen[n - 1, 0])
+
+
+def test_parts_are_whole_lane_tiles_or_none(monkeypatch):
+    """A block splits only into parts of whole lane tiles: the cells'
+    1024 into 512s, a block of 128 not at all."""
+    from cxxnet_tpu.ops import flash
+
+    assert flash._LANES == 128 and flash._PARTS == 2
+    assert flash._parts(1024, 1024) == 2 and flash._parts(256, 512) == 2
+    assert flash._parts(128, 1024) == 1 and flash._parts(1024, 192) == 1
+    monkeypatch.setattr(flash, "_LANES", 8)
+    assert flash._parts(16, 32) == 2 and flash._parts(8, 16) == 1

@@ -188,16 +188,18 @@ def test_a_4_step_chunk_under_adam_matches_the_reference(ref, pattern):
 
 
 def test_the_attention_layer_counts_its_tokens_into_the_round(ref):
-    """PR 37: the masked path's two counters, summed into the round's
+    """PR 37: the masked path's counters, summed into the round's
     once ``count_layer_state`` reads the layers' state — every token
-    counted, none by the flash kernels off the TPU."""
+    counted, none by the flash kernels off the TPU, and so none of their
+    blocks (PR 43)."""
     from cxxnet_tpu.utils.profiler import pipeline_stats
 
     text = granite_h_conf(seq_len=48, batch_size=2, layer_types="mam",
                           compute_dtype="float32", **SMALL)
     tr, net = _trainer(text, ref, 5, 2)
     (key,) = [k for k, v in tr.aux.items() if "attn_tokens" in v]
-    assert set(tr.aux[key]) == {"attn_tokens", "attn_tokens_flash"}
+    assert set(tr.aux[key]) == {"attn_tokens", "attn_tokens_flash",
+                                "attn_blocks", "attn_blocks_unmasked"}
     stats = pipeline_stats()
     before = dict(stats.counters())
     data, labels = _rows(ref, net, 3, 4)
@@ -206,8 +208,8 @@ def test_the_attention_layer_counts_its_tokens_into_the_round(ref):
     got = stats.counters()
     # 4 steps x 2 rows x 48 tokens, one attention layer
     assert got["attn_tokens"] - before.get("attn_tokens", 0) == 4 * 2 * 48
-    assert got.get("attn_tokens_flash", 0) == before.get(
-        "attn_tokens_flash", 0)
+    for name in ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked"):
+        assert got.get(name, 0) == before.get(name, 0)
 
 
 def test_the_mixers_count_their_tokens_into_the_round(ref):

@@ -384,7 +384,8 @@ def test_the_builder_s_conf_trains_and_counts_its_pairs():
     stats = pipeline_stats()
     before = stats.counters().get("expert_pairs", 0)
     tokens = stats.counters().get("attn_tokens", 0)
-    flash = stats.counters().get("attn_tokens_flash", 0)
+    flash = {n: stats.counters().get(n, 0) for n in (
+        "attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked")}
     tr.count_layer_state()
     pairs = stats.counters()["expert_pairs"] - before
     # 8 steps x 64 tokens x 3 picks x 2 layers, a quarter of them held
@@ -392,7 +393,8 @@ def test_the_builder_s_conf_trains_and_counts_its_pairs():
     # the latent layers count their tokens, and none by the kernels off
     # the TPU: 8 steps x 64 tokens x 3 layers
     assert stats.counters()["attn_tokens"] - tokens == 8 * 64 * 3
-    assert stats.counters().get("attn_tokens_flash", 0) == flash
+    # ... so no block of theirs either
+    assert {n: stats.counters().get(n, 0) for n in flash} == flash
     # without the module: the main model alone
     bare = joyai_llm_flash_conf(**dict(TINY, num_nextn_predict_layers=0))
     assert "mtp_" not in bare and bare.count("= softmax") == 1
@@ -455,8 +457,9 @@ def test_masked_attention_counts_its_tokens_and_none_flash_on_a_cpu(
         kind, shapes, cfg):
     lay, p, _ = make(kind, shapes, **cfg)
     aux = lay.init_aux(shapes)
-    assert set(aux) == set(lay.aux_counters) == {"attn_tokens",
-                                                 "attn_tokens_flash"}
+    assert set(aux) == set(lay.aux_counters) == {
+        "attn_tokens", "attn_tokens_flash", "attn_blocks",
+        "attn_blocks_unmasked"}
     assert all(v.dtype == jnp.uint32 and v.shape == () for v in aux.values())
     r = np.random.RandomState(0)
     ins = [jnp.asarray(r.randn(*shapes[0]), jnp.float32)]
@@ -472,6 +475,8 @@ def test_masked_attention_counts_its_tokens_and_none_flash_on_a_cpu(
                                    rtol=1e-6, atol=1e-6)
         assert int(aux["attn_tokens"]) == n * 2 * 16
         assert int(aux["attn_tokens_flash"]) == 0
+        assert int(aux["attn_blocks"]) == int(
+            aux["attn_blocks_unmasked"]) == 0
 
 
 def test_the_plain_attention_layer_keeps_no_counter():

@@ -572,10 +572,11 @@ def test_a_cpu_run_counts_the_scan_s_tokens_and_none_fused():
     assert got.get("gdn_scan_tokens_fused", 0) == before.get(
         "gdn_scan_tokens_fused", 0)
     # and the gated attention layer's (its masked path), likewise
-    assert set(tr.aux["l3_attn1"]) == {"attn_tokens", "attn_tokens_flash"}
+    assert set(tr.aux["l3_attn1"]) == {"attn_tokens", "attn_tokens_flash",
+                                       "attn_blocks", "attn_blocks_unmasked"}
     assert got["attn_tokens"] - before.get("attn_tokens", 0) == 256
-    assert got.get("attn_tokens_flash", 0) == before.get(
-        "attn_tokens_flash", 0)
+    for name in ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked"):
+        assert got.get(name, 0) == before.get(name, 0)
     tr.count_layer_state()
     assert stats.counters()["gdn_scan_tokens"] == got["gdn_scan_tokens"]
 

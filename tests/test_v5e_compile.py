@@ -464,6 +464,9 @@ def test_a_trinity_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
     # the step tables are operands of the calls: their length is the grid's
     assert BLOCK == 1024
     assert f"s32[{steps}]" in text and f"s32[{8 * steps}]" in text
+    # PR 43: the mask reaches the kernels as two bounds a query, columns
+    # beside the query block and rows beside the transposed one
+    assert "s32[1,16384,1]" in text and "s32[1,1,16384]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2.4e9
 
 

@@ -9,7 +9,12 @@ forward + backward (the gradient of a weighted sum of the
 output with respect to q, k and v) under one ``jax.jit``, the median
 wall time of ``--reps`` calls that end in ``block_until_ready``.  One
 JSON line a reading, also written to ``chiprun_out/attn_ab.jsonl``; the
-kernels' outputs are held against ``mha``'s before anything is timed.
+kernels' outputs are held against ``mha``'s before anything is timed.  A
+kernel reading says what it measured: ``blocks_a_head``, the blocks the
+forward visits a head for the row it timed, by what they hold — no mask
+at all, one document crossed by the diagonal or the window's edge, a
+document boundary — from ``flash.count_blocks``, the function the layers'
+``attn_blocks`` counters use.
 A measurement path: it refuses a host without a TPU.
 
 Usage:
@@ -57,7 +62,7 @@ def main() -> int:
     import numpy as np
 
     from cxxnet_tpu.ops.attention import mha
-    from cxxnet_tpu.ops.flash import flash_attention
+    from cxxnet_tpu.ops.flash import count_blocks, flash_attention
 
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not args.cpu_rehearsal:
@@ -106,15 +111,21 @@ def main() -> int:
         a = (q, k, v, w, doc)
         ref = fwd(ref_attn)(*a)[0].astype(jnp.float32)
         ref_g = grads(ref_attn)(*a)
-        rows = [] if args.no_xla else [("xla_rows_512", ref_attn)]
+        rows = [] if args.no_xla else [("xla_rows_512", ref_attn, None)]
         for blk in args.blocks.split(","):
             bq, bk = (int(x) for x in blk.split("x"))
+            visited, free, one = (int(n) // h for n in count_blocks(
+                q, k, v, causal=True, doc=doc, block_q=bq, block_k=bk,
+                **win))
             rows.append((f"flash_{bq}x{bk}", lambda q, k, v, doc, bq=bq,
                          bk=bk: flash_attention(
                              q, k, v, causal=True, scale=scale, doc=doc,
                              block_q=bq, block_k=bk,
-                             interpret=args.cpu_rehearsal, **win)[0]))
-        for label, attn in rows:
+                             interpret=args.cpu_rehearsal, **win)[0],
+                         {"visited": visited, "unmasked": free,
+                          "positions_only": one - free,
+                          "document_boundary": visited - one}))
+        for label, attn, blocks in rows:
             try:
                 got = fwd(attn)(*a)[0].astype(jnp.float32)
                 err = float(jnp.abs(got - ref).max())
@@ -129,6 +140,8 @@ def main() -> int:
                         "max_abs_err": err, "grad_rel_err": g_err,
                         "fwd_tflops_needed": flops / 1e12,
                         "device": dev.device_kind}
+                if blocks:
+                    line["blocks_a_head"] = blocks
             except Exception as e:  # noqa: BLE001 - a reading, reported
                 line = {"shape": name, "form": label,
                         "error": f"{type(e).__name__}: {e}"[:2000]}
