@@ -24,8 +24,11 @@ latent for every head and go through ``ops/attention.attend``, the one
 chooser ``attention``'s masked path uses too: lowered for a TPU, a long
 row runs the flash kernels of ``ops/flash.py`` (document mask with whole
 blocks skipped, the score product ``nope_dim + rope_dim`` wide beside
-values ``v_dim`` wide, bf16 into the MXU, float32 softmax; forward, the
-layer's ``remat`` recompute and both backward kernels); everywhere else
+values ``v_dim`` wide, bf16 into the MXU, float32 softmax; the forward
+and both backward kernels once a step — the net's ``remat`` keeps the
+forward's ``o`` and ``lse`` across the backward pass, ``nhead x T x
+(v_dim x 2 + 4)`` bytes a layer, and its recompute runs no second
+forward kernel: ``flash.KEPT_NAMES``); everywhere else
 ``ops/attention.mha``, in checkpointed row blocks of 512 queries once
 the row is long.  The absorbed form (``W_kvb`` folded into the query
 and the output so that a decode step reads the latent cache alone) is

@@ -147,7 +147,11 @@ class Branch:
     r`` returns ``x + r * f(...)`` and not ``f(...)`` alone.  Under
     ``remat = 1`` a conf layer is one ``jax.checkpoint``: a branch in
     one layer keeps one ``(N, T, D)`` input alive for the backward, not
-    the norm's, the mixer's and the sum's."""
+    the norm's, the mixer's and the sum's — and, where the flash
+    kernels computed its attention, their ``o`` and ``lse`` (``(N, T,
+    heads, value width)`` in the compute dtype and ``(N, heads, T)``
+    float32: ``nnet/net.REMAT_POLICY``), so the recompute does not run
+    the forward kernel again."""
 
     prenorm = 0
     postnorm = 0

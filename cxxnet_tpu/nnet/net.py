@@ -33,9 +33,18 @@ import jax.numpy as jnp
 
 from ..layers import Layer, LossLayer, create_layer
 from ..layers.structure import SplitLayer
+from ..ops.flash import KEPT_NAMES
 from .graph import NetGraph
 
 ConfigEntry = Tuple[str, str]
+
+#: what a layer's ``remat`` keeps across the backward pass: the values its
+#: kernels NAME as their own outputs that the backward reads again (the
+#: flash forward's ``o`` and ``lse``), so the recompute does not run those
+#: kernels a second time.  A layer that names nothing keeps nothing and
+#: lowers to the program ``policy=None`` gives
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+_remat = functools.partial(jax.checkpoint, policy=REMAT_POLICY)
 
 
 def _opsq():
@@ -691,7 +700,7 @@ class FunctionalNet:
                             "unset input node")
                     gparams = [params.get(self.param_key[j], {}) for j in idxs]
                     run_f = (
-                        jax.checkpoint(self._apply_branch_embed)
+                        _remat(self._apply_branch_embed)
                         if (self.remat and train) else self._apply_branch_embed
                     )
                     for j, out in zip(idxs, run_f(gparams, xs)):
@@ -713,7 +722,7 @@ class FunctionalNet:
                         kernels=kern_lib,
                     )
                     run_f = (
-                        jax.checkpoint(fused)
+                        _remat(fused)
                         if (self.remat and train) else fused
                     )
                     for j, out in zip(idxs, run_f(gparams, x)):
@@ -767,7 +776,7 @@ class FunctionalNet:
                                     p, st, xs, train=True, rng=lrng, step=step
                                 )
 
-                            outs, new_state = jax.checkpoint(run_st)(
+                            outs, new_state = _remat(run_st)(
                                 lparams, lstate, inputs
                             )
                         else:
@@ -784,7 +793,7 @@ class FunctionalNet:
                                 p, xs, train=True, rng=lrng, step=step
                             )
 
-                        outs = jax.checkpoint(run)(lparams, inputs)
+                        outs = _remat(run)(lparams, inputs)
                     else:
                         outs = lay.apply(
                             lparams, inputs, train=train, rng=lrng, step=step

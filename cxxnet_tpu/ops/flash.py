@@ -77,6 +77,24 @@ behind ONE ``jax.custom_vjp`` (``_flash``):
   position offsets (``flash_mha_lse``, ring hops) is refused, as a
   document mask with them is (ROADMAP R3).  ``window = 0`` builds the
   tables, operands and kernels this module built before it had one.
+* **the forward's two outputs named for a ``remat``** (PR 44).  ``o``
+  and ``lse`` are the kernel's own outputs AND the backward's residuals;
+  ``_flash_fwd`` names them (``KEPT_NAMES``, ``checkpoint_name``), so a
+  ``jax.checkpoint`` whose policy saves those names — the net's, one a
+  conf layer under ``remat = 1`` (``nnet/net.REMAT_POLICY``) — keeps
+  them across the backward pass and its recompute rebuilds ``q``, ``k``
+  and ``v`` but runs no second ``flash_fwd``: one forward, one ``dq``,
+  one ``dkv`` a layer a step.  A layer then holds ``o`` as the kernel
+  wrote it (``(B H, T, Dv)`` in the operands' dtype) and ``lse`` as its
+  numbers, ``(B H, T)`` float32 — the kernel's ``(B H, T, 1)`` column
+  is tiled to 128 lanes in HBM, so the column is dropped before the
+  name and put back in ``_flash_bwd``.  Under a plain ``jax.checkpoint``
+  (``mha``'s row blocks' own, any other caller's) nothing is kept and
+  nothing changes; where nothing is differentiated a name is the
+  identity.  The ring path (``flash_mha_lse``, ``ring_attention_flash``)
+  shares the ``custom_vjp``: under the net's ``remat`` a hop's ``o`` and
+  ``lse`` would be kept too, ``n`` hops' worth a layer (CPU tests only,
+  no cell measures it).
 
 Precision is that of ``ops/attention._attend``: the products take the
 operands as they come (bf16 into the MXU, float32 stays float32) and
@@ -101,6 +119,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 
 NEG_INF = -1e30
@@ -706,16 +725,29 @@ def _backward(q, k, v, doc, q_off, k_off, out, lse, g, g_lse, causal, scale,
     return _flash_bwd_raw(q, k, v, g, lse, delta - g_lse, geo, interpret)
 
 
+#: the names (``jax.ad_checkpoint.checkpoint_name``) of what the forward
+#: kernel computed and the backward kernels read again: a ``jax.checkpoint``
+#: whose policy saves them (``nnet/net.REMAT_POLICY``) keeps the two across
+#: the backward pass and its recompute runs no second ``flash_fwd``
+KEPT_NAMES = ("flash_o", "flash_lse")
+
+
 def _flash_fwd(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
                interpret, window=0):
     out, lse = _forward(q, k, v, doc, q_off, k_off, causal, scale, bq, bk,
                         heads, interpret, window)
-    return (out, lse), (q, k, v, doc, q_off, k_off, out, lse)
+    # named OUTSIDE the jitted call; the named values are the primal
+    # outputs AND the residuals, so every reader reads what a policy may
+    # keep (``lse`` lane-dense, without its column: the module docstring)
+    out = checkpoint_name(out, KEPT_NAMES[0])
+    lse = checkpoint_name(lse[:, :, 0], KEPT_NAMES[1])
+    return (out, lse[:, :, None]), (q, k, v, doc, q_off, k_off, out, lse)
 
 
 def _flash_bwd(causal, scale, bq, bk, heads, interpret, window, res, cts):
-    dq, dk, dv = _backward(*res, *cts, causal, scale, bq, bk, heads,
-                           interpret, window)
+    *ins, out, lse = res
+    dq, dk, dv = _backward(*ins, out, lse[:, :, None], *cts, causal, scale,
+                           bq, bk, heads, interpret, window)
     return dq, dk, dv, None, None, None
 
 

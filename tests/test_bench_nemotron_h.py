@@ -51,5 +51,5 @@ def test_the_cell_is_the_one_the_issue_names():  # noqa: F811
     assert listed[at + 4:] == [
         "attn_window_core_ms_step", "attn_full_core_ms_step",
         "attn_window_pairs_pct", "attn_core_roofline_pct",
-        "attn_unmasked_blocks_pct"]
+        "attn_unmasked_blocks_pct", "attn_fwd_runs_per_bwd"]
     assert by_name["attn_unmasked_blocks_pct"]["workloads"][3] == n.CELL

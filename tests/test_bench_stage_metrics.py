@@ -4,6 +4,7 @@ the workers can run them beside ``test_bench_harness.py``."""
 
 import json
 import os
+import shutil
 import sys
 
 import pytest
@@ -164,6 +165,93 @@ def test_attn_unmasked_blocks_pct_reads_the_two_block_counters(rounds, want):
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
+# attn_fwd_runs_per_bwd (PR 44): the forward kernel's operations over the
+# backward's on the first chip's trace — events built as
+# ``benchmarks/tests/test_trinity_mini.py`` builds them
+def _kernel_events(fwd, bwd=1, layers=(("l1_attn0", "core_window"),
+                                       ("l9_mla4", "core"))):
+    """(HLO instruction, ns, ``tf_op``) of ONE step with ``fwd`` forward
+    and ``bwd`` backward runs a layer, and the events a step has around
+    them; a ``tf_op`` is ``<scope path>:<type>`` and a v5e's trace leaves
+    the type empty (``benchmarks/fixtures/scopes_v5e.xplane.pb``; the
+    kernels' lines are a chip's own, PR 44)."""
+    body = "jit(step)/while/body/closed_call/"
+    events = [("%fusion.1", 4000, body + "jvp(l1_attn0)/dot_general:"),
+              ("%while.1", 99999, "jit(step)/while:"),
+              ("%ragged-dot-none", 9000, "ragged-dot-none:"),
+              ("%copy.1", 100, None)]
+    for lay, core in layers:
+        back = (f"{body}transpose(jvp({lay}))/jvp({lay})/checkpoint/"
+                f"rematted_computation/{core}/")
+        events += [(f"%flash_fwd.{lay}{i} = (bf16[4,2048,128]{{2,1,0}}, f32[4,"
+                    "2048,1]{2,1,0}) custom-call(s32[2]{0} %p)", 6000, (
+            f"{body}jvp({lay})/{core}/" if i == 0 else back)
+            + "cond/branch_0_fun/jit(_forward)/flash_fwd/pallas_call:")
+            for i in range(fwd)]
+        for i in range(bwd):
+            events += [(f"%flash_dq.{lay}{i} = bf16[4,2048,128]{{2,1,0}} "
+                        "custom-call(s32[2]{0} %p)", 7000, back + "cond/"
+                        "branch_0_fun/jit(_backward)/flash_dq/pallas_call:"),
+                       (f"%flash_dkv.{lay}{i}", 9000, back + "cond/"
+                        "branch_0_fun/jit(_backward)/flash_dkv/pallas_call:")]
+    return events
+
+
+@pytest.mark.parametrize("events, want", [
+    # forward and the remat recompute for one backward: the parent
+    (_kernel_events(2), 2.0),
+    # the net's policy keeps o and lse: one forward a layer
+    (_kernel_events(1), 1.0),
+    (_kernel_events(1, layers=(("l3_attn1", "core_full"),)), 1.0),
+    # the operations of the program are counted, not their events: a
+    # traced round of three steps that begins inside one (its backward
+    # alone) and ends inside another (its forward alone)
+    ([e for e in _kernel_events(2) if "flash_fwd" not in e[0]]
+     + 3 * _kernel_events(2)
+     + [e for e in _kernel_events(2) if "flash_dq" not in e[0]], 2.0),
+    (_kernel_events(1)[4:] + 3 * _kernel_events(1), 1.0),
+    # a forward that never ran on the device is not counted
+    (_kernel_events(1) + [("%flash_fwd.9", 0, "jit(step)/flash_fwd/"
+                           "pallas_call:")], 1.0),
+    # a trace that states the type, and one that has no colon at all
+    ([("%a", 5, "jit(f)/flash_fwd/pallas_call:custom-call"),
+      ("%b", 5, "jit(f)/flash_fwd/pallas_call"),
+      ("%c", 5, "jit(f)/flash_dq/pallas_call")], 2.0),
+    # mha's row blocks, a conv net: neither kernel in the trace
+    (_kernel_events(0, 0), None),
+    # a forward alone (nothing differentiated): no backward to count by
+    (_kernel_events(1, 0), None),
+    ([], None),
+])
+def test_attn_fwd_runs_per_bwd_counts_the_two_kernels_events(events, want):
+    mod = run.load_metric("attn_fwd_runs_per_bwd")
+    assert mod.runs_per_bwd(events) == want
+    # the same kernels inside another op's name are not the kernels
+    assert mod.runs_per_bwd([("%f", 5, "x/flash_fwd/pallas_call/copy:"),
+                             ("%g", 5, "x/flash_dq/pallas_call:")]) is None
+
+
+def test_attn_fwd_runs_per_bwd_reads_nothing_without_a_trace(tmp_path):
+    read = run.load_metric("attn_fwd_runs_per_bwd").read
+    out = str(tmp_path)
+    assert read({"out": out, "trace": None}) is None
+    assert read({"out": out, "trace": {"steps": 0}}) is None
+    # traced, and the round's directory is not there or holds no file
+    assert read({"out": out, "trace": {"steps": 16}}) is None
+    where = os.path.join(out, "trace_round1", "plugins", "profile", "x")
+    os.makedirs(where)
+    assert read({"out": out, "trace": {"steps": 16}}) is None
+    # a v5e's own trace of a net with no attention in it: events, scopes
+    # with their colons, and neither kernel
+    from benchmarks.lib import scopes
+    fixture = os.path.join(ROOT, "benchmarks", "fixtures",
+                           "scopes_v5e.xplane.pb")
+    shutil.copy(fixture, os.path.join(where, "host.xplane.pb"))
+    assert any(s and s.endswith(":") for _, _, s in
+               scopes.device_events(fixture))
+    assert read({"out": out, "trace": {"steps": 16}}) is None
+
+
 # expert_dispatch_compact_pct (PR 39): the routed expert layers count the
 # pairs they computed in slabs after the first, inside the step programs
 @pytest.mark.parametrize("rounds, want", [
@@ -310,6 +398,7 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
     ("attn_core_roofline_pct", ALL_CELLS[6:], "higher"),
     ("attn_flash_pct", ALL_CELLS[2:], "higher"),
     ("attn_unmasked_blocks_pct", ALL_CELLS[2:], "higher"),
+    ("attn_fwd_runs_per_bwd", ALL_CELLS[2:], "lower"),
     ("expert_dispatch_compact_pct", ALL_CELLS[3:], "higher"),
 ] + [(name, ALL_CELLS, "lower") for name in LOOP_BILL])
 def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
@@ -333,7 +422,8 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
     assert names[42:46] == ["mla_ms_step", "mla_core_ms_step",
                             "mla_core_roofline_pct", "mtp_ms_step"]
     # PR 37's one behind them, PR 38's five behind that, and PR 39's
-    # one; PR 40's three; PR 41's one; PR 42's four; PR 43's one, the last
+    # one; PR 40's three; PR 41's one; PR 42's four; PR 43's one; PR 44's
+    # one, the last
     assert names[46:47] == ["attn_flash_pct"]
     assert names[47:52] == LOOP_BILL
     assert names[52:] == ["expert_dispatch_compact_pct",
@@ -345,4 +435,5 @@ def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
                           "attn_full_core_ms_step",
                           "attn_window_pairs_pct",
                           "attn_core_roofline_pct",
-                          "attn_unmasked_blocks_pct"]
+                          "attn_unmasked_blocks_pct",
+                          "attn_fwd_runs_per_bwd"]

@@ -5,6 +5,8 @@ discipline, SURVEY §4.1); the on-TPU compile is covered by the layer's
 probe machinery.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -533,3 +535,67 @@ def test_attend_on_the_cpu_is_mha_and_says_so():
         np.asarray(o, np.float32), np.asarray(mha(
             q[:, :64], k[:, :64], v[:, :64], causal=True, doc=doc[:, :64]),
             np.float32))
+
+
+def _layer_shaped(with_lse):
+    """A layer's shape of work around the kernels — projections from one
+    ``x``, grouped heads under a document mask and a window, an output
+    projection and a residual — as ``loss(run)(params, x)`` for a ``run``
+    that wraps the layer (``jax.checkpoint`` or nothing); ``with_lse``
+    puts a cotangent on ``lse`` too (the ring path's case)."""
+    from cxxnet_tpu.ops.flash import flash_attention
+
+    b, t, h, hk, d, width = 2, 64, 4, 2, 16, 32
+    rng = np.random.RandomState(3)
+    mk = lambda *s: jnp.asarray(0.2 * rng.randn(*s), jnp.float32)
+    params = dict(wq=mk(width, h * d), wk=mk(width, hk * d),
+                  wv=mk(width, hk * d), wo=mk(h * d, width))
+    x = 5 * mk(b, t, width)
+    doc = _docs([[5, 16, 40], [63]], t)
+
+    def layer(p, x):
+        q = (x @ p["wq"]).reshape(b, t, h, d)
+        k = (x @ p["wk"]).reshape(b, t, hk, d)
+        v = (x @ p["wv"]).reshape(b, t, hk, d)
+        o, lse = flash_attention(q, k, v, causal=True, doc=doc, window=24,
+                                 block_q=16, block_k=16, interpret=True)
+        return jnp.tanh(o.reshape(b, t, h * d) @ p["wo"]) + x, lse
+
+    def loss(run):
+        def f(p, x):
+            y, lse = run(layer)(p, x)
+            return (y ** 2).sum() + ((lse ** 2).sum() if with_lse else 0.0)
+        return jax.grad(f, (0, 1))
+
+    return loss, params, x
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["o", "o_and_lse"])
+def test_a_layer_s_remat_keeps_the_kernels_outputs(with_lse):
+    """Under the net's policy a checkpointed layer keeps what the forward
+    kernel NAMES (``flash.KEPT_NAMES``): its gradients are those of the
+    layer with no ``jax.checkpoint`` at all, and the program of value and
+    gradient runs ``flash_fwd`` once where a plain ``jax.checkpoint``
+    runs it twice (forward and recompute); the backward kernels once
+    either way."""
+    from cxxnet_tpu.nnet.net import REMAT_POLICY
+
+    loss, params, x = _layer_shaped(with_lse)
+    grads = {
+        "plain": loss(lambda f: f),
+        "kept": loss(lambda f: jax.checkpoint(f, policy=REMAT_POLICY)),
+        "recomputed": loss(jax.checkpoint),
+    }
+    want = jax.tree_util.tree_leaves(grads["plain"](params, x))
+    assert all(np.abs(np.asarray(w)).max() > 0 for w in want)
+    for name in ("kept", "recomputed"):
+        got = jax.tree_util.tree_leaves(grads[name](params, x))
+        for a, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=2e-5, atol=2e-5, err_msg=name)
+    runs = {name: {kern: len(re.findall(rf"name={kern}\b", str(
+        jax.make_jaxpr(g)(params, x)))) for kern in
+        ("flash_fwd", "flash_dq", "flash_dkv")} for name, g in grads.items()}
+    once = {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+    assert runs == {"plain": once, "kept": once,
+                    "recomputed": dict(once, flash_fwd=2)}, runs

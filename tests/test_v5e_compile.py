@@ -25,11 +25,12 @@ on-chip-measurement guide, section 2: nothing runs, no chip is needed).
   experts and the fallback of 8 (ISSUE 36).
 
 * lowered for a TPU, masked attention IS the flash kernels of
-  ``ops/flash.py`` (PR 37): ``flash_fwd`` (forward and the ``remat``
-  recompute), ``flash_dq`` and ``flash_dkv`` under the latent layers'
-  ``core`` scope and under an ``attention`` layer's own at granite's and
-  qwen3_next's head shapes, and no float32 ``(…, 512, <= 8192)`` score
-  block of ``mha``'s is left.  The JoyAI step is compiled as the CLI
+  ``ops/flash.py`` (PR 37): ``flash_fwd``, ``flash_dq`` and ``flash_dkv``
+  under the latent layers' ``core`` scope and under an ``attention``
+  layer's own at granite's and qwen3_next's head shapes, ONE call of each
+  a layer (since PR 44 the net's ``remat`` policy keeps the forward's
+  ``o`` and ``lse``, so the recompute runs no second ``flash_fwd``), and
+  no float32 ``(…, 512, <= 8192)`` score block of ``mha``'s is left.  The JoyAI step is compiled as the CLI
   compiles it and held to the 14.4 GB that fit a chip: it reads 14.21
   GB with the kernels for 13.60 with the row blocks (ISSUE 37's "no
   higher than 13.60" is NOT met: PERF.md section 6, PR 37).
@@ -226,13 +227,14 @@ def test_the_joyai_step_fits_a_chip_with_sixteen_held_experts(one_chip):
     assert len(re.findall(r" while\(", text)) > 1
     assert re.search(r"f32\[16,2048,1536\]\{2,1,0", text)
     assert not re.search(r"f32\[16,(?:2048,1536|768,2048)\]\{1,2,0", text)
-    # PR 37: every latent layer's core is four Mosaic calls (forward, the
-    # remat recompute, dq, dk/dv), all billed to its core scope; mha's
-    # float32 score blocks (1, 32, 512, <= 8192) are gone
+    # PR 37: every latent layer's core is Mosaic calls, all billed to its
+    # core scope — since PR 44 three of them (forward, dq, dk/dv: the
+    # remat recompute reads the kept o and lse and runs no forward);
+    # mha's float32 score blocks (1, 32, 512, <= 8192) are gone
     calls = _mosaic_calls(text)
-    assert len(calls) == 6 * 4, [c[-60:] for c in calls]
+    assert len(calls) == 6 * 3, [c[-60:] for c in calls]
     assert all("/core/" in c and ("mla" in c) for c in calls), calls
-    for kern, n in (("flash_fwd", 12), ("flash_dq", 6), ("flash_dkv", 6)):
+    for kern, n in (("flash_fwd", 6), ("flash_dq", 6), ("flash_dkv", 6)):
         assert sum(f"/{kern}/pallas_call" in c for c in calls) == n, kern
     assert not _SCORE_BLOCK.search(text)
 
@@ -277,12 +279,12 @@ def test_the_nemotron_step_fits_a_chip_at_one_rank_s_share(one_chip):
     # the shared expert is whole: (5376, 4096) up, no 672-column share
     assert "f32[5376,4096]" in text and "f32[672,4096]" not in text
     # the attention layer (4 query heads on 1 key/value head of 128) is
-    # the flash kernels: four Mosaic calls; since PR 41 the five mixers'
-    # scans are the kernels of ops/ssd_fused.py (forward, recompute,
-    # backward), billed to their scan scopes
+    # the flash kernels: three Mosaic calls (PR 44: one forward); since
+    # PR 41 the five mixers' scans are the kernels of ops/ssd_fused.py
+    # (forward, recompute, backward), billed to their scan scopes
     calls = _mosaic_calls(text)
     ssd = [c for c in calls if "/ssd_scan" in c]
-    assert len(ssd) == 15 and len(calls) == 19, [c[-60:] for c in calls]
+    assert len(ssd) == 15 and len(calls) == 18, [c[-60:] for c in calls]
     assert all("mixer" in c and "/scan/" in c for c in ssd), ssd
     assert all("attn" in c for c in calls if c not in ssd), calls
     assert not _SCORE_BLOCK.search(text)
@@ -295,8 +297,10 @@ def test_the_nemotron_step_fits_a_chip_at_one_rank_s_share(one_chip):
 def test_a_mamba2_layer_lowered_for_a_tpu_is_the_fused_kernels(one_chip, cfg,
                                                                d):
     """One ``mamba2`` layer on a packed row of 8192 tokens, bfloat16,
-    under ``remat`` as the step programs run it."""
+    under ``remat`` as the step programs run it (the scan names nothing
+    the net's policy keeps: forward, recompute, backward)."""
     from cxxnet_tpu.layers import create_layer
+    from cxxnet_tpu.nnet.net import REMAT_POLICY
 
     lay = create_layer("mamba2")
     for k, v in dict(cfg, prenorm=1, residual_scale=0.22).items():
@@ -312,7 +316,7 @@ def test_a_mamba2_layer_lowered_for_a_tpu_is_the_fused_kernels(one_chip, cfg,
             with jax.named_scope("l1_mixer0"):
                 (y,), new = lay.apply_stateful(p, aux, [x, ids])
             return jnp.sum(y.astype(jnp.float32)), new
-        return jax.checkpoint(run)(p, x)
+        return jax.checkpoint(run, policy=REMAT_POLICY)(p, x)
 
     shaped = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda v: _shaped(one_chip, v.shape, v.dtype), t)
@@ -357,10 +361,10 @@ def test_the_granite_step_holds_no_more_than_the_parent_s(one_chip):
     assert live_at_peak_bytes(compiled) <= 15.06e9
     calls = _mosaic_calls(compiled.as_text())
     # nine mixers x (forward, recompute, backward) and the attention
-    # layer's four flash kernels
+    # layer's three flash kernels
     assert sum("/ssd_scan/" in c for c in calls) == 18
     assert sum("/ssd_scan_bwd/" in c for c in calls) == 9
-    assert len(calls) == 31
+    assert len(calls) == 30
     assert all("/scan/" in c for c in calls if "ssd_scan" in c)
 
 
@@ -407,12 +411,12 @@ def test_the_trinity_step_fits_a_chip_at_eight_held_experts(one_chip):
     # the held experts row-major through the scan like the accepted cells'
     assert re.search(r"f32\[8,2048,2048\]\{2,1,0", text)
     assert not re.search(r"f32\[8,(?:2048,2048|1024,2048)\]\{1,2,0", text)
-    # all five attention layers are the flash kernels, four calls each,
-    # each under its layer's own core scope
+    # all five attention layers are the flash kernels, three calls each
+    # (one forward: PR 44), each under its layer's own core scope
     calls = _mosaic_calls(text)
-    assert len(calls) == 20, [c[-60:] for c in calls]
-    assert sum("/core_window/" in c for c in calls) == 16
-    assert sum("/core_full/" in c and "l9_attn4" in c for c in calls) == 4
+    assert len(calls) == 15, [c[-60:] for c in calls]
+    assert sum("/core_window/" in c for c in calls) == 12
+    assert sum("/core_full/" in c and "l9_attn4" in c for c in calls) == 3
     assert not _SCORE_BLOCK.search(text)
 
 
@@ -421,9 +425,10 @@ def test_the_trinity_step_fits_a_chip_at_eight_held_experts(one_chip):
 def test_a_trinity_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
         one_chip, window, steps):
     """One sandwiched ``attention`` layer of the afmoe family on a packed
-    row of 16384 tokens, bfloat16, under ``remat``: the kernels' grids
-    walk the window's steps on a sliding layer."""
+    row of 16384 tokens, bfloat16, under the net's ``remat``: the
+    kernels' grids walk the window's steps on a sliding layer."""
     from cxxnet_tpu.layers import create_layer
+    from cxxnet_tpu.nnet.net import REMAT_POLICY
     from cxxnet_tpu.ops.flash import BLOCK
 
     cfg = dict(nhead=32, nkvhead=4, head_dim=128, qk_norm=1, out_gate=1,
@@ -445,7 +450,7 @@ def test_a_trinity_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
             with jax.named_scope("l3_attn1"):
                 (y,), new = lay.apply_stateful(p, aux, [x, ids])
             return jnp.sum(y.astype(jnp.float32)), new
-        return jax.checkpoint(run)(p, x)
+        return jax.checkpoint(run, policy=REMAT_POLICY)(p, x)
 
     shaped = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda v: _shaped(one_chip, v.shape, v.dtype), t)
@@ -456,7 +461,7 @@ def test_a_trinity_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
     text = compiled.as_text()
     calls = _mosaic_calls(text)
     assert sorted(c.split("/")[-2] for c in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd", "flash_fwd"], calls
+        "flash_dkv", "flash_dq", "flash_fwd"], calls
     scope = "core_window" if window else "core_full"
     assert all("l3_attn1" in c and f"/{scope}/" in c for c in calls), calls
     assert not _SCORE_BLOCK.search(text)
@@ -482,8 +487,10 @@ def test_a_trinity_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
 def test_an_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
         one_chip, cfg):
     """One ``attention`` layer on a packed row of 8192 tokens, bfloat16,
-    under ``remat`` as the step programs run it."""
+    under ``remat`` as the step programs run it (the net's policy: the
+    forward kernel's two outputs are kept, so it runs once)."""
     from cxxnet_tpu.layers import create_layer
+    from cxxnet_tpu.nnet.net import REMAT_POLICY
 
     lay = create_layer("attention")
     for k, v in dict(cfg, causal=1, no_bias=1, prenorm=1).items():
@@ -499,7 +506,7 @@ def test_an_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
             with jax.named_scope("l3_attn1"):
                 (y,), new = lay.apply_stateful(p, aux, [x, ids])
             return jnp.sum(y.astype(jnp.float32)), new
-        return jax.checkpoint(run)(p, x)
+        return jax.checkpoint(run, policy=REMAT_POLICY)(p, x)
 
     shaped = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda v: _shaped(one_chip, v.shape, v.dtype), t)
@@ -510,7 +517,7 @@ def test_an_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
     text = compiled.as_text()
     calls = _mosaic_calls(text)
     assert sorted(c.split("/")[-2] for c in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd", "flash_fwd"], calls
+        "flash_dkv", "flash_dq", "flash_fwd"], calls
     assert all("l3_attn1" in c for c in calls), calls
     assert not _SCORE_BLOCK.search(text)
     # grouped heads are read by the index map: no key or value repeated
@@ -519,3 +526,73 @@ def test_an_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
     dh = cfg.get("head_dim", 2048 // h)
     assert f"bf16[{hk},8192,{dh}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+@pytest.mark.parametrize("cell", ["granite", "qwen3_next"])
+def test_the_net_s_remat_runs_an_attention_layer_s_forward_kernel_once(
+        one_chip, cell):
+    """Through ``FunctionalNet.forward`` itself (``remat = 1``, the four
+    ``jax.checkpoint`` sites under ``REMAT_POLICY``): the cell's builder
+    with its stack cut to the ONE attention layer at its published head
+    shapes on a row of 8192 tokens, over a small vocabulary and small
+    feed-forward parts.  The compiled step holds one ``flash_fwd`` for
+    the layer's ``flash_dq`` and ``flash_dkv`` (PR 44: the forward's
+    ``o`` and ``lse`` are kept across the backward pass), and the kept
+    ``lse`` is its numbers, ``(heads, T)``, not 128 lanes a row."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from tools.compile_for_v5e import compile_step
+
+    from cxxnet_tpu.models import granite_h_conf, qwen3_next_conf
+
+    if cell == "granite":
+        conf, heads = granite_h_conf(layer_types="a", vocab=512,
+                                     mlp_hidden=512, scan_steps=1), 32
+    else:
+        conf, heads = qwen3_next_conf(
+            layer_types="f", vocab=512, num_experts=8, experts_per_tok=2,
+            experts_held=8, expert_hidden=128, shared_hidden=128,
+            scan_steps=1), 16
+    text = compile_step(conf).as_text()
+    calls = _mosaic_calls(text)
+    assert sorted(c.split("/")[-2] for c in calls) == [
+        "flash_dkv", "flash_dq", "flash_fwd"], calls
+    assert all("l1_attn0" in c for c in calls), calls
+    (fwd,) = [c for c in calls if "flash_fwd" in c]
+    assert "rematted_computation" not in fwd and "transpose(" not in fwd
+    assert f"f32[{heads},8192]" in text
+    assert not _SCORE_BLOCK.search(text)
+
+
+def test_a_layer_that_names_nothing_lowers_as_it_did_under_no_policy(
+        one_chip):
+    """``save_only_these_names`` with no such name in the layer saves
+    nothing, which is ``policy=None``: a ``gated_mlp`` branch at
+    granite's widths lowers for the chip to the same text under the
+    net's policy and under a plain ``jax.checkpoint``."""
+    from cxxnet_tpu.layers import create_layer
+    from cxxnet_tpu.nnet.net import REMAT_POLICY
+
+    lay = create_layer("gated_mlp")
+    for k, v in dict(nhidden=8192, prenorm=1, residual_scale=0.22).items():
+        lay.set_param(k, str(v))
+    shapes = [(1, 8192, 2048)]
+    lay.infer_shape(shapes)
+    params = jax.eval_shape(lambda k: lay.init_params(k, shapes),
+                            jax.random.PRNGKey(0))
+
+    def lowered(policy):
+        def loss(p, x):
+            def run(p, x):
+                with jax.named_scope("l2_mlp0"):
+                    (y,) = lay.apply(p, [x], train=True)
+                return jnp.sum(y.astype(jnp.float32))
+            return jax.checkpoint(run, policy=policy)(p, x)
+        shaped = jax.tree_util.tree_map(
+            lambda v: _shaped(one_chip, v.shape, v.dtype), params)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            shaped, _shaped(one_chip, shapes[0])).as_text()
+
+    text = lowered(REMAT_POLICY)
+    assert "stablehlo.dot_general" in text
+    assert text == lowered(None)
