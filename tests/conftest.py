@@ -47,6 +47,55 @@ def split(request, monkeypatch):
     return request.param
 
 
+@pytest.fixture(scope="module")
+def ref(request):
+    """The plain reference of the token-model family whose own tests
+    the module holds (its ``FAMILY``, a row of ``tests/families.py``)."""
+    import families
+
+    return families.reference(request.module.FAMILY)
+
+
+@pytest.fixture(scope="session")
+def one_chip():
+    """The first chip of a DESCRIBED ``v5e:2x2``, for the
+    ``test_v5e_<family>.py`` files (``tests/v5e.py``): nothing runs on
+    it.  Describing it loads the TPU's library, which one process at a
+    time may do unless ``ALLOW_MULTIPLE_LIBTPU_LOAD`` is set (the second
+    fails on ``/tmp/libtpu_lockfile``) — and xdist's workers each take a
+    family's file."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps it away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+#: The files that take longest, in the order they are handed out (PR
+#: 45's junit record; ROADMAP D0): ``--dist loadfile`` gives a file to one
+#: worker, and a long file that starts last is the run's tail.  xdist
+#: gives each of its six workers the next file of this order and one more
+#: to hold behind it: the whole-step compiles for a described chip use
+#: every core they find, so no more than two run at a time (places 0 and
+#: 6, 1 and 7 are one worker's).  The short files fill up behind these.
+LONGEST_FIRST = (
+    "test_v5e_joyai", "test_v5e_trinity", "test_flash_window",
+    "test_bench_harness", "test_bench_nemotron_h", "test_flash",
+    "test_v5e_nemotron", "test_v5e_granite", "test_families",
+    "test_accuracy", "test_bench_joyai_llm_flash", "test_bench_qwen3_next",
+    "test_v5e_qwen3_next", "test_qwen3_next_layers", "test_nemotron_layers",
+    "test_layers", "test_cli", "test_bench_trinity_mini",
+    "test_fault_tolerance", "test_joyai_layers", "test_bench_granite",
+    "test_afmoe_layers", "test_models", "test_granite_hybrid",
+    "test_branch_embed", "test_ops", "test_moe_dispatch", "test_trainer",
+)
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process / long-running tests"
@@ -54,6 +103,18 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "chaos: fault-injection chaos suite (tools/chaos_run.sh)"
     )
+    # xdist's loadfile hands files out by their NUMBER of cases, the most
+    # first, unless told not to: a family's one whole-step compile (one
+    # case, minutes) would start last.  Collection order it is, with the
+    # long files first (``LONGEST_FIRST``).
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(
+        item.module.__name__ if item.module else "", len(rank)))
 
 
 @pytest.fixture(autouse=True)
@@ -121,6 +182,17 @@ def pytest_terminal_summary(terminalreporter):
             "-", f"daemon-thread leak accounting: {total} leaked")
         for mod, names in sorted(_THREAD_LEAKS.items()):
             terminalreporter.write_line(f"  {mod}: {len(names)} {names}")
+
+
+def build_from_shapes(tr):
+    """The trainer's net built, and its parameters as shapes: every
+    layer's ``infer_shape`` and ``init_params`` runs, and no ImageNet-size
+    net's weights are drawn to read a node's shape."""
+    import jax
+
+    tr._build_net()
+    return jax.eval_shape(lambda k: tr.net.init_params(k, tr.batch_size),
+                          jax.random.PRNGKey(0))
 
 
 def run_cli(args, cwd, timeout=300, module=True):

@@ -11,12 +11,11 @@
 import os
 import re
 import shutil
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
+from conftest import run_cli
 from cxxnet_tpu import config as C
 from cxxnet_tpu.io.data import create_iterator
 from cxxnet_tpu.models import alexnet_conf, googlenet_conf
@@ -25,27 +24,16 @@ from cxxnet_tpu.nnet.trainer import NetTrainer
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_digits_conv_beats_mlp_bar(tmp_path):
-    """example/MNIST/digits_conv.conf through the real CLI: <= 4% test
-    error in 15 rounds on real handwritten digits (the committed log
-    records 1.6%)."""
+def _digits_test_errors(tmp_path, *more):
+    """``example/MNIST/digits_conv.conf`` through the real CLI on real
+    handwritten digits: ``{round: test error}`` of its 15 rounds."""
     pytest.importorskip("sklearn")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "make_digits_idx.py"),
-         str(tmp_path / "data")],
-        capture_output=True, text=True,
-    )
+    r = run_cli([os.path.join(REPO, "tools", "make_digits_idx.py"),
+                 str(tmp_path / "data")], str(tmp_path), module=False)
     assert r.returncode == 0, r.stderr
     shutil.copy(os.path.join(REPO, "example", "MNIST", "digits_conv.conf"),
                 str(tmp_path / "digits_conv.conf"))
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO
-    r = subprocess.run(
-        [sys.executable, "-m", "cxxnet_tpu", "digits_conv.conf",
-         "task=train"],
-        cwd=str(tmp_path), env=env, capture_output=True, text=True,
-    )
+    r = run_cli(["digits_conv.conf", "task=train", *more], str(tmp_path))
     assert r.returncode == 0, r.stderr[-2000:]
     errs = {
         int(m.group(1)): float(m.group(2))
@@ -53,6 +41,13 @@ def test_digits_conv_beats_mlp_bar(tmp_path):
                              r.stderr)
     }
     assert 15 in errs, r.stderr[-2000:]
+    return errs
+
+
+def test_digits_conv_beats_mlp_bar(tmp_path):
+    """<= 4% test error in 15 rounds (the committed log records
+    1.6%)."""
+    errs = _digits_test_errors(tmp_path)
     assert errs[15] <= 0.04, f"round-15 test error {errs[15]:.3f} > 4%"
     # convergence, not luck: the tail of the trajectory stays under 6%
     assert max(errs[k] for k in (13, 14, 15)) <= 0.06
@@ -68,30 +63,8 @@ def test_digits_conv_bf16_winograd_converges(tmp_path, wino):
     convergence class as the direct conv (measured A/B:
     example/MNIST/wino_bf16_ab.log — round-15 2.8% F(4x4) / 2.0%
     F(2x2) vs 0.8% direct; bounds leave headroom for run noise)."""
-    pytest.importorskip("sklearn")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "make_digits_idx.py"),
-         str(tmp_path / "data")],
-        capture_output=True, text=True,
-    )
-    assert r.returncode == 0, r.stderr
-    shutil.copy(os.path.join(REPO, "example", "MNIST", "digits_conv.conf"),
-                str(tmp_path / "digits_conv.conf"))
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO
-    r = subprocess.run(
-        [sys.executable, "-m", "cxxnet_tpu", "digits_conv.conf",
-         "task=train", "compute_dtype=bfloat16", f"conv_wino={wino}"],
-        cwd=str(tmp_path), env=env, capture_output=True, text=True,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    errs = {
-        int(m.group(1)): float(m.group(2))
-        for m in re.finditer(r"\[(\d+)\]\ttrain-error:\S+\ttest-error:(\S+)",
-                             r.stderr)
-    }
-    assert 15 in errs, r.stderr[-2000:]
+    errs = _digits_test_errors(tmp_path, "compute_dtype=bfloat16",
+                               f"conv_wino={wino}")
     # same acceptance shape as the fp32 test, widened one notch for the
     # documented bf16-Winograd noise: the tail must reach the digits
     # class (<=4%) and must not diverge (<=6% at round 15)
@@ -133,7 +106,7 @@ iter = end
         it.before_first()
         while it.next():
             tr.update(it.value())
-        if (step + 1) % 25 == 0:
+        if (step + 1) % 10 == 0:
             pred = tr.predict(cached)
             err = float((pred != cached.label[:, 0]).mean())
             if err == 0.0:
@@ -157,8 +130,8 @@ def test_membuffer_overfit_alexnet():
 def test_membuffer_overfit_googlenet():
     _overfit_one_cached_batch(
         googlenet_conf(batch_size=8, num_class=10, synthetic=False,
-                       dev="cpu", input_size=64),
-        "3,64,64", n_steps=300,
+                       dev="cpu", input_size=48),
+        "3,48,48", n_steps=300,
     )
 
 

@@ -5,52 +5,23 @@ rotary) and without (full, no positions), q/k norms and the output
 gate; the sandwich (``postnorm``) on ``attention``, ``gated_mlp`` and
 ``routed_experts``; the ranks' shares of an expert layer adding up to
 the uncut reference BEFORE the post norm; a dropped bias changing the
-chosen experts; the whole small net's loss, gradients and an adam chunk;
-the ``attn_window_pairs`` counter; the layer's refusals.
+chosen experts; the ``attn_window_pairs`` counter; the layer's refusals.
+What every family's tests share (the builder's conf through the trainer,
+the published defaults, the whole small net's loss, gradients and an adam
+chunk against the reference) is a row of ``tests/families.py``.
 """
-
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cxxnet_tpu import config as cfgmod
 from cxxnet_tpu.io.tokens import attn_pairs
-from cxxnet_tpu.layers import create_layer
-from cxxnet_tpu.models import afmoe_conf
-from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.utils.profiler import pipeline_stats
+from families import (held_against, make, rows_with_documents, strs,
+                      with_bias)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@pytest.fixture(scope="module")
-def ref():
-    """The configuration's plain reference: a file of the benchmark's
-    that imports nothing of the program."""
-    from benchmarks import run
-
-    return run.load_file(os.path.join(
-        ROOT, "benchmarks", "references", "afmoe.py"), "reference")
-
-
-def make(kind, in_shapes, seed=0, **cfg):
-    lay = create_layer(kind)
-    for k, v in cfg.items():
-        lay.set_param(k, str(v))
-    out = lay.infer_shape(in_shapes)
-    return lay, lay.init_params(jax.random.PRNGKey(seed), in_shapes), out
-
-
-def rows_with_documents(seed, n, t, vocab=50):
-    """Ids with separators inside every row, none at its first token."""
-    r = np.random.RandomState(seed)
-    ids = r.randint(1, vocab, (n, t))
-    ids[:, t // 3] = 0
-    ids[0, t // 2 + 1] = 0
-    return ids.astype(np.float32)
+FAMILY = "afmoe"
 
 
 def jiggled(p, seed, *tags):
@@ -59,25 +30,6 @@ def jiggled(p, seed, *tags):
     r = np.random.RandomState(seed)
     return dict(p, **{t: jnp.asarray(1 + 0.2 * r.randn(*p[t].shape),
                                      jnp.float32) for t in tags})
-
-
-def strs(cfg):
-    return {k: str(v) for k, v in cfg.items()}
-
-
-def held_against(prog, plain, p, x, tags, atol=5e-5):
-    """Forward and the gradients of the input and of ``tags``."""
-    with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(prog(p, x), plain(p, x), atol=atol)
-        ga = jax.grad(lambda q, a: jnp.sum(jnp.sin(prog(q, a))),
-                      argnums=(0, 1))(p, x)
-        gb = jax.grad(lambda q, a: jnp.sum(jnp.sin(plain(q, a))),
-                      argnums=(0, 1))(p, x)
-    np.testing.assert_allclose(ga[1], gb[1], atol=atol)
-    for tag in tags:
-        np.testing.assert_allclose(ga[0][tag], gb[0][tag], atol=atol,
-                                   err_msg=tag)
-        assert np.abs(np.asarray(ga[0][tag])).max() > 0, tag
 
 
 # ----------------------------------------------------------------------
@@ -185,11 +137,6 @@ MOE = dict(nexpert=32, topk=4, nhidden=10, shared_hidden=6, shared_gate=0,
            eps=1e-5, init_sigma=0.5)
 
 
-def with_bias(p, seed=8):
-    return dict(p, score_bias=jnp.asarray(
-        0.2 * np.random.RandomState(seed).randn(32), jnp.float32))
-
-
 def test_the_eight_shares_add_up_before_the_post_norm(ref):
     """model-configs section 4, with the sandwich: 32 experts over 8
     ranks of 4; every rank norms its input alike, routes over all 32
@@ -202,7 +149,7 @@ def test_the_eight_shares_add_up_before_the_post_norm(ref):
     goes through the norm as it is (the configuration's
     ``deployment``)."""
     _, p, _ = make("routed_experts", [(2, 12, 8)], **dict(MOE, **SANDWICH))
-    p = jiggled(with_bias(p), 9, "norm", "postnorm")
+    p = jiggled(with_bias(p, 8), 9, "norm", "postnorm")
     x = jnp.asarray(np.random.RandomState(10).randn(2, 12, 8), jnp.float32)
     whole = strs(MOE)
     with jax.default_matmul_precision("highest"):
@@ -273,162 +220,9 @@ def test_a_dropped_bias_changes_the_chosen_eight(ref):
     # the program's layer follows the bias it is given
     lay, q, _ = make("routed_experts", [(2, 12, 8)], **MOE)
     xs = jnp.asarray(np.random.RandomState(11).randn(2, 12, 8), jnp.float32)
-    a = lay.apply(with_bias(q), [xs])[0]
+    a = lay.apply(with_bias(q, 8), [xs])[0]
     b = lay.apply(q, [xs])[0]
     assert np.abs(np.asarray(a - b)).max() > 1e-3
-
-
-# ----------------------------------------------------------------------
-TINY = dict(vocab=64, seq_len=64, hidden=32, layer_types="ssf",
-            num_dense_layers=1, sliding_window=16, attn_heads=4,
-            attn_kv_heads=2, head_dim=16, mlp_hidden=48, num_experts=16,
-            experts_per_tok=3, expert_hidden=24, shared_hidden=24,
-            experts_held=4, dev="cpu", compute_dtype="float32",
-            scan_steps=4)
-
-
-def trainer(text):
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(text))
-    tr.set_param("silent", "1")
-    tr.init_model()
-    return tr
-
-
-def in_program_s_keys(tr, made):
-    return {k: {t: made[int(k[1:k.index("_")])][t] for t in tags}
-            for k, tags in tr.params.items()}
-
-
-def test_the_whole_small_net_s_loss_and_gradients_are_the_reference_s(ref):
-    # a sliding layer with the dense MLP, a full one with the experts
-    text = afmoe_conf(**dict(TINY, layer_types="sf"))
-    tr = trainer(text)
-    net = ref.describe(text, 1)
-    assert {int(k[1:k.index("_")]): {t: tuple(v.shape) for t, v in
-                                     tags.items()}
-            for k, tags in tr.params.items()} == net.pshapes
-    made = ref.make_weights(net, 5)
-    # norms off 1, so that each of the four a layer is in its place
-    r = np.random.RandomState(12)
-    for leaves in made.values():
-        for t in leaves:
-            if t in ref.ONES:
-                leaves[t] = jnp.asarray(1 + 0.2 * r.randn(*leaves[t].shape),
-                                        jnp.float32)
-    params = in_program_s_keys(tr, made)
-    ids = rows_with_documents(13, 1, 64, vocab=64)
-    lab = np.roll(ids, -1, axis=1)
-    with jax.default_matmul_precision("highest"):
-        got_l, got = jax.value_and_grad(lambda q: tr.net.loss_fn(
-            q, jnp.asarray(ids), jnp.asarray(lab)))(params)
-        ref_l, ref_g = jax.value_and_grad(ref.loss_fn(net))(
-            made, jnp.asarray(ids, jnp.int32), jnp.asarray(lab, jnp.int32))
-    np.testing.assert_allclose(got_l, ref_l, rtol=1e-6)
-    assert 0.9 * np.log(64) < float(ref_l) < 1.6 * np.log(64)
-    for key, tags in got.items():
-        for tag, g in tags.items():
-            np.testing.assert_allclose(
-                g, ref_g[int(key[1:key.index("_")])][tag], atol=3e-6,
-                err_msg=f"{key}.{tag}")
-    # a share's routers and the bias get no gradient; every norm does
-    assert np.abs(np.asarray(got["l4_moe1"]["wgate"])).max() == 0
-    assert np.abs(np.asarray(got["l4_moe1"]["score_bias"])).max() == 0
-    for key in ("l1_attn0", "l2_mlp0", "l3_attn1", "l4_moe1"):
-        assert np.abs(np.asarray(got[key]["postnorm"])).max() > 0
-
-
-def test_an_adam_chunk_through_update_scan_is_the_reference_s(ref):
-    """The scanned step under adam, 4 steps: the losses, the parameters
-    after and adam's first moment against ``train_chunk``."""
-    text = afmoe_conf(**TINY)
-    tr = trainer(text)
-    net = ref.describe(text, 1)
-    made = ref.make_weights(net, 6)
-    tr.params = in_program_s_keys(tr, made)
-    tr._place_state()
-    data, labels = ref.seeded_chunk(net, 6, 4)
-    with jax.default_matmul_precision("highest"):
-        losses = np.asarray(tr.update_scan(data, labels), np.float64)
-        ref_l, ref_p, ref_m = ref.train_chunk(
-            net, ref.make_weights(net, 6), data, labels, None)
-    np.testing.assert_allclose(losses.reshape(-1), ref_l, rtol=2e-5)
-    m1 = ref.program_update_state(
-        {int(k[1:k.index("_")]): v for k, v in
-         jax.device_get(tr.ustates).items()})
-    for key, tags in tr.params.items():
-        i = int(key[1:key.index("_")])
-        for tag, w in tags.items():
-            np.testing.assert_allclose(w, ref_p[i][tag], atol=2e-5,
-                                       err_msg=f"{key}.{tag}")
-            np.testing.assert_allclose(m1[i][tag], ref_m[i][tag], atol=2e-6,
-                                       err_msg=f"{key}.{tag} m1")
-
-
-def test_the_builder_s_conf_trains_and_counts_its_pairs(tmp_path):
-    text = afmoe_conf(**TINY)
-    assert text.count("= attention:") == 3
-    assert text.count("  window = 16\n") == 2
-    assert text.count("  rotary_dim = 16") == 2
-    assert text.count("= routed_experts:") == 2
-    assert text.count("= gated_mlp:") == 1 and "tied" not in text
-    assert text.count("postnorm = 1") == 6 and "routed_scale = 2.826" in text
-    assert f"multiplier = {32 ** 0.5!r}" in text
-    assert "iter = tokens" not in text
-    fed = afmoe_conf(**dict(TINY, token_file="tokens.bin"))
-    assert "  attn_window = 16\n" in fed
-    assert "attn_window" not in afmoe_conf(**dict(
-        TINY, layer_types="ff", token_file="tokens.bin"))
-    tr = trainer(text)
-    assert set(tr.aux) == {"l1_attn0", "l3_attn1", "l5_attn2", "l4_moe1",
-                           "l6_moe2"}
-    r = np.random.RandomState(0)
-    ids = r.randint(0, 64, (4, 1, 64)).astype(np.float32)
-    router = np.asarray(tr.params["l4_moe1"]["wgate"]).copy()
-    post = np.asarray(tr.params["l4_moe1"]["postnorm"]).copy()
-    first = tr.update_scan(ids, np.roll(ids, -1, axis=2))
-    again = tr.update_scan(ids, np.roll(ids, -1, axis=2))
-    assert np.isfinite(first).all() and again.mean() < first.mean()
-    assert np.array_equal(np.asarray(tr.params["l4_moe1"]["wgate"]), router)
-    assert not np.array_equal(
-        np.asarray(tr.params["l4_moe1"]["postnorm"]), post)
-    stats = pipeline_stats()
-    tokens = stats.counters().get("attn_tokens", 0)
-    blocks = stats.counters().get("attn_blocks", 0)
-    tr.count_layer_state()
-    assert stats.counters()["attn_tokens"] - tokens == 8 * 64 * 3
-    # mha's rows computed them off the TPU: no block of the kernels'
-    assert stats.counters().get("attn_blocks", 0) == blocks
-    with pytest.raises(ValueError, match="string of s and f"):
-        afmoe_conf(layer_types="sxf")
-    with pytest.raises(ValueError, match="num_dense_layers"):
-        afmoe_conf(layer_types="sf", num_dense_layers=3)
-
-
-def test_the_published_defaults_are_what_the_issue_reckoned():
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(afmoe_conf(dev="cpu")))
-    tr._build_net()
-    shapes = jax.eval_shape(
-        lambda k: tr.net.init_params(k, 1), jax.random.PRNGKey(0))
-    count = lambda key: sum(  # noqa: E731
-        int(np.prod(v.shape)) for v in shapes[key].values())
-    # q | gate, k, v fused; the output projection; q/k norms; the sandwich
-    attn = 2048 * (2 * 4096 + 2 * 512) + 4096 * 2048 + 2 * 128 + 2 * 2048
-    assert count("l1_attn0") == count("l9_attn4") == attn == 27_267_328
-    assert count("l2_mlp0") == 3 * 2048 * 6144 + 2 * 2048
-    # router + bias, 8 held experts and the shared one, two norms
-    assert count("l4_moe1") == (128 * 2048 + 128 + 9 * 3 * 2048 * 1024
-                                + 2 * 2048)
-    assert count("l0_embed") == count("l12_head") == 25024 * 2048
-    total = sum(count(k) for k in shapes)
-    assert total == 504_147_712                        # x 16 B = 8.07 GB
-    assert round(total * 16 / 1e9, 2) == 8.07
-    # what ISSUE 42 reckoned for 16 held: 705.4M, 11.29 GB
-    assert total + 4 * 8 * 3 * 2048 * 1024 == 705_474_304
-    text = afmoe_conf()
-    assert text.count("  window = 2048\n") == 4 and "seq_len" not in text
-    assert "label_width = 16384" in text and "nheld = 8" in text
 
 
 # ----------------------------------------------------------------------

@@ -19,7 +19,8 @@ def test_ring_matches_full_attention(rng, causal):
     q, k, v = _qkv(rng)
     plan = make_mesh("cpu:0-7", model_parallel=4)  # seq over 'model' (4-way)
     want = mha(q, k, v, causal=causal)
-    got = ring_self_attention(q, k, v, plan.mesh, "model", causal=causal)
+    got = jax.jit(lambda q, k, v: ring_self_attention(
+        q, k, v, plan.mesh, "model", causal=causal))(q, k, v)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
@@ -29,7 +30,8 @@ def test_ring_full_eight_way(rng):
     q, k, v = _qkv(rng, b=8, t=64)
     plan = make_mesh("cpu:0-7", model_parallel=8)  # pure SP ring
     want = mha(q, k, v, causal=True)
-    got = ring_self_attention(q, k, v, plan.mesh, "model", causal=True)
+    got = jax.jit(lambda q, k, v: ring_self_attention(
+        q, k, v, plan.mesh, "model", causal=True))(q, k, v)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
@@ -47,8 +49,8 @@ def test_ring_gradients_match(rng):
     def loss_full(q, k, v):
         return jnp.sum(mha(q, k, v, causal=True) ** 2)
 
-    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    g_full = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
+    g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    g_full = jax.jit(jax.grad(loss_full, argnums=(0, 1, 2)))(q, k, v)
     for gr, gf in zip(g_ring, g_full):
         np.testing.assert_allclose(
             np.asarray(gr), np.asarray(gf), rtol=5e-4, atol=5e-5
@@ -74,7 +76,8 @@ def test_a2a_matches_full_attention(rng, causal):
     q, k, v = _qkv(rng)  # h=4 divides the 4-way axis
     plan = make_mesh("cpu:0-7", model_parallel=4)
     want = mha(q, k, v, causal=causal)
-    got = a2a_self_attention(q, k, v, plan.mesh, "model", causal=causal)
+    got = jax.jit(lambda q, k, v: a2a_self_attention(
+        q, k, v, plan.mesh, "model", causal=causal))(q, k, v)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
@@ -84,7 +87,8 @@ def test_a2a_eight_way(rng):
     q, k, v = _qkv(rng, b=8, t=64, h=8)
     plan = make_mesh("cpu:0-7", model_parallel=8)
     want = mha(q, k, v, causal=True)
-    got = a2a_self_attention(q, k, v, plan.mesh, "model", causal=True)
+    got = jax.jit(lambda q, k, v: a2a_self_attention(
+        q, k, v, plan.mesh, "model", causal=True))(q, k, v)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
@@ -102,8 +106,8 @@ def test_a2a_gradients_match(rng):
     def loss_full(q_, k_, v_):
         return jnp.sum(mha(q_, k_, v_) ** 2)
 
-    ga = jax.grad(loss_a2a, argnums=(0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
+    ga = jax.jit(jax.grad(loss_a2a, argnums=(0, 1, 2)))(q, k, v)
+    gf = jax.jit(jax.grad(loss_full, argnums=(0, 1, 2)))(q, k, v)
     for a, f in zip(ga, gf):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(f), rtol=1e-4, atol=1e-5
@@ -126,7 +130,8 @@ def test_attention_layer_seq_parallel_modes(rng):
         lay.bind_mesh(plan)
         lay.infer_shape([(4, 16, 32)])
         params = lay.init_params(jax.random.PRNGKey(0), [(4, 16, 32)])
-        (outs[mode],) = lay.apply(params, [x])
+        (outs[mode],) = jax.jit(lambda p, a, lay=lay: lay.apply(p, [a]))(
+            params, x)
     np.testing.assert_allclose(
         np.asarray(outs["ring"]), np.asarray(outs["0"]), rtol=2e-5,
         atol=2e-5)

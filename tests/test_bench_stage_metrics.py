@@ -2,7 +2,6 @@
 (``benchmarks/tests/test_stage_metrics.py``), in a file of their own so
 the workers can run them beside ``test_bench_harness.py``."""
 
-import json
 import os
 import shutil
 import sys
@@ -17,151 +16,149 @@ from benchmarks.tests.test_stage_metrics import *  # noqa: E402,F401,F403
 
 
 # ----------------------------------------------------------------------
-# train_metric_device_pct (PR 30): a counter reader added beside the
-# stage readers
 def _counted(*rounds):
     return {"telemetry": [{"round": i, "steps": 24, "counters": c}
                           for i, c in enumerate(rounds)]}
 
 
-@pytest.mark.parametrize("rounds, want", [
-    ([{"metric_rows": 6144, "metric_rows_device": 6144}] * 2, 100.0),
-    ([{"metric_rows": 6144, "metric_rows_device": 6144},
-      {"metric_rows": 6144}], 50.0),
-    # eval_train = 0 counts no row; an iterator's counters are not rows
-    ([{"tokens": 196608, "docs": 120}], None),
-    ([{}], None),
-    ([], None),
-])
-def test_train_metric_device_pct_reads_the_two_row_counters(rounds, want):
-    read = run.load_metric("train_metric_device_pct").read
-    assert read(_counted(*rounds)) == want
-    # the parent commit's record has no ``counters`` block at all
-    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
+# the readers of two counters of the rounds' records: the share of one in
+# the other over the rounds that counted, None where none did
+COUNTER_READERS = {
+    # train_metric_device_pct (PR 30): a counter reader added beside the
+    # stage readers
+    "train_metric_device_pct": [
+        ([{"metric_rows": 6144, "metric_rows_device": 6144}] * 2, 100.0),
+        ([{"metric_rows": 6144, "metric_rows_device": 6144},
+          {"metric_rows": 6144}], 50.0),
+        # eval_train = 0 counts no row; an iterator's counters are not rows
+        ([{"tokens": 196608, "docs": 120}], None),
+        ([{}], None),
+        ([], None),
+    ],
+    # chunk_overlap_pct (PR 32): the round loop counts every scanned chunk
+    # it fences and those whose fence found a later chunk dispatched
+    "chunk_overlap_pct": [
+        # a round of three chunks: its last has nothing behind it
+        ([{"chunks_fenced": 3, "chunks_overlapped": 2}] * 2, 100.0 * 2 / 3),
+        ([{"chunks_fenced": 3, "chunks_overlapped": 2,
+           "metric_rows": 6144, "metric_rows_device": 6144},
+          {"chunks_fenced": 1}], 50.0),
+        # every chunk fenced inside its own call (the parent's eval_train = 1)
+        ([{"chunks_fenced": 3}], 0.0),
+        # the parent counts no fence; the per-batch path fences no chunk
+        ([{"metric_rows": 6144, "metric_rows_device": 6144}], None),
+        ([{"tokens": 196608, "docs": 120}], None),
+        ([{}], None),
+        ([], None),
+    ],
+    # gdn_scan_fused_pct (PR 34): the gated_deltanet layers count the tokens
+    # through their scan and those the fused kernels computed, inside the
+    # step programs
+    "gdn_scan_fused_pct": [
+        # three layers x 24 steps x 8192 tokens a round, every one fused
+        ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824,
+           "expert_pairs": 61440}] * 2, 100.0),
+        # a round whose programs were lowered for another platform
+        ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824},
+          {"gdn_scan_tokens": 589824}], 50.0),
+        # the jax.numpy form: counted, none fused
+        ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 0}], 0.0),
+        ([{"gdn_scan_tokens": 589824}], 0.0),
+        # the parent counts neither; other layers' counters are not tokens
+        ([{"expert_pairs": 61440, "tokens": 196608}], None),
+        ([{"gdn_scan_tokens": 0}], None),
+        ([{}], None),
+        ([], None),
+    ],
+    # ssd_scan_fused_pct (PR 41): the mamba2 layers count the tokens through
+    # their scan and those the fused kernels computed, inside the step
+    # programs — a twin of the delta rule's reader
+    "ssd_scan_fused_pct": [
+        # nine mixers x 24 steps x 8192 tokens a round, every one fused
+        ([{"ssd_scan_tokens": 1769472, "ssd_scan_tokens_fused": 1769472,
+           "attn_tokens": 196608}] * 2, 100.0),
+        # a round whose programs were lowered for another platform
+        ([{"ssd_scan_tokens": 1769472, "ssd_scan_tokens_fused": 1769472},
+          {"ssd_scan_tokens": 1769472}], 50.0),
+        # the jax.numpy form: counted, none fused
+        ([{"ssd_scan_tokens": 983040, "ssd_scan_tokens_fused": 0}], 0.0),
+        ([{"ssd_scan_tokens": 983040}], 0.0),
+        # the parent counts neither; the delta rule's tokens are not these
+        ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824,
+           "tokens": 196608}], None),
+        ([{"ssd_scan_tokens": 0}], None),
+        ([{}], None),
+        ([], None),
+    ],
+    # attn_flash_pct (PR 37): the masked attention layers (``attention``'s
+    # masked path, ``latent_attention``) count the tokens through them and
+    # those the flash kernels computed, inside the step programs
+    "attn_flash_pct": [
+        # six latent layers x 24 steps x 8192 tokens a round, every one flash
+        ([{"attn_tokens": 1179648, "attn_tokens_flash": 1179648,
+           "attn_pairs": 288000000, "expert_pairs": 61440}] * 2, 100.0),
+        # a round whose programs were lowered for another platform
+        ([{"attn_tokens": 196608, "attn_tokens_flash": 196608},
+          {"attn_tokens": 196608}], 50.0),
+        # a shape the chooser left to mha's row blocks: counted, none flash
+        ([{"attn_tokens": 196608, "attn_tokens_flash": 0}], 0.0),
+        ([{"attn_tokens": 196608}], 0.0),
+        # the parent counts neither; the iterator's pairs are not tokens
+        ([{"attn_pairs": 288000000, "tokens": 196608,
+           "gdn_scan_tokens": 589824}], None),
+        ([{"attn_tokens": 0}], None),
+        ([{}], None),
+        ([], None),
+    ],
+    # attn_unmasked_blocks_pct (PR 43): the same layers count the blocks the
+    # flash kernels' forward visits and those of them whose every pair may
+    # attend
+    "attn_unmasked_blocks_pct": [
+        # Trinity's five layers x 32 heads x 24 steps: a third wholly live
+        ([{"attn_blocks": 180000, "attn_blocks_unmasked": 60000,
+           "attn_tokens": 1966080, "attn_tokens_flash": 1966080}] * 2,
+         100.0 / 3),
+        # a round of one long document a row, and one of short documents
+        ([{"attn_blocks": 1000, "attn_blocks_unmasked": 1000},
+          {"attn_blocks": 3000, "attn_blocks_unmasked": 0}], 25.0),
+        ([{"attn_blocks": 1000}], 0.0),
+        # the parent counts tokens and no blocks; mha's rows visit none
+        ([{"attn_tokens": 196608, "attn_tokens_flash": 196608}], None),
+        ([{"attn_tokens": 196608, "attn_blocks": 0,
+           "attn_blocks_unmasked": 0}], None),
+        ([{}], None),
+        ([], None),
+    ],
+    # expert_dispatch_compact_pct (PR 39): the routed expert layers count the
+    # pairs they computed in slabs after the first, inside the step programs
+    "expert_dispatch_compact_pct": [
+        # four layers x 24 steps x ~5 100 held pairs, every one in the first
+        # slab: the loop never ran
+        ([{"expert_pairs": 491520, "expert_pairs_overflow": 0,
+           "expert_pairs_dropped": 0}] * 2, 100.0),
+        # a round whose router sent a tenth of the pairs past the slab
+        ([{"expert_pairs": 491520, "expert_pairs_overflow": 0},
+          {"expert_pairs": 491520, "expert_pairs_overflow": 98304}], 90.0),
+        # every held pair beyond a slab of none: nothing compact
+        ([{"expert_pairs": 1000, "expert_pairs_overflow": 1000}], 0.0),
+        # the parent counts pairs and no overflow; no pairs, no share
+        ([{"expert_pairs": 491520, "expert_pairs_dropped": 0}], None),
+        ([{"expert_pairs": 0, "expert_pairs_overflow": 0}], None),
+        ([{"tokens": 196608, "attn_tokens": 196608}], None),
+        ([{}], None),
+        ([], None),
+    ],
+}
 
 
-# chunk_overlap_pct (PR 32): the round loop counts every scanned chunk
-# it fences and those whose fence found a later chunk dispatched
-@pytest.mark.parametrize("rounds, want", [
-    # a round of three chunks: its last has nothing behind it
-    ([{"chunks_fenced": 3, "chunks_overlapped": 2}] * 2, 100.0 * 2 / 3),
-    ([{"chunks_fenced": 3, "chunks_overlapped": 2,
-       "metric_rows": 6144, "metric_rows_device": 6144},
-      {"chunks_fenced": 1}], 50.0),
-    # every chunk fenced inside its own call (the parent's eval_train = 1)
-    ([{"chunks_fenced": 3}], 0.0),
-    # the parent counts no fence; the per-batch path fences no chunk
-    ([{"metric_rows": 6144, "metric_rows_device": 6144}], None),
-    ([{"tokens": 196608, "docs": 120}], None),
-    ([{}], None),
-    ([], None),
-])
-def test_chunk_overlap_pct_reads_the_two_fence_counters(rounds, want):
-    read = run.load_metric("chunk_overlap_pct").read
-    assert read(_counted(*rounds)) == pytest.approx(want)
-    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
-
-
-# gdn_scan_fused_pct (PR 34): the gated_deltanet layers count the tokens
-# through their scan and those the fused kernels computed, inside the
-# step programs
-@pytest.mark.parametrize("rounds, want", [
-    # three layers x 24 steps x 8192 tokens a round, every one fused
-    ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824,
-       "expert_pairs": 61440}] * 2, 100.0),
-    # a round whose programs were lowered for another platform
-    ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824},
-      {"gdn_scan_tokens": 589824}], 50.0),
-    # the jax.numpy form: counted, none fused
-    ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 0}], 0.0),
-    ([{"gdn_scan_tokens": 589824}], 0.0),
-    # the parent counts neither; other layers' counters are not tokens
-    ([{"expert_pairs": 61440, "tokens": 196608}], None),
-    ([{"gdn_scan_tokens": 0}], None),
-    ([{}], None),
-    ([], None),
-])
-def test_gdn_scan_fused_pct_reads_the_two_scan_counters(rounds, want):
-    read = run.load_metric("gdn_scan_fused_pct").read
-    assert read(_counted(*rounds)) == want
-    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
-
-
-# ssd_scan_fused_pct (PR 41): the mamba2 layers count the tokens through
-# their scan and those the fused kernels computed, inside the step
-# programs — a twin of the delta rule's reader
-@pytest.mark.parametrize("rounds, want", [
-    # nine mixers x 24 steps x 8192 tokens a round, every one fused
-    ([{"ssd_scan_tokens": 1769472, "ssd_scan_tokens_fused": 1769472,
-       "attn_tokens": 196608}] * 2, 100.0),
-    # a round whose programs were lowered for another platform
-    ([{"ssd_scan_tokens": 1769472, "ssd_scan_tokens_fused": 1769472},
-      {"ssd_scan_tokens": 1769472}], 50.0),
-    # the jax.numpy form: counted, none fused
-    ([{"ssd_scan_tokens": 983040, "ssd_scan_tokens_fused": 0}], 0.0),
-    ([{"ssd_scan_tokens": 983040}], 0.0),
-    # the parent counts neither; the delta rule's tokens are not these
-    ([{"gdn_scan_tokens": 589824, "gdn_scan_tokens_fused": 589824,
-       "tokens": 196608}], None),
-    ([{"ssd_scan_tokens": 0}], None),
-    ([{}], None),
-    ([], None),
-])
-def test_ssd_scan_fused_pct_reads_the_two_scan_counters(rounds, want):
-    read = run.load_metric("ssd_scan_fused_pct").read
-    assert read(_counted(*rounds)) == want
-    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
-
-
-# attn_flash_pct (PR 37): the masked attention layers (``attention``'s
-# masked path, ``latent_attention``) count the tokens through them and
-# those the flash kernels computed, inside the step programs
-@pytest.mark.parametrize("rounds, want", [
-    # six latent layers x 24 steps x 8192 tokens a round, every one flash
-    ([{"attn_tokens": 1179648, "attn_tokens_flash": 1179648,
-       "attn_pairs": 288000000, "expert_pairs": 61440}] * 2, 100.0),
-    # a round whose programs were lowered for another platform
-    ([{"attn_tokens": 196608, "attn_tokens_flash": 196608},
-      {"attn_tokens": 196608}], 50.0),
-    # a shape the chooser left to mha's row blocks: counted, none flash
-    ([{"attn_tokens": 196608, "attn_tokens_flash": 0}], 0.0),
-    ([{"attn_tokens": 196608}], 0.0),
-    # the parent counts neither; the iterator's pairs are not tokens
-    ([{"attn_pairs": 288000000, "tokens": 196608,
-       "gdn_scan_tokens": 589824}], None),
-    ([{"attn_tokens": 0}], None),
-    ([{}], None),
-    ([], None),
-])
-def test_attn_flash_pct_reads_the_two_attention_counters(rounds, want):
-    read = run.load_metric("attn_flash_pct").read
-    assert read(_counted(*rounds)) == want
-    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
-
-
-# attn_unmasked_blocks_pct (PR 43): the same layers count the blocks the
-# flash kernels' forward visits and those of them whose every pair may
-# attend
-@pytest.mark.parametrize("rounds, want", [
-    # Trinity's five layers x 32 heads x 24 steps: a third wholly live
-    ([{"attn_blocks": 180000, "attn_blocks_unmasked": 60000,
-       "attn_tokens": 1966080, "attn_tokens_flash": 1966080}] * 2,
-     100.0 / 3),
-    # a round of one long document a row, and one of short documents
-    ([{"attn_blocks": 1000, "attn_blocks_unmasked": 1000},
-      {"attn_blocks": 3000, "attn_blocks_unmasked": 0}], 25.0),
-    ([{"attn_blocks": 1000}], 0.0),
-    # the parent counts tokens and no blocks; mha's rows visit none
-    ([{"attn_tokens": 196608, "attn_tokens_flash": 196608}], None),
-    ([{"attn_tokens": 196608, "attn_blocks": 0,
-       "attn_blocks_unmasked": 0}], None),
-    ([{}], None),
-    ([], None),
-])
-def test_attn_unmasked_blocks_pct_reads_the_two_block_counters(rounds, want):
-    read = run.load_metric("attn_unmasked_blocks_pct").read
+@pytest.mark.parametrize("name, rounds, want", [
+    (name, rounds, want) for name, cases in COUNTER_READERS.items()
+    for rounds, want in cases])
+def test_a_counter_reader_reads_its_two_counters(name, rounds, want):
+    read = run.load_metric(name).read
     assert read(_counted(*rounds)) == (
-        want if want is None else pytest.approx(want))
+        want if want is None else pytest.approx(want, rel=1e-12))
+    # the parent commit's record has no ``counters`` block at all
     assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
@@ -250,33 +247,6 @@ def test_attn_fwd_runs_per_bwd_reads_nothing_without_a_trace(tmp_path):
     assert any(s and s.endswith(":") for _, _, s in
                scopes.device_events(fixture))
     assert read({"out": out, "trace": {"steps": 16}}) is None
-
-
-# expert_dispatch_compact_pct (PR 39): the routed expert layers count the
-# pairs they computed in slabs after the first, inside the step programs
-@pytest.mark.parametrize("rounds, want", [
-    # four layers x 24 steps x ~5 100 held pairs, every one in the first
-    # slab: the loop never ran
-    ([{"expert_pairs": 491520, "expert_pairs_overflow": 0,
-       "expert_pairs_dropped": 0}] * 2, 100.0),
-    # a round whose router sent a tenth of the pairs past the slab
-    ([{"expert_pairs": 491520, "expert_pairs_overflow": 0},
-      {"expert_pairs": 491520, "expert_pairs_overflow": 98304}], 90.0),
-    # every held pair beyond a slab of none: nothing compact
-    ([{"expert_pairs": 1000, "expert_pairs_overflow": 1000}], 0.0),
-    # the parent counts pairs and no overflow; no pairs, no share
-    ([{"expert_pairs": 491520, "expert_pairs_dropped": 0}], None),
-    ([{"expert_pairs": 0, "expert_pairs_overflow": 0}], None),
-    ([{"tokens": 196608, "attn_tokens": 196608}], None),
-    ([{}], None),
-    ([], None),
-])
-def test_expert_dispatch_compact_pct_reads_the_two_pair_counters(
-        rounds, want):
-    read = run.load_metric("expert_dispatch_compact_pct").read
-    assert read(_counted(*rounds)) == (
-        want if want is None else pytest.approx(want))
-    assert read({"telemetry": [{"round": 1, "steps": 24}]}) is None
 
 
 # the round loop's bill of the device's time from its own fences (PR 38):
@@ -372,16 +342,7 @@ def test_head_and_tail_close_on_the_loops_idle_time():
         read["round_head_ms_step"] + read["h2d_tail_ms_step"])
 
 
-ALL_CELLS = ["googlenet_train_synth", "resnet50_train_synth",
-             "granite_4_0_h_micro_train_packed8k",
-             "qwen3_next_80b_a3b_train_packed8k",
-             "joyai_llm_flash_train_packed8k",
-             "nemotron_3_super_120b_a12b_train_packed8k",
-             "trinity_mini_train_packed16k"]
-
-
-LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
-             "round_head_ms_step", "h2d_tail_ms_step", "chunk_starved_pct"]
+from bench_shadows import ALL_CELLS, LOOP_BILL  # noqa: E402
 
 
 @pytest.mark.parametrize("name, cells, better", [
@@ -402,38 +363,9 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
     ("expert_dispatch_compact_pct", ALL_CELLS[3:], "higher"),
 ] + [(name, ALL_CELLS, "lower") for name in LOOP_BILL])
 def test_benchmark_json_names_the_reader_that_exists(name, cells, better):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    entries = [m for m in bench["per_layer"] if m["name"] == name]
-    assert len(entries) == 1
-    entry = entries[0]
-    mod = run.load_metric(name)
-    assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES, better) == (
-        entry["layer"], entry["unit"], entry["source"], entry["moves"],
-        entry["better"])
-    assert entry["workloads"] == cells
-    assert set(cells) <= {w["name"] for w in bench["workloads"]}
-    # a new entry goes to the end of the list, behind those it found:
-    # the 31st when PR 32 added it, and PR 33's ten behind it
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names.index("chunk_overlap_pct") == 30
-    # PR 34's one entry behind them, and PR 36's four behind that
-    assert names.index("gdn_scan_fused_pct") == 41
-    assert names[42:46] == ["mla_ms_step", "mla_core_ms_step",
-                            "mla_core_roofline_pct", "mtp_ms_step"]
-    # PR 37's one behind them, PR 38's five behind that, and PR 39's
-    # one; PR 40's three; PR 41's one; PR 42's four; PR 43's one; PR 44's
-    # one, the last
-    assert names[46:47] == ["attn_flash_pct"]
-    assert names[47:52] == LOOP_BILL
-    assert names[52:] == ["expert_dispatch_compact_pct",
-                          "moe_latent_proj_ms_step",
-                          "latent_expert_matmul_roofline_pct",
-                          "ssd_scan_grouped_roofline_pct",
-                          "ssd_scan_fused_pct",
-                          "attn_window_core_ms_step",
-                          "attn_full_core_ms_step",
-                          "attn_window_pairs_pct",
-                          "attn_core_roofline_pct",
-                          "attn_unmasked_blocks_pct",
-                          "attn_fwd_runs_per_bwd"]
+    """``cells`` are those the entry was listed under when its PR (or a
+    later one of PRs 30-44) left it; a later cell may follow them and a
+    later entry the last of ``bench_shadows.METRICS_FROM_30``."""
+    import bench_shadows
+
+    bench_shadows.names_the_reader(bench_shadows.load(), name, cells, better)

@@ -121,11 +121,10 @@ def test_inception_pair_forward_and_grads():
     rng = np.random.RandomState(3)
     x = jnp.asarray(rng.randn(16, 12, 12, 8).astype(np.float32))
     y = jnp.asarray(rng.randint(0, 4, (16, 1)).astype(np.float32))
-    la = a.net.loss_fn(a.params, x, y, train=False)
-    lb = b.net.loss_fn(b.params, x, y, train=False)
+    (la, ga), (lb, gb) = (jax.jit(jax.value_and_grad(
+        lambda p, t=t: t.net.loss_fn(p, x, y, train=False)))(t.params)
+        for t in (a, b))
     np.testing.assert_allclose(float(la), float(lb), rtol=2e-4)
-    ga = jax.grad(lambda p: a.net.loss_fn(p, x, y, train=False))(a.params)
-    gb = jax.grad(lambda p: b.net.loss_fn(p, x, y, train=False))(b.params)
     for pa, pb in zip(jax.tree_util.tree_leaves(ga),
                       jax.tree_util.tree_leaves(gb)):
         np.testing.assert_allclose(np.asarray(pa), np.asarray(pb),
@@ -144,18 +143,22 @@ def test_googlenet_all_nine_modules_group():
             input_size=64)))
         tr.set_param("conv_branch_embed", str(bembed))
         tr.set_param("seed", "7")
-        tr.init_model()
+        tr._build_net()
         return tr
 
     a, b = build(0), build(1)
     _items, gmap = b.net._branch_embed_plan()
     assert len(gmap) == 9
     assert all(len(v) == 2 for v in gmap.values())
+    # parameters stay per-layer under the fusion: one seeded start for
+    # both nets (drawn in one program, not 130 leaves one small program
+    # each), and each forward compiled once
+    params = jax.jit(lambda k: a.net.init_params(k, 4))(jax.random.PRNGKey(7))
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.rand(4, 64, 64, 3).astype(np.float32))
     y = jnp.asarray(rng.randint(0, 10, (4, 1)).astype(np.float32))
-    la = float(a.net.loss_fn(a.params, x, y, train=False))
-    lb = float(b.net.loss_fn(b.params, x, y, train=False))
+    la, lb = (float(jax.jit(lambda p, t=t: t.net.loss_fn(
+        p, x, y, train=False))(params)) for t in (a, b))
     np.testing.assert_allclose(la, lb, rtol=1e-3)
 
 
@@ -223,7 +226,7 @@ def test_branch_embed_off_domain_no_group():
         tr = NetTrainer()
         tr.set_params(C.parse_pairs(conf))
         tr.set_param("conv_branch_embed", "1")
-        tr.init_model()
+        tr._build_net()          # the plan reads the graph, no weight
         items, gmap = tr.net._branch_embed_plan()
         assert gmap == {} and items is None
 

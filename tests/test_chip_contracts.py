@@ -255,7 +255,7 @@ def test_pallas_kernel_lowers_for_tpu_at_smoke_shapes(case):
         return
     if name.startswith("routed_experts"):
         # no kernel of ours: the grouped products are the compiler's
-        # (tests/test_v5e_compile.py), the slabs after the first a loop;
+        # (tests/test_v5e_qwen3_next.py), the slabs after the first a loop;
         # the layer states the kept matrices' layout, a custom call the
         # exporter has to be told of
         text = jax.export.export(

@@ -44,8 +44,8 @@ def test_flash_grads_match_mha(causal, split):
     def loss_fl(q, k, v):
         return (flash_mha(q, k, v, causal, 16, 16, True) ** 2).sum()
 
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_fl, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    gf = jax.jit(jax.grad(loss_fl, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(gr, gf, "qkv"):
         np.testing.assert_allclose(
             np.asarray(b), np.asarray(a), rtol=2e-4, atol=2e-4,
@@ -179,8 +179,8 @@ def test_ring_flash_gradients_match():
     def loss_full(q, k, v):
         return jnp.sum(mha(q, k, v, causal=True) ** 2)
 
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    gf = jax.jit(jax.grad(loss_full, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(gr, gf, "qkv"):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
@@ -246,8 +246,8 @@ def test_flash_lse_fully_masked_rows_are_zero(k_off, dead, split):
     assert np.all(np.asarray(lse)[:, :dead] < -1e29)
     # live rows are real attention outputs
     assert dead == t or np.abs(out[:, dead:]).max() > 0
-    grads = jax.grad(lambda *a: hop(*a)[0].sum() + jnp.where(
-        hop(*a)[1] > -1e29, hop(*a)[1], 0.0).sum(), (0, 1, 2))(q, k, v)
+    grads = jax.jit(jax.grad(lambda *a: hop(*a)[0].sum() + jnp.where(
+        hop(*a)[1] > -1e29, hop(*a)[1], 0.0).sum(), (0, 1, 2)))(q, k, v)
     for g in grads:
         assert np.isfinite(np.asarray(g)).all()
         assert dead < t or not np.asarray(g).any()
@@ -290,8 +290,8 @@ def _hold_against_mha(q, k, v, doc, causal, scale, bq, bk, tol):
     assert got.dtype == want.dtype
     pairs = [("o", got, want)] + [
         ("d" + n, a, r) for n, a, r in zip(
-            "qkv", jax.grad(loss(kern), (0, 1, 2))(q, k, v),
-            jax.grad(loss(ref), (0, 1, 2))(q, k, v))]
+            "qkv", jax.jit(jax.grad(loss(kern), (0, 1, 2)))(q, k, v),
+            jax.jit(jax.grad(loss(ref), (0, 1, 2)))(q, k, v))]
     for name, a, r in pairs:
         assert a.shape == r.shape and a.dtype == r.dtype, name
         a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
@@ -364,8 +364,9 @@ def test_grouped_heads_without_documents_and_a_cotangent_of_lse():
                                block_k=8, interpret=True)
 
     loss = lambda fn: lambda *a: sum((x ** 2).sum() for x in fn(*a))
-    for a, r in zip(kern(q, k, v) + jax.grad(loss(kern), (0, 1, 2))(q, k, v),
-                    ref(q, k, v) + jax.grad(loss(ref), (0, 1, 2))(q, k, v)):
+    grads = lambda fn: jax.jit(jax.grad(loss(fn), (0, 1, 2)))  # noqa: E731
+    for a, r in zip(kern(q, k, v) + grads(kern)(q, k, v),
+                    ref(q, k, v) + grads(ref)(q, k, v)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                    rtol=2e-4, atol=2e-4)
 

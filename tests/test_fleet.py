@@ -52,12 +52,20 @@ def make_opts(**kw):
     return FleetOptions(**base)
 
 
-def start_stub_fleet(opts, per_replica=None, extra=(), model_dir=None):
+def start_stub_fleet(opts, per_replica=None, extra=(), model_dir=None,
+                    probe_by_hand=False):
     """ServingFleet over stub replicas, started and ready (no HTTP
     front door bound — tests drive ``fleet.router.route`` directly)."""
     fleet = ServingFleet(opts, spawn_fn=stub_spawn_fn(
         extra=extra, per_replica=per_replica), model_dir=model_dir)
     fleet.supervisor.start()
+    deadline = time.monotonic() + 60.0
+    # the probe loop sleeps a period before its first probe: a test that
+    # keeps probes dormant makes the probes that find the stubs ready
+    while probe_by_hand and time.monotonic() < deadline and len(
+            fleet.supervisor.healthy()) < len(fleet.supervisor.replicas):
+        fleet.supervisor.probe_once()
+        time.sleep(0.05)
     if not fleet.supervisor.wait_ready(timeout_s=60.0):
         snaps = [r.snapshot() for r in fleet.supervisor.replicas]
         fleet.close(drain_timeout_s=0.0)
@@ -412,7 +420,7 @@ def test_rolling_reload_breaker_aborts_rollout():
     not an emptied rotation)."""
     opts = make_opts(replicas=2, probe_period_s=30.0,  # probes dormant
                      reload_breaker_threshold=1, reload_timeout_s=2.0)
-    fleet = start_stub_fleet(opts)
+    fleet = start_stub_fleet(opts, probe_by_hand=True)
     try:
         # replica 0's process dies; the supervisor (probing every 30 s)
         # has not noticed, so the rollout hits it first and fails

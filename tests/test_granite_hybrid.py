@@ -1,39 +1,22 @@
 """The Granite-4.0-H style hybrid (ISSUE 29) on the CPU at small sizes:
 the chunked state-space scan and its gradient against the recurrence,
-the new layers and the whole 10-layer pattern against the benchmark's
-plain reference (``benchmarks/references/granite_hybrid.py``, which
-imports nothing of the program), and the packed-token iterator."""
+the layers' counters, and the packed-token iterator.  The whole 10-layer
+pattern against the benchmark's plain reference is a row of
+``tests/families.py``, run by ``tests/test_families.py``."""
 
 import functools
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cxxnet_tpu import config as cfgmod
+import families
 from cxxnet_tpu.io.data import create_iterator
 from cxxnet_tpu.models import granite_h_conf
-from cxxnet_tpu.models.builders import GRANITE_H_PERIOD
-from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.ops.attention import mha
 from cxxnet_tpu.ops.ssd import doc_index, ssd_recurrence, ssd_scan
 from cxxnet_tpu.utils.profiler import pipeline_stats
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "granite_hybrid_reference",
-        os.path.join(ROOT, "benchmarks", "references", "granite_hybrid.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
 
 @pytest.fixture(autouse=True)
 def _highest():
@@ -102,9 +85,9 @@ def test_no_state_crosses_a_document_start():
     ids = np.ones((1, t), np.float32)
     ids[0, 19] = 0
     doc = doc_index(jnp.asarray(ids))
-    y = ssd_scan(x, dt, a, b, c, doc, 16)
-    y2 = ssd_scan(x.at[:, :20].mul(3.0), dt, a, b.at[:, :20].add(1.0), c,
-                  doc, 16)
+    scan = _jitted(16)[0]
+    y = scan(x, dt, a, b, c, doc)
+    y2 = scan(x.at[:, :20].mul(3.0), dt, a, b.at[:, :20].add(1.0), c, doc)
     np.testing.assert_array_equal(np.asarray(y[:, 20:]),
                                   np.asarray(y2[:, 20:]))
     assert float(jnp.abs(y[:, :20] - y2[:, :20]).max()) > 1e-3
@@ -118,181 +101,78 @@ def test_doc_index_starts_a_document_after_every_separator():
 
 
 # ----------------------------------------------------------------------
-# the layers and the whole pattern against the plain reference
-SMALL = dict(vocab=64, hidden=64, mamba_heads=4, mamba_head_dim=32,
-             mamba_state=16, mamba_chunk=16, attn_heads=4, attn_kv_heads=2,
-             mlp_hidden=96, dev="cpu", eta=0.001, scan_steps=4)
+# the layers in the trainer: their counters, the precision the reference
+# is held at, the tied head (the whole pattern against the plain
+# reference: tests/test_families.py)
+def _mam(**more):
+    """The trainer of a mixer, the attention layer and a mixer with the
+    reference's weights from the seed in its place, and a 4-step chunk
+    with separators around a chunk's edge."""
+    text = granite_h_conf(**dict(families.GRANITE, layer_types="mam",
+                                 **more))
+    tr, net = families.with_reference_weights(text, "granite_h", 5, 2)
+    return tr, net, families.seeded_rows("granite_h", net, 3, 4)
 
 
-def _trainer(text, ref, seed, batch):
-    """The program's trainer with the reference's weights from the seed
-    in its place, as ``benchmarks/run.py`` puts them."""
-    net = ref.describe(text, batch)
-    tr = NetTrainer()
-    tr.set_params(cfgmod.split_sections(
-        cfgmod.parse_pairs(text)).global_entries)
-    tr.set_param("silent", "1")
-    tr.init_model()
-    made = ref.make_weights(net, seed)
-    new = {}
-    for key, tags in tr.params.items():
-        i = int(key[1:key.index("_")])
-        assert {t: tuple(v.shape) for t, v in tags.items()} == {
-            t: tuple(s) for t, s in net.pshapes[i].items()}, key
-        new[key] = {t: made[i][t] for t in tags}
-    tr.params = new
-    tr._place_state()
-    return tr, net
+@pytest.fixture(scope="module")
+def counted():
+    """``(trainer, counters a chunk added)`` once ``count_layer_state``
+    has read the layers' state."""
+    tr, net, (data, labels) = _mam()
+    stats = pipeline_stats()
+    before = dict(stats.counters())
+    tr.update_scan(data, labels, sync=True)
+    tr.count_layer_state()
+    return tr, {k: v - before.get(k, 0)
+                for k, v in stats.counters().items()}
 
 
-def _rows(ref, net, seed, scan):
-    data, labels = ref.seeded_chunk(net, seed, scan)
-    data[0, 0, 5] = data[0, 0, 15] = data[0, -1, 16] = 0  # around an edge
-    return data, labels
-
-
-def _gaps(tr, ref, net, seed, data, labels):
-    """(widest relative loss gap, widest leaf gap of the weights' change,
-    widest leaf gap of adam's first moment), element for element against
-    the largest element of the reference's leaf."""
-    losses = tr.update_scan(data, labels, sync=True)
-    start = jax.device_get(ref.make_weights(net, seed))
-    rl, rp, rm = ref.train_chunk(net, ref.make_weights(net, seed), data,
-                                 labels, None)
-    pp, pu = jax.device_get(tr.params), jax.device_get(tr.ustates)
-    dp = dm = 0.0
-    for key, tags in pp.items():
-        i = int(key[1:key.index("_")])
-        for t, v in tags.items():
-            want = rp[i][t] - start[i][t]
-            dp = max(dp, float(np.abs(v - start[i][t] - want).max()
-                               / np.abs(want).max()))
-            dm = max(dm, float(np.abs(pu[key][t]["m1"] - rm[i][t]).max()
-                               / np.abs(rm[i][t]).max()))
-    return float(np.abs(losses - rl).max() / np.abs(rl).max()), dp, dm
-
-
-@pytest.mark.parametrize("pattern", ["m", "a", GRANITE_H_PERIOD],
-                         ids=["mixer", "attention", "ten_layers"])
-def test_a_4_step_chunk_under_adam_matches_the_reference(ref, pattern):
-    """Loss, every weight and every first moment after a 4-step
-    ``update_scan``: the mixer alone, the attention layer alone (4 query
-    heads over 2 key/value heads, document mask, the multiplier), and
-    the whole pattern of nine mixers around one attention layer."""
-    text = granite_h_conf(seq_len=48, batch_size=2, layer_types=pattern,
-                          compute_dtype="float32", **SMALL)
-    tr, net = _trainer(text, ref, 5, 2)
-    data, labels = _rows(ref, net, 3, 4)
-    loss_gap, dp, dm = _gaps(tr, ref, net, 5, data, labels)
-    assert loss_gap < 1e-5 and dp < 2e-3 and dm < 2e-3, (loss_gap, dp, dm)
-
-
-def test_the_attention_layer_counts_its_tokens_into_the_round(ref):
+def test_the_attention_layer_counts_its_tokens_into_the_round(counted):
     """PR 37: the masked path's counters, summed into the round's
     once ``count_layer_state`` reads the layers' state — every token
     counted, none by the flash kernels off the TPU, and so none of their
     blocks (PR 43)."""
-    from cxxnet_tpu.utils.profiler import pipeline_stats
-
-    text = granite_h_conf(seq_len=48, batch_size=2, layer_types="mam",
-                          compute_dtype="float32", **SMALL)
-    tr, net = _trainer(text, ref, 5, 2)
+    tr, got = counted
     (key,) = [k for k, v in tr.aux.items() if "attn_tokens" in v]
     assert set(tr.aux[key]) == {"attn_tokens", "attn_tokens_flash",
                                 "attn_blocks", "attn_blocks_unmasked"}
-    stats = pipeline_stats()
-    before = dict(stats.counters())
-    data, labels = _rows(ref, net, 3, 4)
-    tr.update_scan(data, labels, sync=True)
-    tr.count_layer_state()
-    got = stats.counters()
     # 4 steps x 2 rows x 48 tokens, one attention layer
-    assert got["attn_tokens"] - before.get("attn_tokens", 0) == 4 * 2 * 48
+    assert got["attn_tokens"] == 4 * 2 * 48
     for name in ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked"):
-        assert got.get(name, 0) == before.get(name, 0)
+        assert got.get(name, 0) == 0
 
 
-def test_the_mixers_count_their_tokens_into_the_round(ref):
+def test_the_mixers_count_their_tokens_into_the_round(counted):
     """PR 41: the scan's two counters, summed over the mixers into the
     round's once ``count_layer_state`` reads the layers' state — every
     token counted, none by the fused kernels off the TPU (and at these
     widths on none)."""
-    from cxxnet_tpu.utils.profiler import pipeline_stats
-
-    text = granite_h_conf(seq_len=48, batch_size=2, layer_types="mam",
-                          compute_dtype="float32", **SMALL)
-    tr, net = _trainer(text, ref, 5, 2)
+    tr, got = counted
     keys = [k for k, v in tr.aux.items() if "scan_tokens" in v]
     assert len(keys) == 2
     for key in keys:
         assert set(tr.aux[key]) == {"scan_tokens", "scan_tokens_fused"}
-    stats = pipeline_stats()
-    before = dict(stats.counters())
-    data, labels = _rows(ref, net, 3, 4)
-    tr.update_scan(data, labels, sync=True)
-    tr.count_layer_state()
-    got = stats.counters()
     # 4 steps x 2 rows x 48 tokens, two mixers
-    assert got["ssd_scan_tokens"] - before.get("ssd_scan_tokens", 0) == (
-        2 * 4 * 2 * 48)
-    assert got.get("ssd_scan_tokens_fused", 0) == before.get(
-        "ssd_scan_tokens_fused", 0)
+    assert got["ssd_scan_tokens"] == 2 * 4 * 2 * 48
+    assert got.get("ssd_scan_tokens_fused", 0) == 0
 
 
-def test_a_bfloat16_run_fails_the_float32_tolerances(ref):
-    """So the tolerances above would catch a lower precision."""
-    text = granite_h_conf(seq_len=48, batch_size=2, layer_types="mam",
-                          compute_dtype="bfloat16", **SMALL)
-    tr, net = _trainer(text, ref, 5, 2)
-    data, labels = _rows(ref, net, 3, 4)
-    loss_gap, dp, dm = _gaps(tr, ref, net, 5, data, labels)
-    assert dp > 2e-2 and dm > 2e-3, (loss_gap, dp, dm)
+def test_a_bfloat16_run_fails_the_float32_tolerances():
+    """So the tolerances of the adam chunk against the reference
+    (``tests/test_families.py``) would catch a lower precision."""
+    tr, net, (data, labels) = _mam(compute_dtype="bfloat16")
+    gaps = families.chunk_gaps(tr, "granite_h", net, 5, data, labels)
+    assert gaps["dw"] > 2e-2 and gaps["dm"] > 2e-3, gaps
 
 
-def test_logits_and_every_gradient_leaf_match_the_reference(ref):
-    text = granite_h_conf(seq_len=32, batch_size=2, layer_types="mam",
-                          compute_dtype="float32", **SMALL)
-    tr, net = _trainer(text, ref, 9, 2)
-    data, labels = _rows(ref, net, 4, 1)
-    ids, lab = data[0], labels[0]
-    logits_node = tr.net.graph.node_index_of("logits")
-
-    @jax.jit
-    def program(p):
-        nodes, total = tr.net.forward(p, jnp.asarray(ids),
-                                      labels=jnp.asarray(lab), train=True)
-        return nodes[logits_node], total
-
-    probs, total = program(tr.params)
-    grads = jax.jit(jax.grad(lambda p: tr.net.loss_fn(
-        p, jnp.asarray(ids), jnp.asarray(lab))))(tr.params)
-    weights = ref.make_weights(net, 9)
-    loss = ref.loss_fn(net)
-    ii, ll = ids.astype(np.int32), lab.astype(np.int32)
-    want_loss, want = jax.jit(jax.value_and_grad(loss))(weights, ii, ll)
-    for key, tags in grads.items():
-        i = int(key[1:key.index("_")])
-        for t, g in tags.items():
-            assert float(jnp.abs(g - want[i][t]).max()) <= 2e-4 * float(
-                jnp.abs(want[i][t]).max()), (key, t)
-    # the softmax layer leaves probabilities in the logits' node, so the
-    # logits are held through them: the mean of -log p[label] is the
-    # reference's loss
-    np.testing.assert_allclose(float(total), float(want_loss), rtol=1e-6)
-    picked = np.take_along_axis(np.asarray(probs), ll[..., None], axis=-1)
-    np.testing.assert_allclose(-np.log(picked).mean(), float(want_loss),
-                               rtol=1e-5)
-    assert probs.shape == (2, 32, 64)
-
-
-def test_the_tied_head_has_one_parameter_and_one_gradient(ref):
-    text = granite_h_conf(seq_len=32, batch_size=2, layer_types="m",
-                          compute_dtype="float32", **SMALL)
-    tr, net = _trainer(text, ref, 2, 2)
+def test_the_tied_head_has_one_parameter_and_one_gradient():
+    text = granite_h_conf(**dict(families.GRANITE, seq_len=32,
+                                 layer_types="m"))
+    tr, net = families.with_reference_weights(text, "granite_h", 2, 2)
     heads = [k for k in tr.params if k.endswith("_head")]
     assert heads == [] and "l0_embed" in tr.params
     assert set(tr.params["l0_embed"]) == {"wmat"}
-    data, labels = _rows(ref, net, 1, 1)
+    data, labels = families.seeded_rows("granite_h", net, 1, 1)
     g = jax.jit(jax.grad(lambda p: tr.net.loss_fn(
         p, jnp.asarray(data[0]), jnp.asarray(labels[0]))))(tr.params)
     # one leaf, the sum of the head's and the lookup's gradients: a row
@@ -311,8 +191,9 @@ def test_attention_takes_grouped_heads_a_scale_and_documents():
     ids = np.ones((2, 24), np.float32)
     ids[0, 7] = ids[1, 12] = 0
     doc = doc_index(jnp.asarray(ids))
-    got = mha(q, k, v, causal=True, scale=0.25, doc=doc)
-    blocked = mha(q, k, v, causal=True, scale=0.25, doc=doc, block_q=8)
+    got, blocked = (jax.jit(lambda q, k, v, block=block: mha(
+        q, k, v, causal=True, scale=0.25, doc=doc, block_q=block))(q, k, v)
+        for block in (0, 8))
     np.testing.assert_allclose(got, blocked, atol=1e-6)
     # by hand, a head at a time
     kk, vv = np.repeat(k, 2, axis=2), np.repeat(v, 2, axis=2)
@@ -397,14 +278,9 @@ def test_token_iterator_keeps_the_shard_contract(tmp_path):
 
 def test_ids_reach_the_embedding_unrounded():
     """12543 is no bfloat16: the net keeps the ids' node in float32."""
-    text = granite_h_conf(seq_len=16, batch_size=1, layer_types="m",
-                          compute_dtype="bfloat16",
-                          **dict(SMALL, vocab=12544))
-    tr = NetTrainer()
-    tr.set_params(cfgmod.split_sections(
-        cfgmod.parse_pairs(text)).global_entries)
-    tr.set_param("silent", "1")
-    tr.init_model()
+    tr = families.trainer(granite_h_conf(**dict(
+        families.GRANITE, seq_len=16, batch_size=1, layer_types="m",
+        compute_dtype="bfloat16", vocab=12544)))
     ids = np.full((1, 16), 12543.0, np.float32)
     h0 = tr.net.graph.node_index_of("h0")
     first = jax.jit(lambda p: tr.net.forward(

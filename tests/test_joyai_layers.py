@@ -5,53 +5,26 @@ reference's; the interleaved rotary pairing against complex numbers;
 ``routed_experts`` with sigmoid scores, a selection bias and an ungated
 shared expert, and the sum of its shares against the uncut layer;
 ``softmax`` with ``target_shift``; ``token_shift``; the two-loss net
-with a shared embedding and head; the ``attn_pairs`` counter.
+with a shared embedding and head; the ``attn_pairs`` counter.  What
+every family's tests share (the builder's conf through the trainer, the
+published defaults, the whole net against the reference) is a row of
+``tests/families.py``.
 """
-
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cxxnet_tpu import config as cfgmod
+import families
 from cxxnet_tpu.io.tokens import attn_pairs
-from cxxnet_tpu.layers import create_layer
 from cxxnet_tpu.layers.moe import route
 from cxxnet_tpu.models import joyai_llm_flash_conf
-from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.ops.attention import rotary
-from cxxnet_tpu.utils.profiler import pipeline_stats
+from families import (expert_shares, held_against, make,
+                      rows_with_documents, strs, with_bias)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@pytest.fixture(scope="module")
-def ref():
-    """The configuration's plain reference: a file of the benchmark's
-    that imports nothing of the program."""
-    from benchmarks import run
-
-    return run.load_file(os.path.join(
-        ROOT, "benchmarks", "references", "joyai_llm_flash.py"), "reference")
-
-
-def make(kind, in_shapes, seed=0, **cfg):
-    lay = create_layer(kind)
-    for k, v in cfg.items():
-        lay.set_param(k, str(v))
-    out = lay.infer_shape(in_shapes)
-    return lay, lay.init_params(jax.random.PRNGKey(seed), in_shapes), out
-
-
-def rows_with_documents(seed, n, t, vocab=50):
-    """Ids with separators inside every row, none at its first token."""
-    r = np.random.RandomState(seed)
-    ids = r.randint(1, vocab, (n, t))
-    ids[:, t // 3] = 0
-    ids[0, t // 2 + 1] = 0
-    return ids.astype(np.float32)
+FAMILY = "joyai_llm_flash"
 
 
 # ----------------------------------------------------------------------
@@ -75,7 +48,7 @@ def test_latent_attention_is_the_reference_s(ref, interleave):
              kv_norm=jnp.asarray(1 + 0.1 * r.randn(16), jnp.float32))
     x = jnp.asarray(r.randn(2, 24, 20), jnp.float32)
     ids = jnp.asarray(rows_with_documents(2, 2, 24))
-    scfg = {k: str(v) for k, v in cfg.items()}
+    scfg = strs(cfg)
     int_ids = ids.astype(jnp.int32)
 
     def prog(q, a):
@@ -84,20 +57,12 @@ def test_latent_attention_is_the_reference_s(ref, interleave):
     def plain(q, a):
         return ref.latent_attention(q, a, int_ids, scfg)
 
+    y, _, _ = held_against(prog, plain, p, x, list(p), y_atol=2e-5)
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(prog(p, x), plain(p, x), atol=2e-5)
-        ga = jax.grad(lambda q, a: jnp.sum(jnp.sin(prog(q, a))),
-                      argnums=(0, 1))(p, x)
-        gb = jax.grad(lambda q, a: jnp.sum(jnp.sin(plain(q, a))),
-                      argnums=(0, 1))(p, x)
         # a token of the second document does not see the first
         cut = x.at[:, :8].set(0.0)
-        np.testing.assert_allclose(prog(p, cut)[:, 9:], prog(p, x)[:, 9:],
+        np.testing.assert_allclose(jax.jit(prog)(p, cut)[:, 9:], y[:, 9:],
                                    atol=1e-6)
-    np.testing.assert_allclose(ga[1], gb[1], atol=5e-5)
-    for tag in p:
-        np.testing.assert_allclose(ga[0][tag], gb[0][tag], atol=5e-5)
-        assert np.abs(np.asarray(ga[0][tag])).max() > 0
 
 
 def test_latent_attention_as_a_branch_and_without_ids(ref):
@@ -105,7 +70,7 @@ def test_latent_attention_as_a_branch_and_without_ids(ref):
                      residual_scale=1.0, eps=1e-6, **MLA)
     assert p["norm"].shape == (20,)
     x = jnp.asarray(np.random.RandomState(3).randn(1, 12, 20), jnp.float32)
-    scfg = {k: str(v) for k, v in dict(MLA, eps=1e-6).items()}
+    scfg = strs(dict(MLA, eps=1e-6))
     with jax.default_matmul_precision("highest"):
         got = lay.apply(p, [x])[0]
         want = x + ref.latent_attention(
@@ -189,11 +154,6 @@ MOE = dict(nexpert=32, topk=4, nhidden=10, shared_hidden=6, shared_gate=0,
            init_sigma=0.5)
 
 
-def with_bias(p, seed=6):
-    return dict(p, score_bias=jnp.asarray(
-        0.2 * np.random.RandomState(seed).randn(32), jnp.float32))
-
-
 def test_routed_experts_with_a_bias_is_the_reference_s(ref):
     lay, p, _ = make("routed_experts", [(2, 12, 8)], first_expert=8,
                      nheld=8, **MOE)
@@ -202,24 +162,20 @@ def test_routed_experts_with_a_bias_is_the_reference_s(ref):
         "shared_wmat": (12, 8), "shared_wproj": (8, 6),
         "score_bias": (32,)}
     assert float(jnp.abs(p["score_bias"]).max()) == 0.0
-    p = with_bias(p)
+    p = with_bias(p, 6)
     x = jnp.asarray(np.random.RandomState(7).randn(2, 12, 8), jnp.float32)
-    scfg = {k: str(v) for k, v in dict(MOE, first_expert=8, nheld=8).items()}
+    scfg = strs(dict(MOE, first_expert=8, nheld=8))
     with jax.default_matmul_precision("highest"):
-        (y,), state = lay.apply_stateful(
+        (y,), state = jax.jit(lay.apply_stateful)(
             p, lay.init_aux([(2, 12, 8)]), [x])
-        want = ref.routed_experts(p, x, scfg)
         _, idx = ref.router(p, x.reshape(-1, 8), scfg)
-        ga = jax.grad(lambda q, a: jnp.sum(jnp.sin(lay.apply(q, [a])[0])),
-                      argnums=(0, 1))(p, x)
-        gb = jax.grad(lambda q, a: jnp.sum(jnp.sin(
-            ref.routed_experts(q, a, scfg))), argnums=(0, 1))(p, x)
+    _, want, ga = held_against(
+        lambda q, a: lay.apply(q, [a])[0],
+        lambda q, a: ref.routed_experts(q, a, scfg), p, x,
+        ("wmat", "wproj", "shared_wmat", "shared_wproj"), y_atol=3e-5)
     np.testing.assert_allclose(y, want, atol=3e-5)
     held = (np.asarray(idx) >= 8) & (np.asarray(idx) < 16)
     assert int(state["pairs"]) == held.sum() > 0
-    np.testing.assert_allclose(ga[1], gb[1], atol=5e-5)
-    for tag in ("wmat", "wproj", "shared_wmat", "shared_wproj"):
-        np.testing.assert_allclose(ga[0][tag], gb[0][tag], atol=5e-5)
     # a share's router and the bias anywhere: no gradient
     assert np.abs(np.asarray(ga[0]["wgate"])).max() == 0
     assert np.abs(np.asarray(ga[0]["score_bias"])).max() == 0
@@ -231,24 +187,15 @@ def test_the_sixteen_shares_add_up_to_the_uncut_reference_layer(ref):
     its own experts' terms and the shared expert; the parts, the shared
     expert counted once, are what the uncut reference gives."""
     _, p, _ = make("routed_experts", [(2, 12, 8)], **MOE)
-    p = with_bias(p)
+    p = with_bias(p, 6)
     x = jnp.asarray(np.random.RandomState(8).randn(2, 12, 8), jnp.float32)
-    whole = {k: str(v) for k, v in MOE.items()}
+    whole = strs(MOE)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(ref.routed_experts(p, x, whole), np.float64)
         none = dict(p, wmat=p["wmat"][:1] * 0, wproj=p["wproj"][:1] * 0)
         shared = np.asarray(ref.routed_experts(
             none, x, dict(whole, nheld="1")), np.float64)
-        parts, pairs = [], 0
-        for rank in range(16):
-            lay, _, _ = make("routed_experts", [(2, 12, 8)],
-                             first_expert=2 * rank, nheld=2, **MOE)
-            mine = dict(p, wmat=p["wmat"][2 * rank:2 * rank + 2],
-                        wproj=p["wproj"][2 * rank:2 * rank + 2])
-            (y,), st = lay.apply_stateful(
-                mine, lay.init_aux([(2, 12, 8)]), [x])
-            parts.append(np.asarray(y, np.float64))
-            pairs += int(st["pairs"])
+        parts, pairs = expert_shares(MOE, p, x, 16, 2)
     assert pairs == 24 * 4               # every pair on exactly one rank
     np.testing.assert_allclose(sum(parts) - 15 * shared, want, atol=5e-5)
     assert np.abs(shared).max() > 0.01 and np.abs(want - shared).max() > 0.01
@@ -291,51 +238,36 @@ def test_softmax_with_a_target_moved_on_and_the_token_shift():
 
 
 # ----------------------------------------------------------------------
-TINY = dict(vocab=64, seq_len=64, hidden=32, num_layers=2, attn_heads=4,
-            q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
-            qk_rope_head_dim=4, v_head_dim=8, mlp_hidden=48, num_experts=16,
-            experts_per_tok=3, expert_hidden=24, shared_hidden=24,
-            experts_held=4, dev="cpu", compute_dtype="float32",
-            scan_steps=4)
-
-
-def trainer(text):
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(text))
-    tr.set_param("silent", "1")
-    tr.init_model()
-    return tr
-
-
 def test_two_losses_share_the_embedding_and_the_head(ref):
     """One leaf each, and its gradient the sum of the main path's and
-    the module's; the whole net's gradient is the reference's."""
-    text = joyai_llm_flash_conf(**TINY)
+    the module's (the whole net's gradient against the reference's:
+    ``tests/test_families.py``)."""
+    text = joyai_llm_flash_conf(**families.JOYAI)
     assert text.count("= shared[embed]") == text.count("= shared[head]") == 1
     assert text.index("= softmax") < text.index("token_shift:mtp_shift")
     assert "target_shift = 1" in text and text.rstrip().count("mtp_") > 10
-    tr = trainer(text)
+    tr = families.trainer(text)
     assert [k for k in tr.params if "embed" in k or "head" in k] == [
         "l0_embed", "l6_head"]
     net = ref.describe(text, 1)
-    made = ref.make_weights(net, 5)
-    params = {k: {t: made[int(k[1:k.index("_")])][t] for t in tags}
-              for k, tags in tr.params.items()}
+    params = families.in_program_s_keys(tr, ref.make_weights(net, 5))
     ids = rows_with_documents(10, 1, 64, vocab=64)
     lab = np.roll(ids, -1, axis=1)
 
-    def grads(conf_text):
-        t = trainer(conf_text)
+    def grads(t):
+        """Of the net of trainer ``t``: the three confs differ in two
+        loss weights and share the parameters' tree."""
         with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(lambda q: t.net.loss_fn(
-                q, jnp.asarray(ids), jnp.asarray(lab)))(params)
+            return jax.jit(jax.value_and_grad(lambda q: t.net.loss_fn(
+                q, jnp.asarray(ids), jnp.asarray(lab))))(params)
 
-    whole_l, whole = grads(text)
-    main_l, main = grads(joyai_llm_flash_conf(**dict(TINY,
-                                                     mtp_loss_weight=0.0)))
+    whole_l, whole = grads(tr)
+    main_l, main = grads(families.trainer(joyai_llm_flash_conf(
+        **dict(families.JOYAI, mtp_loss_weight=0.0)), init=False))
     scale = f"grad_scale = {1.0 / 64!r}"
     assert text.count(scale) == 1
-    mtp_l, mtp = grads(text.replace(scale, "grad_scale = 0.0"))
+    mtp_l, mtp = grads(families.trainer(
+        text.replace(scale, "grad_scale = 0.0"), init=False))
     np.testing.assert_allclose(whole_l, main_l + mtp_l, rtol=1e-6)
     assert 0.2 * main_l < mtp_l < 0.4 * main_l      # 0.3 x a like loss
     for key in ("l0_embed", "l6_head"):
@@ -344,82 +276,6 @@ def test_two_losses_share_the_embedding_and_the_head(ref):
         np.testing.assert_allclose(whole[key]["wmat"], a + b, atol=1e-7)
     # the module's own layers get nothing from the main loss
     assert np.abs(np.asarray(main["l13_mtp_eh_proj"]["wmat"])).max() == 0
-    with jax.default_matmul_precision("highest"):
-        ref_l, ref_g = jax.value_and_grad(ref.loss_fn(net))(
-            made, jnp.asarray(ids, jnp.int32), jnp.asarray(lab, jnp.int32))
-    np.testing.assert_allclose(whole_l, ref_l, rtol=1e-6)
-    for key, tags in whole.items():
-        for tag, g in tags.items():
-            np.testing.assert_allclose(
-                g, ref_g[int(key[1:key.index("_")])][tag], atol=2e-6,
-                err_msg=f"{key}.{tag}")
-
-
-def test_the_builder_s_conf_trains_and_counts_its_pairs():
-    text = joyai_llm_flash_conf(**TINY)
-    assert text.count("= latent_attention:") == 3
-    assert text.count("= routed_experts:") == 2
-    assert text.count("= gated_mlp:") == 1 and "tied" not in text
-    assert "rope_theta = 32000000.0" in text and "routed_scale = 2.5" in text
-    assert "wgate" not in text and ":lr" not in text
-    tr = trainer(text)
-    assert set(tr.aux) == {"l4_moe1", "l15_mtp_moe",
-                           "l1_mla0", "l3_mla1", "l14_mtp_mla"}
-    r = np.random.RandomState(0)
-    ids = r.randint(0, 64, (4, 1, 64)).astype(np.float32)
-    router = np.asarray(tr.params["l4_moe1"]["wgate"]).copy()
-    expert = np.asarray(tr.params["l4_moe1"]["wmat"]).copy()
-    tr.params["l4_moe1"]["score_bias"] = jnp.asarray(
-        0.05 * r.randn(16), jnp.float32)
-    bias = np.asarray(tr.params["l4_moe1"]["score_bias"]).copy()
-    first = tr.update_scan(ids, np.roll(ids, -1, axis=2))
-    again = tr.update_scan(ids, np.roll(ids, -1, axis=2))
-    assert np.isfinite(first).all() and again.mean() < first.mean()
-    # in a share neither the router nor its bias moves under adam
-    assert np.array_equal(np.asarray(tr.params["l4_moe1"]["wgate"]), router)
-    assert np.array_equal(np.asarray(tr.params["l4_moe1"]["score_bias"]),
-                          bias)
-    assert not np.array_equal(np.asarray(tr.params["l4_moe1"]["wmat"]),
-                              expert)
-    stats = pipeline_stats()
-    before = stats.counters().get("expert_pairs", 0)
-    tokens = stats.counters().get("attn_tokens", 0)
-    flash = {n: stats.counters().get(n, 0) for n in (
-        "attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked")}
-    tr.count_layer_state()
-    pairs = stats.counters()["expert_pairs"] - before
-    # 8 steps x 64 tokens x 3 picks x 2 layers, a quarter of them held
-    assert 0.5 * 768 < pairs < 1.5 * 768
-    # the latent layers count their tokens, and none by the kernels off
-    # the TPU: 8 steps x 64 tokens x 3 layers
-    assert stats.counters()["attn_tokens"] - tokens == 8 * 64 * 3
-    # ... so no block of theirs either
-    assert {n: stats.counters().get(n, 0) for n in flash} == flash
-    # without the module: the main model alone
-    bare = joyai_llm_flash_conf(**dict(TINY, num_nextn_predict_layers=0))
-    assert "mtp_" not in bare and bare.count("= softmax") == 1
-    with pytest.raises(ValueError, match="depth of 0 or 1"):
-        joyai_llm_flash_conf(num_nextn_predict_layers=2)
-
-
-def test_the_published_defaults_are_what_the_issue_reckoned():
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(joyai_llm_flash_conf(dev="cpu")))
-    tr._build_net()
-    shapes = jax.eval_shape(
-        lambda k: tr.net.init_params(k, 1), jax.random.PRNGKey(0))
-    count = lambda key: sum(  # noqa: E731
-        int(np.prod(v.shape)) for v in shapes[key].values())
-    assert count("l1_mla0") == 26_347_520 + 2048      # the mixer and its norm
-    assert count("l2_mlp0") == 3 * 2048 * 7168 + 2048
-    # router + bias, 16 held experts, the shared one, the norm
-    assert count("l4_moe1") == (256 * 2048 + 256 + 17 * 3 * 2048 * 768
-                                + 2048)
-    assert count("l19_mtp_eh_proj") == 4096 * 2048
-    assert count("l0_embed") == count("l12_head") == 16160 * 2048
-    total = sum(count(k) for k in shapes)
-    assert total == 680_441_088                        # x 16 B = 10.89 GB
-    assert round(total * 16 / 1e9, 2) == 10.89
 
 
 # ----------------------------------------------------------------------

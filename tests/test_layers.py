@@ -236,8 +236,9 @@ def test_conv_s2d_matches_plain_strided(rng, hw, k, s, p, cin):
     def loss(lay, pr, v):
         return (lay.apply(pr, [v])[0] ** 2).sum()
 
-    ga = jax.grad(loss, argnums=(1, 2))(base, params, jnp.asarray(x))
-    gb = jax.grad(loss, argnums=(1, 2))(s2d, params, jnp.asarray(x))
+    ga, gb = (jax.jit(jax.grad(lambda pr, v, lay=lay: loss(lay, pr, v),
+                               argnums=(0, 1)))(params, jnp.asarray(x))
+              for lay in (base, s2d))
     for a, b in zip(jax.tree_util.tree_leaves(ga),
                     jax.tree_util.tree_leaves(gb)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -551,8 +552,9 @@ def test_conv_winograd_matches_direct(rng, hw, p, cin, cout, variant):
     def loss(lay, pr, v):
         return (lay.apply(pr, [v])[0] ** 2).sum()
 
-    ga = jax.grad(loss, argnums=(1, 2))(base, params, jnp.asarray(x))
-    gb = jax.grad(loss, argnums=(1, 2))(wino, params, jnp.asarray(x))
+    ga, gb = (jax.jit(jax.grad(lambda pr, v, lay=lay: loss(lay, pr, v),
+                               argnums=(0, 1)))(params, jnp.asarray(x))
+              for lay in (base, wino))
     for a, b in zip(jax.tree_util.tree_leaves(ga),
                     jax.tree_util.tree_leaves(gb)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -8,8 +8,10 @@ GoogLeNet, the BASELINE.json benchmark model.
 import numpy as np
 import pytest
 
+from conftest import build_from_shapes
 from cxxnet_tpu import config as cfgmod
 from cxxnet_tpu.models import MODEL_BUILDERS
+from families import FAMILIES
 from cxxnet_tpu.nnet.trainer import NetTrainer
 
 
@@ -19,53 +21,26 @@ def _global_cfg(conf_text: str):
     return cfgmod.split_sections(cfgmod.parse_pairs(conf_text)).global_entries
 
 
+def _shaped(text):
+    """The conf's trainer with its net built, and its parameters' shapes."""
+    tr = NetTrainer()
+    tr.set_params(_global_cfg(text))
+    return tr, build_from_shapes(tr)
+
+
 @pytest.mark.parametrize("name", sorted(MODEL_BUILDERS))
 def test_model_shapes(name):
     """Parse + init at tiny batch; checks graph wiring and shape rules."""
     builder = MODEL_BUILDERS[name]
-    if name == "granite_h":  # the defaults are the published widths
-        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
-                       hidden=32, mamba_heads=4, mamba_head_dim=16,
-                       mamba_state=8, mamba_chunk=8, attn_heads=4,
-                       attn_kv_heads=2, mlp_hidden=48, layer_types="mam")
-    elif name == "qwen3_next":  # the defaults are the published widths
-        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
-                       hidden=32, layer_types="lf", linear_key_heads=2,
-                       linear_value_heads=4, linear_key_dim=8,
-                       linear_value_dim=8, linear_chunk=8, attn_heads=4,
-                       attn_kv_heads=2, head_dim=16, num_experts=8,
-                       experts_per_tok=2, expert_hidden=16, shared_hidden=16,
-                       experts_held=4)
-    elif name == "joyai_llm_flash":  # the defaults are the published widths
-        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
-                       hidden=32, num_layers=2, attn_heads=4, q_lora_rank=24,
-                       kv_lora_rank=16, qk_nope_head_dim=8,
-                       qk_rope_head_dim=4, v_head_dim=8, mlp_hidden=48,
-                       num_experts=8, experts_per_tok=2, expert_hidden=16,
-                       shared_hidden=16, experts_held=4)
-    elif name == "nemotron_h":  # the defaults are the published widths
-        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
-                       hidden=32, pattern="ME*E", mamba_heads=4,
-                       mamba_head_dim=8, mamba_groups=2, mamba_state=8,
-                       mamba_chunk=8, attn_heads=4, attn_kv_heads=2,
-                       head_dim=16, num_experts=8, experts_per_tok=3,
-                       expert_hidden=16, latent_hidden=16, shared_hidden=24,
-                       experts_held=4, num_nextn_predict_layers=1)
-    elif name == "afmoe":  # the defaults are the published widths
-        text = builder(batch_size=4, dev="cpu", vocab=64, seq_len=32,
-                       hidden=32, layer_types="sf", num_dense_layers=1,
-                       sliding_window=8, attn_heads=4, attn_kv_heads=2,
-                       head_dim=16, mlp_hidden=48, num_experts=8,
-                       experts_per_tok=2, expert_hidden=16, shared_hidden=16,
-                       experts_held=4)
+    if name in FAMILIES:  # the defaults are the published widths
+        text = builder(**dict(FAMILIES[name].tiny, batch_size=4))
     elif name.startswith("mnist") or name in ("kaggle_bowl",
                                               "transformer_lm"):
         text = builder(batch_size=4, dev="cpu")
     else:
         text = builder(batch_size=4, dev="cpu", nsample=8)
-    tr = NetTrainer()
-    tr.set_params(_global_cfg(text))
-    tr.init_model()
+    tr, params = _shaped(text)
+    assert params
     shapes = tr.net.node_shapes
     assert all(s is not None for s in shapes)
     # output layer is softmax over the right class count
@@ -92,9 +67,7 @@ def test_resnet50_structure():
     256/512/1024/2048, spatial 56/28/14/7 at 224px, ~25.5M params."""
     text = MODEL_BUILDERS["resnet50"](batch_size=2, dev="cpu", nsample=4,
                                       input_size=224)
-    tr = NetTrainer()
-    tr.set_params(_global_cfg(text))
-    tr.init_model()
+    tr, params = _shaped(text)
     g = tr.graph
     shapes = {g.node_names[i]: s for i, s in enumerate(tr.net.node_shapes)
               if s is not None and i < len(g.node_names)}
@@ -103,8 +76,8 @@ def test_resnet50_structure():
     assert shapes["s2b5"][1:] == (14, 14, 1024)
     assert shapes["s3b2"][1:] == (7, 7, 2048)
     total = sum(
-        int(np.prod(np.shape(w)))
-        for tags in tr.params.values() for w in tags.values()
+        int(np.prod(w.shape))
+        for tags in params.values() for w in tags.values()
     )
     assert 25e6 < total < 26e6, f"param count {total/1e6:.1f}M"
 
@@ -112,9 +85,7 @@ def test_resnet50_structure():
 def test_googlenet_channel_plan():
     """Inception concat widths match Szegedy et al. table 1."""
     text = MODEL_BUILDERS["googlenet"](batch_size=2, dev="cpu", nsample=4)
-    tr = NetTrainer()
-    tr.set_params(_global_cfg(text))
-    tr.init_model()
+    tr, _ = _shaped(text)
     g = tr.graph
     shapes = tr.net.node_shapes
     want = {"i3a": 256, "i3b": 480, "i4a": 512, "i4b": 512, "i4c": 512,

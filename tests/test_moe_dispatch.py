@@ -17,10 +17,8 @@ import numpy as np
 import pytest
 from jax import lax
 
-from cxxnet_tpu import config as cfgmod
-from cxxnet_tpu.layers import create_layer, moe
-from cxxnet_tpu.models import joyai_llm_flash_conf, qwen3_next_conf
-from cxxnet_tpu.nnet.trainer import NetTrainer
+import families
+from cxxnet_tpu.layers import moe
 from cxxnet_tpu.utils.profiler import pipeline_stats
 
 # 512 tokens pick 4 of 64, 4 held: 2048 pairs, 128 to the share of an
@@ -64,11 +62,7 @@ def dense_loop(x, w, idx, wmat, wproj, first):
 
 
 def make(in_shape, **cfg):
-    lay = create_layer("routed_experts")
-    for k, v in cfg.items():
-        lay.set_param(k, str(v))
-    lay.infer_shape([in_shape])
-    return lay, lay.init_params(jax.random.PRNGKey(0), [in_shape])
+    return families.make("routed_experts", [in_shape], **cfg)[:2]
 
 
 def picks(rng, held: int, empty=()):
@@ -86,7 +80,7 @@ def picks(rng, held: int, empty=()):
 def grads_of(fn, x, p):
     loss = lambda x, wmat, wproj: jnp.sum(jnp.sin(  # noqa: E731
         fn(x, wmat, wproj)))
-    return jax.value_and_grad(loss, argnums=(0, 1, 2))(
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
         x, p["wmat"], p["wproj"])
 
 
@@ -124,18 +118,20 @@ def test_a_share_computes_every_held_pair_whatever_the_router_sends(
     monkeypatch.setattr(moe, "route", lambda *a, **kw: (w, idx))
     x = jnp.asarray(rng.randn(M, D), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        (y,), state = lay.apply_stateful(p, lay.init_aux([(M, D)]), [x])
+        (y,), state = jax.jit(lay.apply_stateful)(
+            p, lay.init_aux([(M, D)]), [x])
         got = grads_of(lambda x, a, b: lay.apply(
             dict(p, wmat=a, wproj=b), [x])[0], x, p)
         dense = grads_of(lambda x, a, b: dense_loop(
             x, w, idx, a, b, FIRST), x, p)
         parent = grads_of(lambda x, a, b: parent_held_experts(
             x, w, idx, a, b, FIRST)[0], x, p)
-        want_y, counts = parent_held_experts(x, w, idx, p["wmat"],
-                                             p["wproj"], FIRST)
+        want_y, counts = jax.jit(parent_held_experts, static_argnums=5)(
+            x, w, idx, p["wmat"], p["wproj"], FIRST)
+        dense_y = jax.jit(dense_loop, static_argnums=5)(
+            x, w, idx, p["wmat"], p["wproj"], FIRST)
     np.testing.assert_allclose(y, want_y, atol=2e-5)
-    np.testing.assert_allclose(y, dense_loop(x, w, idx, p["wmat"],
-                                             p["wproj"], FIRST), atol=2e-5)
+    np.testing.assert_allclose(y, dense_y, atol=2e-5)
     assert_same(got, dense, 1e-4)
     assert_same(got, parent, 1e-4)
     for j in empty:                      # no token, no gradient
@@ -175,9 +171,9 @@ def test_a_whole_layer_is_one_slab_with_no_loop_and_differentiates_w():
     idx = jnp.asarray(np.argsort(rng.rand(m, e), axis=-1)[:, :k], jnp.int32)
 
     def grads(fn):
-        return jax.value_and_grad(
+        return jax.jit(jax.value_and_grad(
             lambda x, w, a, b: jnp.sum(jnp.sin(fn(x, w, a, b))),
-            argnums=(0, 1, 2, 3))(x, w, p["wmat"], p["wproj"])
+            argnums=(0, 1, 2, 3)))(x, w, p["wmat"], p["wproj"])
 
     with jax.default_matmul_precision("highest"):
         got = grads(lambda x, w, a, b: moe.held_experts(
@@ -185,7 +181,8 @@ def test_a_whole_layer_is_one_slab_with_no_loop_and_differentiates_w():
         dense = grads(lambda x, w, a, b: dense_loop(x, w, idx, a, b, 0))
         parent = grads(lambda x, w, a, b: parent_held_experts(
             x, w, idx, a, b, 0)[0])
-        (_,), state = lay.apply_stateful(p, lay.init_aux([(m, D)]), [x])
+        (_,), state = jax.jit(lay.apply_stateful)(
+            p, lay.init_aux([(m, D)]), [x])
     assert np.abs(np.asarray(got[1][1])).max() > 0
     assert_same(got, dense, 1e-4)
     assert_same(got, parent, 1e-4)
@@ -216,7 +213,8 @@ def test_a_router_that_favours_the_held_experts_fills_further_slabs(cfg):
         w, idx = moe.route(logits, K, True, score_func=cfg["score_func"],
                            bias=p.get("score_bias"),
                            scale=cfg.get("routed_scale", 1.0))
-        (y,), state = lay.apply_stateful(p, lay.init_aux([(M, D)]), [x])
+        (y,), state = jax.jit(lay.apply_stateful)(
+            p, lay.init_aux([(M, D)]), [x])
         got = grads_of(lambda x, a, b: lay.apply(
             dict(p, wmat=a, wproj=b), [x])[0], x, p)
         # a share's routing weights are constants of the backward pass
@@ -224,36 +222,22 @@ def test_a_router_that_favours_the_held_experts_fills_further_slabs(cfg):
             x, w, idx, a, b, FIRST), x, p)
     held = int(((idx >= FIRST) & (idx < FIRST + G)).sum())
     assert held > C + C // 2             # at least two slabs
-    np.testing.assert_allclose(y, dense_loop(x, w, idx, p["wmat"],
-                                             p["wproj"], FIRST), atol=5e-5)
+    with jax.default_matmul_precision("highest"):
+        dense_y = jax.jit(dense_loop, static_argnums=5)(
+            x, w, idx, p["wmat"], p["wproj"], FIRST)
+    np.testing.assert_allclose(y, dense_y, atol=5e-5)
     assert_same(got, dense, 2e-4)
     assert int(state["pairs"]) == held
     assert int(state["pairs_overflow"]) == held - C
     assert int(state["pairs_dropped"]) == 0
 
 
-TINY = dict(vocab=64, seq_len=64, hidden=32, attn_heads=4, num_experts=16,
-            experts_per_tok=3, expert_hidden=24, shared_hidden=24,
-            experts_held=4, dev="cpu", compute_dtype="float32", scan_steps=4)
-CONFS = {
-    "qwen3_next": lambda: qwen3_next_conf(
-        layer_types="lf", linear_key_heads=2, linear_value_heads=4,
-        linear_key_dim=8, linear_value_dim=8, linear_chunk=16,
-        attn_kv_heads=2, head_dim=16, **TINY),
-    "joyai_llm_flash": lambda: joyai_llm_flash_conf(
-        num_layers=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
-        qk_rope_head_dim=4, v_head_dim=8, mlp_hidden=48, **TINY),
-}
-
-
-@pytest.mark.parametrize("family", sorted(CONFS))
+@pytest.mark.parametrize("family", ["joyai_llm_flash", "qwen3_next"])
 def test_the_builders_confs_train_a_round_and_count_no_overflow(family):
-    text = CONFS[family]()
+    f = families.FAMILIES[family]
+    text = f.builder(**f.tiny)
     assert text.count("= routed_experts:") == 2
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(text))
-    tr.set_param("silent", "1")
-    tr.init_model()
+    tr = families.trainer(text)
     ids = np.random.RandomState(0).randint(0, 64, (4, 1, 64)).astype(
         np.float32)
     stats = pipeline_stats()

@@ -6,7 +6,9 @@ latent behind two projections; for each of the three kinds of layer,
 the sum of the shares a tensor- and expert-parallel deployment's ranks
 hold against the uncut reference layer; the programs of the accepted
 cells' layers (one group, gated experts in the stream's width) held to
-what they were before; the builder's conf through the trainer.
+what they were before; the shipped example and the CLI.  What every
+family's tests share (the builder's conf through the trainer, the
+published defaults) is a row of ``tests/families.py``.
 """
 
 import hashlib
@@ -18,53 +20,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cxxnet_tpu import config as cfgmod
+import families
 from cxxnet_tpu.layers import create_layer
 from cxxnet_tpu.models import nemotron_h_conf
-from cxxnet_tpu.models.builders import NEMOTRON_H_STAGE
-from cxxnet_tpu.nnet.trainer import NetTrainer
 from cxxnet_tpu.ops.ssd import doc_index, ssd_recurrence, ssd_scan
-from cxxnet_tpu.utils.profiler import pipeline_stats
+from families import (held_against, make, rows_with_documents, strs,
+                      with_bias)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.fixture(scope="module")
-def ref():
-    """The configuration's plain reference: a file of the benchmark's
-    that imports nothing of the program."""
-    from benchmarks import run
-
-    return run.load_file(os.path.join(
-        ROOT, "benchmarks", "references", "nemotron_h.py"), "reference")
-
-
-def make(kind, in_shapes, seed=0, **cfg):
-    lay = create_layer(kind)
-    for k, v in cfg.items():
-        lay.set_param(k, str(v))
-    out = lay.infer_shape(in_shapes)
-    return lay, lay.init_params(jax.random.PRNGKey(seed), in_shapes), out
-
-
-def strs(cfg):
-    return {k: str(v) for k, v in cfg.items()}
-
-
-def rows_with_documents(seed, n, t, vocab=50):
-    """Ids with separators inside every row, none at its first token."""
-    r = np.random.RandomState(seed)
-    ids = r.randint(1, vocab, (n, t))
-    ids[:, t // 3] = 0
-    ids[0, t // 2 + 1] = 0
-    return ids.astype(np.float32)
-
-
-def grads_agree(ga, gb, tags, atol=5e-5):
-    np.testing.assert_allclose(ga[1], gb[1], atol=atol)
-    for tag in tags:
-        np.testing.assert_allclose(ga[0][tag], gb[0][tag], atol=atol,
-                                   err_msg=tag)
+FAMILY = "nemotron_h"
 
 
 # ----------------------------------------------------------------------
@@ -83,23 +49,22 @@ def test_the_grouped_scan_is_the_recurrence_a_head_reading_its_group(chunk):
     c = jnp.asarray(r.randn(n, t, g, s), jnp.float32)
     doc = doc_index(jnp.asarray(rows_with_documents(2, n, t)))
 
-    def loss(fn, *v):
-        return jnp.sum(jnp.sin(fn(*v)))
+    def both(fn):
+        """Value and all five gradients, compiled once."""
+        return jax.jit(lambda *v: (fn(*v), jax.grad(
+            lambda *v: jnp.sum(jnp.sin(fn(*v))), argnums=range(5))(*v)))
 
     chunked = lambda *v: ssd_scan(*v, doc, chunk)  # noqa: E731
     stepwise = lambda *v: ssd_recurrence(*v, doc)  # noqa: E731
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(chunked(x, dt, a, b, c),
-                                   stepwise(x, dt, a, b, c), atol=2e-5)
-        ga = jax.grad(lambda *v: loss(chunked, *v), argnums=range(5))(
-            x, dt, a, b, c)
-        gb = jax.grad(lambda *v: loss(stepwise, *v), argnums=range(5))(
-            x, dt, a, b, c)
+        y, ga = both(chunked)(x, dt, a, b, c)
+        want, gb = both(stepwise)(x, dt, a, b, c)
         # head 2 of 6 in 3 groups reads group 1, and no other
-        moved = chunked(x, dt, a, b.at[:, :, 1].add(1.0), c)
+        moved, _ = both(chunked)(x, dt, a, b.at[:, :, 1].add(1.0), c)
+    np.testing.assert_allclose(y, want, atol=2e-5)
     for got, want in zip(ga, gb):
         np.testing.assert_allclose(got, want, atol=1e-4)
-    same = np.isclose(np.asarray(moved), np.asarray(chunked(x, dt, a, b, c)),
+    same = np.isclose(np.asarray(moved), np.asarray(y),
                       atol=1e-6).all(axis=(0, 1, 3))
     assert list(same) == [True, True, False, False, True, True]
 
@@ -119,15 +84,9 @@ def test_mamba2_with_groups_is_the_reference_s(ref):
     x = jnp.asarray(r.randn(2, 24, 10), jnp.float32)
     ids = jnp.asarray(rows_with_documents(4, 2, 24))
     cfg = strs(MIX)
-    with jax.default_matmul_precision("highest"):
-        (y,) = lay.apply(p, [x, ids])
-        want = ref.mamba2(p, x, ids.astype(jnp.int32), cfg)
-        ga = jax.grad(lambda q, a: jnp.sum(jnp.sin(
-            lay.apply(q, [a, ids])[0])), argnums=(0, 1))(p, x)
-        gb = jax.grad(lambda q, a: jnp.sum(jnp.sin(ref.mamba2(
-            q, a, ids.astype(jnp.int32), cfg))), argnums=(0, 1))(p, x)
-    np.testing.assert_allclose(y, want, atol=3e-5)
-    grads_agree(ga, gb, p)
+    held_against(lambda q, a: lay.apply(q, [a, ids])[0],
+                 lambda q, a: ref.mamba2(q, a, ids.astype(jnp.int32), cfg),
+                 p, x, p, y_atol=3e-5)
     with pytest.raises(ValueError, match="ngroup"):
         make("mamba2", shapes, **dict(MIX, ngroup=3))
 
@@ -136,12 +95,6 @@ def test_mamba2_with_groups_is_the_reference_s(ref):
 LAT = dict(nexpert=16, topk=3, nhidden=10, latent_hidden=6, shared_hidden=12,
            expert_act="relu2", shared_gate=0, score_func="sigmoid",
            select_bias=1, routed_scale=5.0, init_sigma=0.3)
-
-
-def with_bias(p, seed=5, sigma=0.05):
-    return dict(p, score_bias=jnp.asarray(
-        np.random.RandomState(seed).randn(*p["score_bias"].shape) * sigma,
-        jnp.float32))
 
 
 @pytest.mark.parametrize("first,held", [(0, 16), (4, 4)])
@@ -153,25 +106,25 @@ def test_latent_relu2_experts_are_the_reference_s(ref, first, held):
         "wgate": (16, 8), "wmat": (held, 6, 10), "wproj": (held, 10, 6),
         "shared_wmat": (12, 8), "shared_wproj": (8, 12),
         "score_bias": (16,), "latent_in": (6, 8), "latent_out": (8, 6)}
-    p = with_bias(p)
+    p = with_bias(p, 5, 0.05)
     x = jnp.asarray(np.random.RandomState(7).randn(2, 12, 8), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        (y,), state = lay.apply_stateful(p, lay.init_aux(shapes), [x])
-        want = ref.routed_experts(p, x, strs(cfg))
+        (y,), state = jax.jit(lay.apply_stateful)(p, lay.init_aux(shapes),
+                                                  [x])
         _, idx = ref.router(p, x.reshape(-1, 8), strs(cfg))
-        ga = jax.grad(lambda q, a: jnp.sum(jnp.sin(lay.apply(q, [a])[0])),
-                      argnums=(0, 1))(p, x)
-        gb = jax.grad(lambda q, a: jnp.sum(jnp.sin(
-            ref.routed_experts(q, a, strs(cfg)))), argnums=(0, 1))(p, x)
+    # a share's router gets no gradient; a whole layer's does
+    tags = ["wmat", "wproj", "shared_wmat", "shared_wproj", "latent_in",
+            "latent_out"]
+    still = ["score_bias"]
+    (tags if held == 16 else still).append("wgate")
+    _, want, _ = held_against(
+        lambda q, a: lay.apply(q, [a])[0],
+        lambda q, a: ref.routed_experts(q, a, strs(cfg)), p, x, tags,
+        y_atol=3e-5, zero=still)
     np.testing.assert_allclose(y, want, atol=3e-5)
     idx = np.asarray(idx)
     assert int(state["pairs"]) == (
         (idx >= first) & (idx < first + held)).sum() > 0
-    grads_agree(ga, gb, ("wmat", "wproj", "shared_wmat", "shared_wproj",
-                         "latent_in", "latent_out", "wgate"))
-    # a share's router gets no gradient; a whole layer's does
-    assert (np.abs(np.asarray(ga[0]["wgate"])).max() > 0) == (held == 16)
-    assert np.abs(np.asarray(ga[0]["score_bias"])).max() == 0
 
 
 def test_an_expert_s_activation_and_its_latent_are_two_keys():
@@ -285,7 +238,7 @@ def test_the_ranks_shares_add_up_to_the_uncut_reference_layer(ref, kind):
     _, p, _ = make(name, shapes, seed=13, **whole)
     p = dict(p, norm=jnp.asarray(1 + 0.2 * r.randn(16), jnp.float32))
     if kind[0] == "E":
-        p = with_bias(p)
+        p = with_bias(p, 5, 0.05)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(fn(p), np.float64)
         parts = []
@@ -480,92 +433,6 @@ def test_the_accepted_cells_layers_give_the_numbers_they_gave(case):
 
 
 # ----------------------------------------------------------------------
-TOY = dict(vocab=64, seq_len=32, hidden=32, pattern="ME*E", mamba_heads=4,
-           mamba_head_dim=8, mamba_groups=2, mamba_state=8, mamba_chunk=8,
-           attn_heads=4, attn_kv_heads=2, head_dim=16, num_experts=8,
-           experts_per_tok=3, expert_hidden=16, latent_hidden=16,
-           shared_hidden=24, experts_held=4, num_nextn_predict_layers=1)
-
-
-def test_the_builder_s_conf_trains_and_counts_its_pairs():
-    """One conf layer a pattern letter, the module last under ``mtp_``
-    names with the shared embedding and head, two losses; a scanned
-    chunk through the trainer moves every leaf but the routers and
-    their bias, and the expert layers count their pairs."""
-    text = nemotron_h_conf(batch_size=2, dev="cpu", scan_steps=2,
-                           compute_dtype="float32", **TOY)
-    kinds = re.findall(r"^layer\[[^\]]*\] = (\S+)", text, re.M)
-    assert kinds == [
-        "embedding:embed", "mamba2:mixer0", "routed_experts:moe1",
-        "attention:attn2", "routed_experts:moe3", "rms_norm:norm_f",
-        "lm_head:head", "softmax", "token_shift:mtp_shift", "shared[embed]",
-        "rms_norm:mtp_enorm", "rms_norm:mtp_hnorm", "concat:mtp_cat",
-        "fullc:mtp_eh_proj", "attention:mtp_attn0",
-        "routed_experts:mtp_moe1", "rms_norm:mtp_norm_f", "shared[head]",
-        "softmax"]
-    assert "gated_mlp" not in text and "rotary" not in text
-    tr = NetTrainer()
-    tr.set_params(cfgmod.split_sections(
-        cfgmod.parse_pairs(text)).global_entries)
-    tr.init_model()
-    before = jax.device_get(tr.params)
-    r = np.random.RandomState(0)
-    ids = r.randint(1, 64, (2, 2, 32)).astype(np.float32)
-    ids[:, :, 11] = 0
-    stats = pipeline_stats()
-    pairs = stats.counters().get("expert_pairs", 0)
-    dropped = stats.counters().get("expert_pairs_dropped", 0)
-    losses = np.asarray(tr.update_scan(ids, np.roll(ids, -1, axis=2)))
-    tr.count_layer_state()
-    assert losses.shape == (2,) and np.isfinite(losses).all()
-    # both losses at uniform predictions: (1 + 0.3) ln 64, about
-    assert 1.2 * np.log(64) < losses[0] < 1.5 * np.log(64)
-    # 2 steps x 64 tokens x 3 picks x 3 layers, half of them held
-    assert 0.5 * 576 < stats.counters()["expert_pairs"] - pairs < 1.5 * 576
-    assert stats.counters().get("expert_pairs_dropped", 0) == dropped
-    after = jax.device_get(tr.params)
-    for key, tags in before.items():
-        for tag, w in tags.items():
-            still = np.array_equal(w, after[key][tag])
-            assert still == (tag in ("wgate", "score_bias")), (key, tag)
-
-
-def test_the_published_defaults_are_what_the_issue_reckoned():
-    """ISSUE 40's count from config.json's keys at one rank's share —
-    13.7M a mixer, 5.25M the attention — and an expert layer at 8 held
-    with its shared expert WHOLE (98.6M, where the issue divided its
-    columns by 8 for 60.0M): 700.9M parameters without the prediction
-    module, 838.2M with it."""
-    text = nemotron_h_conf()
-    assert text.count("= mamba2:") == NEMOTRON_H_STAGE.count("M") == 5
-    assert text.count("= attention:") == 1 and "mtp_" not in text
-    assert text.count("= routed_experts:") == 5
-    with_module = nemotron_h_conf(num_nextn_predict_layers=1)
-    assert with_module.startswith(text[:text.index("netconfig = end")])
-    assert with_module.count("= attention:") == 2
-    assert with_module.count("= routed_experts:") == 6
-    text = with_module
-    tr = NetTrainer()
-    tr.set_params(cfgmod.split_sections(
-        cfgmod.parse_pairs(text + "\ndev = cpu\n")).global_entries)
-    tr.set_param("silent", "1")
-    tr._build_net()
-    shapes = jax.eval_shape(lambda k: tr.net.init_params(k, 1),
-                            jax.random.PRNGKey(0))
-    by_layer = {key: sum(int(np.prod(v.shape)) for v in tags.values())
-                for key, tags in shapes.items()}
-    names = {key.split("_", 1)[1]: n for key, n in by_layer.items()}
-    assert names["mixer0"] == 13_708_592     # 13.70M + its pre-norm
-    assert names["attn7"] == 5_242_880 + 4096
-    assert names["moe1"] == 98_570_752       # 44.04M of it the 8 held,
-    assert names["mtp_moe1"] == names["moe1"]        # 44.04M the shared
-    assert names["mtp_eh_proj"] == 2 * 4096 * 4096
-    module = sum(n for key, n in names.items() if key.startswith("mtp_"))
-    assert round(sum(by_layer.values()) / 1e6, 1) == 838.2
-    assert sum(by_layer.values()) - module == 700_865_520
-
-
-# ----------------------------------------------------------------------
 EXAMPLE = dict(vocab=512, seq_len=256, hidden=128, pattern="MEM*E",
                mamba_heads=8, mamba_head_dim=32, mamba_groups=2,
                mamba_state=32, mamba_chunk=64, attn_heads=4, attn_kv_heads=2,
@@ -579,9 +446,10 @@ def test_the_shipped_example_is_the_builder_s_and_the_cli_trains_it(
         tmp_path):
     """``example/nemotron_h/nemotron_h_small.conf`` is what the builder
     writes at the arguments its header names, and ``python -m
-    cxxnet_tpu`` trains it on the CPU from a seeded token file: the
-    same CLI, iterator, round loop, ``update_scan`` and adam as the
-    benchmark's cell."""
+    cxxnet_tpu`` trains what the builder writes on the CPU from a seeded
+    token file — at the small widths of ``tests/families.py``, a round
+    of three chunks: the same CLI, iterator, round loop, ``update_scan``
+    and adam as the benchmark's cell."""
     from conftest import run_cli
 
     path = os.path.join(ROOT, "example", "nemotron_h",
@@ -591,15 +459,18 @@ def test_the_shipped_example_is_the_builder_s_and_the_cli_trains_it(
     body = "".join(line for line in shipped.splitlines(True)
                    if not line.startswith("#"))
     assert body == nemotron_h_conf(**EXAMPLE)
+    small = dict(families.NEMOTRON_H, token_file="tokens.bin", eta=0.001)
+    (tmp_path / "small.conf").write_text(nemotron_h_conf(**small))
     r = np.random.RandomState(0)
-    ids = r.randint(1, 512, 1 << 15).astype("<u2")
-    ids[r.rand(ids.size) < 1 / 60] = 0
+    # three chunks of 2 steps x 2 rows x 32 tokens, and the last label
+    ids = r.randint(1, 64, 3 * 2 * 2 * 32 + 1).astype("<u2")
+    ids[r.rand(ids.size) < 1 / 20] = 0
     ids.tofile(str(tmp_path / "tokens.bin"))
-    out = run_cli([path, "dev=cpu", "num_round=2", "max_round=2",
-                   "save_model=0", "compute_dtype=float32",
-                   f"model_dir={tmp_path}/models"], str(tmp_path))
+    out = run_cli(["small.conf", "num_round=1", "max_round=1",
+                   "save_model=0", f"model_dir={tmp_path}/models"],
+                  str(tmp_path))
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     losses = [float(v) for v in re.findall(r"train-logloss:([0-9.]+)",
                                            out.stdout + out.stderr)]
-    assert "update round 1" in out.stdout + out.stderr
+    assert "update round 0" in out.stdout + out.stderr
     assert all(np.isfinite(v) for v in losses)

@@ -54,8 +54,8 @@ def test_pipeline_gradients_match(rng):
     def loss_seq(p):
         return jnp.sum(sequential(p, x) ** 2)
 
-    gp = jax.grad(loss_pipe)(params)
-    gs = jax.grad(loss_seq)(params)
+    gp = jax.jit(jax.grad(loss_pipe))(params)
+    gs = jax.jit(jax.grad(loss_seq))(params)
     for k in gs:
         np.testing.assert_allclose(
             np.asarray(gp[k]), np.asarray(gs[k]), rtol=1e-4, atol=1e-5

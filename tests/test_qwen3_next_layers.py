@@ -4,7 +4,9 @@ gated delta rule (``ops/gdn.py``) and its gradient against the
 token-by-token recurrence; ``attention``'s rotary positions, q/k norms,
 output gate and free head width against a plain attention;
 ``routed_experts`` against a dense masked loop and against the sum of
-its shares; the untied ``lm_head``; the counters a step program keeps.
+its shares; the untied ``lm_head``; the scan's counters.  What every
+family's tests share (the builder's conf through the trainer, the
+published defaults) is a row of ``tests/families.py``.
 """
 
 import math
@@ -14,11 +16,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cxxnet_tpu import config as cfgmod
-from cxxnet_tpu.layers import create_layer
+import families
 from cxxnet_tpu.layers.moe import held_experts, route
 from cxxnet_tpu.models import qwen3_next_conf
-from cxxnet_tpu.nnet.trainer import NetTrainer
+from families import expert_shares, make, through_cos
 from cxxnet_tpu.ops.attention import doc_positions, rotary
 from cxxnet_tpu.ops.gdn import (gated_delta_recurrence, gated_delta_scan,
                                 gated_delta_scan_counted, gated_delta_xla,
@@ -26,14 +27,6 @@ from cxxnet_tpu.ops.gdn import (gated_delta_recurrence, gated_delta_scan,
 from cxxnet_tpu.ops.gdn_fused import gated_delta_fused, supported
 from cxxnet_tpu.ops.ssd import doc_index
 from cxxnet_tpu.utils.profiler import pipeline_stats
-
-
-def make(kind, in_shapes, seed=0, **cfg):
-    lay = create_layer(kind)
-    for k, v in cfg.items():
-        lay.set_param(k, str(v))
-    out = lay.infer_shape(in_shapes)
-    return lay, lay.init_params(jax.random.PRNGKey(seed), in_shapes), out
 
 
 # ----------------------------------------------------------------------
@@ -73,8 +66,8 @@ def test_chunked_delta_rule_gradient_is_the_recurrence_s(chunk):
     xs, doc = delta_inputs(seed=1)
 
     def through(fn):
-        return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
-                        argnums=(0, 1, 2, 3, 4))(*xs)
+        return jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                                argnums=(0, 1, 2, 3, 4)))(*xs)
 
     with jax.default_matmul_precision("highest"):
         got = through(lambda *a: gated_delta_scan(*a, doc, chunk))
@@ -100,10 +93,11 @@ def test_checkpointed_segments_carry_the_state_between_them(chunk, segment):
     with jax.default_matmul_precision("highest"):
         got = gated_delta_scan(*xs, doc, chunk, segment)
         want = gated_delta_recurrence(*xs, doc)
-        ga = jax.grad(lambda v: jnp.sum(jnp.sin(gated_delta_scan(
-            xs[0], xs[1], v, xs[3], xs[4], doc, chunk, segment))))(xs[2])
-        gb = jax.grad(lambda v: jnp.sum(jnp.sin(gated_delta_recurrence(
-            xs[0], xs[1], v, xs[3], xs[4], doc))))(xs[2])
+        ga = jax.jit(jax.grad(lambda v: jnp.sum(jnp.sin(gated_delta_scan(
+            xs[0], xs[1], v, xs[3], xs[4], doc, chunk, segment)))))(xs[2])
+        gb = jax.jit(jax.grad(lambda v: jnp.sum(jnp.sin(
+            gated_delta_recurrence(xs[0], xs[1], v, xs[3], xs[4], doc)))))(
+            xs[2])
     np.testing.assert_allclose(got, want, atol=2e-6)
     np.testing.assert_allclose(ga, gb, atol=5e-6)
     with pytest.raises(ValueError, match="multiple of chunk"):
@@ -135,13 +129,6 @@ def fused_inputs(case, dtype):
     xs = tuple(jnp.asarray(a, jnp.float32).astype(dtype) for a in (q, k, v))
     return xs + (jnp.asarray(g, jnp.float32),
                  jnp.asarray(beta, jnp.float32)), doc
-
-
-def through_cos(fn, xs):
-    """``fn``'s output in float32 and the cotangents of ``xs`` under
-    ``cos`` of it: forward and all five gradients of a scan."""
-    out, back = jax.vjp(lambda *a: fn(*a).astype(jnp.float32), *xs)
-    return (out,) + back(jnp.cos(out))
 
 
 def assert_near_the_recurrence(got, form, want, dtype):
@@ -240,11 +227,12 @@ def test_gated_deltanet_layer_shapes_and_document_reset():
     x = r.randn(2, 24, 16).astype(np.float32)
     ids = r.randint(1, 9, (2, 24)).astype(np.float32)
     ids[:, 10] = 0                       # a document ends at 10
-    y = lay.apply(p, [jnp.asarray(x), jnp.asarray(ids)])[0]
+    run = jax.jit(lambda a: lay.apply(p, [a, jnp.asarray(ids)])[0])
+    y = run(jnp.asarray(x))
     # what follows the separator depends on nothing before it
     x2 = x.copy()
     x2[:, :11] = r.randn(2, 11, 16)
-    y2 = lay.apply(p, [jnp.asarray(x2), jnp.asarray(ids)])[0]
+    y2 = run(jnp.asarray(x2))
     np.testing.assert_allclose(y[:, 11:] - x[:, 11:], y2[:, 11:] - x2[:, 11:],
                                atol=1e-5)
     assert np.abs(np.asarray(y[:, :11] - y2[:, :11])).max() > 1e-3
@@ -322,7 +310,8 @@ def test_attention_with_rotary_norms_gate_and_its_own_head_width():
     ids = r.randint(1, 9, (2, 20)).astype(np.float32)
     ids[0, 6] = ids[1, 13] = 0
     with jax.default_matmul_precision("highest"):
-        got = lay.apply(p, [jnp.asarray(x), jnp.asarray(ids)])[0]
+        got = jax.jit(lambda q, a, i: lay.apply(q, [a, i])[0])(
+            p, jnp.asarray(x), jnp.asarray(ids))
     want = plain_attention(p, x, ids, h, hk, dh, rot, theta, eps)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -404,7 +393,7 @@ def test_routed_experts_is_the_dense_masked_loop():
         "shared_gate": (1, 8)}
     x = np.random.RandomState(5).randn(2, 12, 8).astype(np.float32)
     with jax.default_matmul_precision("highest"):
-        (y,), state = lay.apply_stateful(
+        (y,), state = jax.jit(lay.apply_stateful)(
             p, lay.init_aux([(2, 12, 8)]), [jnp.asarray(x)])
     want, load = dense_moe(p, x, 16, 3, 4, 6)
     np.testing.assert_allclose(np.asarray(y).reshape(-1, 8), want, atol=2e-5)
@@ -421,16 +410,17 @@ def test_routed_experts_with_ties_and_an_expert_nobody_picks():
     x = np.abs(np.random.RandomState(6).randn(24, 8)).astype(np.float32)
     p = dict(p, wgate=jnp.asarray(wg))
     with jax.default_matmul_precision("highest"):
-        (y,), state = lay.apply_stateful(p, lay.init_aux([(24, 8)]),
-                                         [jnp.asarray(x)])
+        (y,), state = jax.jit(lay.apply_stateful)(
+            p, lay.init_aux([(24, 8)]), [jnp.asarray(x)])
     want, load = dense_moe(p, x, 16, 3, 0, 16)
     assert load[9] == 0 and load[2] > 0
     # a tie admits no extra expert: every token has exactly three pairs
     assert int(state["pairs"]) == 24 * 3 == load.sum()
     np.testing.assert_allclose(y, want, atol=2e-5)
     # the gradient goes through the permutations both ways
-    g = jax.grad(lambda q, a: jnp.sum(jnp.sin(lay.apply(q, [a])[0])),
-                 argnums=(0, 1))(p, jnp.asarray(x))
+    g = jax.jit(jax.grad(
+        lambda q, a: jnp.sum(jnp.sin(lay.apply(q, [a])[0])),
+        argnums=(0, 1)))(p, jnp.asarray(x))
     # no token, no gradient
     assert np.abs(np.asarray(g[0]["wmat"][9])).max() == 0
     assert np.abs(np.asarray(g[0]["wmat"][2])).max() > 0
@@ -446,18 +436,9 @@ def test_the_shares_of_all_ranks_add_up_to_the_whole_layer():
     with jax.default_matmul_precision("highest"):
         want = whole.apply(p, [x])[0]
         only_shared = dense_moe(p, np.asarray(x), 16, 3, 0, 0)[0]
-        parts, pairs = [], 0
-        for rank in range(4):
-            lay, _, _ = make("routed_experts", [(2, 12, 8)],
-                             first_expert=4 * rank, nheld=4, **MOE)
-            mine = dict(p, wmat=p["wmat"][4 * rank:4 * rank + 4],
-                        wproj=p["wproj"][4 * rank:4 * rank + 4])
-            (y,), st = lay.apply_stateful(mine, lay.init_aux([(2, 12, 8)]),
-                                          [x])
-            parts.append(np.asarray(y, np.float64).reshape(-1, 8))
-            pairs += int(st["pairs"])
+        parts, pairs = expert_shares(MOE, p, x, 4, 4)
     assert pairs == 24 * 3               # every pair on exactly one rank
-    total = sum(parts) - 3 * only_shared
+    total = sum(q.reshape(-1, 8) for q in parts) - 3 * only_shared
     np.testing.assert_allclose(total, np.asarray(want).reshape(-1, 8),
                                atol=3e-5)
     with pytest.raises(ValueError, match="not among"):
@@ -476,8 +457,8 @@ def test_a_share_takes_its_routing_weights_as_constants():
     loss = lambda lay: lambda q, a: jnp.sum(  # noqa: E731
         jnp.sin(lay.apply(q, [a])[0]))
     with jax.default_matmul_precision("highest"):
-        gw = jax.grad(loss(whole), argnums=(0, 1))(p, x)
-        gs = jax.grad(loss(share), argnums=(0, 1))(mine, x)
+        gw = jax.jit(jax.grad(loss(whole), argnums=(0, 1)))(p, x)
+        gs = jax.jit(jax.grad(loss(share), argnums=(0, 1)))(mine, x)
         # the same layer with its router detached from the input
         logits = x.reshape(-1, 8) @ p["wgate"].T
 
@@ -490,7 +471,7 @@ def test_a_share_takes_its_routing_weights_as_constants():
             y = y + jax.nn.sigmoid(u @ q["shared_gate"].T) * sh
             return jnp.sum(jnp.sin(y))
 
-        gd = jax.grad(detached, argnums=(0, 1))(mine, x)
+        gd = jax.jit(jax.grad(detached, argnums=(0, 1)))(mine, x)
     assert np.abs(np.asarray(gw[0]["wgate"])).max() > 0
     assert np.abs(np.asarray(gs[0]["wgate"])).max() == 0
     np.testing.assert_allclose(gs[1], gd[1], atol=1e-5)
@@ -508,57 +489,10 @@ def test_an_untied_head_owns_its_matrix():
     assert p2 == {}
 
 
-TINY = dict(vocab=64, seq_len=64, hidden=32, layer_types="lf",
-            linear_key_heads=2, linear_value_heads=4, linear_key_dim=8,
-            linear_value_dim=8, linear_chunk=16, attn_heads=4,
-            attn_kv_heads=2, head_dim=16, num_experts=16, experts_per_tok=3,
-            expert_hidden=24, shared_hidden=24, experts_held=4, dev="cpu",
-            compute_dtype="float32", scan_steps=4)
-
-
-def test_the_builder_s_conf_trains_and_counts_its_pairs():
-    text = qwen3_next_conf(**TINY)
-    assert text.count("= gated_deltanet:") == 1
-    assert text.count("= routed_experts:") == 2 and "tied" not in text
-    assert "rotary_dim = 4" in text and "rope_theta = 10000000.0" in text
-    # adam at one rate for everything, the routers too: a share's
-    # router stays put because its gradient is zero (layers/moe.py)
-    assert "wgate" not in text and ":lr" not in text
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(text))
-    tr.set_param("silent", "1")
-    tr.init_model()
-    assert set(tr.aux) == {"l1_gdn0", "l2_moe0", "l4_moe1", "l3_attn1"}
-    r = np.random.RandomState(0)
-    ids = r.randint(0, 64, (4, 1, 64)).astype(np.float32)
-    router = np.asarray(tr.params["l2_moe0"]["wgate"]).copy()
-    expert = np.asarray(tr.params["l2_moe0"]["wmat"]).copy()
-    first = tr.update_scan(ids, np.roll(ids, -1, axis=2))
-    again = tr.update_scan(ids, np.roll(ids, -1, axis=2))
-    assert np.isfinite(first).all() and again.mean() < first.mean()
-    assert np.array_equal(np.asarray(tr.params["l2_moe0"]["wgate"]), router)
-    assert not np.array_equal(np.asarray(tr.params["l2_moe0"]["wmat"]),
-                              expert)
-    stats = pipeline_stats()
-    before = stats.counters().get("expert_pairs", 0)
-    tr.count_layer_state()
-    got = stats.counters()
-    pairs = got["expert_pairs"] - before
-    # 8 steps x 64 tokens x 3 picks x 2 layers, a quarter of them held
-    assert 0.6 * 768 < pairs < 1.4 * 768
-    assert got["expert_pairs_max"] * 4 >= pairs
-    assert got.get("expert_pairs_dropped", 0) == 0
-    tr.count_layer_state()               # nothing new: nothing added
-    assert stats.counters()["expert_pairs"] - before == pairs
-
-
 def test_a_cpu_run_counts_the_scan_s_tokens_and_none_fused():
     """The gated_deltanet layer's counters: tokens x layers through the
     scan, and those the kernels computed - none off the TPU."""
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(qwen3_next_conf(**TINY)))
-    tr.set_param("silent", "1")
-    tr.init_model()
+    tr = families.trainer(qwen3_next_conf(**families.QWEN3_NEXT))
     assert set(tr.aux["l1_gdn0"]) == {"scan_tokens", "scan_tokens_fused"}
     stats = pipeline_stats()
     before = dict(stats.counters())
@@ -579,18 +513,3 @@ def test_a_cpu_run_counts_the_scan_s_tokens_and_none_fused():
         assert got.get(name, 0) == before.get(name, 0)
     tr.count_layer_state()
     assert stats.counters()["gdn_scan_tokens"] == got["gdn_scan_tokens"]
-
-
-def test_the_published_defaults_are_what_the_issue_reckoned():
-    tr = NetTrainer()
-    tr.set_params(cfgmod.parse_pairs(qwen3_next_conf(dev="cpu")))
-    tr._build_net()
-    shapes = jax.eval_shape(
-        lambda k: tr.net.init_params(k, 1), jax.random.PRNGKey(0))
-    count = lambda key: sum(  # noqa: E731
-        int(np.prod(v.shape)) for v in shapes[key].values())
-    assert count("l1_gdn0") == 33_718_464 + 2048      # the mixer and its norm
-    assert count("l7_attn3") == 27_263_488 + 2048
-    assert count("l2_moe0") == 104_859_648 + 2048
-    total = sum(count(k) for k in shapes)
-    assert round(total / 1e6, 1) == 625.7              # x 16 B = 10.01 GB

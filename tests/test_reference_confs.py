@@ -8,6 +8,7 @@ import os
 
 import pytest
 
+from conftest import build_from_shapes
 from cxxnet_tpu import config as C
 from cxxnet_tpu.nnet.trainer import NetTrainer
 
@@ -52,7 +53,7 @@ def test_reference_conf_builds_net(rel, nclass):
     tr.set_params(cfg)
     tr.set_param("dev", "cpu")
     tr.set_param("batch_size", "4")  # tiny for CPU shape inference
-    tr.init_model()
+    assert build_from_shapes(tr)
     out = tr.net.node_shapes[tr.net.out_node_index()]
     assert out[-1] == nclass, f"{rel}: output {out}"
 
@@ -85,7 +86,7 @@ def test_repo_example_conf_builds_net(rel, nclass):
     tr.set_params(cfg)
     tr.set_param("dev", "cpu")
     tr.set_param("batch_size", "4")
-    tr.init_model()
+    assert build_from_shapes(tr)
     out = tr.net.node_shapes[tr.net.out_node_index()]
     assert out[-1] == nclass, f"{rel}: output {out}"
 

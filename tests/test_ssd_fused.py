@@ -11,6 +11,7 @@ import pytest
 from cxxnet_tpu.ops.ssd import (ssd_recurrence, ssd_scan, ssd_scan_counted,
                                 ssd_xla)
 from cxxnet_tpu.ops.ssd_fused import heads_per_step, ssd_fused, supported
+from families import through_cos
 
 #: case -> (N, T, H, P, chunk, where documents begin or None)
 CASES = {
@@ -47,13 +48,6 @@ def fused_inputs(case, dtype):
     return ((jnp.asarray(x, f32).astype(dtype), jnp.asarray(dt, f32),
              jnp.asarray(a, f32), jnp.asarray(b, f32).astype(dtype),
              jnp.asarray(c, f32).astype(dtype)), doc, chunk)
-
-
-def through_cos(fn, xs):
-    """``fn``'s output in float32 and the cotangents of ``xs`` under
-    ``cos`` of it: forward and all five gradients of a scan."""
-    out, back = jax.vjp(lambda *v: fn(*v).astype(jnp.float32), *xs)
-    return (out,) + back(jnp.cos(out))
 
 
 @pytest.mark.parametrize("case, dtype", [

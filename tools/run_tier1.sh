@@ -1,8 +1,13 @@
 #!/usr/bin/env bash
-# Tier-1 verify: the EXACT pipeline ROADMAP.md documents, so local runs
-# and CI invoke the identical command.  Fast tests only (-m 'not slow');
-# fault-injection and multi-process tests marked @pytest.mark.slow run in
-# the full suite instead.
+# Tier-1 verify: the command the driver runs after every PR (the
+# `commands` of /root/TESTS_LAST_RUN.json: xdist, 6 workers, a file to a
+# worker, 1470 s), so a local run counts what the gate counts.  The
+# one-process line in ROADMAP.md ("Tier-1 verify", 870 s, -p no:xdist)
+# has reached its end in no record of this round: ~80 minutes of cases.
+# Fast tests only (-m 'not slow'); fault-injection and multi-process tests
+# marked @pytest.mark.slow run in the full suite instead.  PR 45's run:
+# see ROADMAP.md D0 for passed / wall / CPU-seconds and the rules that
+# keep the suite inside its clock.
 #
 # Usage: tools/run_tier1.sh [extra pytest args...]
 #        CHAOS=1 tools/run_tier1.sh   # also run the fault-matrix chaos
@@ -206,12 +211,15 @@
 #                                     # plus a perf_guard --smoke verdict
 set -o pipefail
 cd "$(dirname "$0")/.."
-rm -f /tmp/_t1.log
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
-  --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly \
-  "$@" 2>&1 | tee /tmp/_t1.log
+t1=${TMPDIR:-/tmp}
+rm -f "$t1/_t1.log" "$t1/_t1.xml"
+timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
+  python -m pytest tests/ -q -m 'not slow' \
+  --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 \
+  --dist loadfile --junitxml="$t1/_t1.xml" -p no:randomly \
+  "$@" 2>&1 | tee "$t1/_t1.log"
 rc=${PIPESTATUS[0]}
-echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
+echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$t1/_t1.log" | tr -cd . | wc -c)
 if [ "${CHAOS:-0}" = "1" ]; then
   echo "=== opt-in chaos stage (CHAOS=1) ==="
   tools/chaos_run.sh || rc=1
