@@ -155,6 +155,13 @@ class Layer:
     def init_params(self, key: jax.Array, in_shapes: Sequence[Shape]) -> Params:
         return {}
 
+    def borrows(self) -> dict:
+        """``{tag: (layer name, its tag)}``: single leaves of OTHER layers
+        this one computes with beside its own.  ``FunctionalNet`` hands
+        them over under ``tag`` in ``params``: one leaf, one gradient, the
+        sum of every use (``routed_experts``' ``route_norm``)."""
+        return {}
+
     def apply(
         self,
         params: Params,

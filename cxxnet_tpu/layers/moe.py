@@ -59,6 +59,34 @@ projections are computed by every rank alike (replicated, like the
 router): ``r`` is linear in the experts' terms, so the ranks' ``r
 W_out^T`` add up to the whole layer's.
 
+With ``expert_act = reglu`` (the SmallThinker family's sparse ReGLU) an
+expert is the gated three matrices with ``relu`` in the gate's place of
+``silu``: ``W_d (relu(W_g x) * W_u x)``, on the same fused ``gate | up``.
+
+**A router that reads another node** (the SmallThinker family routes
+BEFORE it attends, so that a device can fetch the chosen experts while
+attention runs).  Given a SECOND input the router reads that node and
+the experts the first::
+
+    layer[x0,h0->h1] = routed_experts:moe0      x0 = h0 + attention(...)
+      route_norm = attn0
+
+    p = softmax(rms_norm(h0; attn0's norm) W_r^T),   y = ... expert_e(u)
+
+``route_norm = <layer>`` puts the second input through an ``rms_norm``
+under THAT layer's ``norm`` weight (its ``prenorm``; this layer's
+``eps``) first — the attention's own normed input, without a node or a
+weight of its own: ``FunctionalNet`` hands the leaf over beside this
+layer's parameters (``Layer.borrows``), one leaf and one gradient, the
+sum of both uses.  Without the key the second input is read as it is.
+Under ``remat = 1`` the layer's checkpoint keeps its two inputs, and the
+second is the array the attention layer's checkpoint keeps already: no
+``(N, T, D)`` array more than a layer with one input; the norm is
+computed once more here, forward and in the recompute.  In a whole layer
+the router's gradient flows into that norm weight and into the stream
+before the attention; in a share it is zero like everything of the
+router's.
+
 **No pair is dropped and no expert has a capacity.**  The (token,
 expert) pairs are sorted by expert, pairs of experts held elsewhere
 last.  Every array between that sort and a token's sum has the ``C``
@@ -93,9 +121,12 @@ With ``nheld = nexpert`` (or ``SLAB_FACTOR`` x the share >= 1) ``C`` is
 * ``score_func`` — ``softmax`` (default) or ``sigmoid``; ``select_bias``
   (default 0) — 1 chooses by score + ``score_bias``; ``routed_scale``
   (default 1) multiplies the chosen weights
-* ``expert_act`` — ``swiglu`` (default) or ``relu2``, for the held
-  experts and the shared one alike; ``latent_hidden`` (default 0: none)
-  — the width ``L`` the held experts read and write
+* ``expert_act`` — ``swiglu`` (default), ``reglu`` or ``relu2``, for
+  the held experts and the shared one alike; ``latent_hidden`` (default
+  0: none) — the width ``L`` the held experts read and write
+* ``route_norm`` — with a second input (the node the router reads): the
+  layer whose ``norm`` weight that input is normed under (default: none,
+  the router reads the second input as it is)
 * ``prenorm`` / ``postnorm`` / ``residual_scale`` / ``eps`` — the
   residual branch in one layer (``sequence.Branch``); with ``postnorm``
   the layer's output — in a share the PARTIAL sum of the held experts
@@ -105,7 +136,7 @@ With ``nheld = nexpert`` (or ``SLAB_FACTOR`` x the share >= 1) ``C`` is
   ``init_sigma`` for every matrix
 
 Parameters (tags), with ``W`` the experts' width — ``latent_hidden``, or
-D without — and ``c`` = 2 (``swiglu``: gate | up fused) or 1
+D without — and ``c`` = 2 (``swiglu``, ``reglu``: gate | up fused) or 1
 (``relu2``): ``wgate`` (nexpert, D); the held experts' matrices
 as the grouped product reads them, ``(expert, in, out)`` — ``wmat``
 (nheld, W, c nhidden) and ``wproj`` (nheld, nhidden, W): kept
@@ -133,7 +164,8 @@ summed; ``pairs_dropped`` — 0, by the construction above;
 ``pairs_overflow`` — pairs beyond the first slab, computed by the loop.
 uint32, wrapping: the reader takes differences.
 
-Scopes inside the layer's: ``route`` (router, softmax, top-k),
+Scopes inside the layer's: ``route`` (router, softmax, top-k; with a
+second input its norm too),
 ``dispatch`` (the sort, a slab's plan and gather; backward: ``dx``),
 ``experts`` (the grouped products), ``combine`` (the weights and the sum
 onto the tokens), ``shared``, and with a latent ``latent_in`` and
@@ -159,7 +191,7 @@ from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from .base import Layer, Params, Shape, register
-from .sequence import Branch
+from .sequence import Branch, rms_norm
 
 COUNTERS = ("pairs", "pairs_max", "pairs_dropped", "pairs_overflow")
 
@@ -171,6 +203,8 @@ COUNTERS = ("pairs", "pairs_max", "pairs_dropped", "pairs_overflow")
 SLAB_FACTOR = 2
 #: a slab is whole row tiles of the grouped product's kernel
 ROW_TILE = 512
+#: ``expert_act`` of a gated expert -> what its gate goes through
+GATES = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
 
 
 def slab_rows(pairs: int, nheld: int, nexpert: int) -> int:
@@ -223,8 +257,9 @@ def _experts(xs, wmat, wproj, sizes, valid, act: str = "swiglu"):
     """A slab's rows ``xs (c, D)`` through their experts, ``(c, D)`` in
     the activations' dtype and zero past the held pairs (whatever the
     grouped product left there is put out): ``act = "swiglu"`` on a
-    fused ``wmat (G, D, 2F)`` gate | up, ``"relu2"`` (``relu(.)^2``, no
-    gate) on ``wmat (G, D, F)``.  The kernels accumulate in float32.  It
+    fused ``wmat (G, D, 2F)`` gate | up, ``"reglu"`` on the same with
+    ``relu`` for ``silu``, ``"relu2"`` (``relu(.)^2``, no gate) on
+    ``wmat (G, D, F)``.  The kernels accumulate in float32.  It
     names no scope: the backward calls it under ``jax.vjp``, which
     would wrap one (``jvp(experts)``) where the trace's readers look
     for the plain name."""
@@ -233,7 +268,7 @@ def _experts(xs, wmat, wproj, sizes, valid, act: str = "swiglu"):
         h = jnp.square(jax.nn.relu(gu.astype(jnp.float32))).astype(xs.dtype)
     else:
         f = wmat.shape[-1] // 2
-        h = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+        h = (GATES[act](gu[:, :f].astype(jnp.float32))
              * gu[:, f:].astype(jnp.float32)).astype(xs.dtype)
     ys = lax.ragged_dot(jnp.where(valid, h, 0), wproj, sizes,
                         preferred_element_type=xs.dtype)
@@ -351,7 +386,7 @@ def held_experts(x, w, idx, wmat, wproj, first: int, nexpert: int,
                  act: str = "swiglu"):
     """The held experts' part of the layer: ``x (M, D)``, the router's
     ``w`` / ``idx (M, k)`` over ``nexpert`` experts, ``wmat (G, D, 2F)``
-    (``(G, D, F)`` with ``act = "relu2"``) and ``wproj (G, F, D)`` of
+    (gate | up; ``(G, D, F)`` with ``act = "relu2"``) and ``wproj (G, F, D)`` of
     the ``G`` experts ``first .. first + G - 1`` -> (``y (M, D)``, pairs
     a held expert ``(G,)`` int32).  ``D`` is whatever width the experts
     live in: the stream's, or a latent's."""
@@ -393,6 +428,7 @@ class RoutedExpertsLayer(Layer, Branch):
         self.routed_scale = 1.0
         self.latent_hidden = 0  # 0: the experts read the stream itself
         self.expert_act = "swiglu"
+        self.route_norm = ""  # the layer whose norm the router's input takes
 
     _INT_KEYS = ("nexpert", "topk", "first_expert", "nheld",
                  "shared_hidden", "shared_gate", "norm_topk", "select_bias",
@@ -410,22 +446,41 @@ class RoutedExpertsLayer(Layer, Branch):
         elif name == "routed_scale":
             self.routed_scale = float(val)
         elif name == "expert_act":
-            if val not in ("swiglu", "relu2"):
+            if val not in (*GATES, "relu2"):
                 raise ValueError(
-                    f"routed_experts: expert_act is swiglu or relu2, got "
-                    f"{val!r}")
+                    f"routed_experts: expert_act is swiglu, reglu or relu2, "
+                    f"got {val!r}")
             self.expert_act = val
+        elif name == "route_norm":
+            self.route_norm = val
         elif not self.set_branch_param(name, val):
             super().set_param(name, val)
 
     def _held(self) -> int:
         return self.nheld or self.nexpert - self.first_expert
 
+    def borrows(self):
+        """``FunctionalNet`` reads it: the router's input is normed under
+        the named layer's ``norm`` weight, handed over as ``route_norm``."""
+        return ({"route_norm": (self.route_norm, "norm")}
+                if self.route_norm else {})
+
     def infer_shape(self, in_shapes: Sequence[Shape]) -> List[Shape]:
-        self._check_arity(in_shapes, 1)
+        if len(in_shapes) not in (1, 2):
+            raise ValueError(
+                f"routed_experts: expected 1 input, or 2 (the experts' and "
+                f"the router's), got {len(in_shapes)}")
         if len(in_shapes[0]) not in (2, 3):
             raise ValueError("routed_experts: input must be a matrix or a "
                              "sequence node")
+        if len(in_shapes) == 2 and tuple(in_shapes[1]) != tuple(in_shapes[0]):
+            raise ValueError(
+                f"routed_experts: the router's input {tuple(in_shapes[1])} "
+                f"is not shaped like the experts' {tuple(in_shapes[0])}")
+        if self.route_norm and len(in_shapes) != 2:
+            raise ValueError(
+                "routed_experts: route_norm norms the router's own input, "
+                "the layer's second")
         if self.param.num_hidden <= 0 or self.nexpert < 1 or not (
                 1 <= self.topk <= self.nexpert):
             raise ValueError("routed_experts: set nexpert, nhidden and "
@@ -444,7 +499,7 @@ class RoutedExpertsLayer(Layer, Branch):
         d = in_shapes[0][-1]
         f, g, sh = self.param.num_hidden, self._held(), self.shared_hidden
         lat = self.latent_hidden or d  # the width the held experts live in
-        fused = 1 if self.expert_act == "relu2" else 2  # gate | up, or up
+        fused = 1 if self.expert_act == "relu2" else 2  # up, or gate | up
         ks = jax.random.split(key, 6)
         sigma = self.param.init_sigma
 
@@ -472,11 +527,11 @@ class RoutedExpertsLayer(Layer, Branch):
         return {name: jnp.zeros((), jnp.uint32) for name in COUNTERS}
 
     def apply(self, params, inputs, *, train=False, rng=None, step=None):
-        return self._run(params, inputs[0])[0]
+        return self._run(params, *inputs)[0]
 
     def apply_stateful(self, params, aux, inputs, *, train=False, rng=None,
                        step=None):
-        outs, counts = self._run(params, inputs[0])
+        outs, counts = self._run(params, *inputs)
         pairs = counts.sum()
         tokens = inputs[0].size // inputs[0].shape[-1]
         slab = slab_rows(tokens * self.topk, self._held(), self.nexpert)
@@ -488,12 +543,18 @@ class RoutedExpertsLayer(Layer, Branch):
                 pairs - slab, 0).astype(jnp.uint32),
         }
 
-    def _run(self, params, x0):
+    def _run(self, params, x0, r0=None):
         cdt = x0.dtype
         u = self.branch_in(params, x0)
         x = u.reshape(-1, u.shape[-1])
         with jax.named_scope("route"):
-            logits = jnp.dot(x.astype(jnp.float32), params["wgate"].T,
+            xr = x
+            if r0 is not None:
+                # the router reads another node than the experts
+                if self.route_norm:
+                    r0 = rms_norm(r0, params["route_norm"], self.eps)
+                xr = r0.reshape(x.shape)
+            logits = jnp.dot(xr.astype(jnp.float32), params["wgate"].T,
                              precision=lax.Precision.HIGHEST)
             w, idx = route(logits, self.topk, bool(self.norm_topk),
                            score_func=self.score_func,
@@ -523,7 +584,7 @@ class RoutedExpertsLayer(Layer, Branch):
                 gu = x @ params["shared_wmat"].astype(cdt).T
                 hid = (jnp.square(jax.nn.relu(gu))
                        if self.expert_act == "relu2"
-                       else jax.nn.silu(gu[:, :sh]) * gu[:, sh:])
+                       else GATES[self.expert_act](gu[:, :sh]) * gu[:, sh:])
                 s = hid @ params["shared_wproj"].astype(cdt).T
                 if self.shared_gate:
                     s = jax.nn.sigmoid(

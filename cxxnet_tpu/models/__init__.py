@@ -30,6 +30,7 @@ from .builders import (  # noqa: F401
     resnet50_conf,
     resnet101_conf,
     resnet152_conf,
+    smallthinker_conf,
     transformer_conf,
     transformer_lm_conf,
     vgg16_conf,
@@ -54,4 +55,5 @@ MODEL_BUILDERS = {
     "joyai_llm_flash": joyai_llm_flash_conf,
     "nemotron_h": nemotron_h_conf,
     "afmoe": afmoe_conf,
+    "smallthinker": smallthinker_conf,
 }

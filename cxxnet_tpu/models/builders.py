@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 import math
-from typing import List
+from typing import List, Sequence
 
 
 def _iter_block(
@@ -1026,6 +1026,126 @@ def afmoe_conf(
     )
     feed = (f"  attn_window = {sliding_window}\n"
             if "s" in layer_types else "")
+    return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
+                      compute_dtype, eta, scan_steps, feed=feed)
+
+
+#: one period of SmallThinker's two layouts: a full layer without
+#: positions, then three rotary layers under the window
+SMALLTHINKER_PERIOD = (0, 1, 1, 1)
+
+
+def smallthinker_conf(
+    vocab: int = 18992,
+    seq_len: int = 16384,
+    hidden: int = 2560,
+    sliding_window_layout: Sequence[int] = SMALLTHINKER_PERIOD,
+    rope_layout: Sequence[int] = SMALLTHINKER_PERIOD,
+    sliding_window: int = 4096,
+    attn_heads: int = 28,
+    attn_kv_heads: int = 4,
+    head_dim: int = 128,
+    rope_theta: float = 1500000.0,
+    num_experts: int = 64,
+    experts_per_tok: int = 6,
+    expert_hidden: int = 768,
+    first_expert: int = 0,
+    experts_held: int = 16,
+    eps: float = 1e-6,
+    token_file: str = "",
+    batch_size: int = 1,
+    num_round: int = 10,
+    dev: str = "tpu",
+    compute_dtype: str = "bfloat16",
+    eta: float = 0.0003,
+    scan_steps: int = 8,
+) -> str:
+    """A SmallThinker style language model (PowerInfer, arXiv:2507.20984;
+    ``model_name: smallthinker_21b_instruct``): every layer a
+    grouped-query attention without biases, q/k norms or gate, then
+    ``num_experts`` ReGLU experts of ``expert_hidden`` and nothing else —
+    no dense layer, no shared expert — in pre-norm residual blocks.  THE
+    ROUTER READS THE ATTENTION'S INPUT and the experts its output::
+
+        u = rms_norm(x; n1);  (w, e) = top-k of softmax(u W_r^T), renormed
+        x' = x + attention(u);  v = rms_norm(x'; n2)
+        x'' = x' + sum_j w_j W_d^e_j (relu(W_g^e_j v) * W_u^e_j v)
+
+    (``routed_experts`` with a second input under ``route_norm =
+    attn<i>``: the attention layer's own norm weight, ``layers/moe.py``).
+    Layer ``i`` sees a causal window of ``sliding_window`` keys where
+    ``sliding_window_layout[i]`` is 1 and its whole document where it is
+    0, and rotates its queries and keys (rotate-half over the whole head
+    at ``rope_theta``) where ``rope_layout[i]`` is 1; the published
+    layouts are one period of four: a full layer with NO positions, then
+    three rotary layers under the window.  An untied head.
+
+    The defaults are the published widths of SmallThinker-21BA3B, four
+    layers deep — its layers 0-3, one whole period — with ONE RANK'S
+    SHARE of a 4-way expert-parallel layout: ``experts_held`` = 16 of the
+    64 experts of every layer from ``first_expert`` on (the router still
+    ranks all 64), over an eighth of the vocabulary: 559.3M parameters.
+    In a share the routing weights are constants of the backward pass, as
+    in ``qwen3_next_conf``; the embedding starts at normal(0, 1) as in
+    ``joyai_llm_flash_conf``, the other matrices at 0.02.
+
+    Rows of ``seq_len`` = 16384 packed tokens (``iter = tokens``); with
+    a ``token_file`` the feed is told the window (``attn_window``) and
+    counts ``attn_window_pairs`` beside ``attn_pairs``.  Written for
+    memory as ``granite_h_conf`` is; documents and positions as
+    ``qwen3_next_conf``.
+    """
+    if len(sliding_window_layout) != len(rope_layout):
+        raise ValueError("smallthinker_conf: sliding_window_layout and "
+                         "rope_layout give one entry a layer each")
+    if (set(sliding_window_layout) | set(rope_layout)) - {0, 1}:
+        raise ValueError("smallthinker_conf: a layout is a list of 0 and 1")
+    branch = (f"  prenorm = 1\n  eps = {eps!r}\n  residual_scale = 1.0\n"
+              "  init_sigma = 0.02\n")
+    s = (
+        "netconfig = start\n"
+        "layer[0->h0] = embedding:embed\n"
+        f"  nvocab = {vocab}\n"
+        f"  nhidden = {hidden}\n"
+        # a token's own row has to stand out of the stream
+        # (joyai_llm_flash_conf)
+        "  init_sigma = 1.0\n"
+    )
+    for i, (near, rotary) in enumerate(zip(sliding_window_layout,
+                                           rope_layout)):
+        s += (
+            f"layer[h{i},0->x{i}] = attention:attn{i}\n"
+            f"  nhead = {attn_heads}\n"
+            f"  nkvhead = {attn_kv_heads}\n"
+            f"  head_dim = {head_dim}\n"
+            + (f"  window = {sliding_window}\n" if near else "")
+            + (f"  rotary_dim = {head_dim}\n"
+               f"  rope_theta = {rope_theta!r}\n" if rotary else "")
+            + "  causal = 1\n  no_bias = 1\n" + branch
+            # the experts read the attention's output, the router its input
+            + f"layer[x{i},h{i}->h{i + 1}] = routed_experts:moe{i}\n"
+            f"  route_norm = attn{i}\n"
+            f"  nexpert = {num_experts}\n"
+            f"  topk = {experts_per_tok}\n"
+            f"  nhidden = {expert_hidden}\n"
+            f"  first_expert = {first_expert}\n"
+            f"  nheld = {experts_held}\n"
+            "  expert_act = reglu\n"
+            "  norm_topk = 1\n" + branch
+        )
+    s += (
+        f"layer[h{len(rope_layout)}->nf] = rms_norm:norm_f\n"
+        f"  eps = {eps!r}\n"
+        "layer[nf->logits] = lm_head:head\n"
+        f"  nhidden = {vocab}\n"
+        "  init_sigma = 0.02\n"
+        "layer[logits->logits] = softmax\n"
+        # the mean over all positions: the loss sums over T
+        f"  grad_scale = {1.0 / seq_len!r}\n"
+        "netconfig = end\n"
+    )
+    feed = (f"  attn_window = {sliding_window}\n"
+            if any(sliding_window_layout) else "")
     return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
                       compute_dtype, eta, scan_steps, feed=feed)
 

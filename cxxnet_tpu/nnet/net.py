@@ -123,6 +123,16 @@ class FunctionalNet:
                     graph.layers[i].type_name != "shared":
                 self.param_key[i] = self.param_key[
                     graph.layer_index_of(lay.tied)]
+        # a layer that BORROWS single leaves of other layers beside its
+        # own (``borrows()``: routed_experts' ``route_norm``) is handed
+        # them under its own tags: one leaf, one gradient
+        self.borrowed: Dict[int, Dict[str, Tuple[str, str]]] = {}
+        for i, lay in enumerate(self.layer_objs):
+            wants = lay.borrows()
+            if wants:
+                self.borrowed[i] = {
+                    tag: (self.param_key[graph.layer_index_of(name)], src)
+                    for tag, (name, src) in wants.items()}
         self.node_shapes: List[Optional[Tuple[int, ...]]] = []
         # params kept in f32 even under mixed precision (norm layers,
         # whose math runs in f32 — a bf16 round-trip would only lose bits)
@@ -272,6 +282,12 @@ class FunctionalNet:
             p = lay.init_params(sub, in_shapes)
             if p:
                 params[self.param_key[i]] = p
+        for i, wants in self.borrowed.items():
+            for tag, (key, src) in wants.items():
+                if src not in params.get(key, {}):
+                    raise ValueError(
+                        f"layer {i} ({self.graph.layers[i].type_name}): "
+                        f"{tag} names a layer that has no {src!r} weight")
         return params
 
     # ------------------------------------------------------------------
@@ -751,6 +767,10 @@ class FunctionalNet:
                 else:
                     key = self.param_key[i]
                     lparams = params.get(key, {})
+                    if i in self.borrowed:
+                        lparams = dict(lparams, **{
+                            tag: params[k][src] for tag, (k, src)
+                            in self.borrowed[i].items()})
                     if _opsq().is_quantized(lparams):
                         # int8 entry: dequant-free apply (ops/quant.py) —
                         # conv/fullc only, by the exporter's construction

@@ -88,8 +88,9 @@ LONGEST_FIRST = (
     "test_bench_harness", "test_bench_nemotron_h", "test_flash",
     "test_v5e_nemotron", "test_v5e_granite", "test_families",
     "test_accuracy", "test_bench_joyai_llm_flash", "test_bench_qwen3_next",
-    "test_v5e_qwen3_next", "test_qwen3_next_layers", "test_nemotron_layers",
-    "test_layers", "test_cli", "test_bench_trinity_mini",
+    "test_v5e_qwen3_next", "test_v5e_smallthinker", "test_qwen3_next_layers",
+    "test_nemotron_layers", "test_layers", "test_cli",
+    "test_bench_trinity_mini", "test_bench_smallthinker",
     "test_fault_tolerance", "test_joyai_layers", "test_bench_granite",
     "test_afmoe_layers", "test_models", "test_granite_hybrid",
     "test_branch_embed", "test_ops", "test_moe_dispatch", "test_trainer",

@@ -293,6 +293,13 @@ AFMOE = dict(vocab=64, seq_len=64, hidden=32, layer_types="ssf",
              experts_held=4, dev="cpu", compute_dtype="float32",
              scan_steps=4)
 
+SMALLTHINKER = dict(vocab=64, seq_len=64, hidden=32,
+                    sliding_window_layout=(0, 1), rope_layout=(0, 1),
+                    sliding_window=16, attn_heads=6, attn_kv_heads=2,
+                    head_dim=16, num_experts=16, experts_per_tok=3,
+                    expert_hidden=24, experts_held=4, dev="cpu",
+                    compute_dtype="float32", scan_steps=4)
+
 FLASH_COUNTERS = ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked")
 
 
@@ -331,6 +338,28 @@ def _afmoe_also(text):
         models.afmoe_conf(layer_types="sf", num_dense_layers=3)
 
 
+def _smallthinker_also(text):
+    # every expert layer reads two nodes: the attention's output for the
+    # experts, its input for the router, under the attention's norm
+    for i in (0, 1):
+        assert (f"layer[x{i},h{i}->h{i + 1}] = routed_experts:moe{i}\n"
+                f"  route_norm = attn{i}\n") in text
+    fed = models.smallthinker_conf(**dict(SMALLTHINKER,
+                                          token_file="tokens.bin"))
+    assert "  attn_window = 16\n" in fed
+    assert "attn_window" not in models.smallthinker_conf(**dict(
+        SMALLTHINKER, sliding_window_layout=(0, 0), token_file="tokens.bin"))
+    # the two layouts are the config's own keys, read apart: a windowed
+    # layer without positions is written as asked
+    odd = models.smallthinker_conf(**dict(SMALLTHINKER,
+                                          rope_layout=(1, 0)))
+    assert odd.index("rotary_dim") < odd.index("  window = 16")
+    with pytest.raises(ValueError, match="one entry a layer each"):
+        models.smallthinker_conf(rope_layout=(0, 1, 1))
+    with pytest.raises(ValueError, match="list of 0 and 1"):
+        models.smallthinker_conf(rope_layout=(0, 2, 1, 1))
+
+
 def _joyai_defaults_also(text, counts):
     assert round(sum(counts.values()) * 16 / 1e9, 2) == 10.89
 
@@ -363,6 +392,22 @@ def _afmoe_defaults_also(text, counts):
     assert "label_width = 16384" in text and "nheld = 8" in text
 
 
+def _smallthinker_defaults_also(text, counts):
+    # q, k, v fused; the output projection; the norm
+    attn = 2560 * (3584 + 2 * 512) + 3584 * 2560 + 2560
+    assert counts["l1_attn0"] == counts["l7_attn3"] == attn
+    assert round(sum(counts.values()) * 16 / 1e9, 2) == 8.95
+    # the guide's floor, 8 held: 370.5M
+    assert sum(counts.values()) - 4 * 8 * 3 * 2560 * 768 == 370_547_200
+    # a full layer without positions, then three rotary ones under the
+    # window; every layer routes on the attention's input
+    assert text.count("  window = 4096\n") == 3 == text.count(
+        "  rope_theta = 1500000.0\n")
+    assert text.index("layer[h1,0->x1]") < text.index("  window = 4096")
+    assert text.count("  route_norm = attn") == 4
+    assert "label_width = 16384" in text and "nheld = 16" in text
+
+
 def _granite_whole_also(tr, params, ids, lab, loss, grads):
     """The softmax layer leaves probabilities in the logits' node, so the
     logits are held through them: the mean of -log p[label] is the
@@ -385,6 +430,16 @@ def _afmoe_whole_also(tr, params, ids, lab, loss, grads):
     assert np.abs(np.asarray(grads["l4_moe1"]["score_bias"])).max() == 0
     for key in ("l1_attn0", "l2_mlp0", "l3_attn1", "l4_moe1"):
         assert np.abs(np.asarray(grads[key]["postnorm"])).max() > 0
+
+
+def _smallthinker_whole_also(tr, params, ids, lab, loss, grads):
+    """A WHOLE layer (nheld = nexpert): the router learns, and its
+    gradient reaches the attention's norm weight and the stream before
+    the attention (the reference's sum of both uses was held above)."""
+    assert 0.9 * np.log(64) < float(loss) < 1.6 * np.log(64)
+    for key in ("l2_moe0", "l4_moe1"):
+        assert np.abs(np.asarray(grads[key]["wgate"])).max() > 0
+    assert np.abs(np.asarray(grads["l1_attn0"]["norm"])).max() > 0
 
 
 FAMILIES = {
@@ -486,4 +541,32 @@ FAMILIES = {
         whole_net=dict(layer_types="sf"), grad_tol=dict(atol=3e-6),
         whole_also=_afmoe_whole_also,
         chunks={"": (dict(), dict(loss=2e-5, w_abs=2e-5, m_abs=2e-6))}),
+    "smallthinker": Family(
+        builder=models.smallthinker_conf, tiny=SMALLTHINKER,
+        reference="smallthinker.py",
+        conf_has={"= attention:": 2, "  window = 16\n": 1,
+                  "  rotary_dim = 16": 1, "= routed_experts:": 2,
+                  "  route_norm = attn": 2, "  expert_act = reglu": 2},
+        conf_lacks=("tied", "iter = tokens", "gated_mlp", "qk_norm",
+                    "shared_hidden", "postnorm"),
+        aux=frozenset({"l1_attn0", "l3_attn1", "l2_moe0", "l4_moe1"}),
+        # 8 steps x 64 tokens x 3 picks x 2 layers, a quarter of them held
+        counts={"expert_pairs": (0.5 * 768, 1.5 * 768),
+                "attn_tokens": (8 * 64 * 2, 8 * 64 * 2)},
+        unmoved=("attn_blocks", "expert_pairs_dropped"),
+        also=_smallthinker_also,
+        defaults={},
+        layers={"l1_attn0": 20_974_080,
+                # router, 16 held experts, the norm
+                "l2_moe0": 64 * 2560 + 16 * 3 * 2560 * 768 + 2560,
+                "l0_embed": 18992 * 2560, "l10_head": 18992 * 2560},
+        total=559_290_880,                        # x 16 B = 8.95 GB
+        defaults_also=_smallthinker_defaults_also,
+        # whole: the router's gradient reaches n1 and the stream before
+        # the attention
+        whole_net=dict(experts_held=16), grad_tol=dict(atol=3e-6),
+        whole_also=_smallthinker_whole_also,
+        chunks={"share": (dict(), dict(loss=2e-5, w_abs=2e-5, m_abs=2e-6)),
+                "whole": (dict(experts_held=16),
+                          dict(loss=2e-5, w_abs=2e-5, m_abs=2e-6))}),
 }

@@ -256,6 +256,20 @@ def test_the_cell_s_table_is_45_steps_for_136():
     assert 1 - live / (150 * 512 ** 2) == pytest.approx(1 / 5, abs=0.01)
 
 
+def test_smallthinker_s_table_is_70_steps_for_136():
+    """T 16384, W 4096, blocks of 1024, seven query heads a key/value
+    head (ISSUE 46): a query block visits at most 5 key blocks, 70 steps
+    for the diagonal's 136 (Trinity's window of 2048: 45)."""
+    fwd, bwd = _steps(16, 16, 1024, 1024, True, 7, 4096)
+    assert len(fwd[0]) == 70 == 1 + 2 + 3 + 4 + 12 * 5
+    assert len(bwd[0]) == 7 * 70
+    assert max(np.bincount(fwd[0])) == 5
+    assert len(_steps(16, 16, 1024, 1024, True, 7)[0][0]) == 136
+    # dead pairs inside the visited blocks: a fifth
+    live = 4096 * 4097 / 2 + (16384 - 4096) * 4096
+    assert 1 - live / (70 * 1024 ** 2) == pytest.approx(1 / 5, abs=0.01)
+
+
 def test_no_window_is_the_table_the_parent_built():
     """``window = 0`` builds the tables ``_steps`` and ``_ranges`` gave
     before they knew a window, and the program of a layer without one is
@@ -314,6 +328,9 @@ def test_block_for_reads_the_cell_s_shape_as_any_other():
     assert block_for(*shapes) == 1024
     with pytest.raises(TypeError):
         block_for(*shapes, window=2048)
+    # SmallThinker's 28 query heads on 4 (group 7): the same block
+    assert block_for(sds((1, 16384, 28, 128), jnp.bfloat16),
+                     *shapes[1:]) == 1024
 
 
 @pytest.mark.parametrize("window", [0, 256])
@@ -444,6 +461,30 @@ def test_a_step_s_class_under_traced_offsets(q_off, k_off, t, tk, bq, bk):
         ok)
 
 
+def test_group_seven_under_the_real_window_is_the_xla_path_s():
+    """SmallThinker's geometry at its REAL window and block: 7 query
+    heads on 1 key/value head, rows of 5120 tokens in three documents
+    (one longer than the window), blocks of 1024, the kernels
+    interpreted, against ``mha``'s row blocks with the document mask:
+    outputs and the three gradients."""
+    t, window = 5120, 4096
+    q, k, v = _qkv(7, 1, d=8, t=t, seed=6, b=1)
+    doc = _docs([[300, 4800]], t)
+    mask = dict(causal=True, doc=doc, window=window)
+    visited, free, one = (int(n) for n in count_blocks(
+        q, k, v, block_q=1024, block_k=1024, **mask))
+    # the diagonal's 15 steps a head; the middle document (300..4799)
+    # is longer than the window, whose edge crosses the last block row
+    assert visited == 7 * 15 and 0 < free < one < visited
+
+    def kern(q, k, v):
+        return flash_attention(q, k, v, block_q=1024, block_k=1024,
+                               interpret=True, **mask)[0]
+
+    _hold(jax.jit(kern), jax.jit(lambda q, k, v: mha(
+        q, k, v, block_q=512, **mask)), q, k, v, 2e-5)
+
+
 def test_the_cells_rows_run_all_three_classes():
     """A row of 16384 tokens in four documents under a window of 2048 and
     blocks of 1024, as ``tools/attn_ab.py`` cuts it: 45 visited steps at
@@ -464,7 +505,10 @@ def test_the_cells_rows_run_all_three_classes():
     (16, 2, 32, 32, 0),         # qwen3_next: 16 / 2
     (32, 4, 16, 16, 40),        # the afmoe family's sliding layers: 32 / 4
     (32, 4, 16, 16, 0),         # ... and its full ones
-], ids=["192x128", "32over8", "16over2", "32over4_window", "32over4"])
+    (28, 4, 16, 16, 40),        # SmallThinker's window layers: group 7
+    (28, 4, 16, 16, 0),         # ... and its position-free full ones
+], ids=["192x128", "32over8", "16over2", "32over4_window", "32over4",
+        "28over4_window", "28over4"])
 @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
                                         (jnp.bfloat16, 3e-2)],
                          ids=["f32", "bf16"])

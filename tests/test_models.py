@@ -50,7 +50,8 @@ def test_model_shapes(name):
               "kaggle_bowl": 121,
               "transformer": 10, "transformer_lm": 256,
               "granite_h": 64, "qwen3_next": 64, "joyai_llm_flash": 64,
-              "nemotron_h": 64, "afmoe": 64, "resnet50": 1000, "resnet101": 1000,
+              "nemotron_h": 64, "afmoe": 64, "smallthinker": 64,
+              "resnet50": 1000, "resnet101": 1000,
               "resnet152": 1000}[name]
     assert out[-1] == expect
     if name in ("resnet101", "resnet152", "vgg19"):
@@ -176,6 +177,11 @@ PARENT_CONF_TEXT = {
     "joyai_llm_flash": ("joyai_llm_flash", dict(
         cell="6fcfc3c618b3970b", rehearsal="b0fb223ded4f0985",
         tail="0a6ce8cf6464fc5e")),
+    # SmallThinker's, as PR 46 (which brought the builder) wrote it: a
+    # later edit to a shared piece of the builders shows here too
+    "smallthinker": ("smallthinker_21b_a3b", dict(
+        cell="395b180fc7918c4e", rehearsal="217efae201c01c02",
+        tail="2793fddd0d477c79")),
 }
 
 

@@ -138,7 +138,7 @@ def test_an_expert_s_activation_and_its_latent_are_two_keys():
     _, p, _ = make("routed_experts", shapes, nexpert=8, topk=2, nhidden=10,
                    latent_hidden=6)
     assert p["wmat"].shape == (8, 6, 20) and p["wproj"].shape == (8, 10, 6)
-    with pytest.raises(ValueError, match="swiglu or relu2"):
+    with pytest.raises(ValueError, match="swiglu, reglu or relu2"):
         make("routed_experts", shapes, nexpert=8, topk=2, nhidden=10,
              expert_act="gelu")
     with pytest.raises(ValueError, match="latent_hidden"):

@@ -21,6 +21,7 @@ Usage:
     python tools/attn_ab.py [--shapes joyai,granite,qwen3_next]
         [--blocks 512x512,1024x512] [--docs 5] [--reps 10] [--no-xla]
         [--tokens 16384 --window 2048]
+        [--shapes smallthinker --tokens 16384 --window 4096]
         [--cpu-rehearsal --tokens 256]
 """
 
@@ -39,6 +40,7 @@ SHAPES = {
     "granite": (32, 8, 64, 64, 0.015625),
     "qwen3_next": (16, 2, 256, 256, None),
     "trinity": (32, 4, 128, 128, None),
+    "smallthinker": (28, 4, 128, 128, None),
 }
 
 
