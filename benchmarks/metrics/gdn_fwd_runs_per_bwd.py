@@ -1,0 +1,59 @@
+"""How often the delta rule's two forward kernels run for one run of its
+backward: the operations of the traced round's ``XLA Ops`` whose name
+ends ``gdn_solve/pallas_call`` plus those ending ``gdn_scan/pallas_call``,
+over twice those ending ``gdn_scan_bwd/pallas_call`` (``ops/gdn_fused.py``:
+one ``gdn_scan_bwd`` a ``gated_deltanet`` layer's backward pass).  2.0
+where a layer's ``remat`` recompute runs both kernels a second time only
+to rebuild what the backward reads; 1.5 where the net's ``jax.checkpoint``
+policy keeps ``solve``'s ``T``, ``W`` and ``U0`` (PR 47); 1.0 where it
+keeps ``scan``'s output and entering states too — and 2.0 again the day
+a change to ``nnet/net.py`` or to jax loses the policy.  Counted as
+``attn_fwd_runs_per_bwd`` counts: the DISTINCT operations of the step
+program that ran (an event's HLO name), not events and not time, on the
+first chip (``lib/scopes.device_events``); a name is the event's
+``tf_op`` without its ``:<type>``.  ``None`` without a trace, or where
+the trace holds none of the kernels (every cell but qwen3_next's; the
+``jax.numpy`` form of the rule)."""
+
+import glob
+import os
+
+from benchmarks.lib import scopes, tracered
+
+LAYER = "layers and kernels"
+UNIT = "x"
+SOURCE = "device_trace"
+MOVES = "train_samples_s_chip"
+
+FWD = ("gdn_solve/pallas_call", "gdn_scan/pallas_call")
+BWD = "gdn_scan_bwd/pallas_call"
+
+
+def runs_per_bwd(events):
+    """``events``: (HLO instruction, duration in ns, ``tf_op`` or None)
+    of one chip's ``XLA Ops``."""
+    ops = {end: set() for end in FWD + (BWD,)}
+    for hlo, dur, scope in events:
+        if dur <= 0 or scope is None:
+            continue
+        name = scope.split(":")[0]
+        for end, seen in ops.items():
+            if name.endswith(end):
+                seen.add(hlo.split(" = ")[0])
+    fwd, bwd = sum(len(ops[end]) for end in FWD), len(ops[BWD])
+    return fwd / (len(FWD) * bwd) if fwd and bwd else None
+
+
+def read(run):
+    t = run.get("trace")
+    out = scopes.run_dir(run)
+    if not t or not t.get("steps") or out is None:
+        return None
+    dirs = sorted(glob.glob(os.path.join(out, "trace_round*")))
+    if not dirs:
+        return None
+    try:
+        return runs_per_bwd(scopes.device_events(
+            tracered.find_xplane(dirs[-1])))
+    except (FileNotFoundError, OSError, ValueError):
+        return None

@@ -33,17 +33,19 @@ import jax.numpy as jnp
 
 from ..layers import Layer, LossLayer, create_layer
 from ..layers.structure import SplitLayer
-from ..ops.flash import KEPT_NAMES
+from ..ops import flash, gdn_fused
 from .graph import NetGraph
 
 ConfigEntry = Tuple[str, str]
 
 #: what a layer's ``remat`` keeps across the backward pass: the values its
 #: kernels NAME as their own outputs that the backward reads again (the
-#: flash forward's ``o`` and ``lse``), so the recompute does not run those
-#: kernels a second time.  A layer that names nothing keeps nothing and
-#: lowers to the program ``policy=None`` gives
-REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+#: flash forward's ``o`` and ``lse``; what the delta rule's ``solve`` and
+#: ``scan`` wrote), so the recompute does not run those kernels a second
+#: time.  A layer that names nothing keeps nothing and lowers to the
+#: program ``policy=None`` gives
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *flash.KEPT_NAMES, *gdn_fused.KEPT_NAMES)
 _remat = functools.partial(jax.checkpoint, policy=REMAT_POLICY)
 
 

@@ -31,6 +31,15 @@ kernels behind one ``jax.custom_vjp``:
   ``dA = -T^T dT T^T = -(T^T dU) U^T`` under the strict lower mask, so
   no ``dT`` is formed.
 
+What ``solve`` and ``scan`` write is NAMED where the forward rule returns
+it (``KEPT_NAMES``, ``checkpoint_name``), so a ``jax.checkpoint`` whose
+policy saves the names (the net's ``remat``: ``nnet/net.REMAT_POLICY``)
+keeps it across the backward pass and its recompute runs neither kernel
+again: 0.6 GB a layer at 8192 tokens and 32 heads (``T`` is held as the
+kernel wrote it, its 64 columns on 128 lanes: 134 of them; its numbers
+alone would cost a relayout copy each way), for 5.0 ms of a layer's 14
+(PERF.md, PR 47).
+
 A grid step takes a stretch of ``STRETCH`` chunks (an inner loop) for
 the value heads of ONE key head: ``q``/``k`` are read by the block's
 index map at ``j // (Hv / Hk)``, never repeated in HBM, and their
@@ -55,6 +64,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 #: the chunk the kernels are written for (a quarter of the MXU's side)
 CHUNK = 64
@@ -518,9 +528,23 @@ def _rule(q, k, v, aux, dims, interpret):
     return _rule_fwd(q, k, v, aux, dims, interpret)[0]
 
 
+#: the names (``jax.ad_checkpoint.checkpoint_name``) of what the two
+#: forward kernels wrote — ``solve``'s ``T``, ``W`` and ``U0``, ``scan``'s
+#: ``o`` and the states that entered each chunk: a ``jax.checkpoint`` whose
+#: policy saves them (``nnet/net.REMAT_POLICY``) keeps the five across the
+#: backward pass and its recompute runs neither kernel a second time
+_SOLVED = ("gdn_tinv", "gdn_w", "gdn_u0")
+_SCANNED = ("gdn_o", "gdn_states")
+KEPT_NAMES = _SOLVED + _SCANNED
+
+
 def _rule_fwd(q, k, v, aux, dims, interpret):
-    tinv, w, u0 = _solve(k, v, aux, dims, interpret)
-    o, states = _scan(q, k, w, u0, aux, dims, interpret)
+    # the named values are the primal output, what ``scan`` reads AND the
+    # residuals, so every reader reads what a policy may keep
+    tinv, w, u0 = map(checkpoint_name, _solve(k, v, aux, dims, interpret),
+                      _SOLVED)
+    o, states = map(checkpoint_name,
+                    _scan(q, k, w, u0, aux, dims, interpret), _SCANNED)
     return o, (q, k, v, aux, tinv, w, u0, states)
 
 

@@ -31,7 +31,7 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
 #: the entries of ``per_layer`` from PR 32's on, in the order their PRs
 #: appended them: PR 33's ten, PR 34's one, PR 36's four, PR 37's one,
 #: PR 38's five, PR 39's one, PR 40's three, PR 41's one, PR 42's four,
-#: PR 43's one, PR 44's one
+#: PR 43's one, PR 44's one, PR 46's one, PR 47's one
 METRICS_FROM_30 = [
     "chunk_overlap_pct", "gdn_mixer_ms_step", "gdn_scan_ms_step",
     "gdn_scan_roofline_pct", "moe_ms_step", "moe_route_dispatch_ms_step",
@@ -45,7 +45,7 @@ METRICS_FROM_30 = [
     "ssd_scan_fused_pct", "attn_window_core_ms_step",
     "attn_full_core_ms_step", "attn_window_pairs_pct",
     "attn_core_roofline_pct", "attn_unmasked_blocks_pct",
-    "attn_fwd_runs_per_bwd"]
+    "attn_fwd_runs_per_bwd", "moe_route_ms_step", "gdn_fwd_runs_per_bwd"]
 
 
 def load():
@@ -137,7 +137,7 @@ def qwen3_next_cell(bench):
 
 def nemotron_h_cell(bench):
     """What PR 40 left: the cell, its configuration, its three metrics
-    and the shared ones it is listed under; the entries of PRs 41-44 go
+    and the shared ones it is listed under; the entries of PRs 41-47 go
     behind its three, in ``METRICS_FROM_30``'s order."""
     from benchmarks.tests import test_nemotron_h as n
 
@@ -164,8 +164,9 @@ def nemotron_h_cell(bench):
         assert n.CELL not in by_name[name]["workloads"]
     listed = [m["name"] for m in bench["per_layer"]]
     at = listed.index(n.NEW_METRICS[0])
-    assert listed[at:at + 3] == n.NEW_METRICS == METRICS_FROM_30[-10:-7]
-    assert listed[at:at + 10] == METRICS_FROM_30[-10:]
+    since = METRICS_FROM_30[METRICS_FROM_30.index(n.NEW_METRICS[0]):]
+    assert listed[at:at + 3] == n.NEW_METRICS == since[:3]
+    assert listed[at:at + len(since)] == since
     assert by_name["attn_unmasked_blocks_pct"]["workloads"][3] == n.CELL
 
 

@@ -228,8 +228,10 @@ def test_attn_fwd_runs_per_bwd_counts_the_two_kernels_events(events, want):
                              ("%g", 5, "x/flash_dq/pallas_call:")]) is None
 
 
-def test_attn_fwd_runs_per_bwd_reads_nothing_without_a_trace(tmp_path):
-    read = run.load_metric("attn_fwd_runs_per_bwd").read
+@pytest.mark.parametrize("name", ["attn_fwd_runs_per_bwd",
+                                  "gdn_fwd_runs_per_bwd"])
+def test_a_runs_per_bwd_reader_reads_nothing_without_a_trace(tmp_path, name):
+    read = run.load_metric(name).read
     out = str(tmp_path)
     assert read({"out": out, "trace": None}) is None
     assert read({"out": out, "trace": {"steps": 0}}) is None
@@ -238,8 +240,8 @@ def test_attn_fwd_runs_per_bwd_reads_nothing_without_a_trace(tmp_path):
     where = os.path.join(out, "trace_round1", "plugins", "profile", "x")
     os.makedirs(where)
     assert read({"out": out, "trace": {"steps": 16}}) is None
-    # a v5e's own trace of a net with no attention in it: events, scopes
-    # with their colons, and neither kernel
+    # a v5e's own trace of a conv net: events, scopes with their colons,
+    # and none of the reader's kernels
     from benchmarks.lib import scopes
     fixture = os.path.join(ROOT, "benchmarks", "fixtures",
                            "scopes_v5e.xplane.pb")
@@ -247,6 +249,76 @@ def test_attn_fwd_runs_per_bwd_reads_nothing_without_a_trace(tmp_path):
     assert any(s and s.endswith(":") for _, _, s in
                scopes.device_events(fixture))
     assert read({"out": out, "trace": {"steps": 16}}) is None
+
+
+# gdn_fwd_runs_per_bwd (PR 47): the delta rule's two forward kernels'
+# operations over twice the backward's, counted the same way
+def _gdn_events(solve, scan, bwd=1, layers=("l1_gdn0", "l3_gdn1", "l5_gdn2")):
+    """(HLO instruction, ns, ``tf_op``) of ONE step of a net whose
+    ``gated_deltanet`` ``layers`` each run ``gdn_solve`` ``solve`` times,
+    ``gdn_scan`` ``scan`` times and ``gdn_scan_bwd`` ``bwd`` times — the
+    first run of a forward kernel in the forward pass, the second in the
+    layer's ``remat`` recompute — and the events a step has around them
+    (the kernels' scope paths are a compile's for a described v5e)."""
+    body = "jit(step)/while/body/closed_call/"
+    events = [("%fusion.1", 4000, body + "jvp(l1_gdn0)/in_proj/dot_general:"),
+              ("%while.1", 99999, "jit(step)/while:"),
+              ("%ragged-dot-none", 9000, "ragged-dot-none:"),
+              ("%copy.1", 100, None)]
+    kern = "scan/cond/branch_0_fun/{}/pallas_call:"
+    for lay in layers:
+        back = f"{body}transpose(jvp({lay}))/jvp({lay})/checkpoint/"
+        where = (f"{body}jvp({lay})/", back + f"rematted_computation/{lay}/")
+        for name, n in (("gdn_solve", solve), ("gdn_scan", scan)):
+            events += [(f"%{name}.{lay}{i} = (f32[1,32,8192,64]{{3,2,1,0}}) "
+                        "custom-call(bf16[1,8192,2048]{2,1,0} %p)", 3900,
+                        where[i] + kern.format(name)) for i in range(n)]
+        events += [(f"%gdn_scan_bwd.{lay}{i}", 4100,
+                    back + f"{lay}/" + kern.format("gdn_scan_bwd"))
+                   for i in range(bwd)]
+    return events
+
+
+@pytest.mark.parametrize("events, want", [
+    # forward and the remat recompute run both kernels: the parent
+    (_gdn_events(2, 2), 2.0),
+    # the net's policy keeps T, W and U0: the recompute runs the scan alone
+    (_gdn_events(1, 2), 1.5),
+    # it keeps o and the entering states too: neither kernel runs again
+    (_gdn_events(1, 1), 1.0),
+    (_gdn_events(1, 2, layers=("l1_gdn0",)), 1.5),
+    # the operations of the program are counted, not their events: a
+    # traced round of three steps that begins inside one (its backward
+    # alone) and ends inside another (its forward alone)
+    ([e for e in _gdn_events(2, 2) if "gdn_scan_bwd" in e[0]]
+     + 3 * _gdn_events(2, 2)
+     + [e for e in _gdn_events(2, 2) if "gdn_scan_bwd" not in e[0]], 2.0),
+    (_gdn_events(1, 2)[4:] + 3 * _gdn_events(1, 2), 1.5),
+    # a kernel that never ran on the device is not counted
+    (_gdn_events(1, 1) + [("%gdn_solve.9", 0, "jit(step)/gdn_solve/"
+                           "pallas_call:")], 1.0),
+    # a trace that states the type, and one that has no colon at all
+    ([("%a", 5, "jit(f)/gdn_solve/pallas_call:custom-call"),
+      ("%b", 5, "jit(f)/gdn_scan/pallas_call"),
+      ("%c", 5, "jit(f)/gdn_scan_bwd/pallas_call")], 1.0),
+    # the jax.numpy form of the rule, another family's net: no kernel
+    (_gdn_events(0, 0, 0), None),
+    (_kernel_events(2), None),
+    # a forward alone (nothing differentiated): no backward to count by
+    (_gdn_events(1, 1, 0), None),
+    ([], None),
+])
+def test_gdn_fwd_runs_per_bwd_counts_the_three_kernels_events(events, want):
+    mod = run.load_metric("gdn_fwd_runs_per_bwd")
+    assert mod.runs_per_bwd(events) == want
+    # the backward's name does not end as the forward scan's does, and the
+    # same kernels inside another op's name are not the kernels
+    assert mod.runs_per_bwd([("%f", 5, "x/gdn_scan_bwd/pallas_call:")]) is None
+    assert mod.runs_per_bwd([("%f", 5, "x/gdn_scan/pallas_call/copy:"),
+                             ("%g", 5, "x/gdn_scan_bwd/pallas_call:")]) is None
+    # and the flash kernels' reader sees none of these
+    assert run.load_metric("attn_fwd_runs_per_bwd").runs_per_bwd(
+        _gdn_events(2, 2)) is None
 
 
 # the round loop's bill of the device's time from its own fences (PR 38):
@@ -360,6 +432,7 @@ from bench_shadows import ALL_CELLS, LOOP_BILL  # noqa: E402
     ("attn_flash_pct", ALL_CELLS[2:], "higher"),
     ("attn_unmasked_blocks_pct", ALL_CELLS[2:], "higher"),
     ("attn_fwd_runs_per_bwd", ALL_CELLS[2:], "lower"),
+    ("gdn_fwd_runs_per_bwd", ALL_CELLS[3:4], "lower"),
     ("expert_dispatch_compact_pct", ALL_CELLS[3:], "higher"),
 ] + [(name, ALL_CELLS, "lower") for name in LOOP_BILL])
 def test_benchmark_json_names_the_reader_that_exists(name, cells, better):

@@ -10,6 +10,7 @@ published defaults) is a row of ``tests/families.py``.
 """
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,7 @@ from cxxnet_tpu.ops.attention import doc_positions, rotary
 from cxxnet_tpu.ops.gdn import (gated_delta_recurrence, gated_delta_scan,
                                 gated_delta_scan_counted, gated_delta_xla,
                                 unit_rows)
-from cxxnet_tpu.ops.gdn_fused import gated_delta_fused, supported
+from cxxnet_tpu.ops.gdn_fused import KEPT_NAMES, gated_delta_fused, supported
 from cxxnet_tpu.ops.ssd import doc_index
 from cxxnet_tpu.utils.profiler import pipeline_stats
 
@@ -213,6 +214,84 @@ def test_the_platform_and_the_shapes_choose_the_path_and_say_so():
         jax.jit(lambda *a: gated_delta_scan_counted(*a, doc)),
         platforms=["tpu"])(*xs)
     assert "tpu_custom_call" in exported.mlir_module()
+
+
+def _mixer_shaped():
+    """A mixer's shape of work around the kernels — ``q``, ``k``, ``v``,
+    the decay and ``beta`` projected from one ``x``, two value heads on
+    one key head under three documents, something non-linear on ``o``,
+    an output projection and a residual — as ``layer(p, x)`` and
+    ``loss(run)(p, x)`` for a ``run`` that wraps the layer
+    (``jax.checkpoint`` or nothing)."""
+    n, t, hk, hv, d, width = 1, 128, 1, 2, 128, 32
+    rng = np.random.RandomState(7)
+    mk = lambda *s: jnp.asarray(0.2 * rng.randn(*s), jnp.float32)  # noqa
+    params = dict(wq=mk(width, hk * d), wk=mk(width, hk * d),
+                  wv=mk(width, hv * d), wg=mk(width, hv), wb=mk(width, hv),
+                  wo=mk(hv * d, width))
+    x = 3 * mk(n, t, width)
+    doc = jnp.asarray(np.repeat([0, 1, 2], [40, 50, 38])[None], jnp.int32)
+
+    def layer(p, x):
+        q = (x @ p["wq"]).reshape(n, t, hk, d)
+        k = (x @ p["wk"]).reshape(n, t, hk, d)
+        v = (x @ p["wv"]).reshape(n, t, hv, d)
+        o = gated_delta_fused(
+            q, k, v, -jax.nn.softplus(x @ p["wg"]),
+            jax.nn.sigmoid(x @ p["wb"]), doc, unit=1e-6,
+            q_scale=1 / math.sqrt(d), interpret=True)
+        return jnp.tanh(o.reshape(n, t, hv * d)) @ p["wo"] + x
+
+    def loss(run):
+        return jax.jit(jax.grad(
+            lambda p, x: (run(layer)(p, x) ** 2).sum(), (0, 1)))
+
+    return layer, loss, params, x
+
+
+def test_a_layer_s_remat_keeps_what_the_forward_kernels_made():
+    """Under the net's policy a checkpointed layer keeps what the forward
+    rule NAMES (``gdn_fused.KEPT_NAMES``): its gradients are those of a
+    plain ``jax.checkpoint`` and of no checkpoint at all BIT FOR BIT (a
+    kept value is the value the recompute would have made), and the
+    program of the gradient runs ``gdn_solve`` and ``gdn_scan`` once
+    where a plain ``jax.checkpoint`` runs each twice (forward and
+    recompute); the backward kernel once either way."""
+    from cxxnet_tpu.nnet.net import REMAT_POLICY
+
+    _, loss, params, x = _mixer_shaped()
+    grads = {
+        "plain": loss(lambda f: f),
+        "kept": loss(lambda f: jax.checkpoint(f, policy=REMAT_POLICY)),
+        "recomputed": loss(jax.checkpoint),
+    }
+    want = jax.tree_util.tree_leaves(grads["recomputed"](params, x))
+    assert all(np.abs(np.asarray(w)).max() > 0 for w in want)
+    for name in ("kept", "plain"):
+        got = jax.tree_util.tree_leaves(grads[name](params, x))
+        for a, r in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(r),
+                                          err_msg=name)
+    runs = {name: {kern: len(re.findall(rf"name={kern}\b", str(
+        jax.make_jaxpr(g)(params, x)))) for kern in
+        ("gdn_solve", "gdn_scan", "gdn_scan_bwd")}
+        for name, g in grads.items()}
+    once = {"gdn_solve": 1, "gdn_scan": 1, "gdn_scan_bwd": 1}
+    assert runs == {"plain": once, "kept": once,
+                    "recomputed": dict(once, gdn_solve=2, gdn_scan=2)}, runs
+
+
+def test_the_names_a_checkpointed_mixer_offers_are_the_kept_names():
+    """Read from the jaxpr of the checkpointed function, not from a copy
+    of the tuple: what ``_rule_fwd`` names is what ``KEPT_NAMES`` lists,
+    each once (that the net's policy saves them is the test above)."""
+    from cxxnet_tpu.nnet.net import REMAT_POLICY
+
+    layer, _, params, x = _mixer_shaped()
+    text = str(jax.make_jaxpr(jax.checkpoint(layer, policy=REMAT_POLICY))(
+        params, x))
+    assert tuple(re.findall(r"name\[name=(\w+)\]", text)) == KEPT_NAMES
+    assert len(set(KEPT_NAMES)) == len(KEPT_NAMES)
 
 
 def test_gated_deltanet_layer_shapes_and_document_reset():

@@ -15,6 +15,9 @@ qwen3_next's published widths, compiled for a DESCRIBED v5e chip
   ``ops/gdn_fused.py`` (PR 34): three Mosaic custom calls under the
   caller's ``scan`` scope, the backward's too, no whole-row chunk
   matrices, and less scratch than the segmented form with no segments;
+  under the net's ``remat`` policy a layer runs each of the three ONCE
+  (PR 47: what ``gdn_solve`` and ``gdn_scan`` wrote is kept across the
+  backward pass);
 * lowered for a TPU, masked attention IS the flash kernels of
   ``ops/flash.py`` (PR 37) at qwen3_next's head shapes, ONE call of each
   a layer (PR 44: the net's ``remat`` policy keeps ``o`` and ``lse``).
@@ -130,6 +133,38 @@ def test_the_delta_rule_lowered_for_a_tpu_is_the_fused_kernels(one_chip):
     assert "f32[1,32,8192,64]" in text and "f32[1,32,128,128,128]" in text
     # and the whole backward needs less scratch than the segmented form
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9 < 1.6e9
+
+
+@pytest.mark.parametrize("checkpoint, want", [
+    # the net's policy keeps what ``gdn_solve`` and ``gdn_scan`` wrote:
+    # the recompute runs neither
+    ({}, {"gdn_solve": 1, "gdn_scan": 1, "gdn_scan_bwd": 1}),
+    # the control, a ``jax.checkpoint`` that keeps nothing: the parent's
+    # program, every chunk solved twice
+    ({"policy": None}, {"gdn_solve": 2, "gdn_scan": 2, "gdn_scan_bwd": 1}),
+], ids=["the_net_s_policy", "no_policy"])
+def test_a_layer_s_remat_runs_the_forward_kernels_once(one_chip, checkpoint,
+                                                       want):
+    """One ``gated_deltanet`` layer at the published widths (a row of
+    8192 tokens, 16 key and 32 value heads of 128, bfloat16) under
+    ``remat`` as the step programs run it."""
+    compiled = v5e.compile_layer(
+        one_chip, "gated_deltanet",
+        dict(nkhead=16, nvhead=32, key_dim=128, value_dim=128, conv_width=4,
+             chunk=64, prenorm=1, residual_scale=1.0),
+        [(1, 8192, 2048), (1, 8192)], "l1_gdn0", **checkpoint)
+    calls = v5e.mosaic_calls(compiled.as_text())
+    runs = {k: [c for c in calls if c.endswith(f"/{k}/pallas_call")]
+            for k in want}
+    assert {k: len(v) for k, v in runs.items()} == want, calls
+    assert all("l1_gdn0" in c and "/scan/" in c for c in calls), calls
+    again = [c.split("/")[-2] for c in calls if "rematted_computation" in c]
+    assert again == [k for k in ("gdn_solve", "gdn_scan") if want[k] == 2]
+    (bwd,) = runs["gdn_scan_bwd"]
+    assert "transpose(" in bwd and "rematted_computation" not in bwd
+    # one layer's kept values are alive between its forward and its
+    # backward either way: 1.60 GB of temporaries under both policies
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.7e9
 
 
 @pytest.mark.parametrize("cfg", [

@@ -57,13 +57,16 @@ def step_that_fits(text, parameters, limit):
     return text
 
 
-def compile_layer(one_chip, kind, cfg, shapes, scope):
+def compile_layer(one_chip, kind, cfg, shapes, scope, **checkpoint):
     """Value and gradient (weights and input) of one layer of ``kind``
-    under the named ``scope`` and the net's ``remat`` policy, bfloat16,
-    compiled for the chip from shapes alone; ``shapes[1]``, where there
-    is one, is the row's ids."""
+    under the named ``scope`` and the net's ``remat`` policy (or the
+    ``policy`` given: ``None`` keeps nothing), bfloat16, compiled for the
+    chip from shapes alone; ``shapes[1]``, where there is one, is the
+    row's ids."""
     from cxxnet_tpu.layers import create_layer
     from cxxnet_tpu.nnet.net import REMAT_POLICY
+
+    checkpoint.setdefault("policy", REMAT_POLICY)
 
     lay = create_layer(kind)
     for k, v in cfg.items():
@@ -78,7 +81,7 @@ def compile_layer(one_chip, kind, cfg, shapes, scope):
             with jax.named_scope(scope):
                 (y,), new = lay.apply_stateful(p, aux, [x, ids])
             return jnp.sum(y.astype(jnp.float32)), new
-        return jax.checkpoint(run, policy=REMAT_POLICY)(p, x)
+        return jax.checkpoint(run, **checkpoint)(p, x)
 
     on_chip = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda v: shaped(one_chip, v.shape, v.dtype), t)
