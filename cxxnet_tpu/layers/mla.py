@@ -62,14 +62,17 @@ round by ``NetTrainer.count_layer_state``, as ``gated_deltanet``'s):
 of them the flash kernels computed, which the branch that ran says for
 itself; ``attn_blocks`` / ``attn_blocks_unmasked`` — the blocks the
 forward kernel then visits over all heads, and those of them whose
-every pair may attend.  uint32, wrapping; the round's counters of the
-same names sum them over the layers (``attention``'s masked path counts
-into the same four).
+every pair may attend; ``attn_tokens_bwd_fused`` — the tokens whose
+backward is the one kernel.  uint32, wrapping; the round's counters of
+the same names sum them over the layers (``attention``'s masked path
+counts into the same five).
 
 Scopes inside the layer's: ``q_proj``, ``kv_proj``, ``rotary``, ``core``
-(scores, mask, softmax, values — forward, recomputed and backward; on a
-TPU the kernels ``flash_fwd``, ``flash_dq``, ``flash_dkv`` and the
-layout changes around them), ``out_proj``.
+(scores, mask, softmax, values — forward and backward; on a TPU the
+kernels ``flash_fwd`` and ``flash_bwd``, once each a layer a step — or
+``flash_dq`` + ``flash_dkv`` where a row does not fit the one kernel's
+VMEM budget, ``ops/flash.py`` — and the layout changes around them),
+``out_proj``.
 """
 
 from __future__ import annotations

@@ -71,9 +71,12 @@ its own inside the layer's, ``core_window`` on a windowed layer and
 it, and ``attn_tokens_flash``, those of them the kernels computed — the
 branch that ran says so for itself — and, where they did,
 ``attn_blocks``, the (query block, key block) steps the forward kernel
-computed over all heads, and ``attn_blocks_unmasked``, those of them
+computed over all heads, ``attn_blocks_unmasked``, those of them
 whose every pair may attend (``ops/flash.count_blocks``, from the
-tables the kernels read).
+tables the kernels read), and ``attn_tokens_bwd_fused``, the tokens
+whose backward is the ONE kernel ``flash_bwd`` and not ``flash_dq`` +
+``flash_dkv`` (``ops/flash.one_backward``, the kernels' own choice from
+the shapes).
 """
 
 from __future__ import annotations
@@ -89,31 +92,34 @@ from .base import Layer, Params, Shape, register
 #: the masked attention layers' ``aux`` state (``attention``'s masked
 #: path, ``latent_attention``), and the round's counters they add to
 ATTN_COUNTERS = ("attn_tokens", "attn_tokens_flash", "attn_blocks",
-                 "attn_blocks_unmasked")
+                 "attn_blocks_unmasked", "attn_tokens_bwd_fused")
 
 
 def attend_counted(scope, q, k, v, *, causal=False, scale=None, doc=None,
                    window=0):
     """``ops/attention.attend`` under the scope ``scope`` -> ``(o,
-    ran)``, ``ran`` uint32 ``(3,)``: 1 where the flash kernels computed
-    it, and then the blocks their forward visits and those of them whose
-    every pair may attend (``ops/flash.count_blocks``; 0 where ``mha``
-    ran)."""
+    ran)``, ``ran`` uint32 ``(4,)``: 1 where the flash kernels computed
+    it, and then the blocks their forward visits, those of them whose
+    every pair may attend (``ops/flash.count_blocks``) and 1 where their
+    backward is the one kernel (``ops/flash.one_backward``); 0 where
+    ``mha`` ran."""
     from ..ops.attention import attend
-    from ..ops.flash import count_blocks
+    from ..ops.flash import count_blocks, one_backward
 
     with jax.named_scope(scope):
         o, flash = attend(q, k, v, causal=causal, scale=scale, doc=doc,
                           window=window)
     blocks = count_blocks(q, k, v, causal=causal, doc=doc, window=window)
-    return o, jnp.concatenate([flash[None], blocks[:2] * flash])
+    one = jnp.uint32(one_backward(q, k, v))
+    return o, jnp.concatenate([flash[None], blocks[:2] * flash,
+                               (one * flash)[None]])
 
 
 def count_attention(aux, x, ran):
     """``aux`` after ``x (N, T, D)`` went through attention, ``ran`` as
     ``attend_counted`` gives it (uint32, wrapping)."""
     tokens = jnp.uint32(x.shape[0] * x.shape[1])
-    add = (tokens, tokens * ran[0], ran[1], ran[2])
+    add = (tokens, tokens * ran[0], ran[1], ran[2], tokens * ran[3])
     return {name: aux[name] + n for name, n in zip(ATTN_COUNTERS, add)}
 
 

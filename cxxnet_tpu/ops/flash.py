@@ -9,20 +9,46 @@ blockwise scores with an online (log-sum-exp) softmax — entirely in
 VMEM: scores never touch HBM, and memory is O(T) instead of O(T^2).
 
 The backward pass is the flash recomputation scheme: the forward saves
-``(q, k, v, o)`` and the per-row LSE (``m + log l``); two backward
-kernels re-derive the probability blocks from (q, k, lse) and accumulate
+``(q, k, v, o)`` and the per-row LSE (``m + log l``); the backward
+re-derives the probability blocks from (q, k, lse) and accumulates
 
 * ``dq_i  = sum_j  [p_ij * (do_i . v_j - delta_i)] k_j * scale``
 * ``dk_j  = sum_i  [p_ij * (do_i . v_j - delta_i)] q_i * scale``
 * ``dv_j  = sum_i  p_ij^T do_i``
 
-with ``delta_i = sum_d dO_id O_id`` computed once in XLA.  The ``dk`` /
-``dv`` kernel works on the transposed block (keys along the sublanes),
-so every product is a plain ``a @ b`` or ``a @ b^T`` and the per-query
-scalars arrive as rows.
+with ``delta_i = sum_d dO_id O_id`` computed once in XLA.  It has two
+forms, one algorithm, and ``_Geometry`` chooses between them from the
+call's shapes alone (``_Geometry.one``, ``_one_fits``; no conf key, no
+environment variable):
 
-What the three kernels (``flash_fwd``, ``flash_dq``, ``flash_dkv``) take,
-behind ONE ``jax.custom_vjp`` (``_flash``):
+* **ONE kernel, ``flash_bwd``** (PR 48) — ``s``, ``p``, ``dp`` and
+  ``ds`` of a (query block, key block) pair derived ONCE and all three
+  gradients taken from them: five products and one ``exp`` a live pair.
+  ``dq`` sums over key blocks and ``dk`` / ``dv`` over query blocks and
+  the group's query heads, so one sweep cannot keep both in block-sized
+  accumulators: the grid is ``(key-value heads, steps)``, the steps the
+  forward's table once a query head of the group (query-major), ``dq``
+  in a ``(block, Dqk)`` float32 accumulator written at a query block's
+  last step, ``dk`` and ``dv`` for the key-value head's WHOLE row in
+  float32 VMEM, indexed by the step's key block and written once a head.
+  No new HBM buffer, no read-modify-write.  The tile is the transposed
+  one (keys along the sublanes, the per-query scalars rows), so four of
+  the five products are a plain ``a @ b`` or ``a @ b^T`` and one,
+  ``ds^T k``, contracts over the tile's first axis.  It runs where the
+  row's accumulators and the two buffers of each output fit
+  ``_ROW_VMEM``: every token cell's shapes (16384 x (128 + 128), 8192 x
+  (256 + 256), 8192 x (192 + 128) in bfloat16).
+* **two kernels, ``flash_dq`` and ``flash_dkv``**, for a row past that
+  budget: each re-derives the same ``s``, ``p``, ``dp`` and ``ds`` —
+  three products and an ``exp`` for ``dq`` on the forward's grid, four
+  and an ``exp`` for ``dk`` / ``dv`` on the transposed block, the key
+  block resident — seven products and two ``exp`` a live pair, in
+  block-sized accumulators whatever the row's length.
+
+Both forms keep the same live pairs and the same float32 statistics.
+
+What the kernels (``flash_fwd``; ``flash_bwd`` or ``flash_dq`` +
+``flash_dkv``) take, behind ONE ``jax.custom_vjp`` (``_flash``):
 
 * **a document mask**: ``doc (B, T)``, the non-decreasing document
   index of ``ops/ssd.doc_index``; a query sees the keys of its own
@@ -43,7 +69,7 @@ behind ONE ``jax.custom_vjp`` (``_flash``):
   products, not its softmax (PERF.md, PR 43), so the pairs an edge leaves
   dead are worth leaving out: a step's body is chosen from its scalars
   (``_tiles``) — each half of one block (the queries' in the forward,
-  the keys' in ``dq`` and ``dkv``) runs against the halves of the other
+  the keys' in the backward's kernels) runs against the halves of the other
   it can see, as one tile, and a half wholly above the diagonal or
   beyond the window is in no tile.  A quarter of such a block's
   products, its ``exp`` and its passes are not done; a block no edge
@@ -71,7 +97,7 @@ behind ONE ``jax.custom_vjp`` (``_flash``):
   causal edge and the documents', so the reach stays ONE range (its
   ``lo`` raised, ``qhi`` lowered) and the static step table leaves out
   the (query block, key block) pairs that lie wholly beyond the window,
-  forward, ``dq`` and ``dkv`` alike: at T 16384, window 2048 and blocks
+  forward and backward alike: at T 16384, window 2048 and blocks
   of 1024 a query block visits at most 3 key blocks, 45 steps for 136.
   Positions are the row's own, from 0 on both sides: a window with
   position offsets (``flash_mha_lse``, ring hops) is refused, as a
@@ -83,8 +109,8 @@ behind ONE ``jax.custom_vjp`` (``_flash``):
   ``jax.checkpoint`` whose policy saves those names — the net's, one a
   conf layer under ``remat = 1`` (``nnet/net.REMAT_POLICY``) — keeps
   them across the backward pass and its recompute rebuilds ``q``, ``k``
-  and ``v`` but runs no second ``flash_fwd``: one forward, one ``dq``,
-  one ``dkv`` a layer a step.  A layer then holds ``o`` as the kernel
+  and ``v`` but runs no second ``flash_fwd``: one forward and one
+  backward kernel a layer a step.  A layer then holds ``o`` as the kernel
   wrote it (``(B H, T, Dv)`` in the operands' dtype) and ``lse`` as its
   numbers, ``(B H, T)`` float32 — the kernel's ``(B H, T, 1)`` column
   is tiled to 128 lanes in HBM, so the column is dropped before the
@@ -148,9 +174,34 @@ _LANES = 128
 
 _NN = (((1,), (0,)), ((), ()))    # a @ b
 _NT = (((1,), (1,)), ((), ()))    # a @ b^T
+_TN = (((0,), (0,)), ((), ()))    # a^T @ b
 
 #: bits of a step's flag: the first / the last step of its output block
 _FIRST, _LAST = 1, 2
+
+#: a grid's form, ``(keys, kv)``.  ``keys``: the key block is the resident
+#: one and the query blocks sweep past it (else the query block, and the
+#: key blocks sweep).  ``kv``: the grid's first axis is the key-value
+#: heads, the group's query heads part of the sweep, and the tile is
+#: transposed — keys along the sublanes, the per-query scalars rows (else
+#: the query heads, the scalars columns).  The forward's and ``dq``'s, the
+#: ``dk``/``dv`` kernel's, the one backward kernel's
+_BY_Q, _BY_K, _ONE = (False, False), (True, True), (False, True)
+
+#: what the ONE backward kernel may hold in VMEM of a key-value head's
+#: whole row: ``dk`` and ``dv`` in float32 accumulators and, as they leave
+#: in the operands' dtype, the two buffers of each — ``Tk (Dqk + Dv) (4 + 2
+#: itemsize)`` bytes, 32 MiB in bfloat16 at 16384 x (128 + 128) and at 8192
+#: x (256 + 256), 20 at 8192 x (192 + 128).  Beside the ~20 MiB of blocks
+#: and tiles every kernel here takes, under ``_VMEM_LIMIT`` with room; a
+#: row of 32768 or float32 operands at 16384 are past it and run the two
+#: kernels
+_ROW_VMEM = 40 * 1024 * 1024
+
+
+def _one_fits(tk: int, dqk: int, dv: int, dtype) -> bool:
+    return (tk * (dqk + dv) * (4 + 2 * jnp.dtype(dtype).itemsize)
+            <= _ROW_VMEM)
 
 
 def _dot(a, b, dims):
@@ -349,6 +400,62 @@ def _dkv_kernel(offs, ik_t, iq_t, fl_t, lo_t, hi_t, g_t, *refs,
         dv_out[0] = vacc[:].astype(dv_out.dtype)
 
 
+def _bwd_kernel(offs, iq_t, ik_t, fl_t, lo_t, hi_t, g_t, *refs,
+                bq, bk, n, tb, causal, window, masked, scale):
+    """``dq``, ``dk`` and ``dv`` from ONE derivation of a tile's ``s``,
+    ``p``, ``dp`` and ``ds``: ``_dkv_kernel``'s transposed block (keys
+    along the sublanes) under ``_dq_kernel``'s sweep (a query block with
+    its key blocks, for each query head of the key-value head's group).
+    ``dq`` sums over the sweep's inner axis into a block-sized
+    accumulator as there; ``dk`` and ``dv`` sum over the outer two, so
+    their accumulators hold the key-value head's WHOLE row, indexed by
+    the step's key block, and leave once a head.  Five products a tile
+    for the two kernels' seven, one ``exp`` for two; ``ds^T k`` contracts
+    over the tile's first axis."""
+    from jax.experimental import pallas as pl
+
+    ((q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref), lo_q, hi_q,
+     (dq_out, dk_out, dv_out, qacc, kacc, vacc)) = _split(refs, 6, masked)
+    s_i = pl.program_id(1)
+    iq, ik, fl = iq_t[s_i], ik_t[s_i], fl_t[s_i]
+    row = (pl.program_id(0) // tb) * n + iq if tb else iq
+
+    @pl.when(s_i == 0)
+    def _init_row():
+        kacc[:] = jnp.zeros_like(kacc)
+        vacc[:] = jnp.zeros_like(vacc)
+
+    @pl.when((fl & _FIRST) != 0)
+    def _init():
+        qacc[:] = jnp.zeros_like(qacc)
+
+    def tile(qs, ks):
+        qb, dob, kb = q_ref[0, qs, :], do_ref[0, qs, :], k_ref[0, ks, :]
+        rows = pl.ds(pl.multiple_of(ik * bk, bk) + ks.start, ks.size)
+        s = _dot(kb, qb, _NT) * scale
+        p = jnp.exp(s - lse_ref[0, :, qs])
+        if masked:
+            p = jnp.where(_may_attend(ik * bk + ks.start, ks.size, 0,
+                                      lo_q[0, :, qs], hi_q[0, :, qs]), p, 0.0)
+        vacc[rows, :] += _dot(p.astype(dob.dtype), dob, _NN)
+        dp = _dot(v_ref[0, ks, :], dob, _NT)
+        ds = (p * (dp - dl_ref[0, :, qs])).astype(qb.dtype)
+        kacc[rows, :] += _dot(ds, qb, _NN)
+        qacc[qs, :] += _dot(ds, kb, _TN)
+
+    _tiles(tile, (ik >= lo_t[row]) & (ik <= hi_t[row]), offs, iq, ik, bq, bk,
+           causal, window, True)
+
+    @pl.when((fl & _LAST) != 0)
+    def _done():
+        dq_out[0] = (qacc[:] * scale).astype(dq_out.dtype)
+
+    @pl.when(s_i == pl.num_programs(1) - 1)
+    def _done_row():
+        dk_out[0] = (kacc[:] * scale).astype(dk_out.dtype)
+        dv_out[0] = vacc[:].astype(dv_out.dtype)
+
+
 def _pick_block(t: int, want: int) -> int:
     b = min(want, t)
     while t % b:
@@ -480,8 +587,9 @@ def _offs(q_off, k_off):
 
 
 class _Geometry:
-    """What the three calls share: block counts, the head grouping, the
-    step tables and the live ranges, and the block specs over them."""
+    """What a call's kernels share: block counts, the head grouping, the
+    step tables and the live ranges, the block specs over them by a
+    grid's form, and which form the backward takes (``one``)."""
 
     def __init__(self, q, k, v, doc, q_off, k_off, causal, scale, bq, bk,
                  heads, window=0):
@@ -505,11 +613,21 @@ class _Geometry:
         self.ranges = _ranges(doc, self.offs, self.nq, self.nk, bq, bk,
                               causal, window)
         #: heads of the grid's first axis that one row of ``ranges``
-        #: serves, forward and backward (0: the one row serves all)
+        #: serves, query heads and key-value heads (0: the one row serves
+        #: all)
         self.tb = ((heads, heads // self.group) if self.has_doc else (0, 0))
-        self.fwd_t, self.bwd_t = _steps(
+        self.fwd_t, bwd_t = _steps(
             self.nq, self.nk, bq, bk,
             causal and not dyn and self.t == self.tk, self.group, window)
+        #: the backward is ONE kernel where a key-value head's whole row
+        #: of ``dk`` and ``dv`` fits its share of VMEM, else two
+        self.one = _one_fits(self.tk, self.dqk, self.dv, v.dtype)
+        # the one kernel's: the forward's sweep once a query head of the
+        # group
+        iq, ik, fl, _ = self.fwd_t
+        self.tables = {_BY_Q: self.fwd_t, _BY_K: bwd_t, _ONE: (
+            *(np.tile(x, self.group) for x in (iq, ik, fl)),
+            np.repeat(np.arange(self.group, dtype=np.int32), len(iq)))}
         #: nothing masks a call that is not causal and has no window and
         #: no documents: no bounds, no operands
         self.masked = bool(causal or window or self.has_doc)
@@ -518,10 +636,11 @@ class _Geometry:
             self.bounds = _bounds(doc, self.offs, self.t, self.tk, causal,
                                   window)
 
-    def kernel(self, fn, bwd: bool):
+    def kernel(self, fn, form):
+        keys, kv = form
         return functools.partial(
-            fn, bq=self.bq, bk=self.bk, tb=self.tb[bwd],
-            n=self.nk if bwd else self.nq, causal=self.causal,
+            fn, bq=self.bq, bk=self.bk, tb=self.tb[kv],
+            n=self.nk if keys else self.nq, causal=self.causal,
             window=self.window, masked=self.masked, scale=self.scale)
 
     def classes(self):
@@ -552,18 +671,21 @@ class _Geometry:
             full = full & (q0 + (self.bq - 1) - k0 < self.window)
         return live, live & full, live & one
 
-    def specs(self, bwd: bool):
+    def specs(self, form):
         """``(q-side spec of a width, k-side spec of a width, the spec of a
         query block's per-row scalars, [the documents' two specs])`` for
-        the forward and ``dq`` grids (heads of q, the query block
-        resident; the scalars are ``offs, iq_t, ik_t, fl_t, lo, hi, g_t``)
-        or the ``dk``/``dv`` grid (heads of k, the key block resident;
-        ``offs, ik_t, iq_t, fl_t, qlo, qhi, g_t``).  The block that moves
-        is clamped into the resident block's live range."""
+        a grid of ``form``: the forward's and ``dq``'s (heads of q, the
+        query block resident; the scalars are ``offs, iq_t, ik_t, fl_t,
+        lo, hi, g_t``), the ``dk``/``dv`` grid (heads of k, the key block
+        resident; ``offs, ik_t, iq_t, fl_t, qlo, qhi, g_t``) or the one
+        backward kernel's (heads of k, the query block resident: the
+        first's scalars, the second's heads and rows).  The block that
+        moves is clamped into the resident block's live range."""
         from jax.experimental import pallas as pl
 
-        g, tb = self.group, self.tb[bwd]
-        n = self.nk if bwd else self.nq
+        keys, kv = form
+        g, tb = self.group, self.tb[kv]
+        n = self.nk if keys else self.nq
 
         def moving(b, s, offs, own_t, other_t, fl_t, lo, hi, g_t):
             r = (b // tb) * n + own_t[s] if tb else own_t[s]
@@ -572,8 +694,8 @@ class _Geometry:
         def resident(b, s, offs, own_t, *_):
             return own_t[s]
 
-        q_blk, k_blk = (moving, resident) if bwd else (resident, moving)
-        if bwd:
+        q_blk, k_blk = (moving, resident) if keys else (resident, moving)
+        if kv:
             q_head = lambda b, s, *sc: b * g + sc[-1][s]
             k_head = lambda b, s, *sc: b
         else:
@@ -594,7 +716,7 @@ class _Geometry:
         # a query block's per-row scalars (its two bounds: one row of
         # them a table row): a column beside the resident query block, a
         # row beside the transposed one
-        if bwd:
+        if kv:
             qrow = spec((1, 1, self.bq), q_head, q_blk, 2)
             bound = spec((1, 1, self.bq), row_b, q_blk, 2)
         else:
@@ -602,26 +724,27 @@ class _Geometry:
             bound = spec((1, self.bq, 1), row_b, q_blk, 1)
         return qs, ks, qrow, ([bound, bound] if self.masked else [])
 
-    def bound_operands(self, bwd: bool):
+    def bound_operands(self, form):
         """The queries' two bounds as ``specs`` reads them."""
         if not self.masked:
             return ()
-        return tuple(x[:, None, :] if bwd else x[:, :, None]
+        return tuple(x[:, None, :] if form[1] else x[:, :, None]
                      for x in self.bounds)
 
-    def call(self, kern, bwd, in_specs, out_specs, out_shape, scratch,
+    def call(self, kern, form, in_specs, out_specs, out_shape, scratch,
              operands, name, interpret):
         from jax.experimental import pallas as pl
         from jax.experimental.pallas import tpu as pltpu
 
-        tabs = self.bwd_t if bwd else self.fwd_t
-        scalars = (self.offs, *tabs[:3], *self.ranges[2 * bwd:2 * bwd + 2],
-                   tabs[3])
+        keys, kv = form
+        tabs = self.tables[form]
+        scalars = (self.offs, *tabs[:3],
+                   *self.ranges[2 * keys:2 * keys + 2], tabs[3])
         return pl.pallas_call(
-            self.kernel(kern, bwd),
+            self.kernel(kern, form),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(scalars),
-                grid=(self.bhk if bwd else self.bh, len(tabs[0])),
+                grid=(self.bhk if kv else self.bh, len(tabs[0])),
                 in_specs=in_specs, out_specs=out_specs,
                 scratch_shapes=scratch),
             out_shape=out_shape,
@@ -638,10 +761,10 @@ def _flash_fwd_raw(q, k, v, geo: _Geometry, interpret):
     without a relayout."""
     from jax.experimental.pallas import tpu as pltpu
 
-    qs, ks, qrow, bounds = geo.specs(False)
+    qs, ks, qrow, bounds = geo.specs(_BY_Q)
     bq, dv = geo.bq, geo.dv
     return geo.call(
-        _fwd_kernel, False,
+        _fwd_kernel, _BY_Q,
         [qs(geo.dqk), ks(geo.dqk), ks(dv), *bounds],
         [qs(dv), qrow],
         [jax.ShapeDtypeStruct((geo.bh, geo.t, dv), q.dtype),
@@ -649,39 +772,76 @@ def _flash_fwd_raw(q, k, v, geo: _Geometry, interpret):
         [pltpu.VMEM((bq, dv), jnp.float32),
          pltpu.VMEM((bq, 128), jnp.float32),
          pltpu.VMEM((bq, 128), jnp.float32)],
-        (q, k, v, *geo.bound_operands(False)), "flash_fwd", interpret)
+        (q, k, v, *geo.bound_operands(_BY_Q)), "flash_fwd", interpret)
 
 
 def _flash_bwd_raw(q, k, v, do, lse, dl, geo: _Geometry, interpret):
     """``lse`` and ``dl`` (``delta``, less a cotangent of ``lse``) as the
-    forward's ``(BH, T, 1)`` columns."""
+    forward's ``(BH, T, 1)`` columns -> ``(dq, dk, dv)``, by the one
+    kernel where ``geo.one`` says a row fits, else by the two."""
+    if geo.one:
+        return _bwd_one(q, k, v, do, lse, dl, geo, interpret)
+    return (_bwd_dq(q, k, v, do, lse, dl, geo, interpret),
+            *_bwd_dkv(q, k, v, do, lse, dl, geo, interpret))
+
+
+def _bwd_dq(q, k, v, do, lse, dl, geo: _Geometry, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
-    bq, bk, dqk, dv = geo.bq, geo.bk, geo.dqk, geo.dv
-    qs, ks, qrow, bounds = geo.specs(False)
-    dq = geo.call(
-        _dq_kernel, False,
+    dqk, dv = geo.dqk, geo.dv
+    qs, ks, qrow, bounds = geo.specs(_BY_Q)
+    return geo.call(
+        _dq_kernel, _BY_Q,
         [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *bounds],
         qs(dqk), jax.ShapeDtypeStruct(q.shape, q.dtype),
-        [pltpu.VMEM((bq, dqk), jnp.float32)],
-        (q, k, v, do, lse, dl, *geo.bound_operands(False)), "flash_dq",
+        [pltpu.VMEM((geo.bq, dqk), jnp.float32)],
+        (q, k, v, do, lse, dl, *geo.bound_operands(_BY_Q)), "flash_dq",
         interpret)
 
-    # the key block is the resident operand; the query heads of its
-    # group and their query blocks sweep innermost
-    qs, ks, qrow, bounds = geo.specs(True)
+
+def _bwd_dkv(q, k, v, do, lse, dl, geo: _Geometry, interpret):
+    """The key block is the resident operand; the query heads of its
+    group and their query blocks sweep innermost."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    bk, dqk, dv = geo.bk, geo.dqk, geo.dv
+    qs, ks, qrow, bounds = geo.specs(_BY_K)
     as_rows = lambda x: x.reshape(geo.bh, 1, geo.t)
-    dk, dv_ = geo.call(
-        _dkv_kernel, True,
+    return geo.call(
+        _dkv_kernel, _BY_K,
         [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *bounds],
         [ks(dqk), ks(dv)],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
         [pltpu.VMEM((bk, dqk), jnp.float32),
          pltpu.VMEM((bk, dv), jnp.float32)],
-        (q, k, v, do, as_rows(lse), as_rows(dl), *geo.bound_operands(True)),
+        (q, k, v, do, as_rows(lse), as_rows(dl), *geo.bound_operands(_BY_K)),
         "flash_dkv", interpret)
-    return dq, dk, dv_
+
+
+def _bwd_one(q, k, v, do, lse, dl, geo: _Geometry, interpret):
+    """The query block is the resident operand, under the group's query
+    heads; ``dk`` and ``dv`` leave as a key-value head's whole row."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    dqk, dv = geo.dqk, geo.dv
+    qs, ks, qrow, bounds = geo.specs(_ONE)
+    row = lambda width: pl.BlockSpec((1, geo.tk, width),
+                                     lambda b, s, *sc: (b, 0, 0))
+    as_rows = lambda x: x.reshape(geo.bh, 1, geo.t)
+    return geo.call(
+        _bwd_kernel, _ONE,
+        [qs(dqk), ks(dqk), ks(dv), qs(dv), qrow, qrow, *bounds],
+        [qs(dqk), row(dqk), row(dv)],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((geo.bq, dqk), jnp.float32),
+         pltpu.VMEM((geo.tk, dqk), jnp.float32),
+         pltpu.VMEM((geo.tk, dv), jnp.float32)],
+        (q, k, v, do, as_rows(lse), as_rows(dl), *geo.bound_operands(_ONE)),
+        "flash_bwd", interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 13)))
@@ -715,7 +875,7 @@ def _forward(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _backward(q, k, v, doc, q_off, k_off, out, lse, g, g_lse, causal, scale,
               bq, bk, heads, interpret, window):
-    """The two backward calls, shared the same way."""
+    """The backward's call — or its two — shared the same way."""
     geo = _Geometry(q, k, v, doc, q_off, k_off, causal, scale, bq, bk, heads,
                     window)
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(
@@ -776,6 +936,17 @@ def block_for(q, k, v):
           and q.dtype == k.dtype == v.dtype
           and v.dtype in (jnp.bfloat16, jnp.float32))
     return block if ok else None
+
+
+def one_backward(q, k, v) -> bool:
+    """Whether the backward of ``flash_attention(q, k, v, ...)`` at
+    ``block_for``'s blocks — as ``ops/attention.attend`` calls the
+    kernels — is the ONE kernel ``flash_bwd`` (``_Geometry.one``: a
+    key-value head's whole row of ``dk`` and ``dv`` fits its share of
+    VMEM) and not ``flash_dq`` + ``flash_dkv``; ``False`` where
+    ``block_for`` has no block (``mha`` computes such a call)."""
+    return block_for(q, k, v) is not None and _one_fits(
+        k.shape[1], q.shape[-1], v.shape[-1], v.dtype)
 
 
 def count_blocks(q, k, v, *, causal: bool = False, doc=None, window: int = 0,

@@ -31,7 +31,7 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
 #: the entries of ``per_layer`` from PR 32's on, in the order their PRs
 #: appended them: PR 33's ten, PR 34's one, PR 36's four, PR 37's one,
 #: PR 38's five, PR 39's one, PR 40's three, PR 41's one, PR 42's four,
-#: PR 43's one, PR 44's one, PR 46's one, PR 47's one
+#: PR 43's one, PR 44's one, PR 46's one, PR 47's one, PR 48's one
 METRICS_FROM_30 = [
     "chunk_overlap_pct", "gdn_mixer_ms_step", "gdn_scan_ms_step",
     "gdn_scan_roofline_pct", "moe_ms_step", "moe_route_dispatch_ms_step",
@@ -45,7 +45,8 @@ METRICS_FROM_30 = [
     "ssd_scan_fused_pct", "attn_window_core_ms_step",
     "attn_full_core_ms_step", "attn_window_pairs_pct",
     "attn_core_roofline_pct", "attn_unmasked_blocks_pct",
-    "attn_fwd_runs_per_bwd", "moe_route_ms_step", "gdn_fwd_runs_per_bwd"]
+    "attn_fwd_runs_per_bwd", "moe_route_ms_step", "gdn_fwd_runs_per_bwd",
+    "attn_bwd_fused_pct"]
 
 
 def load():

@@ -44,6 +44,33 @@ def split(request, monkeypatch):
 
     if request.param:
         monkeypatch.setattr(flash, "_LANES", 8)
+        _retraced(flash, request)
+    return request.param
+
+
+def _retraced(flash, request):
+    """The kernels' two jitted calls keep their traces by shapes and
+    settings, not by the module's constants: a test that patches one
+    starts and ends with none kept."""
+    def drop():
+        flash._forward.clear_cache()
+        flash._backward.clear_cache()
+    drop()
+    request.addfinalizer(drop)
+
+
+@pytest.fixture
+def two_kernels(request, monkeypatch):
+    """The backward of the flash kernels is ONE kernel where a key-value
+    head's row of ``dk`` and ``dv`` fits its VMEM budget
+    (``ops/flash._ROW_VMEM``); ``two_kernels = True`` (an indirect
+    parameter) leaves it no room, so ``flash_dq`` + ``flash_dkv`` run —
+    as for a row too long to fit."""
+    from cxxnet_tpu.ops import flash
+
+    if request.param:
+        monkeypatch.setattr(flash, "_ROW_VMEM", 0)
+        _retraced(flash, request)
     return request.param
 
 

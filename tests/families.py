@@ -300,7 +300,8 @@ SMALLTHINKER = dict(vocab=64, seq_len=64, hidden=32,
                     expert_hidden=24, experts_held=4, dev="cpu",
                     compute_dtype="float32", scan_steps=4)
 
-FLASH_COUNTERS = ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked")
+FLASH_COUNTERS = ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked",
+                  "attn_tokens_bwd_fused")
 
 
 def _joyai_also(text):

@@ -301,12 +301,13 @@ def test_the_layer_says_what_a_window_does_not_go_with():
     lay, p, _ = make("attention", [(1, 16, 32)], nhead=4, causal=1, window=8)
     assert not lay._plain(1) and set(lay.init_aux([(1, 16, 32)])) == {
         "attn_tokens", "attn_tokens_flash", "attn_blocks",
-        "attn_blocks_unmasked"}
+        "attn_blocks_unmasked", "attn_tokens_bwd_fused"}
     x = jnp.asarray(np.random.RandomState(2).randn(1, 16, 32), jnp.float32)
     (y,), aux = lay.apply_stateful(p, lay.init_aux([(1, 16, 32)]), [x])
     assert int(aux["attn_tokens"]) == 16 and int(
         aux["attn_tokens_flash"]) == 0 and int(aux["attn_blocks"]) == int(
-            aux["attn_blocks_unmasked"]) == 0
+            aux["attn_blocks_unmasked"]) == int(
+                aux["attn_tokens_bwd_fused"]) == 0
     # and names its scope for the trace's readers
     hlo = jax.jit(lambda a: lay.apply(p, [a])[0]).lower(x).as_text(
         debug_info=True)

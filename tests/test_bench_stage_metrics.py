@@ -110,6 +110,25 @@ COUNTER_READERS = {
         ([{}], None),
         ([], None),
     ],
+    # attn_bwd_fused_pct (PR 48): the same layers count the tokens whose
+    # backward ran as the one kernel ``flash_bwd``
+    "attn_bwd_fused_pct": [
+        # Trinity's five layers x 24 steps x 16384 tokens, every one fused
+        ([{"attn_tokens": 1966080, "attn_tokens_flash": 1966080,
+           "attn_tokens_bwd_fused": 1966080, "attn_blocks": 180000}] * 2,
+         100.0),
+        # a round whose programs were lowered for another platform
+        ([{"attn_tokens": 196608, "attn_tokens_bwd_fused": 196608},
+          {"attn_tokens": 196608}], 50.0),
+        # a row past the one kernel's VMEM budget: the two kernels ran
+        ([{"attn_tokens": 196608, "attn_tokens_flash": 196608,
+           "attn_tokens_bwd_fused": 0}], 0.0),
+        # the parent counts tokens and flash tokens, and not these
+        ([{"attn_tokens": 196608, "attn_tokens_flash": 196608}], None),
+        ([{"attn_tokens": 0, "attn_tokens_bwd_fused": 0}], None),
+        ([{}], None),
+        ([], None),
+    ],
     # attn_unmasked_blocks_pct (PR 43): the same layers count the blocks the
     # flash kernels' forward visits and those of them whose every pair may
     # attend
@@ -430,6 +449,7 @@ from bench_shadows import ALL_CELLS, LOOP_BILL  # noqa: E402
     ("attn_window_pairs_pct", ALL_CELLS[6:], "lower"),
     ("attn_core_roofline_pct", ALL_CELLS[6:], "higher"),
     ("attn_flash_pct", ALL_CELLS[2:], "higher"),
+    ("attn_bwd_fused_pct", ALL_CELLS[2:], "higher"),
     ("attn_unmasked_blocks_pct", ALL_CELLS[2:], "higher"),
     ("attn_fwd_runs_per_bwd", ALL_CELLS[2:], "lower"),
     ("gdn_fwd_runs_per_bwd", ALL_CELLS[3:4], "lower"),

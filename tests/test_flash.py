@@ -596,7 +596,132 @@ def test_a_layer_s_remat_keeps_the_kernels_outputs(with_lse):
                                        rtol=2e-5, atol=2e-5, err_msg=name)
     runs = {name: {kern: len(re.findall(rf"name={kern}\b", str(
         jax.make_jaxpr(g)(params, x)))) for kern in
-        ("flash_fwd", "flash_dq", "flash_dkv")} for name, g in grads.items()}
-    once = {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+        ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkv")}
+        for name, g in grads.items()}
+    once = {"flash_fwd": 1, "flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
     assert runs == {"plain": once, "kept": once,
                     "recomputed": dict(once, flash_fwd=2)}, runs
+
+
+# ------------------------------------- the ONE backward kernel (PR 48)
+#: rows of 64 tokens (two a case) under blocks of 16: ``heads`` is (query
+#: heads, key-value heads, q.k width, v width); ``cuts`` where each row's
+#: documents end (none: no document mask)
+ONE_BACKWARD = {
+    "causal": dict(heads=(2, 2, 16, 16)),
+    "documents_not_causal": dict(heads=(2, 2, 16, 16), causal=False,
+                                 cuts=[[16, 48], [32]]),
+    "a_window_s_sliding_layer": dict(heads=(4, 2, 16, 16), window=24,
+                                     cuts=[[5, 16, 40], [63]]),
+    "the_full_layer_beside_it": dict(heads=(4, 2, 16, 16),
+                                     cuts=[[5, 16, 40], [63]]),
+    "the_diagonal_s_blocks_in_parts": dict(heads=(4, 2, 16, 16), window=24,
+                                           cuts=[[5, 16, 40], [63]],
+                                           split=True),
+    "grouped_32_over_4": dict(heads=(32, 4, 16, 16), cuts=[[20], [3, 60]]),
+    "grouped_28_over_4": dict(heads=(28, 4, 16, 16), window=32,
+                              cuts=[[20], [3, 60]]),
+    "grouped_16_over_2": dict(heads=(16, 2, 32, 32), cuts=[[56], []]),
+    "widths_192_and_128": dict(heads=(2, 2, 192, 128), scale=0.11,
+                               cuts=[[5, 40], [27]]),
+    "a_cotangent_of_lse": dict(heads=(4, 2, 16, 16), lse=True, scale=0.2,
+                               cuts=[[5, 16, 40], [63]]),
+    "a_boundary_inside_every_block": dict(
+        heads=(2, 1, 16, 16), cuts=[[5, 21, 37, 53], [9, 27, 43, 62]]),
+    "unequal_blocks": dict(heads=(2, 1, 24, 16), blocks=(16, 32),
+                           cuts=[[1, 2, 3], [62, 63]]),
+    "bfloat16": dict(heads=(4, 2, 16, 16), dtype=jnp.bfloat16, tol=3e-2,
+                     cuts=[[5, 16, 40], [63]]),
+    "a_row_past_the_budget": dict(heads=(4, 2, 16, 16), window=24, lse=True,
+                                  cuts=[[5, 16, 40], [63]], fits=False),
+}
+
+
+def _o_and_lse(q, k, v, doc, causal, scale, window):
+    """``mha``'s output and, from the dense masked scores, the
+    log-sum-exp a query row ``(B, T, H)`` — the golden model of
+    ``flash_attention``'s two outputs."""
+    t, g = q.shape[1], q.shape[2] // k.shape[2]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                   jnp.repeat(k, g, axis=2).astype(jnp.float32)) * scale
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    ok = jnp.ones((1, t, t), bool)
+    if causal:
+        ok = ok & (j <= i)
+    if window:
+        ok = ok & (i - j < window)
+    if doc is not None:
+        ok = ok & (doc[:, :, None] == doc[:, None, :])
+    lse = jax.nn.logsumexp(jnp.where(ok[:, None], s, -1e30), axis=-1)
+    return (mha(q, k, v, causal=causal, scale=scale, doc=doc, window=window),
+            lse.transpose(0, 2, 1))
+
+
+@pytest.mark.parametrize("case, split, two_kernels", [
+    (name, c.get("split", False), not c.get("fits", True))
+    for name, c in sorted(ONE_BACKWARD.items())],
+    indirect=["split", "two_kernels"],
+    ids=sorted(ONE_BACKWARD))
+def test_the_one_backward_kernel_is_the_two_kernels_and_mha_s(
+        case, split, two_kernels):
+    """``flash_bwd`` — ``dq``, ``dk`` and ``dv`` from one derivation of a
+    tile's scores — gives the gradients ``flash_dq`` + ``flash_dkv`` give
+    on the same residuals and the golden model's; ``flash_attention``
+    runs it where a key-value head's row fits the kernel's VMEM budget,
+    and the two kernels, with the same gradients, where it does not."""
+    from cxxnet_tpu.ops import flash
+
+    c = ONE_BACKWARD[case]
+    h, hk, dqk, dv = c["heads"]
+    dtype, tol = c.get("dtype", jnp.float32), c.get("tol", 2e-5)
+    causal, window = c.get("causal", True), c.get("window", 0)
+    scale = c.get("scale", dqk ** -0.5)
+    bq, bk = c.get("blocks", (16, 16))
+    q, k, v = _masked(h, hk, dqk, dv, dtype=dtype, seed=len(case))
+    doc = _docs(c["cuts"], 64) if "cuts" in c else None
+    rng = np.random.RandomState(1)
+    cts = (jnp.asarray(rng.randn(2, 64, h, dv), dtype),
+           jnp.asarray(rng.randn(2, 64, h) * bool(c.get("lse")),
+                       jnp.float32))
+
+    def kern(q, k, v):
+        return flash.flash_attention(
+            q, k, v, causal=causal, scale=scale, doc=doc, window=window,
+            block_q=bq, block_k=bk, interpret=True)
+
+    def close(got, want, tol, what):
+        for name, a, r in zip("qkv", got, want):
+            assert a.shape == r.shape and a.dtype == r.dtype, (what, name)
+            a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+            assert np.isfinite(a).all() and np.abs(r).max() > 0, (what, name)
+            assert np.abs(a - r).max() <= tol * np.abs(r).max(), (what, name)
+
+    # what ``flash_attention`` runs, by the shapes and the budget alone
+    out, vjp = jax.vjp(kern, q, k, v)
+    ran = set(re.findall(r"name=(flash_(?:bwd|dq|dkv))\b",
+                         str(jax.make_jaxpr(vjp)(cts))))
+    assert ran == ({"flash_dq", "flash_dkv"} if two_kernels
+                   else {"flash_bwd"})
+    got = vjp(cts)
+    # ... against the golden model's gradients
+    _, ref_vjp = jax.vjp(lambda *a: _o_and_lse(
+        *a, doc, causal, scale, window), q, k, v)
+    close(got, ref_vjp(cts), tol, "mha")
+    # ... and the one kernel against the two, on the forward's own
+    # residuals (folded as ``_backward`` folds them)
+    qf, kf, vf, g = (flash._fold(x) for x in (q, k, v, cts[0]))
+    o, lse = flash._forward(qf, kf, vf, doc, None, None, causal=causal,
+                            scale=scale, bq=bq, bk=bk, heads=h,
+                            interpret=True, window=window)
+    dl = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        -1, keepdims=True) - flash._fold(cts[1][..., None])
+    geo = flash._Geometry(qf, kf, vf, doc, None, None, causal, scale, bq, bk,
+                          h, window)
+    assert geo.one != two_kernels
+    a = (qf, kf, vf, g, lse, dl)
+    two = (flash._bwd_dq(*a, geo, True), *flash._bwd_dkv(*a, geo, True))
+    one = flash._bwd_one(*a, geo, True)
+    close(one, two, 1e-6 if dtype == jnp.float32 else 1e-2, "two kernels")
+    unfold = lambda x, n: flash._unfold(x, 2, n)  # noqa: E731
+    close([unfold(one[0], h), unfold(one[1], hk), unfold(one[2], hk)], got,
+          1e-6 if dtype == jnp.float32 else 1e-2, "flash_attention")

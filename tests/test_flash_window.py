@@ -335,9 +335,9 @@ def test_block_for_reads_the_cell_s_shape_as_any_other():
 
 @pytest.mark.parametrize("window", [0, 256])
 def test_attend_lowers_the_windowed_kernels_for_a_tpu(window):
-    """Lowered for a TPU a long windowed row is the three kernels (the
-    Pallas -> Mosaic lowering runs here); on the CPU it is ``mha`` and
-    the flag says so."""
+    """Lowered for a TPU a long windowed row is the two kernels, forward
+    and backward (the Pallas -> Mosaic lowering runs here); on the CPU it
+    is ``mha`` and the flag says so."""
     q, k, v = _qkv(4, 2, d=64, t=1024, dtype=jnp.bfloat16, b=1)
     doc = _docs([[300, 700]], 1024)
 
@@ -348,7 +348,9 @@ def test_attend_lowers_the_windowed_kernels_for_a_tpu(window):
     text = jax.export.export(
         jax.jit(jax.grad(f, (0, 1, 2), has_aux=True)),
         platforms=["tpu"])(q, k, v, doc).mlir_module()
-    assert text.count("tpu_custom_call") >= 3
+    assert all(f"{name}/pallas_call" in text
+               for name in ("flash_fwd", "flash_bwd"))
+    assert text.count("tpu_custom_call") >= 2
     (_, flash) = jax.jit(f)(q, k, v, doc)
     assert int(flash) == 0
     o, _ = attend(q, k, v, causal=True, doc=doc, window=window)

@@ -135,10 +135,12 @@ def test_the_attention_layer_counts_its_tokens_into_the_round(counted):
     tr, got = counted
     (key,) = [k for k, v in tr.aux.items() if "attn_tokens" in v]
     assert set(tr.aux[key]) == {"attn_tokens", "attn_tokens_flash",
-                                "attn_blocks", "attn_blocks_unmasked"}
+                                "attn_blocks", "attn_blocks_unmasked",
+                                "attn_tokens_bwd_fused"}
     # 4 steps x 2 rows x 48 tokens, one attention layer
     assert got["attn_tokens"] == 4 * 2 * 48
-    for name in ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked"):
+    for name in ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked",
+                 "attn_tokens_bwd_fused"):
         assert got.get(name, 0) == 0
 
 

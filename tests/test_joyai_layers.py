@@ -315,7 +315,7 @@ def test_masked_attention_counts_its_tokens_and_none_flash_on_a_cpu(
     aux = lay.init_aux(shapes)
     assert set(aux) == set(lay.aux_counters) == {
         "attn_tokens", "attn_tokens_flash", "attn_blocks",
-        "attn_blocks_unmasked"}
+        "attn_blocks_unmasked", "attn_tokens_bwd_fused"}
     assert all(v.dtype == jnp.uint32 and v.shape == () for v in aux.values())
     r = np.random.RandomState(0)
     ins = [jnp.asarray(r.randn(*shapes[0]), jnp.float32)]
@@ -332,7 +332,8 @@ def test_masked_attention_counts_its_tokens_and_none_flash_on_a_cpu(
         assert int(aux["attn_tokens"]) == n * 2 * 16
         assert int(aux["attn_tokens_flash"]) == 0
         assert int(aux["attn_blocks"]) == int(
-            aux["attn_blocks_unmasked"]) == 0
+            aux["attn_blocks_unmasked"]) == int(
+                aux["attn_tokens_bwd_fused"]) == 0
 
 
 def test_the_plain_attention_layer_keeps_no_counter():

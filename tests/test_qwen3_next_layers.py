@@ -586,9 +586,11 @@ def test_a_cpu_run_counts_the_scan_s_tokens_and_none_fused():
         "gdn_scan_tokens_fused", 0)
     # and the gated attention layer's (its masked path), likewise
     assert set(tr.aux["l3_attn1"]) == {"attn_tokens", "attn_tokens_flash",
-                                       "attn_blocks", "attn_blocks_unmasked"}
+                                       "attn_blocks", "attn_blocks_unmasked",
+                                       "attn_tokens_bwd_fused"}
     assert got["attn_tokens"] - before.get("attn_tokens", 0) == 256
-    for name in ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked"):
+    for name in ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked",
+                 "attn_tokens_bwd_fused"):
         assert got.get(name, 0) == before.get(name, 0)
     tr.count_layer_state()
     assert stats.counters()["gdn_scan_tokens"] == got["gdn_scan_tokens"]

@@ -11,9 +11,10 @@ v5e chip (``tests/v5e.py``).
   than the parent's 15.06 GB: 14.65.
 * lowered for a TPU, masked attention IS the flash kernels of
   ``ops/flash.py`` (PR 37) under an ``attention`` layer's own scope at
-  granite's head shapes, ONE call of each a layer (since PR 44 the net's
-  ``remat`` policy keeps the forward's ``o`` and ``lse``, so the
-  recompute runs no second ``flash_fwd``), and no float32
+  granite's head shapes, ONE ``flash_fwd`` and ONE ``flash_bwd`` a layer
+  (since PR 44 the net's ``remat`` policy keeps the forward's ``o`` and
+  ``lse``, so the recompute runs no second ``flash_fwd``; since PR 48
+  the backward is one kernel), and no float32
   ``(…, 512, <= 8192)`` score block of ``mha``'s is left.
 """
 
@@ -43,10 +44,12 @@ def test_the_granite_step_holds_no_more_than_the_parent_s(one_chip):
     assert v5e.live_at_peak_bytes(compiled) <= 15.06e9
     calls = v5e.mosaic_calls(compiled.as_text())
     # nine mixers x (forward, recompute, backward) and the attention
-    # layer's three flash kernels
+    # layer's two flash kernels (``flash_fwd``, ``flash_bwd``: PR 48)
     assert sum("/ssd_scan/" in c for c in calls) == 18
     assert sum("/ssd_scan_bwd/" in c for c in calls) == 9
-    assert len(calls) == 30
+    assert len(calls) == 29
+    assert sorted(c.split("/")[-2] for c in calls if "flash" in c) == [
+        "flash_bwd", "flash_fwd"]
     assert all("/scan/" in c for c in calls if "ssd_scan" in c)
 
 

@@ -39,11 +39,12 @@ def test_the_joyai_step_fits_a_chip_with_sixteen_held_experts(one_chip):
     assert re.search(r"f32\[16,2048,1536\]\{2,1,0", text)
     assert not re.search(r"f32\[16,(?:2048,1536|768,2048)\]\{1,2,0", text)
     # PR 37: every latent layer's core is Mosaic calls, all billed to its
-    # core scope — since PR 44 three of them (forward, dq, dk/dv: the
-    # remat recompute reads the kept o and lse and runs no forward);
+    # core scope — since PR 44 one forward a layer (the remat recompute
+    # reads the kept o and lse and runs none), since PR 48 one backward
+    # (``flash_bwd`` at 192 / 128: no ``flash_dq`` + ``flash_dkv``);
     # mha's float32 score blocks (1, 32, 512, <= 8192) are gone
     calls = v5e.mosaic_calls(text)
-    assert len(calls) == 6 * 3, [c[-60:] for c in calls]
+    assert len(calls) == 6 * 2, [c[-60:] for c in calls]
     assert all("/core/" in c and ("mla" in c) for c in calls), calls
-    for kern, n in (("flash_fwd", 6), ("flash_dq", 6), ("flash_dkv", 6)):
+    for kern, n in (("flash_fwd", 6), ("flash_bwd", 6)):
         assert sum(f"/{kern}/pallas_call" in c for c in calls) == n, kern

@@ -49,12 +49,15 @@ def test_the_nemotron_step_fits_a_chip_at_one_rank_s_share(one_chip):
     # the shared expert is whole: (5376, 4096) up, no 672-column share
     assert "f32[5376,4096]" in text and "f32[672,4096]" not in text
     # the attention layer (4 query heads on 1 key/value head of 128) is
-    # the flash kernels: three Mosaic calls (PR 44: one forward); since
-    # PR 41 the five mixers' scans are the kernels of ops/ssd_fused.py
-    # (forward, recompute, backward), billed to their scan scopes
+    # the flash kernels: two Mosaic calls (PR 44: one forward; PR 48: one
+    # backward, ``flash_bwd``); since PR 41 the five mixers' scans are the
+    # kernels of ops/ssd_fused.py (forward, recompute, backward), billed
+    # to their scan scopes
     calls = v5e.mosaic_calls(text)
     ssd = [c for c in calls if "/ssd_scan" in c]
-    assert len(ssd) == 15 and len(calls) == 18, [c[-60:] for c in calls]
+    assert len(ssd) == 15 and len(calls) == 17, [c[-60:] for c in calls]
+    assert sorted(c.split("/")[-2] for c in calls if c not in ssd) == [
+        "flash_bwd", "flash_fwd"]
     assert all("mixer" in c and "/scan/" in c for c in ssd), ssd
     assert all("attn" in c for c in calls if c not in ssd), calls
 

@@ -19,8 +19,9 @@ qwen3_next's published widths, compiled for a DESCRIBED v5e chip
   (PR 47: what ``gdn_solve`` and ``gdn_scan`` wrote is kept across the
   backward pass);
 * lowered for a TPU, masked attention IS the flash kernels of
-  ``ops/flash.py`` (PR 37) at qwen3_next's head shapes, ONE call of each
-  a layer (PR 44: the net's ``remat`` policy keeps ``o`` and ``lse``).
+  ``ops/flash.py`` (PR 37) at qwen3_next's head shapes, ONE ``flash_fwd``
+  and ONE ``flash_bwd`` a layer (PR 44: the net's ``remat`` policy keeps
+  ``o`` and ``lse``; PR 48: the backward is one kernel).
 """
 
 import re

@@ -46,14 +46,16 @@ def test_the_smallthinker_step_fits_a_chip_at_sixteen_held_experts(one_chip):
     # the held experts row-major through the scan like the accepted cells'
     assert re.search(r"f32\[16,2560,1536\]\{2,1,0", text)
     assert not re.search(r"f32\[16,(?:2560,1536|768,2560)\]\{1,2,0", text)
-    # all four attention layers are the flash kernels, three calls each:
-    # ONE forward a layer under the net's remat policy (PR 44)
+    # all four attention layers are the flash kernels, two calls each:
+    # ONE forward a layer under the net's remat policy (PR 44) and ONE
+    # backward (PR 48: ``flash_bwd``, no ``flash_dq`` + ``flash_dkv``)
     calls = v5e.mosaic_calls(text)
-    assert len(calls) == 12, [c[-60:] for c in calls]
-    assert sum("/core_window/" in c for c in calls) == 9
-    assert sum("/core_full/" in c and "l1_attn0" in c for c in calls) == 3
+    assert len(calls) == 8, [c[-60:] for c in calls]
+    assert sum("/core_window/" in c for c in calls) == 6
+    assert sum("/core_full/" in c and "l1_attn0" in c for c in calls) == 2
     fwd = [c for c in calls if c.endswith("flash_fwd/pallas_call")]
-    assert len(fwd) == 4
+    assert len(fwd) == 4 == sum(
+        c.endswith("flash_bwd/pallas_call") for c in calls)
     assert not any("rematted_computation" in c or "transpose(" in c
                    for c in fwd)
     # the kept lse is its numbers, (heads, T)
@@ -79,7 +81,7 @@ def test_a_smallthinker_window_layer_lowered_for_a_tpu_is_the_flash_kernels(
     text = compiled.as_text()
     calls = v5e.mosaic_calls(text)
     assert sorted(c.split("/")[-2] for c in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd"], calls
+        "flash_bwd", "flash_fwd"], calls
     assert all("l3_attn1" in c and "/core_window/" in c for c in calls), calls
     assert not v5e.SCORE_BLOCK.search(text)
     # no key or value repeated to the query heads' count in HBM

@@ -39,12 +39,15 @@ def test_the_trinity_step_fits_a_chip_at_eight_held_experts(one_chip):
     # the held experts row-major through the scan like the accepted cells'
     assert re.search(r"f32\[8,2048,2048\]\{2,1,0", text)
     assert not re.search(r"f32\[8,(?:2048,2048|1024,2048)\]\{1,2,0", text)
-    # all five attention layers are the flash kernels, three calls each
-    # (one forward: PR 44), each under its layer's own core scope
+    # all five attention layers are the flash kernels, two calls each
+    # (one forward: PR 44; one backward, ``flash_bwd``: PR 48), each
+    # under its layer's own core scope
     calls = v5e.mosaic_calls(text)
-    assert len(calls) == 15, [c[-60:] for c in calls]
-    assert sum("/core_window/" in c for c in calls) == 12
-    assert sum("/core_full/" in c and "l9_attn4" in c for c in calls) == 3
+    assert len(calls) == 10, [c[-60:] for c in calls]
+    assert sum("/core_window/" in c for c in calls) == 8
+    assert sum("/core_full/" in c and "l9_attn4" in c for c in calls) == 2
+    assert sorted({c.split("/")[-2] for c in calls}) == [
+        "flash_bwd", "flash_fwd"]
 
 
 @pytest.mark.parametrize("window, steps", [(2048, 45), (0, 136)],
@@ -66,7 +69,7 @@ def test_a_trinity_attention_layer_lowered_for_a_tpu_is_the_flash_kernels(
     text = compiled.as_text()
     calls = v5e.mosaic_calls(text)
     assert sorted(c.split("/")[-2] for c in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd"], calls
+        "flash_bwd", "flash_fwd"], calls
     scope = "core_window" if window else "core_full"
     assert all("l3_attn1" in c and f"/{scope}/" in c for c in calls), calls
     assert not v5e.SCORE_BLOCK.search(text)

@@ -94,14 +94,16 @@ def compile_layer(one_chip, kind, cfg, shapes, scope, **checkpoint):
 def attention_layer_is_the_flash_kernels(one_chip, cfg):
     """One ``attention`` layer on a packed row of 8192 tokens, bfloat16,
     under ``remat`` as the step programs run it (the net's policy: the
-    forward kernel's two outputs are kept, so it runs once)."""
+    forward kernel's two outputs are kept, so it runs once; PR 48: the
+    backward is the ONE kernel — that it compiles here is the proof it
+    fits a v5e's VMEM at these head shapes)."""
     compiled = compile_layer(one_chip, "attention",
                              dict(cfg, causal=1, no_bias=1, prenorm=1),
                              [(1, 8192, 2048), (1, 8192)], "l3_attn1")
     text = compiled.as_text()
     calls = mosaic_calls(text)
     assert sorted(c.split("/")[-2] for c in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd"], calls
+        "flash_bwd", "flash_fwd"], calls
     assert all("l3_attn1" in c for c in calls), calls
     assert not SCORE_BLOCK.search(text)
     # grouped heads are read by the index map: no key or value repeated
@@ -147,13 +149,14 @@ def net_s_remat_runs_the_forward_kernel_once(conf, heads):
     with its stack cut to the ONE attention layer at its published head
     shapes on a row of 8192 tokens, over a small vocabulary and small
     feed-forward parts.  The compiled step holds one ``flash_fwd`` for
-    the layer's ``flash_dq`` and ``flash_dkv`` (PR 44: the forward's
-    ``o`` and ``lse`` are kept across the backward pass), and the kept
-    ``lse`` is its numbers, ``(heads, T)``, not 128 lanes a row."""
+    the layer's one ``flash_bwd`` (PR 44: the forward's ``o`` and
+    ``lse`` are kept across the backward pass; PR 48: one backward
+    kernel), and the kept ``lse`` is its numbers, ``(heads, T)``, not 128
+    lanes a row."""
     text = compile_step(conf).as_text()
     calls = mosaic_calls(text)
     assert sorted(c.split("/")[-2] for c in calls) == [
-        "flash_dkv", "flash_dq", "flash_fwd"], calls
+        "flash_bwd", "flash_fwd"], calls
     assert all("l1_attn0" in c for c in calls), calls
     (fwd,) = [c for c in calls if "flash_fwd" in c]
     assert "rematted_computation" not in fwd and "transpose(" not in fwd

@@ -14,7 +14,13 @@ kernel reading says what it measured: ``blocks_a_head``, the blocks the
 forward visits a head for the row it timed, by what they hold — no mask
 at all, one document crossed by the diagonal or the window's edge, a
 document boundary — from ``flash.count_blocks``, the function the layers'
-``attn_blocks`` counters use.
+``attn_blocks`` counters use.  It also carries the backward's kernels
+alone, ms a call on the forward's own ``o`` and ``lse``
+(``bwd_kernels_ms``): ``flash_dq`` and ``flash_dkv``, their sum, and the
+ONE kernel ``flash_bwd`` that ``ops/flash.py`` runs in their place where
+a row fits its VMEM budget — whose ``dq``, ``dk`` and ``dv`` are held
+against the two kernels' first (``one_vs_two_rel_err``); ``backward``
+says which of the two forms the ``fwd_bwd_ms`` beside it ran.
 A measurement path: it refuses a host without a TPU.
 
 Usage:
@@ -63,6 +69,7 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from cxxnet_tpu.ops import flash
     from cxxnet_tpu.ops.attention import mha
     from cxxnet_tpu.ops.flash import count_blocks, flash_attention
 
@@ -93,6 +100,41 @@ def main() -> int:
             walls.append(time.perf_counter() - t0)
         return float(np.median(walls)) * 1e3
 
+    def backward_kernels(q, k, v, w, scale, bq, bk):
+        """The backward's kernels alone at blocks ``bq x bk`` -> ``(ms a
+        call by kernel, the one kernel's largest error against the
+        two)``, each a jitted call of its own on the folded operands."""
+        h = q.shape[2]
+        scale = float(q.shape[-1] ** -0.5 if scale is None else scale)
+        qf, kf, vf, do = (flash._fold(x) for x in (q, k, v, w))
+        out, lse = flash._forward(
+            qf, kf, vf, doc, None, None, causal=True, scale=scale, bq=bq,
+            bk=bk, heads=h, interpret=args.cpu_rehearsal, window=args.window)
+        dl = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(
+            -1, keepdims=True)
+
+        def alone(kern):
+            def run(*a):
+                geo = flash._Geometry(qf, kf, vf, doc, None, None, True,
+                                      scale, bq, bk, h, args.window)
+                got = kern(*a, geo, args.cpu_rehearsal)
+                return got if isinstance(got, tuple) else (got,)
+            return jax.jit(run)
+
+        a = (qf, kf, vf, do, lse, dl)
+        dq, dkv, one = (alone(kern) for kern in (
+            flash._bwd_dq, flash._bwd_dkv, flash._bwd_one))
+        ms = {"flash_dq": timed(dq, *a), "flash_dkv": timed(dkv, *a)}
+        ms["two"] = ms["flash_dq"] + ms["flash_dkv"]
+        if not flash._one_fits(kf.shape[1], qf.shape[-1], vf.shape[-1],
+                               vf.dtype):
+            return ms, None
+        ms["flash_bwd"] = timed(one, *a)
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+        err = max(float(jnp.abs(f32(x) - f32(y)).max() / jnp.abs(f32(y)).max())
+                  for x, y in zip(one(*a), dq(*a) + dkv(*a)))
+        return ms, err
+
     for name in args.shapes.split(","):
         h, hk, dqk, dv, scale = SHAPES[name]
         mk = lambda *s: jnp.asarray(rng.randn(*s), jnp.bfloat16)  # noqa: E731
@@ -113,7 +155,7 @@ def main() -> int:
         a = (q, k, v, w, doc)
         ref = fwd(ref_attn)(*a)[0].astype(jnp.float32)
         ref_g = grads(ref_attn)(*a)
-        rows = [] if args.no_xla else [("xla_rows_512", ref_attn, None)]
+        rows = [] if args.no_xla else [("xla_rows_512", ref_attn, None, None)]
         for blk in args.blocks.split(","):
             bq, bk = (int(x) for x in blk.split("x"))
             visited, free, one = (int(n) // h for n in count_blocks(
@@ -126,8 +168,8 @@ def main() -> int:
                              interpret=args.cpu_rehearsal, **win)[0],
                          {"visited": visited, "unmasked": free,
                           "positions_only": one - free,
-                          "document_boundary": visited - one}))
-        for label, attn, blocks in rows:
+                          "document_boundary": visited - one}, (bq, bk)))
+        for label, attn, blocks, blk in rows:
             try:
                 got = fwd(attn)(*a)[0].astype(jnp.float32)
                 err = float(jnp.abs(got - ref).max())
@@ -144,6 +186,11 @@ def main() -> int:
                         "device": dev.device_kind}
                 if blocks:
                     line["blocks_a_head"] = blocks
+                    line["bwd_kernels_ms"], line["one_vs_two_rel_err"] = (
+                        backward_kernels(q, k, v, w, scale, *blk))
+                    line["backward"] = (
+                        "flash_bwd" if "flash_bwd" in line["bwd_kernels_ms"]
+                        else "flash_dq + flash_dkv")
             except Exception as e:  # noqa: BLE001 - a reading, reported
                 line = {"shape": name, "form": label,
                         "error": f"{type(e).__name__}: {e}"[:2000]}
