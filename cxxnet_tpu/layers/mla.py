@@ -19,6 +19,11 @@ rope_dim`` wide and the value product ``v_dim`` wide.  With ``u`` the
     o_h = softmax(s, causal, own document) v_h
     y = concat_h(o_h) W_o^T
 
+With ``q_rank = 0`` (the family's ``q_lora_rank: null``) the query has no
+latent and no norm: ``[q_nope | q_rope]_h = u W_q^T``.  With ``out_gate =
+head`` every head's output is gated by one scalar a token before ``W_o``:
+``o_h <- o_h * sigmoid(u . w_gate_h)``.
+
 Training runs the **expanded** form: keys and values are made from the
 latent for every head and go through ``ops/attention.attend``, the one
 chooser ``attention``'s masked path uses too: lowered for a TPU, a long
@@ -36,8 +41,10 @@ decode's; this layer has no cache and no decode path.
 
 ``latent_attention`` config keys:
 
-* ``nhead``, ``q_rank``, ``kv_rank``, ``nope_dim``, ``rope_dim``,
-  ``v_dim`` — required, positive; ``rope_dim`` even
+* ``nhead``, ``kv_rank``, ``nope_dim``, ``rope_dim``, ``v_dim`` —
+  required, positive; ``rope_dim`` even; ``q_rank`` — required, positive
+  or 0 (no query latent)
+* ``out_gate`` — ``none`` (default) or ``head``
 * ``rope_theta`` (10000), ``rope_interleave`` (1: the pairs ``(2i,
   2i+1)``, as the family's checkpoints keep them; 0: rotate-half)
 * ``causal`` (0)
@@ -53,8 +60,11 @@ q_rank) — a head's rows are its ``nope_dim`` then its ``rope_dim``;
 ``wkva`` (kv_rank + rope_dim, D) — the latent's rows, then the shared
 rotary key's; ``kv_norm`` (kv_rank); ``wkvb`` (nhead (nope_dim + v_dim),
 kv_rank) — a head's rows are its keys' ``nope_dim`` then its ``v_dim``;
-``wproj`` (D, nhead v_dim); ``norm`` (D) with ``prenorm``.  All float32
-at rest, cast where used.
+``wproj`` (D, nhead v_dim); ``norm`` (D) with ``prenorm``.  With ``q_rank
+= 0`` the one ``wq`` (nhead (nope_dim + rope_dim), D) stands in the place
+of ``wqa``, ``q_norm`` and ``wqb`` (a parameter tree that still brings one
+of the three is refused); with ``out_gate = head`` there is ``wgate``
+(nhead, D).  All float32 at rest, cast where used.
 
 State (``aux``, carried through the step programs and read once a
 round by ``NetTrainer.count_layer_state``, as ``gated_deltanet``'s):
@@ -72,7 +82,8 @@ Scopes inside the layer's: ``q_proj``, ``kv_proj``, ``rotary``, ``core``
 kernels ``flash_fwd`` and ``flash_bwd``, once each a layer a step — or
 ``flash_dq`` + ``flash_dkv`` where a row does not fit the one kernel's
 VMEM budget, ``ops/flash.py`` — and the layout changes around them),
-``out_proj``.
+``gate`` (with ``out_gate = head``: the gates' product, the sigmoid and
+the multiplication), ``out_proj``.
 """
 
 from __future__ import annotations
@@ -94,24 +105,33 @@ class LatentAttentionLayer(Layer, Branch):
     type_name = "latent_attention"
     #: state leaf -> the round's counter it is added to
     aux_counters = {name: name for name in ATTN_COUNTERS}
-    f32_tags = frozenset({"wqa", "q_norm", "wqb", "wkva", "kv_norm", "wkvb",
-                          "wproj", "norm", "postnorm"})
+    f32_tags = frozenset({"wqa", "q_norm", "wqb", "wq", "wkva", "kv_norm",
+                          "wkvb", "wgate", "wproj", "norm", "postnorm"})
 
-    #: every one must be set positive
-    _INT_KEYS = ("nhead", "q_rank", "kv_rank", "nope_dim", "rope_dim",
-                 "v_dim")
+    #: every one must be set positive; ``q_rank`` must be set, and may be 0
+    _INT_KEYS = ("nhead", "kv_rank", "nope_dim", "rope_dim", "v_dim")
+    #: the query latent's leaves, absent with ``q_rank = 0``
+    _Q_LATENT = ("wqa", "q_norm", "wqb")
 
     def __init__(self) -> None:
         super().__init__()
         for k in self._INT_KEYS:
             setattr(self, k, 0)
+        self.q_rank = -1
+        self.out_gate = "none"
         self.rope_theta = 10000.0
         self.rope_interleave = 1
         self.causal = 0
 
     def set_param(self, name, val):
-        if name in self._INT_KEYS or name in ("rope_interleave", "causal"):
+        if name in self._INT_KEYS or name in ("q_rank", "rope_interleave",
+                                              "causal"):
             setattr(self, name, int(val))
+        elif name == "out_gate":
+            if val not in ("none", "head"):
+                raise ValueError(
+                    f"latent_attention: out_gate is none or head, got {val!r}")
+            self.out_gate = val
         elif name == "rope_theta":
             self.rope_theta = float(val)
         elif not self.set_branch_param(name, val):
@@ -122,9 +142,10 @@ class LatentAttentionLayer(Layer, Branch):
         if len(in_shapes[0]) != 3:
             raise ValueError("latent_attention: input must be a sequence "
                              "node (N, T, D)")
-        if min(getattr(self, k) for k in self._INT_KEYS) <= 0:
+        if min(getattr(self, k) for k in self._INT_KEYS) <= 0 or (
+                self.q_rank < 0):
             raise ValueError("latent_attention: set " + ", ".join(
-                self._INT_KEYS))
+                self._INT_KEYS) + " and q_rank (0: no query latent)")
         if self.rope_dim % 2:
             raise ValueError(
                 f"latent_attention: rope_dim={self.rope_dim} must be even")
@@ -143,11 +164,15 @@ class LatentAttentionLayer(Layer, Branch):
             "wqa": normal(ks[0], (self.q_rank, d)),
             "q_norm": jnp.ones((self.q_rank,), jnp.float32),
             "wqb": normal(ks[1], (h * (dn + dr), self.q_rank)),
+        } if self.q_rank else {"wq": normal(ks[0], (h * (dn + dr), d))}
+        out.update({
             "wkva": normal(ks[2], (self.kv_rank + dr, d)),
             "kv_norm": jnp.ones((self.kv_rank,), jnp.float32),
             "wkvb": normal(ks[3], (h * (dn + dv), self.kv_rank)),
             "wproj": normal(ks[4], (d, h * dv)),
-        }
+        })
+        if self.out_gate == "head":
+            out["wgate"] = normal(jax.random.fold_in(key, 5), (h, d))
         out.update(self.branch_params(d))
         return out
 
@@ -170,10 +195,19 @@ class LatentAttentionLayer(Layer, Branch):
         cdt = x0.dtype
         doc = doc_index(inputs[1]) if len(inputs) > 1 else None
         u = self.branch_in(params, x0)
+        if not self.q_rank and any(k in params for k in self._Q_LATENT):
+            raise ValueError(
+                "latent_attention: q_rank = 0 has the one query matrix wq; "
+                "the parameters bring " + ", ".join(
+                    k for k in self._Q_LATENT if k in params))
         with jax.named_scope("q_proj"):
-            cq = rms_norm(u @ params["wqa"].astype(cdt).T, params["q_norm"],
-                          self.eps)
-            q = (cq @ params["wqb"].astype(cdt).T).reshape(n, t, h, dn + dr)
+            if self.q_rank:
+                cq = rms_norm(u @ params["wqa"].astype(cdt).T,
+                              params["q_norm"], self.eps)
+                q = cq @ params["wqb"].astype(cdt).T
+            else:
+                q = u @ params["wq"].astype(cdt).T
+            q = q.reshape(n, t, h, dn + dr)
         with jax.named_scope("kv_proj"):
             ckv = u @ params["wkva"].astype(cdt).T
             k_rope = ckv[..., self.kv_rank:].reshape(n, t, 1, dr)
@@ -193,6 +227,10 @@ class LatentAttentionLayer(Layer, Branch):
                     (n, t, h, dr))], axis=-1)
         o, ran = attend_counted("core", q, k, kv[..., dn:],
                                 causal=bool(self.causal), doc=doc)
+        if self.out_gate == "head":
+            with jax.named_scope("gate"):
+                o = o * jax.nn.sigmoid(
+                    u @ params["wgate"].astype(cdt).T)[..., None]
         with jax.named_scope("out_proj"):
             out = o.reshape(n, t, h * dv) @ params["wproj"].astype(cdt).T
         return [self.branch_out(params, x0, out)], ran

@@ -37,7 +37,12 @@ With ``score_func = sigmoid`` (the DeepSeek-V3 family's router) ``p =
 sigmoid(u W_r^T)``; with ``select_bias = 1`` the ``topk`` are the
 largest of ``p + b`` for a leaf ``score_bias`` ``b (nexpert,)`` while
 the weights stay the unbiased ``p`` of the chosen, then ``w = w /
-(sum(w) + 1e-20)`` and, last, ``w = routed_scale * w``.  ``b`` enters
+(sum(w) + 1e-20)`` and, last, ``w = routed_scale * w``.  With ``n_group
+= G > 1`` the choice is group-limited first (DeepSeek-V3's ``noaux_tc``):
+the router's width is ``G`` groups of consecutive experts, a group's
+score is the sum of its 2 largest ``p + b``, a token keeps its
+``topk_group`` best groups and its ``topk`` are the largest ``p + b``
+among THEIR experts.  ``b`` enters
 the selection only: its gradient is exactly zero and no updater moves
 it (the family moves it by a balance rule between steps, from all
 ranks' loads; that rule is not here).  ``shared_gate = 0`` adds the
@@ -120,7 +125,10 @@ With ``nheld = nexpert`` (or ``SLAB_FACTOR`` x the share >= 1) ``C`` is
   ``norm_topk`` (default 1)
 * ``score_func`` — ``softmax`` (default) or ``sigmoid``; ``select_bias``
   (default 0) — 1 chooses by score + ``score_bias``; ``routed_scale``
-  (default 1) multiplies the chosen weights
+  (default 1) multiplies the chosen weights; ``n_group`` / ``topk_group``
+  (default 1 / 1: no limit) — the group-limited choice; ``n_group``
+  divides ``nexpert`` and ``topk_group`` groups hold at least ``topk``
+  experts
 * ``expert_act`` — ``swiglu`` (default), ``reglu`` or ``relu2``, for
   the held experts and the shared one alike; ``latent_hidden`` (default
   0: none) — the width ``L`` the held experts read and write
@@ -165,7 +173,8 @@ summed; ``pairs_dropped`` — 0, by the construction above;
 uint32, wrapping: the reader takes differences.
 
 Scopes inside the layer's: ``route`` (router, softmax, top-k; with a
-second input its norm too),
+second input its norm too; inside it ``group_limit`` with ``n_group >
+1``),
 ``dispatch`` (the sort, a slab's plan and gather; backward: ``dx``),
 ``experts`` (the grouped products), ``combine`` (the weights and the sum
 onto the tokens), ``shared``, and with a latent ``latent_in`` and
@@ -225,26 +234,45 @@ def _onto_tokens(rows, tok, m: int):
     return jax.ops.segment_sum(rows, tok, num_segments=m)
 
 
+def _group_limited(choice, n_group: int, topk_group: int):
+    """``choice (M, E)`` with every expert outside a token's ``topk_group``
+    best of ``n_group`` groups of consecutive experts at ``-inf``; a
+    group's score is the sum of its 2 largest entries (DeepSeek-V3's
+    ``noaux_tc``)."""
+    m, e = choice.shape
+    grp = choice.reshape(m, n_group, e // n_group)
+    score = lax.top_k(grp, min(2, e // n_group))[0].sum(axis=-1)
+    _, best = lax.top_k(score, topk_group)
+    kept = (best[:, :, None] == lax.iota(jnp.int32, n_group)).any(axis=1)
+    return jnp.where(kept[:, :, None], grp, -jnp.inf).reshape(m, e)
+
+
 def route(logits, topk: int, norm_topk: bool = True, *,
-          score_func: str = "softmax", bias=None, scale: float = 1.0):
+          score_func: str = "softmax", bias=None, scale: float = 1.0,
+          n_group: int = 1, topk_group: int = 1):
     """``logits (M, E)`` float32 -> (weights ``(M, k)`` float32, expert
     ids ``(M, k)`` int32): the ``topk`` largest of the softmax over all
     ``E``, by index so a tie admits no extra expert, divided by their
     sum with ``norm_topk``.  ``score_func = "sigmoid"`` scores each
     expert alone; ``bias (E,)`` is added for the choice only — the
     weights are the unbiased scores of the chosen; ``scale`` multiplies
-    them last."""
+    them last.  With ``n_group > 1`` the choice is group-limited: the
+    ``topk`` are taken among the experts of each token's ``topk_group``
+    best groups (scope ``group_limit``)."""
     logits = logits.astype(jnp.float32)
-    if score_func == "softmax" and bias is None:
+    if score_func == "softmax" and bias is None and n_group == 1:
         w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), topk)
         if norm_topk:
             w = w / w.sum(axis=-1, keepdims=True)
     else:
         p = (jax.nn.sigmoid(logits) if score_func == "sigmoid"
              else jax.nn.softmax(logits, axis=-1))
-        _, idx = lax.top_k(
-            p if bias is None
-            else p + lax.stop_gradient(bias.astype(jnp.float32)), topk)
+        choice = (p if bias is None
+                  else p + lax.stop_gradient(bias.astype(jnp.float32)))
+        if n_group > 1:
+            with jax.named_scope("group_limit"):
+                choice = _group_limited(choice, n_group, topk_group)
+        _, idx = lax.top_k(choice, topk)
         w = jnp.take_along_axis(p, idx, axis=-1)
         if norm_topk:
             w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
@@ -429,10 +457,12 @@ class RoutedExpertsLayer(Layer, Branch):
         self.latent_hidden = 0  # 0: the experts read the stream itself
         self.expert_act = "swiglu"
         self.route_norm = ""  # the layer whose norm the router's input takes
+        self.n_group = 1
+        self.topk_group = 1
 
     _INT_KEYS = ("nexpert", "topk", "first_expert", "nheld",
                  "shared_hidden", "shared_gate", "norm_topk", "select_bias",
-                 "latent_hidden")
+                 "latent_hidden", "n_group", "topk_group")
 
     def set_param(self, name, val):
         if name in self._INT_KEYS:
@@ -493,6 +523,15 @@ class RoutedExpertsLayer(Layer, Branch):
                 f"the {self.nexpert} routed")
         if self.latent_hidden < 0:
             raise ValueError("routed_experts: latent_hidden >= 0")
+        if self.n_group < 1 or self.nexpert % self.n_group or not (
+                1 <= self.topk_group <= self.n_group) or (
+                self.n_group > 1 and self.topk_group
+                * (self.nexpert // self.n_group) < self.topk):
+            raise ValueError(
+                f"routed_experts: n_group={self.n_group} must divide "
+                f"nexpert={self.nexpert}, and topk_group={self.topk_group} "
+                f"(at most n_group) groups must hold at least topk="
+                f"{self.topk} experts")
         return [tuple(in_shapes[0])]
 
     def init_params(self, key, in_shapes) -> Params:
@@ -559,7 +598,8 @@ class RoutedExpertsLayer(Layer, Branch):
             w, idx = route(logits, self.topk, bool(self.norm_topk),
                            score_func=self.score_func,
                            bias=params.get("score_bias"),
-                           scale=self.routed_scale)
+                           scale=self.routed_scale, n_group=self.n_group,
+                           topk_group=self.topk_group)
             if self._held() < self.nexpert:
                 # a share: the weights' cotangent needs the other ranks'
                 w = lax.stop_gradient(w)

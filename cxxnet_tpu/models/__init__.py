@@ -19,6 +19,7 @@ Parity sources (structure, hyper-parameters, schedules):
 from .builders import (  # noqa: F401
     afmoe_conf,
     alexnet_conf,
+    bailing_hybrid_conf,
     googlenet_conf,
     granite_h_conf,
     joyai_llm_flash_conf,
@@ -56,4 +57,5 @@ MODEL_BUILDERS = {
     "nemotron_h": nemotron_h_conf,
     "afmoe": afmoe_conf,
     "smallthinker": smallthinker_conf,
+    "bailing_hybrid": bailing_hybrid_conf,
 }

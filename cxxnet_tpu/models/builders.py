@@ -1150,6 +1150,160 @@ def smallthinker_conf(
                       compute_dtype, eta, scan_steps, feed=feed)
 
 
+def bailing_hybrid_conf(
+    vocab: int = 19648,
+    seq_len: int = 8192,
+    hidden: int = 2560,
+    num_layers: int = 6,
+    layer_group_size: int = 6,
+    first_k_dense: int = 1,
+    attn_heads: int = 32,
+    head_dim: int = 128,
+    short_conv_kernel_size: int = 4,
+    kda_lower_bound: float = -5.0,
+    kv_lora_rank: int = 512,
+    qk_nope_head_dim: int = 128,
+    qk_rope_head_dim: int = 64,
+    v_head_dim: int = 128,
+    rope_theta: float = 6e6,
+    rope_interleave: int = 1,
+    mlp_hidden: int = 6144,
+    num_experts: int = 512,
+    experts_per_tok: int = 8,
+    n_group: int = 8,
+    topk_group: int = 4,
+    expert_hidden: int = 768,
+    shared_hidden: int = 768,
+    routed_scaling_factor: float = 2.5,
+    first_expert: int = 0,
+    experts_held: int = 8,
+    expert_swiglu_limits: Sequence[float] = (),
+    shared_swiglu_limits: Sequence[float] = (),
+    eps: float = 1e-6,
+    token_file: str = "",
+    batch_size: int = 1,
+    num_round: int = 10,
+    dev: str = "tpu",
+    compute_dtype: str = "bfloat16",
+    eta: float = 0.0003,
+    scan_steps: int = 8,
+) -> str:
+    """A ``bailing_hybrid`` style language model (inclusionAI, the
+    Ling-3.0 family): layers in periods of ``layer_group_size``, every
+    layer of a period a Kimi Delta Attention mixer (``kimi_delta``: the
+    delta rule with one decay a head AND key channel under the bounded
+    gate ``kda_lower_bound * sigmoid(.)``, ``attn_heads`` heads of
+    ``head_dim`` for q, k and v alike, a head-wise sigmoid output gate)
+    but the LAST (``(i + 1) % layer_group_size == 0``), which is a
+    multi-head latent attention WITHOUT a query latent and with one
+    sigmoid gate a head on its output (``latent_attention`` with
+    ``q_rank = 0``, ``out_gate = head``); the first ``first_k_dense``
+    layers a dense gated MLP of ``mlp_hidden``, the others
+    ``num_experts`` SwiGLU experts of ``expert_hidden`` behind
+    DeepSeek-V3's group-limited sigmoid router (``n_group`` groups, a
+    token keeps its ``topk_group`` best and takes its
+    top-``experts_per_tok`` by score + bias among them, weighs them by
+    the unbiased scores, renormalised and times
+    ``routed_scaling_factor``) plus one ungated shared expert; every
+    branch pre-normed by ``rms_norm`` and added back; an untied head.
+
+    The defaults are the published widths of Ling-3.0-flash, one whole
+    period deep from layer 0 — the leading dense layer and the five
+    that follow — with ONE RANK'S SHARE of a 64-way expert-parallel
+    layout: ``experts_held`` = 8 of the 512 experts of every layer from
+    ``first_expert`` on (the router still ranks all 512), over an eighth
+    of the vocabulary: 767.0M parameters.  Routing weights, selection
+    bias and the embedding's start as ``joyai_llm_flash_conf``'s.
+
+    ``expert_swiglu_limits`` / ``shared_swiglu_limits`` (the family's
+    ``*_swiglu_limit_list`` for these layers) must be all 0, "no clamp":
+    the clamp of an expert's gate and up products is not built, and a
+    non-zero limit is refused rather than dropped.  The family's
+    prediction module (loss factor 0 as published) is not built here.
+    Written for memory as ``granite_h_conf`` is; documents and positions
+    as ``qwen3_next_conf``.
+    """
+    if any(expert_swiglu_limits) or any(shared_swiglu_limits):
+        raise ValueError(
+            "bailing_hybrid_conf: a non-zero swiglu limit asks for a clamp "
+            "of the experts' gate and up products, which routed_experts "
+            f"does not have: {list(expert_swiglu_limits)} / "
+            f"{list(shared_swiglu_limits)}")
+    if layer_group_size < 1:
+        raise ValueError("bailing_hybrid_conf: layer_group_size >= 1")
+    branch = (f"  prenorm = 1\n  eps = {eps!r}\n"
+              "  residual_scale = 1.0\n"
+              "  init_sigma = 0.02\n")
+    s = (
+        "netconfig = start\n"
+        "layer[0->h0] = embedding:embed\n"
+        f"  nvocab = {vocab}\n"
+        f"  nhidden = {hidden}\n"
+        # a token's own row has to stand out of the stream
+        # (joyai_llm_flash_conf)
+        "  init_sigma = 1.0\n"
+    )
+    for i in range(num_layers):
+        if (i + 1) % layer_group_size:
+            s += (
+                f"layer[h{i},0->x{i}] = kimi_delta:kda{i}\n"
+                f"  nhead = {attn_heads}\n"
+                f"  key_dim = {head_dim}\n"
+                f"  value_dim = {head_dim}\n"
+                f"  conv_width = {short_conv_kernel_size}\n"
+                f"  lower_bound = {kda_lower_bound!r}\n" + branch
+            )
+        else:
+            s += (
+                f"layer[h{i},0->x{i}] = latent_attention:mla{i}\n"
+                f"  nhead = {attn_heads}\n"
+                "  q_rank = 0\n"
+                f"  kv_rank = {kv_lora_rank}\n"
+                f"  nope_dim = {qk_nope_head_dim}\n"
+                f"  rope_dim = {qk_rope_head_dim}\n"
+                f"  v_dim = {v_head_dim}\n"
+                f"  rope_theta = {rope_theta!r}\n"
+                f"  rope_interleave = {rope_interleave}\n"
+                "  out_gate = head\n"
+                "  causal = 1\n" + branch
+            )
+        if i < first_k_dense:
+            s += (
+                f"layer[x{i}->h{i + 1}] = gated_mlp:mlp{i}\n"
+                f"  nhidden = {mlp_hidden}\n" + branch
+            )
+        else:
+            s += (
+                f"layer[x{i}->h{i + 1}] = routed_experts:moe{i}\n"
+                f"  nexpert = {num_experts}\n"
+                f"  topk = {experts_per_tok}\n"
+                f"  n_group = {n_group}\n"
+                f"  topk_group = {topk_group}\n"
+                f"  nhidden = {expert_hidden}\n"
+                f"  first_expert = {first_expert}\n"
+                f"  nheld = {experts_held}\n"
+                f"  shared_hidden = {shared_hidden}\n"
+                "  shared_gate = 0\n"
+                "  score_func = sigmoid\n"
+                "  select_bias = 1\n"
+                f"  routed_scale = {routed_scaling_factor!r}\n"
+                "  norm_topk = 1\n" + branch
+            )
+    s += (
+        f"layer[h{num_layers}->nf] = rms_norm:norm_f\n"
+        f"  eps = {eps!r}\n"
+        "layer[nf->logits] = lm_head:head\n"
+        f"  nhidden = {vocab}\n"
+        "  init_sigma = 0.02\n"
+        "layer[logits->logits] = softmax\n"
+        # the mean over all positions: the loss sums over T
+        f"  grad_scale = {1.0 / seq_len!r}\n"
+        "netconfig = end\n"
+    )
+    return _packed_lm(s, token_file, seq_len, batch_size, num_round, dev,
+                      compute_dtype, eta, scan_steps)
+
+
 NEMOTRON_H_STAGE = "MEMEMEM*EME"
 
 

@@ -31,7 +31,8 @@ LOOP_BILL = ["loop_device_step_ms", "loop_device_idle_pct",
 #: the entries of ``per_layer`` from PR 32's on, in the order their PRs
 #: appended them: PR 33's ten, PR 34's one, PR 36's four, PR 37's one,
 #: PR 38's five, PR 39's one, PR 40's three, PR 41's one, PR 42's four,
-#: PR 43's one, PR 44's one, PR 46's one, PR 47's one, PR 48's one
+#: PR 43's one, PR 44's one, PR 46's one, PR 47's one, PR 48's one, PR
+#: 49's five
 METRICS_FROM_30 = [
     "chunk_overlap_pct", "gdn_mixer_ms_step", "gdn_scan_ms_step",
     "gdn_scan_roofline_pct", "moe_ms_step", "moe_route_dispatch_ms_step",
@@ -46,7 +47,8 @@ METRICS_FROM_30 = [
     "attn_full_core_ms_step", "attn_window_pairs_pct",
     "attn_core_roofline_pct", "attn_unmasked_blocks_pct",
     "attn_fwd_runs_per_bwd", "moe_route_ms_step", "gdn_fwd_runs_per_bwd",
-    "attn_bwd_fused_pct"]
+    "attn_bwd_fused_pct", "kda_mixer_ms_step", "kda_scan_ms_step",
+    "kda_scan_roofline_pct", "kda_scan_fused_pct", "kda_fwd_runs_per_bwd"]
 
 
 def load():

@@ -121,6 +121,9 @@ LONGEST_FIRST = (
     "test_fault_tolerance", "test_joyai_layers", "test_bench_granite",
     "test_afmoe_layers", "test_models", "test_granite_hybrid",
     "test_branch_embed", "test_ops", "test_moe_dispatch", "test_trainer",
+    # PR 49's, behind everything the seed's order placed: a seventh
+    # whole-step compile beside the other six would make three at a time
+    "test_v5e_ling", "test_ling_layers", "test_bench_ling",
 )
 
 

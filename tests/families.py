@@ -300,6 +300,13 @@ SMALLTHINKER = dict(vocab=64, seq_len=64, hidden=32,
                     expert_hidden=24, experts_held=4, dev="cpu",
                     compute_dtype="float32", scan_steps=4)
 
+LING = dict(vocab=64, seq_len=64, hidden=32, num_layers=3, layer_group_size=3,
+            attn_heads=2, head_dim=16, kv_lora_rank=16, qk_nope_head_dim=8,
+            qk_rope_head_dim=4, v_head_dim=8, mlp_hidden=48, num_experts=16,
+            experts_per_tok=3, n_group=4, topk_group=2, expert_hidden=24,
+            shared_hidden=24, experts_held=4, dev="cpu",
+            compute_dtype="float32", scan_steps=4)
+
 FLASH_COUNTERS = ("attn_tokens_flash", "attn_blocks", "attn_blocks_unmasked",
                   "attn_tokens_bwd_fused")
 
@@ -359,6 +366,39 @@ def _smallthinker_also(text):
         models.smallthinker_conf(rope_layout=(0, 1, 1))
     with pytest.raises(ValueError, match="list of 0 and 1"):
         models.smallthinker_conf(rope_layout=(0, 2, 1, 1))
+
+
+def _ling_also(text):
+    # a period's last layer is the latent attention, the others the rule
+    kinds = re.findall(r"^layer\[[^\]]*\] = (\w+):", text, re.M)
+    assert kinds == ["embedding", "kimi_delta", "gated_mlp", "kimi_delta",
+                     "routed_experts", "latent_attention", "routed_experts",
+                     "rms_norm", "lm_head"]
+    assert "q_rank = 0\n  kv_rank = 16" in text
+    assert "mtp_" not in text and text.count("= softmax") == 1
+
+
+def _ling_defaults_also(text, counts):
+    """ISSUE 49's table: 63.05M a KDA mixer, 31.97M the latent attention,
+    47.19M the dense MLP, 54.40M an expert layer's routed part at 8 held,
+    100.60M the vocabulary's two matrices: 767.0M = 12.27 GB."""
+    assert round(sum(counts.values()) * 16 / 1e9, 2) == 12.27
+    kinds = re.findall(r"^layer\[[^\]]*\] = (\w+):", text, re.M)
+    assert kinds.count("kimi_delta") == 5
+    assert kinds.index("latent_attention") == 11    # layer 5's mixer
+    assert text.count("  n_group = 8\n  topk_group = 4\n") == 5
+    assert "label_width = 8192" in text and "nheld = 8" in text
+    # a sixteenth expert a layer would not fit: 1003M = 16.0 GB
+    assert round((sum(counts.values()) + 5 * 8 * 3 * 2560 * 768) * 16
+                 / 1e9, 1) == 16.0
+
+
+def _ling_whole_also(tr, params, ids, lab, loss, grads):
+    assert 0.9 * np.log(64) < float(loss) < 1.6 * np.log(64)
+    # whole layers: the routers learn; the bias never does
+    for key in ("l4_moe1", "l6_moe2"):
+        assert np.abs(np.asarray(grads[key]["wgate"])).max() > 0
+        assert np.abs(np.asarray(grads[key]["score_bias"])).max() == 0
 
 
 def _joyai_defaults_also(text, counts):
@@ -570,4 +610,34 @@ FAMILIES = {
         chunks={"share": (dict(), dict(loss=2e-5, w_abs=2e-5, m_abs=2e-6)),
                 "whole": (dict(experts_held=16),
                           dict(loss=2e-5, w_abs=2e-5, m_abs=2e-6))}),
+    "bailing_hybrid": Family(
+        builder=models.bailing_hybrid_conf, tiny=LING,
+        reference="bailing_hybrid.py",
+        conf_has={"= kimi_delta:": 2, "= latent_attention:": 1,
+                  "= routed_experts:": 2, "= gated_mlp:": 1,
+                  "  lower_bound = -5.0\n": 2, "  out_gate = head\n": 1,
+                  "  n_group = 4\n  topk_group = 2\n": 2,
+                  "rope_theta = 6000000.0": 1, "routed_scale = 2.5": 2},
+        conf_lacks=("tied", "wgate", ":lr", "q_norm"),
+        aux=frozenset({"l1_kda0", "l3_kda1", "l5_mla2", "l4_moe1",
+                       "l6_moe2"}),
+        biased="l4_moe1",
+        # 8 steps x 64 tokens x 3 picks x 2 layers, a quarter of them
+        # held; two mixers count their tokens through the scan, one the
+        # attention's, none by kernels off the TPU
+        counts={"expert_pairs": (0.4 * 768, 1.6 * 768),
+                "kda_scan_tokens": (8 * 64 * 2, 8 * 64 * 2),
+                "attn_tokens": (8 * 64, 8 * 64)},
+        unmoved=FLASH_COUNTERS + ("kda_scan_tokens_fused",), also=_ling_also,
+        defaults={},
+        layers={"l1_kda0": 63_049_888 + 2560,     # the mixer and its norm
+                "l11_mla5": 31_965_696 + 2560,
+                "l2_mlp0": 3 * 2560 * 6144 + 2560,
+                # router + bias, 8 held experts, the shared one, the norm
+                "l4_moe1": 512 * 2560 + 512 + 9 * 3 * 2560 * 768 + 2560,
+                "l0_embed": 19648 * 2560, "l14_head": 19648 * 2560},
+        total=767.0, defaults_also=_ling_defaults_also,
+        whole_net=dict(experts_held=16), grad_tol=dict(atol=3e-6),
+        whole_also=_ling_whole_also,
+        chunks={"share": (dict(), dict(loss=2e-5, w_abs=2e-5, m_abs=2e-6))}),
 }

@@ -51,6 +51,7 @@ def test_model_shapes(name):
               "transformer": 10, "transformer_lm": 256,
               "granite_h": 64, "qwen3_next": 64, "joyai_llm_flash": 64,
               "nemotron_h": 64, "afmoe": 64, "smallthinker": 64,
+              "bailing_hybrid": 64,
               "resnet50": 1000, "resnet101": 1000,
               "resnet152": 1000}[name]
     assert out[-1] == expect
@@ -182,6 +183,10 @@ PARENT_CONF_TEXT = {
     "smallthinker": ("smallthinker_21b_a3b", dict(
         cell="395b180fc7918c4e", rehearsal="217efae201c01c02",
         tail="2793fddd0d477c79")),
+    # Ling-3.0-flash's, as PR 49 (which brought the builder) wrote it
+    "bailing_hybrid": ("ling_3_0_flash", dict(
+        cell="2d0673208147d6b9", rehearsal="12be26b8d8e34358",
+        tail="c5e8f915b318fe02")),
 }
 
 
